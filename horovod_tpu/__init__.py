@@ -13,10 +13,6 @@ Layer map (vs SURVEY.md §1): the user API here is L5; collectives compile
 to XLA HLOs over the device mesh (replacing L2b/L1's NCCL/MPI data plane).
 """
 
-from . import _jax_compat
-
-_jax_compat.install()
-
 from .version import __version__  # noqa: F401
 
 from .basics import (  # noqa: F401
@@ -33,6 +29,7 @@ from .basics import (  # noqa: F401
     config,
     cross_rank,
     cross_size,
+    enable_compile_cache,
     global_axis_name,
     global_mesh,
     init,

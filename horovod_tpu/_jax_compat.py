@@ -1,11 +1,5 @@
-"""Compatibility shims for the range of jax releases the image may carry.
-
-The codebase targets the current public API (``jax.shard_map`` with
-``check_vma=``); on older jax (< 0.5) the same functionality lives at
-``jax.experimental.shard_map.shard_map`` with the ``check_rep=`` spelling.
-Installing the alias once at import time keeps every call site on the
-modern spelling instead of scattering version branches through the tree.
-"""
+"""The one JAX configuration call the subprocess worker scripts in
+``tests/`` share."""
 
 from __future__ import annotations
 
@@ -13,35 +7,6 @@ import jax
 
 
 def force_cpu_devices(n: int) -> None:
-    """Configure an ``n``-device virtual CPU mesh across jax releases.
-
-    Newer jax exposes the ``jax_num_cpu_devices`` config option; older
-    releases only honor the XLA_FLAGS form, which still takes effect as
-    long as the backend has not been initialized yet (callers — the
-    subprocess worker scripts in tests/ — invoke this immediately after
-    importing jax, before any device query).
-    """
-    import os
-
-    try:
-        jax.config.update("jax_num_cpu_devices", int(n))
-    except AttributeError:
-        flags = os.environ.get("XLA_FLAGS", "")
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={int(n)}"
-        ).strip()
-
-
-def install() -> None:
-    """Idempotently install missing aliases onto the ``jax`` module."""
-    if not hasattr(jax, "shard_map"):
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                      check_vma=None, **kwargs):
-            if check_vma is not None and "check_rep" not in kwargs:
-                kwargs["check_rep"] = check_vma
-            return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kwargs)
-
-        jax.shard_map = shard_map
+    """Configure an ``n``-device virtual CPU mesh. Call right after
+    importing jax, before any device query initializes the backend."""
+    jax.config.update("jax_num_cpu_devices", int(n))

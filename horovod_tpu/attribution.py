@@ -55,7 +55,7 @@ explicit ``insufficient_samples`` body), the scrape gauges
 Stdlib-only and jax-free by design (like ``tracing.py`` /
 ``comms_model.py``): the KV server imports this on the driver before
 any framework init. jax is touched only inside
-:func:`detect_peak_flops`, lazily and best-effort.
+:func:`detect_peak_flops`, when the model's FLOPs are declared.
 """
 
 from __future__ import annotations
@@ -561,31 +561,34 @@ CHIP_PEAK_FLOPS = {
 }
 
 
-def peak_flops_for_kind(device_kind: str) -> float | None:
-    """Peak bf16 FLOPs/s for a device-kind string, or None when the
-    kind is unknown (CPU meshes, future chips)."""
+def peak_flops_for_kind(device_kind: str) -> float:
+    """Peak bf16 FLOPs/s for a device-kind string. A kind the table
+    does not list is an error: a missing peak would turn every MFU
+    into ``null`` and an assumed one would make it wrong."""
     kind = str(device_kind or "").lower()
     for key, peak in CHIP_PEAK_FLOPS.items():
         if key in kind:
             return peak
-    return None
+    raise ValueError(
+        f"no peak FLOP/s known for device kind {device_kind!r}: add it "
+        f"to attribution.CHIP_PEAK_FLOPS (known: "
+        f"{', '.join(CHIP_PEAK_FLOPS)})")
 
 
 def detect_peak_flops() -> float | None:
     """This process's aggregate peak FLOPs/s (per-chip peak × local
-    device count), lazily via jax; None on unknown backends. Never
-    raises — the attribution plane must work on the driver too, where
-    jax may not even be importable."""
+    device count). None where there is no chip to have a peak — a CPU
+    mesh, or the driver, where jax may not be importable; an
+    accelerator the table does not know raises
+    (:func:`peak_flops_for_kind`)."""
     try:
         import jax
-
-        devices = jax.local_devices()
-        if not devices:
-            return None
-        peak = peak_flops_for_kind(getattr(devices[0], "device_kind", ""))
-        return peak * len(devices) if peak else None
-    except Exception:  # noqa: BLE001 — best-effort detection
+    except ImportError:
         return None
+    devices = jax.local_devices()
+    if not devices or devices[0].platform == "cpu":
+        return None
+    return peak_flops_for_kind(devices[0].device_kind) * len(devices)
 
 
 # ---------------------------------------------------------------------------
@@ -595,7 +598,6 @@ def detect_peak_flops() -> float | None:
 _lock = threading.Lock()
 _model_flops: float | None = None
 _peak_flops: float | None = None
-_peak_probed = False
 _sentinel: RegressionSentinel | None = None
 _last_step: dict | None = None
 
@@ -604,35 +606,30 @@ def set_model_flops_per_step(flops: float | None,
                              peak_flops: float | None = None) -> None:
     """Declare the model's analytic FLOPs per training step for THIS
     process's devices — the MFU numerator (``hvd_mfu_ratio`` =
-    flops / (step wall × peak)). ``peak_flops`` overrides the detected
-    per-process aggregate peak (:func:`detect_peak_flops`); pass it on
-    backends the chip table doesn't know. ``None`` clears the setting
-    (the gauge stops updating)."""
-    global _model_flops, _peak_flops, _peak_probed
+    flops / (step wall × peak)). Call it after ``hvd.init()``: the peak
+    is resolved HERE, once, from the local devices
+    (:func:`detect_peak_flops`) unless ``peak_flops`` overrides it, so
+    a chip the table does not know raises in the caller's face and not
+    inside the per-step hook, whose guard would swallow it. ``None``
+    clears the setting (the gauge stops updating)."""
+    global _model_flops, _peak_flops
+    with _lock:
+        peak = _peak_flops
+    if flops is None:
+        peak = None
+    elif peak_flops is not None:
+        peak = float(peak_flops) if peak_flops > 0 else None
+    elif peak is None:
+        peak = detect_peak_flops()
     with _lock:
         _model_flops = float(flops) if flops else None
-        if peak_flops is not None:
-            _peak_flops = float(peak_flops) if peak_flops > 0 else None
-            _peak_probed = True
-        elif flops is None:
-            _peak_flops = None
-            _peak_probed = False
+        _peak_flops = peak
 
 
 def model_flops() -> tuple[float | None, float | None]:
-    """(flops_per_step, peak_flops_per_process), detecting the peak on
-    first use when it was not passed explicitly."""
-    global _peak_flops, _peak_probed
+    """(flops_per_step, peak_flops_per_process) as declared."""
     with _lock:
-        flops = _model_flops
-        peak = _peak_flops
-        probed = _peak_probed
-    if flops is not None and peak is None and not probed:
-        peak = detect_peak_flops()
-        with _lock:
-            _peak_flops = peak
-            _peak_probed = True
-    return flops, peak
+        return _model_flops, _peak_flops
 
 
 def local_sentinel() -> RegressionSentinel:
@@ -646,11 +643,10 @@ def local_sentinel() -> RegressionSentinel:
 def reset_for_testing() -> None:
     """Fresh worker-side state (model FLOPs kept out too; env knobs
     re-read on next use)."""
-    global _model_flops, _peak_flops, _peak_probed, _sentinel, _last_step
+    global _model_flops, _peak_flops, _sentinel, _last_step
     with _lock:
         _model_flops = None
         _peak_flops = None
-        _peak_probed = False
         _sentinel = None
         _last_step = None
 

@@ -172,6 +172,24 @@ def _exchange_coordinator_port(coord: str, proc_id: int) -> str:
     )
 
 
+def enable_compile_cache() -> str:
+    """Give JAX's persistent compilation cache a directory; returns it.
+
+    ``JAX_COMPILATION_CACHE_DIR`` is how the cache is placed from
+    outside: when it is set JAX reads it itself and nothing is set here.
+    Otherwise the cache lives at ``<checkout>/.jax_cache`` — a fixed
+    path, because the path is part of the cache's key and a directory
+    that moves never hits. Call before the first compilation.
+    """
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(checkout, ".jax_cache"))
+    return jax.config.jax_compilation_cache_dir
+
+
 def init(devices: Sequence[Any] | None = None) -> None:
     """Initialize the framework: topology, global mesh, process sets.
 

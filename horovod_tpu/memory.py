@@ -130,39 +130,67 @@ def capacity_bytes() -> int | None:
     return None
 
 
+# On a TPU ``bytes_in_use`` counts buffers only. What a loaded program
+# needs for its temporaries is ``bytes_reserved``: held for as long as the
+# executable is loaded, not only while it runs (v5e, BERT-Large sharded
+# step, read after the steps returned: 10.87 GiB beside 2.71 GiB of
+# buffers on every device, ``bytes_limit - bytes_in_use - bytes_reserved
+# == largest_free_block_bytes``; 0 once the step is dropped; PERF.md). The watermarks
+# below read ``bytes_in_use`` alone and do not see it: PERF.md section 7.
+_DEVICE_STAT_KEYS = ("bytes_in_use", "bytes_limit", "peak_bytes_in_use",
+                     "bytes_reserved", "peak_bytes_reserved",
+                     "largest_free_block_bytes")
+
 _device_stats_dead = False
 
 
+def local_device_memory_stats() -> list[dict]:
+    """The backend's memory view of EVERY local device, in
+    ``jax.local_devices()`` order — one dict per device with ``id``,
+    ``kind`` and the allocator counters the platform reports (none on
+    CPU)."""
+    import jax
+
+    return [
+        {"id": d.id, "kind": d.device_kind,
+         **{k: int(v) for k, v in (d.memory_stats() or {}).items()
+            if k in _DEVICE_STAT_KEYS and isinstance(v, (int, float))}}
+        for d in jax.local_devices()
+    ]
+
+
 def device_memory_stats() -> dict | None:
-    """The backend's device-memory view (``bytes_in_use`` /
-    ``bytes_limit`` / ``peak_bytes_in_use`` where present), from the
-    first local device. None when jax is unavailable (driver-side) or
-    the platform exposes nothing (CPU) — and that verdict is cached, so
-    the per-span watermark hook never re-probes a statless backend.
-    Never raises."""
+    """The per-device memory view this process is bound by
+    (``bytes_in_use`` / ``bytes_limit`` / ``peak_bytes_in_use`` where
+    present), folded over all local devices: the fullest device's
+    usage counters, the smallest limit and free block — so a run that
+    piles everything on one device reads as that device, whichever it
+    is. None when jax is unavailable (driver-side) or the platform
+    exposes nothing (CPU) — and that verdict is cached, so the per-span
+    watermark hook never re-probes a statless backend. Never raises:
+    heartbeat payloads and OOM flight records call it while something
+    else is already going wrong (:func:`local_device_memory_stats` is
+    the one that raises)."""
     global _device_stats_dead
     if _device_stats_dead:
         return None
     try:
-        import jax
-
-        devs = jax.local_devices()
-        if not devs:
-            _device_stats_dead = True
-            return None
-        stats = devs[0].memory_stats()
-        if not stats:
-            _device_stats_dead = True
-            return None
-        keep = ("bytes_in_use", "bytes_limit", "peak_bytes_in_use",
-                "bytes_reserved", "largest_free_block_bytes")
-        return {k: int(v) for k, v in stats.items()
-                if k in keep and isinstance(v, (int, float))}
+        per_device = local_device_memory_stats()
     except ImportError:
         _device_stats_dead = True  # driver-side: jax never appears
         return None
-    except Exception:  # noqa: BLE001 — stats are advisory, CPU has none
+    except Exception:  # noqa: BLE001 — a backend whose stats call fails
         return None
+    folded: dict[str, int] = {}
+    for k in _DEVICE_STAT_KEYS:
+        values = [stats[k] for stats in per_device if k in stats]
+        if values:
+            folded[k] = (min(values) if k in (
+                "bytes_limit", "largest_free_block_bytes") else max(values))
+    if not folded:
+        _device_stats_dead = True
+        return None
+    return folded
 
 
 # ---------------------------------------------------------------------------

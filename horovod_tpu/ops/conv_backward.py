@@ -54,7 +54,7 @@ def _dw_kernel(x_ref, dy_ref, out_ref):
     )
 
 
-def dw_1x1(x2d, dy2d, tile: int = 4096, interpret: bool | None = None):
+def dw_1x1(x2d, dy2d, tile: int = 4096, interpret: bool = False):
     """Filter gradient of a 1x1 conv as a streaming Pallas matmul.
 
     ``x2d [K, Cin]``, ``dy2d [K, Cout]`` (K = N*H*W, padded by the
@@ -65,8 +65,6 @@ def dw_1x1(x2d, dy2d, tile: int = 4096, interpret: bool | None = None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     K, ci = x2d.shape
     _, co = dy2d.shape
     if K % tile:
@@ -95,12 +93,13 @@ def dw_1x1(x2d, dy2d, tile: int = 4096, interpret: bool | None = None):
     )(x2d, dy2d)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def conv1x1(x, w, strides=(1, 1)):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def conv1x1(x, w, strides=(1, 1), interpret=False):
     """1x1 convolution (NHWC x [1,1,Cin,Cout]) with Pallas backward.
 
     Forward matches ``lax.conv_general_dilated``; backward computes
-    dx as one MXU matmul (dy @ w^T) and dw with :func:`dw_1x1`.
+    dx as one MXU matmul (dy @ w^T) and dw with :func:`dw_1x1`
+    (compiled for the TPU unless ``interpret=True``).
     """
     return _conv1x1_fwd_impl(x, w, strides)
 
@@ -112,11 +111,11 @@ def _conv1x1_fwd_impl(x, w, strides):
                       preferred_element_type=jnp.float32).astype(x.dtype)
 
 
-def _conv1x1_fwd(x, w, strides):
+def _conv1x1_fwd(x, w, strides, interpret):
     return _conv1x1_fwd_impl(x, w, strides), (x, w)
 
 
-def _conv1x1_bwd(strides, res, dy):
+def _conv1x1_bwd(strides, interpret, res, dy):
     x, w = res
     xs = x[:, ::strides[0], ::strides[1], :] if strides != (1, 1) else x
     N, H, W_, ci = xs.shape
@@ -134,7 +133,7 @@ def _conv1x1_bwd(strides, res, dy):
         dx = dx.at[:, ::strides[0], ::strides[1], :].set(dxs)
     else:
         dx = dxs
-    dw = dw_1x1(xs.reshape(-1, ci), dy2)[None, None]
+    dw = dw_1x1(xs.reshape(-1, ci), dy2, interpret=interpret)[None, None]
     return dx, dw.astype(w.dtype)
 
 

@@ -833,11 +833,14 @@ def _segment_sync(leaves, seg_index, spec, axis_name, salt):
     custom-vjp backward reduces the segment's COTANGENTS through the
     exact wire the DistributedOptimizer was built with (op, compression,
     scaling, bucketing — via ``optimizer._reduce_grads``). Because the
-    boundary sits inside the differentiated function, the collective is
-    emitted at the point in the backward pass where this segment's
-    gradients finish accumulating — for late-layer segments that is
-    EARLY in the backward, so XLA's latency-hiding scheduler can overlap
-    the transfer with the remaining layers' backward compute.
+    boundary sits inside the differentiated function, the collective
+    DEPENDS only on this segment's cotangents — for late-layer segments
+    those are complete EARLY in the backward, so XLA's latency-hiding
+    scheduler can overlap the transfer with the remaining layers'
+    backward compute. Data dependence is all the program fixes: where
+    the collective prints in the jaxpr (after the whole backward, under
+    jax 0.9's trace-order transposition) and when a device issues it are
+    not.
 
     ``salt`` (the int8 stochastic-rounding step counter) rides the
     forward as a residual rather than a closure: custom-vjp rules must
@@ -939,9 +942,9 @@ def overlap_gradient_sync(
     segment's gradients materialize first during backprop) and each
     segment gets an identity-forward / reduce-backward custom-vjp
     boundary. Differentiating through the wrapped tree yields gradients
-    that are ALREADY reduced, with each segment's collective issued at
-    the point its gradients finish accumulating instead of after a
-    global post-backward barrier.
+    that are ALREADY reduced, with each segment's collective depending
+    on that segment's gradients alone instead of sitting behind a global
+    post-backward barrier.
 
     Must be applied INSIDE the differentiated function::
 
@@ -1021,14 +1024,14 @@ def make_overlapped_train_step(
       ``HOROVOD_OVERLAP_SEGMENTS``.
 
     The parameter pytree is split into K contiguous byte-balanced
-    segments (reverse-topological issue: during backward the LAST
-    segment's gradients materialize first, and its collective is
-    emitted right there), so ICI/DCN transfer of segment *i* runs
-    concurrently with backward compute of segments *< i* instead of
-    serializing after the full backward. Hierarchical (cross, local)
-    meshes compose per segment: each segment's buckets take the
-    two-level reduce-scatter → cross-allreduce → allgather form,
-    including the int8-compressed exchange.
+    segments (during backward the LAST segment's gradients materialize
+    first, and its collective needs nothing else), so ICI/DCN transfer
+    of segment *i* can run concurrently with backward compute of
+    segments *< i* instead of serializing after the full backward.
+    Hierarchical (cross, local) meshes compose per segment: each
+    segment's buckets take the two-level reduce-scatter →
+    cross-allreduce → allgather form, including the int8-compressed
+    exchange.
     """
     import optax
 

@@ -206,11 +206,14 @@ def shard_sequence(tree, axis: int = 2, process_set=None):
 
 
 def make_sp_attention_step(axis_name: str = "hvd", scheme: str = "ring",
-                           causal: bool = False, mesh=None):
+                           causal: bool = False, mesh=None,
+                           interpret: bool = False):
     """Build a jitted global-sequence attention fn over the mesh.
 
     Takes global [B, H, S, D] arrays, shards S over the axis, runs the
     chosen scheme, returns the global output — the one-call user surface.
+    ``interpret=True`` runs the ``ring-flash`` Pallas kernel in
+    interpret mode (CPU tests); the default compiles it for the TPU.
     """
     from jax.sharding import PartitionSpec as P
 
@@ -223,8 +226,7 @@ def make_sp_attention_step(axis_name: str = "hvd", scheme: str = "ring",
     elif scheme == "ring-flash":
         inner = functools.partial(
             ring_attention, axis_name=axis_name, causal=causal,
-            use_flash=True,
-            interpret=jax.default_backend() != "tpu",
+            use_flash=True, interpret=interpret,
         )
     elif scheme == "ulysses":
         inner = functools.partial(ulysses_attention, axis_name=axis_name,

@@ -54,15 +54,11 @@ def active() -> bool:
 
 
 def maybe_start_from_env() -> None:
-    """Called by ``hvd.init()``: honor HOROVOD_PROFILER_LOGDIR."""
+    """Called by ``hvd.init()``: honor HOROVOD_PROFILER_LOGDIR. A trace
+    the user asked for by name that cannot start fails ``init()``."""
     logdir = os.environ.get("HOROVOD_PROFILER_LOGDIR", "")
     if logdir:
-        try:
-            start(logdir)
-        except Exception:
-            # Profiler not supported on this backend (e.g. some tunneled
-            # dev setups) — never fail init over observability.
-            pass
+        start(logdir)
 
 
 def summary() -> dict:

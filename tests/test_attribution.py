@@ -289,6 +289,47 @@ class TestWorkerPlane:
             == pytest.approx(1.2, abs=1e-3)
         assert "sentinel" in out and "exposed_comm_residual_s" in out
 
+    def test_unknown_device_kind_is_an_error_not_a_missing_peak(self):
+        assert attribution.peak_flops_for_kind("TPU v5 lite") == 197e12
+        with pytest.raises(ValueError, match="CHIP_PEAK_FLOPS"):
+            attribution.peak_flops_for_kind("TPU v9 imaginary")
+        # A CPU mesh has no chip peak to look up: None, and no error.
+        assert attribution.detect_peak_flops() is None
+
+    def test_unknown_chip_fails_the_declaration_not_the_step_hook(
+            self, monkeypatch):
+        # The per-step hook runs under tracing's "attribution is
+        # advisory" guard, so a peak looked up there would fail quietly
+        # and take the phase gauges and the sentinel with it. The peak is
+        # resolved where the user declares the FLOPs, and only there.
+        import types
+
+        import jax
+
+        def fake_devices(kind):
+            return lambda: [types.SimpleNamespace(platform="tpu",
+                                                  device_kind=kind)] * 4
+
+        monkeypatch.setattr(jax, "local_devices", fake_devices("TPU v5"))
+        with pytest.raises(ValueError, match="'TPU v5'"):
+            attribution.set_model_flops_per_step(1e9)
+        assert attribution.model_flops() == (None, None)
+        self._run_synced_step()  # the plane works on, without MFU
+        assert attribution.summary()["last_step"]["phases"][
+            attribution.PHASE_COMPUTE] == pytest.approx(1.2, abs=1e-3)
+        assert "mfu" not in attribution.summary()["last_step"]
+
+        monkeypatch.setattr(jax, "local_devices",
+                            fake_devices("TPU v5 lite"))
+        attribution.set_model_flops_per_step(1e9)
+        assert attribution.model_flops() == (1e9, 4 * 197e12)
+        # From here on no step looks at the devices again.
+        monkeypatch.setattr(jax, "local_devices", fake_devices("TPU v5"))
+        self._run_synced_step()
+        assert attribution.summary()["last_step"]["mfu"] > 0
+        assert tracing.get_tracer().payload()[
+            "peak_flops_per_rank"] == 4 * 197e12
+
     def test_phase_vocabulary_is_shared(self):
         # Satellite: bench, the elastic step, and attribution must agree
         # on one constant set.

@@ -1,12 +1,10 @@
-"""The bench robustness contract (VERDICT r3 #1): incremental cumulative
-emission, transient-error retry, and the driver-facing record keys. These
-units protect the machinery that made BENCH_r04 green — a regression here
-silently reverts to the all-or-nothing bench that lost round 3's numbers.
+"""The bench failure contract: incremental cumulative emission, a failed
+section recorded instead of destroying the run, and the driver-facing
+record keys. A regression here silently reverts to the all-or-nothing
+bench that loses every number to one late failure.
 """
 
-import io
 import json
-import sys
 
 import bench
 
@@ -36,44 +34,26 @@ class TestEmitter:
         assert rec["value"] == 2724.07
 
 
-class TestRetry:
-    def test_transient_error_retries_once(self, monkeypatch):
-        monkeypatch.setattr(bench.time, "sleep", lambda s: None)
+class TestRunSection:
+    def test_result_passes_through(self):
+        errors = []
+        assert bench._run_section("s", lambda: "ok", errors) == "ok"
+        assert not errors
+
+    def test_failure_is_recorded_once_and_not_retried(self):
         calls = []
 
-        def flaky():
-            calls.append(1)
-            if len(calls) == 1:
-                raise RuntimeError(
-                    "INTERNAL: http://x/remote_compile: read body: "
-                    "response body closed before all bytes were read")
-            return "ok"
-
-        errors = []
-        assert bench._with_retry("s", flaky, errors) == "ok"
-        assert len(calls) == 2 and not errors
-
-    def test_permanent_error_records_and_returns_none(self):
-        errors = []
-        out = bench._with_retry(
-            "s", lambda: (_ for _ in ()).throw(ValueError("shape")), errors)
-        assert out is None
-        assert len(errors) == 1 and "shape" in errors[0]
-
-    def test_no_retry_in_multi_controller_mode(self, monkeypatch):
-        monkeypatch.setattr(bench.time, "sleep", lambda s: None)
-        calls = []
-
-        def flaky():
+        def failing():
             calls.append(1)
             raise RuntimeError("UNAVAILABLE: socket closed")
 
         errors = []
-        assert bench._with_retry("s", flaky, errors,
-                                 allow_retry=False) is None
-        assert len(calls) == 1  # a retrying rank would desert its peers
+        assert bench._run_section("s", failing, errors) is None
+        assert len(calls) == 1
+        assert len(errors) == 1 and "socket closed" in errors[0]
 
-    def test_transient_classification(self):
-        assert bench._is_transient(RuntimeError("read body: closed"))
-        assert bench._is_transient(ConnectionError("Connection reset"))
-        assert not bench._is_transient(ValueError("bad shape"))
+    def test_exit_code_is_zero_only_for_a_clean_run_with_a_headline(self):
+        headline = (0.047, 0.01)
+        assert bench._exit_code(headline, []) == 0
+        assert bench._exit_code(headline, ["bert: ValueError: x"]) == 1
+        assert bench._exit_code(None, []) == 1

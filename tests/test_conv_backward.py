@@ -30,13 +30,13 @@ def test_conv1x1_forward_and_grads_match_autodiff(strides):
     x = jnp.asarray(rng.randn(2, 8, 8, 12).astype(np.float32))
     w = jnp.asarray(rng.randn(1, 1, 12, 20).astype(np.float32) * 0.1)
 
-    out = conv1x1(x, w, strides)
+    out = conv1x1(x, w, strides, True)
     want = _ref_conv(x, w, strides)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
 
     def loss_ours(x, w):
-        return jnp.sum(conv1x1(x, w, strides) ** 2)
+        return jnp.sum(conv1x1(x, w, strides, True) ** 2)
 
     def loss_ref(x, w):
         return jnp.sum(_ref_conv(x, w, strides) ** 2)
@@ -54,5 +54,6 @@ def test_conv1x1_bf16_path():
     x = jnp.asarray(rng.randn(2, 4, 4, 8).astype(np.float32), jnp.bfloat16)
     w = jnp.asarray(rng.randn(1, 1, 8, 16).astype(np.float32) * 0.1,
                     jnp.bfloat16)
-    gw = jax.grad(lambda w: jnp.sum(conv1x1(x, w).astype(jnp.float32)))(w)
+    gw = jax.grad(lambda w: jnp.sum(
+        conv1x1(x, w, (1, 1), True).astype(jnp.float32)))(w)
     assert gw.dtype == jnp.bfloat16 and gw.shape == (1, 1, 8, 16)

@@ -273,6 +273,42 @@ class TestLiveAccounting:
         memory.note_resident("params", 1)
         assert memory.flight_summary()["resident"]["params"] == 1
 
+    def test_device_stats_fold_over_every_local_device(self, monkeypatch):
+        # Everything piled on the SECOND device must read as that
+        # device, not as the first one's idle allocator.
+        monkeypatch.setattr(memory, "_device_stats_dead", False)
+        monkeypatch.setattr(memory, "local_device_memory_stats", lambda: [
+            {"id": 0, "kind": "x", "bytes_in_use": 10,
+             "peak_bytes_in_use": 20, "bytes_limit": 1000},
+            {"id": 1, "kind": "x", "bytes_in_use": 700,
+             "peak_bytes_in_use": 900, "peak_bytes_reserved": 5000,
+             "bytes_limit": 990},
+        ])
+        assert memory.device_memory_stats() == {
+            "bytes_in_use": 700, "peak_bytes_in_use": 900,
+            "peak_bytes_reserved": 5000, "bytes_limit": 990}
+
+    def test_a_backend_whose_stats_call_fails_reads_as_no_stats(
+            self, monkeypatch):
+        # The fold feeds heartbeat payloads and OOM flight records; it
+        # may not be what fails them.
+        def broken():
+            raise RuntimeError("INTERNAL: allocator stats unavailable")
+
+        monkeypatch.setattr(memory, "_device_stats_dead", False)
+        monkeypatch.setattr(memory, "local_device_memory_stats", broken)
+        assert memory.device_memory_stats() is None
+        assert memory.capacity_bytes() is None
+        assert memory.get_observatory().payload()["device"] is None
+
+    def test_cpu_devices_report_no_allocator_stats(self):
+        import jax
+
+        per_device = memory.local_device_memory_stats()
+        assert [d["id"] for d in per_device] == [
+            d.id for d in jax.local_devices()]
+        assert all(set(d) == {"id", "kind"} for d in per_device)
+
 
 # ---------------------------------------------------------------------------
 # Exposure: merge + GET /memory

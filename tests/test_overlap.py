@@ -9,16 +9,14 @@ custom-vjp boundary, so the collective HLOs anchor where their operands
 materialize instead of in one post-backward block. Asserted here:
 
 - the leaf→segment map is stable, contiguous, and covering;
-- the traced program really interleaves segment collectives with backward
-  compute (jaxpr ordering, contrasted against the monolithic path);
+- each segment's collective depends only on its own segment's gradients
+  (data dependence in the jaxpr, contrasted against the monolithic path);
 - numerics match the monolithic DistributedOptimizer path — exactly for
   the f32 wire, within quantization tolerance for the int8 wire over the
   hierarchical (cross, local) mesh;
 - the salted stochastic rounding decorrelates repeated values across
   steps, and a poisoned autotune wrapper refuses to train on.
 """
-
-import re
 
 import jax
 import jax.numpy as jnp
@@ -87,23 +85,50 @@ def _mlp_problem(n_layers=4, dim=8, batch=16):
     return params, (x, y), loss_fn
 
 
-class TestJaxprInterleaving:
-    """The scheduler's whole point, asserted on the traced program: the
-    segment collectives sit BETWEEN backward compute ops, where the
-    monolithic path's single reduction trails every differentiation op."""
+class TestSegmentCollectiveDependence:
+    """The scheduler's whole point, asserted on the traced program's DATA
+    DEPENDENCES: each segment's reduction consumes only that segment's
+    gradient leaves, so it depends on a strict subset of the backward
+    pass and a scheduler is free to start it while the rest of the
+    backward still runs — where the monolithic path's single reduction
+    depends on every gradient matmul.
 
-    def _positions(self, hvd, traced_grads, params, batch):
+    The position of a ``psum`` in the jaxpr TEXT says nothing: all
+    boundaries wrap the parameters before ``loss_fn`` runs, and jax 0.9
+    transposes a linearized program in trace order, so every segment's
+    reduction prints after the last backward ``dot_general``. The order
+    of issue on a device is XLA's scheduler's to decide, from these same
+    dependences; whether it overlaps is read from a device trace."""
+
+    def _collective_ancestors(self, hvd, traced_grads, params, batch):
+        """For the shard_map body of ``traced_grads``: one entry per
+        ``psum`` holding the positions of the equations it transitively
+        depends on, plus the positions of every ``dot_general``."""
         mesh = hvd.global_mesh()
         sm = jax.shard_map(
             traced_grads, mesh=mesh, in_specs=(P(), P("hvd")),
             out_specs=P(), check_vma=False)
-        txt = str(jax.make_jaxpr(sm)(params, batch))
-        colls = [m.start() for m in re.finditer(r"\bpsum", txt)]
-        dots = [m.start() for m in re.finditer(r"\bdot_general", txt)]
+        outer = jax.make_jaxpr(sm)(params, batch).jaxpr
+        (body,) = [e.params["jaxpr"] for e in outer.eqns
+                   if e.primitive.name == "shard_map"]
+        producer, ancestors = {}, []
+        for i, eqn in enumerate(body.eqns):
+            deps = set()
+            for v in eqn.invars:
+                j = producer.get(id(v))
+                if j is not None:
+                    deps |= ancestors[j] | {j}
+            ancestors.append(deps)
+            for v in eqn.outvars:
+                producer[id(v)] = i
+        names = [e.primitive.name for e in body.eqns]
+        colls = [ancestors[i] | {i} for i, nm in enumerate(names)
+                 if nm == "psum"]
+        dots = {i for i, nm in enumerate(names) if nm == "dot_general"}
         assert colls and dots
         return colls, dots
 
-    def test_segment_collectives_interleave_with_backward(self, hvd):
+    def test_segment_collectives_depend_on_their_segment_only(self, hvd):
         params, batch, loss_fn = _mlp_problem()
         spec = hvd.reduce_spec_of(hvd.DistributedOptimizer(optax.sgd(0.1)))
         k = 3
@@ -115,16 +140,24 @@ class TestJaxprInterleaving:
 
             return jax.grad(loss_of)(p)
 
-        colls, dots = self._positions(hvd, overlapped, params, batch)
+        colls, dots = self._collective_ancestors(
+            hvd, overlapped, params, batch)
         # One collective per segment...
         assert len(colls) == k
-        # ...and they are interleaved: the first reduction is issued
-        # before the last backward matmul, not after the full backward.
-        assert colls[0] < dots[-1]
+        # ...none waiting for another...
+        psums = {max(c) for c in colls}
+        assert all(not (c - {max(c)}) & psums for c in colls)
+        # ...and each needing only part of the backward pass (no other
+        # segment's weight-gradient matmuls), the last layers' segment
+        # least of all: it can start while the rest still differentiates.
+        assert all(not dots <= c for c in colls)
+        needed = [len(c & dots) for c in colls]
+        assert min(needed) < max(needed)
 
-    def test_monolithic_collectives_trail_backward(self, hvd):
-        # The contrast that makes the interleaving assertion meaningful:
-        # the post-backward path's reduction comes after EVERY matmul.
+    def test_monolithic_collective_depends_on_the_whole_backward(self, hvd):
+        # The contrast that makes the assertion above meaningful: the
+        # post-backward path's reduction waits for every gradient matmul
+        # (the weight gradients directly, the rest through the chain).
         params, batch, loss_fn = _mlp_problem()
         spec = hvd.reduce_spec_of(hvd.DistributedOptimizer(optax.sgd(0.1)))
 
@@ -137,8 +170,10 @@ class TestJaxprInterleaving:
                 spec.postscale_factor, spec.fusion_threshold_bytes,
                 spec.num_groups, world_size=_known_size(spec.process_set))
 
-        colls, dots = self._positions(hvd, monolithic, params, batch)
-        assert colls[0] > dots[-1]
+        colls, dots = self._collective_ancestors(
+            hvd, monolithic, params, batch)
+        assert len(colls) == 1
+        assert dots <= colls[0]
 
 
 class TestOverlapEquivalence:

@@ -226,7 +226,8 @@ class TestRingFlashAttention:
         B, H, S, D = 1, 2, 16 * n, 32
         q, k, v = make_qkv(B=B, H=H, S=S, D=D)
         want = dense_attention(q, k, v, causal)
-        fn = sp.make_sp_attention_step(scheme="ring-flash", causal=causal)
+        fn = sp.make_sp_attention_step(scheme="ring-flash", causal=causal,
+                                       interpret=True)
         got = fn(q, k, v)
         np.testing.assert_allclose(
             np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
@@ -235,7 +236,8 @@ class TestRingFlashAttention:
     def test_backward_matches_dense(self, hvd):
         n = hvd.size()
         q, k, v = make_qkv(B=1, H=1, S=16 * n, D=16)
-        fn = sp.make_sp_attention_step(scheme="ring-flash", causal=True)
+        fn = sp.make_sp_attention_step(scheme="ring-flash", causal=True,
+                                       interpret=True)
 
         def loss_flash(q, k, v):
             return jnp.sum(fn(q, k, v).astype(jnp.float32) ** 2)

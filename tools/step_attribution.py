@@ -21,8 +21,7 @@ import sys
 def main() -> int:
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
-    import jax
-
+    import horovod_tpu as hvd
     from tools.resnet_step import TRACE_STEPS, build_step
 
     traces = sorted(glob.glob(
@@ -32,9 +31,7 @@ def main() -> int:
               "tools/step_op_profile.py first")
         return 1
 
-    cache = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), ".jax_cache")
-    jax.config.update("jax_compilation_cache_dir", cache)
+    hvd.enable_compile_cache()
 
     step, args = build_step()
     hlo = step.lower(*args).compile().as_text()

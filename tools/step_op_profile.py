@@ -24,11 +24,10 @@ def main() -> int:
         os.path.abspath(__file__))))
     import jax
 
+    import horovod_tpu as hvd
     from tools.resnet_step import TRACE_STEPS, build_step
 
-    cache = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), ".jax_cache")
-    jax.config.update("jax_compilation_cache_dir", cache)
+    hvd.enable_compile_cache()
 
     step, (p_, s_, o_, batch) = build_step()
     for _ in range(4):
