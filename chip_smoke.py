@@ -107,7 +107,6 @@ def run(sync_mode: str = "allreduce") -> dict:
             f"({len(jax.devices())} x {jax.devices()[0].device_kind!r}). "
             "Not running on anything else.")
 
-    import jax.monitoring
     import jaxlib
     import numpy as np
 
@@ -127,20 +126,8 @@ def run(sync_mode: str = "allreduce") -> dict:
     print(f"peak: {peak_flops_for_kind(device['kind']) / 1e12:.0f} "
           "TFLOP/s bf16 per chip (attribution.CHIP_PEAK_FLOPS)")
 
-    cache_events = {"hits": 0, "misses": 0, "backend_compile_s": 0.0}
-
-    def on_event(event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            cache_events["hits"] += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            cache_events["misses"] += 1
-
-    def on_duration(event, seconds, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            cache_events["backend_compile_s"] += seconds
-
-    jax.monitoring.register_event_listener(on_event)
-    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    # From here on the program keeps JAX's account of compiling
+    # (hvd.cache_stats()["compile"]), the step's first call by itself.
     print(f"compile cache: {hvd.enable_compile_cache()}")
 
     hvd.init()
@@ -192,9 +179,9 @@ def run(sync_mode: str = "allreduce") -> dict:
         seconds = time.perf_counter() - t0
         return params, opt_state, float(loss), seconds
 
-    before = dict(cache_events)
     params, opt_state, loss, first_step_s = one_step(params, opt_state)
-    compile_events = {k: cache_events[k] - before[k] for k in before}
+    compile_events = hvd.cache_stats()["compile"]["steps"]["train_step"][
+        "first_call"]
     losses, step_s = [loss], []
     for _ in range(TIMED_STEPS):
         params, opt_state, loss, seconds = one_step(params, opt_state)
@@ -265,11 +252,14 @@ def run(sync_mode: str = "allreduce") -> dict:
     print(f"hbm predicted (memory.footprint_of): resident "
           f"{predicted['resident_total'] / gib:.2f} GiB per device")
 
-    cache_hit = (compile_events["hits"] > 0
-                 and compile_events["misses"] == 0)
-    print(f"first step: {first_step_s:.1f} s, of which backend compile "
+    cache_hit = (compile_events["cache_hits"] > 0
+                 and compile_events["cache_misses"] == 0)
+    print(f"first step: {first_step_s:.1f} s, of which tracing "
+          f"{compile_events['trace_s']:.1f} s, lowering "
+          f"{compile_events['lower_s']:.1f} s, backend compile "
           f"{compile_events['backend_compile_s']:.1f} s; persistent cache "
-          f"hits={compile_events['hits']} misses={compile_events['misses']} "
+          f"hits={compile_events['cache_hits']} "
+          f"misses={compile_events['cache_misses']} "
           f"-> {'served from the cache' if cache_hit else 'compiled'}")
     print(f"steps: median {statistics.median(step_s) * 1e3:.1f} ms over "
           f"{TIMED_STEPS} (min {min(step_s) * 1e3:.1f}, max "

@@ -80,12 +80,44 @@ SPAN_OPTIMIZER_UPDATE = "optimizer_update"
 PHASE_SPAN_NAMES = (SPAN_FORWARD_BACKWARD, SPAN_COLLECTIVE,
                     SPAN_OPTIMIZER_UPDATE)
 
+#: Host spans every factory step opens, on the ring and, as
+#: ``jax.profiler.TraceAnnotation``s, on the profiler's clock
+#: (``data_parallel._StallWatchedStep``). ``hvd.step`` is the whole
+#: wrapper call; its self time is what the hooks cost.
+SPAN_STEP = "hvd.step"
+SPAN_STEP_DISPATCH = "hvd.step.dispatch"  # self._fn(...): autotune + jit
+SPAN_STEP_DRAIN = "hvd.step.drain"  # block_until_ready, watched/sampled
+STEP_SPAN_NAMES = (SPAN_STEP, SPAN_STEP_DISPATCH, SPAN_STEP_DRAIN)
+#: ``cause`` argument of :data:`SPAN_STEP_DRAIN`.
+DRAIN_STALL_WATCH = "stall_watch"
+DRAIN_TRACE_SAMPLE = "trace_sample"
+
+#: Phase scopes inside the compiled step (``jax.named_scope`` through
+#: ``profiler.annotate_collective``, which prepends :data:`SCOPE_PREFIX`):
+#: they reach the compiled step's ``metadata={op_name=...}`` and from
+#: there a device trace. Collective scopes that predate them
+#: (``allreduce.bucket<i>.<n>B``, ``grad_reducescatter``, ...) nest inside
+#: ``wire``.
+SCOPE_PREFIX = "hvd."
+SCOPE_WIRE = "wire"
+SCOPE_WIRE_COMPRESS = "wire.compress"
+SCOPE_WIRE_UNPACK = "wire.unpack"
+SCOPE_WIRE_DECOMPRESS = "wire.decompress"
+SCOPE_OPTIMIZER = "optimizer"
+SCOPE_ATTN_FWD = "attn.fwd"
+SCOPE_ATTN_BWD = "attn.bwd"
+PHASE_SCOPE_NAMES = tuple(SCOPE_PREFIX + name for name in (
+    SCOPE_WIRE, SCOPE_OPTIMIZER, SCOPE_ATTN_FWD, SCOPE_ATTN_BWD))
+
 #: Span categories. ``phase``-cat spans are host-observable compute
 #: segments; ``collective``-cat spans are communication; the ``step``
-#: span is the envelope the tracer inserts at step end.
+#: span is the envelope the tracer inserts at step end; ``host``-cat
+#: spans are the step wrapper's own parts (neither compute nor wire, so
+#: they stay in the decomposition's overhead).
 CAT_PHASE = "phase"
 CAT_COLLECTIVE = "collective"
 CAT_STEP = "step"
+CAT_HOST = "host"
 COMPUTE_CATS = (CAT_PHASE,)
 COMM_CATS = (CAT_COLLECTIVE,)
 

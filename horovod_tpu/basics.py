@@ -180,9 +180,26 @@ def enable_compile_cache() -> str:
     Otherwise the cache lives at ``<checkout>/.jax_cache`` — a fixed
     path, because the path is part of the cache's key and a directory
     that moves never hits. Call before the first compilation.
+
+    The cache's key leaves a program's metadata out (JAX's default), so
+    an executable that a tree with other named scopes cached (an older
+    checkout sharing the directory) is served with THAT tree's scopes in
+    its text and in every profile of it; ``profiler.step_scopes`` then
+    fails saying so. Putting the metadata into the key
+    (``jax_compilation_cache_include_metadata_in_key``) cures it, at a
+    price measured on the v5e (PERF.md, PR 23): the key then holds the
+    callers' file names and line numbers, and the same tree started
+    through another script, or after an edit that moves a line, compiles
+    everything again. That is for a profiling session to set, not for
+    every start.
     """
     import jax
 
+    from . import profiler
+
+    # What the cache saves is read off the compile account
+    # (``hvd.cache_stats()["compile"]``), so it listens from here on.
+    profiler.compile_account().listen()
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         jax.config.update("jax_compilation_cache_dir",
@@ -225,6 +242,7 @@ def init(devices: Sequence[Any] | None = None) -> None:
         from . import profiler
 
         profiler.maybe_start_from_env()
+        profiler.compile_account().listen()
         get_logger().info(
             "horovod_tpu initialized: %d rank(s), %d host(s), backend=%s",
             topo.size,

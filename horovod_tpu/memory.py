@@ -66,8 +66,12 @@ from .utils.env import get_int
 #: ``hvd_hbm_bytes``). ``params``/``opt_state`` are the model kinds the
 #: footprint model prices; the rest are framework overheads measured
 #: live only.
+#: What the loaded programs reserve for their temporaries: no call site
+#: notes it, the backend counts it (``bytes_reserved``).
+PROGRAM_TEMPORARIES = "program_temporaries"
+
 KINDS = ("params", "opt_state", "grads", "peer_pool", "executables",
-         "serving", "other")
+         "serving", "other", PROGRAM_TEMPORARIES)
 
 #: The model kinds — the subset :func:`predict_footprint` prices, and
 #: the subset the residual (predicted − measured) gauge compares.
@@ -135,8 +139,10 @@ def capacity_bytes() -> int | None:
 # executable is loaded, not only while it runs (v5e, BERT-Large sharded
 # step, read after the steps returned: 10.87 GiB beside 2.71 GiB of
 # buffers on every device, ``bytes_limit - bytes_in_use - bytes_reserved
-# == largest_free_block_bytes``; 0 once the step is dropped; PERF.md). The watermarks
-# below read ``bytes_in_use`` alone and do not see it: PERF.md section 7.
+# == largest_free_block_bytes``; 0 once the step is dropped; PERF.md). So
+# what a chip holds is the two together (:func:`device_taken_bytes`):
+# the watermarks fold that, and ``bytes_reserved`` is the observatory's
+# ``program_temporaries`` kind.
 _DEVICE_STAT_KEYS = ("bytes_in_use", "bytes_limit", "peak_bytes_in_use",
                      "bytes_reserved", "peak_bytes_reserved",
                      "largest_free_block_bytes")
@@ -159,6 +165,21 @@ def local_device_memory_stats() -> list[dict]:
     ]
 
 
+def _local_stats_or_none() -> list[dict] | None:
+    """:func:`local_device_memory_stats`, or None where it cannot be
+    asked (no jax: cached) or fails (not cached: it may recover)."""
+    global _device_stats_dead
+    if _device_stats_dead:
+        return None
+    try:
+        return local_device_memory_stats()
+    except ImportError:
+        _device_stats_dead = True  # driver-side: jax never appears
+        return None
+    except Exception:  # noqa: BLE001 — a backend whose stats call fails
+        return None
+
+
 def device_memory_stats() -> dict | None:
     """The per-device memory view this process is bound by
     (``bytes_in_use`` / ``bytes_limit`` / ``peak_bytes_in_use`` where
@@ -172,14 +193,8 @@ def device_memory_stats() -> dict | None:
     else is already going wrong (:func:`local_device_memory_stats` is
     the one that raises)."""
     global _device_stats_dead
-    if _device_stats_dead:
-        return None
-    try:
-        per_device = local_device_memory_stats()
-    except ImportError:
-        _device_stats_dead = True  # driver-side: jax never appears
-        return None
-    except Exception:  # noqa: BLE001 — a backend whose stats call fails
+    per_device = _local_stats_or_none()
+    if per_device is None:
         return None
     folded: dict[str, int] = {}
     for k in _DEVICE_STAT_KEYS:
@@ -191,6 +206,24 @@ def device_memory_stats() -> dict | None:
         _device_stats_dead = True
         return None
     return folded
+
+
+def device_taken_bytes() -> tuple[int, int] | None:
+    """``(now, at peak)`` of what the fullest local device holds: buffers
+    plus the loaded programs' temporaries, ``bytes_in_use +
+    bytes_reserved`` and the sum of the two peaks (an upper bound: the
+    backend keeps no peak of the sum). None where the backend reports
+    nothing; never raises."""
+    per_device = _local_stats_or_none()
+    if per_device is None:
+        return None
+    taken = [(d["bytes_in_use"] + d.get("bytes_reserved", 0),
+              d.get("peak_bytes_in_use", 0)
+              + d.get("peak_bytes_reserved", 0))
+             for d in per_device if "bytes_in_use" in d]
+    if not taken:
+        return None
+    return max(now for now, _ in taken), max(peak for _, peak in taken)
 
 
 # ---------------------------------------------------------------------------
@@ -607,8 +640,9 @@ class MemoryObservatory:
     # -- measurement ----------------------------------------------------------
 
     def measured_resident(self) -> dict[str, int]:
-        """Per-kind resident bytes: the noted cells plus one guarded
-        poll of every registered supplier."""
+        """Per-kind resident bytes: the noted cells, one guarded poll of
+        every registered supplier, and — where the backend counts them —
+        the loaded programs' temporaries on the fullest device."""
         with self._lock:
             out = dict(self._resident)
             suppliers = dict(self._suppliers)
@@ -619,6 +653,9 @@ class MemoryObservatory:
                     out[kind] = nbytes
             except Exception:  # noqa: BLE001 — a dead supplier must
                 pass  # not break the measurement
+        stats = device_memory_stats()
+        if stats and "bytes_reserved" in stats:
+            out[PROGRAM_TEMPORARIES] = int(stats["bytes_reserved"])
         return out
 
     def resident_total(self) -> int:
@@ -653,21 +690,26 @@ class MemoryObservatory:
         return max(0.0, min(1.0, 1.0 - self.resident_total() / float(cap)))
 
     def note_phase(self, name: str, cat: str | None = None) -> None:
-        """Watermark hook, called by ``tracing.span.__exit__`` on every
-        span close: fold the current resident total (and the device
-        allocator's in-use bytes where available) into the span's
-        phase watermark. A new process-lifetime peak ≥5% above the
-        last journaled one emits an ``hbm_watermark`` journal event
-        (latched — growth bursts journal once, steady state never).
-        Never raises."""
+        """Watermark hook, called where the chip holds what a step
+        holds — the close of a SYNCED step scope
+        (``tracing.StepTracer._end_step``; a factory step syncs every
+        50th call) — and by ``tracing.span.__exit__`` for spans outside
+        any step: fold what is resident into the phase's watermark, the
+        larger of the noted total and what the backend says the fullest
+        device holds, buffers plus the loaded programs' temporaries (now
+        and at peak; :func:`device_taken_bytes`). A new process-lifetime
+        peak ≥5% above the last journaled one emits an ``hbm_watermark``
+        journal event (latched — growth bursts journal once, steady
+        state never). It asks every local device, so it is never called
+        on an un-synced step. Never raises."""
         try:
             phase = str(name) if str(name) in PHASES else (
                 "collective" if cat == "collective" else
                 "step" if cat == "step" else "other")
             total = self.resident_total()
-            stats = device_memory_stats()
-            if stats:
-                total = max(total, int(stats.get("bytes_in_use", 0)))
+            taken = device_taken_bytes()
+            if taken:
+                total = max(total, *taken)
             journal = False
             with self._lock:
                 self._phase_notes += 1

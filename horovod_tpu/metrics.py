@@ -623,6 +623,19 @@ GRAD_SYNC_BUCKETS = histogram(
     "hvd_grad_sync_buckets",
     "Fusion buckets per traced gradient flush.",
     ("sync_mode",), COUNT_BUCKETS)
+GRAD_SYNC_LAST_BYTES = gauge(
+    "hvd_grad_sync_last_bytes",
+    "Wire bytes (post-compression view) of the LAST traced gradient "
+    "flush: what the step that runs puts on the wire, where a step "
+    "traces one flush.", ("sync_mode",))
+GRAD_SYNC_LAST_BUCKETS = gauge(
+    "hvd_grad_sync_last_buckets",
+    "Fusion buckets of the last traced gradient flush.", ("sync_mode",))
+STEP_RECOMPILES = counter(
+    "hvd_step_recompiles_total",
+    "Calls of a factory step, after its first, in which a program was "
+    "compiled (the journal's step_recompiled event names the call).",
+    ("step",))
 OVERLAP_SEGMENTS = gauge(
     "hvd_overlap_segments",
     "Segments in the last overlap-scheduler leaf map.")
@@ -702,16 +715,19 @@ RESIDENT_BYTES = gauge(
 HBM_BYTES = gauge(
     "hvd_hbm_bytes",
     "Per-rank resident device-memory bytes by kind (params|opt_state|"
-    "grads|peer_pool|executables|serving|other) — the memory "
-    "observatory's live accounting (horovod_tpu/memory.py): exact "
-    "nbytes noted by the call sites that materialize each kind, plus "
-    "polled suppliers (replica pool, executable cache).", ("kind",))
+    "grads|peer_pool|executables|serving|other|program_temporaries) — "
+    "the memory observatory's live accounting (horovod_tpu/memory.py): "
+    "exact nbytes noted by the call sites that materialize each kind, "
+    "polled suppliers (replica pool, executable cache), and the "
+    "backend's own count of what the loaded programs reserve for their "
+    "temporaries.", ("kind",))
 HBM_WATERMARK = gauge(
     "hvd_hbm_watermark_bytes",
-    "Peak resident bytes observed at span exits of each step phase "
-    "(step|forward_backward|collective|optimizer_update|other) — the "
-    "memory observatory's per-phase high-water marks, folded in by the "
-    "tracing plane.", ("phase",))
+    "Peak bytes held, buffers plus the loaded programs' temporaries, "
+    "observed at the close of synced steps and of spans outside any "
+    "step, by phase (step|forward_backward|collective|optimizer_update|"
+    "other) — the memory observatory's high-water marks, folded in by "
+    "the tracing plane.", ("phase",))
 HBM_HEADROOM = gauge(
     "hvd_hbm_headroom_ratio",
     "1 - resident_total/capacity, clamped to [0,1]. Capacity comes from "
@@ -990,7 +1006,7 @@ def _materialize_checkpoint_cells() -> None:
     # premerge scrape gate can assert the instruments exist and
     # dashboards can tell "nothing resident yet" from "not measuring".
     for kind in ("params", "opt_state", "grads", "peer_pool",
-                 "executables", "serving", "other"):
+                 "executables", "serving", "other", "program_temporaries"):
         HBM_BYTES.labels(kind=kind)
     for phase in ("step", "forward_backward", "collective",
                   "optimizer_update", "other"):

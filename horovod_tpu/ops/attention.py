@@ -35,6 +35,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..attribution import SCOPE_ATTN_BWD, SCOPE_ATTN_FWD
+
+# Every ``pallas_call`` here carries this one name. XLA names a custom
+# call's instruction after the innermost component of its name stack, a
+# device trace names the event after the instruction, and the benchmark
+# finds the kernels there as ``flash_attention.<n>``: until now only
+# because they sat right under ``jit(flash_attention)``. Which of them is
+# a forward and which a backward kernel is the scope one level up
+# (``hvd.attn.fwd`` / ``hvd.attn.bwd``), read from the step's text.
+KERNEL_NAME = "flash_attention"
+
 NEG_INF = -1e30
 # logsumexp sentinel for fully-masked rows: exp(s - BIG) == 0 for any
 # representable s, so backward P/dq come out exactly 0 for those rows.
@@ -393,6 +404,15 @@ def _flash_dqkv_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 def _fwd_call(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
               interpret):
+    from ..profiler import annotate_collective
+
+    with annotate_collective(SCOPE_ATTN_FWD):
+        return _fwd_kernels(qr, kr, vr, causal, block_q, block_k, q_offset,
+                            k_offset, interpret)
+
+
+def _fwd_kernels(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
+                 interpret):
     BH, Sq, D = qr.shape
     Sk = kr.shape[1]
     scale = 1.0 / (D ** 0.5)
@@ -419,6 +439,7 @@ def _fwd_call(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
                 jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32),
             ],
             interpret=interpret,
+            name=KERNEL_NAME,
         )(qr, kr, vr)
     kernel = functools.partial(
         _flash_fwd_kernel, causal=causal, scale=scale,
@@ -447,11 +468,21 @@ def _fwd_call(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
             pltpu.VMEM((block_q, D), jnp.float32),  # fp32 accumulator
         ],
         interpret=interpret,
+        name=KERNEL_NAME,
     )(qr, kr, vr)
 
 
 def _flash_bwd(causal, block_q, block_k, q_offset, k_offset, interpret,
                res, g, g_lse=None):
+    from ..profiler import annotate_collective
+
+    with annotate_collective(SCOPE_ATTN_BWD):
+        return _bwd_kernels(causal, block_q, block_k, q_offset, k_offset,
+                            interpret, res, g, g_lse)
+
+
+def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
+                 res, g, g_lse):
     qr, kr, vr, out, lse = res
     BH, Sq, D = qr.shape
     Sk = kr.shape[1]
@@ -498,6 +529,7 @@ def _flash_bwd(causal, block_q, block_k, q_offset, k_offset, interpret,
                 jax.ShapeDtypeStruct((BH, Sk, D), vr.dtype),
             ],
             interpret=interpret,
+            name=KERNEL_NAME,
         )(qr, kr, vr, do, lse, delta, g_lse)
         return dq, dk, dv
 
@@ -521,6 +553,7 @@ def _flash_bwd(causal, block_q, block_k, q_offset, k_offset, interpret,
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), qr.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name=KERNEL_NAME,
     )(qr, kr, vr, do, lse, delta, g_lse)
 
     kv_specs = [
@@ -552,6 +585,7 @@ def _flash_bwd(causal, block_q, block_k, q_offset, k_offset, interpret,
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
+        name=KERNEL_NAME,
     )(qr, kr, vr, do, lse, delta, g_lse)
     return dq, dk, dv
 

@@ -55,7 +55,20 @@ def cache_stats(reset: bool = False) -> dict:
 
         {"executable_cache": {"hits", "misses", "size", "capacity",
                               "bytes"},
-         "eager_dispatch": {kind: count, ...}}
+         "eager_dispatch": {kind: count, ...},
+         "compile": {"trace_s", "lower_s", "backend_compile_s",
+                     "cache_hits", "cache_misses", "programs",
+                     "listening", "steps": {step: {"first_call",
+                                                   "recompiles"}}}}
+
+    ``compile`` is JAX's own account (``jax.monitoring``) of every
+    program this process traced, lowered and compiled since
+    ``hvd.init()`` or ``hvd.enable_compile_cache()``, whichever ran
+    first (``profiler.CompileAccount``): seconds of jaxpr tracing, of
+    lowering to MLIR and of backend compile (on a persistent-cache hit:
+    of loading the executable), the persistent cache's hits and misses,
+    and per factory step the share of its first call and the number of
+    recompiles. ``reset`` leaves it alone.
 
     ``bytes`` is the cache's noted memory cost — the sum of each resident
     entry's serialized-program size, recorded by the dispatch path on the
@@ -71,6 +84,8 @@ def cache_stats(reset: bool = False) -> dict:
     use it so counters do not leak across phases. The cluster metrics
     registry resets separately via ``metrics.reset_for_testing()``.
     """
+    from .. import profiler
+
     cache = global_cache()
     stats = {
         "executable_cache": {
@@ -81,6 +96,7 @@ def cache_stats(reset: bool = False) -> dict:
             "bytes": cache.nbytes(),
         },
         "eager_dispatch": dict(_dispatch_counts),
+        "compile": profiler.compile_account().summary(),
     }
     if reset:
         _dispatch_counts.clear()

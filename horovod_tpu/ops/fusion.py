@@ -317,6 +317,21 @@ def _reduce_bucket_planned(flat, op, axis_name, prescale_factor,
         postscale_factor)
 
 
+def _unpack_bucket(reduced, bucket, tensors, out) -> None:
+    """Cut a reduced bucket back into its leaves (``out[i]`` for ``i`` in
+    ``bucket``), under the wire's unpack scope: the slices and reshapes
+    are device work of their own once a bucket holds tens of MB."""
+    from ..attribution import SCOPE_WIRE_UNPACK
+    from ..profiler import annotate_collective
+
+    with annotate_collective(SCOPE_WIRE_UNPACK):
+        offset = 0
+        for i in bucket:
+            n = tensors[i].size
+            out[i] = reduced[offset:offset + n].reshape(tensors[i].shape)
+            offset += n
+
+
 def fused_allreduce(
     tensors: Sequence[Any],
     op,
@@ -377,12 +392,7 @@ def fused_allreduce(
                 reduced = _reduce_bucket_planned(
                     packed, op, axis_name, prescale_factor,
                     postscale_factor, plan)
-            offset = 0
-            for i in bucket:
-                n = tensors[i].size
-                out[i] = reduced[offset:offset + n].reshape(
-                    tensors[i].shape)
-                offset += n
+            _unpack_bucket(reduced, bucket, tensors, out)
             continue
         if len(bucket) == 1:
             i = bucket[0]
@@ -398,11 +408,7 @@ def fused_allreduce(
             reduced = _reduce_bucket(
                 packed, op, axis_name, prescale_factor, postscale_factor
             )
-        offset = 0
-        for i in bucket:
-            n = tensors[i].size
-            out[i] = reduced[offset : offset + n].reshape(tensors[i].shape)
-            offset += n
+        _unpack_bucket(reduced, bucket, tensors, out)
     return out
 
 
@@ -526,6 +532,7 @@ def fused_reducescatter(
     """
     from jax import lax
 
+    from ..attribution import SCOPE_WIRE_UNPACK
     from ..profiler import annotate_collective
     from .collective_ops import Average, Sum
 
@@ -561,8 +568,10 @@ def fused_reducescatter(
                     flat, axis_name, scatter_dimension=0, tiled=True)
             if scale != 1.0:
                 row = row * jnp.asarray(scale, row.dtype)
-        for i, shard in zip(bucket, _split_shard_row(row, bucket_sizes)):
-            out[i] = shard
+        with annotate_collective(SCOPE_WIRE_UNPACK):
+            for i, shard in zip(bucket,
+                                _split_shard_row(row, bucket_sizes)):
+                out[i] = shard
     return out
 
 
@@ -585,6 +594,7 @@ def fused_allgather_shards(
     """
     from jax import lax
 
+    from ..attribution import SCOPE_WIRE_UNPACK
     from ..profiler import annotate_collective
 
     n = int(world_size)
@@ -610,13 +620,14 @@ def fused_allgather_shards(
                     plan, row, axis_name)
             else:
                 full = lax.all_gather(row, axis_name, axis=0, tiled=True)
-        grid = full.reshape(n, -1)
-        offset = 0
-        for i, s in zip(bucket, bucket_sizes):
-            t = templates[i]
-            out[i] = (grid[:, offset:offset + s]
-                      .reshape(-1)[: int(t.size)].reshape(t.shape))
-            offset += s
+        with annotate_collective(SCOPE_WIRE_UNPACK):
+            grid = full.reshape(n, -1)
+            offset = 0
+            for i, s in zip(bucket, bucket_sizes):
+                t = templates[i]
+                out[i] = (grid[:, offset:offset + s]
+                          .reshape(-1)[: int(t.size)].reshape(t.shape))
+                offset += s
     return out
 
 

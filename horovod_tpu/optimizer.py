@@ -34,6 +34,12 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from .attribution import (
+    SCOPE_OPTIMIZER,
+    SCOPE_WIRE,
+    SCOPE_WIRE_COMPRESS,
+    SCOPE_WIRE_DECOMPRESS,
+)
 from .compression import Compression
 from .exceptions import SyncModeIneligibleError
 from .ops import collective_ops
@@ -98,8 +104,37 @@ def _record_flush(sync_mode: str, wire_leaves, threshold_bytes,
         metrics.GRAD_SYNC_FLUSHES.inc(sync_mode=sync_mode)
         metrics.GRAD_SYNC_BYTES.observe(nbytes, sync_mode=sync_mode)
         metrics.GRAD_SYNC_BUCKETS.observe(nbuckets, sync_mode=sync_mode)
+        metrics.GRAD_SYNC_LAST_BYTES.set(nbytes, sync_mode=sync_mode)
+        metrics.GRAD_SYNC_LAST_BUCKETS.set(nbuckets, sync_mode=sync_mode)
     except Exception:  # noqa: BLE001 — instrumentation is best-effort
         pass
+
+
+def _compress_leaves(compression, leaves):
+    """``(wire tensors, contexts)`` of the leaves, the casts under their
+    own scope inside the wire's."""
+    from .profiler import annotate_collective
+
+    with annotate_collective(SCOPE_WIRE_COMPRESS):
+        compressed = [compression.compress(g) for g in leaves]
+    return [c[0] for c in compressed], [c[1] for c in compressed]
+
+
+def _decompress_leaves(compression, reduced, ctxs):
+    from .profiler import annotate_collective
+
+    with annotate_collective(SCOPE_WIRE_DECOMPRESS):
+        return [compression.decompress(r, ctx)
+                for r, ctx in zip(reduced, ctxs)]
+
+
+def _inner_update(inner, grads, state, params):
+    """The wrapped optimizer's own ``update``, under the compiled step's
+    optimizer scope."""
+    from .profiler import annotate_collective
+
+    with annotate_collective(SCOPE_OPTIMIZER):
+        return inner.update(grads, state, params)
 
 
 def _reduce_grads(
@@ -145,61 +180,66 @@ def _reduce_grads(
             return grads
         return jax.tree.map(lambda g: g * jnp.asarray(scale, g.dtype), grads)
 
-    if getattr(compression, "marker", None) == "int8":
-        # Int8 changes the exchange, not just the wire dtype (summing
-        # int8 on the wire overflows): quantized all_to_all +
-        # dequant-sum + requant + all_gather, bucketed like the fused
-        # path. Needs the axis size as a static int for chunk shapes.
-        from .ops.quantization import int8_fused_allreduce
+    from .profiler import annotate_collective
 
-        if op not in (collective_ops.Average, collective_ops.Sum):
-            raise ValueError(
-                f"Compression.int8 supports op=Average/Sum, got {op!r}")
-        if world_size is None:
-            raise ValueError(
-                "Compression.int8 needs a known process-set size at "
-                "trace time (init() first)")
+    # Everything past the short-circuit is the wire: one phase scope in
+    # the compiled step, with the casts, each bucket's pack + collective
+    # (``ops.fusion``) and the unpacking as its children.
+    with annotate_collective(SCOPE_WIRE):
+        if getattr(compression, "marker", None) == "int8":
+            # Int8 changes the exchange, not just the wire dtype (summing
+            # int8 on the wire overflows): quantized all_to_all +
+            # dequant-sum + requant + all_gather, bucketed like the fused
+            # path. Needs the axis size as a static int for chunk shapes.
+            from .ops.quantization import int8_fused_allreduce
+
+            if op not in (collective_ops.Average, collective_ops.Sum):
+                raise ValueError(
+                    f"Compression.int8 supports op=Average/Sum, got {op!r}")
+            if world_size is None:
+                raise ValueError(
+                    "Compression.int8 needs a known process-set size at "
+                    "trace time (init() first)")
+            leaves, treedef = jax.tree.flatten(grads)
+            if num_groups and num_groups > 0:
+                # Same num_groups contract as the cast path: cap buckets
+                # at total/num_groups bytes (sized on the f32 exchange
+                # view).
+                total = sum(int(jnp.asarray(g).size) * 4 for g in leaves)
+                threshold_bytes = max(1, total // num_groups)
+            # Bucketing rides the f32 exchange view; the wire is int8.
+            _record_flush("allreduce", leaves, threshold_bytes,
+                          itemsize_override=1)
+            reduced = int8_fused_allreduce(
+                leaves, axis_name, world_size, op=op,
+                threshold_bytes=threshold_bytes,
+                prescale_factor=prescale_factor,
+                postscale_factor=postscale_factor,
+                salt=quant_salt, issue_reversed=issue_reversed)
+            return jax.tree.unflatten(treedef, reduced)
+
         leaves, treedef = jax.tree.flatten(grads)
+        wire, ctxs = _compress_leaves(compression, leaves)
         if num_groups and num_groups > 0:
-            # Same num_groups contract as the cast path: cap buckets at
-            # total/num_groups bytes (sized on the f32 exchange view).
-            total = sum(int(jnp.asarray(g).size) * 4 for g in leaves)
+            # Reference's num_groups: split tensors into N groups, fuse
+            # within each. Emulate by capping each bucket at
+            # total/num_groups bytes.
+            total = sum(int(w.size) * jnp.dtype(w.dtype).itemsize
+                        for w in wire)
             threshold_bytes = max(1, total // num_groups)
-        # Bucketing rides the f32 exchange view; the wire itself is int8.
-        _record_flush("allreduce", leaves, threshold_bytes,
-                      itemsize_override=1)
-        reduced = int8_fused_allreduce(
-            leaves, axis_name, world_size, op=op,
+        _record_flush("allreduce", wire, threshold_bytes)
+        reduced = fused_allreduce(
+            wire,
+            op=op,
+            axis_name=axis_name,
             threshold_bytes=threshold_bytes,
             prescale_factor=prescale_factor,
             postscale_factor=postscale_factor,
-            salt=quant_salt, issue_reversed=issue_reversed)
-        return jax.tree.unflatten(treedef, reduced)
-
-    leaves, treedef = jax.tree.flatten(grads)
-    compressed = [compression.compress(g) for g in leaves]
-    wire = [c[0] for c in compressed]
-    ctxs = [c[1] for c in compressed]
-    if num_groups and num_groups > 0:
-        # Reference's num_groups: split tensors into N groups, fuse within
-        # each. Emulate by capping each bucket at total/num_groups bytes.
-        total = sum(int(w.size) * jnp.dtype(w.dtype).itemsize for w in wire)
-        threshold_bytes = max(1, total // num_groups)
-    _record_flush("allreduce", wire, threshold_bytes)
-    reduced = fused_allreduce(
-        wire,
-        op=op,
-        axis_name=axis_name,
-        threshold_bytes=threshold_bytes,
-        prescale_factor=prescale_factor,
-        postscale_factor=postscale_factor,
-        issue_reversed=issue_reversed,
-        world_size=world_size,
-    )
-    restored = [
-        compression.decompress(r, ctx) for r, ctx in zip(reduced, ctxs)
-    ]
-    return jax.tree.unflatten(treedef, restored)
+            issue_reversed=issue_reversed,
+            world_size=world_size,
+        )
+        return jax.tree.unflatten(
+            treedef, _decompress_leaves(compression, reduced, ctxs))
 
 
 def _reduce_expert_partitioned(grads, op, axis_name, compression,
@@ -369,33 +409,33 @@ def _reducescatter_grads(
             leaves, threshold_bytes, num_groups)
         _record_flush(flush_label, leaves, sharded_threshold,
                       itemsize_override=1)
-        with annotate_collective("grad_reducescatter"):
+        with annotate_collective(SCOPE_WIRE), \
+                annotate_collective("grad_reducescatter"):
             shards = int8_fused_reducescatter(
                 leaves, axis_name, n, op=op,
                 threshold_bytes=sharded_threshold,
                 prescale_factor=prescale_factor,
                 postscale_factor=postscale_factor,
                 salt=quant_salt, issue_reversed=issue_reversed)
-        shards = [
-            s.astype(l.dtype)
-            if jnp.issubdtype(jnp.asarray(l).dtype, jnp.floating) else s
-            for s, l in zip(shards, leaves)
-        ]
+            shards = [
+                s.astype(l.dtype)
+                if jnp.issubdtype(jnp.asarray(l).dtype, jnp.floating) else s
+                for s, l in zip(shards, leaves)
+            ]
         return jax.tree.unflatten(treedef, shards)
-    compressed = [compression.compress(g) for g in leaves]
-    wire = [c[0] for c in compressed]
-    ctxs = [c[1] for c in compressed]
-    sharded_threshold = _sharded_threshold(wire, threshold_bytes, num_groups)
-    _record_flush(flush_label, wire, sharded_threshold)
-    with annotate_collective("grad_reducescatter"):
-        shards = fused_reducescatter(
-            wire, op, axis_name, n,
-            threshold_bytes=sharded_threshold,
-            prescale_factor=prescale_factor,
-            postscale_factor=postscale_factor,
-            issue_reversed=issue_reversed)
-    restored = [compression.decompress(s, ctx)
-                for s, ctx in zip(shards, ctxs)]
+    with annotate_collective(SCOPE_WIRE):
+        wire, ctxs = _compress_leaves(compression, leaves)
+        sharded_threshold = _sharded_threshold(
+            wire, threshold_bytes, num_groups)
+        _record_flush(flush_label, wire, sharded_threshold)
+        with annotate_collective("grad_reducescatter"):
+            shards = fused_reducescatter(
+                wire, op, axis_name, n,
+                threshold_bytes=sharded_threshold,
+                prescale_factor=prescale_factor,
+                postscale_factor=postscale_factor,
+                issue_reversed=issue_reversed)
+        restored = _decompress_leaves(compression, shards, ctxs)
     return jax.tree.unflatten(treedef, restored)
 
 
@@ -468,28 +508,26 @@ def _gather_param_shards(
     if getattr(compression, "marker", None) == "int8":
         from .ops.quantization import int8_fused_allgather_shards
 
-        with annotate_collective("param_allgather"):
+        with annotate_collective(SCOPE_WIRE), \
+                annotate_collective("param_allgather"):
             full = int8_fused_allgather_shards(
                 s_leaves, t_leaves, axis_name, n,
                 threshold_bytes=_sharded_threshold(
                     t_leaves, threshold_bytes, num_groups),
                 salt=quant_salt)
-        full = [f.astype(t.dtype) for f, t in zip(full, t_leaves)]
+            full = [f.astype(t.dtype) for f, t in zip(full, t_leaves)]
         return jax.tree.unflatten(treedef, full)
     from .ops.fusion import fused_allgather_shards
 
-    compressed = [compression.compress(s) for s in s_leaves]
-    wire = [c[0] for c in compressed]
-    ctxs = [c[1] for c in compressed]
-    with annotate_collective("param_allgather"):
-        full = fused_allgather_shards(
-            wire, t_leaves, axis_name, n,
-            threshold_bytes=_sharded_threshold(
-                t_leaves, threshold_bytes, num_groups))
-    restored = [
-        compression.decompress(f, ctx).astype(t.dtype)
-        for f, ctx, t in zip(full, ctxs, t_leaves)
-    ]
+    with annotate_collective(SCOPE_WIRE):
+        wire, ctxs = _compress_leaves(compression, s_leaves)
+        with annotate_collective("param_allgather"):
+            full = fused_allgather_shards(
+                wire, t_leaves, axis_name, n,
+                threshold_bytes=_sharded_threshold(
+                    t_leaves, threshold_bytes, num_groups))
+        restored = [r.astype(t.dtype) for r, t in zip(
+            _decompress_leaves(compression, full, ctxs), t_leaves)]
     return jax.tree.unflatten(treedef, restored)
 
 
@@ -780,12 +818,15 @@ def sharded_step_update(spec, grads, local_state, params, axis_name=None,
             world_size=n, quant_salt=salt)
     action, flag = _tripwire_flag(grad_shards, axis_name,
                                   rank_identical=False)
+    from .profiler import annotate_collective
+
     param_shards = _local_shards(params, axis_name, n)
-    updates, new_inner = spec.inner.update(
-        grad_shards, inner_local, param_shards)
+    updates, new_inner = _inner_update(
+        spec.inner, grad_shards, inner_local, param_shards)
     updates, new_inner = _tripwire_guard(action, flag, updates, new_inner,
                                          inner_local)
-    new_param_shards = optax.apply_updates(param_shards, updates)
+    with annotate_collective(SCOPE_OPTIMIZER):
+        new_param_shards = optax.apply_updates(param_shards, updates)
     new_local = _SaltState(new_inner, salt + 1) if int8 else new_inner
     if not gather:
         return new_param_shards, new_local
@@ -1002,12 +1043,12 @@ def DistributedOptimizer(
                                           rank_identical=False)
             if int8:
                 inner_local, salt = state.inner_state, state.counter
-                upd, new_inner = optimizer.update(grads, inner_local,
-                                                  params)
+                upd, new_inner = _inner_update(optimizer, grads,
+                                               inner_local, params)
                 upd, new_inner = _tripwire_guard(action, flag, upd,
                                                  new_inner, inner_local)
                 return upd, _SaltState(new_inner, salt + 1)
-            upd, new_inner = optimizer.update(grads, state, params)
+            upd, new_inner = _inner_update(optimizer, grads, state, params)
             upd, new_inner = _tripwire_guard(action, flag, upd, new_inner,
                                              state)
             return upd, new_inner
@@ -1048,8 +1089,8 @@ def DistributedOptimizer(
             action, flag = _tripwire_flag(grad_shards, effective,
                                           rank_identical=False)
             param_shards = _local_shards(params, effective, n)
-            updates_sh, new_inner = optimizer.update(
-                grad_shards, inner_local, param_shards)
+            updates_sh, new_inner = _inner_update(
+                optimizer, grad_shards, inner_local, param_shards)
             updates_sh, new_inner = _tripwire_guard(
                 action, flag, updates_sh, new_inner, inner_local)
             updates_full = _gather_param_shards(
@@ -1083,14 +1124,15 @@ def DistributedOptimizer(
                 # Allreduce output is rank-identical by construction —
                 # the skip decision needs no extra collective.
                 action, flag = _tripwire_flag(reduced, effective)
-                updates, new_inner = optimizer.update(
-                    reduced, state.inner_state, params)
+                updates, new_inner = _inner_update(
+                    optimizer, reduced, state.inner_state, params)
                 updates, new_inner = _tripwire_guard(
                     action, flag, updates, new_inner, state.inner_state)
                 return updates, _SaltState(new_inner, state.counter + 1)
             reduced = reduce_fn(grads)
             action, flag = _tripwire_flag(reduced, effective)
-            updates, new_inner = optimizer.update(reduced, state, params)
+            updates, new_inner = _inner_update(optimizer, reduced, state,
+                                               params)
             updates, new_inner = _tripwire_guard(action, flag, updates,
                                                  new_inner, state)
             return updates, new_inner
@@ -1124,7 +1166,8 @@ def DistributedOptimizer(
             reduced = reduce_fn(mean_g, salt=salt)
             action, flag = _tripwire_flag(
                 reduced, _effective_traced_axis(ps) or axis_name)
-            updates, new_inner = optimizer.update(reduced, inner, params)
+            updates, new_inner = _inner_update(optimizer, reduced, inner,
+                                               params)
             updates, new_inner = _tripwire_guard(action, flag, updates,
                                                  new_inner, inner)
             return updates, new_inner, jax.tree.map(jnp.zeros_like, acc_g)

@@ -40,29 +40,52 @@ class _StallWatchedStep:
     """
 
     def __init__(self, fn, name_prefix: str):
+        from .. import tracing
         from ..utils.env import get_int
 
         self._fn = fn
         self._prefix = name_prefix
         self._every = get_int("HOROVOD_STALL_CHECK_STEPS", 50)
+        # Read where the step is built, like the stall watch's period: a
+        # call reads no environment variable.
+        self._sample = tracing.sample_every()
         self._calls = 0
         self._trace_calls = 0
+        self._world = None  # the world formation _multi / _env_plane are of
+        self._multi = self._env_plane = False
+        self._abstract_args = None  # shapes and shardings of the first call
+        self._compiled = 0  # the compile account's programs, as last seen
+        self._compile_before = None
 
-    @staticmethod
-    def _cross_rank_available() -> bool:
+    def _cross_rank_available(self) -> bool:
         """True when the cross-rank stallwatch can ride a host plane
         this deployment actually has: an already-formed native world, or
-        the launcher env contract that makes one formable. NOT cached
-        and NEVER forms the world itself — a jax.distributed job that
-        deliberately skips the host plane must not have one spun up (or
-        crash on a missing rendezvous) as a side effect of the watch."""
-        import os
-
+        the launcher env contract that makes one formable. The world
+        object is looked at on every call and NEVER formed here — a
+        jax.distributed job that deliberately skips the host plane must
+        not have one spun up (or crash on a missing rendezvous) as a
+        side effect of the watch. The launcher's variables are read once
+        per world formation (``hvd.init``), not once a call."""
         from . import hierarchical
 
-        return (hierarchical._host_world is not None
-                or bool(os.environ.get("HOROVOD_NATIVE_PORT"))
-                or bool(os.environ.get("HOROVOD_RENDEZVOUS_ADDR")))
+        return hierarchical._host_world is not None or self._env_plane
+
+    def _world_facts(self) -> None:
+        """What the launcher's environment says of this world, read anew
+        only when ``hvd.init`` has formed another one."""
+        import os
+
+        from .. import basics
+        from ..process_world import size as _psize
+
+        formation = basics._state.topology  # a new object every init()
+        if formation is self._world and formation is not None:
+            return
+        self._world = formation
+        self._multi = _psize() > 1
+        self._env_plane = (
+            bool(os.environ.get("HOROVOD_NATIVE_PORT"))
+            or bool(os.environ.get("HOROVOD_RENDEZVOUS_ADDR")))
 
     def _step_number(self, cross_rank: bool) -> int:
         """Watch-step counter. In multi-controller worlds the stallwatch
@@ -71,9 +94,7 @@ class _StallWatchedStep:
         step N times, a fresh worker 0) — so the counter lives on the
         native world object, which every member recreates together at
         each (re-)formation."""
-        from ..process_world import size as _psize
-
-        if cross_rank and _psize() > 1:
+        if cross_rank and self._multi:
             from .hierarchical import _default_native_world
 
             w = _default_native_world()
@@ -93,77 +114,62 @@ class _StallWatchedStep:
 
         return bool(_active_tuner and _active_tuner[0]._hvd_tuning)
 
+    def _remember_arguments(self, args, kwargs) -> None:
+        """Shapes, dtypes and shardings of the first call's arguments:
+        what ``profiler.step_scopes`` lowers the step with, long after
+        the arrays themselves were donated."""
+        import jax
+
+        from .. import profiler
+
+        def abstract(leaf):
+            if hasattr(leaf, "shape") and hasattr(leaf, "dtype"):
+                return jax.ShapeDtypeStruct(
+                    leaf.shape, leaf.dtype,
+                    sharding=getattr(leaf, "sharding", None))
+            # Nothing here may keep a buffer alive: a handle that is no
+            # pytree (DeferredParams) is not remembered.
+            return leaf if isinstance(leaf, (bool, int, float, str)) else None
+
+        self._abstract_args = jax.tree.map(abstract, (args, kwargs))
+        profiler.register_step(self)
+
+    def _book_compiles(self, account, rec, call: int) -> None:
+        """Something compiled inside this call: its share of the compile
+        account goes on the step span, and on any call after the first
+        it is a recompile — counted, and journaled with the call."""
+        share = account.since(self._compile_before)
+        self._compiled = account.programs
+        rec.args = {**(rec.args or {}), "compile": share}
+        step = account.steps.setdefault(
+            self._prefix, {"first_call": None, "recompiles": 0})
+        if call == 1:  # of this wrapper: the newest of a name is kept
+            step["first_call"] = share
+            return
+        step["recompiles"] += 1
+        step["last_recompile"] = {"call": call, **share}
+        from .. import metrics
+
+        metrics.STEP_RECOMPILES.inc(step=self._prefix)
+        metrics.event("step_recompiled", step=self._prefix, call=call,
+                      **share)
+
     def __call__(self, *args, **kwargs):
-        from ..autotune import _poison_error, warmup_aborted
+        from .. import attribution, tracing
 
-        if warmup_aborted():
-            # A mid-warmup autotune abort poisons EVERY factory step in
-            # the process, not just the tuner's wrapper: co-built steps
-            # and steps built post-abort pass through maybe_autotune_step
-            # bare, but all of them route through this wrapper — and all
-            # of them would trace collective sequences that may diverge
-            # from peers that pinned the broadcast winner.
-            raise _poison_error()
-        from .. import tracing
-
-        tuning = self._tuning_live()
-        watch_due = False
-        cross = False
-        n = 0
-        if self._every > 0 and not tuning:
-            cross = self._cross_rank_available()
-            n = self._step_number(cross)
-            watch_due = n % self._every == 0
-        tracer = tracing.get_tracer()
-        # Every call opens a step record in the flight-recorder ring
-        # (cheap: one dict append; un-synced steps time only the async
-        # dispatch). Every HOROVOD_TRACE_SAMPLE-th call OF THIS WRAPPER
-        # additionally blocks on the results — real step wall time — and
-        # ships its spans to the rendezvous KV for the cross-rank merge.
-        # The sampling counter is per-wrapper, not the shared tracer
-        # counter: two interleaved factory steps (train + eval) sharing
-        # one process counter could alias one of them out of sampling
-        # forever. Sampling defers while an autotune warmup is live,
-        # exactly like the stall watch: the pipeline drain would bias
-        # the tuner's samples.
+        # Every call is one hvd.step span, opened before anything else
+        # here runs: a profiler annotation and a step record in the
+        # flight-recorder ring, with the dispatch and (where there is
+        # one) the drain as its children, so that the span's self time
+        # is what the hooks of this wrapper cost.
         self._trace_calls += 1
+        call = self._trace_calls
+        tracer = tracing.get_tracer()
         try:
-            from .. import faults
-
-            if faults.fire(faults.MEMORY_PRESSURE):
-                # drop = synthetic device OOM at the step boundary: the
-                # deterministic injector behind the memory observatory's
-                # forensics tests (caught and dumped just below, exactly
-                # like a real RESOURCE_EXHAUSTED out of the jitted call).
-                raise RuntimeError(
-                    "RESOURCE_EXHAUSTED: injected memory pressure "
-                    "(fault point memory.pressure)")
-            with tracer.step_scope(self._prefix) as rec:
-                sample = tracing.sample_every()
-                sample_due = (not tuning and sample > 0
-                              and self._trace_calls % sample == 0)
-                if watch_due:
-                    import jax
-
-                    from ..stall import watch
-
-                    # The announcement precedes the DISPATCH: on backends
-                    # that execute synchronously (CPU) a diverged peer hangs
-                    # this rank inside the jitted call itself, before any
-                    # post-hoc fetch could announce.
-                    with watch(name=f"{self._prefix}.{n}",
-                               cross_rank=cross):
-                        out = self._fn(*args, **kwargs)
-                        out = jax.block_until_ready(out)
-                    rec.synced = True
-                else:
-                    out = self._fn(*args, **kwargs)
-                    if sample_due:
-                        import jax
-
-                        out = jax.block_until_ready(out)
-                        rec.synced = True
-                rec.ship = sample_due and rec.synced
+            with tracer.step_scope(
+                    attribution.SPAN_STEP,
+                    {"kind": self._prefix, "call": call}) as rec:
+                return self._watched_call(tracer, rec, call, args, kwargs)
         except Exception as exc:
             # The factory step boundary is the OOM forensics consumer:
             # a RESOURCE_EXHAUSTED surfacing here dumps a memory flight
@@ -178,6 +184,85 @@ class _StallWatchedStep:
             except Exception:  # noqa: BLE001 — forensics must not
                 pass  # mask the original failure
             raise
+
+    def _watched_call(self, tracer, rec, call: int, args, kwargs):
+        from .. import attribution, faults, profiler
+        from ..autotune import _poison_error, warmup_aborted
+
+        if warmup_aborted():
+            # A mid-warmup autotune abort poisons EVERY factory step in
+            # the process, not just the tuner's wrapper: co-built steps
+            # and steps built post-abort pass through maybe_autotune_step
+            # bare, but all of them route through this wrapper — and all
+            # of them would trace collective sequences that may diverge
+            # from peers that pinned the broadcast winner.
+            raise _poison_error()
+        tuning = self._tuning_live()
+        watch_due = False
+        cross = False
+        n = 0
+        if self._every > 0 and not tuning:
+            self._world_facts()
+            cross = self._cross_rank_available()
+            n = self._step_number(cross)
+            watch_due = n % self._every == 0
+        # Every HOROVOD_TRACE_SAMPLE-th call OF THIS WRAPPER additionally
+        # blocks on the results — real step wall time — and ships its
+        # spans to the rendezvous KV for the cross-rank merge.
+        # The sampling counter is per-wrapper, not the shared tracer
+        # counter: two interleaved factory steps (train + eval) sharing
+        # one process counter could alias one of them out of sampling
+        # forever. Sampling defers while an autotune warmup is live,
+        # exactly like the stall watch: the pipeline drain would bias
+        # the tuner's samples.
+        sample_due = (not tuning and self._sample > 0
+                      and call % self._sample == 0)
+        if self._abstract_args is None:
+            self._remember_arguments(args, kwargs)
+        account = profiler.compile_account()
+        if account.programs != self._compiled or call == 1:
+            # Programs compiled since this step last looked (another
+            # step's, an eager collective's) are not this call's.
+            self._compiled = account.programs
+            self._compile_before = account.totals()
+        if faults.fire(faults.MEMORY_PRESSURE):
+            # drop = synthetic device OOM at the step boundary: the
+            # deterministic injector behind the memory observatory's
+            # forensics tests (caught and dumped by the caller, exactly
+            # like a real RESOURCE_EXHAUSTED out of the jitted call).
+            raise RuntimeError(
+                "RESOURCE_EXHAUSTED: injected memory pressure "
+                "(fault point memory.pressure)")
+        if watch_due:
+            import jax
+
+            from ..stall import watch
+
+            # The announcement precedes the DISPATCH: on backends
+            # that execute synchronously (CPU) a diverged peer hangs
+            # this rank inside the jitted call itself, before any
+            # post-hoc fetch could announce.
+            with watch(name=f"{self._prefix}.{n}", cross_rank=cross):
+                with tracer.host_span(attribution.SPAN_STEP_DISPATCH):
+                    out = self._fn(*args, **kwargs)
+                with tracer.host_span(
+                        attribution.SPAN_STEP_DRAIN,
+                        {"cause": attribution.DRAIN_STALL_WATCH}):
+                    out = jax.block_until_ready(out)
+        else:
+            with tracer.host_span(attribution.SPAN_STEP_DISPATCH):
+                out = self._fn(*args, **kwargs)
+            if sample_due:
+                import jax
+
+                with tracer.host_span(
+                        attribution.SPAN_STEP_DRAIN,
+                        {"cause": attribution.DRAIN_TRACE_SAMPLE}):
+                    out = jax.block_until_ready(out)
+        rec.synced = watch_due or sample_due
+        rec.ship = sample_due
+        if account.programs != self._compiled:
+            self._book_compiles(account, rec, call)
         return out
 
     @property
@@ -465,12 +550,26 @@ def _make_allreduce_train_step(loss_fn, optimizer, mesh, axis_name,
     hierarchical (cross, local) tuple, or the 2-D (batch, model) tuple:
     the optimizer's allreduce resolves the bound axis form at trace
     time and takes the matching two-level composition for tuples)."""
+    import contextlib
+
     import optax
+
+    from ..attribution import SCOPE_OPTIMIZER
+    from ..optimizer import reduce_spec_of
+    from ..profiler import annotate_collective
+
+    # A DistributedOptimizer scopes its wire and its inner update itself;
+    # a bare optax optimizer is all update.
+    bare = reduce_spec_of(optimizer) is None
 
     def spmd_step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        updates, new_opt_state = optimizer.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
+        with (annotate_collective(SCOPE_OPTIMIZER) if bare
+              else contextlib.nullcontext()):
+            updates, new_opt_state = optimizer.update(
+                grads, opt_state, params)
+        with annotate_collective(SCOPE_OPTIMIZER):
+            new_params = optax.apply_updates(params, updates)
         if loss_is_averaged:
             loss = jax.lax.pmean(loss, axis_name)
         return new_params, new_opt_state, loss
@@ -623,8 +722,10 @@ def _make_fsdp_train_step(loss_fn, spec, mesh, axis_name, donate,
     """
     import optax
 
+    from ..attribution import SCOPE_OPTIMIZER
     from ..autotune import maybe_autotune_step
     from ..optimizer import _SaltState, _known_size
+    from ..profiler import annotate_collective
     from .param_sharding import ShardedParams, gather_params
 
     int8 = getattr(spec.compression, "marker", None) == "int8"
@@ -667,9 +768,10 @@ def _make_fsdp_train_step(loss_fn, spec, mesh, axis_name, donate,
         # each segment boundary's backward emitted its reducescatter
         # inside backprop and its cotangent IS the owned (s,) slice.
         loss, grad_shards = jax.value_and_grad(loss_of)(shards)
-        updates, new_inner = spec.inner.update(grad_shards, inner_local,
-                                               shards)
-        new_shards = optax.apply_updates(shards, updates)
+        with annotate_collective(SCOPE_OPTIMIZER):
+            updates, new_inner = spec.inner.update(
+                grad_shards, inner_local, shards)
+            new_shards = optax.apply_updates(shards, updates)
         new_local = _SaltState(new_inner, salt + 1) if int8 else new_inner
         new_rows = ShardedParams(
             [a[None] for a in jax.tree.leaves(new_shards)], meta)
@@ -754,8 +856,10 @@ def _make_fsdp_train_step_2d(loss_fn, spec, mesh2d, donate,
     """
     import optax
 
+    from ..attribution import SCOPE_OPTIMIZER
     from ..autotune import maybe_autotune_step
     from ..optimizer import _SaltState, _known_size
+    from ..profiler import annotate_collective
     from .mesh import MESH2D_AXES, MESH2D_ROW_AXES, mesh_axis_sizes
     from .param_sharding import ShardedParams, gather_params_2d
 
@@ -800,9 +904,10 @@ def _make_fsdp_train_step_2d(loss_fn, spec, mesh2d, donate,
         # psum_scatter and batch-leg reducescatter inside backprop and
         # its cotangent IS the owned (s,) slice.
         loss, grad_shards = jax.value_and_grad(loss_of)(shards)
-        updates, new_inner = spec.inner.update(grad_shards, inner_local,
-                                               shards)
-        new_shards = optax.apply_updates(shards, updates)
+        with annotate_collective(SCOPE_OPTIMIZER):
+            updates, new_inner = spec.inner.update(
+                grad_shards, inner_local, shards)
+            new_shards = optax.apply_updates(shards, updates)
         new_local = _SaltState(new_inner, salt + 1) if int8 else new_inner
         new_rows = ShardedParams(
             [a[None] for a in jax.tree.leaves(new_shards)], meta)
@@ -1035,7 +1140,9 @@ def make_overlapped_train_step(
     """
     import optax
 
+    from ..attribution import SCOPE_OPTIMIZER
     from ..optimizer import _SaltState, reduce_spec_of
+    from ..profiler import annotate_collective
 
     spec = reduce_spec_of(optimizer)
     if spec is None:
@@ -1129,8 +1236,10 @@ def make_overlapped_train_step(
         # segment i-1 is still reducing — the monolithic path's global
         # post-backward barrier (one concat depending on every gradient)
         # does not exist here.
-        updates, new_inner = spec.inner.update(grads, inner_state, params)
-        new_params = optax.apply_updates(params, updates)
+        with annotate_collective(SCOPE_OPTIMIZER):
+            updates, new_inner = spec.inner.update(
+                grads, inner_state, params)
+            new_params = optax.apply_updates(params, updates)
         new_state = _SaltState(new_inner, salt + 1) if int8 else new_inner
         if loss_is_averaged:
             loss = jax.lax.pmean(loss, axis_name)

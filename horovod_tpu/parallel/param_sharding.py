@@ -495,6 +495,7 @@ def _gather_boundary_2d(shard_leaves, templates, seg_index, spec,
     """
     from jax import lax
 
+    from ..attribution import SCOPE_WIRE
     from ..optimizer import _gather_param_shards, _reducescatter_grads
     from ..ops import collective_ops
     from ..ops.fusion import shard_ownership_2d
@@ -518,7 +519,9 @@ def _gather_boundary_2d(shard_leaves, templates, seg_index, spec,
                 b, spec.fusion_threshold_bytes, 0, quant_salt=s)
         _record_gather(templates, None, axis="model")
         full = []
-        with annotate_collective(
+        # The batch legs are the wire's own functions and scope
+        # themselves; the model legs are plain collectives of this file.
+        with annotate_collective(SCOPE_WIRE), annotate_collective(
                 f"fsdp.param_gather.model.seg{seg_index}"):
             for blk, t in zip(blocks, templates):
                 flat = lax.all_gather(jnp.ravel(blk), model_axis,
@@ -529,7 +532,7 @@ def _gather_boundary_2d(shard_leaves, templates, seg_index, spec,
 
     def reduce_cts(cts, s):
         blocks = []
-        with annotate_collective(
+        with annotate_collective(SCOPE_WIRE), annotate_collective(
                 f"fsdp.grad_reducescatter.model.seg{seg_index}"):
             for ct, (share, shard) in zip(cts, ownership):
                 flat = jnp.ravel(jnp.asarray(ct))

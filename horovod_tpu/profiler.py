@@ -12,15 +12,23 @@ activities against GPU kernels. Here the same role is played by
   framework annotations appear above the TPU op stream — one merged view.
 - Programmatic: ``hvd.profiler.start(logdir)`` / ``hvd.profiler.stop()``,
   and :func:`trace` as a with-block for scoped capture.
-- :func:`annotate_collective` names in-trace collective regions (segment
-  allreduces, fusion buckets, hierarchical legs) so comm/compute overlap
-  is visible against the TPU op stream in the captured trace.
+- :func:`annotate_collective` names in-trace regions of the compiled step
+  — the phase scopes ``hvd.wire`` / ``hvd.optimizer`` / ``hvd.attn.*``
+  and, inside the wire, segment allreduces, fusion buckets, hierarchical
+  legs — so each is identifiable against the TPU op stream in the
+  captured trace; :func:`instruction_scopes` reads them back from a
+  compiled step's text, instruction by instruction.
+- :func:`compile_account` is JAX's own account of tracing, lowering and
+  compiling (``jax.monitoring``), heard by the program once per process
+  and served as ``hvd.cache_stats()["compile"]``.
 """
 
 from __future__ import annotations
 
 import os
+import re
 import threading
+import weakref
 
 _lock = threading.Lock()
 _active_logdir: str | None = None
@@ -105,9 +113,160 @@ def summary() -> dict:
     }
 
 
+class CompileAccount:
+    """What ``jax.monitoring`` reports of every program this process
+    traced, lowered and compiled, summed: seconds of jaxpr tracing (a
+    jit nested in another counts in both), of lowering to MLIR, and of
+    backend compile (on a persistent-cache hit, of loading the
+    executable); persistent-cache hits and misses; programs compiled.
+    ``steps`` holds, per factory step, the share of its first call and
+    its recompiles (``data_parallel._StallWatchedStep`` books them)."""
+
+    DURATIONS = {
+        "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+        "/jax/core/compile/backend_compile_duration": "backend_compile_s",
+    }
+    EVENTS = {
+        "/jax/compilation_cache/cache_hits": "cache_hits",
+        "/jax/compilation_cache/cache_misses": "cache_misses",
+    }
+    FIELDS = (*DURATIONS.values(), *EVENTS.values(), "programs")
+
+    def __init__(self):
+        self.trace_s = self.lower_s = self.backend_compile_s = 0.0
+        self.cache_hits = self.cache_misses = self.programs = 0
+        self.steps: dict[str, dict] = {}
+        self.listening = False
+
+    def on_duration(self, event: str, seconds: float, **_) -> None:
+        field = self.DURATIONS.get(event)
+        if field is not None:
+            setattr(self, field, getattr(self, field) + seconds)
+            if field == "backend_compile_s":
+                self.programs += 1
+
+    def on_event(self, event: str, **_) -> None:
+        field = self.EVENTS.get(event)
+        if field is not None:
+            setattr(self, field, getattr(self, field) + 1)
+
+    def listen(self) -> None:
+        """Register with ``jax.monitoring``, once per process (its
+        listeners cannot be taken back)."""
+        import jax.monitoring
+
+        with _lock:
+            if self.listening:
+                return
+            jax.monitoring.register_event_listener(self.on_event)
+            jax.monitoring.register_event_duration_secs_listener(
+                self.on_duration)
+            self.listening = True
+
+    def totals(self) -> dict:
+        return {field: getattr(self, field) for field in self.FIELDS}
+
+    def since(self, before: dict) -> dict:
+        """The account's growth since ``before`` (a :meth:`totals`)."""
+        return {field: round(value - before[field], 6)
+                if isinstance(value, float) else value - before[field]
+                for field, value in self.totals().items()}
+
+    def summary(self) -> dict:
+        return {"listening": self.listening, **self.totals(),
+                "steps": {name: dict(step)
+                          for name, step in self.steps.items()}}
+
+
+_compile_account = CompileAccount()
+
+
+def compile_account() -> CompileAccount:
+    """The process's compile account. ``hvd.init()`` and
+    ``hvd.enable_compile_cache()`` make it listen, whichever runs first;
+    until then every figure is 0 and ``listening`` False."""
+    return _compile_account
+
+
+#: ``%name = ... metadata={op_name="jit(step)/.../hvd.wire/psum"`` of one
+#: instruction of a compiled program's text.
+_INSTRUCTION_OP_NAME = re.compile(
+    r'^\s*(?:ROOT )?%?([\w.-]+) = [^\n]*?metadata=\{op_name="([^"]*)"',
+    re.M)
+
+
+def instruction_scopes(hlo_text: str) -> dict[str, str]:
+    """Instruction name → the JAX name stack it was compiled from
+    (``jit(spmd_step)/transpose(jvp(Bert))/hvd.attn.bwd/pallas_call``),
+    from a compiled program's text. A device trace names an operation by
+    its instruction; which phase scope it ran under is only here. A
+    fusion carries its root instruction's name stack. Raises where the
+    text holds none of the phase scopes: that is no step of this
+    program's, or an executable served by a persistent cache whose key
+    leaves metadata out (``jax_compilation_cache_include_metadata_in_key``)
+    and that was compiled before the scopes existed."""
+    from .attribution import PHASE_SCOPE_NAMES
+
+    scopes = dict(_INSTRUCTION_OP_NAME.findall(hlo_text))
+    if not any(phase_of(scope) for scope in scopes.values()):
+        raise ValueError(
+            f"no phase scope ({', '.join(PHASE_SCOPE_NAMES)}) in the "
+            f"program's text ({len(scopes)} instructions with an "
+            "op_name): not a factory step, or an executable loaded from a "
+            "persistent compilation cache that a tree with other scopes "
+            "wrote (the cache's key leaves metadata out): compile it "
+            "afresh, in a cache directory of its own or with "
+            "jax_compilation_cache_include_metadata_in_key set")
+    return scopes
+
+
+def phase_of(scope: str) -> str | None:
+    """The innermost phase scope (``hvd.wire``, ``hvd.optimizer``,
+    ``hvd.attn.fwd``, ``hvd.attn.bwd``) among the components of a name
+    stack, wherever it sits (the overlapped step's wire is under
+    ``transpose``), or None."""
+    from .attribution import PHASE_SCOPE_NAMES
+
+    for part in reversed(scope.split("/")):
+        if part in PHASE_SCOPE_NAMES:
+            return part
+    return None
+
+
+_factory_steps: "weakref.WeakSet" = weakref.WeakSet()
+
+
+def register_step(step) -> None:
+    """A factory step, at its first call (``_StallWatchedStep``)."""
+    _factory_steps.add(step)
+
+
+def step_texts() -> list[str]:
+    """The compiled text of every live factory step that has been called:
+    each is lowered with the shapes and shardings of its first call and
+    compiled, which jit memoises, so that nothing compiles and the text
+    is that of the executable that runs. About a second for a 24-layer
+    step; never on a step's path."""
+    texts = []
+    for step in list(_factory_steps):
+        if not hasattr(step, "lower"):
+            continue  # a Python step of several programs (elastic)
+        args, kwargs = step._abstract_args
+        texts.append(step.lower(*args, **kwargs).compile().as_text())
+    if not texts:
+        raise ValueError("no factory step has been called in this process")
+    return texts
+
+
+def step_scopes() -> dict[str, str]:
+    """:func:`instruction_scopes` of the live factory steps' texts."""
+    return instruction_scopes("\n".join(step_texts()))
+
+
 def annotate_collective(name: str):
     """Name the ops traced inside the scope (``jax.named_scope``) so each
-    collective region is identifiable in xprof traces and HLO dumps.
+    region is identifiable in xprof traces and HLO dumps: ``hvd.<name>``.
 
     This is the compiled-regime counterpart of the host timeline's
     ``activity`` ranges (which cannot see inside a jitted program): the

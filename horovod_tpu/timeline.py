@@ -32,7 +32,11 @@ import threading
 import time
 from typing import Any
 
-_timeline: "Timeline | None" = None
+# None: not looked for yet (the next get_timeline() reads
+# HOROVOD_TIMELINE); _OFF: looked for and not asked for, so that a span
+# costs neither a lock nor an environment read; else the writer.
+_OFF = object()
+_timeline: "Timeline | object | None" = None
 _lock = threading.Lock()
 
 
@@ -148,15 +152,18 @@ class Timeline:
 
 
 def get_timeline() -> Timeline | None:
-    """The process timeline, or None when HOROVOD_TIMELINE is unset."""
+    """The process timeline, or None when HOROVOD_TIMELINE is unset.
+    The variable is read once; :func:`start_timeline` and
+    :func:`stop_timeline` are how capture changes after that."""
     global _timeline
-    with _lock:
-        if _timeline is None:
-            path = os.environ.get("HOROVOD_TIMELINE", "")
-            if not path:
-                return None
-            _timeline = Timeline(path)
-        return _timeline
+    timeline = _timeline
+    if timeline is None:
+        with _lock:
+            if _timeline is None:
+                path = os.environ.get("HOROVOD_TIMELINE", "")
+                _timeline = Timeline(path) if path else _OFF
+            timeline = _timeline
+    return None if timeline is _OFF else timeline
 
 
 def start_timeline(file_path: str, mark_cycles: bool = False) -> None:
@@ -170,7 +177,7 @@ def start_timeline(file_path: str, mark_cycles: bool = False) -> None:
     # materialize a writer at the stale path (truncating a flushed
     # trace). The old writer shuts down outside the lock.
     with _lock:
-        old = _timeline
+        old = None if _timeline is _OFF else _timeline
         os.environ["HOROVOD_TIMELINE"] = file_path
         if mark_cycles:
             os.environ["HOROVOD_TIMELINE_MARK_CYCLES"] = "1"
@@ -187,7 +194,7 @@ def stop_timeline() -> None:
     ``hvd.stop_timeline``)."""
     global _timeline, _mark_cycles
     with _lock:
-        tl = _timeline
+        tl = None if _timeline is _OFF else _timeline
         _timeline = None
         os.environ.pop("HOROVOD_TIMELINE", None)
         os.environ.pop("HOROVOD_TIMELINE_MARK_CYCLES", None)

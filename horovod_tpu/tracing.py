@@ -33,21 +33,31 @@ what was it doing instead*. This module is that sensor layer:
    every rung of the recovery ladder leaves a postmortem of what each
    rank was doing when the world wedged.
 
-Knobs (see docs/timeline.md):
+Knob (see docs/timeline.md): ``HOROVOD_TRACE_SAMPLE`` — ship every Nth
+step's spans (0 = default = record locally only, never ship; shipping
+syncs the sampled step). The flight recorder's depth (8 steps) and the
+per-step span cap (64; overflow is counted, never silently unbounded)
+are :class:`StepTracer` constructor arguments.
 
-- ``HOROVOD_TRACE_SAMPLE`` — ship every Nth step's spans (0 = default =
-  record locally only, never ship; shipping syncs the sampled step).
-- ``HOROVOD_TRACE_RING_STEPS`` — flight-recorder depth K (default 8).
-- ``HOROVOD_TRACE_MAX_SPANS`` — per-step span cap (default 64; overflow
-  is counted, never silently unbounded).
+A step scope and a step's host spans (:meth:`StepTracer.step_scope`,
+:meth:`StepTracer.host_span`) are real spans: each is a
+``jax.profiler.TraceAnnotation`` — a device trace holds it on the host
+plane, on the trace's own clock — **and** a ring record carrying name,
+start, duration, an id and its parent span's id, so a span's self time
+is computed (:func:`self_times`), not guessed from nesting. With no
+profiler session open a span costs two clock reads, one ring append and
+an annotation that does nothing: no environment read, no lock beyond the
+ring's.
 
-Stdlib-only and jax-free by design: the KV server (driver side, before
-any framework init) imports :func:`compute_skew` from here.
+Stdlib-only and jax-free to import by design: the KV server (driver
+side, before any framework init) imports :func:`compute_skew` from here.
+``jax.profiler`` is imported where the first span opens.
 """
 
 from __future__ import annotations
 
 import collections
+import itertools
 import json
 import os
 import socket
@@ -55,6 +65,7 @@ import threading
 import time
 from typing import Any, Callable, Mapping
 
+from .attribution import CAT_HOST, _length, _merge
 from .utils.env import get_float, get_int
 
 #: KV scope trace payloads ship to (``PUT /trace/<host>``).
@@ -67,13 +78,36 @@ def sample_every() -> int:
     return get_int("HOROVOD_TRACE_SAMPLE", 0)
 
 
-def ring_steps() -> int:
-    """Flight-recorder depth: how many recent steps the ring keeps."""
-    return max(1, get_int("HOROVOD_TRACE_RING_STEPS", 8))
+class _NoAnnotation:
+    """Stands in for ``TraceAnnotation`` where jax cannot be imported."""
+
+    def __init__(self, name, **kwargs):
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
 
 
-def max_spans_per_step() -> int:
-    return max(1, get_int("HOROVOD_TRACE_MAX_SPANS", 64))
+_annotation_cls = None
+
+
+def annotation(name: str, args: Mapping[str, Any] | None = None):
+    """A ``jax.profiler.TraceAnnotation`` named ``name``: with a profiler
+    session open it lands on the trace's host plane, ``args`` as the
+    event's statistics; with none it costs a fraction of a microsecond.
+    The class is looked up at the first span, not at import."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        try:
+            from jax.profiler import TraceAnnotation
+
+            _annotation_cls = TraceAnnotation
+        except Exception:  # noqa: BLE001 — no jax here: ring records only
+            _annotation_cls = _NoAnnotation
+    return _annotation_cls(name, **args) if args else _annotation_cls(name)
 
 
 def _rank() -> str:
@@ -168,9 +202,10 @@ class StepRecord:
     just async dispatch; ``ship`` marks it for posting to the KV."""
 
     __slots__ = ("step", "kind", "t_start", "spans", "dropped",
-                 "synced", "ship", "dur")
+                 "synced", "ship", "dur", "span_id", "args")
 
-    def __init__(self, step: int, kind: str, t_start: float):
+    def __init__(self, step: int, kind: str, t_start: float,
+                 span_id: int | None = None):
         self.step = step
         self.kind = kind
         self.t_start = t_start
@@ -179,6 +214,8 @@ class StepRecord:
         self.synced = False
         self.ship = False
         self.dur: float | None = None
+        self.span_id = span_id  # the step span's id: its children's parent
+        self.args: Mapping | None = None  # more args for the step span
 
     def as_dict(self) -> dict:
         out = {
@@ -199,23 +236,58 @@ class StepTracer:
     """Per-process span recorder: a ring of the last K steps (the flight
     recorder) plus the currently open step and spans. Recording is cheap
     (one dict append under a lock) and always on; only shipping and the
-    sampled-step sync are gated by ``HOROVOD_TRACE_SAMPLE``."""
+    sampled-step sync are gated by ``HOROVOD_TRACE_SAMPLE``.
 
-    def __init__(self, clock_sync: ClockSync | None = None):
+    ``ring_steps`` is the flight recorder's depth; ``max_spans`` caps a
+    step's spans (overflow is counted as ``dropped_spans``)."""
+
+    def __init__(self, clock_sync: ClockSync | None = None,
+                 ring_steps: int = 8, max_spans: int = 64):
         self.clock = clock_sync or ClockSync()
+        self.max_spans = max(1, int(max_spans))
         self._lock = threading.Lock()
         self._ring: collections.deque = collections.deque(
-            maxlen=ring_steps())
+            maxlen=max(1, int(ring_steps)))
         self._current: StepRecord | None = None
         self._ambient: StepRecord | None = None
-        self._open: dict[int, tuple[str, str, float]] = {}
+        self._open: dict[int, tuple] = {}
         self._next_open = 0
         self._step_count = 0
         self._dispatch_seq: dict[str, int] = {}
+        # Span ids (next() on a count is atomic) and, per thread, the ids
+        # of the spans open on it: the top one is the next span's parent.
+        self._ids = itertools.count(1)
+        self._tls = threading.local()
 
     # -- span recording -----------------------------------------------------
 
-    def begin_span(self, name: str, cat: str) -> int:
+    def _stack(self) -> list:
+        try:
+            return self._tls.stack
+        except AttributeError:
+            stack = self._tls.stack = []
+            return stack
+
+    def _push(self) -> tuple[int, int | None]:
+        """Open a span on this thread: ``(its id, its parent's id)``."""
+        stack = self._stack()
+        parent = stack[-1] if stack else None
+        span_id = next(self._ids)
+        stack.append(span_id)
+        return span_id, parent
+
+    def _pop(self, span_id: int) -> None:
+        stack = self._stack()
+        if stack and stack[-1] == span_id:
+            stack.pop()
+        elif span_id in stack:  # closed out of order: drop it and above
+            del stack[stack.index(span_id):]
+
+    def in_step(self) -> bool:
+        return self._current is not None
+
+    def begin_span(self, name: str, cat: str, span_id: int | None = None,
+                   parent: int | None = None) -> int:
         """Register an in-flight span (so a wedge shows up in the flight
         record as an OPEN span with its age). Returns a token for
         :meth:`end_span`."""
@@ -223,7 +295,7 @@ class StepTracer:
         with self._lock:
             token = self._next_open
             self._next_open += 1
-            self._open[token] = (name, cat, t0)
+            self._open[token] = (name, cat, t0, span_id, parent)
         return token
 
     def end_span(self, token: int,
@@ -233,8 +305,15 @@ class StepTracer:
             opened = self._open.pop(token, None)
             if opened is None:
                 return
-            name, cat, t0 = opened
-            self._record_locked(name, cat, t0, now - t0, args)
+            name, cat, t0, span_id, parent = opened
+            self._record_locked(name, cat, t0, now - t0, args, span_id,
+                                parent)
+
+    def host_span(self, name: str,
+                  args: Mapping[str, Any] | None = None) -> "_HostSpan":
+        """One part of a step as a real span: ``with
+        tracer.host_span(attribution.SPAN_STEP_DISPATCH): ...``."""
+        return _HostSpan(self, name, args)
 
     def record(self, name: str, cat: str, t_start: float, dur: float,
                args: Mapping[str, Any] | None = None) -> None:
@@ -275,7 +354,8 @@ class StepTracer:
             self._dispatch_seq[name] = seq
             self._record_locked(f"{name}#{seq}", cat, t0, 0.0, None)
 
-    def _record_locked(self, name, cat, t_start, dur, args) -> None:
+    def _record_locked(self, name, cat, t_start, dur, args,
+                       span_id=None, parent=None) -> None:
         target = self._current
         if target is None:
             # Spans outside any step (eager scripting) collect into an
@@ -283,17 +363,22 @@ class StepTracer:
             if self._ambient is None:
                 self._ambient = StepRecord(-1, "eager", t_start)
             target = self._ambient
-        if len(target.spans) >= max_spans_per_step():
+        if len(target.spans) >= self.max_spans:
             target.dropped += 1
         else:
             sp = {"name": name, "cat": cat,
                   "t": round(float(t_start), 6),
                   "dur": round(float(dur), 6)}
+            if span_id is not None:
+                sp["id"] = span_id
+                sp["step"] = target.step
+                if parent is not None:
+                    sp["parent"] = parent
             if args:
                 sp["args"] = dict(args)
             target.spans.append(sp)
         if (target is self._ambient
-                and len(target.spans) >= max_spans_per_step()):
+                and len(target.spans) >= self.max_spans):
             # Full ambient window: rotate it into the ring so eager-only
             # scripts produce bounded records too (same cap as steps).
             self._ring.append(self._ambient.as_dict())
@@ -301,21 +386,28 @@ class StepTracer:
 
     # -- step scopes ----------------------------------------------------------
 
-    def step_scope(self, kind: str = "step") -> "_StepScope":
-        return _StepScope(self, kind)
+    def step_scope(self, kind: str = "step",
+                   args: Mapping[str, Any] | None = None) -> "_StepScope":
+        """One step as a span named ``kind``: the envelope of everything
+        recorded until it closes. ``args`` ride on the profiler
+        annotation and on the ring's step span."""
+        return _StepScope(self, kind, args)
 
     def _begin_step(self, kind: str) -> StepRecord:
+        span_id, _ = self._push()
         with self._lock:
             self._step_count += 1
             if self._ambient is not None and self._ambient.spans:
                 self._ring.append(self._ambient.as_dict())
             self._ambient = None
-            rec = StepRecord(self._step_count, kind, self.clock.now())
+            rec = StepRecord(self._step_count, kind, self.clock.now(),
+                             span_id)
             self._current = rec
             return rec
 
     def _end_step(self, rec: StepRecord) -> None:
         rec.dur = self.clock.now() - rec.t_start
+        self._pop(rec.span_id)
         with self._lock:
             if self._current is rec:
                 self._current = None
@@ -323,10 +415,20 @@ class StepTracer:
                 "name": rec.kind, "cat": "step",
                 "t": round(rec.t_start, 6),
                 "dur": round(rec.dur, 6),
-                "args": {"synced": rec.synced},
+                "id": rec.span_id, "step": rec.step,
+                "args": {"synced": rec.synced, **(rec.args or {})},
             })
             self._ring.append(rec.as_dict())
         if rec.synced:
+            # Where a step was blocked on, the chip holds what the step
+            # holds: the memory observatory's watermark latch. Never on
+            # an un-synced call (it asks every local device).
+            try:
+                from . import memory
+
+                memory.note_phase(rec.kind, "step")
+            except Exception:  # noqa: BLE001 — advisory
+                pass
             # Synced steps carry REAL wall time, so they feed the
             # attribution plane: phase decomposition, exposed-comm and
             # MFU gauges, the local regression sentinel. Un-synced
@@ -378,7 +480,7 @@ class StepTracer:
             open_spans = [
                 {"name": name, "cat": cat, "t": round(t0, 6),
                  "age_s": round(now - t0, 6)}
-                for name, cat, t0 in self._open.values()
+                for name, cat, t0, _, _ in self._open.values()
             ]
             current = (self._current.as_dict()
                        if self._current is not None else None)
@@ -418,13 +520,20 @@ class StepTracer:
 
 
 class _StepScope:
-    def __init__(self, tracer: StepTracer, kind: str):
+    def __init__(self, tracer: StepTracer, kind: str,
+                 args: Mapping[str, Any] | None = None):
         self._tracer = tracer
         self._kind = kind
+        self._args = args
+        self._annotation = None
         self.rec: StepRecord | None = None
 
     def __enter__(self) -> StepRecord:
         self.rec = self._tracer._begin_step(self._kind)
+        self.rec.args = self._args
+        self._annotation = annotation(
+            self._kind, {"step": self.rec.step, **(self._args or {})})
+        self._annotation.__enter__()
         return self.rec
 
     def __exit__(self, exc_type, exc, tb):
@@ -434,8 +543,65 @@ class _StepScope:
                 "cat": "error",
                 "t": round(self._tracer.clock.now(), 6), "dur": 0.0,
             })
+        self._annotation.__exit__(exc_type, exc, tb)
         self._tracer._end_step(self.rec)
         return False
+
+
+class _HostSpan:
+    """A span on both legs: a profiler annotation and a ring record with
+    id and parent. The hot path of every factory step: two clock reads,
+    one append under the ring's lock, nothing else."""
+
+    __slots__ = ("_tracer", "_name", "_args", "_annotation", "_t0", "_id",
+                 "_parent")
+
+    def __init__(self, tracer: StepTracer, name: str,
+                 args: Mapping[str, Any] | None):
+        self._tracer = tracer
+        self._name = name
+        self._args = args
+
+    def __enter__(self):
+        tracer = self._tracer
+        self._id, self._parent = tracer._push()
+        self._annotation = annotation(self._name, self._args)
+        self._annotation.__enter__()
+        self._t0 = tracer.clock.now()
+        return self
+
+    def __exit__(self, *exc):
+        tracer = self._tracer
+        now = tracer.clock.now()
+        self._annotation.__exit__(*exc)
+        tracer._pop(self._id)
+        with tracer._lock:
+            tracer._record_locked(self._name, CAT_HOST, self._t0,
+                                  now - self._t0, self._args, self._id,
+                                  self._parent)
+        return False
+
+
+def self_times(spans) -> list[float]:
+    """Per span of one step record's ``spans``, its self time in seconds:
+    its duration less the part of it that its child spans cover (children
+    are the spans whose ``parent`` is its ``id``; a span without an id
+    has none)."""
+    children: dict[int, list] = {}
+    for sp in spans:
+        parent = sp.get("parent")
+        if parent is not None:
+            children.setdefault(parent, []).append(
+                (sp["t"], sp["t"] + sp["dur"]))
+    out = []
+    for sp in spans:
+        start, end = sp["t"], sp["t"] + sp["dur"]
+        covered = _length(_merge([
+            (max(s, start), min(e, end))
+            for s, e in children.get(sp.get("id"), ()) if e > start
+            and s < end]))
+        out.append(max(sp["dur"] - covered, 0.0))
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -458,18 +624,22 @@ def clock_sync() -> ClockSync:
 
 def get_tracer() -> StepTracer:
     global _tracer
+    tracer = _tracer  # every factory step comes through here: no lock
+    if tracer is not None:
+        return tracer
     with _lock:
         if _tracer is None:
             _tracer = StepTracer(clock_sync())
         return _tracer
 
 
-def reset_for_testing() -> None:
-    """Fresh tracer + clock sync (re-reads the ring/sampling env)."""
+def reset_for_testing(tracer: StepTracer | None = None) -> None:
+    """Fresh tracer + clock sync; ``tracer`` installs one built with a
+    ring depth or span cap of the test's own."""
     global _tracer, _clock_sync, _last_hb_ship
     with _lock:
-        _tracer = None
-        _clock_sync = None
+        _tracer = tracer
+        _clock_sync = tracer.clock if tracer is not None else None
     with _ship_lock:
         _last_hb_ship = 0.0
 
@@ -496,6 +666,8 @@ class span:
         self.cat = cat
         self.args = args
         self._token: int | None = None
+        self._tracer: StepTracer | None = None
+        self._id: int | None = None
         self._act = None
 
     def __enter__(self):
@@ -507,15 +679,21 @@ class span:
         except Exception:  # noqa: BLE001
             self._act = None
         try:
-            self._token = get_tracer().begin_span(self.name, self.cat)
+            self._tracer = get_tracer()
+            self._id, parent = self._tracer._push()
+            self._token = self._tracer.begin_span(
+                self.name, self.cat, self._id, parent)
         except Exception:  # noqa: BLE001
             self._token = None
         return self
 
     def __exit__(self, *exc):
+        in_step = False
         if self._token is not None:
             try:
-                get_tracer().end_span(self._token, self.args)
+                self._tracer._pop(self._id)
+                in_step = self._tracer.in_step()
+                self._tracer.end_span(self._token, self.args)
             except Exception:  # noqa: BLE001
                 pass
         if self._act is not None:
@@ -523,16 +701,16 @@ class span:
                 self._act.__exit__(*exc)
             except Exception:  # noqa: BLE001
                 pass
-        # Memory-observatory watermark hook: every span close folds the
-        # current resident total into its phase's high-water mark
-        # (memory.note_phase never raises and is cheap — cached cells
-        # plus two guarded supplier polls).
-        try:
-            from . import memory
+        # Memory-observatory watermark hook, for spans outside any step
+        # (inside one, the close of a synced step is the latch: it asks
+        # every local device, too dear for every span of every step).
+        if not in_step:
+            try:
+                from . import memory
 
-            memory.note_phase(self.name, self.cat)
-        except Exception:  # noqa: BLE001 — tracing must not fail
-            pass
+                memory.note_phase(self.name, self.cat)
+            except Exception:  # noqa: BLE001 — tracing must not fail
+                pass
         return False
 
 

@@ -288,6 +288,41 @@ class TestLiveAccounting:
             "bytes_in_use": 700, "peak_bytes_in_use": 900,
             "peak_bytes_reserved": 5000, "bytes_limit": 990}
 
+    def test_the_latch_counts_what_the_chip_holds(self, monkeypatch):
+        # A v5e's allocator counts buffers (bytes_in_use) and the loaded
+        # programs' temporaries (bytes_reserved) apart; what a chip holds
+        # is the two together, on the fullest device.
+        stats = [
+            {"id": 0, "kind": "x", "bytes_in_use": 4000,
+             "peak_bytes_in_use": 6000, "bytes_reserved": 9000,
+             "peak_bytes_reserved": 9500, "bytes_limit": 16000},
+            {"id": 1, "kind": "x", "bytes_in_use": 4200,
+             "peak_bytes_in_use": 4300, "bytes_reserved": 9000,
+             "peak_bytes_reserved": 9000, "bytes_limit": 16000},
+        ]
+        monkeypatch.setattr(memory, "_device_stats_dead", False)
+        monkeypatch.setattr(memory, "local_device_memory_stats",
+                            lambda: stats)
+        assert memory.device_taken_bytes() == (13200, 15500)
+        memory.note_resident("params", 3000)
+        resident = memory.get_observatory().measured_resident()
+        assert resident == {"params": 3000, "program_temporaries": 9000}
+        assert memory.summary()["resident"]["program_temporaries"] == 9000
+        assert hvd_metrics.HBM_BYTES.labels(
+            kind="program_temporaries").get() == 9000
+        # The close of a synced step scope is the latch; an un-synced
+        # one asks no device.
+        tracing.reset_for_testing()
+        tracer = tracing.get_tracer()
+        with tracer.step_scope("hvd.step"):
+            pass
+        assert memory.get_observatory().watermarks() == {}
+        with tracer.step_scope("hvd.step") as rec:
+            rec.synced = True
+        assert memory.get_observatory().watermarks() == {"step": 15500}
+        assert memory.get_observatory().peak_bytes() == 15500
+        assert hvd_metrics.HBM_WATERMARK.labels(phase="step").get() == 15500
+
     def test_a_backend_whose_stats_call_fails_reads_as_no_stats(
             self, monkeypatch):
         # The fold feeds heartbeat payloads and OOM flight records; it

@@ -1,0 +1,79 @@
+"""Compiled for a TPU v5e that is not there (the installed libtpu compiles
+for a described topology): what only the chip's compiler decides about the
+attention kernels, checked at no chip time.
+
+XLA names a custom call's instruction after the innermost component of its
+name stack, a device trace names the event after the instruction, and the
+benchmark finds the kernels by that name (``attn_kernel_ms.json``'s
+``kernel_names``). So the name is part of the yardstick: a scope or a
+``name=`` put around a ``pallas_call`` must not change it, and which kernel
+is a forward and which a backward one is told by the scope one level up.
+
+The topology is described inside a fixture, never at import (one process
+at a time may load the TPU's library; see the on-chip-measurement guide),
+and this is the only test file that does so.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import re
+
+import pytest
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topology = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:1x1",
+            chips_per_host_bounds=(1, 1, 1))
+    except Exception as e:  # noqa: BLE001 — no libtpu here, or it is taken
+        pytest.skip(f"no v5e:1x1 topology can be described here: {e}")
+    return SingleDeviceSharding(topology.devices[0])
+
+
+def kernel_instructions(text: str) -> list:
+    """``(instruction name, op_name)`` of every Mosaic custom call."""
+    found = []
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        name = re.match(r"\s*(?:ROOT )?%?(\S+) = ", line).group(1)
+        found.append((name, re.search(r'op_name="([^"]*)"', line).group(1)))
+    return found
+
+
+@pytest.mark.parametrize("seq, kernels", [(512, 2), (1024, 3)])
+def test_the_flash_kernels_keep_the_name_the_benchmark_finds_them_by(
+        one_chip, seq, kernels):
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu import profiler
+    from horovod_tpu.ops.attention import flash_attention
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    shape = jax.ShapeDtypeStruct((2, 4, seq, 64), jnp.bfloat16,
+                                 sharding=one_chip)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        shape, shape, shape).compile().as_text()
+    found = kernel_instructions(text)
+    # One tile: a forward and a fused backward kernel; more: dq and dk/dv.
+    assert len(found) == kernels
+    with open(os.path.join(REPO_ROOT, "benchmark", "layer_metrics",
+                           "attn_kernel_ms.json")) as f:
+        wanted = re.compile(json.load(f)["kernel_names"])
+    assert all(wanted.search(name) for name, _ in found), found
+    phases = [profiler.phase_of(scope) for _, scope in found]
+    assert phases.count("hvd.attn.fwd") == 1
+    assert phases.count("hvd.attn.bwd") == kernels - 1

@@ -117,20 +117,16 @@ class TestStepTracer:
         assert names[0] == "train_step"  # the step span leads
         assert "forward" in names and "allreduce" in names
 
-    def test_ring_keeps_last_k(self, monkeypatch):
-        monkeypatch.setenv("HOROVOD_TRACE_RING_STEPS", "3")
-        tracing.reset_for_testing()
-        tr = tracing.get_tracer()
+    def test_ring_keeps_last_k(self):
+        tr = tracing.StepTracer(ring_steps=3)
         for _ in range(7):
             with tr.step_scope("train_step"):
                 pass
         steps = [s["step"] for s in tr.ring_snapshot()]
         assert steps == [5, 6, 7]
 
-    def test_span_cap_counts_drops(self, monkeypatch):
-        monkeypatch.setenv("HOROVOD_TRACE_MAX_SPANS", "4")
-        tracing.reset_for_testing()
-        tr = tracing.get_tracer()
+    def test_span_cap_counts_drops(self):
+        tr = tracing.StepTracer(max_spans=4)
         with tr.step_scope("train_step"):
             for i in range(10):
                 tr.record(f"s{i}", "phase", 0.0, 0.001)
@@ -546,8 +542,7 @@ assert runner.drain_requested()
     def test_ring_depth_covers_last_k_steps(self, tmp_path, monkeypatch):
         """The dump carries exactly the last K steps (the acceptance
         contract: a postmortem of every rank's last K steps)."""
-        monkeypatch.setenv("HOROVOD_TRACE_RING_STEPS", "4")
-        tracing.reset_for_testing()
+        tracing.reset_for_testing(tracing.StepTracer(ring_steps=4))
         ev = tmp_path / "events.jsonl"
         monkeypatch.setenv("HOROVOD_EVENT_LOG", str(ev))
         self._arm_ring(n=9)
@@ -610,7 +605,10 @@ class TestFactoryIntegration:
             assert synced and all(s % 2 == 0 for s in synced)
             spans = [e for e in srv.timeline_json()["traceEvents"]
                      if e.get("ph") == "X"]
-            assert any(e["name"] == "train_step" for e in spans)
+            # The factory's step span, with the builder's name beside it.
+            steps = [e for e in spans if e["name"] == "hvd.step"]
+            assert steps and all(
+                e["args"]["kind"] == "train_step" for e in steps)
         finally:
             srv.stop()
 
@@ -634,3 +632,205 @@ class TestFactoryIntegration:
         all_spans = [sp for s in snap for sp in s["spans"]]
         assert any(sp["name"] == "allreduce"
                    and sp["cat"] == "collective" for sp in all_spans)
+
+
+# ---------------------------------------------------------------------------
+# The factory step's own spans (hvd.step and its children)
+# ---------------------------------------------------------------------------
+
+
+def _toy_step(donate=True):
+    import numpy as np
+    import optax
+
+    import horovod_tpu as hvd
+
+    hvd.init()
+
+    def loss_fn(params, batch):
+        x, y = batch
+        return (((x @ params["w"]) - y) ** 2).mean()
+
+    opt = hvd.DistributedOptimizer(optax.sgd(0.1))
+    step = hvd.data_parallel.make_train_step(loss_fn, opt, donate=donate)
+    params = hvd.data_parallel.replicate({"w": np.ones((4, 1), np.float32)})
+    opt_state = hvd.data_parallel.replicate(opt.init(params))
+
+    def batch(rows=8):
+        return hvd.data_parallel.shard_batch(
+            (np.ones((rows, 4), np.float32), np.zeros((rows, 1), np.float32)))
+
+    return step, params, opt_state, batch
+
+
+class TestFactoryStepSpans:
+    def test_step_and_children_land_in_the_ring(self, monkeypatch):
+        from horovod_tpu import attribution
+
+        monkeypatch.setenv("HOROVOD_STALL_CHECK_STEPS", "3")
+        step, params, opt_state, batch = _toy_step()
+        for _ in range(3):
+            params, opt_state, _ = step(params, opt_state, batch())
+        records = tracing.get_tracer().ring_snapshot()
+        assert [r["kind"] for r in records] == [attribution.SPAN_STEP] * 3
+        for call, record in enumerate(records, start=1):
+            envelope, *children = record["spans"]
+            assert envelope["name"] == "hvd.step"
+            assert envelope["cat"] == "step"
+            assert envelope["step"] == record["step"]
+            assert "parent" not in envelope
+            assert envelope["args"]["kind"] == "train_step"
+            assert envelope["args"]["call"] == call
+            assert envelope["args"]["synced"] == (call == 3)
+            assert all(child["parent"] == envelope["id"]
+                       and child["step"] == record["step"]
+                       and child["cat"] == "host" for child in children)
+            names = [child["name"] for child in children]
+            assert names[0] == "hvd.step.dispatch"
+            # The watched (third) call drains, and says why.
+            assert names[1:] == (["hvd.step.drain"] if call == 3 else [])
+        drain = records[-1]["spans"][2]
+        assert drain["args"] == {"cause": "stall_watch"}
+        # The first call compiled the step: its share rides on the span.
+        share = records[0]["spans"][0]["args"]["compile"]
+        assert share["programs"] >= 1 and share["trace_s"] > 0
+        assert "compile" not in records[1]["spans"][0]["args"]
+
+    def test_self_time_is_duration_less_children(self):
+        spans = [
+            {"name": "hvd.step", "t": 10.0, "dur": 1.0, "id": 1},
+            {"name": "hvd.step.dispatch", "t": 10.1, "dur": 0.6, "id": 2,
+             "parent": 1},
+            # Overlaps its sibling by 0.1 s: counted once.
+            {"name": "hvd.step.drain", "t": 10.6, "dur": 0.3, "id": 3,
+             "parent": 1},
+            {"name": "inner", "t": 10.2, "dur": 0.25, "id": 4, "parent": 2},
+            {"name": "eager", "t": 10.15, "dur": 0.5},  # no id: no family
+        ]
+        got = tracing.self_times(spans)
+        assert got == pytest.approx([0.2, 0.35, 0.3, 0.25, 0.5])
+
+    def test_self_time_of_recorded_spans(self):
+        ticks = iter([100.0, 100.1, 100.4, 100.5, 100.7, 101.0])
+        tracer = tracing.StepTracer(tracing.ClockSync(lambda: next(ticks)))
+        with tracer.step_scope("hvd.step"):       # 100.0 ... 101.0
+            with tracer.host_span("a"):            # 100.1 ... 100.4
+                pass
+            with tracer.host_span("b"):            # 100.5 ... 100.7
+                pass
+        (record,) = tracer.ring_snapshot()
+        assert [s["name"] for s in record["spans"]] == ["hvd.step", "a", "b"]
+        assert tracing.self_times(record["spans"]) == pytest.approx(
+            [0.5, 0.3, 0.2])
+
+    def test_a_raising_step_still_closes_its_spans(self):
+        from horovod_tpu.parallel.data_parallel import _StallWatchedStep
+
+        def explode(x):
+            raise RuntimeError("boom")
+
+        wrapped = _StallWatchedStep(explode, "train_step")
+        with pytest.raises(RuntimeError, match="boom"):
+            wrapped(1)
+        tracer = tracing.get_tracer()
+        (record,) = tracer.ring_snapshot()
+        names = [span["name"] for span in record["spans"]]
+        assert names == ["hvd.step", "hvd.step.dispatch",
+                         "error:RuntimeError"]
+        assert tracer.flight_snapshot()["open_spans"] == []
+        assert "current_step" not in tracer.flight_snapshot()
+        # Nothing is left open on this thread: the next span has no parent.
+        with tracer.host_span("after"):
+            pass
+        after = tracer.ring_snapshot()[-1]["spans"][-1]
+        assert after["name"] == "after" and "parent" not in after
+
+    def test_a_compile_on_a_later_call_is_one_recompile(self, tmp_path,
+                                                        monkeypatch):
+        import horovod_tpu as hvd
+
+        events = tmp_path / "events.jsonl"
+        monkeypatch.setenv("HOROVOD_EVENT_LOG", str(events))
+        step, params, opt_state, batch = _toy_step(donate=False)
+        for _ in range(3):
+            step(params, opt_state, batch())
+        assert metrics.STEP_RECOMPILES.labels(step="train_step").get() == 0
+        step(params, opt_state, batch(rows=16))  # a shape it has not seen
+        step(params, opt_state, batch(rows=16))
+        assert metrics.STEP_RECOMPILES.labels(step="train_step").get() == 1
+        account = hvd.cache_stats()["compile"]
+        assert account["listening"] and account["programs"] >= 2
+        booked = account["steps"]["train_step"]
+        assert booked["recompiles"] == 1
+        assert booked["last_recompile"]["call"] == 4
+        assert booked["first_call"]["programs"] >= 1
+        (journaled,) = [e for e in _read_events(events)
+                        if e["event"] == "step_recompiled"]
+        assert journaled["step"] == "train_step" and journaled["call"] == 4
+        assert journaled["programs"] >= 1
+        fourth = tracing.get_tracer().ring_snapshot()[-2]["spans"][0]
+        assert fourth["args"]["compile"]["programs"] >= 1
+        monkeypatch.delenv("HOROVOD_EVENT_LOG")
+        metrics.journal()
+
+    def test_the_hot_path_reads_no_environment_variable(self, monkeypatch):
+        step, params, opt_state, batch = _toy_step()
+        one = batch()
+        for _ in range(2):  # the first call compiles and reads its fill
+            params, opt_state, _ = step(params, opt_state, one)
+        read = []
+        get = os.environ.get
+
+        def counting(key, default=None):
+            read.append(key)
+            return get(key, default)
+
+        monkeypatch.setattr(os.environ, "get", counting)
+        for _ in range(20):
+            params, opt_state, _ = step(params, opt_state, one)
+        monkeypatch.undo()
+        assert read == []
+        assert len(tracing.get_tracer().ring_snapshot()) == 8
+
+    def test_a_synced_step_latches_the_watermark_and_no_other(
+            self, monkeypatch):
+        from horovod_tpu import memory
+
+        noted = []
+        monkeypatch.setattr(memory, "note_phase",
+                            lambda name, cat=None: noted.append((name, cat)))
+        tracer = tracing.get_tracer()
+        with tracer.step_scope("hvd.step"):
+            with tracing.span("forward_backward", "phase"):
+                pass
+        assert noted == []  # un-synced, and a span inside a step
+        with tracer.step_scope("hvd.step") as rec:
+            rec.synced = True
+        assert noted == [("hvd.step", "step")]
+        with tracing.span("allreduce", "collective"):
+            pass  # outside any step: its own close is the latch
+        assert noted[-1] == ("allreduce", "collective")
+
+    def test_spans_are_profiler_annotations_with_their_arguments(
+            self, monkeypatch):
+        opened = []
+
+        class Recording:
+            def __init__(self, name, **kwargs):
+                opened.append((name, kwargs))
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(tracing, "_annotation_cls", Recording)
+        tracer = tracing.get_tracer()
+        with tracer.step_scope("hvd.step", {"kind": "train_step",
+                                            "call": 7}):
+            with tracer.host_span("hvd.step.drain", {"cause": "stall_watch"}):
+                pass
+        assert opened == [
+            ("hvd.step", {"step": 1, "kind": "train_step", "call": 7}),
+            ("hvd.step.drain", {"cause": "stall_watch"})]
