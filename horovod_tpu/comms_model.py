@@ -758,28 +758,33 @@ def prune_margin() -> float:
     return max(m, 1.0)
 
 
-def bucket_byte_sizes(leaf_sizes: Sequence[tuple[int, str]],
-                      threshold_bytes: int) -> list[int]:
-    """Total bytes per fusion bucket for a leaf layout under a candidate
+def bucket_byte_runs(leaf_sizes: Sequence[tuple[int, str]],
+                     threshold_bytes: int) -> list[list[int]]:
+    """Each fusion bucket's leaf bytes for a leaf layout under a candidate
     threshold — a faithful stdlib mirror of ``ops.fusion.bucket_leaves``
     (order-preserving greedy same-dtype packing; threshold <= 0 means
     one bucket per leaf)."""
-    buckets: list[int] = []
+    buckets: list[list[int]] = []
     bucket_dtype: str | None = None
     bucket_bytes = 0
-    first = True
     for nbytes, dtype in leaf_sizes:
         nbytes = int(nbytes)
-        if (threshold_bytes <= 0 or first or bucket_dtype != dtype
+        if (threshold_bytes <= 0 or not buckets or bucket_dtype != dtype
                 or bucket_bytes + nbytes > threshold_bytes):
-            buckets.append(nbytes)
+            buckets.append([nbytes])
             bucket_dtype = dtype
             bucket_bytes = nbytes
-            first = False
         else:
-            buckets[-1] += nbytes
+            buckets[-1].append(nbytes)
             bucket_bytes += nbytes
     return buckets
+
+
+def bucket_byte_sizes(leaf_sizes: Sequence[tuple[int, str]],
+                      threshold_bytes: int) -> list[int]:
+    """Total bytes per fusion bucket (:func:`bucket_byte_runs`)."""
+    return [sum(run) for run in bucket_byte_runs(leaf_sizes,
+                                                 threshold_bytes)]
 
 
 def segment_byte_runs(leaf_sizes: Sequence[tuple[int, str]],

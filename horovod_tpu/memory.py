@@ -59,7 +59,7 @@ import threading
 import time
 from typing import Any, Callable, Mapping, Sequence
 
-from .comms_model import bucket_byte_sizes, segment_byte_runs
+from .comms_model import bucket_byte_runs, segment_byte_runs
 from .utils.env import get_int
 
 #: Canonical resident-state kinds (`kind` label values of
@@ -373,10 +373,15 @@ def predict_footprint(
 
     Transient peaks (modeled, not exactness-tested):
 
-    - ``grad_buckets`` — 2× the largest fused gradient bucket under
-      ``threshold_bytes`` (in-flight fused buffer + collective
-      output), at ``grad_itemsize`` wire bytes per element (int8 wire
-      = 1 — ``param_sharding._wire_itemsize``).
+    - ``grad_buckets`` — 2× the largest packed vector of a gradient
+      bucket under ``threshold_bytes`` (in-flight fused buffer +
+      collective output), at ``grad_itemsize`` wire bytes per element
+      (int8 wire = 1 — ``param_sharding._wire_itemsize``). The
+      ``sharded`` / ``fsdp`` / int8 wires pack whole buckets; the flat
+      ``allreduce`` wire packs only a bucket's leaves under
+      ``ops.fusion.PACK_CUTOFF_BYTES`` and reduces the others where
+      they lie (a bucket the comms planner schedules packs whole too:
+      not modeled).
     - ``fsdp_gather`` — the largest per-segment just-in-time gather's
       full-leaf bytes (``segment_byte_runs`` over ``num_segments``,
       the stdlib mirror of ``ops.fusion.segment_leaves``).
@@ -429,10 +434,16 @@ def predict_footprint(
     wire = [(size * (int(grad_itemsize) if grad_itemsize
                      else (1 if int8 else itemsize)), dtype)
             for size, itemsize, dtype in params]
-    buckets = []
-    for run in segment_byte_runs(wire, k):
-        buckets.extend(bucket_byte_sizes(run, int(threshold_bytes)))
-    grad_buckets = 2 * max(buckets, default=0)
+    packed = [bucket for run in segment_byte_runs(wire, k)
+              for bucket in bucket_byte_runs(run, int(threshold_bytes))]
+    if mode == "allreduce" and not int8:
+        from .ops.fusion import PACK_CUTOFF_BYTES
+
+        packed = [[nbytes for nbytes in bucket if nbytes < PACK_CUTOFF_BYTES]
+                  for bucket in packed]
+        # one small leaf is reduced as itself too: no vector
+        packed = [small for small in packed if len(small) > 1]
+    grad_buckets = 2 * max(map(sum, packed), default=0)
 
     fsdp_gather = 0
     model_axis_gather = 0

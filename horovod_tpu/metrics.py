@@ -631,6 +631,11 @@ GRAD_SYNC_LAST_BYTES = gauge(
 GRAD_SYNC_LAST_BUCKETS = gauge(
     "hvd_grad_sync_last_buckets",
     "Fusion buckets of the last traced gradient flush.", ("sync_mode",))
+GRAD_SYNC_LAST_PACKED_BYTES = gauge(
+    "hvd_grad_sync_last_packed_bytes",
+    "The part of hvd_grad_sync_last_bytes that went through a bucket's "
+    "packed vector (copied in, reduced, cut back): on the flat allreduce "
+    "wire only leaves under ops.fusion.PACK_CUTOFF_BYTES.", ("sync_mode",))
 STEP_RECOMPILES = counter(
     "hvd_step_recompiles_total",
     "Calls of a factory step, after its first, in which a program was "

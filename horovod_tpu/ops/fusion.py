@@ -2,14 +2,22 @@
 
 The reference packs small tensors into a persistent 64 MiB scratch buffer at
 runtime (``horovod/common/fusion_buffer_manager.cc`` + the controller's
-``FuseResponses()``), because each NCCL launch has fixed latency. On TPU the
-same economics hold — each AllReduce HLO has fixed ICI latency — but the
-packing can happen **at trace time**: the gradient pytree is known when the
-step function is traced, so we statically group leaves into same-dtype
-buckets up to ``HOROVOD_FUSION_THRESHOLD`` bytes, emit one concat + one
-AllReduce + one split per bucket, and let XLA fuse the pack/unpack copies
-into neighboring ops (the role played by ``cuda_kernels.cu``'s batched
-memcpy kernels in the reference).
+``FuseResponses()``), because each NCCL launch has fixed latency, and sends a
+tensor over the threshold alone, uncopied. On TPU the grouping can happen
+**at trace time**: the gradient pytree is known when the step function is
+traced, so we statically group leaves into same-dtype buckets up to
+``HOROVOD_FUSION_THRESHOLD`` bytes and emit each bucket together. Inside a
+bucket only the leaves under :data:`PACK_CUTOFF_BYTES` are packed: one
+concat + one AllReduce + one split for them, and one AllReduce per larger
+leaf in its own shape, which the compiler's combiner merges with its
+neighbours into an all-reduce that takes its operands where they lie.
+
+Until PR 25 every leaf was packed, on the expectation that XLA would fuse
+the pack/unpack copies into neighboring ops (the role of
+``cuda_kernels.cu``'s batched memcpy kernels in the reference). The chip's
+trace said it does not: a flat vector and a 2-D leaf are tiled differently
+there, so every slice out of a reduced bucket is a re-tiling copy of its
+own (PERF.md §5.3).
 
 This "static negotiation" is why no background controller thread exists in
 the JAX path: readiness ordering is a dataflow fact inside the compiled
@@ -203,6 +211,10 @@ def note_finite_traced(finite, action: str, axis_name=None) -> None:
         pass
 
 
+def _wire_bytes(t) -> int:
+    return int(t.size) * jnp.dtype(t.dtype).itemsize
+
+
 def bucket_leaves(
     leaves: Sequence[Any], threshold_bytes: int | None = None
 ) -> list[list[int]]:
@@ -218,7 +230,7 @@ def bucket_leaves(
     bucket_dtype = None
     bucket_bytes = 0
     for i, leaf in enumerate(leaves):
-        nbytes = int(leaf.size) * jnp.dtype(leaf.dtype).itemsize
+        nbytes = _wire_bytes(leaf)
         if (
             threshold_bytes <= 0
             or not buckets
@@ -232,6 +244,22 @@ def bucket_leaves(
             buckets[-1].append(i)
             bucket_bytes += nbytes
     return buckets
+
+
+#: A leaf of at least this many wire bytes rides the all-reduce as itself;
+#: smaller ones share a bucket's packed vector. Packing spares a leaf a
+#: collective's fixed cost and charges it a copy in and a copy out, and on
+#: a TPU the copy out is a re-tiling (a flat vector and a 2-D leaf are
+#: tiled differently), which XLA does not fuse away: cutting 670 MB back
+#: into BERT-Large's leaves took 7.8 ms a step on four v5e chips, five
+#: times what reading and writing them once would (PERF.md §5.3). Adjacent
+#: all-reduces are merged by the compiler where they lie, so an unpacked
+#: leaf pays no fixed cost of its own either; the buffer is kept for the
+#: biases and norm vectors, kilobytes each and hundreds of them. On that
+#: cell 4 MiB (packing the 2 MiB leaves too) cost 3.3 ms a step and 8 KiB
+#: changed nothing (PERF.md §6, PR 25). Not an option: nothing about a job
+#: but its leaves' sizes decides it.
+PACK_CUTOFF_BYTES = 1 << 20
 
 
 def _note_leaf_sizes(tensors) -> None:
@@ -332,6 +360,75 @@ def _unpack_bucket(reduced, bucket, tensors, out) -> None:
             offset += n
 
 
+def _fused_allreduce(tensors, op, axis_name, threshold_bytes,
+                     prescale_factor, postscale_factor, issue_reversed,
+                     world_size):
+    """:func:`fused_allreduce`, and the wire bytes of ``tensors`` that went
+    through a packed vector (the flush gauge's count, which only the code
+    that chose each bucket's schedule can make exactly)."""
+    tensors = [jnp.asarray(t) for t in tensors]
+    from ..profiler import annotate_collective
+    from .collective_ops import Adasum, Average, Sum
+
+    if op == Adasum:
+        # Adasum's scale factors are whole-vector dot products — packing
+        # tensors into one buffer would couple per-layer factors (the
+        # reference computes them per tensor inside its fusion buffer too).
+        return [
+            _reduce_bucket(t, op, axis_name, prescale_factor, postscale_factor)
+            for t in tensors
+        ], 0
+    _note_leaf_sizes(tensors)
+    plannable = op in (Sum, Average)
+    # The two-level composition's reduce-scatter leg cuts ONE vector into
+    # the local axis's shares, so a bucket on an axis tuple stays whole.
+    hierarchical = isinstance(axis_name, (tuple, list))
+    buckets = bucket_leaves(tensors, threshold_bytes)
+    out: list[Any] = [None] * len(tensors)
+    packed_bytes = 0
+    for bi, bucket in (
+            reversed(list(enumerate(buckets))) if issue_reversed
+            else enumerate(buckets)):
+        # Annotation names carry the bucket's static wire bytes so a
+        # profile of the step attributes transfer time to sized buckets
+        # (the tracing plane's per-collective vocabulary, trace-time leg).
+        sizes = {i: _wire_bytes(tensors[i]) for i in bucket}
+        nbytes = sum(sizes.values())
+        plan = (_plan_bucket("allreduce", nbytes, axis_name, world_size)
+                if plannable else None)
+        # rhd and two_level are schedules over one flat vector too.
+        whole = plan is not None or hierarchical
+        small = [i for i in bucket
+                 if whole or sizes[i] < PACK_CUTOFF_BYTES]
+        if plan is None and len(small) == 1:
+            small = []  # a vector of one leaf would be a copy for nothing
+        in_vector = set(small)
+        alone = [i for i in bucket if i not in in_vector]
+        with annotate_collective(
+                f"allreduce.bucket{bi}.{nbytes}B{_bucket_suffix(plan)}"):
+            # Next to each other, in their own shapes: the compiler's
+            # combiner merges neighbouring all-reduces into one that takes
+            # its operands where they lie.
+            for i in (reversed(alone) if issue_reversed else alone):
+                out[i] = _reduce_bucket(
+                    tensors[i], op, axis_name, prescale_factor,
+                    postscale_factor)
+            if small:
+                flats = [tensors[i].ravel() for i in small]
+                packed = (flats[0] if len(small) == 1
+                          else jnp.concatenate(flats))
+                packed_bytes += sum(sizes[i] for i in small)
+                reduced = (
+                    _reduce_bucket(packed, op, axis_name, prescale_factor,
+                                   postscale_factor) if plan is None
+                    else _reduce_bucket_planned(
+                        packed, op, axis_name, prescale_factor,
+                        postscale_factor, plan))
+        if small:
+            _unpack_bucket(reduced, small, tensors, out)
+    return out, packed_bytes
+
+
 def fused_allreduce(
     tensors: Sequence[Any],
     op,
@@ -344,11 +441,20 @@ def fused_allreduce(
 ) -> list[Any]:
     """Allreduce a list of tensors with static bucketing (traced regime).
 
-    ``issue_reversed`` emits the bucket collectives last-bucket-first —
-    the overlap scheduler's issue order: inside a backward pass the last
-    leaves' gradients materialize first, so reverse emission puts each
-    HLO next to the point its operands become ready (results are
-    identical either way; only the program order hint changes).
+    A bucket (:func:`bucket_leaves`) is what is emitted together under one
+    ``hvd.allreduce.bucket<i>.<n>B`` scope: each leaf of at least
+    :data:`PACK_CUTOFF_BYTES` reduced as itself, in its own shape, and the
+    leaves under it concatenated into one flat vector, reduced, and cut
+    back (``hvd.wire.unpack``). A bucket the comms planner schedules, and
+    one on a hierarchical axis tuple, packs every leaf: those schedules
+    work on one vector.
+
+    ``issue_reversed`` emits the collectives last-bucket-first, and last
+    leaf first inside a bucket — the overlap scheduler's issue order:
+    inside a backward pass the last leaves' gradients materialize first,
+    so reverse emission puts each HLO next to the point its operands
+    become ready (results are identical either way; only the program
+    order hint changes).
 
     ``world_size`` (the process-set size as a static int) arms the
     comms planner: with ``HOROVOD_COMMS_PLANNER`` set and the size
@@ -357,59 +463,9 @@ def fused_allreduce(
     ICI×DCN — ``ops/comms_planner.py``); unset or unknown, every bucket
     keeps the flat emission bit-for-bit.
     """
-    tensors = [jnp.asarray(t) for t in tensors]
-    from ..profiler import annotate_collective
-    from .collective_ops import Adasum, Average, Sum
-
-    if op == Adasum:
-        # Adasum's scale factors are whole-vector dot products — packing
-        # tensors into one buffer would couple per-layer factors (the
-        # reference computes them per tensor inside its fusion buffer too).
-        return [
-            _reduce_bucket(t, op, axis_name, prescale_factor, postscale_factor)
-            for t in tensors
-        ]
-    _note_leaf_sizes(tensors)
-    plannable = op in (Sum, Average)
-    buckets = bucket_leaves(tensors, threshold_bytes)
-    out: list[Any] = [None] * len(tensors)
-    for bi, bucket in (
-            reversed(list(enumerate(buckets))) if issue_reversed
-            else enumerate(buckets)):
-        # Annotation names carry the bucket's static wire bytes so a
-        # profile of the step attributes transfer time to sized buckets
-        # (the tracing plane's per-collective vocabulary, trace-time leg).
-        nbytes = sum(int(tensors[i].size)
-                     * jnp.dtype(tensors[i].dtype).itemsize for i in bucket)
-        plan = (_plan_bucket("allreduce", nbytes, axis_name, world_size)
-                if plannable else None)
-        if plan is not None:
-            flats = [tensors[i].ravel() for i in bucket]
-            with annotate_collective(
-                    f"allreduce.bucket{bi}.{nbytes}B{_bucket_suffix(plan)}"):
-                packed = (flats[0] if len(bucket) == 1
-                          else jnp.concatenate(flats))
-                reduced = _reduce_bucket_planned(
-                    packed, op, axis_name, prescale_factor,
-                    postscale_factor, plan)
-            _unpack_bucket(reduced, bucket, tensors, out)
-            continue
-        if len(bucket) == 1:
-            i = bucket[0]
-            with annotate_collective(f"allreduce.bucket{bi}.{nbytes}B"):
-                out[i] = _reduce_bucket(
-                    tensors[i], op, axis_name, prescale_factor,
-                    postscale_factor
-                )
-            continue
-        flats = [tensors[i].ravel() for i in bucket]
-        with annotate_collective(f"allreduce.bucket{bi}.{nbytes}B"):
-            packed = jnp.concatenate(flats)
-            reduced = _reduce_bucket(
-                packed, op, axis_name, prescale_factor, postscale_factor
-            )
-        _unpack_bucket(reduced, bucket, tensors, out)
-    return out
+    return _fused_allreduce(
+        tensors, op, axis_name, threshold_bytes, prescale_factor,
+        postscale_factor, issue_reversed, world_size)[0]
 
 
 def fused_allreduce_pytree(
@@ -549,8 +605,7 @@ def fused_reducescatter(
             reversed(list(enumerate(buckets))) if issue_reversed
             else enumerate(buckets)):
         bucket_sizes = [sizes[i] for i in bucket]
-        nbytes = sum(int(tensors[i].size)
-                     * jnp.dtype(tensors[i].dtype).itemsize for i in bucket)
+        nbytes = sum(_wire_bytes(tensors[i]) for i in bucket)
         plan = _plan_bucket("reducescatter", nbytes, axis_name, n)
         with annotate_collective(
                 f"reducescatter.bucket{bi}.{nbytes}B{_bucket_suffix(plan)}"):

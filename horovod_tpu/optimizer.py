@@ -43,7 +43,7 @@ from .attribution import (
 from .compression import Compression
 from .exceptions import SyncModeIneligibleError
 from .ops import collective_ops
-from .ops.fusion import fused_allreduce
+from .ops.fusion import _fused_allreduce
 
 
 def _tripwire_flag(reduced, axis_name=None, rank_identical=True):
@@ -79,7 +79,8 @@ def _tripwire_guard(action, flag, updates, new_state, old_state):
 
 
 def _record_flush(sync_mode: str, wire_leaves, threshold_bytes,
-                  itemsize_override: int | None = None) -> None:
+                  itemsize_override: int | None = None,
+                  packed_bytes: int | None = None) -> None:
     """Metrics-plane instrumentation of a gradient-sync flush.
 
     Runs at TRACE time (the flush is traced machinery), so the counters
@@ -89,8 +90,11 @@ def _record_flush(sync_mode: str, wire_leaves, threshold_bytes,
     static under tracing, so sizes are exact. ``itemsize_override``
     keeps the bytes histogram honest for exchanges whose wire dtype is
     not the leaves' dtype (int8: the leaves passed in are the f32
-    bucketing view, but the wire carries 1 byte/element). Never raises:
-    observability must not break tracing."""
+    bucketing view, but the wire carries 1 byte/element).
+    ``packed_bytes`` is the part of those bytes that went through a
+    bucket's packed vector; left out, all of them did (every wire but the
+    flat allreduce packs whole buckets). Never raises: observability must
+    not break tracing."""
     try:
         from . import metrics
         from .ops.fusion import bucket_leaves
@@ -106,6 +110,9 @@ def _record_flush(sync_mode: str, wire_leaves, threshold_bytes,
         metrics.GRAD_SYNC_BUCKETS.observe(nbuckets, sync_mode=sync_mode)
         metrics.GRAD_SYNC_LAST_BYTES.set(nbytes, sync_mode=sync_mode)
         metrics.GRAD_SYNC_LAST_BUCKETS.set(nbuckets, sync_mode=sync_mode)
+        metrics.GRAD_SYNC_LAST_PACKED_BYTES.set(
+            nbytes if packed_bytes is None else packed_bytes,
+            sync_mode=sync_mode)
     except Exception:  # noqa: BLE001 — instrumentation is best-effort
         pass
 
@@ -227,17 +234,11 @@ def _reduce_grads(
             total = sum(int(w.size) * jnp.dtype(w.dtype).itemsize
                         for w in wire)
             threshold_bytes = max(1, total // num_groups)
-        _record_flush("allreduce", wire, threshold_bytes)
-        reduced = fused_allreduce(
-            wire,
-            op=op,
-            axis_name=axis_name,
-            threshold_bytes=threshold_bytes,
-            prescale_factor=prescale_factor,
-            postscale_factor=postscale_factor,
-            issue_reversed=issue_reversed,
-            world_size=world_size,
-        )
+        reduced, packed_bytes = _fused_allreduce(
+            wire, op, axis_name, threshold_bytes, prescale_factor,
+            postscale_factor, issue_reversed, world_size)
+        _record_flush("allreduce", wire, threshold_bytes,
+                      packed_bytes=packed_bytes)
         return jax.tree.unflatten(
             treedef, _decompress_leaves(compression, reduced, ctxs))
 

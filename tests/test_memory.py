@@ -184,6 +184,26 @@ class TestExactness:
         assert t["grad_buckets"] > 0
         assert fp["peak_total"] == fp["resident_total"] + max(t.values())
 
+    @pytest.mark.parametrize("mode, int8, leaves, packed", [
+        # the flat wire packs only the leaves under the cutoff
+        ("allreduce", False, 3, 4000 + 400),
+        # a bucket's one small leaf is reduced as itself: nothing packed
+        ("allreduce", False, 2, 0),
+        ("allreduce", True, 3, (2 << 20) + 1100),  # int8: whole, 1 B each
+        ("sharded", False, 3, 4 * ((2 << 20) + 1100)),
+    ])
+    def test_grad_buckets_prices_what_is_still_packed(self, mode, int8,
+                                                      leaves, packed):
+        from horovod_tpu.ops.fusion import PACK_CUTOFF_BYTES
+
+        layout = [(2 << 20, 4, "float32"), (1000, 4, "float32"),
+                  (100, 4, "float32")][:leaves]
+        assert layout[0][0] * 4 >= PACK_CUTOFF_BYTES > layout[1][0] * 4
+        fp = memory.predict_footprint(
+            layout, sync_mode=mode, world_size=4, opt_templates=[],
+            int8=int8)
+        assert fp["transient"]["grad_buckets"] == 2 * packed
+
     def test_capacity_headroom(self):
         base = memory.predict_footprint([(100, 4, "float32")],
                                         world_size=1, opt_templates=[])
@@ -525,7 +545,10 @@ class TestOomForensics:
 
 
 class TestAutotuneGuard:
-    LAYOUT = [(1 << 20, 4, "float32")]  # 4 MB of float32 params
+    # 256 MB of float32 params in 4 MB leaves: what allreduce and sharded
+    # keep whole outweighs the whole buckets the fsdp wire packs (the flat
+    # allreduce wire packs no leaf this large, so it has no such term).
+    LAYOUT = [(1 << 20, 4, "float32")] * 64
 
     def _note_layout(self):
         memory.get_observatory().note_layout(self.LAYOUT)
