@@ -106,8 +106,17 @@ SCOPE_WIRE_DECOMPRESS = "wire.decompress"
 SCOPE_OPTIMIZER = "optimizer"
 SCOPE_ATTN_FWD = "attn.fwd"
 SCOPE_ATTN_BWD = "attn.bwd"
+#: A mixture-of-experts layer (``parallel/moe.py``): the router's picks and
+#: slots, the tokens' way into the slots, the grouped expert matmuls, and
+#: the way back. The backward pass's operations carry the same scopes.
+SCOPE_MOE_ROUTE = "moe.route"
+SCOPE_MOE_DISPATCH = "moe.dispatch"
+SCOPE_MOE_EXPERTS = "moe.experts"
+SCOPE_MOE_COMBINE = "moe.combine"
 PHASE_SCOPE_NAMES = tuple(SCOPE_PREFIX + name for name in (
-    SCOPE_WIRE, SCOPE_OPTIMIZER, SCOPE_ATTN_FWD, SCOPE_ATTN_BWD))
+    SCOPE_WIRE, SCOPE_OPTIMIZER, SCOPE_ATTN_FWD, SCOPE_ATTN_BWD,
+    SCOPE_MOE_ROUTE, SCOPE_MOE_DISPATCH, SCOPE_MOE_EXPERTS,
+    SCOPE_MOE_COMBINE))
 
 #: Span categories. ``phase``-cat spans are host-observable compute
 #: segments; ``collective``-cat spans are communication; the ``step``

@@ -898,6 +898,12 @@ MOE_EXPERT_LOAD = gauge(
     "Tokens routed to each expert in the last observed MoE step (this "
     "rank's routing view) — the imbalance the skew attribution chases.",
     ("expert",))
+MOE_SLOTS_LAST = gauge(
+    "hvd_moe_slots_last",
+    "Expert slots (experts_here x capacity) one routing group of the LAST "
+    "traced top-k MoE layer computes, occupied or not: set at trace time, "
+    "as hvd_grad_sync_last_bytes is.",
+    ("experts_here", "capacity", "top_k"))
 ALLTOALL_LATENCY = histogram(
     "hvd_alltoall_latency_seconds",
     "Wall time of alltoall exchanges (eager dispatches and MoE "

@@ -8,3 +8,11 @@ from .bert import (  # noqa: F401
     BertConfig,
     mlm_loss,
 )
+from .olmoe import (  # noqa: F401
+    OLMOE_1B_7B,
+    OLMOE_TINY,
+    Olmoe,
+    OlmoeConfig,
+    causal_lm_loss,
+    routing_stats,
+)

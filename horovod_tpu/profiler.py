@@ -223,12 +223,15 @@ def instruction_scopes(hlo_text: str) -> dict[str, str]:
 
 def phase_of(scope: str) -> str | None:
     """The innermost phase scope (``hvd.wire``, ``hvd.optimizer``,
-    ``hvd.attn.fwd``, ``hvd.attn.bwd``) among the components of a name
-    stack, wherever it sits (the overlapped step's wire is under
-    ``transpose``), or None."""
+    ``hvd.attn.fwd``, ``hvd.attn.bwd``, ``hvd.moe.*``) among the
+    components of a name stack, wherever it sits (the overlapped step's
+    wire is under ``transpose``), or None. A transformation wraps the
+    outermost name of what it transforms (``vmap(hvd.moe.route)``,
+    ``transpose(jvp(hvd.moe.experts))``): the name inside is the scope."""
     from .attribution import PHASE_SCOPE_NAMES
 
     for part in reversed(scope.split("/")):
+        part = part.rsplit("(", 1)[-1].rstrip(")")
         if part in PHASE_SCOPE_NAMES:
             return part
     return None

@@ -186,6 +186,13 @@ def test_a_text_without_a_phase_scope_is_refused():
      "flash_attention/pallas_call", "hvd.attn.fwd"),
     ("jit(s)/jvp(Bert)/layer_0/dot_general", None),
     ("jit(s)/hvd.optimizer_like/mul", None),
+    # a transformation wraps the outermost name of what it transforms
+    ("jit(s)/jvp(Olmoe)/layer_0/moe/vmap(hvd.moe.route)/dot_general",
+     "hvd.moe.route"),
+    ("jit(s)/transpose(jvp(hvd.moe.experts))/dot_general", "hvd.moe.experts"),
+    ("jit(s)/transpose(jvp(Olmoe))/layer_0/moe/vmap(hvd.moe.combine)/"
+     "scatter-add", "hvd.moe.combine"),
+    ("jit(s)/vmap(hvd.moe.routes)/add", None),
 ])
 def test_phase_of_takes_the_innermost_phase_scope(scope, phase):
     assert profiler.phase_of(scope) == phase

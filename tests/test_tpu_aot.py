@@ -77,3 +77,31 @@ def test_the_flash_kernels_keep_the_name_the_benchmark_finds_them_by(
     phases = [profiler.phase_of(scope) for _, scope in found]
     assert phases.count("hvd.attn.fwd") == 1
     assert phases.count("hvd.attn.bwd") == kernels - 1
+
+
+def test_olmoes_causal_kernels_compile_at_its_widths(one_chip):
+    """One sequence of 4,096 in 16 heads of 128, causal: a grid of 16 x 8
+    x 8 tiles of 512 through the multi-tile forward, dq and dkv kernels,
+    found by the name the OLMoE cell's readers look for."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu import profiler
+    from horovod_tpu.models import olmoe
+
+    def loss(q, k, v):
+        out = olmoe.flash_attention_fn(q, k, v, jnp.bfloat16)
+        return out.astype(jnp.float32).sum()
+
+    shape = jax.ShapeDtypeStruct((1, 4096, 16, 128), jnp.bfloat16,
+                                 sharding=one_chip)
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        shape, shape, shape).compile().as_text()
+    found = kernel_instructions(text)
+    assert len(found) == 3
+    with open(os.path.join(REPO_ROOT, "benchmark", "layer_metrics",
+                           "causal_attn_kernel_ms.json")) as f:
+        wanted = re.compile(json.load(f)["kernel_names"])
+    assert all(wanted.search(name) for name, _ in found), found
+    phases = [profiler.phase_of(scope) for _, scope in found]
+    assert sorted(phases) == ["hvd.attn.bwd", "hvd.attn.bwd", "hvd.attn.fwd"]

@@ -4,8 +4,9 @@
 
 ``flash_attention`` forward and backward in bf16 against
 ``blockwise_attention_reference`` in float32 at the single-tile shape
-BERT-Large uses (S512 D64) and at a multi-tile causal shape (S2048 D128),
-then ``ops.conv_backward.dw_1x1`` against the matmul it replaces. Compiled,
+BERT-Large uses (S512 D64), at a multi-tile causal shape (S2048 D128)
+and at OLMoE's (one sequence of S4096, 16 heads of D128: a grid of 16 x 8 x 8
+tiles of 512), then ``ops.conv_backward.dw_1x1`` against the matmul it replaces. Compiled,
 never ``interpret=True``: off a TPU this exits non-zero.
 
 The tolerance is the one ``tests/test_sequence_parallel.py`` uses for bf16
@@ -106,6 +107,7 @@ def main() -> None:
           f"count={len(jax.devices())}")
     check_flash(4, 16, 512, 64, causal=False)
     check_flash(2, 4, 2048, 128, causal=True)
+    check_flash(1, 16, 4096, 128, causal=True)
     check_dw_1x1()
     print("kernels ok")
 
