@@ -904,6 +904,13 @@ MOE_SLOTS_LAST = gauge(
     "traced top-k MoE layer computes, occupied or not: set at trace time, "
     "as hvd_grad_sync_last_bytes is.",
     ("experts_here", "capacity", "top_k"))
+ATTN_TILES_LAST = gauge(
+    "hvd_attn_tiles_last",
+    "(q, k) tile pairs a (batch x head) slice of the LAST traced multi-tile "
+    "flash-attention call computes and skips (a causal call skips the "
+    "tiles its mask leaves nothing of): set at trace time, as "
+    "hvd_grad_sync_last_bytes is.",
+    ("kind",))
 ALLTOALL_LATENCY = histogram(
     "hvd_alltoall_latency_seconds",
     "Wall time of alltoall exchanges (eager dispatches and MoE "

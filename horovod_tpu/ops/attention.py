@@ -9,10 +9,14 @@ recipes (PAPERS.md): tile K/V, keep running max ``m``, normalizer ``l`` and
 un-normalized output ``o`` in fp32, rescale on each new tile.
 
 Two implementations, one semantics:
-- ``flash_attention``: Pallas TPU kernel (MXU-tiled, fp32 accumulators in
-  VMEM scratch, grid over (batch*heads, Q blocks)); ``interpret=True`` makes
-  it runnable on the CPU dev mesh. Differentiable: a ``jax.custom_vjp``
-  supplies Pallas backward kernels (dq and dk/dv) from saved
+- ``flash_attention``: Pallas TPU kernels (MXU-tiled, fp32 accumulators in
+  VMEM scratch); ``interpret=True`` makes them runnable on the CPU dev
+  mesh. A sequence that is one tile runs a grid of (batch*heads,) slices:
+  a direct-softmax forward and one fused backward kernel. Longer ones run
+  (batch*heads, Q blocks, K blocks) with K innermost in the forward and
+  the dq kernel and (batch*heads, K blocks, Q blocks) with Q innermost in
+  the dk/dv kernel, one K/V (or Q/dO) tile resident a step. Differentiable:
+  a ``jax.custom_vjp`` supplies the backward kernels from saved
   (out, logsumexp) residuals, so ring attention trains end-to-end.
 - ``blockwise_attention_reference``: pure-jnp same math; the numerics
   oracle in tests. The kernel requires block-divisible sequence lengths
@@ -23,7 +27,13 @@ Causal masking uses GLOBAL positions: ``q_offset``/``k_offset`` give the
 global position of element 0 of the Q/K sequences. With ``Sq != Sk`` and
 both offsets 0 the intended alignment is ambiguous (top-left vs the
 decode-style bottom-right), so ``flash_attention`` raises and asks for
-explicit offsets rather than silently picking one.
+explicit offsets rather than silently picking one. The multi-tile causal
+kernels tell from a grid step's block indices and the static offsets
+whether the mask leaves anything of its tile (``_tile_visible``): a tile
+it leaves nothing of is neither computed nor fetched. The grid itself
+stays whole, so a skipped tile still costs its (empty) grid step; the
+gauge ``hvd_attn_tiles_last{kind}`` says how many a call computes and
+skips.
 """
 
 from __future__ import annotations
@@ -146,6 +156,48 @@ def _causal_mask(qi, j, block_q, block_k, q_offset, k_offset):
     return qpos >= kpos
 
 
+def _tile_visible(qi, kj, block_q, block_k, q_offset, k_offset):
+    """Whether the causal mask leaves anything of tile (q block ``qi``, k
+    block ``kj``): its last query is at or after its first key. The
+    multi-tile causal kernels skip every other tile, where what the masked
+    step adds to its accumulators is exactly zero. Plain arithmetic, so
+    Python ints, numpy grids and traced program ids all do."""
+    return q_offset + (qi + 1) * block_q - 1 >= k_offset + kj * block_k
+
+
+def _last_k_block(qi, num_kb, block_q, block_k, q_offset, k_offset):
+    """The last k block of which q block ``qi`` sees anything, clamped into
+    the grid: the index maps of the K-innermost calls stop there, so the
+    steps past it name a block that is already resident and fetch nothing."""
+    last = (q_offset - k_offset + (qi + 1) * block_q - 1) // block_k
+    return jnp.clip(last, 0, num_kb - 1)
+
+
+def _first_q_block(kj, num_qb, block_q, block_k, q_offset, k_offset):
+    """The first q block that sees anything of k block ``kj``, clamped into
+    the grid: ``_last_k_block``'s mirror for the Q-innermost dk/dv call."""
+    first = (k_offset - q_offset + kj * block_k) // block_q
+    return jnp.clip(first, 0, num_qb - 1)
+
+
+def _record_tiles(causal, num_qb, num_kb, block_q, block_k, q_offset,
+                  k_offset) -> None:
+    """At trace time, as ``optimizer._record_flush`` does for the wire:
+    the (q, k) tile pairs a slice of this multi-tile call computes and
+    skips, by the kernels' own predicate over the grid."""
+    import numpy as np
+
+    from .. import metrics
+
+    computed = num_qb * num_kb
+    if causal:
+        computed = int(_tile_visible(
+            np.arange(num_qb)[:, None], np.arange(num_kb)[None, :], block_q,
+            block_k, q_offset, k_offset).sum())
+    metrics.ATTN_TILES_LAST.set(computed, kind="computed")
+    metrics.ATTN_TILES_LAST.set(num_qb * num_kb - computed, kind="skipped")
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                       acc_scr, *, causal: bool, scale: float, block_q: int,
                       block_k: int, q_offset: int, k_offset: int):
@@ -162,36 +214,42 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0]       # [block_q, D]
-    k_tile = k_ref[0]  # [block_k, D]
-    v_tile = v_ref[0]
-    # Matmuls take the STORED dtype (bf16 in production) with f32 MXU
-    # accumulation — upcasting bf16 operands to f32 first adds no
-    # precision (they were already rounded) and runs the MXU at 1/4
-    # rate; this one change moved BERT-Large flash fwd+bwd ~2x.
-    s = jax.lax.dot_general(
-        q, k_tile,
-        (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale  # [block_q, block_k]
-    if causal:
-        mask = _causal_mask(qi, j, block_q, block_k, q_offset, k_offset)
-        s = jnp.where(mask, s, NEG_INF)
-    m_prev = m_scr[:, 0]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    corr = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
-    if causal:
-        p = jnp.where(mask, p, 0.0)
-    l_scr[:, 0] = l_scr[:, 0] * corr + p.sum(axis=-1)
-    # P rounds to the value dtype for the MXU pass (the standard flash
-    # trade: probabilities in bf16, accumulation in f32).
-    acc_scr[:] = acc_scr[:] * corr[:, None] + jax.lax.dot_general(
-        p.astype(v_tile.dtype), v_tile,
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_scr[:, 0] = m_new
+    # A causal call computes only the tiles its mask leaves something of.
+    # _init and _finalize_block stay outside: the last k tiles of a q block
+    # are the skipped ones, and it still has to write its output.
+    @pl.when(_tile_visible(qi, j, block_q, block_k, q_offset, k_offset)
+             if causal else True)
+    def _step():
+        q = q_ref[0]       # [block_q, D]
+        k_tile = k_ref[0]  # [block_k, D]
+        v_tile = v_ref[0]
+        # Matmuls take the STORED dtype (bf16 in production) with f32 MXU
+        # accumulation — upcasting bf16 operands to f32 first adds no
+        # precision (they were already rounded) and runs the MXU at 1/4
+        # rate; this one change moved BERT-Large flash fwd+bwd ~2x.
+        s = jax.lax.dot_general(
+            q, k_tile,
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [block_q, block_k]
+        if causal:
+            mask = _causal_mask(qi, j, block_q, block_k, q_offset, k_offset)
+            s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_scr[:, 0]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new[:, None])
+        if causal:
+            p = jnp.where(mask, p, 0.0)
+        l_scr[:, 0] = l_scr[:, 0] * corr + p.sum(axis=-1)
+        # P rounds to the value dtype for the MXU pass (the standard flash
+        # trade: probabilities in bf16, accumulation in f32).
+        acc_scr[:] = acc_scr[:] * corr[:, None] + jax.lax.dot_general(
+            p.astype(v_tile.dtype), v_tile,
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_scr[:, 0] = m_new
 
     @pl.when(j == num_kb - 1)
     def _finalize_block():
@@ -226,34 +284,38 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    # Stored-dtype (bf16) matmul operands with f32 MXU accumulation —
-    # see the forward kernel's note; f32 upcasts quartered throughput.
-    q = q_ref[0]
-    k_tile = k_ref[0]
-    v_tile = v_ref[0]
-    do = do_ref[0]
-    # lse/delta blocks are full rows [1, Sq] (TPU tiling); slice our q tile.
-    lse = lse_ref[0, 0, pl.ds(qi * block_q, block_q)]
-    delta = delta_ref[0, 0, pl.ds(qi * block_q, block_q)]
-    glse = glse_ref[0, 0, pl.ds(qi * block_q, block_q)]
+    @pl.when(_tile_visible(qi, j, block_q, block_k, q_offset, k_offset)
+             if causal else True)
+    def _step():
+        # Stored-dtype (bf16) matmul operands with f32 MXU accumulation —
+        # see the forward kernel's note; f32 upcasts quartered throughput.
+        q = q_ref[0]
+        k_tile = k_ref[0]
+        v_tile = v_ref[0]
+        do = do_ref[0]
+        # lse/delta blocks are full rows [1, Sq] (TPU tiling); slice our q
+        # tile.
+        lse = lse_ref[0, 0, pl.ds(qi * block_q, block_q)]
+        delta = delta_ref[0, 0, pl.ds(qi * block_q, block_q)]
+        glse = glse_ref[0, 0, pl.ds(qi * block_q, block_q)]
 
-    s = jax.lax.dot_general(
-        q, k_tile, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale
-    if causal:
-        mask = _causal_mask(qi, j, block_q, block_k, q_offset, k_offset)
-        s = jnp.where(mask, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])
-    dp = jax.lax.dot_general(
-        do, v_tile, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    ds = p * (dp - delta[:, None] + glse[:, None])
-    dq_scr[:] = dq_scr[:] + scale * jax.lax.dot_general(
-        ds.astype(k_tile.dtype), k_tile, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+        s = jax.lax.dot_general(
+            q, k_tile, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale
+        if causal:
+            mask = _causal_mask(qi, j, block_q, block_k, q_offset, k_offset)
+            s = jnp.where(mask, s, NEG_INF)
+        p = jnp.exp(s - lse[:, None])
+        dp = jax.lax.dot_general(
+            do, v_tile, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        ds = p * (dp - delta[:, None] + glse[:, None])
+        dq_scr[:] = dq_scr[:] + scale * jax.lax.dot_general(
+            ds.astype(k_tile.dtype), k_tile, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
     @pl.when(j == num_kb - 1)
     def _write():
@@ -278,39 +340,44 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    # Stored-dtype (bf16) matmul operands with f32 MXU accumulation —
-    # see the forward kernel's note; f32 upcasts quartered throughput.
-    q = q_ref[0]
-    k_tile = k_ref[0]
-    v_tile = v_ref[0]
-    do = do_ref[0]
-    lse = lse_ref[0, 0, pl.ds(i * block_q, block_q)]
-    delta = delta_ref[0, 0, pl.ds(i * block_q, block_q)]
-    glse = glse_ref[0, 0, pl.ds(i * block_q, block_q)]
+    # The first q tiles of a k block are the skipped ones: _init zeroes
+    # dk_scr / dv_scr all the same.
+    @pl.when(_tile_visible(i, kj, block_q, block_k, q_offset, k_offset)
+             if causal else True)
+    def _step():
+        # Stored-dtype (bf16) matmul operands with f32 MXU accumulation —
+        # see the forward kernel's note; f32 upcasts quartered throughput.
+        q = q_ref[0]
+        k_tile = k_ref[0]
+        v_tile = v_ref[0]
+        do = do_ref[0]
+        lse = lse_ref[0, 0, pl.ds(i * block_q, block_q)]
+        delta = delta_ref[0, 0, pl.ds(i * block_q, block_q)]
+        glse = glse_ref[0, 0, pl.ds(i * block_q, block_q)]
 
-    s = jax.lax.dot_general(
-        q, k_tile, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale  # [block_q, block_k]
-    if causal:
-        mask = _causal_mask(i, kj, block_q, block_k, q_offset, k_offset)
-        s = jnp.where(mask, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])  # [block_q, block_k]
-    # dV_j += P^T @ dO (P rounds to the stored dtype for the MXU pass)
-    dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-        p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    dp = jax.lax.dot_general(
-        do, v_tile, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    ds = p * (dp - delta[:, None] + glse[:, None])
-    # dK_j += scale * dS^T @ Q
-    dk_scr[:] = dk_scr[:] + scale * jax.lax.dot_general(
-        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+        s = jax.lax.dot_general(
+            q, k_tile, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [block_q, block_k]
+        if causal:
+            mask = _causal_mask(i, kj, block_q, block_k, q_offset, k_offset)
+            s = jnp.where(mask, s, NEG_INF)
+        p = jnp.exp(s - lse[:, None])  # [block_q, block_k]
+        # dV_j += P^T @ dO (P rounds to the stored dtype for the MXU pass)
+        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dp = jax.lax.dot_general(
+            do, v_tile, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        ds = p * (dp - delta[:, None] + glse[:, None])
+        # dK_j += scale * dS^T @ Q
+        dk_scr[:] = dk_scr[:] + scale * jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
 
     @pl.when(i == num_qb - 1)
     def _write():
@@ -411,6 +478,28 @@ def _fwd_call(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
                             k_offset, interpret)
 
 
+def _kv_index_map(causal, num_kb, block_q, block_k, q_offset, k_offset):
+    """K/V block of grid step (bh, q block i, k block j), K innermost. A
+    causal call stops at the last tile q block ``i`` computes: a
+    ``pl.when`` alone would still have the pipeline fetch the skipped
+    steps' blocks, and a block index that does not change fetches nothing.
+    """
+    if not causal:
+        return lambda bh, i, j: (bh, j, 0)
+    return lambda bh, i, j: (bh, jnp.minimum(j, _last_k_block(
+        i, num_kb, block_q, block_k, q_offset, k_offset)), 0)
+
+
+def _q_index_map(causal, num_qb, block_q, block_k, q_offset, k_offset):
+    """Q-side block (q, dO) of grid step (bh, k block j, q block i), Q
+    innermost: a causal call starts at the first tile k block ``j``
+    computes (``_kv_index_map``'s mirror)."""
+    if not causal:
+        return lambda bh, j, i: (bh, i, 0)
+    return lambda bh, j, i: (bh, jnp.maximum(i, _first_q_block(
+        j, num_qb, block_q, block_k, q_offset, k_offset)), 0)
+
+
 def _fwd_kernels(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
                  interpret):
     BH, Sq, D = qr.shape
@@ -446,13 +535,19 @@ def _fwd_kernels(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
         block_q=block_q, block_k=block_k,
         q_offset=q_offset, k_offset=k_offset,
     )
+    num_qb, num_kb = Sq // block_q, Sk // block_k
+    _record_tiles(causal, num_qb, num_kb, block_q, block_k, q_offset,
+                  k_offset)
+    kv_spec = pl.BlockSpec((1, block_k, D),
+                           _kv_index_map(causal, num_kb, block_q, block_k,
+                                         q_offset, k_offset))
     return pl.pallas_call(
         kernel,
-        grid=(BH, Sq // block_q, Sk // block_k),
+        grid=(BH, num_qb, num_kb),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, i, j: (bh, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, i, j: (bh, j, 0)),
+            kv_spec,
+            kv_spec,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
@@ -533,10 +628,16 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
         )(qr, kr, vr, do, lse, delta, g_lse)
         return dq, dk, dv
 
+    num_qb, num_kb = Sq // block_q, Sk // block_k
+    _record_tiles(causal, num_qb, num_kb, block_q, block_k, q_offset,
+                  k_offset)
+    kv_spec = pl.BlockSpec((1, block_k, D),
+                           _kv_index_map(causal, num_kb, block_q, block_k,
+                                         q_offset, k_offset))
     q_specs = [
         pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
-        pl.BlockSpec((1, block_k, D), lambda bh, i, j: (bh, j, 0)),
-        pl.BlockSpec((1, block_k, D), lambda bh, i, j: (bh, j, 0)),
+        kv_spec,
+        kv_spec,
         pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
         pl.BlockSpec((1, 1, Sq), lambda bh, i, j: (bh, 0, 0)),
         pl.BlockSpec((1, 1, Sq), lambda bh, i, j: (bh, 0, 0)),
@@ -547,7 +648,7 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
             _flash_dq_kernel, causal=causal, scale=scale, block_q=block_q,
             block_k=block_k, q_offset=q_offset, k_offset=k_offset,
         ),
-        grid=(BH, Sq // block_q, Sk // block_k),
+        grid=(BH, num_qb, num_kb),
         in_specs=q_specs,
         out_specs=pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), qr.dtype),
@@ -556,11 +657,14 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
         name=KERNEL_NAME,
     )(qr, kr, vr, do, lse, delta, g_lse)
 
+    q_spec = pl.BlockSpec((1, block_q, D),
+                          _q_index_map(causal, num_qb, block_q, block_k,
+                                       q_offset, k_offset))
     kv_specs = [
-        pl.BlockSpec((1, block_q, D), lambda bh, j, i: (bh, i, 0)),
+        q_spec,
         pl.BlockSpec((1, block_k, D), lambda bh, j, i: (bh, j, 0)),
         pl.BlockSpec((1, block_k, D), lambda bh, j, i: (bh, j, 0)),
-        pl.BlockSpec((1, block_q, D), lambda bh, j, i: (bh, i, 0)),
+        q_spec,
         pl.BlockSpec((1, 1, Sq), lambda bh, j, i: (bh, 0, 0)),
         pl.BlockSpec((1, 1, Sq), lambda bh, j, i: (bh, 0, 0)),
         pl.BlockSpec((1, 1, Sq), lambda bh, j, i: (bh, 0, 0)),
@@ -570,7 +674,7 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
             _flash_dkv_kernel, causal=causal, scale=scale, block_q=block_q,
             block_k=block_k, q_offset=q_offset, k_offset=k_offset,
         ),
-        grid=(BH, Sk // block_k, Sq // block_q),
+        grid=(BH, num_kb, num_qb),
         in_specs=kv_specs,
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda bh, j, i: (bh, j, 0)),

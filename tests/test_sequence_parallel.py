@@ -158,6 +158,171 @@ class TestFlashKernel:
             np.testing.assert_allclose(np.asarray(g), 0.0)
 
 
+# (Sq, Sk, block_q, block_k, q_offset, k_offset): what the multi-tile
+# causal kernels must get right now that they skip the tiles the mask
+# leaves nothing of and no longer fetch them.
+CAUSAL_TILE_CASES = [
+    pytest.param(256, 256, 32, 32, 0, 0, id="8x8-empty-last-and-first"),
+    pytest.param(256, 256, 64, 32, 0, 0, id="wide-q-blocks"),
+    pytest.param(256, 256, 32, 64, 0, 0, id="wide-k-blocks"),
+    pytest.param(128, 256, 32, 64, 128, 0, id="decode-aligned-Sq-lt-Sk"),
+    pytest.param(256, 128, 64, 32, 0, 64, id="empty-q-block"),
+    pytest.param(128, 256, 32, 32, 0, 96, id="empty-k-blocks"),
+    pytest.param(128, 128, 32, 32, 128, 0, id="every-tile-full"),
+    pytest.param(128, 256, 32, 32, 37, 5, id="unaligned-offsets"),
+    # the predicate's edge: a tile's last query IS its first key
+    pytest.param(128, 128, 32, 32, 0, 31, id="one-element-visible"),
+]
+
+
+def dense_causal_with_lse(q, k, v, q_offset, k_offset):
+    """Dense float32 causal attention and its per-row logsumexp at global
+    positions; a row that sees no key gives 0 and an lse of 0 (the kernel's
+    sentinel there is a constant: no gradient either way)."""
+    D = q.shape[-1]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / (D ** 0.5)
+    qpos = q_offset + jnp.arange(q.shape[2])
+    kpos = k_offset + jnp.arange(k.shape[2])
+    mask = qpos[:, None] >= kpos[None, :]
+    s = jnp.where(mask, s, -1e30)
+    m = s.max(axis=-1, keepdims=True)
+    p = jnp.where(mask, jnp.exp(s - m), 0.0)
+    l = p.sum(axis=-1, keepdims=True)
+    seen = l > 0.0
+    safe_l = jnp.where(seen, l, 1.0)
+    out = jnp.einsum("bhqk,bhkd->bhqd", p / safe_l, v)
+    lse = jnp.where(seen, m + jnp.log(safe_l), 0.0)[..., 0]
+    return out, lse, seen[..., 0]
+
+
+class TestCausalTileSkipping:
+    @pytest.mark.parametrize("Sq, Sk, bq, bk, q_off, k_off",
+                             CAUSAL_TILE_CASES + [
+                                 pytest.param(64, 64, 32, 32, 0, 64,
+                                              id="nothing-visible"),
+                                 pytest.param(96, 160, 32, 32, 1000, 1031,
+                                              id="far-offsets"),
+                                 pytest.param(64, 128, 16, 64, 17, 0,
+                                              id="k-block-spans-q-blocks"),
+                             ])
+    def test_a_tile_is_skipped_iff_the_mask_leaves_nothing_of_it(
+            self, Sq, Sk, bq, bk, q_off, k_off):
+        from horovod_tpu.ops import attention as att
+
+        nq, nk = Sq // bq, Sk // bk
+        visible = ((q_off + np.arange(Sq))[:, None]
+                   >= (k_off + np.arange(Sk))[None, :])
+        computed = np.zeros((nq, nk), bool)
+        for i in range(nq):
+            for j in range(nk):
+                tile = visible[i * bq:(i + 1) * bq, j * bk:(j + 1) * bk]
+                computed[i, j] = att._tile_visible(i, j, bq, bk, q_off,
+                                                   k_off)
+                assert computed[i, j] == tile.any(), (i, j)
+        # The index maps stay on a tile that is computed, or at the grid's
+        # edge where a whole q block / k block sees nothing: the K-innermost
+        # calls stop at a row's last computed tile, the Q-innermost call
+        # starts at a column's first.
+        for i in range(nq):
+            last = int(att._last_k_block(i, nk, bq, bk, q_off, k_off))
+            if computed[i].any():
+                assert computed[i, :last + 1].all()
+                assert not computed[i, last + 1:].any()
+            else:
+                assert last == 0
+        for j in range(nk):
+            first = int(att._first_q_block(j, nq, bq, bk, q_off, k_off))
+            if computed[:, j].any():
+                assert computed[first:, j].all()
+                assert not computed[:first, j].any()
+            else:
+                assert first == nq - 1
+
+    @pytest.mark.parametrize("Sq, Sk, bq, bk, q_off, k_off",
+                             CAUSAL_TILE_CASES)
+    def test_forward_and_gradients_match_reference(self, Sq, Sk, bq, bk,
+                                                   q_off, k_off):
+        q, _, _ = make_qkv(B=1, H=2, S=Sq, D=32, seed=1)
+        _, k, v = make_qkv(B=1, H=2, S=Sk, D=32, seed=2)
+
+        def flash(q, k, v):
+            return flash_attention(q, k, v, causal=True, block_q=bq,
+                                   block_k=bk, q_offset=q_off,
+                                   k_offset=k_off, interpret=True)
+
+        def reference(q, k, v):
+            return blockwise_attention_reference(
+                q, k, v, causal=True, block_size=32, q_offset=q_off,
+                k_offset=k_off)
+
+        got_out, got_vjp = jax.vjp(flash, q, k, v)
+        want_out, want_vjp = jax.vjp(reference, q, k, v)
+        np.testing.assert_allclose(np.asarray(got_out), np.asarray(want_out),
+                                   rtol=2e-5, atol=2e-5)
+        g = make_qkv(B=1, H=2, S=Sq, D=32, seed=3)[0]
+        for got, want in zip(got_vjp(g), want_vjp(g)):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=2e-3, atol=2e-3)
+
+    @pytest.mark.parametrize("Sq, Sk, bq, bk, q_off, k_off",
+                             CAUSAL_TILE_CASES)
+    def test_gradients_through_lse_match_dense(self, Sq, Sk, bq, bk, q_off,
+                                               k_off):
+        from horovod_tpu.ops.attention import LSE_MASKED, flash_attention_lse
+
+        q, _, _ = make_qkv(B=1, H=1, S=Sq, D=32, seed=4)
+        _, k, v = make_qkv(B=1, H=1, S=Sk, D=32, seed=5)
+        g_out = make_qkv(B=1, H=1, S=Sq, D=32, seed=6)[0]
+        g_lse = g_out[..., 0]
+
+        def flash(q, k, v):
+            return flash_attention_lse(q, k, v, causal=True, block_q=bq,
+                                       block_k=bk, q_offset=q_off,
+                                       k_offset=k_off, interpret=True)
+
+        def dense(q, k, v):
+            out, lse, seen = dense_causal_with_lse(q, k, v, q_off, k_off)
+            return (out, lse), seen
+
+        (out, lse), got_vjp = jax.vjp(flash, q, k, v)
+        (want_out, want_lse), want_vjp, seen = jax.vjp(dense, q, k, v,
+                                                       has_aux=True)
+        seen = np.asarray(seen)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want_out),
+                                   rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(lse)[seen],
+                                   np.asarray(want_lse)[seen],
+                                   rtol=2e-5, atol=2e-5)
+        assert np.all(np.asarray(lse)[~seen] == LSE_MASKED)
+        for got, want in zip(got_vjp((g_out, g_lse)),
+                             want_vjp((g_out, g_lse))):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=2e-3, atol=2e-3)
+
+    @pytest.mark.parametrize("causal, computed, skipped",
+                             [(True, 36, 28), (False, 64, 0)])
+    def test_the_gauge_counts_the_tiles_of_the_last_traced_call(
+            self, causal, computed, skipped):
+        from horovod_tpu import metrics
+
+        q, k, v = make_qkv(B=1, H=1, S=256, D=32)
+
+        def loss(q, k, v):
+            return flash_attention(q, k, v, causal=causal, block_q=32,
+                                   block_k=32, interpret=True).sum()
+
+        # Lowered, not run: the gauge is set while the calls are traced,
+        # by the forward's and again by the backward's.
+        for fn in (loss, jax.grad(loss, argnums=(0, 1, 2))):
+            metrics.ATTN_TILES_LAST.set(-1, kind="computed")
+            metrics.ATTN_TILES_LAST.set(-1, kind="skipped")
+            jax.jit(fn).lower(q, k, v)
+            assert metrics.ATTN_TILES_LAST.labels(
+                kind="computed").get() == computed
+            assert metrics.ATTN_TILES_LAST.labels(
+                kind="skipped").get() == skipped
+
+
 class TestRingAttention:
     @pytest.mark.parametrize("causal", [False, True])
     def test_matches_dense(self, hvd, causal):

@@ -51,6 +51,32 @@ def kernel_instructions(text: str) -> list:
     return found
 
 
+def kernel_bodies(lowered_text: str) -> list:
+    """The Mosaic module of every ``tpu_custom_call`` of a lowered
+    (StableHLO) text, as MLIR text: what the kernel and its index maps
+    were traced to, before the chip's compiler has it."""
+    import base64
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    bodies = []
+    for config in re.findall(r'backend_config = "((?:[^"\\]|\\.)*)"',
+                             lowered_text):
+        body = json.loads(config.replace("\\22", '"'))[
+            "custom_call_config"]["body"]
+        context = mlir.make_ir_context()
+        # serialised in the ``stable_mosaic`` dialect, which nothing registers
+        context.allow_unregistered_dialects = True
+        with context:
+            module = ir.Module.parse(base64.b64decode(body))
+            bodies.append(module.operation.get_asm(enable_debug_info=False))
+    return bodies
+
+
+CLAMP = re.compile(r"arith\.(minsi|maxsi)")
+
+
 @pytest.mark.parametrize("seq, kernels", [(512, 2), (1024, 3)])
 def test_the_flash_kernels_keep_the_name_the_benchmark_finds_them_by(
         one_chip, seq, kernels):
@@ -95,8 +121,16 @@ def test_olmoes_causal_kernels_compile_at_its_widths(one_chip):
 
     shape = jax.ShapeDtypeStruct((1, 4096, 16, 128), jnp.bfloat16,
                                  sharding=one_chip)
-    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
-        shape, shape, shape).compile().as_text()
+    lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        shape, shape, shape)
+    # Each of the three stops (or starts) its inner block index at the
+    # last (first) tile the mask leaves anything of, and steps only on
+    # such a tile: a third branch beside the first / last inner step's.
+    bodies = kernel_bodies(lowered.as_text())
+    assert len(bodies) == 3
+    assert all(CLAMP.search(body) for body in bodies)
+    assert all(body.count("scf.if") == 3 for body in bodies)
+    text = lowered.compile().as_text()
     found = kernel_instructions(text)
     assert len(found) == 3
     with open(os.path.join(REPO_ROOT, "benchmark", "layer_metrics",
@@ -105,3 +139,27 @@ def test_olmoes_causal_kernels_compile_at_its_widths(one_chip):
     assert all(wanted.search(name) for name, _ in found), found
     phases = [profiler.phase_of(scope) for _, scope in found]
     assert sorted(phases) == ["hvd.attn.bwd", "hvd.attn.bwd", "hvd.attn.fwd"]
+
+
+def test_a_call_that_is_not_causal_holds_no_clamp_and_no_tile_branch(
+        one_chip):
+    """The skipping exists only under ``causal``: the multi-tile kernels
+    of a bidirectional call keep plain ``(bh, j, 0)`` index maps and their
+    two ``pl.when`` (first and last inner step), and still compile."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops.attention import flash_attention
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    shape = jax.ShapeDtypeStruct((1, 16, 4096, 128), jnp.bfloat16,
+                                 sharding=one_chip)
+    lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        shape, shape, shape)
+    bodies = kernel_bodies(lowered.as_text())
+    assert len(bodies) == 3
+    assert not any(CLAMP.search(body) for body in bodies)
+    assert all(body.count("scf.if") == 2 for body in bodies)
+    assert len(kernel_instructions(lowered.compile().as_text())) == 3
