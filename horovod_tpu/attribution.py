@@ -13,16 +13,15 @@ looks through (arXiv:1909.09756). This module is that analysis layer:
 1. **Phase vocabulary** — the canonical span names
    (:data:`SPAN_FORWARD_BACKWARD` / :data:`SPAN_COLLECTIVE` /
    :data:`SPAN_OPTIMIZER_UPDATE`) shared by the elastic step
-   (``parallel/data_parallel.py``), ``bench.py``'s phase lane, and this
-   module — one constant set, so the three planes cannot drift.
+   (``parallel/data_parallel.py``) and this module — one constant set,
+   so the two planes cannot drift.
 2. **Per-rank decomposition** (:func:`decompose_step`): interval
    arithmetic over one rank's own span timeline splits step wall time
    into ``compute / exposed_comm / straggler_wait / overhead``, where
    *exposed_comm* is collective wall time NOT hidden under concurrent
-   compute spans — the first direct measurement of what the overlap
-   scheduler and the fsdp prefetch actually hide (vs the indirect
-   ``hvd_fsdp_prefetch_overlap_ratio`` probe). The four phases sum to
-   the step wall time by construction.
+   compute spans — the direct measurement of what the overlap
+   scheduler and the fsdp prefetch actually hide. The four phases sum
+   to the step wall time by construction.
 3. **Cluster critical path** (:func:`analyze_cluster`): merges all
    ranks' offset-corrected spans for a (generation, step) group and
    walks the longest dependency chain through compute segments and
@@ -30,9 +29,8 @@ looks through (arXiv:1909.09756). This module is that analysis layer:
    arriver) and how much skew it injected. Per-rank ``straggler_wait``
    (time spent inside a collective waiting for the gating rank) is
    carved out of that rank's exposed-comm total here.
-4. **MFU** (:func:`set_model_flops_per_step`): ``bench.py``'s analytic
-   FLOPs machinery promoted into the framework — declare the model's
-   FLOPs per step once and every synced step exports
+4. **MFU** (:func:`set_model_flops_per_step`): declare the model's
+   analytic FLOPs per step once and every synced step exports
    ``hvd_mfu_ratio`` (peak FLOPs detected from the local devices or
    passed explicitly).
 5. **Regression sentinel** (:class:`RegressionSentinel`): an EWMA
@@ -67,13 +65,12 @@ from typing import Any, Mapping, Sequence
 from .utils.env import get_float, get_int
 
 # ---------------------------------------------------------------------------
-# Phase vocabulary (the one constant set bench/tracing/attribution share)
+# Phase vocabulary (the one constant set tracing and attribution share)
 # ---------------------------------------------------------------------------
 
 #: Canonical phase-span names recorded inside a step scope. The elastic
-#: step (``parallel/data_parallel.py``) and ``bench.py``'s derived phase
-#: lane both emit exactly these, so ``phase_span_medians_ms`` and the
-#: attribution plane can never disagree on vocabulary.
+#: step (``parallel/data_parallel.py``) emits exactly these, so it and
+#: the attribution plane can never disagree on vocabulary.
 SPAN_FORWARD_BACKWARD = "forward_backward"
 SPAN_COLLECTIVE = "collective"
 SPAN_OPTIMIZER_UPDATE = "optimizer_update"
@@ -586,12 +583,11 @@ class RegressionSentinel:
 
 
 # ---------------------------------------------------------------------------
-# MFU machinery (bench.py's analytic-FLOPs plumbing, promoted)
+# MFU machinery
 # ---------------------------------------------------------------------------
 
 #: bf16 dense peak FLOPs/s per chip by device kind substring (no
-#: sparsity). The table ``bench.py`` carried since round 1, promoted so
-#: any workload can price MFU.
+#: sparsity), so any workload can price MFU.
 CHIP_PEAK_FLOPS = {
     "v6e": 918e12,
     "v6 lite": 918e12,

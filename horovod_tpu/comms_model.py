@@ -15,10 +15,9 @@ link_class)``:
   (``ops/collective_ops._eager_dispatch`` observes every timed eager
   collective), from an explicit **microprobe**
   (``ops.collective_ops.run_comms_microprobe`` — small/large payload
-  sweeps over a process set, the seeding pass ``bench.py``'s comms lane
-  runs), and from shipped trace spans whose names carry the fusion
-  pass's static bucket bytes (``allreduce.bucket0.1048576B`` — see
-  :func:`ingest_steps`).
+  sweeps over a process set), and from shipped trace spans whose names
+  carry the fusion pass's static bucket bytes
+  (``allreduce.bucket0.1048576B`` — see :func:`ingest_steps`).
 - **Fit** is exponentially-weighted least squares
   (``HOROVOD_COMMS_DECAY``): old samples decay so a drifting link
   re-fits instead of being averaged away, with confidence intervals

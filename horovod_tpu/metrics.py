@@ -256,8 +256,7 @@ class Registry:
 
     def snapshot(self) -> list[dict]:
         """Every family's dump, in registration order — the compact form
-        workers piggyback on heartbeats and ``bench.py`` writes to
-        ``HOROVOD_METRICS_SNAPSHOT``."""
+        workers piggyback on heartbeats."""
         with self._lock:
             fams = list(self._families.values())
         return [f.dump() for f in fams]
@@ -302,9 +301,9 @@ def render(extra_labels: Mapping[str, str] | None = None) -> str:
 
 def reset_for_testing() -> None:
     """Zero every instrument (and the goodput accumulators) without a
-    process restart — tests and bench warmup phases call this so counters
-    do not leak across phases. Instrument *definitions* survive; only the
-    cells are dropped (and goodput's zero-cells re-created)."""
+    process restart — tests call this so counters do not leak between
+    them. Instrument *definitions* survive; only the cells are dropped
+    (and goodput's zero-cells re-created)."""
     _registry.reset()
     goodput().reset()
     _materialize_checkpoint_cells()
@@ -708,11 +707,6 @@ PARAM_GATHER_BYTES = histogram(
     "by mesh axis: 'batch' is the bucketed data-axis leg (the flat 1-D "
     "wire records here too), 'model' the intra-layer ICI leg of the 2-D "
     "mesh.", ("axis",), BYTE_BUCKETS)
-PARAM_GATHER_SECONDS = histogram(
-    "hvd_param_gather_seconds",
-    "Wall time of a standalone fsdp parameter-gather program (the bench "
-    "probe that prices the gather the step must hide under compute).",
-    (), LATENCY_BUCKETS_S)
 RESIDENT_BYTES = gauge(
     "hvd_resident_state_bytes",
     "Per-rank resident bytes of sharded training state at rest, by kind "
@@ -743,11 +737,6 @@ HBM_RESIDUAL = gauge(
     "Predicted minus measured resident bytes over the model kinds "
     "(params+opt_state) — the footprint model's drift alarm "
     "(memory.predict_footprint vs the live accounting).")
-FSDP_PREFETCH_OVERLAP = gauge(
-    "hvd_fsdp_prefetch_overlap_ratio",
-    "Fraction of the fsdp parameter-gather time hidden under compute "
-    "(gather time hidden / total gather time), derived from the bench "
-    "phase probes and tracing spans.")
 MESH_AXIS_SIZE = gauge(
     "hvd_mesh_axis_size",
     "Axis sizes of the 2-D (batch, model) training mesh the step "
@@ -851,8 +840,8 @@ EXPOSED_COMM = gauge(
 OVERLAP_HIDDEN = gauge(
     "hvd_overlap_hidden_ratio",
     "Fraction of the last synced step's collective wall time hidden "
-    "under concurrent compute spans (measured by interval arithmetic, "
-    "vs the bench-derived hvd_fsdp_prefetch_overlap_ratio probe).")
+    "under concurrent compute spans (measured by interval arithmetic "
+    "over the rank's own spans).")
 MFU_RATIO = gauge(
     "hvd_mfu_ratio",
     "Model FLOPs utilization of the last synced step: "
@@ -943,9 +932,9 @@ SERVE_SWAP_SECONDS = histogram(
     (), LATENCY_BUCKETS_S)
 
 # Materialize the zero cells (the goodput pattern): a job that never
-# checkpointed or replicated still reports the series at 0, so the scrape
-# gate can assert the instruments exist and dashboards can tell "never
-# needed" from "not measuring".
+# checkpointed or replicated still reports the series at 0, so
+# dashboards can tell "never needed" from "not measuring"
+# (tests/test_observability.py scrapes them off a live server).
 def _materialize_checkpoint_cells() -> None:
     for kind in ("save", "restore"):
         for rung in ("durable", "peer"):
@@ -956,16 +945,13 @@ def _materialize_checkpoint_cells() -> None:
     for axis in ("batch", "model"):
         PARAM_GATHER_BYTES.labels(axis=axis)
         MESH_AXIS_SIZE.labels(axis=axis)
-    PARAM_GATHER_SECONDS.labels()
-    FSDP_PREFETCH_OVERLAP.labels()
     for mode in ("sharded", "fsdp"):
         RESIDENT_BYTES.labels(kind="opt_state", sync_mode=mode)
     RESIDENT_BYTES.labels(kind="params", sync_mode="fsdp")
     DRIVER_EPOCH.labels()
     DRIVER_TAKEOVERS.labels()
     # Comms-observatory zero cells: a job that never fitted a model
-    # still reports the roofline series at 0, so the premerge scrape
-    # gate can assert the instruments exist and dashboards can tell
+    # still reports the roofline series at 0, so dashboards can tell
     # "no model yet" from "not measuring".
     for lc in ("ici", "dcn"):
         LINK_LATENCY.labels(link_class=lc, op="allreduce")
@@ -974,26 +960,23 @@ def _materialize_checkpoint_cells() -> None:
     COLLECTIVE_EFFICIENCY.labels()
     COMMS_RESIDUAL.labels()
     # Comms-planner zero cells: a run that never planned (knob unset)
-    # still reports the series at 0 — the premerge scrape gate asserts
-    # they exist, and dashboards can tell "planner off" from "not
-    # measuring".
+    # still reports the series at 0, so dashboards can tell "planner
+    # off" from "not measuring".
     PLANNER_PLANS.labels()
     PLANNER_REPLANS.labels()
     for op in ("allreduce", "reducescatter", "allgather", "alltoall"):
         for algo in ("flat", "rhd", "two_level"):
             PLANNER_DISPATCH.labels(op=op, algorithm=algo)
     # Expert-parallel MoE zero cells: a job that never ran an MoE layer
-    # (or never dropped a token) still reports the series at 0 — the
-    # premerge scrape gate asserts the instruments exist.
+    # (or never dropped a token) still reports the series at 0.
     MOE_DISPATCH_BYTES.labels()
     MOE_TOKENS_DROPPED.labels()
     MOE_EXPERT_LOAD.labels(expert="0")
     for algo in ("flat", "two_level"):
         ALLTOALL_LATENCY.labels(algorithm=algo)
     # Serving-bridge zero cells: a job that never published (knob unset)
-    # or a serving tier that never swapped still reports the series at 0
-    # — the premerge scrape gate asserts the instruments exist, and
-    # dashboards can tell "no swaps yet" from "not measuring".
+    # or a serving tier that never swapped still reports the series at
+    # 0, so dashboards can tell "no swaps yet" from "not measuring".
     SERVE_MODEL_AGE.labels()
     SERVE_SWAPS.labels()
     SERVE_REQUESTS.labels()
@@ -1001,16 +984,14 @@ def _materialize_checkpoint_cells() -> None:
     for reason in ("fenced", "corrupt", "rollback", "storm", "dwell"):
         SERVE_REJECTED.labels(reason=reason)
     # Integrity defense plane zero cells: a job that never corrupted,
-    # never tripped, and never rewound still reports the series at 0 —
-    # the premerge scrape gate asserts they exist, and dashboards can
-    # tell "clean run" from "not measuring".
+    # never tripped, and never rewound still reports the series at 0,
+    # so dashboards can tell "clean run" from "not measuring".
     INTEGRITY_CHECKS.labels()
     for action in ("warn", "skip", "abort"):
         NONFINITE_STEPS.labels(action=action)
     REWINDS.labels(reason="loss_spike")
     # Attribution-plane zero cells: a job that never synced a step (or
-    # never declared its FLOPs) still reports the series at 0, so the
-    # premerge scrape gate can assert the instruments exist and
+    # never declared its FLOPs) still reports the series at 0, so
     # dashboards can tell "no regression" from "not measuring".
     for phase in STEP_PHASES:
         STEP_PHASE_SECONDS.labels(phase=phase)
@@ -1020,8 +1001,7 @@ def _materialize_checkpoint_cells() -> None:
     OVERLAP_HIDDEN.labels()
     MFU_RATIO.labels()
     # Memory-observatory zero cells: a job that never measured (or has
-    # no capacity source) still reports the series at 0, so the
-    # premerge scrape gate can assert the instruments exist and
+    # no capacity source) still reports the series at 0, so
     # dashboards can tell "nothing resident yet" from "not measuring".
     for kind in ("params", "opt_state", "grads", "peer_pool",
                  "executables", "serving", "other", "program_temporaries"):
@@ -1061,17 +1041,14 @@ def checkpoint_summary() -> dict:
 
 def fsdp_summary() -> dict:
     """Process-local parameter-sharding ledger for
-    ``profiler.summary()``: per-rank resident bytes by kind/mode, the
-    traced param-gather byte/latency totals, and the bench-derived
-    prefetch-overlap ratio (gather time hidden under compute / total
-    gather time; 0 until a bench probe has priced the gather)."""
+    ``profiler.summary()``: per-rank resident bytes by kind/mode and the
+    traced param-gather byte totals."""
     resident: dict = {}
     for sample in RESIDENT_BYTES.dump()["samples"]:
         labels = sample["labels"]
         resident.setdefault(labels["sync_mode"], {})[labels["kind"]] = (
             sample["value"])
     gb = PARAM_GATHER_BYTES.dump()["samples"]
-    gs = PARAM_GATHER_SECONDS.dump()["samples"]
     by_axis = {s["labels"].get("axis", ""): s for s in gb}
     return {
         "resident_bytes": resident,
@@ -1080,9 +1057,7 @@ def fsdp_summary() -> dict:
             "bytes_total": round(sum(s["sum"] for s in gb)),
             "bytes_by_axis": {a: round(s["sum"])
                               for a, s in sorted(by_axis.items())},
-            "probe_seconds_total": round(gs[0]["sum"], 4) if gs else 0.0,
         },
-        "prefetch_overlap_ratio": FSDP_PREFETCH_OVERLAP.labels().get(),
     }
 
 
@@ -1108,7 +1083,7 @@ class GoodputTracker:
 
     Mirrored live into the ``hvd_goodput_*`` registry counters so the
     cluster scrape carries every rank's goodput; :meth:`summary` is the
-    process-local view ``profiler.summary()`` and ``bench.py`` emit.
+    process-local view ``profiler.summary()`` emits.
     """
 
     CAUSES = ("rendezvous", "restore", "backoff", "failed_attempt")

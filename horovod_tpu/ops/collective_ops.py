@@ -76,13 +76,12 @@ def cache_stats(reset: bool = False) -> dict:
     so it is a lower bound). The same total feeds the memory
     observatory's ``hvd_hbm_bytes{kind="executables"}`` gauge.
 
-    Also surfaced in ``hvd.profiler.summary()`` and emitted once per run
-    by ``bench.py``.
+    Also surfaced in ``hvd.profiler.summary()``.
 
     ``reset=True`` zeroes the hit/miss/dispatch counters AFTER collecting
-    them (cached executables stay cached) — tests and bench warmup phases
-    use it so counters do not leak across phases. The cluster metrics
-    registry resets separately via ``metrics.reset_for_testing()``.
+    them (cached executables stay cached) — tests use it so counters do
+    not leak between them. The cluster metrics registry resets
+    separately via ``metrics.reset_for_testing()``.
     """
     from .. import profiler
 
@@ -342,8 +341,8 @@ def _link_class_of(ps) -> str:
             cache = topo.__dict__.setdefault("_link_class_by_set", {})
             # The declared-fabric override participates in the key: the
             # classification is a function of (set, live map), and a
-            # bench/test that declares an emulated fabric mid-run must
-            # not be served the previous fabric's cached class.
+            # test that declares an emulated fabric mid-run must not be
+            # served the previous fabric's cached class.
             key = (ps.process_set_id,
                    os.environ.get("HOROVOD_LINK_CLASS_MAP", ""))
             cls = cache.get(key)
@@ -1011,10 +1010,10 @@ def run_comms_microprobe(process_set=None, sizes=None,
     # per-algorithm ground truth plan pricing closes its loop on.
     # Planner off: one flat pass, exactly as before. The RETURNED
     # samples stay flat-only either way: callers take medians per
-    # payload size (the bench fit-tolerance lane), and mixing
-    # schedules with different cost curves into one list would skew
-    # them — the non-flat passes exist to feed the model, which reads
-    # the per-algorithm attribution straight off the dispatch path.
+    # payload size, and mixing schedules with different cost curves
+    # into one list would skew them — the non-flat passes exist to feed
+    # the model, which reads the per-algorithm attribution straight off
+    # the dispatch path.
     planner_live = False
     from . import comms_planner
 

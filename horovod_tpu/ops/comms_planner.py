@@ -147,8 +147,8 @@ def reset_for_testing() -> None:
 
 class forced:
     """Context manager pinning every plan to ``algorithm`` — the
-    per-algorithm microprobe's hook (``run_comms_microprobe``) and the
-    bench lane's A/B switch. Nestable; the innermost pin wins."""
+    per-algorithm microprobe's hook (``run_comms_microprobe``).
+    Nestable; the innermost pin wins."""
 
     def __init__(self, algorithm: str):
         if algorithm not in PLANNER_ALGORITHMS:

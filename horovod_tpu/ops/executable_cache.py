@@ -112,7 +112,7 @@ class ExecutableCache:
 
     def reset_stats(self) -> None:
         """Zero the hit/miss counters WITHOUT dropping entries — the
-        ``cache_stats(reset=True)`` contract (bench warmup must zero the
+        ``cache_stats(reset=True)`` contract (a caller zeroes the
         counters while keeping its warm executables)."""
         with self._lock:
             self.hits = 0

@@ -136,8 +136,7 @@ def int8_allreduce_flat(flat, axis_name: str, world_size: int,
     if prescale_factor != 1.0:
         x = x * prescale_factor
     if n <= 1:
-        # Single member: quantize-dequantize round trip only (the
-        # machinery-forced bench measures exactly this cost).
+        # Single member: quantize-dequantize round trip only.
         pad = (-m) % BLOCK
         xp = jnp.pad(x, (0, pad))
         q, scale = _quantize_blocks(xp, salt)

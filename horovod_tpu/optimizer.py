@@ -170,15 +170,7 @@ def _reduce_grads(
     entirely and only the scale factors are applied. This is the compiled
     analog of the reference short-circuiting single-rank allreduces.
     """
-    import os
-
-    # HOROVOD_FORCE_WIRE_MACHINERY=1 disables the single-rank short-circuit
-    # so benchmarks can measure the compression/bucketing/collective path
-    # even on one chip (a 1-member collective compiles to the identity, but
-    # the casts and concat/splits still execute — the honest "framework
-    # overhead" number; see bench.py vs_baseline_machinery).
-    force = os.environ.get("HOROVOD_FORCE_WIRE_MACHINERY", "") == "1"
-    if world_size == 1 and not force and op in (
+    if world_size == 1 and op in (
         collective_ops.Average,
         collective_ops.Sum,
     ):

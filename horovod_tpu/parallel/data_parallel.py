@@ -1398,8 +1398,7 @@ def make_elastic_train_step(
         # leg, host collective leg, compiled apply), so each gets a real
         # span — the per-phase breakdown the cross-rank timeline merges
         # and the attribution plane decomposes. Names come from the one
-        # shared vocabulary (attribution.PHASE_SPAN_NAMES) so bench's
-        # phase lane and this step cannot drift.
+        # shared vocabulary (attribution.PHASE_SPAN_NAMES).
         with tracing.span(attribution.SPAN_FORWARD_BACKWARD,
                           attribution.CAT_PHASE):
             loss, grads = grad_step(params, batch)

@@ -612,7 +612,7 @@ def make_data_parallel_moe_step(axis_name: str = "hvd",
     loss-trajectory oracle and the resident-bytes/throughput comparator
     for :func:`make_expert_parallel_moe_step`. Same wrapper-side
     metrics (dropped tokens, expert load) so the host-cost profile is
-    symmetric in the bench A/B."""
+    symmetric when the two are compared."""
     import numpy as np
 
     from .. import basics

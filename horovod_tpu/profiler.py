@@ -91,8 +91,6 @@ def summary() -> dict:
     per-kind resident bytes, the per-phase watermarks, the footprint
     model's predicted-vs-measured residual, headroom, and the top
     resident leaves — reset via ``memory.reset_for_testing()``).
-    ``bench.py`` emits this once per run so every benchmark record
-    carries the cache/goodput behavior that produced it.
     """
     from . import (attribution, comms_model, integrity, memory, metrics,
                    tracing)

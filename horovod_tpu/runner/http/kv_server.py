@@ -1730,7 +1730,7 @@ class KVClient:
         """``GET /model`` (auth-exempt): the training→serving bridge's
         health/age view — the newest assemblable ``modelstate`` commit,
         publish counters, and the model age (what serving readiness
-        probes and the premerge HTTP gate poll)."""
+        probes poll)."""
         with self._request("GET", "/model") as r:
             return json.loads(r.read().decode())
 

@@ -57,7 +57,7 @@ def parse_link_class_map(spec: str) -> list[list[int]] | None:
     islands, each a comma-separated list of global ranks and/or ``a-b``
     ranges — ``"0-3;4-7"`` declares two 4-rank slices whose intra-island
     links are ICI and whose cross-island links are DCN. The override
-    exists so CPU tests and benches can emulate a multi-slice fabric,
+    exists so CPU tests can emulate a multi-slice fabric,
     and so multi-slice worlds whose devices expose no ``slice_index``
     can declare theirs. Returns None for an empty/invalid spec (invalid
     maps must never take down init — the topology falls back to the
@@ -212,8 +212,8 @@ class Topology:
     def link_class_map(self) -> list[list[int]] | None:
         """The ``HOROVOD_LINK_CLASS_MAP`` islands covering THIS world, or
         None (no/invalid override, or one that names ranks outside the
-        world). Read dynamically — benches and tests declare an emulated
-        fabric after init — and parse-cached per distinct env value."""
+        world). Read dynamically — tests declare an emulated fabric
+        after init — and parse-cached per distinct env value."""
         raw = os.environ.get("HOROVOD_LINK_CLASS_MAP", "")
         cached = getattr(self, "_lcm_cache", None)
         if cached is not None and cached[0] == raw:

@@ -317,8 +317,8 @@ class StepTracer:
 
     def record(self, name: str, cat: str, t_start: float, dur: float,
                args: Mapping[str, Any] | None = None) -> None:
-        """Record a completed span directly (bench's derived phase
-        medians use this)."""
+        """Record a completed span directly, from its start and
+        duration."""
         with self._lock:
             self._record_locked(name, cat, t_start, dur, args)
 

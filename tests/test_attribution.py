@@ -241,22 +241,25 @@ class TestRegressionSentinel:
 # ---------------------------------------------------------------------------
 
 
-class TestWorkerPlane:
-    def _run_synced_step(self):
-        tr = tracing.get_tracer()
-        with tr.step_scope("train_step") as rec:
-            rec.synced = True
-            t0 = tr.clock.now()
-            tr.record(attribution.SPAN_FORWARD_BACKWARD,
-                      attribution.CAT_PHASE, t0, 1.0)
-            tr.record(attribution.SPAN_COLLECTIVE,
-                      attribution.CAT_COLLECTIVE, t0 + 0.8, 0.7)
-            tr.record(attribution.SPAN_OPTIMIZER_UPDATE,
-                      attribution.CAT_PHASE, t0 + 1.6, 0.2)
+def _run_synced_step():
+    """One synced step on this process's tracer: compute [0, 1] and
+    [1.6, 1.8], a collective over [0.8, 1.5]."""
+    tr = tracing.get_tracer()
+    with tr.step_scope("train_step") as rec:
+        rec.synced = True
+        t0 = tr.clock.now()
+        tr.record(attribution.SPAN_FORWARD_BACKWARD,
+                  attribution.CAT_PHASE, t0, 1.0)
+        tr.record(attribution.SPAN_COLLECTIVE,
+                  attribution.CAT_COLLECTIVE, t0 + 0.8, 0.7)
+        tr.record(attribution.SPAN_OPTIMIZER_UPDATE,
+                  attribution.CAT_PHASE, t0 + 1.6, 0.2)
 
+
+class TestWorkerPlane:
     def test_synced_step_exports_gauges(self):
         attribution.set_model_flops_per_step(1e9, peak_flops=1e12)
-        self._run_synced_step()
+        _run_synced_step()
         exposed = metrics.EXPOSED_COMM.labels().get()
         assert exposed == pytest.approx(0.5, abs=1e-3)
         hidden = metrics.OVERLAP_HIDDEN.labels().get()
@@ -283,7 +286,7 @@ class TestWorkerPlane:
     def test_profiler_summary_has_attribution(self):
         from horovod_tpu import profiler
 
-        self._run_synced_step()
+        _run_synced_step()
         out = profiler.summary()["attribution"]
         assert out["last_step"]["phases"][attribution.PHASE_COMPUTE] \
             == pytest.approx(1.2, abs=1e-3)
@@ -314,7 +317,7 @@ class TestWorkerPlane:
         with pytest.raises(ValueError, match="'TPU v5'"):
             attribution.set_model_flops_per_step(1e9)
         assert attribution.model_flops() == (None, None)
-        self._run_synced_step()  # the plane works on, without MFU
+        _run_synced_step()  # the plane works on, without MFU
         assert attribution.summary()["last_step"]["phases"][
             attribution.PHASE_COMPUTE] == pytest.approx(1.2, abs=1e-3)
         assert "mfu" not in attribution.summary()["last_step"]
@@ -325,14 +328,13 @@ class TestWorkerPlane:
         assert attribution.model_flops() == (1e9, 4 * 197e12)
         # From here on no step looks at the devices again.
         monkeypatch.setattr(jax, "local_devices", fake_devices("TPU v5"))
-        self._run_synced_step()
+        _run_synced_step()
         assert attribution.summary()["last_step"]["mfu"] > 0
         assert tracing.get_tracer().payload()[
             "peak_flops_per_rank"] == 4 * 197e12
 
     def test_phase_vocabulary_is_shared(self):
-        # Satellite: bench, the elastic step, and attribution must agree
-        # on one constant set.
+        # The elastic step and attribution must agree on one constant set.
         assert attribution.PHASE_SPAN_NAMES == (
             "forward_backward", "collective", "optimizer_update")
         assert attribution.STEP_PHASES == (
@@ -379,6 +381,51 @@ class TestCriticalpathEndpoint:
                 assert sum(d["phases"].values()) == pytest.approx(
                     d["wall_s"], rel=0.05)
             assert "sentinel" in body["regression"]
+        finally:
+            srv.stop()
+
+    def test_a_live_tracers_payload_analyses_as_two_ranks(self):
+        """The producer's own wire format through the real routes: a
+        synced step recorded on this process's tracer, published as
+        rank 0 and, clocks shifted +5 s with the matching measured
+        offset, as rank 1. Both land on one timebase, so every rank
+        decomposes, the collective names its gating rank and the skew
+        gauges read no lateness."""
+        from horovod_tpu.runner.http.kv_server import KVClient
+
+        _run_synced_step()
+        live = tracing.get_tracer().payload()
+        assert live["steps"] and live["steps"][-1]["synced"]
+        shifted = copy.deepcopy(live)
+        for rec in shifted["steps"]:
+            rec["t"] += 5.0
+            for sp in rec["spans"]:
+                sp["t"] += 5.0
+        srv = _server()
+        try:
+            srv.set_cluster_info(world_np=2)
+            client = KVClient("127.0.0.1", srv.port)
+            client.put("trace", "h0", json.dumps(dict(
+                live, rank="0", host="h0", clock_offset_s=0.0)).encode())
+            client.put("trace", "h1", json.dumps(dict(
+                shifted, rank="1", host="h1",
+                clock_offset_s=-5.0)).encode())
+            body = self._get(srv, "/criticalpath")
+            assert body["status"] == "ok"
+            g = body["groups"][-1]
+            assert sorted(g["ranks"]) == ["0", "1"]
+            for d in g["ranks"].values():
+                assert d["wall_s"] > 0
+                assert sum(d["phases"].values()) == pytest.approx(
+                    d["wall_s"], rel=0.05)
+            colls = [n for n in g["critical_path"]
+                     if n["kind"] == "collective"]
+            assert colls and all(
+                n["gating_rank"] in ("0", "1") for n in colls)
+            parsed = metrics.validate_prometheus_text(srv.metrics_text())
+            skews = [v for _, v in
+                     parsed["hvd_collective_skew_seconds"]["samples"]]
+            assert skews and max(skews) < 1e-6
         finally:
             srv.stop()
 
@@ -694,14 +741,18 @@ class TestMetricDocsLane:
             capture_output=True, text=True, timeout=120)
         assert proc.returncode == 0, proc.stderr or proc.stdout
 
-    def test_checker_catches_drift(self, tmp_path):
-        """An instrument registered in code but absent from the docs
-        table fails the lane naming the metric."""
+    @pytest.fixture()
+    def cmd(self):
         sys.path.insert(0, os.path.join(REPO, "tools"))
         try:
-            import check_metric_docs as cmd
+            import check_metric_docs
         finally:
             sys.path.pop(0)
+        return check_metric_docs
+
+    def test_checker_catches_drift(self, cmd, tmp_path):
+        """An instrument registered in code but absent from the docs
+        table fails the lane naming the metric."""
         pkg = tmp_path / "horovod_tpu"
         pkg.mkdir()
         (pkg / "m.py").write_text(
@@ -715,3 +766,30 @@ class TestMetricDocsLane:
         documented = cmd.doc_metrics(str(docs / "observability.md"))
         assert "hvd_totally_new_metric_total" in registered
         assert "hvd_ghost_metric" in documented
+
+    def test_every_instrument_has_a_writer_in_the_package(self, cmd):
+        """The third lane on this tree: nothing is registered that only
+        a script outside ``horovod_tpu/`` could ever have set."""
+        assert cmd.unwritten_metrics() == {}
+
+    @pytest.mark.parametrize("use, unwritten", [
+        ("X.labels()\n", True),                       # a zero cell only
+        ("y = X.labels(kind='a').get()\n", True),      # a read
+        ("X.inc(2, kind='a')\n", False),
+        ("X.labels(kind=str(k)).observe(\n    0.5)\n", False),
+        ("from .m import X as _X\nm.X.set(1.0)\n", False),
+    ])
+    def test_writer_lane_tells_a_write_from_a_zero_cell(
+            self, cmd, tmp_path, use, unwritten):
+        pkg = tmp_path / "horovod_tpu"
+        pkg.mkdir()
+        (pkg / "m.py").write_text(
+            'X = gauge(\n    "hvd_lonely_ratio", "help", ("kind",))\n'
+            'self._c = counter("hvd_kept_total", "help")\n'
+            'self._c.inc()\n')
+        (pkg / "user.py").write_text(use)
+        found = cmd.unwritten_metrics(str(tmp_path))
+        assert "hvd_kept_total" not in found
+        assert ("hvd_lonely_ratio" in found) is unwritten
+        if unwritten:
+            assert found["hvd_lonely_ratio"] == "X (horovod_tpu/m.py)"

@@ -1,7 +1,9 @@
 """What a CPU can check of the chip bring-up: the compile-cache helper
 places the cache where it says, nothing else in the tree sets a cache
-directory, and ``chip_smoke.py`` refuses to run off a TPU."""
+directory, ``chip_smoke.py`` refuses to run off a TPU, and the repository
+has one benchmark entry point."""
 
+import json
 import os
 import subprocess
 import sys
@@ -63,3 +65,24 @@ def test_chip_smoke_refuses_to_run_without_a_tpu():
     assert "needs a TPU" in proc.stderr and "'cpu'" in proc.stderr
     # Refused before anything was built, and no result line was printed.
     assert proc.stdout == ""
+
+
+def test_the_repository_has_one_benchmark_entry_point():
+    """``BENCHMARK.json``'s command is the benchmark. ``bench.py`` is what
+    ``tests/benchmark``'s ``TestFlops`` imports and no more: two names of
+    arithmetic, read without JAX, nothing to run."""
+    with open(os.path.join(REPO_ROOT, "BENCHMARK.json")) as f:
+        command = json.load(f)["command"]
+    assert os.path.isfile(os.path.join(REPO_ROOT, command[1]))
+    proc = subprocess.run(
+        [sys.executable, "-c",
+         "import sys, bench; print('jax' in sys.modules); "
+         "print(sorted(n for n in vars(bench) if not n.startswith('_')))"],
+        cwd=REPO_ROOT, capture_output=True, text=True, timeout=60,
+        check=True)
+    assert proc.stdout.split("\n")[:2] == [
+        "False",
+        "['RESNET50_TRAIN_FLOPS_PER_IMAGE_224', 'bert_flops_per_token']"]
+    with open(os.path.join(REPO_ROOT, "bench.py")) as f:
+        source = f.read()
+    assert "__main__" not in source and "def main" not in source

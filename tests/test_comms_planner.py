@@ -70,6 +70,9 @@ class TestDecision:
         big = cp.plan_bucket("allreduce", 16 << 20, N)
         assert big.algorithm == "two_level"
         assert big.provenance == "static_crossover"
+        # ...and it is chosen on price: the schedule's predicted cost is
+        # under the flat one's on the split fabric.
+        assert big.costs["two_level"] < big.costs["flat"]
         small = cp.plan_bucket("allreduce", 256, N)
         assert small.algorithm == "flat"
         assert small.provenance == "static_crossover"
@@ -355,7 +358,7 @@ class TestInt8PerLeg:
 
 
 class TestWiring:
-    def _flush(self, x_leaves, world=N):
+    def _program(self, x_leaves, world=N):
         from horovod_tpu.ops.fusion import fused_allreduce
 
         def body(*vs):
@@ -370,7 +373,26 @@ class TestWiring:
                            in_specs=tuple(P("w") for _ in x_leaves),
                            out_specs=tuple(P("w") for _ in x_leaves),
                            check_vma=False)
-        return [np.asarray(o) for o in jax.jit(fn)(*x_leaves)]
+        return jax.jit(fn)
+
+    def _flush(self, x_leaves, world=N):
+        return [np.asarray(o)
+                for o in self._program(x_leaves, world)(*x_leaves)]
+
+    def test_auto_on_a_uniform_fabric_lowers_to_the_flat_program(
+            self, hvd, monkeypatch):
+        """Where the planner prices and still picks flat, parity is by
+        construction: the flush lowers to the text of the flush with the
+        planner off. On a declared split the same buckets lower to
+        another program."""
+        leaves = [np.ones((N, 256 * 1024), np.float32) for _ in range(2)]
+        flat = self._program(leaves).lower(*leaves).as_text()
+        monkeypatch.setenv("HOROVOD_COMMS_PLANNER", "auto")
+        cp.reset_for_testing()
+        assert self._program(leaves).lower(*leaves).as_text() == flat
+        monkeypatch.setenv("HOROVOD_LINK_CLASS_MAP", "0-3;4-7")
+        cp.reset_for_testing()
+        assert self._program(leaves).lower(*leaves).as_text() != flat
 
     def test_planned_flush_matches_flat_flush(self, hvd, monkeypatch):
         rng = np.random.RandomState(6)

@@ -963,12 +963,25 @@ class TestIntegrityKvPlane:
         assert view["status"] == "no_records"
         assert view["records"] == {} and view["vote"] is None
 
-    def test_records_ride_heartbeats_and_vote_renders(self, kv_server):
+    @pytest.mark.parametrize("divergent", [True, False])
+    def test_records_ride_heartbeats_and_vote_renders(self, kv_server,
+                                                      divergent):
+        """Two ranks' fingerprints, collected and voted over HTTP: two
+        digests that differ, and the steady state the plane certifies:
+        ``make_record`` of one state on both ranks votes clean."""
         import urllib.request
 
+        if divergent:
+            records = [_rec(0, "aaa", step=6), _rec(1, "bbb", step=6)]
+        else:
+            state = ({"w": np.arange(8, dtype=np.float32)},
+                     {"m": np.zeros(8, dtype=np.float32)})
+            records = [integrity.make_record(
+                *state, step=6, rank=r, host=f"host{r}", generation=1)
+                for r in (0, 1)]
         client = KVClient("127.0.0.1", kv_server.port)
-        _put_heartbeat(client, "hostA", 0, _rec(0, "aaa", step=6))
-        _put_heartbeat(client, "hostB", 1, _rec(1, "bbb", step=6))
+        _put_heartbeat(client, "hostA", 0, records[0])
+        _put_heartbeat(client, "hostB", 1, records[1])
         kv_server.set_cluster_info(world_np=2)
         records = kv_server.integrity_records()
         assert sorted(records) == [0, 1]
@@ -978,8 +991,10 @@ class TestIntegrityKvPlane:
             view = json.loads(r.read().decode())
         assert view["status"] == "ok"
         assert sorted(view["records"]) == ["0", "1"]
+        assert all(rec["digest"] for rec in view["records"].values())
         assert view["vote"] is not None
-        assert view["vote"]["divergent"] is True
+        assert view["vote"]["divergent"] is divergent
+        assert view["vote"]["voters"] == 2
         assert view["vote"]["group"][1] == 6
 
     def test_malformed_heartbeats_tolerated(self, kv_server):

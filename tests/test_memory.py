@@ -64,12 +64,23 @@ def _uneven_params():
     }
 
 
+def _tree_bytes(tree) -> int:
+    """Bytes of a pytree from its leaves' shapes and dtypes alone (so an
+    ``eval_shape`` tree counts too). Kept here and not taken from
+    ``memory.tree_nbytes``: it measures that module from outside."""
+    import jax
+
+    return int(sum(
+        int(np.prod(np.shape(l)) if np.shape(l) else 1)
+        * np.dtype(l.dtype).itemsize
+        for l in jax.tree.leaves(tree)))
+
+
 def _measured_resident(hvd, opt, params, mode, n):
     """The byte count the live layouts actually occupy per rank —
     measured from materialized state, independent of the model."""
     import jax
 
-    from bench import _tree_bytes
     from horovod_tpu.parallel import param_sharding
 
     if mode == "allreduce":
@@ -435,16 +446,32 @@ class TestMemoryEndpoint:
         srv.start()
         return srv
 
-    def test_get_memory_merges_two_ranks(self):
+    @pytest.mark.parametrize("source", ["written_out", "observatory"])
+    def test_get_memory_merges_two_ranks(self, source):
+        """Two ranks' payloads on their heartbeats, merged over HTTP:
+        written out by hand, and the live observatory's own wire format
+        (what a worker piggybacks) relabelled as two ranks."""
         from horovod_tpu.runner.http.kv_server import KVClient
 
+        if source == "observatory":
+            memory.note_resident("params", 100)
+            memory.note_resident("opt_state", 10)
+            live = memory.get_observatory().payload()
+            assert live["status"] == "ok"
+
+            def payload(rank, host):
+                scale = rank + 1
+                return dict(live, rank=rank, host=host, resident=dict(
+                    live["resident"], params=100 * scale))
+        else:
+            payload = _payload
         srv = self._server()
         try:
             client = KVClient("127.0.0.1", srv.port)
             for rank, host in ((0, "mem-r0"), (1, "mem-r1")):
                 client.put("heartbeat", host, json.dumps(
                     {"rank": rank, "steps": 1, "commits": 0,
-                     "memory": _payload(rank, host)}).encode())
+                     "memory": payload(rank, host)}).encode())
             url = f"http://127.0.0.1:{srv.port}/memory"
             with urllib.request.urlopen(url, timeout=10) as r:
                 assert r.status == 200

@@ -708,6 +708,82 @@ class TestClusterScrape:
         finally:
             server.stop()
 
+    @pytest.fixture(scope="class")
+    def cold_scrape(self):
+        """``GET /metrics`` of a live server holding one heartbeat of a
+        process that has measured nothing yet, parsed strictly."""
+        import json as _json
+        import urllib.request
+
+        from horovod_tpu import metrics
+        from horovod_tpu.runner.http.kv_server import (
+            KVClient, RendezvousServer,
+        )
+
+        metrics.reset_for_testing()
+        server = RendezvousServer(host="127.0.0.1")
+        server.start()
+        try:
+            server.set_cluster_info(world_np=1)
+            KVClient("127.0.0.1", server.port).put(
+                "heartbeat", "cold-host", _json.dumps({
+                    "rank": 0, "steps": 0, "commits": 0,
+                    "metrics": metrics.snapshot()}).encode())
+            url = f"http://127.0.0.1:{server.port}/metrics"
+            with urllib.request.urlopen(url, timeout=10) as r:
+                return metrics.validate_prometheus_text(r.read().decode())
+        finally:
+            server.stop()
+
+    @pytest.mark.parametrize("plane, families", [
+        ("driver", ("hvd_heartbeat_age_seconds", "hvd_world_generation",
+                    "hvd_policy_decisions_total", "hvd_policy_spare_hosts",
+                    "hvd_driver_epoch", "hvd_driver_lost_total",
+                    "hvd_integrity_quarantined_ranks")),
+        ("goodput", ("hvd_goodput_productive_seconds_total",
+                     "hvd_goodput_lost_seconds_total")),
+        ("checkpoint_and_sharding", (
+            "hvd_checkpoint_seconds", "hvd_peer_replication_bytes",
+            "hvd_param_gather_bytes", "hvd_resident_state_bytes",
+            "hvd_mesh_axis_size")),
+        ("comms", ("hvd_link_bandwidth_bytes_per_second",
+                   "hvd_link_latency_seconds",
+                   "hvd_collective_efficiency_ratio",
+                   "hvd_comms_residual_seconds", "hvd_planner_plans_total",
+                   "hvd_planner_replans_total",
+                   "hvd_planner_dispatch_total")),
+        ("integrity", ("hvd_integrity_checks_total",
+                       "hvd_integrity_divergence_total",
+                       "hvd_nonfinite_steps_total", "hvd_rewinds_total")),
+        ("attribution", ("hvd_step_phase_seconds",
+                         "hvd_exposed_comm_seconds",
+                         "hvd_overlap_hidden_ratio", "hvd_mfu_ratio",
+                         "hvd_step_regression_score")),
+        ("moe", ("hvd_moe_dispatch_bytes", "hvd_moe_tokens_dropped_total",
+                 "hvd_moe_expert_load", "hvd_alltoall_latency_seconds")),
+        ("serving", ("hvd_serve_model_age_seconds", "hvd_serve_swaps_total",
+                     "hvd_serve_rejected_publishes_total",
+                     "hvd_serve_requests_total", "hvd_serve_swap_seconds")),
+        ("memory", ("hvd_hbm_bytes", "hvd_hbm_watermark_bytes",
+                    "hvd_hbm_headroom_ratio",
+                    "hvd_hbm_model_residual_bytes")),
+    ])
+    def test_zero_cells_reach_the_scrape(self, cold_scrape, plane,
+                                         families):
+        """A plane that has measured nothing still shows its series at 0
+        on the cluster scrape (0 = nothing happened, absence = not
+        measuring), carried by an ordinary heartbeat."""
+        missing = [name for name in families
+                   if not cold_scrape.get(name, {}).get("samples")]
+        assert not missing, (plane, missing)
+        if plane == "checkpoint_and_sharding":
+            # Both axes of the 2-D mesh, or "flat wire" and "not
+            # measuring that axis" read the same.
+            for name in ("hvd_mesh_axis_size", "hvd_param_gather_bytes"):
+                axes = {labels.get("axis")
+                        for labels, _ in cold_scrape[name]["samples"]}
+                assert {"batch", "model"} <= axes, (name, axes)
+
     def test_scrape_unauthenticated_even_with_secret(self, monkeypatch):
         """A Prometheus scraper cannot HMAC-sign: /metrics must answer
         without auth while the KV surface stays 403-protected."""

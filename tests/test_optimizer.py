@@ -227,3 +227,41 @@ def test_grad_wrapper_averages(hvd):
 def test_invalid_backward_passes(hvd):
     with pytest.raises(ValueError):
         hvd.DistributedOptimizer(optax.sgd(0.1), backward_passes_per_step=0)
+
+
+@pytest.mark.parametrize("op, prescale, postscale, factor", [
+    ("Average", 1.0, 1.0, None),
+    ("Sum", 1.0, 1.0, None),
+    ("Sum", 0.5, 4.0, 2.0),
+])
+def test_one_member_short_circuit_is_unconditional(
+        hvd, monkeypatch, op, prescale, postscale, factor):
+    """A process set of one has no wire, whatever the environment says:
+    ``_reduce_grads`` hands back its input leaves themselves when the
+    scale is one, and applies only the scale otherwise. The variable set
+    here is the switch a removed benchmark used to force the machinery."""
+    from horovod_tpu import optimizer
+
+    monkeypatch.setenv("HOROVOD_FORCE_WIRE_MACHINERY", "1")
+    grads = {"w": jnp.arange(6.0).reshape(2, 3),
+             "b": jnp.ones((4,), jnp.bfloat16)}
+
+    def reduce(tree):
+        return optimizer._reduce_grads(
+            tree, getattr(hvd, op), "hvd", hvd.Compression.bf16,
+            prescale, postscale, 1 << 20, 0, world_size=1)
+
+    out = reduce(grads)
+    text = jax.jit(reduce).lower(grads).as_text(debug_info=True)
+    assert "hvd.wire" not in text
+    assert "convert" not in text  # no compression cast either
+    if factor is None:
+        assert all(o is g for o, g in zip(
+            jax.tree.leaves(out), jax.tree.leaves(grads)))
+        assert "multiply" not in text
+    else:
+        assert text.count("stablehlo.multiply") == 2  # one a leaf
+        for o, g in zip(jax.tree.leaves(out), jax.tree.leaves(grads)):
+            assert o.dtype == g.dtype
+            np.testing.assert_array_equal(
+                np.asarray(o, np.float32), factor * np.asarray(g, np.float32))

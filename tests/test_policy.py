@@ -454,8 +454,8 @@ class TestSpareAndPreemptScopes:
 
     def test_scrape_zero_materializes_policy_instruments(self, server):
         """The hvd_policy_* instruments exist on the scrape BEFORE any
-        decision fires — gate 4 asserts them, dashboards can tell 'no
-        drains yet' from 'not measuring'."""
+        decision fires, so dashboards can tell 'no drains yet' from 'not
+        measuring'."""
         parsed = hvd_metrics.validate_prometheus_text(
             server.metrics_text())
         spares = parsed["hvd_policy_spare_hosts"]["samples"]

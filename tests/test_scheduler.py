@@ -393,7 +393,7 @@ class TestSchedulerUnits:
         """The observability surface, served over real HTTP: the pool
         and job gauges plus the decision counter zero-materialized on
         /metrics, and GET /pool carrying >= 2 job entries with
-        world/goodput/SLO state — what premerge gate 4 scrapes."""
+        world/goodput/SLO state."""
         sched = MultiJobScheduler(_specs(), ["h1", "h2", "h3"],
                                   str(tmp_path))
         sched._start_http()
@@ -415,6 +415,9 @@ class TestSchedulerUnits:
             pool = json.loads(urllib.request.urlopen(
                 f"{base}/pool", timeout=10).read().decode())
             assert len(pool["jobs"]) == 2
+            for entry in pool["jobs"].values():
+                assert {"state", "priority", "min_np", "max_np",
+                        "target_goodput", "lease"} <= set(entry)
             assert pool["jobs"]["alpha"]["target_goodput"] == 0.9
             assert pool["jobs"]["alpha"]["state"] == "pending"
             assert len(pool["hosts"]) == 3

@@ -6,8 +6,8 @@
 ``blockwise_attention_reference`` in float32 at the single-tile shape
 BERT-Large uses (S512 D64), at a multi-tile causal shape (S2048 D128)
 and at OLMoE's (one sequence of S4096, 16 heads of D128: a grid of 16 x 8 x 8
-tiles of 512), then ``ops.conv_backward.dw_1x1`` against the matmul it replaces. Compiled,
-never ``interpret=True``: off a TPU this exits non-zero.
+tiles of 512). Compiled, never ``interpret=True``: off a TPU this exits
+non-zero.
 
 The tolerance is the one ``tests/test_sequence_parallel.py`` uses for bf16
 inputs (rtol = atol = 2e-2) with atol multiplied by the reference's
@@ -77,23 +77,6 @@ def check_flash(batch, heads, seq, dim, causal) -> None:
         _close(name, g, w)
 
 
-def check_dw_1x1() -> None:
-    import jax
-    import jax.numpy as jnp
-
-    from horovod_tpu.ops.conv_backward import dw_1x1
-
-    rows, cin, cout = 128 * 56 * 56, 64, 256
-    print(f"conv_backward.dw_1x1 [{rows}, {cin}]^T @ [{rows}, {cout}] bf16")
-    kx, ky = jax.random.split(jax.random.PRNGKey(1))
-    x = jax.random.normal(kx, (rows, cin), jnp.bfloat16)
-    dy = jax.random.normal(ky, (rows, cout), jnp.bfloat16)
-    want = jax.jit(lambda x, dy: jax.lax.dot_general(
-        x, dy, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32))(x, dy)
-    _close("dw", jax.jit(dw_1x1)(x, dy), want)
-
-
 def main() -> None:
     import jax
 
@@ -108,7 +91,6 @@ def main() -> None:
     check_flash(4, 16, 512, 64, causal=False)
     check_flash(2, 4, 2048, 128, causal=True)
     check_flash(1, 16, 4096, 128, causal=True)
-    check_dw_1x1()
     print("kernels ok")
 
 

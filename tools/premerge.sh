@@ -1,32 +1,30 @@
 #!/usr/bin/env bash
-# Default pre-merge check: the tier-1 test suite (ROADMAP.md's verify
-# command, verbatim), the fault-injection smoke lane (chaos coverage must
-# not silently rot), a 2-step CPU smoke of bench.py — the bench
-# exercises the full machinery (DistributedOptimizer wire, raw baseline,
-# forced-wire, overlap scheduler) end to end, which unit tests alone do
-# not — then a /metrics scrape of the bench run's instrument snapshot
-# through a live rendezvous KV server (the observability plane must not
-# silently rot either). Run from anywhere; exits nonzero if any gate
-# fails.
+# Default pre-merge check, on the CPU: the metric-docs consistency lane,
+# the tier-1 test suite as the driver runs it (six workers, a file to a
+# worker), and the fault-injection lane with its slow tests (chaos
+# coverage must not silently rot). Speed is not judged here: the chip
+# cells of BENCHMARK.json are the speed record (benchmark/run.py,
+# PERF.md). Run from anywhere; exits nonzero if any gate fails.
 set -u -o pipefail
 cd "$(dirname "$0")/.."
 
-echo "== premerge gate 0/4: metric-docs consistency (static lane) =="
+echo "== premerge gate 0/2: metric-docs consistency (static lane) =="
 # Every hvd_* instrument registered in code must appear in
-# docs/observability.md's metric tables and vice versa — the table
-# drifted in every PR since the metrics plane landed; this makes the
-# drift a named CI failure instead of a docs bug found at incident time.
+# docs/observability.md's metric tables and vice versa, and must be
+# written somewhere inside horovod_tpu/ — the table drifted in every PR
+# since the metrics plane landed; this makes the drift a named CI
+# failure instead of a docs bug found at incident time.
 if ! python tools/check_metric_docs.py; then
     echo "premerge: metric-docs consistency lane failed" >&2
     exit 1
 fi
 
-echo "== premerge gate 1/4: tier-1 tests =="
+echo "== premerge gate 1/2: tier-1 tests =="
 t1log="$(mktemp "${TMPDIR:-/tmp}/_t1.XXXXXX.log")"  # per-run: concurrent
 trap 'rm -f "$t1log"' EXIT                          # premerges must not clobber
-timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
+timeout -k 10 1470 env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
     -m 'not slow' --continue-on-collection-errors -p no:cacheprovider \
-    -p no:xdist -p no:randomly 2>&1 | tee "$t1log"
+    -p xdist -n 6 --dist loadfile -p no:randomly 2>&1 | tee "$t1log"
 rc=${PIPESTATUS[0]}
 echo "DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' "$t1log" \
     | tr -cd . | wc -c)"
@@ -47,7 +45,7 @@ if [ "$rc" -ne 0 ]; then
     echo "premerge: only known-environmental failures; continuing"
 fi
 
-echo "== premerge gate 2/4: fault-injection + recovery (chaos lane) =="
+echo "== premerge gate 2/2: fault-injection + recovery (chaos lane) =="
 # The FULL chaos files, slow marks included: the e2e liveness/abort/
 # recovery tests are the acceptance proof for the robustness layer and
 # must not rot just because tier-1 deselects @slow. test_recovery.py
@@ -100,720 +98,6 @@ if ! timeout -k 10 2400 env JAX_PLATFORMS=cpu HOROVOD_TEST_HARD_TIMEOUT=240 \
     --continue-on-collection-errors \
     -p no:cacheprovider -p no:xdist -p no:randomly; then
     echo "premerge: fault-injection/recovery chaos lane failed" >&2
-    exit 1
-fi
-
-echo "== premerge gate 3/4: bench.py --smoke perf lane (8-dev CPU mesh, 2 steps/section) =="
-blog="$(mktemp "${TMPDIR:-/tmp}/_bench.XXXXXX.log")"
-msnap="$(mktemp "${TMPDIR:-/tmp}/_metrics.XXXXXX.json")"
-tsnap="$(mktemp "${TMPDIR:-/tmp}/_trace.XXXXXX.json")"
-csnap="$(mktemp "${TMPDIR:-/tmp}/_comms.XXXXXX.json")"
-memsnap="$(mktemp "${TMPDIR:-/tmp}/_memory.XXXXXX.json")"
-trap 'rm -f "$t1log" "$blog" "$msnap" "$tsnap" "$csnap" "$memsnap"' EXIT
-# Scrape/timeline artifacts survive the run for build archiving.
-ARTIFACTS="${PREMERGE_ARTIFACTS:-${TMPDIR:-/tmp}/premerge-artifacts}"
-mkdir -p "$ARTIFACTS"
-# The 8-device virtual mesh (the test harness's stand-in slice): on one
-# device the collectives compile to identities and the sharded mode has
-# no optimizer compute to shard away, so single-device ratios cannot
-# judge the sync modes against each other. The bench also dumps its
-# metrics snapshot (HOROVOD_METRICS_SNAPSHOT) and trace payload
-# (HOROVOD_TRACE_SNAPSHOT) for the gate-4 scrape + timeline lanes.
-if ! JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-    HOROVOD_METRICS_SNAPSHOT="$msnap" \
-    HOROVOD_TRACE_SNAPSHOT="$tsnap" \
-    HOROVOD_COMMS_SNAPSHOT="$csnap" \
-    HOROVOD_MEMORY_SNAPSHOT="$memsnap" \
-    python bench.py --smoke | tee "$blog"; then
-    echo "premerge: bench smoke failed" >&2
-    exit 1
-fi
-# Perf lane: the machinery metrics must be PRESENT in the record (a bench
-# refactor silently dropping them reads as "no regression" forever); the
-# sharded sync mode must not regress more than 2% below the monolithic
-# machinery ratio (both are vs the same raw baseline, so the comparison
-# cancels the baseline out); the fsdp mode must not regress more than 2%
-# below sharded (same wire bytes per step — RS+AG — so the comparison
-# isolates where the gather sits) and its per-rank resident param+opt
-# bytes must be < 40% of monolithic (the memory win that motivates the
-# mode; on the 8-dev mesh the honest number is ~1/8).
-if ! python - "$blog" <<'EOF'
-import json
-import sys
-
-last = None
-with open(sys.argv[1]) as f:
-    for line in f:
-        line = line.strip()
-        if line.startswith("{"):
-            try:
-                last = json.loads(line)
-            except ValueError:
-                pass
-if last is None:
-    sys.exit("premerge perf lane: no JSON record in bench output")
-mono = last.get("vs_baseline_machinery")
-sharded = last.get("vs_baseline_machinery_sharded")
-fsdp = last.get("vs_baseline_machinery_fsdp")
-resident = last.get("resident_bytes_per_rank") or {}
-if mono is None or sharded is None or fsdp is None:
-    sys.exit(
-        "premerge perf lane: machinery metrics missing from bench record "
-        f"(vs_baseline_machinery={mono!r}, "
-        f"vs_baseline_machinery_sharded={sharded!r}, "
-        f"vs_baseline_machinery_fsdp={fsdp!r})")
-if sharded < mono * 0.98:
-    sys.exit(
-        f"premerge perf lane: sharded sync mode regressed "
-        f"{(1 - sharded / mono) * 100:.1f}% below the monolithic "
-        f"machinery ratio (sharded={sharded}, monolithic={mono}, "
-        f"allowed slack 2%)")
-if fsdp < sharded * 0.98:
-    sys.exit(
-        f"premerge perf lane: fsdp sync mode regressed "
-        f"{(1 - fsdp / sharded) * 100:.1f}% below the sharded machinery "
-        f"ratio (fsdp={fsdp}, sharded={sharded}, allowed slack 2%)")
-r_mono = resident.get("monolithic")
-r_fsdp = resident.get("fsdp")
-if not r_mono or r_fsdp is None:
-    sys.exit(
-        "premerge perf lane: resident_bytes_per_rank missing from bench "
-        f"record (got {resident!r})")
-if r_fsdp >= 0.40 * r_mono:
-    sys.exit(
-        f"premerge perf lane: fsdp resident param+opt bytes are "
-        f"{r_fsdp / r_mono:.1%} of monolithic (must be < 40%: the "
-        f"params-sharded-at-rest contract; fsdp={r_fsdp}, "
-        f"monolithic={r_mono})")
-# 2-D mesh lane: fsdp on the emulated 4x2 (batch, model) split must hold
-# within 2% of 1-D fsdp (the two-leg gather must not cost wall clock on
-# the machinery-forced wire) and its resident bytes must not exceed the
-# 1-D rows (the rank-factorized layout is byte-identical by the ceil
-# identity — any growth means the layout regressed).
-fsdp_2d = last.get("vs_baseline_machinery_fsdp_2d")
-if fsdp_2d is None:
-    sys.exit(
-        "premerge perf lane: vs_baseline_machinery_fsdp_2d missing from "
-        "bench record (the 2-D mesh lane did not run)")
-if fsdp_2d < fsdp * 0.98:
-    sys.exit(
-        f"premerge perf lane: fsdp on the 2-D (batch, model) mesh "
-        f"regressed {(1 - fsdp_2d / fsdp) * 100:.1f}% below 1-D fsdp "
-        f"(fsdp_2d={fsdp_2d}, fsdp={fsdp}, allowed slack 2%)")
-r_2d = resident.get("fsdp_2d")
-if r_2d is None:
-    sys.exit(
-        "premerge perf lane: resident_bytes_per_rank has no fsdp_2d "
-        f"entry (got {resident!r})")
-if r_2d > r_fsdp:
-    sys.exit(
-        f"premerge perf lane: 2-D fsdp resident bytes exceed the 1-D "
-        f"rows (fsdp_2d={r_2d}, fsdp={r_fsdp}; the rank-factorized "
-        f"layout must be byte-identical)")
-# Memory lane: the analytic footprint model must price the fsdp lane's
-# measured resident bytes within 5% (on the CPU mesh the shapes are
-# fully static, so the honest number is exact — the 5% slack only
-# absorbs a future lane changing its optimizer); a silent drift here
-# means predict_footprint no longer mirrors shard_ownership.
-memory = last.get("memory") or {}
-mem_rows = memory.get("predicted_vs_measured") or {}
-mem_fsdp = mem_rows.get("fsdp") or {}
-if not mem_fsdp:
-    sys.exit("premerge memory lane: bench record has no memory "
-             f"predicted_vs_measured fsdp row (got {memory!r})")
-drift = mem_fsdp.get("drift_ratio")
-if drift is None or drift > 0.05:
-    sys.exit(
-        f"premerge memory lane: footprint model drifted {drift!r} from "
-        f"the measured fsdp resident bytes (predicted="
-        f"{mem_fsdp.get('predicted_resident_bytes')!r}, measured="
-        f"{mem_fsdp.get('measured_resident_bytes')!r}, allowed 5%)")
-comms = last.get("comms") or {}
-if not comms:
-    sys.exit("premerge comms lane: bench record has no 'comms' section")
-if not comms.get("within_tolerance"):
-    sys.exit(
-        "premerge comms lane: fitted alpha-beta model missed the observed "
-        f"per-bucket latencies (per-mode rel residuals "
-        f"{comms.get('per_mode_rel_residual')!r} vs tolerance "
-        f"{comms.get('fit_tolerance')!r})")
-if comms.get("autotune_pruned", 0) < 1:
-    sys.exit(
-        "premerge comms lane: model-guided autotune pruned no dominated "
-        f"candidate (grid {comms.get('autotune_grid')!r}, predicted "
-        f"{comms.get('autotune_predicted_s')!r})")
-if comms.get("autotune_winner_guided") != comms.get(
-        "autotune_winner_exhaustive"):
-    sys.exit(
-        "premerge comms lane: model-guided pruning changed the autotune "
-        f"winner (exhaustive={comms.get('autotune_winner_exhaustive')!r}, "
-        f"guided={comms.get('autotune_winner_guided')!r})")
-planner = last.get("planner") or {}
-if not planner or planner.get("skipped"):
-    sys.exit("premerge planner lane: bench record has no 'planner' "
-             f"section (got {planner!r})")
-if planner.get("split_selected_algorithm") != "two_level":
-    sys.exit(
-        "premerge planner lane: the planner picked "
-        f"{planner.get('split_selected_algorithm')!r} on the emulated "
-        "2-slice DCN split (must schedule two_level for "
-        f"above-crossover buckets; bucket_bytes="
-        f"{planner.get('bucket_bytes')!r})")
-pp, pf = (planner.get("split_predicted_planned_s"),
-          planner.get("split_predicted_flat_s"))
-if pp is None or pf is None or pp >= pf:
-    sys.exit(
-        "premerge planner lane: the planned schedule's predicted cost "
-        f"does not beat flat on the emulated split (planned={pp!r}, "
-        f"flat={pf!r})")
-if planner.get("uniform_selected_algorithm") != "flat":
-    sys.exit(
-        "premerge planner lane: the planner left flat on a uniform "
-        "single-class fabric (picked "
-        f"{planner.get('uniform_selected_algorithm')!r})")
-up, uf = (planner.get("uniform_planned_step_s"),
-          planner.get("uniform_flat_step_s"))
-identical = planner.get("uniform_program_identical")
-# Uniform-fabric parity: when the planner picks flat it must emit the
-# byte-identical program (parity by construction — wall timing of
-# identical programs on a loaded CPU box is ±20% noise); only a
-# genuinely divergent program falls back to the 2% wall-clock gate.
-if not identical:
-    if not up or not uf or up > uf / 0.98:
-        sys.exit(
-            "premerge planner lane: planner-enabled flush diverged from "
-            "the flat program on the single-class fabric AND regressed "
-            f"beyond the 2% slack (identical={identical!r}, "
-            f"planned={up!r}, flat={uf!r})")
-moe = last.get("moe") or {}
-if not moe or moe.get("skipped"):
-    sys.exit("premerge moe lane: bench record has no 'moe' section "
-             f"(got {moe!r})")
-dp_tps, ep_tps = moe.get("dp_tokens_per_sec"), moe.get("ep_tokens_per_sec")
-if not dp_tps or not ep_tps:
-    sys.exit(
-        "premerge moe lane: tokens/sec missing from the moe record "
-        f"(dp={dp_tps!r}, ep={ep_tps!r})")
-# EP-vs-DP floor: both layers run identical routing and identical
-# per-rank FFN FLOPs; EP adds the real dispatch/combine alltoalls and
-# its payoff (1/E resident expert bytes, asserted in
-# tests/test_moe_parallel.py) is invisible to a virtual CPU mesh — so
-# EP <= DP here by construction and the floor guards a pathologically
-# slow wire (a dispatch that serializes, a quantizer in the hot path
-# when compression is off), not parity. 0.5 = the exchange may cost up
-# to as much as the whole dense step, never more.
-if ep_tps < 0.5 * dp_tps:
-    sys.exit(
-        f"premerge moe lane: expert-parallel tokens/sec regressed to "
-        f"{ep_tps / dp_tps:.1%} of the data-parallel MoE baseline "
-        f"(ep={ep_tps}, dp={dp_tps}, floor 50% — the alltoall wire "
-        f"must not cost more than the dense step it shards)")
-if moe.get("algorithm") not in ("flat", "two_level"):
-    sys.exit(
-        f"premerge moe lane: dispatch wire reports no algorithm "
-        f"(got {moe.get('algorithm')!r})")
-print(f"premerge planner lane: ok (split schedule "
-      f"{planner['split_selected_algorithm']!r} "
-      f"[{planner.get('split_provenance')!r}], predicted "
-      f"{pp:.6f}s vs flat {pf:.6f}s; uniform program "
-      f"identical={identical!r}, wall ratio "
-      f"{(up / uf) if up and uf else float('nan'):.4f})")
-print(f"premerge perf lane: ok (monolithic={mono}, sharded={sharded}, "
-      f"fsdp={fsdp}, resident fsdp/mono={r_fsdp / r_mono:.1%})")
-print(f"premerge memory lane: ok (fsdp predicted "
-      f"{mem_fsdp['predicted_resident_bytes']} vs measured "
-      f"{mem_fsdp['measured_resident_bytes']} bytes, drift {drift})")
-print(f"premerge comms lane: ok (pruned {comms['autotune_pruned']} of "
-      f"{len(comms.get('autotune_grid') or [])} candidates, winner "
-      f"{comms['autotune_winner_guided']!r} matches exhaustive; fit "
-      f"residuals {comms.get('per_mode_rel_residual')})")
-print(f"premerge moe lane: ok (ep/dp tokens-per-sec ratio "
-      f"{ep_tps / dp_tps:.2f}, wire {moe.get('algorithm')!r}, "
-      f"int8-vs-fp32 dispatch {moe.get('dispatch_int8_vs_fp32')!r})")
-EOF
-then
-    echo "premerge: perf lane failed" >&2
-    exit 1
-fi
-
-echo "== premerge gate 4/4: /metrics scrape + /timeline + /criticalpath + /comms + /integrity lane =="
-# End-to-end over the REAL plumbing: the bench run's instrument snapshot
-# is published to a live RendezvousServer via the same heartbeat PUT
-# workers use, then scraped back over plain HTTP from GET /metrics; the
-# bench's trace payload is published to PUT /trace as two ranks (the
-# second a relabeled copy with a deliberate clock shift + matching
-# offset, so offset correction is exercised), GET /timeline is fetched
-# and must parse as Chrome-trace JSON with >=2 rank tracks, and the
-# skew gauges must appear on the scrape. Both bodies are archived as
-# build artifacts ($PREMERGE_ARTIFACTS, default /tmp/premerge-artifacts)
-# alongside the metrics snapshot. Fails if any endpoint is unreachable,
-# any line flunks the strict Prometheus-text validator, or the core
-# instrument set (collective dispatch histograms, heartbeat gauge,
-# goodput counters) is absent.
-if ! JAX_PLATFORMS=cpu python - "$msnap" "$tsnap" "$ARTIFACTS" "$csnap" "$memsnap" <<'EOF'
-import copy
-import json
-import os
-import socket
-import sys
-import urllib.request
-
-import numpy as np
-
-from horovod_tpu import integrity, metrics
-from horovod_tpu.runner.http.kv_server import KVClient, RendezvousServer
-
-with open(sys.argv[1]) as f:
-    snap = json.load(f)
-if not isinstance(snap, list) or not snap:
-    sys.exit("premerge metrics lane: bench wrote an empty snapshot")
-with open(sys.argv[2]) as f:
-    trace = json.load(f)
-if not isinstance(trace, dict) or not trace.get("steps"):
-    sys.exit("premerge timeline lane: bench wrote an empty trace payload")
-artifacts = sys.argv[3]
-with open(sys.argv[4]) as f:
-    comms = json.load(f)
-if not isinstance(comms, dict) or comms.get("status") != "ok":
-    sys.exit("premerge comms lane: bench wrote no fitted comms payload "
-             f"(status={comms.get('status') if isinstance(comms, dict) else comms!r})")
-with open(sys.argv[5]) as f:
-    mempayload = json.load(f)
-if not isinstance(mempayload, dict) or mempayload.get("status") != "ok":
-    sys.exit("premerge memory lane: bench wrote no measured memory payload "
-             f"(status={mempayload.get('status') if isinstance(mempayload, dict) else mempayload!r})")
-server = RendezvousServer(host="127.0.0.1")
-server.start()
-server.set_cluster_info(world_np=2)
-try:
-    client = KVClient("127.0.0.1", server.port)
-    # Two ranks' integrity fingerprints of the SAME state (the bitwise-
-    # agreement steady state) piggyback the heartbeats, so GET
-    # /integrity proves the voting plane's collection + vote over the
-    # real plumbing with >=2 rank digests.
-    iparams = {"w": np.arange(8, dtype=np.float32)}
-    iopt = {"m": np.zeros(8, dtype=np.float32)}
-    irecs = [integrity.make_record(iparams, iopt, step=3, rank=r,
-                                   host=f"bench-r{r}", generation=1)
-             for r in (0, 1)]
-    client.put("heartbeat", socket.gethostname(), json.dumps(
-        {"rank": 0, "steps": 1, "commits": 0, "metrics": snap,
-         "integrity": irecs[0],
-         "comms": dict(comms, rank="0", host="bench-r0"),
-         "memory": dict(mempayload, rank=0, host="bench-r0")}).encode())
-    # A second rank's comms payload (relabeled) so GET /comms proves the
-    # cluster merge over the real heartbeat plumbing with >=2 ranks.
-    client.put("heartbeat", "bench-r1", json.dumps(
-        {"rank": 1, "steps": 1, "commits": 0,
-         "integrity": irecs[1],
-         "comms": dict(comms, rank="1", host="bench-r1"),
-         "memory": dict(mempayload, rank=1, host="bench-r1")}).encode())
-    # Publish the bench trace as rank 0, plus a relabeled copy as rank 1
-    # whose wall clocks are shifted +5s with the matching measured
-    # offset (-5s): after correction both ranks must land on one
-    # timebase, which the skew gauges then read as ~zero lateness.
-    SHIFT = 5.0
-    trace0 = dict(trace, rank="0", host="bench-r0", clock_offset_s=0.0)
-    trace1 = copy.deepcopy(trace)
-    trace1.update(rank="1", host="bench-r1", clock_offset_s=-SHIFT)
-    for steprec in trace1.get("steps", []):
-        steprec["t"] = steprec.get("t", 0) + SHIFT
-        for sp in steprec.get("spans", []):
-            sp["t"] = sp.get("t", 0) + SHIFT
-    client.put("trace", "bench-r0", json.dumps(trace0).encode())
-    client.put("trace", "bench-r1", json.dumps(trace1).encode())
-    url = f"http://127.0.0.1:{server.port}/metrics"
-    with urllib.request.urlopen(url, timeout=10) as r:
-        if r.status != 200:
-            sys.exit(f"premerge metrics lane: {url} answered {r.status}")
-        text = r.read().decode()
-    parsed = metrics.validate_prometheus_text(text)
-    required = (
-        "hvd_collective_latency_seconds",
-        "hvd_collective_payload_bytes",
-        "hvd_heartbeat_age_seconds",
-        "hvd_goodput_productive_seconds_total",
-        "hvd_goodput_lost_seconds_total",
-        "hvd_world_generation",
-        "hvd_collective_skew_seconds",
-        "hvd_straggler_score",
-        "hvd_checkpoint_seconds",
-        "hvd_peer_replication_bytes",
-        "hvd_param_gather_bytes",
-        "hvd_param_gather_seconds",
-        "hvd_resident_state_bytes",
-        "hvd_fsdp_prefetch_overlap_ratio",
-        # 2-D (batch, model) mesh plane: zero-materialized per axis (0 =
-        # flat 1-D wire, absence = not measuring).
-        "hvd_mesh_axis_size",
-        "hvd_policy_decisions_total",
-        "hvd_policy_spare_hosts",
-        "hvd_driver_epoch",
-        "hvd_driver_lost_total",
-        "hvd_link_bandwidth_bytes_per_second",
-        "hvd_link_latency_seconds",
-        "hvd_collective_efficiency_ratio",
-        "hvd_comms_residual_seconds",
-        # Comms planner: zero-materialized (0 = planner off, absence =
-        # not measuring) plus per-algorithm dispatch counts.
-        "hvd_planner_plans_total",
-        "hvd_planner_replans_total",
-        "hvd_planner_dispatch_total",
-        # SDC defense plane: zero-materialized so a clean run still
-        # reports the instruments (clean run != not measuring).
-        "hvd_integrity_checks_total",
-        "hvd_integrity_divergence_total",
-        "hvd_integrity_quarantined_ranks",
-        "hvd_nonfinite_steps_total",
-        "hvd_rewinds_total",
-        # Step-time attribution plane: zero-materialized likewise; the
-        # bench's synced bench_phases step sets the phase/exposed-comm
-        # gauges to real values.
-        "hvd_step_phase_seconds",
-        "hvd_exposed_comm_seconds",
-        "hvd_overlap_hidden_ratio",
-        "hvd_mfu_ratio",
-        "hvd_step_regression_score",
-        # Expert-parallel MoE plane: zero-materialized at import so the
-        # scrape always carries them (0 routed bytes = no MoE step ran,
-        # absence = not measuring).
-        "hvd_moe_dispatch_bytes",
-        "hvd_moe_tokens_dropped_total",
-        "hvd_moe_expert_load",
-        "hvd_alltoall_latency_seconds",
-        # Training→serving bridge: the bench's serving lane hot-swaps a
-        # real ModelServer under a request hammer, so the swap counter/
-        # histogram carry real samples; the rejection counter is
-        # zero-materialized per reason.
-        "hvd_serve_model_age_seconds",
-        "hvd_serve_swaps_total",
-        "hvd_serve_rejected_publishes_total",
-        "hvd_serve_requests_total",
-        "hvd_serve_swap_seconds",
-        # HBM memory observatory: all four zero-materialized, and the
-        # bench's mode lanes note real resident bytes into the kind
-        # gauge (0 = nothing resident, absence = not measuring).
-        "hvd_hbm_bytes",
-        "hvd_hbm_watermark_bytes",
-        "hvd_hbm_headroom_ratio",
-        "hvd_hbm_model_residual_bytes",
-    )
-    missing = [m for m in required
-               if not parsed.get(m, {}).get("samples")]
-    if missing:
-        sys.exit(
-            f"premerge metrics lane: core instruments missing samples "
-            f"from the scrape: {missing}")
-    # The 2-D mesh instruments must carry BOTH per-axis cells — a scrape
-    # with the family present but an axis cell missing reads as "flat
-    # wire" when it might mean "not measuring that axis".
-    for fam in ("hvd_mesh_axis_size", "hvd_param_gather_bytes"):
-        axes = {labels.get("axis")
-                for labels, _ in parsed[fam]["samples"]}
-        if not {"batch", "model"} <= axes:
-            sys.exit(
-                f"premerge metrics lane: {fam} is missing per-axis "
-                f"cells (got axes {sorted(a for a in axes if a)!r}, "
-                f"need both 'batch' and 'model')")
-    dispatches = sum(
-        v for labels, v in parsed["hvd_collective_latency_seconds"]["samples"]
-        if labels.get("le") == "+Inf")
-    if dispatches < 1:
-        sys.exit("premerge metrics lane: dispatch histogram is empty "
-                 "(bench recorded no eager collectives)")
-    skews = [v for _, v in parsed["hvd_collective_skew_seconds"]["samples"]]
-    if any(s > 1.0 for s in skews):
-        sys.exit(
-            f"premerge timeline lane: offset correction failed — shifted "
-            f"replica shows residual skew {skews} (expected ~0)")
-    # Merged timeline over HTTP: valid Chrome trace JSON, >=2 rank tracks.
-    turl = f"http://127.0.0.1:{server.port}/timeline"
-    with urllib.request.urlopen(turl, timeout=10) as r:
-        if r.status != 200:
-            sys.exit(f"premerge timeline lane: {turl} answered {r.status}")
-        tbody = r.read()
-    merged = json.loads(tbody)
-    events = merged.get("traceEvents")
-    if not isinstance(events, list) or not events:
-        sys.exit("premerge timeline lane: /timeline has no traceEvents")
-    spans = [e for e in events if e.get("ph") == "X"]
-    bad = [e for e in spans
-           if not isinstance(e.get("ts"), (int, float))
-           or not isinstance(e.get("dur"), (int, float))]
-    if bad:
-        sys.exit(f"premerge timeline lane: malformed span events: {bad[:3]}")
-    pids = {e.get("pid") for e in spans}
-    if len(pids) < 2:
-        sys.exit(
-            f"premerge timeline lane: expected >=2 rank tracks, got "
-            f"pids={sorted(pids)}")
-    # Step attribution over HTTP: the 2-rank bench trace must analyze
-    # into a per-rank phase decomposition whose phases sum to the step
-    # wall time within 5%, with a named gating rank on every
-    # critical-path collective (the ISSUE-13 acceptance contract).
-    aurl = f"http://127.0.0.1:{server.port}/criticalpath"
-    with urllib.request.urlopen(aurl, timeout=10) as r:
-        if r.status != 200:
-            sys.exit(f"premerge attribution lane: {aurl} answered "
-                     f"{r.status}")
-        abody = r.read()
-    cpath = json.loads(abody)
-    if cpath.get("status") != "ok":
-        sys.exit(
-            f"premerge attribution lane: /criticalpath status "
-            f"{cpath.get('status')!r} (expected 'ok' — did the bench "
-            f"trace lose its synced bench_phases step?)")
-    agroups = cpath.get("groups") or []
-    newest = agroups[-1]
-    aranks = newest.get("ranks") or {}
-    if len(aranks) < 2:
-        sys.exit(
-            f"premerge attribution lane: expected >=2 rank "
-            f"decompositions, got {sorted(aranks)}")
-    for arank, ainfo in aranks.items():
-        total = sum((ainfo.get("phases") or {}).values())
-        wall = ainfo.get("wall_s") or 0.0
-        if wall <= 0 or abs(total - wall) > 0.05 * wall:
-            sys.exit(
-                f"premerge attribution lane: rank {arank} phases sum to "
-                f"{total:.6f}s vs step wall {wall:.6f}s (must agree "
-                f"within 5%; phases={ainfo.get('phases')})")
-    acolls = [n for n in (newest.get("critical_path") or [])
-              if n.get("kind") == "collective"]
-    if not acolls:
-        sys.exit("premerge attribution lane: critical path has no "
-                 "collective barrier nodes")
-    unnamed = [n for n in acolls if not n.get("gating_rank")
-               and n.get("gating_rank") != 0]
-    if unnamed:
-        sys.exit(
-            f"premerge attribution lane: critical-path collectives "
-            f"without a named gating rank: {unnamed[:3]}")
-    with open(os.path.join(artifacts, "criticalpath.json"), "wb") as f:
-        f.write(abody)
-    # Cluster-merged comms model over HTTP: >=2 rank payloads, fitted.
-    curl = f"http://127.0.0.1:{server.port}/comms"
-    with urllib.request.urlopen(curl, timeout=10) as r:
-        if r.status != 200:
-            sys.exit(f"premerge comms lane: {curl} answered {r.status}")
-        cbody = r.read()
-    cmerged = json.loads(cbody)
-    if cmerged.get("status") != "ok":
-        sys.exit(
-            f"premerge comms lane: /comms status "
-            f"{cmerged.get('status')!r} (expected 'ok')")
-    crank_payloads = cmerged.get("ranks") or {}
-    if len(crank_payloads) < 2:
-        sys.exit(
-            f"premerge comms lane: expected >=2 rank payloads in the "
-            f"/comms merge, got {sorted(crank_payloads)}")
-    if not cmerged.get("cluster"):
-        sys.exit("premerge comms lane: /comms cluster aggregate is empty")
-    # Cluster-merged memory observatory over HTTP: >=2 rank payloads
-    # with measured resident breakdowns, summed per kind in the cluster
-    # aggregate (the same heartbeat piggyback plumbing as /comms).
-    murl = f"http://127.0.0.1:{server.port}/memory"
-    with urllib.request.urlopen(murl, timeout=10) as r:
-        if r.status != 200:
-            sys.exit(f"premerge memory lane: {murl} answered {r.status}")
-        mbody = r.read()
-    mmerged = json.loads(mbody)
-    if mmerged.get("status") != "ok":
-        sys.exit(
-            f"premerge memory lane: /memory status "
-            f"{mmerged.get('status')!r} (expected 'ok')")
-    mrank_payloads = mmerged.get("ranks") or {}
-    if len(mrank_payloads) < 2:
-        sys.exit(
-            f"premerge memory lane: expected >=2 rank payloads in the "
-            f"/memory merge, got {sorted(mrank_payloads)}")
-    mcluster = mmerged.get("cluster") or {}
-    if not mcluster.get("resident_bytes"):
-        sys.exit("premerge memory lane: /memory cluster aggregate has "
-                 f"no resident byte breakdown (got {mcluster!r})")
-    with open(os.path.join(artifacts, "memory.json"), "wb") as f:
-        f.write(mbody)
-    # Integrity voting plane over HTTP: both piggybacked fingerprints
-    # collected, and the newest complete group votes clean (bitwise
-    # agreement is the steady state the plane certifies).
-    iurl = f"http://127.0.0.1:{server.port}/integrity"
-    with urllib.request.urlopen(iurl, timeout=10) as r:
-        if r.status != 200:
-            sys.exit(f"premerge integrity lane: {iurl} answered {r.status}")
-        ibody = r.read()
-    imerged = json.loads(ibody)
-    if imerged.get("status") != "ok":
-        sys.exit(f"premerge integrity lane: /integrity status "
-                 f"{imerged.get('status')!r} (expected 'ok')")
-    irank_recs = imerged.get("records") or {}
-    if len(irank_recs) < 2:
-        sys.exit(
-            f"premerge integrity lane: expected >=2 rank digests in the "
-            f"/integrity collection, got {sorted(irank_recs)}")
-    if any(not rec.get("digest") for rec in irank_recs.values()):
-        sys.exit("premerge integrity lane: a collected record carries "
-                 "no state digest")
-    ivote = imerged.get("vote")
-    if not ivote or ivote.get("divergent") or ivote.get("voters", 0) < 2:
-        sys.exit(
-            f"premerge integrity lane: expected a clean 2-voter verdict "
-            f"on the newest complete group, got {ivote!r}")
-    with open(os.path.join(artifacts, "integrity.json"), "wb") as f:
-        f.write(ibody)
-    # Training→serving bridge over HTTP: publish one commit record to
-    # the modelstate scope through the real client, then prove GET
-    # /model assembles it back digest-exact — and that a torn publish
-    # (truncated body) is 422'd with the good record left authoritative.
-    import pickle
-    import urllib.error
-
-    from horovod_tpu import peercheck
-    srec = peercheck.ReplicaRecord(
-        rank=0, step=7, generation=server.version, world_size=1,
-        payload=pickle.dumps({"params": {"w": [1, 2, 3]},
-                              "param_layout": "full", "row": None,
-                              "layout": "none", "extras": {}}),
-        has_params=True)
-    sblob = peercheck.encode_record(srec)
-    client.put("modelstate", "0", sblob)
-    try:
-        client.put("modelstate", "0", sblob[:-4])
-        sys.exit("premerge serving lane: torn modelstate PUT was accepted")
-    except urllib.error.HTTPError as e:
-        if e.code != 422:
-            sys.exit(f"premerge serving lane: torn PUT answered {e.code} "
-                     "(expected 422)")
-    surl = f"http://127.0.0.1:{server.port}/model"
-    with urllib.request.urlopen(surl, timeout=10) as r:
-        if r.status != 200:
-            sys.exit(f"premerge serving lane: {surl} answered {r.status}")
-        sbody = r.read()
-    sview = json.loads(sbody)
-    if sview.get("status") != "ok":
-        sys.exit(f"premerge serving lane: /model status "
-                 f"{sview.get('status')!r} (expected 'ok')")
-    want_digest = peercheck.replica_set_digest([srec])
-    got = (sview.get("model") or {}).get("digest")
-    if got != want_digest:
-        sys.exit(f"premerge serving lane: /model digest {got!r} != "
-                 f"published record digest {want_digest!r}")
-    if sview.get("rejected", 0) < 1:
-        sys.exit("premerge serving lane: the torn PUT was not counted "
-                 "as a rejected publish")
-    with open(os.path.join(artifacts, "model.json"), "wb") as f:
-        f.write(sbody)
-    with open(os.path.join(artifacts, "comms.json"), "wb") as f:
-        f.write(cbody)
-    with open(os.path.join(artifacts, "timeline.json"), "wb") as f:
-        f.write(tbody)
-    with open(os.path.join(artifacts, "metrics_snapshot.json"), "w") as f:
-        json.dump(snap, f)
-    with open(os.path.join(artifacts, "metrics_scrape.prom"), "w") as f:
-        f.write(text)
-    print(f"premerge metrics lane: ok ({len(parsed)} metric families, "
-          f"{dispatches:.0f} dispatches in the latency histogram)")
-    print(f"premerge timeline lane: ok ({len(spans)} spans across "
-          f"{len(pids)} rank tracks; archived to {artifacts})")
-    print(f"premerge attribution lane: ok (/criticalpath analyzed "
-          f"{len(agroups)} group(s), {len(aranks)} rank decompositions, "
-          f"{len(acolls)} gated collective(s) on the critical path)")
-    print(f"premerge comms lane: ok (/comms merged "
-          f"{len(crank_payloads)} rank payloads, "
-          f"{len(cmerged['cluster'])} cluster fit keys)")
-    print(f"premerge memory lane: ok (/memory merged "
-          f"{len(mrank_payloads)} rank payloads, cluster resident "
-          f"{mcluster.get('resident_total')!r} bytes)")
-    print(f"premerge integrity lane: ok (/integrity collected "
-          f"{len(irank_recs)} rank digests, clean "
-          f"{ivote['voters']}-voter verdict)")
-    print(f"premerge serving lane: ok (/model serves the published "
-          f"commit digest-exact; torn publish 422'd and counted)")
-finally:
-    server.stop()
-EOF
-then
-    echo "premerge: metrics scrape/timeline lane failed" >&2
-    exit 1
-fi
-
-# Scheduler observability sub-lane: a MultiJobScheduler with two jobs on
-# a shared pool serves GET /metrics (the pool/job gauges and the
-# decision counter must be present and zero-materialized BEFORE any
-# decision executes — 0 means "nothing decided", absence means "not
-# measuring") and GET /pool (the per-host lease/condemnation dump with
-# >=2 job entries carrying the SLO math) over real HTTP.
-if ! JAX_PLATFORMS=cpu python - <<'EOF'
-import json
-import sys
-import tempfile
-import urllib.request
-
-from horovod_tpu import metrics
-from horovod_tpu.runner.elastic.scheduler import (
-    JobSpec, MultiJobScheduler, SCHED_ACTIONS)
-
-workdir = tempfile.mkdtemp(prefix="premerge-sched-")
-sched = MultiJobScheduler(
-    [JobSpec(job_id="trainA", command=["true"], min_np=2, max_np=4,
-             priority=10, target_goodput=0.8),
-     JobSpec(job_id="trainB", command=["true"], min_np=1, max_np=2,
-             priority=1)],
-    ["h1", "h2", "h3", "h4"], workdir)
-sched._start_http()
-try:
-    base = f"http://127.0.0.1:{sched.port}"
-    with urllib.request.urlopen(f"{base}/metrics", timeout=10) as r:
-        if r.status != 200:
-            sys.exit(f"premerge scheduler lane: /metrics answered "
-                     f"{r.status}")
-        text = r.read().decode()
-    parsed = metrics.validate_prometheus_text(text)
-    required = ("hvd_pool_hosts", "hvd_pool_spares",
-                "hvd_pool_blacklisted", "hvd_jobs_running",
-                "hvd_jobs_preempted_total", "hvd_sched_decisions_total")
-    missing = [m for m in required
-               if not parsed.get(m, {}).get("samples")]
-    if missing:
-        sys.exit(f"premerge scheduler lane: instruments missing from "
-                 f"the scrape: {missing}")
-    actions = {l.get("action"): v for l, v in
-               parsed["hvd_sched_decisions_total"]["samples"]}
-    if actions != {a: 0.0 for a in SCHED_ACTIONS}:
-        sys.exit(
-            f"premerge scheduler lane: hvd_sched_decisions_total must "
-            f"zero-materialize all of {SCHED_ACTIONS}, got {actions!r}")
-    if parsed["hvd_pool_hosts"]["samples"] != [({}, 4.0)]:
-        sys.exit(f"premerge scheduler lane: hvd_pool_hosts wrong: "
-                 f"{parsed['hvd_pool_hosts']['samples']!r}")
-    with urllib.request.urlopen(f"{base}/pool", timeout=10) as r:
-        if r.status != 200:
-            sys.exit(f"premerge scheduler lane: /pool answered "
-                     f"{r.status}")
-        pool = json.loads(r.read().decode())
-    jobs = pool.get("jobs") or {}
-    if len(jobs) < 2:
-        sys.exit(f"premerge scheduler lane: GET /pool carries "
-                 f"{len(jobs)} job entries (need >=2): {sorted(jobs)}")
-    for jid in ("trainA", "trainB"):
-        ent = jobs.get(jid) or {}
-        for field in ("state", "priority", "min_np", "max_np",
-                      "target_goodput", "lease"):
-            if field not in ent:
-                sys.exit(f"premerge scheduler lane: /pool job {jid!r} "
-                         f"missing {field!r}: {ent!r}")
-    if len(pool.get("hosts") or []) != 4:
-        sys.exit(f"premerge scheduler lane: /pool hosts wrong: "
-                 f"{pool.get('hosts')!r}")
-    print(f"premerge scheduler lane: ok (/metrics zero-materialized "
-          f"{len(required)} pool/job instruments over "
-          f"{sorted(SCHED_ACTIONS)}; /pool serves {len(jobs)} jobs on "
-          f"{len(pool['hosts'])} pool hosts)")
-finally:
-    sched._httpd.shutdown()
-    sched._httpd.server_close()
-EOF
-then
-    echo "premerge: scheduler observability lane failed" >&2
     exit 1
 fi
 echo "premerge: all gates passed"
