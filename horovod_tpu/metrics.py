@@ -900,6 +900,13 @@ ATTN_TILES_LAST = gauge(
     "tiles its mask leaves nothing of): set at trace time, as "
     "hvd_grad_sync_last_bytes is.",
     ("kind",))
+ATTN_GROUP_LAST = gauge(
+    "hvd_attn_group_last",
+    "(batch x head) slices a grid step of the LAST traced single-tile "
+    "flash-attention call takes, by kernel (fwd: the direct-softmax "
+    "forward; bwd: the fused backward): set at trace time, as "
+    "hvd_attn_tiles_last is.",
+    ("kernel",))
 ALLTOALL_LATENCY = histogram(
     "hvd_alltoall_latency_seconds",
     "Wall time of alltoall exchanges (eager dispatches and MoE "

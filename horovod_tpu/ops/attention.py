@@ -11,8 +11,14 @@ un-normalized output ``o`` in fp32, rescale on each new tile.
 Two implementations, one semantics:
 - ``flash_attention``: Pallas TPU kernels (MXU-tiled, fp32 accumulators in
   VMEM scratch); ``interpret=True`` makes them runnable on the CPU dev
-  mesh. A sequence that is one tile runs a grid of (batch*heads,) slices:
-  a direct-softmax forward and one fused backward kernel. Longer ones run
+  mesh. A sequence that is one tile runs a direct-softmax forward and one
+  fused backward kernel on a grid of (batch*heads // G,): a grid step
+  takes a group of G slices and computes them batched, because one
+  128 x 64 slice alone is a chain of small dependent steps that leaves
+  the units idle. ``_group_size`` picks G from the call's shapes (the
+  largest divisor of batch*heads whose blocks and scores fit
+  ``GROUP_BUDGET_BYTES`` of VMEM); the gauge
+  ``hvd_attn_group_last{kernel}`` says which. Longer ones run
   (batch*heads, Q blocks, K blocks) with K innermost in the forward and
   the dq kernel and (batch*heads, K blocks, Q blocks) with Q innermost in
   the dk/dv kernel, one K/V (or Q/dO) tile resident a step. Differentiable:
@@ -141,11 +147,102 @@ def _auto_block(seq_len: int) -> int:
     scores (1 MB) sit comfortably in VMEM. Short sequences (< 128, the
     dev/interpret regime) run as one block; longer non-multiple-of-128
     sequences fall back to 128 so the divisibility check still raises
-    with its pad-upstream guidance instead of a VMEM blowup."""
+    with its pad-upstream guidance instead of a VMEM blowup. A sequence
+    that is one block leaves a grid over (batch x head) slices only;
+    ``_group_size`` says how many of them a step of that grid takes."""
     for cand in (512, 256, 128):
         if seq_len % cand == 0:
             return cand
     return seq_len if seq_len < 128 else 128
+
+
+# What a grid step of a single-tile kernel may hold in VMEM, as
+# ``_group_footprint`` counts it: three quarters of the 16 MiB a kernel
+# gets by default, the rest left for what the count does not see.
+# ``_group_size`` reads nothing else. Set on a v5e at BERT-Large's two
+# shapes (PERF.md, PR 29).
+GROUP_BUDGET_BYTES = 12 * 1024 * 1024
+
+# What a slice of each single-tile kernel holds, as ``_group_footprint``
+# counts it. Forward: q, o / k, v / lse; the scores and their exponentials.
+_FWD_SLICE = dict(q_blocks=2, k_blocks=2, rows=1, temporaries=2)
+# Fused backward: q, dO, dq / k, v, dk, dv / lse, delta, g_lse; the
+# probabilities, dP and dS beside their casts to the stored dtype.
+_BWD_SLICE = dict(q_blocks=3, k_blocks=4, rows=3, temporaries=4)
+
+
+def _vmem_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """Bytes a [rows, cols] block takes in VMEM: rows padded to the
+    dtype's sublane tile (8 of float32, 16 of bf16), cols to 128 lanes."""
+    sublanes = 8 * max(1, 4 // itemsize)
+    return (-(-rows // sublanes) * sublanes) * (-(-cols // 128) * 128) \
+        * itemsize
+
+
+def _group_footprint(group, block_q, block_k, d, itemsize, q_blocks,
+                     k_blocks, rows, temporaries) -> int:
+    """VMEM bytes of one grid step that takes ``group`` slices: per slice
+    ``q_blocks`` [block_q, d] and ``k_blocks`` [block_k, d] blocks of the
+    stored dtype and ``rows`` float32 [1, block_q] rows, all held twice
+    (the pipeline fetches the next step's while this one computes), and
+    ``temporaries`` float32 [block_q, block_k] arrays, which the batched
+    computation keeps for every slice of the group at once."""
+    blocks = (q_blocks * _vmem_bytes(block_q, d, itemsize)
+              + k_blocks * _vmem_bytes(block_k, d, itemsize)
+              + rows * _vmem_bytes(1, block_q, 4))
+    return group * (2 * blocks
+                    + temporaries * _vmem_bytes(block_q, block_k, 4))
+
+
+def _group_size(bh, block_q, block_k, d, itemsize, **slice_counts) -> int:
+    """How many (batch x head) slices a grid step of a single-tile kernel
+    takes: the largest divisor of ``bh`` whose ``_group_footprint`` fits
+    ``GROUP_BUDGET_BYTES``, and 1 where none does. What a 128 x 64 slice
+    costs alone is its own chain of product, softmax and product, each
+    waiting for the last; the slices of a group are independent chains in
+    one block of code, which the scheduler interleaves (0.76 -> 0.25 ms a
+    forward call at BERT-Large's S=128, in groups of 24). At S=512 a
+    slice's scores are 1 MB a temporary and the group is 3 or 2."""
+    fits = GROUP_BUDGET_BYTES // _group_footprint(
+        1, block_q, block_k, d, itemsize, **slice_counts)
+    return max((group for group in range(1, min(bh, fits) + 1)
+                if bh % group == 0), default=1)
+
+
+def _group_index(ref):
+    """The index of a grid step's slices in its blocks' leading dimension:
+    a group of one is slice 0, the program these kernels were before they
+    took groups; a larger one is all of it, and the kernel's products and
+    reductions run batched over that dimension."""
+    return 0 if ref.shape[0] == 1 else slice(None)
+
+
+def _dot(a, b, a_dim, b_dim):
+    """Contract dimension ``a_dim`` of ``a`` with ``b_dim`` of ``b``, both
+    counted from a slice's own two ([rows, cols]); float32 accumulation.
+    Operands with a leading dimension of slices give one product a slice."""
+    lead = a.ndim - 2
+    batch = tuple(range(lead))
+    return jax.lax.dot_general(
+        a, b, (((a_dim + lead,), (b_dim + lead,)), (batch, batch)),
+        preferred_element_type=jnp.float32)
+
+
+def _group_specs(kernel, qr, block_q, block_k, slice_counts):
+    """The group a single-tile call's grid steps take and the block specs
+    of its operands: ``(G, [G, block_q, D], [G, block_k, D], float32
+    [G, 1, block_q])``. Sets ``hvd_attn_group_last{kernel}`` at trace
+    time, as ``_record_tiles`` does its gauge."""
+    from .. import metrics
+
+    bh, _, d = qr.shape
+    group = _group_size(bh, block_q, block_k, d, qr.dtype.itemsize,
+                        **slice_counts)
+    metrics.ATTN_GROUP_LAST.set(group, kernel=kernel)
+    return (group,
+            pl.BlockSpec((group, block_q, d), lambda i: (i, 0, 0)),
+            pl.BlockSpec((group, block_k, d), lambda i: (i, 0, 0)),
+            pl.BlockSpec((group, 1, block_q), lambda i: (i, 0, 0)))
 
 
 def _causal_mask(qi, j, block_q, block_k, q_offset, k_offset):
@@ -392,30 +489,26 @@ def _flash_fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
     tile there is nothing to run online-softmax OVER — the running-max
     rescale machinery (scratch init/rw, correction exp, accumulator
     rescale) is pure overhead. Direct softmax, same outputs/sentinels
-    as the general kernel. Grid (BH,)."""
-    q = q_ref[0]
-    k_tile = k_ref[0]
-    v_tile = v_ref[0]
-    s = jax.lax.dot_general(
-        q, k_tile, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale
+    as the general kernel. Grid (BH // G,): the blocks hold a group of G
+    slices, each computed as it was alone."""
+    g = _group_index(q_ref)
+    q = q_ref[g]
+    k_tile = k_ref[g]
+    v_tile = v_ref[g]
+    s = _dot(q, k_tile, 1, 1) * scale
     if causal:
         mask = _causal_mask(0, 0, block_q, block_k, q_offset, k_offset)
         s = jnp.where(mask, s, NEG_INF)
     m = s.max(axis=-1)
-    p = jnp.exp(s - m[:, None])
+    p = jnp.exp(s - m[..., None])
     if causal:
         p = jnp.where(mask, p, 0.0)
     l = p.sum(axis=-1)
     empty = l == 0.0
     safe_l = jnp.where(empty, 1.0, l)
-    acc = jax.lax.dot_general(
-        p.astype(v_tile.dtype), v_tile, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    o_ref[0] = (acc / safe_l[:, None]).astype(o_ref.dtype)
-    lse_ref[0, 0, :] = jnp.where(empty, LSE_MASKED, m + jnp.log(safe_l))
+    acc = _dot(p.astype(v_tile.dtype), v_tile, 1, 0)
+    o_ref[g] = (acc / safe_l[..., None]).astype(o_ref.dtype)
+    lse_ref[g, 0, :] = jnp.where(empty, LSE_MASKED, m + jnp.log(safe_l))
 
 
 def _flash_dqkv_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
@@ -423,45 +516,32 @@ def _flash_dqkv_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
                              *, causal: bool, scale: float, block_q: int,
                              block_k: int, q_offset: int, k_offset: int):
     """Fused single-tile backward: when the whole sequence is ONE
-    (block_q, block_k) tile (the BERT-Large S=512 shape), the separate
+    (block_q, block_k) tile (the BERT-Large shapes), the separate
     dQ and dK/dV passes each recompute the identical s → p → dp → ds
     chain. This kernel computes the chain once and emits all three
     grads — roughly a third of the backward softmax/VPU work saved.
-    Grid (BH,) only; the callers route here iff nq == nk == 1."""
-    q = q_ref[0]
-    k_tile = k_ref[0]
-    v_tile = v_ref[0]
-    do = do_ref[0]
-    lse = lse_ref[0, 0, :]
-    delta = delta_ref[0, 0, :]
-    glse = glse_ref[0, 0, :]
+    Grid (BH // G,), the blocks a group of G slices as in the forward;
+    the callers route here iff nq == nk == 1."""
+    g = _group_index(q_ref)
+    q = q_ref[g]
+    k_tile = k_ref[g]
+    v_tile = v_ref[g]
+    do = do_ref[g]
+    lse = lse_ref[g, 0, :]
+    delta = delta_ref[g, 0, :]
+    glse = glse_ref[g, 0, :]
 
-    s = jax.lax.dot_general(
-        q, k_tile, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale  # [block_q, block_k]
+    s = _dot(q, k_tile, 1, 1) * scale  # [block_q, block_k]
     if causal:
         mask = _causal_mask(0, 0, block_q, block_k, q_offset, k_offset)
         s = jnp.where(mask, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])
+    p = jnp.exp(s - lse[..., None])
     pw = p.astype(do.dtype)
-    dv_ref[0] = jax.lax.dot_general(
-        pw, do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).astype(dv_ref.dtype)
-    dp = jax.lax.dot_general(
-        do, v_tile, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    ds = (p * (dp - delta[:, None] + glse[:, None])).astype(q.dtype)
-    dq_ref[0] = (scale * jax.lax.dot_general(
-        ds, k_tile, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )).astype(dq_ref.dtype)
-    dk_ref[0] = (scale * jax.lax.dot_general(
-        ds, q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )).astype(dk_ref.dtype)
+    dv_ref[g] = _dot(pw, do, 0, 0).astype(dv_ref.dtype)
+    dp = _dot(do, v_tile, 1, 1)
+    ds = (p * (dp - delta[..., None] + glse[..., None])).astype(q.dtype)
+    dq_ref[g] = (scale * _dot(ds, k_tile, 1, 0)).astype(dq_ref.dtype)
+    dk_ref[g] = (scale * _dot(ds, q, 0, 0)).astype(dk_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -506,23 +586,19 @@ def _fwd_kernels(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
     Sk = kr.shape[1]
     scale = 1.0 / (D ** 0.5)
     if Sq == block_q and Sk == block_k:
-        # Single-tile sequences skip the online-softmax machinery.
+        # Single-tile sequences skip the online-softmax machinery, and a
+        # grid step takes a group of them.
+        group, q_spec, kv_spec, row_spec = _group_specs(
+            "fwd", qr, block_q, block_k, _FWD_SLICE)
         return pl.pallas_call(
             functools.partial(
                 _flash_fwd_single_kernel, causal=causal, scale=scale,
                 block_q=block_q, block_k=block_k,
                 q_offset=q_offset, k_offset=k_offset,
             ),
-            grid=(BH,),
-            in_specs=[
-                pl.BlockSpec((1, block_q, D), lambda bh: (bh, 0, 0)),
-                pl.BlockSpec((1, block_k, D), lambda bh: (bh, 0, 0)),
-                pl.BlockSpec((1, block_k, D), lambda bh: (bh, 0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, block_q, D), lambda bh: (bh, 0, 0)),
-                pl.BlockSpec((1, 1, Sq), lambda bh: (bh, 0, 0)),
-            ],
+            grid=(BH // group,),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=[q_spec, row_spec],
             out_shape=[
                 jax.ShapeDtypeStruct((BH, Sq, D), qr.dtype),
                 jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32),
@@ -593,31 +669,22 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
                     axis=-1)[:, None, :]  # [BH, 1, Sq]
 
     if Sq == block_q and Sk == block_k:
-        # Single-tile sequences (BERT-Large S=512 with auto-block):
-        # one fused kernel computes dq, dk, dv — the two-pass split
-        # below exists only to bound VMEM for many-tile sequences.
-        specs = [
-            pl.BlockSpec((1, block_q, D), lambda bh: (bh, 0, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh: (bh, 0, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh: (bh, 0, 0)),
-            pl.BlockSpec((1, block_q, D), lambda bh: (bh, 0, 0)),
-            pl.BlockSpec((1, 1, Sq), lambda bh: (bh, 0, 0)),
-            pl.BlockSpec((1, 1, Sq), lambda bh: (bh, 0, 0)),
-            pl.BlockSpec((1, 1, Sq), lambda bh: (bh, 0, 0)),
-        ]
+        # Single-tile sequences (BERT-Large with auto-block): one fused
+        # kernel computes dq, dk, dv for a group of slices a grid step —
+        # the two-pass split below exists only to bound VMEM for
+        # many-tile sequences.
+        group, q_spec, kv_spec, row_spec = _group_specs(
+            "bwd", qr, block_q, block_k, _BWD_SLICE)
         dq, dk, dv = pl.pallas_call(
             functools.partial(
                 _flash_dqkv_fused_kernel, causal=causal, scale=scale,
                 block_q=block_q, block_k=block_k, q_offset=q_offset,
                 k_offset=k_offset,
             ),
-            grid=(BH,),
-            in_specs=specs,
-            out_specs=[
-                pl.BlockSpec((1, block_q, D), lambda bh: (bh, 0, 0)),
-                pl.BlockSpec((1, block_k, D), lambda bh: (bh, 0, 0)),
-                pl.BlockSpec((1, block_k, D), lambda bh: (bh, 0, 0)),
-            ],
+            grid=(BH // group,),
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec,
+                      row_spec],
+            out_specs=[q_spec, kv_spec, kv_spec],
             out_shape=[
                 jax.ShapeDtypeStruct((BH, Sq, D), qr.dtype),
                 jax.ShapeDtypeStruct((BH, Sk, D), kr.dtype),

@@ -323,6 +323,158 @@ class TestCausalTileSkipping:
                 kind="skipped").get() == skipped
 
 
+# (BH, causal): odd, prime and composite counts of (batch x head) slices.
+GROUP_CASES = [
+    pytest.param(bh, causal, id=f"bh{bh}-{'causal' if causal else 'full'}")
+    for bh in (1, 2, 7, 12, 16, 96) for causal in (False, True)]
+
+# A budget at which the test shapes (S=32, D=16, float32) get groups of up
+# to 7 slices in the forward and 4 in the backward kernel, so that grids of
+# several steps of several slices run too; the module's own gives them one
+# group or two.
+SMALL_BUDGET = 1280 * 1024
+
+
+class TestSliceGroups:
+    """A grid step of the single-tile kernels takes a group of slices. A
+    slice's arithmetic is what it was, so every output and gradient equals
+    the ``G = 1`` program's (budget 0: the kernels before they took
+    groups) in every bit."""
+
+    @staticmethod
+    def run(monkeypatch, budget, bh, causal, q_off=0, k_off=0, S=32, D=16):
+        from horovod_tpu import metrics
+        from horovod_tpu.ops import attention as att
+
+        monkeypatch.setattr(att, "GROUP_BUDGET_BYTES", budget)
+        ks = jax.random.split(jax.random.PRNGKey(bh), 5)
+        q, k, v, g_out = (jax.random.normal(key, (bh, S, D), jnp.float32)
+                          for key in ks[:4])
+        g_lse = jax.random.normal(ks[4], (bh, 1, S), jnp.float32)
+
+        # not jitted: every call traces the kernels anew under the budget
+        def flash(q, k, v):
+            return att._flash_with_lse(q, k, v, causal, S, S, q_off, k_off,
+                                       True)
+
+        (out, lse), vjp = jax.vjp(flash, q, k, v)
+        dq, dk, dv = vjp((g_out, g_lse))
+        groups = tuple(int(metrics.ATTN_GROUP_LAST.labels(kernel=kernel).get())
+                       for kernel in ("fwd", "bwd"))
+        return [np.asarray(x) for x in (out, lse, dq, dk, dv)], groups
+
+    @pytest.mark.parametrize("budget", [None, SMALL_BUDGET],
+                             ids=["module-budget", "small-budget"])
+    @pytest.mark.parametrize("bh, causal", GROUP_CASES)
+    def test_grouped_kernels_equal_the_ungrouped_bit_for_bit(
+            self, monkeypatch, bh, causal, budget):
+        from horovod_tpu.ops import attention as att
+
+        budget = att.GROUP_BUDGET_BYTES if budget is None else budget
+        want, ones = self.run(monkeypatch, 0, bh, causal)
+        got, groups = self.run(monkeypatch, budget, bh, causal)
+        assert ones == (1, 1)
+        assert all(bh % g == 0 for g in groups)
+        if bh > 1 and bh % 2 == 0:
+            assert min(groups) > 1  # something is grouped at these sizes
+        for name, g, w in zip(("out", "lse", "dq", "dk", "dv"), got, want):
+            assert np.isfinite(g).all(), name
+            np.testing.assert_array_equal(g, w, err_msg=name)
+
+    @pytest.mark.parametrize("q_off, k_off", [
+        pytest.param(5, 0, id="q-ahead"),
+        pytest.param(0, 7, id="first-rows-masked"),
+        pytest.param(100, 117, id="far-offsets"),
+        pytest.param(0, 32, id="every-row-masked"),
+    ])
+    @pytest.mark.parametrize("bh", [7, 12])
+    def test_offsets_and_masked_rows_are_kept_per_slice(self, monkeypatch,
+                                                        bh, q_off, k_off):
+        from horovod_tpu.ops.attention import LSE_MASKED
+
+        want, _ = self.run(monkeypatch, 0, bh, True, q_off, k_off)
+        got, groups = self.run(monkeypatch, SMALL_BUDGET, bh, True, q_off,
+                               k_off)
+        assert groups == ((7, 1) if bh == 7 else (6, 4))
+        for name, g, w in zip(("out", "lse", "dq", "dk", "dv"), got, want):
+            assert np.isfinite(g).all(), name
+            np.testing.assert_array_equal(g, w, err_msg=name)
+        # rows whose keys all lie ahead carry the sentinel in every slice
+        masked = (q_off + np.arange(32)) < k_off
+        assert (got[1][:, 0, masked] == LSE_MASKED).all()
+        assert (got[1][:, 0, ~masked] < LSE_MASKED).all()
+        assert (got[0][:, masked] == 0).all()
+        assert (got[2][:, masked] == 0).all()
+
+    @pytest.mark.parametrize("bh", [1, 2, 7, 12, 16, 96, 384, 1536, 1543,
+                                    7919])
+    @pytest.mark.parametrize("seq", [128, 512])
+    @pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+    def test_the_group_divides_the_slices_and_fits_the_budget(self, bh, seq,
+                                                              kernel):
+        from horovod_tpu.ops import attention as att
+
+        counts = att._FWD_SLICE if kernel == "fwd" else att._BWD_SLICE
+        group = att._group_size(bh, seq, seq, 64, 2, **counts)
+        assert group >= 1 and bh % group == 0
+        if group > 1:
+            assert att._group_footprint(group, seq, seq, 64, 2, **counts) \
+                <= att.GROUP_BUDGET_BYTES
+        # the largest such divisor: no larger one fits
+        assert not any(
+            bh % g == 0 and att._group_footprint(
+                g, seq, seq, 64, 2, **counts) <= att.GROUP_BUDGET_BYTES
+            for g in range(group + 1, bh + 1))
+        if bh in (1543, 7919):  # primes past what the budget holds
+            assert group == 1
+
+    @pytest.mark.parametrize("kernel", ["fwd", "bwd"])
+    def test_the_group_does_not_grow_with_the_sequence(self, kernel):
+        from horovod_tpu.ops import attention as att
+
+        counts = att._FWD_SLICE if kernel == "fwd" else att._BWD_SLICE
+        groups = [att._group_size(1536, seq, seq, 64, 2, **counts)
+                  for seq in (16, 64, 128, 256, 512)]
+        assert groups == sorted(groups, reverse=True)
+        assert groups[2] > 1  # BERT's S=128 is grouped
+
+    def test_the_gauge_holds_the_group_of_the_last_traced_call(
+            self, monkeypatch):
+        from horovod_tpu import metrics
+        from horovod_tpu.ops import attention as att
+
+        q, k, v = make_qkv(B=3, H=4, S=32, D=16)
+
+        def loss(q, k, v):
+            return flash_attention(q, k, v, interpret=True).sum()
+
+        for budget in (SMALL_BUDGET, att.GROUP_BUDGET_BYTES):
+            monkeypatch.setattr(att, "GROUP_BUDGET_BYTES", budget)
+            jax.clear_caches()
+            want = (att._group_size(12, 32, 32, 16, 4, **att._FWD_SLICE),
+                    att._group_size(12, 32, 32, 16, 4, **att._BWD_SLICE))
+            # Lowered, not run: set while the calls are traced.
+            for kernel in ("fwd", "bwd"):
+                metrics.ATTN_GROUP_LAST.set(-1, kernel=kernel)
+            jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, v)
+            got = tuple(metrics.ATTN_GROUP_LAST.labels(kernel=kernel).get()
+                        for kernel in ("fwd", "bwd"))
+            assert got == want
+        jax.clear_caches()
+
+    def test_a_multi_tile_call_takes_no_group(self):
+        from horovod_tpu import metrics
+
+        q, k, v = make_qkv(B=1, H=4, S=64, D=16)
+        for kernel in ("fwd", "bwd"):
+            metrics.ATTN_GROUP_LAST.set(-1, kernel=kernel)
+        jax.jit(jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, block_q=32, block_k=32, interpret=True).sum())).lower(
+                q, k, v)
+        assert metrics.ATTN_GROUP_LAST.labels(kernel="fwd").get() == -1
+        assert metrics.ATTN_GROUP_LAST.labels(kernel="bwd").get() == -1
+
+
 class TestRingAttention:
     @pytest.mark.parametrize("causal", [False, True])
     def test_matches_dense(self, hvd, causal):

@@ -105,6 +105,49 @@ def test_the_flash_kernels_keep_the_name_the_benchmark_finds_them_by(
     assert phases.count("hvd.attn.bwd") == kernels - 1
 
 
+def test_berts_s128_kernels_take_a_group_of_slices_a_grid_step(one_chip):
+    """96 rows of 128 in 16 heads of 64: 1,536 single-tile slices, which
+    the forward and the fused backward kernel take ``G`` a grid step. The
+    grid is ``BH // G`` with ``G > 1`` as the gauge says, the blocks hold
+    ``G`` slices, the body is the ungrouped one's two and five products
+    (batched, no loop: its size does not grow with ``G``), and the
+    compiled step still holds one kernel of each under the name the
+    benchmark finds them by."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu import metrics, profiler
+    from horovod_tpu.ops.attention import flash_attention
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    bh = 96 * 16
+    shape = jax.ShapeDtypeStruct((96, 16, 128, 64), jnp.bfloat16,
+                                 sharding=one_chip)
+    lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        shape, shape, shape)
+    groups = [int(metrics.ATTN_GROUP_LAST.labels(kernel=kernel).get())
+              for kernel in ("fwd", "bwd")]
+    bodies = kernel_bodies(lowered.as_text())
+    assert len(bodies) == 2
+    for group, body, products in zip(groups, bodies, (2, 5)):
+        assert group > 1 and bh % group == 0
+        assert f"iteration_bounds = array<i64: {bh // group}>" in body
+        assert f"window_bounds = array<i64: {group}, 128, 64>" in body
+        assert f"window_bounds = array<i64: {group}, 1, 128>" in body
+        assert "scf.for" not in body
+        assert body.count("tpu.matmul") == products
+    found = kernel_instructions(lowered.compile().as_text())
+    assert len(found) == 2
+    with open(os.path.join(REPO_ROOT, "benchmark", "layer_metrics",
+                           "attn_kernel_ms.json")) as f:
+        wanted = re.compile(json.load(f)["kernel_names"])
+    assert all(wanted.search(name) for name, _ in found), found
+    phases = [profiler.phase_of(scope) for _, scope in found]
+    assert sorted(phases) == ["hvd.attn.bwd", "hvd.attn.fwd"]
+
+
 def test_olmoes_causal_kernels_compile_at_its_widths(one_chip):
     """One sequence of 4,096 in 16 heads of 128, causal: a grid of 16 x 8
     x 8 tiles of 512 through the multi-tile forward, dq and dkv kernels,

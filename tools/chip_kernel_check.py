@@ -3,8 +3,9 @@
     python tools/chip_kernel_check.py
 
 ``flash_attention`` forward and backward in bf16 against
-``blockwise_attention_reference`` in float32 at the single-tile shape
-BERT-Large uses (S512 D64), at a multi-tile causal shape (S2048 D128)
+``blockwise_attention_reference`` in float32 at the single-tile shapes
+BERT-Large uses (S512 and S128, D64: a grid step takes a group of slices),
+at 15 causal single-tile slices, at a multi-tile causal shape (S2048 D128)
 and at OLMoE's (one sequence of S4096, 16 heads of D128: a grid of 16 x 8 x 8
 tiles of 512). Compiled, never ``interpret=True``: off a TPU this exits
 non-zero.
@@ -89,6 +90,10 @@ def main() -> None:
     print(f"device: platform={d.platform} device_kind={d.device_kind!r} "
           f"count={len(jax.devices())}")
     check_flash(4, 16, 512, 64, causal=False)
+    # BERT's S=128: 1,536 single-tile slices, a group of them a grid step;
+    # and a count of slices that no power of two divides
+    check_flash(96, 16, 128, 64, causal=False)
+    check_flash(3, 5, 128, 64, causal=True)
     check_flash(2, 4, 2048, 128, causal=True)
     check_flash(1, 16, 4096, 128, causal=True)
     print("kernels ok")
