@@ -110,10 +110,19 @@ SCOPE_MOE_ROUTE = "moe.route"
 SCOPE_MOE_DISPATCH = "moe.dispatch"
 SCOPE_MOE_EXPERTS = "moe.experts"
 SCOPE_MOE_COMBINE = "moe.combine"
+#: A linear-attention layer (``ops/linear_attention.py``,
+#: ``models/olmo_hybrid.py``): what turns the projections into the rule's
+#: operands (short convolutions, SiLU, l2-norms, ``beta`` and ``g``), the
+#: gated delta rule itself (chunk products, triangular solve, the scan
+#: over chunks), and the gated RMSNorm of its output. Backward too.
+SCOPE_LINATTN_CONV = "linattn.conv"
+SCOPE_LINATTN_SCAN = "linattn.scan"
+SCOPE_LINATTN_GATE = "linattn.gate"
 PHASE_SCOPE_NAMES = tuple(SCOPE_PREFIX + name for name in (
     SCOPE_WIRE, SCOPE_OPTIMIZER, SCOPE_ATTN_FWD, SCOPE_ATTN_BWD,
     SCOPE_MOE_ROUTE, SCOPE_MOE_DISPATCH, SCOPE_MOE_EXPERTS,
-    SCOPE_MOE_COMBINE))
+    SCOPE_MOE_COMBINE, SCOPE_LINATTN_CONV, SCOPE_LINATTN_SCAN,
+    SCOPE_LINATTN_GATE))
 
 #: Span categories. ``phase``-cat spans are host-observable compute
 #: segments; ``collective``-cat spans are communication; the ``step``

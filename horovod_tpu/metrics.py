@@ -893,6 +893,12 @@ MOE_SLOTS_LAST = gauge(
     "traced top-k MoE layer computes, occupied or not: set at trace time, "
     "as hvd_grad_sync_last_bytes is.",
     ("experts_here", "capacity", "top_k"))
+LINATTN_CHUNKS_LAST = gauge(
+    "hvd_linattn_chunks_last",
+    "Chunks a sequence that the LAST traced gated-delta-rule call scans "
+    "(sequence length / chunk), one scan step each: set at trace time, as "
+    "hvd_grad_sync_last_bytes is.",
+    ("chunk", "heads_here"))
 ATTN_TILES_LAST = gauge(
     "hvd_attn_tiles_last",
     "(q, k) tile pairs a (batch x head) slice of the LAST traced multi-tile "

@@ -16,3 +16,10 @@ from .olmoe import (  # noqa: F401
     causal_lm_loss,
     routing_stats,
 )
+from .olmo_hybrid import (  # noqa: F401
+    OLMO_HYBRID_7B,
+    OLMO_HYBRID_TINY,
+    OlmoHybrid,
+    OlmoHybridConfig,
+    take_head_window,
+)

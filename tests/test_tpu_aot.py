@@ -206,3 +206,41 @@ def test_a_call_that_is_not_causal_holds_no_clamp_and_no_tile_branch(
     assert not any(CLAMP.search(body) for body in bodies)
     assert all(body.count("scf.if") == 2 for body in bodies)
     assert len(kernel_instructions(lowered.compile().as_text())) == 3
+
+
+def test_olmo_hybrids_delta_rule_compiles_at_its_widths(one_chip):
+    """One sequence of 4,096 in 15 heads of 96 / 192, chunks of 64: the
+    chunk-parallel gated delta rule, forward and backward, as the v5e's
+    compiler takes it. The scan stays one loop over the 64 chunks each way
+    with the float32 state as its carry, the solve's products run at the
+    highest precision, and every operation of it carries the scope the
+    Olmo Hybrid cell's readers sum."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu import profiler
+    from horovod_tpu.ops import linear_attention
+
+    def loss(q, k, v, g, beta):
+        out = linear_attention.gated_delta_rule(q, k, v, g, beta, chunk=64)
+        return out.astype(jnp.float32).sum()
+
+    def shaped(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    narrow, wide = shaped(1, 4096, 15, 96), shaped(1, 4096, 15, 192)
+    gate = shaped(1, 4096, 15, dtype=jnp.float32)
+    text = jax.jit(jax.grad(loss, argnums=range(5))).lower(
+        narrow, narrow, wide, gate, gate).compile().as_text()
+    scopes = profiler.instruction_scopes(text)
+    # (the loss's own cast and sum are the only operations outside it)
+    assert {profiler.phase_of(scope) for scope in scopes.values()} == {
+        "hvd.linattn.scan", None}
+    # two loops, the scan forward and the scan backward, each carrying
+    # the 64 chunks' operands; the solve is expanded without one
+    loops = [line for line in text.splitlines() if " while(" in line]
+    assert len(loops) == 2, len(loops)
+    assert all("[64,1,15," in line and "f32[1,15,96,192]" in line
+               for line in loops)
+    assert "operand_precision={highest,highest}" in text
+    assert "custom_call_target=\"tpu_custom_call\"" not in text
