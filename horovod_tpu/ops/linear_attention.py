@@ -22,19 +22,27 @@ matrices on the MXU, and only the state crosses chunks, in one
 inside the chunk and ``S0`` the state that enters it::
 
     A_ij = beta_i (k_i . k_j) exp(gamma_i - gamma_j)     j < i, else 0
-    (I + A) [U | W] = beta * [V | exp(gamma) * K]        forward substitution
+    (I + A) [U | W] = beta * [V | exp(gamma) * K]
     V' = U - W S0
     O  = (Q * exp(gamma)) S0 + tril(Q K^T * exp(gamma_i - gamma_j)) V'
     S1 = exp(gamma_C) S0 + (K * exp(gamma_C - gamma))^T V'
 
 The operands of the chunk products are in the inputs' type with float32
-accumulation; ``gamma``, the decays, the triangular solve and the carried
-state are float32 (``gamma_i - gamma_j`` is masked to ``j <= i`` before
-the exponential: above the diagonal it is positive and overflows). Plain
-JAX, differentiated by JAX: no kernel yet. The scope
-``hvd.linattn.scan`` is around all of it, forward and backward, and the
-gauge ``hvd_linattn_chunks_last{chunk,heads_here}`` says at trace time how
-many chunks a sequence the step that runs scans.
+accumulation; ``gamma``, the decays, the solve and the carried state are
+float32 (``gamma_i - gamma_j`` is masked to ``j <= i`` before the
+exponential: above the diagonal it is positive and overflows).
+
+The solve is no forward substitution (``C`` dependent rows a system,
+which the v5e ran in 1.8 ms a layer): :func:`solve_unit_lower` builds
+``(I + A)^-1`` by block doubling, ``log2 C`` levels of float32
+multiply-adds with the systems along the lanes, applies it by one float32
+product at ``Precision.HIGHEST``, and is differentiated by a rule of its
+own that keeps the inverse and the solution and makes two such products.
+The loop's left operands are rounded to the compute type once, outside
+it. The rest is plain JAX, differentiated by JAX: no kernel yet. The
+scope ``hvd.linattn.scan`` is around all of it, forward and backward, and
+the gauge ``hvd_linattn_chunks_last{chunk,heads_here}`` says at trace time
+how many chunks a sequence the step that runs scans.
 """
 
 from __future__ import annotations
@@ -92,9 +100,8 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64):
         rest = jnp.exp(gamma[..., -1:] - gamma)[..., None]  # to its end
 
         a = jnp.tril(beta * product("bhnic,bhnjc->bhnij", k, k) * decay, -1)
-        solved = lax.linalg.triangular_solve(
-            a, beta * jnp.concatenate([v.astype(f32), k * grow], -1),
-            left_side=True, lower=True, unit_diagonal=True)
+        solved = solve_unit_lower(
+            a, beta * jnp.concatenate([v.astype(f32), k * grow], -1))
         u, w = solved[..., :d_v], solved[..., d_v:]
         inside = product("bhnic,bhnjc->bhnij", q, k) * decay
 
@@ -106,15 +113,95 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64):
             state = kept * state + product("bhck,bhcv->bhkv", k_out, new)
             return state, out.astype(dtype)
 
-        # (rest and the chunk's whole decay are decay's last row and grow's
-        # last entry; sliced out of those the v5e's step took 1.1 ms longer)
-        per_chunk = (u, w, inside, q * grow, k * rest,
+        # The products' left operands go in rounded to the compute type:
+        # the rounding an iteration made, made once. (Stacked in pairs
+        # that share a right operand they lose 5 ms a step to the
+        # stacking, and JAX's transposition then rounds one summed
+        # cotangent where it rounded two. rest and the chunk's whole decay
+        # are decay's last row and grow's last entry; sliced out of those
+        # the v5e's step took 1.1 ms longer.)
+        per_chunk = (u, w.astype(dtype), inside.astype(dtype),
+                     (q * grow).astype(dtype), (k * rest).astype(dtype),
                      jnp.exp(gamma[..., -1])[..., None, None])
         state = jnp.zeros((batch, heads, k.shape[-1], d_v), f32)
         _, out = lax.scan(one_chunk, state, jax.tree.map(
             lambda x: jnp.moveaxis(x, 2, 0), per_chunk))
         # [N, B, H, C, d_v] -> [B, S, H, d_v]
         return out.transpose(1, 0, 3, 2, 4).reshape(batch, seq, heads, d_v)
+
+
+def _exact(a, b):
+    """``a @ b`` over the last two axes, float32 at full precision (a
+    float32 product at the TPU's default is one bfloat16 pass)."""
+    return jnp.matmul(a, b, precision=lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)
+
+
+def _unit_lower_inverse(a):
+    """``(I + A)^-1`` for strictly lower triangular float32 ``a [..., C,
+    C]``, by block doubling: the diagonal blocks' inverses at width 1 are
+    1, and two neighbours ``T11``, ``T22`` of width ``b`` with the ``A21``
+    between them make the next level's ``[[T11, 0], [-T22 A21 T11,
+    T22]]``, which is ``[[L11, 0], [A21, L22]]^-1``: the block form of
+    forward substitution, ``log2 C`` levels (not the product of ``I +
+    (-A)^(2^j)``, whose powers grow without bound when keys align and
+    ``beta`` nears 2). The systems lie along the lanes (``[C, C,
+    systems]``) and a level's two products are float32 multiply-adds over
+    them: as products on the MXU the levels are memory passes or fill a
+    corner of it each (``PERF.md`` §6, PR 31). A ``C`` that is no power of
+    two is padded to one with zeros: the padded matrix's inverse holds
+    the wanted one in its corner."""
+    size, lead = a.shape[-1], a.shape[:-2]
+    full = 1 << (size - 1).bit_length()
+    a = jnp.moveaxis(a.reshape((-1, size, size)), 0, -1)
+    a = jnp.pad(a, [(0, full - size)] * 2 + [(0, 0)])
+
+    def times(left, right):  # [pairs, i, j, systems] x [pairs, j, k, systems]
+        return (left[:, :, :, None] * right[:, None]).sum(2)
+
+    # top down: every diagonal block gives its A21 and its two halves
+    below, parts = [], a[None]
+    while parts.shape[1] > 1:
+        half = parts.shape[1] // 2
+        below.append(parts[:, half:, :half])
+        parts = jnp.stack([parts[:, :half, :half], parts[:, half:, half:]],
+                          1).reshape((-1, half, half, a.shape[-1]))
+    # bottom up: neighbours' inverses and their A21 make the pair's
+    blocks = jnp.ones_like(parts)
+    for a21 in reversed(below):
+        halves = blocks.reshape((-1, 2) + blocks.shape[1:])
+        t11, t22 = halves[:, 0], halves[:, 1]
+        t21 = -times(times(t22, a21), t11)
+        blocks = jnp.concatenate([
+            jnp.concatenate([t11, jnp.zeros_like(t11)], 2),
+            jnp.concatenate([t21, t22], 2)], 1)
+    return jnp.moveaxis(blocks[0, :size, :size], -1, 0).reshape(
+        lead + (size, size))
+
+
+@jax.custom_vjp
+def solve_unit_lower(a, rhs):
+    """``X`` of ``(I + A) X = rhs`` for strictly lower triangular ``a
+    [..., C, C]`` and ``rhs [..., C, n]``, float32: the inverse by
+    :func:`_unit_lower_inverse`, applied by one product. Differentiated by
+    its own rule, which keeps the inverse and ``X`` and solves nothing:
+    JAX's would keep every level's intermediates for the backward pass."""
+    return _solve_forward(a, rhs)[0]
+
+
+def _solve_forward(a, rhs):
+    t = _unit_lower_inverse(a)
+    x = _exact(t, rhs)
+    return x, (t, x)
+
+
+def _solve_backward(kept, x_bar):
+    t, x = kept
+    rhs_bar = _exact(jnp.swapaxes(t, -1, -2), x_bar)
+    return -jnp.tril(_exact(rhs_bar, jnp.swapaxes(x, -1, -2)), -1), rhs_bar
+
+
+solve_unit_lower.defvjp(_solve_forward, _solve_backward)
 
 
 def _record_chunks(count: int, chunk: int, heads: int) -> None:

@@ -1,8 +1,13 @@
 """``ops/linear_attention.py`` against its definition: the chunk-parallel
 gated delta rule is the token-by-token recurrence, values and gradients,
 for every chunk size, with ``beta`` up to 2 and with strong and weak
-decay; the short convolution is causal; a ragged sequence is refused; the
-scope and the gauge are there."""
+decay; its solve by block doubling is ``triangular_solve``, values and
+both gradients, and leaves no ``triangular_solve`` in the program; the
+loop's left operands are rounded once, outside it; the short convolution
+is causal; a ragged sequence is refused; the scope and
+the gauge are there."""
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -78,6 +83,101 @@ def test_chunk_forms_gradients_are_the_recurrences(chunk, decay):
         assert float(jnp.abs(w).max()) > 0, name
         np.testing.assert_allclose(
             g, w, rtol=0, atol=1e-4 * float(jnp.abs(w).max()), err_msg=name)
+
+
+def systems(chunk: int, keys: str):
+    """Five systems ``(I + A) X = rhs`` as the rule builds them from unit
+    keys: ``A_ij = beta_i (k_i . k_j)`` below the diagonal. ``random``
+    keys with ``beta`` in (0, 2); all keys ``equal`` with ``beta`` = 2,
+    where every entry of ``A`` is 2 and the powers of ``A`` grow without
+    bound (the inverse's entries stay +-2)."""
+    key = jax.random.PRNGKey(chunk)
+    if keys == "random":
+        k = jax.random.normal(key, (5, chunk, 8))
+        beta = 2.0 * jax.random.uniform(jax.random.fold_in(key, 1),
+                                        (5, chunk, 1))
+    else:
+        k = jnp.broadcast_to(jax.random.normal(key, (5, 1, 8)), (5, chunk, 8))
+        beta = jnp.full((5, chunk, 1), 2.0)
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    a = jnp.tril(beta * jnp.einsum("nic,njc->nij", k, k), -1)
+    rhs = jax.random.normal(jax.random.fold_in(key, 2), (5, chunk, 12))
+    weight = jax.random.normal(jax.random.fold_in(key, 3), (5, chunk, 12))
+    return a, rhs, weight
+
+
+def substitution(a, rhs):
+    return jax.lax.linalg.triangular_solve(
+        a, rhs, left_side=True, lower=True, unit_diagonal=True)
+
+
+@pytest.mark.parametrize("keys", ["random", "equal"])
+@pytest.mark.parametrize("chunk", [16, 32, 48, 64])
+def test_the_block_doubling_solve_is_forward_substitution(chunk, keys):
+    a, rhs, weight = systems(chunk, keys)
+
+    def close(got, want, what):
+        np.testing.assert_allclose(
+            got, want, rtol=0, atol=1e-5 * float(jnp.abs(want).max()),
+            err_msg=what)
+
+    close(linear_attention.solve_unit_lower(a, rhs), substitution(a, rhs),
+          "X")
+    got = jax.grad(lambda a, rhs: jnp.sum(
+        linear_attention.solve_unit_lower(a, rhs) * weight), (0, 1))(a, rhs)
+    want = jax.grad(lambda a, rhs: jnp.sum(
+        substitution(a, rhs) * weight), (0, 1))(a, rhs)
+    # (substitution's rule also fills the triangle the solve never reads)
+    close(got[0], jnp.tril(want[0], -1), "the gradient to A")
+    close(got[1], want[1], "the gradient to rhs")
+
+
+@pytest.mark.parametrize("program", ["forward", "gradient"])
+def test_the_solve_is_products_under_the_scope_and_no_substitution(program):
+    """No ``triangular_solve`` in the program. The inverse's levels are
+    float32 multiply-adds; the one product that applies it, and the
+    gradient's two under ``transpose``, are float32 at the highest
+    precision (the rule's other products are at the default), all under
+    the scope the readers sum."""
+    def rule(*a):
+        return jnp.sum(linear_attention.gated_delta_rule(*a, chunk=32))
+
+    fn = rule if program == "forward" else jax.grad(rule, argnums=range(5))
+    # (the primitive by its name; on this backend it lowers to LAPACK's)
+    assert "triangular_solve" not in str(jax.make_jaxpr(fn)(*inputs("weak")))
+    text = jax.jit(fn).lower(*inputs("weak")).as_text(debug_info=True)
+    assert "stablehlo.custom_call" not in text
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    lines = [line for line in text.splitlines()
+             if "stablehlo.dot_general" in line and "HIGHEST" in line]
+    assert all("f32" in line and "bf16" not in line for line in lines)
+    exact = [names[re.search(r"loc\((#loc\d+)\)$", line).group(1)]
+             for line in lines]
+    assert all("hvd.linattn.scan" in name for name in exact), exact
+    backward = [name for name in exact if "transpose(" in name]
+    assert len(exact) - len(backward) == 1
+    assert len(backward) == (2 if program == "gradient" else 0)
+
+
+@pytest.mark.parametrize("chunk", [16, 32, 64])
+def test_the_loops_left_operands_are_rounded_once_outside_it(chunk):
+    """The scan takes ``W``, the chunk's scores and the decayed ``Q`` and
+    ``K`` already in the compute type (beside ``U`` and the chunk's decay
+    in float32), and an iteration casts none of them again: it rounds only
+    what follows from the state. A cast commutes with the slice the scan
+    takes, so the values are those of a cast made in every iteration."""
+    q, k, v, g, beta = inputs("weak", seed=4)
+    args = tuple(x.astype(jnp.bfloat16) for x in (q, k, v)) + (g, beta)
+    program = jax.make_jaxpr(
+        lambda *a: linear_attention.gated_delta_rule(*a, chunk=chunk))(*args)
+    scan, = (e for e in program.eqns if e.primitive.name == "scan")
+    fixed = scan.params["num_consts"] + scan.params["num_carry"]
+    assert [str(x.aval.dtype) for x in scan.invars[fixed:]] == [
+        "float32"] + ["bfloat16"] * 4 + ["float32"]
+    body = scan.params["jaxpr"].jaxpr
+    sliced = set(body.invars[fixed:])
+    casts = [e for e in body.eqns if e.primitive.name == "convert_element_type"]
+    assert casts and not any(e.invars[0] in sliced for e in casts)
 
 
 def test_bfloat16_operands_keep_a_float32_state():

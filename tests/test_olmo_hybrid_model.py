@@ -42,9 +42,10 @@ def toy(bench, **changes):
     return dict(config, training=training, **changes)
 
 
-def both_sides(bench, config, rows=2, seq=32, seed=5):
+def both_sides(bench, config, rows=2, seq=32, seed=5, double=False):
     """``(loss, gradients)`` of the product and of the reference on the
-    same seeded weights and tokens."""
+    same seeded weights and tokens; with ``double`` the reference computes
+    in float64 from the same float32 weights."""
     cells, _ = bench
     code = cells.load_code(cells.HERE, "configs", "olmo_hybrid.py")
     reference = cells.load_code(cells.HERE, "reference", "olmo_hybrid.py")
@@ -54,9 +55,10 @@ def both_sides(bench, config, rows=2, seq=32, seed=5):
     tokens = code.make_batch(config, job, jax.random.fold_in(key, 1), rows)
     product = jax.jit(jax.value_and_grad(code.loss_fn(config, job)))(
         params, tokens)
-    with jax.default_matmul_precision("highest"):
+    with jax.default_matmul_precision("highest"), jax.enable_x64(double):
+        weights = jax.tree.map(jnp.float64, params) if double else params
         plain = jax.jit(jax.value_and_grad(
-            partial(reference.loss, config)))(params, tokens)
+            partial(reference.loss, config)))(weights, tokens)
     return product, plain, params
 
 
@@ -81,16 +83,24 @@ def test_float32_product_is_the_reference(bench, case):
     changes = dict(CASES[case])
     seq = changes.pop("seq", 32)
     (loss, grads), (ref_loss, ref_grads), _ = both_sides(
-        bench, toy(bench, **changes), seq=seq)
+        bench, toy(bench, **changes), seq=seq, double=True)
     assert float(loss) == pytest.approx(float(ref_loss), rel=1e-5)
     for (path, got), want in zip(
             jax.tree_util.tree_leaves_with_path(grads),
             jax.tree.leaves(ref_grads)):
-        scale = float(jnp.abs(want).max())
+        want = np.asarray(want)
+        scale = float(np.abs(want).max())
         assert scale > 0, jax.tree_util.keystr(path)
         # the floor: float32's own noise on a gradient that is the small
         # remainder of cancelling terms (a head's A_log of 1.4e-4: the
-        # reference in float32 and in float64 differ by 9e-7 there too)
+        # reference in float32 and in float64 differ by 9e-7 there too).
+        # The reference is in float64 since PR 31: in float32 it is itself
+        # up to 0.45e-4 of a leaf's largest from that, erring as the
+        # product's solve did while that was forward substitution too, and
+        # left 1e-4 no room for the product's own error. Against float64
+        # the worst leaf of the six cases reads 0.59e-4 (0.29e-4 with
+        # substitution, 0.72e-4 with a step of refinement on the inverse:
+        # what float32 leaves of a gradient through three of these layers).
         np.testing.assert_allclose(
             got, want, rtol=0, atol=1e-4 * scale + 5e-6,
             err_msg=jax.tree_util.keystr(path))

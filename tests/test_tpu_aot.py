@@ -212,9 +212,13 @@ def test_olmo_hybrids_delta_rule_compiles_at_its_widths(one_chip):
     """One sequence of 4,096 in 15 heads of 96 / 192, chunks of 64: the
     chunk-parallel gated delta rule, forward and backward, as the v5e's
     compiler takes it. The scan stays one loop over the 64 chunks each way
-    with the float32 state as its carry, the solve's products run at the
-    highest precision, and every operation of it carries the scope the
-    Olmo Hybrid cell's readers sum."""
+    with the float32 state as its carry, the solve is no
+    ``triangular_solve`` but multiply-adds and three products at the
+    highest precision (PR 31), and every operation of it carries the
+    scope the Olmo Hybrid cell's readers sum. Arguments + temporaries
+    were 742,576,128 B with XLA's substitution (PR 30) and may not pass
+    that by 1% (680,411,136 now): a rule that kept every level of the
+    inverse for the backward pass would."""
     import jax
     import jax.numpy as jnp
 
@@ -230,14 +234,19 @@ def test_olmo_hybrids_delta_rule_compiles_at_its_widths(one_chip):
 
     narrow, wide = shaped(1, 4096, 15, 96), shaped(1, 4096, 15, 192)
     gate = shaped(1, 4096, 15, dtype=jnp.float32)
-    text = jax.jit(jax.grad(loss, argnums=range(5))).lower(
-        narrow, narrow, wide, gate, gate).compile().as_text()
+    compiled = jax.jit(jax.grad(loss, argnums=range(5))).lower(
+        narrow, narrow, wide, gate, gate).compile()
+    text = compiled.as_text()
+    assert "triangular_solve" not in text and "triangular-solve" not in text
+    planned = compiled.memory_analysis()
+    assert (planned.argument_size_in_bytes + planned.temp_size_in_bytes
+            <= 1.01 * 742_576_128)
     scopes = profiler.instruction_scopes(text)
     # (the loss's own cast and sum are the only operations outside it)
     assert {profiler.phase_of(scope) for scope in scopes.values()} == {
         "hvd.linattn.scan", None}
     # two loops, the scan forward and the scan backward, each carrying
-    # the 64 chunks' operands; the solve is expanded without one
+    # the 64 chunks' operands; the solve has none
     loops = [line for line in text.splitlines() if " while(" in line]
     assert len(loops) == 2, len(loops)
     assert all("[64,1,15," in line and "f32[1,15,96,192]" in line
