@@ -103,6 +103,18 @@ SCOPE_WIRE_DECOMPRESS = "wire.decompress"
 SCOPE_OPTIMIZER = "optimizer"
 SCOPE_ATTN_FWD = "attn.fwd"
 SCOPE_ATTN_BWD = "attn.bwd"
+#: Around the two above where the call is windowed (``flash_attention(...,
+#: window=W)``): ``hvd.attn.window/hvd.attn.fwd/flash_attention``. No phase
+#: of its own (the innermost phase scope stays ``attn.fwd`` / ``attn.bwd``,
+#: which is what sums by phase read): it tells a window layer's kernels
+#: from a full layer's in a model that has both (``models/smallthinker``).
+SCOPE_ATTN_WINDOW = "attn.window"
+#: JAX's own name-stack component for the forward operations that a
+#: ``jax.checkpoint`` (``nn.remat``) runs again inside the backward pass:
+#: ``transpose(jvp(...))/rematted_computation/...``. Not a scope this
+#: package opens; named here because it is how the recomputed forward of a
+#: model with ``remat`` is told from the first one in the step's text.
+SCOPE_RECOMPUTE = "rematted_computation"
 #: A mixture-of-experts layer (``parallel/moe.py``): the router's picks and
 #: slots, the tokens' way into the slots, the grouped expert matmuls, and
 #: the way back. The backward pass's operations carry the same scopes.

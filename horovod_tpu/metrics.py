@@ -913,6 +913,11 @@ ATTN_GROUP_LAST = gauge(
     "forward; bwd: the fused backward): set at trace time, as "
     "hvd_attn_tiles_last is.",
     ("kernel",))
+ATTN_KV_GROUP_LAST = gauge(
+    "hvd_attn_kv_group_last",
+    "Query heads that read one key/value head in the LAST traced multi-tile "
+    "flash-attention call (1: a head of keys and values a query head): set "
+    "at trace time, beside hvd_attn_tiles_last.")
 ALLTOALL_LATENCY = histogram(
     "hvd_alltoall_latency_seconds",
     "Wall time of alltoall exchanges (eager dispatches and MoE "

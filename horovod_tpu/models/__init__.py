@@ -23,3 +23,10 @@ from .olmo_hybrid import (  # noqa: F401
     OlmoHybridConfig,
     take_head_window,
 )
+from .smallthinker import (  # noqa: F401
+    SMALLTHINKER_21B_A3B,
+    SMALLTHINKER_TINY,
+    SmallThinker,
+    SmallThinkerConfig,
+    take_expert_window,
+)

@@ -1,0 +1,348 @@
+"""SmallThinker — the framework's first model with two kinds of attention
+layer, grouped keys and values, and a layer recomputed in the backward pass.
+
+PowerInfer's ``SmallThinker-21BA3B-Instruct`` (``config.json``,
+``model_type`` ``smallthinker``): a pre-norm causal decoder of 52 layers in
+a period of four. ``sliding_window_layout`` and ``rope_layout`` (both
+``[0, 1, 1, 1]`` repeated) say, layer by layer, whether attention is
+*windowed* (query ``i`` sees the keys ``j`` with ``0 <= i - j <
+sliding_window_size``) or full causal, and whether queries and keys get a
+rotary embedding or no positional embedding at all. 28 query heads read 4
+key/value heads, 7 to one; no bias and no QK-norm. Every layer is a
+mixture of 64 ReLU-gated experts (a sparse ReGLU) of which a token takes
+6, and **the router reads the pre-attention normalised input**, not the
+expert block's:
+
+    h = RMSNorm_1(x);  rho = h W_router        (float32)
+    x' = x + Attention(h) W_o
+    u = RMSNorm_2(x');  P = top-6 of rho;  g = softmax(rho[P])
+    x'' = x' + sum_{e in P} g_e W_down,e (relu(W_gate,e u) * (W_up,e u))
+
+The gates are the softmax over the six picked logits. A final RMSNorm and
+an untied head over every position; no auxiliary loss.
+
+TPU-first choices, as ``models/olmoe.py`` (whose ``RMSNorm``, ``rope`` and
+capacity slots this model uses): bfloat16 activations with float32
+parameters, norms, RoPE and router; attention through the framework's flash
+kernels (``attention_fn=``), which take the window and the grouped keys and
+values as they are (nothing is repeated in HBM); the experts through
+``parallel/moe.py``'s slots, one sequence a routing group. A model may hold
+a window of the experts (``experts_here`` from ``first_expert`` on), one
+chip's share of expert parallelism: the router keeps its width and a
+token's gates are normalised over all six picks, wherever they live, so
+the shares' outputs add up to the whole layer's.
+
+**Recomputation** (``remat``, on by default): a decoder layer is wrapped in
+``nn.remat`` (``jax.checkpoint``), so the forward pass keeps a layer's
+input and, by the policy (``save_kernels_and_projections``), the flash
+kernels' output and log-sum-exp and the projections' results; the backward
+pass computes the rest of the layer again, one layer's activations alive
+at a time: 10.6 GiB for one sequence of the model's own 16,384 positions
+on a 16 GB chip where keeping everything takes 13.3 (PERF.md). The
+attention kernels are not run twice. With and without
+``remat`` the parameter tree, the loss and the gradients are the same.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Callable
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ..attribution import SCOPE_MOE_ROUTE
+from ..ops.attention import flash_attention
+from ..parallel import moe
+from ..profiler import annotate_collective
+from .olmoe import RMSNorm, _record_slots, rope
+
+PERIOD = (0, 1, 1, 1)  # one period of both layouts: full + NoPE, then 3 x
+
+
+@dataclasses.dataclass(frozen=True)
+class SmallThinkerConfig:
+    vocab_size: int = 151936
+    hidden_size: int = 2560
+    num_layers: int = 52
+    num_heads: int = 28
+    num_kv_heads: int = 4
+    head_dim: int = 128
+    intermediate_size: int = 768  # one expert's width
+    num_experts: int = 64
+    top_k: int = 6
+    experts_here: int | None = None  # None: all from first_expert on
+    first_expert: int = 0
+    capacity_factor: float = 1.25
+    window: int = 4096
+    sliding_window_layout: tuple | None = None  # None: PERIOD, repeated
+    rope_layout: tuple | None = None
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 1.5e6
+    remat: bool = True
+    dtype: Any = jnp.bfloat16
+
+    def __post_init__(self):
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError(
+                f"{self.num_heads} query heads cannot share "
+                f"{self.num_kv_heads} key/value heads evenly")
+        for name in ("sliding_window_layout", "rope_layout"):
+            layout = getattr(self, name)
+            if layout is not None and len(layout) != self.num_layers:
+                raise ValueError(
+                    f"{name} has {len(layout)} entries for "
+                    f"{self.num_layers} layers")
+
+    def _layout(self, given) -> tuple:
+        if given is not None:
+            return tuple(bool(flag) for flag in given)
+        return tuple(bool(PERIOD[i % len(PERIOD)])
+                     for i in range(self.num_layers))
+
+    @property
+    def windowed(self) -> tuple:
+        """Layer by layer: is attention windowed?"""
+        return self._layout(self.sliding_window_layout)
+
+    @property
+    def rotary(self) -> tuple:
+        """Layer by layer: do queries and keys get RoPE?"""
+        return self._layout(self.rope_layout)
+
+    @property
+    def experts_held(self) -> int:
+        if self.experts_here is None:
+            return self.num_experts - self.first_expert
+        return self.experts_here
+
+    def capacity(self, seq_len: int) -> int:
+        return moe.expert_capacity(self.capacity_factor, seq_len, self.top_k,
+                                   self.num_experts)
+
+
+SMALLTHINKER_21B_A3B = SmallThinkerConfig()
+SMALLTHINKER_TINY = SmallThinkerConfig(  # test-sized: 14 heads on 2, 7 to 1
+    vocab_size=256, hidden_size=56, num_layers=4, num_heads=14,
+    num_kv_heads=2, head_dim=8, intermediate_size=24, num_experts=8,
+    top_k=3, capacity_factor=8 / 3, window=24,
+)
+
+
+def dense_window_attention(q, k, v, dtype, window=None):
+    """``q [B, S, H, D]``, ``k``, ``v [B, S, KV heads, D]``; the banded (or
+    full) causal softmax in float32, the keys and values of a group
+    repeated: the fallback where no kernel runs."""
+    group = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(x.astype(jnp.float32), group, axis=2) for x in (k, v))
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                        k) / (q.shape[-1] ** 0.5)
+    ahead = jnp.arange(q.shape[1])[:, None] - jnp.arange(k.shape[1])[None, :]
+    seen = ahead >= 0 if window is None else (ahead >= 0) & (ahead < window)
+    out = jnp.einsum("bhqk,bkhd->bqhd",
+                     jax.nn.softmax(jnp.where(seen, scores, -1e30), -1), v)
+    return out.astype(dtype)
+
+
+def flash_attention_fn(q, k, v, dtype, window=None, interpret: bool = False,
+                       block: int | None = None):
+    """Adapter plugging the causal Pallas flash kernels into
+    ``SmallThinker``: ``[B, S, heads, D]`` -> transpose -> kernel, the keys
+    and values with their own, smaller number of heads. ``block`` is for
+    tests that want several tiles of a short sequence."""
+    out = flash_attention(
+        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+        v.transpose(0, 2, 1, 3), causal=True, window=window, block_q=block,
+        block_k=block, interpret=interpret)
+    return out.transpose(0, 2, 1, 3).astype(dtype)
+
+
+class GroupedAttention(nn.Module):
+    config: SmallThinkerConfig
+    windowed: bool
+    rotary: bool
+    attention_fn: Callable | None = None
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+
+        def project(name, width):
+            return nn.Dense(width, use_bias=False, dtype=cfg.dtype,
+                            param_dtype=jnp.float32, name=name)
+
+        def heads(y, count):
+            return y.reshape(x.shape[:2] + (count, cfg.head_dim))
+
+        q = heads(project("query", cfg.num_heads * cfg.head_dim)(x),
+                  cfg.num_heads)
+        k = heads(project("key", cfg.num_kv_heads * cfg.head_dim)(x),
+                  cfg.num_kv_heads)
+        v = heads(project("value", cfg.num_kv_heads * cfg.head_dim)(x),
+                  cfg.num_kv_heads)
+        if self.rotary:
+            q = rope(q, cfg.rope_theta).astype(cfg.dtype)
+            k = rope(k, cfg.rope_theta).astype(cfg.dtype)
+        attend = self.attention_fn or dense_window_attention
+        out = attend(q, k, v, cfg.dtype,
+                     cfg.window if self.windowed else None)
+        return project("out", cfg.hidden_size)(
+            out.reshape(x.shape[:2] + (-1,)))
+
+
+class SparseReGLU(nn.Module):
+    """This model's window of the experts, on picks the router made before
+    attention: ``(tokens [B, S, D], logits [B, S, num_experts]) ->`` the
+    experts' weighted outputs ``[B, S, D]`` (no residual)."""
+
+    config: SmallThinkerConfig
+
+    @nn.compact
+    def __call__(self, x, logits):
+        cfg = self.config
+        hidden, width, here = (cfg.hidden_size, cfg.intermediate_size,
+                               cfg.experts_held)
+        stacked = nn.initializers.lecun_normal(batch_axis=(0,))
+        w_gate = self.param("experts_gate", stacked, (here, hidden, width),
+                            jnp.float32)
+        w_up = self.param("experts_up", stacked, (here, hidden, width),
+                          jnp.float32)
+        w_down = self.param("experts_down", stacked, (here, width, hidden),
+                            jnp.float32)
+        capacity = cfg.capacity(x.shape[1])
+        _record_slots(here, capacity, cfg.top_k)
+
+        def one_sequence(tokens, logits):
+            send, expert, pos, keep, gate, counts = moe.route_to_capacity(
+                tokens.astype(cfg.dtype), logits, cfg.num_experts, capacity,
+                top_k=cfg.top_k, first_expert=cfg.first_expert,
+                experts_here=here, gates_over_picks=True)
+            back = moe.gated_expert_ffn(
+                w_gate.astype(cfg.dtype), w_up.astype(cfg.dtype),
+                w_down.astype(cfg.dtype), send[..., :hidden],
+                activation=jax.nn.relu)
+            out = moe.combine_top_k(back, expert, pos, keep, gate,
+                                    cfg.first_expert)
+            in_window = (expert >= cfg.first_expert) & (
+                expert < cfg.first_expert + here)
+            return out, counts, jnp.sum(in_window & ~keep)
+
+        out, counts, dropped = jax.vmap(one_sequence)(x, logits)
+        self.sow("intermediates", "routing",
+                 {"load": counts.sum(0), "dropped": dropped.sum(),
+                  "pairs": counts.sum() + dropped.sum()})
+        return out
+
+
+class DecoderLayer(nn.Module):
+    config: SmallThinkerConfig
+    windowed: bool
+    rotary: bool
+    attention_fn: Callable | None = None
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        n1 = RMSNorm(cfg.rms_norm_eps, name="ln_attn")(x)
+        router = self.param("router", nn.initializers.lecun_normal(),
+                            (cfg.hidden_size, cfg.num_experts), jnp.float32)
+        # The router reads the PRE-attention normalised input, in float32
+        # all the way: a TPU's default float32 matmul is one bfloat16 pass,
+        # and a pick is a discontinuity.
+        with annotate_collective(SCOPE_MOE_ROUTE):
+            logits = jnp.matmul(n1, router,
+                                precision=jax.lax.Precision.HIGHEST)
+        x = x + GroupedAttention(
+            cfg, self.windowed, self.rotary, self.attention_fn,
+            name="attention")(n1.astype(cfg.dtype))
+        n2 = RMSNorm(cfg.rms_norm_eps, name="ln_moe")(x)
+        return x + SparseReGLU(cfg, name="moe")(n2, logits)
+
+
+def save_kernels_and_projections(prim, *args, **params) -> bool:
+    """The ``jax.checkpoint`` policy of a recomputed layer. Beside its
+    input the forward pass keeps what a Pallas kernel returned (the only
+    kernel of a layer's forward pass is the flash forward kernel, whose
+    output and log-sum-exp are the residuals the dq and dkv kernels want,
+    so it never runs again) and the results of the matrix products without
+    a batch dimension (the four attention projections and the router:
+    0.2 GiB a layer at 16,384 tokens for 4 ms of recomputation each).
+    Norms, RoPE, the slots' gathers, the experts' batched products and the
+    combine are computed again."""
+    return prim.name == "pallas_call" or (
+        jax.checkpoint_policies.dots_with_no_batch_dims_saveable(
+            prim, *args, **params))
+
+
+class SmallThinker(nn.Module):
+    """Call: ``model.apply(vars, input_ids)`` -> logits ``[B, S, V]`` in
+    float32."""
+
+    config: SmallThinkerConfig = SMALLTHINKER_21B_A3B
+    attention_fn: Callable | None = None
+
+    @nn.compact
+    def __call__(self, input_ids):
+        cfg = self.config
+        layer = DecoderLayer
+        if cfg.remat:
+            layer = nn.remat(DecoderLayer,
+                             policy=save_kernels_and_projections)
+        x = nn.Embed(cfg.vocab_size, cfg.hidden_size,
+                     param_dtype=jnp.float32,
+                     name="token_embeddings")(input_ids).astype(cfg.dtype)
+        for i, (windowed, rotary) in enumerate(zip(cfg.windowed,
+                                                   cfg.rotary)):
+            x = layer(cfg, windowed, rotary, self.attention_fn,
+                      name=f"layer_{i}")(x)
+        x = RMSNorm(cfg.rms_norm_eps, name="ln_out")(x).astype(cfg.dtype)
+        # bf16 in, f32 out on the MXU, as models/bert.py's head.
+        head = self.param("lm_head", nn.initializers.lecun_normal(),
+                          (cfg.hidden_size, cfg.vocab_size), jnp.float32)
+        return jax.lax.dot_general(
+            x, head.astype(cfg.dtype), (((x.ndim - 1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+
+def causal_lm_loss(model: SmallThinker, params, tokens):
+    """Next-token cross entropy of ``tokens [B, S + 1]``: positions
+    ``0..S-1`` are read and ``1..S`` are their labels. The source's config
+    has no auxiliary-loss coefficient, so there is none."""
+    logits = model.apply({"params": params}, tokens[:, :-1])
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    return -jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1).mean()
+
+
+def take_expert_window(params, share: SmallThinkerConfig):
+    """The parameters ``share`` holds (its ``experts_here`` experts from
+    ``first_expert`` on), cut out of the tree of the same model with all
+    its experts: the stacked expert weights lose the other experts' rows;
+    attention, router, norms, embedding and head are every window's
+    alike."""
+    first, last = share.first_expert, share.first_expert + share.experts_held
+    out = dict(params)
+    for i in range(share.num_layers):
+        layer = dict(params[f"layer_{i}"])
+        layer["moe"] = {name: leaf[first:last]
+                        for name, leaf in layer["moe"].items()}
+        out[f"layer_{i}"] = layer
+    return out
+
+
+def routing_stats(model: SmallThinker, params, input_ids):
+    """What the routing did with ``input_ids [B, S]``, layer by layer, as
+    ``models.olmoe.routing_stats``: ``{"load": [layers, experts_here],
+    "dropped": [layers], "dropped_share": [layers]}``. A program of its
+    own, without recomputation; jit it."""
+    plain = SmallThinker(dataclasses.replace(model.config, remat=False),
+                         model.attention_fn)
+    _, state = plain.apply({"params": params}, input_ids,
+                           mutable=["intermediates"])
+    layers = [state["intermediates"][f"layer_{i}"]["moe"]["routing"][0]
+              for i in range(model.config.num_layers)]
+    load = jnp.stack([layer["load"] for layer in layers])
+    dropped = jnp.stack([layer["dropped"] for layer in layers])
+    pairs = jnp.stack([layer["pairs"] for layer in layers])
+    return {"load": load, "dropped": dropped,
+            "dropped_share": dropped / jnp.maximum(pairs, 1)}

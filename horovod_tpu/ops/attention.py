@@ -40,10 +40,24 @@ it leaves nothing of is neither computed nor fetched. The grid itself
 stays whole, so a skipped tile still costs its (empty) grid step; the
 gauge ``hvd_attn_tiles_last{kind}`` says how many a call computes and
 skips.
+
+A causal call may also be *windowed* (``window=W``): query ``i`` sees the
+keys ``j`` with ``0 <= i - j < W``, a band under the diagonal. The same
+predicate then throws away the tiles behind the band as well as those
+ahead of the diagonal, and the index maps are clamped at both ends (a
+first visible key tile for a query tile, a last visible query tile for a
+key tile). And ``k``, ``v`` may have fewer heads than ``q`` (*grouped*
+keys and values: query head ``n`` reads key/value head ``n // group``):
+the key/value block is found through the index map, nothing is repeated
+in HBM, and the dk/dv kernel sums over a group's query heads in its
+float32 accumulators (a grid axis of its own inside the K block's
+reduction). The gauge ``hvd_attn_kv_group_last`` says the group. Both go
+through the multi-tile kernels, whatever the length.
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -51,7 +65,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..attribution import SCOPE_ATTN_BWD, SCOPE_ATTN_FWD
+from ..attribution import (SCOPE_ATTN_BWD, SCOPE_ATTN_FWD,
+                           SCOPE_ATTN_WINDOW)
 
 # Every ``pallas_call`` here carries this one name. XLA names a custom
 # call's instruction after the innermost component of its name stack, a
@@ -245,21 +260,30 @@ def _group_specs(kernel, qr, block_q, block_k, slice_counts):
             pl.BlockSpec((group, 1, block_q), lambda i: (i, 0, 0)))
 
 
-def _causal_mask(qi, j, block_q, block_k, q_offset, k_offset):
+def _causal_mask(qi, j, block_q, block_k, q_offset, k_offset, window=None):
     qpos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
     kpos = k_offset + j * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1)
-    return qpos >= kpos
+    if window is None:
+        return qpos >= kpos
+    return (qpos >= kpos) & (qpos - kpos < window)
 
 
-def _tile_visible(qi, kj, block_q, block_k, q_offset, k_offset):
+def _tile_visible(qi, kj, block_q, block_k, q_offset, k_offset, window=None):
     """Whether the causal mask leaves anything of tile (q block ``qi``, k
-    block ``kj``): its last query is at or after its first key. The
-    multi-tile causal kernels skip every other tile, where what the masked
-    step adds to its accumulators is exactly zero. Plain arithmetic, so
-    Python ints, numpy grids and traced program ids all do."""
-    return q_offset + (qi + 1) * block_q - 1 >= k_offset + kj * block_k
+    block ``kj``): its last query is at or after its first key and, under
+    a ``window``, its first query is less than ``window`` past its last
+    key (the differences ``i - j`` of a tile are a run of integers, so the
+    two ends decide). The multi-tile causal kernels skip every other tile,
+    where what the masked step adds to its accumulators is exactly zero.
+    Plain arithmetic, so Python ints, numpy grids and traced program ids
+    all do."""
+    ahead = q_offset + (qi + 1) * block_q - 1 >= k_offset + kj * block_k
+    if window is None:
+        return ahead
+    return ahead & (q_offset + qi * block_q
+                    - (k_offset + (kj + 1) * block_k - 1) < window)
 
 
 def _last_k_block(qi, num_kb, block_q, block_k, q_offset, k_offset):
@@ -277,11 +301,29 @@ def _first_q_block(kj, num_qb, block_q, block_k, q_offset, k_offset):
     return jnp.clip(first, 0, num_qb - 1)
 
 
+def _first_k_block(qi, num_kb, block_q, block_k, q_offset, k_offset, window):
+    """The first k block of which q block ``qi`` sees anything under a
+    window, clamped into the grid: the block of its first query's oldest
+    key, ``window - 1`` back."""
+    first = (q_offset - k_offset + qi * block_q - (window - 1)) // block_k
+    return jnp.clip(first, 0, num_kb - 1)
+
+
+def _last_q_block(kj, num_qb, block_q, block_k, q_offset, k_offset, window):
+    """The last q block that sees anything of k block ``kj`` under a
+    window, clamped into the grid: the block of the query ``window - 1``
+    past its last key (``_first_k_block``'s mirror)."""
+    last = (k_offset - q_offset + (kj + 1) * block_k - 1
+            + (window - 1)) // block_q
+    return jnp.clip(last, 0, num_qb - 1)
+
+
 def _record_tiles(causal, num_qb, num_kb, block_q, block_k, q_offset,
-                  k_offset) -> None:
+                  k_offset, window=None, group=1) -> None:
     """At trace time, as ``optimizer._record_flush`` does for the wire:
     the (q, k) tile pairs a slice of this multi-tile call computes and
-    skips, by the kernels' own predicate over the grid."""
+    skips, by the kernels' own predicate over the grid, and the query
+    heads that share one key/value head."""
     import numpy as np
 
     from .. import metrics
@@ -290,14 +332,16 @@ def _record_tiles(causal, num_qb, num_kb, block_q, block_k, q_offset,
     if causal:
         computed = int(_tile_visible(
             np.arange(num_qb)[:, None], np.arange(num_kb)[None, :], block_q,
-            block_k, q_offset, k_offset).sum())
+            block_k, q_offset, k_offset, window).sum())
     metrics.ATTN_TILES_LAST.set(computed, kind="computed")
     metrics.ATTN_TILES_LAST.set(num_qb * num_kb - computed, kind="skipped")
+    metrics.ATTN_KV_GROUP_LAST.set(group)
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                       acc_scr, *, causal: bool, scale: float, block_q: int,
-                      block_k: int, q_offset: int, k_offset: int):
+                      block_k: int, q_offset: int, k_offset: int,
+                      window: int | None = None):
     # Grid (BH, num_q_blocks, num_k_blocks), K innermost: only ONE
     # [block_k, D] K/V tile is VMEM-resident per step (long sequences never
     # exceed VMEM); scratch carries (m, l, acc) across the K dimension.
@@ -314,8 +358,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     # A causal call computes only the tiles its mask leaves something of.
     # _init and _finalize_block stay outside: the last k tiles of a q block
     # are the skipped ones, and it still has to write its output.
-    @pl.when(_tile_visible(qi, j, block_q, block_k, q_offset, k_offset)
-             if causal else True)
+    @pl.when(_tile_visible(qi, j, block_q, block_k, q_offset, k_offset,
+                           window) if causal else True)
     def _step():
         q = q_ref[0]       # [block_q, D]
         k_tile = k_ref[0]  # [block_k, D]
@@ -330,7 +374,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
             preferred_element_type=jnp.float32,
         ) * scale  # [block_q, block_k]
         if causal:
-            mask = _causal_mask(qi, j, block_q, block_k, q_offset, k_offset)
+            mask = _causal_mask(qi, j, block_q, block_k, q_offset, k_offset,
+                                window)
             s = jnp.where(mask, s, NEG_INF)
         m_prev = m_scr[:, 0]
         m_new = jnp.maximum(m_prev, s.max(axis=-1))
@@ -364,7 +409,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                      glse_ref, dq_ref, dq_scr, *, causal: bool,
                      scale: float, block_q: int, block_k: int,
-                     q_offset: int, k_offset: int):
+                     q_offset: int, k_offset: int,
+                     window: int | None = None):
     """dQ pass. Grid (BH, num_q_blocks, num_k_blocks), K innermost;
     accumulates dq for one Q tile across all K tiles.
 
@@ -381,8 +427,8 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    @pl.when(_tile_visible(qi, j, block_q, block_k, q_offset, k_offset)
-             if causal else True)
+    @pl.when(_tile_visible(qi, j, block_q, block_k, q_offset, k_offset,
+                           window) if causal else True)
     def _step():
         # Stored-dtype (bf16) matmul operands with f32 MXU accumulation —
         # see the forward kernel's note; f32 upcasts quartered throughput.
@@ -401,7 +447,8 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32,
         ) * scale
         if causal:
-            mask = _causal_mask(qi, j, block_q, block_k, q_offset, k_offset)
+            mask = _causal_mask(qi, j, block_q, block_k, q_offset, k_offset,
+                                window)
             s = jnp.where(mask, s, NEG_INF)
         p = jnp.exp(s - lse[:, None])
         dp = jax.lax.dot_general(
@@ -422,25 +469,37 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       glse_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
                       causal: bool, scale: float, block_q: int,
-                      block_k: int, q_offset: int, k_offset: int):
+                      block_k: int, q_offset: int, k_offset: int,
+                      window: int | None = None, group: int = 1):
     """dK/dV pass. Grid (BH, num_k_blocks, num_q_blocks), Q innermost;
-    accumulates dk, dv for one K/V tile across all Q tiles.
+    accumulates dk, dv for one K/V tile across all Q tiles. With grouped
+    keys and values the grid is (B x KV heads, num_k_blocks, group,
+    num_q_blocks) and the tile's accumulators also run over the ``group``
+    query heads that read it: their sum is taken here, in float32.
 
     dV_j = sum_i P_ij dO_i; dK_j = scale * sum_i dS_ij Q_i.
     """
     kj = pl.program_id(1)
-    i = pl.program_id(2)
-    num_qb = pl.num_programs(2)
+    if group == 1:
+        i = pl.program_id(2)
+        num_qb = pl.num_programs(2)
+    else:
+        head, i = pl.program_id(2), pl.program_id(3)
+        num_qb = pl.num_programs(3)
 
-    @pl.when(i == 0)
+    def at_end(step, head_wanted):
+        """This K tile's first (0, head 0) or last inner step."""
+        return step if group == 1 else step & (head == head_wanted)
+
+    @pl.when(at_end(i == 0, 0))
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
     # The first q tiles of a k block are the skipped ones: _init zeroes
     # dk_scr / dv_scr all the same.
-    @pl.when(_tile_visible(i, kj, block_q, block_k, q_offset, k_offset)
-             if causal else True)
+    @pl.when(_tile_visible(i, kj, block_q, block_k, q_offset, k_offset,
+                           window) if causal else True)
     def _step():
         # Stored-dtype (bf16) matmul operands with f32 MXU accumulation —
         # see the forward kernel's note; f32 upcasts quartered throughput.
@@ -457,7 +516,8 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32,
         ) * scale  # [block_q, block_k]
         if causal:
-            mask = _causal_mask(i, kj, block_q, block_k, q_offset, k_offset)
+            mask = _causal_mask(i, kj, block_q, block_k, q_offset, k_offset,
+                                window)
             s = jnp.where(mask, s, NEG_INF)
         p = jnp.exp(s - lse[:, None])  # [block_q, block_k]
         # dV_j += P^T @ dO (P rounds to the stored dtype for the MXU pass)
@@ -476,7 +536,7 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(i == num_qb - 1)
+    @pl.when(at_end(i == num_qb - 1, group - 1))
     def _write():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -549,43 +609,92 @@ def _flash_dqkv_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
 # ---------------------------------------------------------------------------
 
 
-def _fwd_call(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
-              interpret):
+def _kind_scope(window):
+    """A windowed call's kernels sit under ``hvd.attn.window`` as well,
+    outside ``hvd.attn.fwd`` / ``hvd.attn.bwd``: a model with both kinds of
+    layer tells them apart in its step's text, and whoever sums by the
+    innermost phase scope still finds forward and backward."""
     from ..profiler import annotate_collective
 
-    with annotate_collective(SCOPE_ATTN_FWD):
+    if window is None:
+        return contextlib.nullcontext()
+    return annotate_collective(SCOPE_ATTN_WINDOW)
+
+
+def _fwd_call(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
+              interpret, window=None):
+    from ..profiler import annotate_collective
+
+    with _kind_scope(window), annotate_collective(SCOPE_ATTN_FWD):
         return _fwd_kernels(qr, kr, vr, causal, block_q, block_k, q_offset,
-                            k_offset, interpret)
+                            k_offset, interpret, window)
 
 
-def _kv_index_map(causal, num_kb, block_q, block_k, q_offset, k_offset):
+def _kv_head(bh, group):
+    """The key/value slice that query slice ``bh`` reads: slices are
+    (batch, head) pairs in that order and ``group`` query heads share one
+    key/value head, so it is ``bh // group`` whatever the batch."""
+    return bh if group == 1 else bh // group
+
+
+def _kv_index_map(causal, num_kb, block_q, block_k, q_offset, k_offset,
+                  window=None, group=1):
     """K/V block of grid step (bh, q block i, k block j), K innermost. A
     causal call stops at the last tile q block ``i`` computes: a
     ``pl.when`` alone would still have the pipeline fetch the skipped
     steps' blocks, and a block index that does not change fetches nothing.
+    A windowed one also starts at the first tile it computes.
     """
     if not causal:
-        return lambda bh, i, j: (bh, j, 0)
-    return lambda bh, i, j: (bh, jnp.minimum(j, _last_k_block(
-        i, num_kb, block_q, block_k, q_offset, k_offset)), 0)
+        return lambda bh, i, j: (_kv_head(bh, group), j, 0)
+
+    def block(i, j):
+        j = jnp.minimum(j, _last_k_block(
+            i, num_kb, block_q, block_k, q_offset, k_offset))
+        if window is None:
+            return j
+        return jnp.maximum(j, _first_k_block(
+            i, num_kb, block_q, block_k, q_offset, k_offset, window))
+
+    return lambda bh, i, j: (_kv_head(bh, group), block(i, j), 0)
 
 
-def _q_index_map(causal, num_qb, block_q, block_k, q_offset, k_offset):
-    """Q-side block (q, dO) of grid step (bh, k block j, q block i), Q
-    innermost: a causal call starts at the first tile k block ``j``
-    computes (``_kv_index_map``'s mirror)."""
+def _q_block(causal, num_qb, block_q, block_k, q_offset, k_offset,
+             window=None):
+    """``(k block j, q block i) ->`` the Q-side block (q, dO) of a grid
+    step of the Q-innermost dk/dv call: a causal call starts at the first
+    tile k block ``j`` computes (``_kv_index_map``'s mirror), a windowed
+    one also stops at the last."""
     if not causal:
-        return lambda bh, j, i: (bh, i, 0)
-    return lambda bh, j, i: (bh, jnp.maximum(i, _first_q_block(
-        j, num_qb, block_q, block_k, q_offset, k_offset)), 0)
+        return lambda j, i: i
+
+    def block(j, i):
+        i = jnp.maximum(i, _first_q_block(
+            j, num_qb, block_q, block_k, q_offset, k_offset))
+        if window is None:
+            return i
+        return jnp.minimum(i, _last_q_block(
+            j, num_qb, block_q, block_k, q_offset, k_offset, window))
+
+    return block
+
+
+def _single_tile(Sq, Sk, block_q, block_k, window, group) -> bool:
+    """The direct-softmax forward and the fused backward take one-tile
+    sequences with a head of keys and values a query head and no window;
+    anything else is the multi-tile kernels', on a grid of one tile if
+    need be."""
+    return (Sq == block_q and Sk == block_k and window is None
+            and group == 1)
 
 
 def _fwd_kernels(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
-                 interpret):
+                 interpret, window=None):
     BH, Sq, D = qr.shape
     Sk = kr.shape[1]
+    group = BH // kr.shape[0]
     scale = 1.0 / (D ** 0.5)
-    if Sq == block_q and Sk == block_k:
+    if _single_tile(Sq, Sk, block_q, block_k, window, group):
         # Single-tile sequences skip the online-softmax machinery, and a
         # grid step takes a group of them.
         group, q_spec, kv_spec, row_spec = _group_specs(
@@ -609,14 +718,14 @@ def _fwd_kernels(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
     kernel = functools.partial(
         _flash_fwd_kernel, causal=causal, scale=scale,
         block_q=block_q, block_k=block_k,
-        q_offset=q_offset, k_offset=k_offset,
+        q_offset=q_offset, k_offset=k_offset, window=window,
     )
     num_qb, num_kb = Sq // block_q, Sk // block_k
     _record_tiles(causal, num_qb, num_kb, block_q, block_k, q_offset,
-                  k_offset)
+                  k_offset, window, group)
     kv_spec = pl.BlockSpec((1, block_k, D),
                            _kv_index_map(causal, num_kb, block_q, block_k,
-                                         q_offset, k_offset))
+                                         q_offset, k_offset, window, group))
     return pl.pallas_call(
         kernel,
         grid=(BH, num_qb, num_kb),
@@ -644,19 +753,20 @@ def _fwd_kernels(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
 
 
 def _flash_bwd(causal, block_q, block_k, q_offset, k_offset, interpret,
-               res, g, g_lse=None):
+               res, g, g_lse=None, window=None):
     from ..profiler import annotate_collective
 
-    with annotate_collective(SCOPE_ATTN_BWD):
+    with _kind_scope(window), annotate_collective(SCOPE_ATTN_BWD):
         return _bwd_kernels(causal, block_q, block_k, q_offset, k_offset,
-                            interpret, res, g, g_lse)
+                            interpret, res, g, g_lse, window)
 
 
 def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
-                 res, g, g_lse):
+                 res, g, g_lse, window=None):
     qr, kr, vr, out, lse = res
     BH, Sq, D = qr.shape
-    Sk = kr.shape[1]
+    BHkv, Sk = kr.shape[:2]
+    group = BH // BHkv
     scale = 1.0 / (D ** 0.5)
     do = g
     if g_lse is None:
@@ -668,7 +778,7 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)[:, None, :]  # [BH, 1, Sq]
 
-    if Sq == block_q and Sk == block_k:
+    if _single_tile(Sq, Sk, block_q, block_k, window, group):
         # Single-tile sequences (BERT-Large with auto-block): one fused
         # kernel computes dq, dk, dv for a group of slices a grid step —
         # the two-pass split below exists only to bound VMEM for
@@ -697,10 +807,10 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
 
     num_qb, num_kb = Sq // block_q, Sk // block_k
     _record_tiles(causal, num_qb, num_kb, block_q, block_k, q_offset,
-                  k_offset)
+                  k_offset, window, group)
     kv_spec = pl.BlockSpec((1, block_k, D),
                            _kv_index_map(causal, num_kb, block_q, block_k,
-                                         q_offset, k_offset))
+                                         q_offset, k_offset, window, group))
     q_specs = [
         pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
         kv_spec,
@@ -714,6 +824,7 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
         functools.partial(
             _flash_dq_kernel, causal=causal, scale=scale, block_q=block_q,
             block_k=block_k, q_offset=q_offset, k_offset=k_offset,
+            window=window,
         ),
         grid=(BH, num_qb, num_kb),
         in_specs=q_specs,
@@ -724,32 +835,38 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
         name=KERNEL_NAME,
     )(qr, kr, vr, do, lse, delta, g_lse)
 
-    q_spec = pl.BlockSpec((1, block_q, D),
-                          _q_index_map(causal, num_qb, block_q, block_k,
-                                       q_offset, k_offset))
-    kv_specs = [
-        q_spec,
-        pl.BlockSpec((1, block_k, D), lambda bh, j, i: (bh, j, 0)),
-        pl.BlockSpec((1, block_k, D), lambda bh, j, i: (bh, j, 0)),
-        q_spec,
-        pl.BlockSpec((1, 1, Sq), lambda bh, j, i: (bh, 0, 0)),
-        pl.BlockSpec((1, 1, Sq), lambda bh, j, i: (bh, 0, 0)),
-        pl.BlockSpec((1, 1, Sq), lambda bh, j, i: (bh, 0, 0)),
-    ]
+    q_block = _q_block(causal, num_qb, block_q, block_k, q_offset, k_offset,
+                       window)
+    if group == 1:
+        grid = (BH, num_kb, num_qb)
+        q_spec = pl.BlockSpec((1, block_q, D),
+                              lambda bh, j, i: (bh, q_block(j, i), 0))
+        k_spec = pl.BlockSpec((1, block_k, D), lambda bh, j, i: (bh, j, 0))
+        row_spec = pl.BlockSpec((1, 1, Sq), lambda bh, j, i: (bh, 0, 0))
+    else:
+        # One key/value head's tile stays resident while the group's query
+        # heads, and every q block of each, go by.
+        grid = (BHkv, num_kb, group, num_qb)
+        q_spec = pl.BlockSpec(
+            (1, block_q, D),
+            lambda bh, j, h, i: (bh * group + h, q_block(j, i), 0))
+        k_spec = pl.BlockSpec((1, block_k, D),
+                              lambda bh, j, h, i: (bh, j, 0))
+        row_spec = pl.BlockSpec((1, 1, Sq),
+                                lambda bh, j, h, i: (bh * group + h, 0, 0))
     dk, dv = pl.pallas_call(
         functools.partial(
             _flash_dkv_kernel, causal=causal, scale=scale, block_q=block_q,
             block_k=block_k, q_offset=q_offset, k_offset=k_offset,
+            window=window, group=group,
         ),
-        grid=(BH, num_kb, num_qb),
-        in_specs=kv_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda bh, j, i: (bh, j, 0)),
-            pl.BlockSpec((1, block_k, D), lambda bh, j, i: (bh, j, 0)),
-        ],
+        grid=grid,
+        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec,
+                  row_spec],
+        out_specs=[k_spec, k_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, Sk, D), kr.dtype),
-            jax.ShapeDtypeStruct((BH, Sk, D), vr.dtype),
+            jax.ShapeDtypeStruct((BHkv, Sk, D), kr.dtype),
+            jax.ShapeDtypeStruct((BHkv, Sk, D), vr.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
@@ -762,36 +879,39 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
 
 
 # custom_vjp over the (out, lse)-returning primal so residuals are exact.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash_with_lse(qr, kr, vr, causal, block_q, block_k, q_offset,
-                    k_offset, interpret):
+                    k_offset, interpret, window=None):
     return _fwd_call(qr, kr, vr, causal, block_q, block_k, q_offset,
-                     k_offset, interpret)
+                     k_offset, interpret, window)
 
 
 def _flash_with_lse_fwd(qr, kr, vr, causal, block_q, block_k, q_offset,
-                        k_offset, interpret):
+                        k_offset, interpret, window):
     out, lse = _fwd_call(qr, kr, vr, causal, block_q, block_k, q_offset,
-                         k_offset, interpret)
+                         k_offset, interpret, window)
     return (out, lse), (qr, kr, vr, out, lse)
 
 
 def _flash_with_lse_bwd(causal, block_q, block_k, q_offset, k_offset,
-                        interpret, res, gs):
+                        interpret, window, res, gs):
     g, g_lse = gs
     # float0 cotangent (lse unused downstream) -> zeros.
     if g_lse is None or g_lse.dtype == jax.dtypes.float0:
         g_lse = None
     return _flash_bwd(causal, block_q, block_k, q_offset, k_offset,
-                      interpret, res, g, g_lse)
+                      interpret, res, g, g_lse, window)
 
 
 _flash_with_lse.defvjp(_flash_with_lse_fwd, _flash_with_lse_bwd)
 
 
-def _prepare_flash(q, k, v, causal, block_q, block_k, q_offset, k_offset):
+def _prepare_flash(q, k, v, causal, block_q, block_k, q_offset, k_offset,
+                   window=None):
     """Shared validation + block selection for the flash entry points —
-    one implementation so the guards cannot drift between them."""
+    one implementation so the guards cannot drift between them:
+    ``(block_q, block_k, window)``, the window ``None`` where it hides
+    nothing the causal mask leaves."""
     Sq, Sk = q.shape[2], k.shape[2]
     if not (q.dtype == k.dtype == v.dtype):
         # The kernels run stored-dtype matmuls (f32 MXU accumulation);
@@ -801,6 +921,12 @@ def _prepare_flash(q, k, v, causal, block_q, block_k, q_offset, k_offset):
             f"flash attention operands must share a dtype; got "
             f"q={q.dtype}, k={k.dtype}, v={v.dtype} — cast them to one "
             "dtype")
+    if k.shape != v.shape or k.shape[0] != q.shape[0] \
+            or q.shape[1] % k.shape[1]:
+        raise ValueError(
+            f"flash attention wants k and v of one shape [B, KV heads, S, "
+            f"D] whose heads divide q's; got q={q.shape}, k={k.shape}, "
+            f"v={v.shape}")
     block_q = block_q if block_q is not None else _auto_block(Sq)
     block_k = block_k if block_k is not None else _auto_block(Sk)
     if Sq % block_q or Sk % block_k:
@@ -816,18 +942,42 @@ def _prepare_flash(q, k, v, causal, block_q, block_k, q_offset, k_offset):
             "q_offset=0, k_offset=0 is top-left — use "
             "blockwise_attention_reference if that is what you want)"
         )
-    return block_q, block_k
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError(
+                f"window={window} is a band under the diagonal (query i "
+                "sees keys j with 0 <= i - j < window): it needs "
+                "causal=True and a window of at least 1")
+        if window >= q_offset + Sq - k_offset:
+            window = None  # no query is that far past any key
+    return block_q, block_k, window
+
+
+def _flash(q, k, v, causal, block_q, block_k, q_offset, k_offset, interpret,
+           window):
+    """``[B, H, Sq, D]``, ``[B, KV heads, Sk, D]`` twice -> ``(out [B, H,
+    Sq, D], lse [B, H, Sq])``: both entry points' one way to the kernels."""
+    B, H, Sq, D = q.shape
+    block_q, block_k, window = _prepare_flash(
+        q, k, v, causal, block_q, block_k, q_offset, k_offset, window)
+    out, lse = _flash_with_lse(
+        q.reshape(B * H, Sq, D), k.reshape((-1,) + k.shape[2:]),
+        v.reshape((-1,) + v.shape[2:]), causal, block_q, block_k, q_offset,
+        k_offset, interpret, window)
+    return out.reshape(B, H, Sq, D), lse.reshape(B, H, Sq)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "block_q", "block_k", "q_offset", "k_offset",
-                     "interpret"),
+                     "interpret", "window"),
 )
 def flash_attention(q, k, v, causal: bool = False, block_q: int | None = None,
                     block_k: int | None = None, q_offset: int = 0,
-                    k_offset: int = 0, interpret: bool = False):
-    """Pallas flash attention. q, k, v: [B, H, S, D] → [B, H, S, D].
+                    k_offset: int = 0, interpret: bool = False,
+                    window: int | None = None):
+    """Pallas flash attention. q: [B, H, S, D], k, v: [B, KV heads, S, D]
+    → [B, H, S, D].
 
     Forward grid: (B*H, Sq/block_q, Sk/block_k); each program streams K/V
     tiles from VMEM blocks with fp32 running-max/normalizer/accumulator
@@ -841,28 +991,28 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int | None = None,
     alignment explicit (``q_offset=Sk - Sq`` gives decode-style bottom-right
     alignment); with both defaulted the call raises instead of silently
     picking top-left.
+
+    ``window`` (static, needs ``causal``): query ``i`` sees the keys ``j``
+    with ``0 <= i - j < window`` in global positions; a window no query
+    reaches the far edge of is the causal call itself. ``k`` and ``v`` may
+    have fewer heads than ``q``, a divisor of its count: query head ``n``
+    reads key/value head ``n // (H // KV heads)``, and their gradients come
+    back summed over the group, in the shape they came in.
     """
-    B, H, Sq, D = q.shape
-    Sk = k.shape[2]
-    block_q, block_k = _prepare_flash(q, k, v, causal, block_q, block_k,
-                                      q_offset, k_offset)
-    qr = q.reshape(B * H, Sq, D)
-    kr = k.reshape(B * H, Sk, D)
-    vr = v.reshape(B * H, Sk, D)
-    out, _lse = _flash_with_lse(qr, kr, vr, causal, block_q, block_k,
-                                q_offset, k_offset, interpret)
-    return out.reshape(B, H, Sq, D)
+    return _flash(q, k, v, causal, block_q, block_k, q_offset, k_offset,
+                  interpret, window)[0]
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "block_q", "block_k", "q_offset", "k_offset",
-                     "interpret"),
+                     "interpret", "window"),
 )
 def flash_attention_lse(q, k, v, causal: bool = False,
                         block_q: int | None = None,
                         block_k: int | None = None, q_offset: int = 0,
-                        k_offset: int = 0, interpret: bool = False):
+                        k_offset: int = 0, interpret: bool = False,
+                        window: int | None = None):
     """Like :func:`flash_attention` but also returns the per-row
     logsumexp ``[B, H, Sq]`` (fp32) — the hook ring attention uses to
     merge per-shard partial attentions exactly:
@@ -871,12 +1021,5 @@ def flash_attention_lse(q, k, v, causal: bool = False,
     Fully differentiable — INCLUDING through lse: its cotangent
     propagates into the backward kernels (dS += P * g_lse), which is what
     makes logsumexp-merged schemes like ring-flash train exactly."""
-    B, H, Sq, D = q.shape
-    Sk = k.shape[2]
-    block_q, block_k = _prepare_flash(q, k, v, causal, block_q, block_k,
-                                      q_offset, k_offset)
-    out, lse = _flash_with_lse(
-        q.reshape(B * H, Sq, D), k.reshape(B * H, Sk, D),
-        v.reshape(B * H, Sk, D), causal, block_q, block_k, q_offset,
-        k_offset, interpret)
-    return out.reshape(B, H, Sq, D), lse.reshape(B, H, Sq)
+    return _flash(q, k, v, causal, block_q, block_k, q_offset, k_offset,
+                  interpret, window)

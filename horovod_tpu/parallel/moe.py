@@ -131,7 +131,8 @@ def make_moe_step(axis_name: str = "hvd", capacity: int = 4, mesh=None):
 
 
 def route_to_capacity(tokens, logits, num_experts, capacity, top_k=1,
-                      first_expert=0, experts_here=None):
+                      first_expert=0, experts_here=None,
+                      gates_over_picks=False):
     """Capacity-factor top-k routing into fixed per-expert slots — the
     jit-compatible answer to ragged dispatch (the helper the uneven-split
     ``alltoall`` rejection points at).
@@ -144,7 +145,10 @@ def route_to_capacity(tokens, logits, num_experts, capacity, top_k=1,
     ``expert`` (the picks, ``lax.top_k`` of the logits: ties go to the
     lower index, as ``argmax``), ``pos`` (slot within the expert's
     buffer), ``keep``, ``gate`` (softmax prob of each pick over all
-    ``num_experts``, not renormalised over the picks), each ``[T]`` for
+    ``num_experts``, not renormalised over the picks; with
+    ``gates_over_picks`` the softmax over the ``top_k`` picked logits
+    alone, whichever window holds each pick, so that a token's gates add
+    up to one over all the windows), each ``[T]`` for
     ``top_k=1`` and ``[T, top_k]`` otherwise, and ``counts
     [experts_here]`` (kept pairs per expert — the
     ``hvd_moe_expert_load`` signal).
@@ -166,9 +170,12 @@ def route_to_capacity(tokens, logits, num_experts, capacity, top_k=1,
     if experts_here is None:
         experts_here = num_experts - first_expert
     with annotate_collective(SCOPE_MOE_ROUTE):
-        _, expert = lax.top_k(logits, top_k)                   # [T, K]
-        gate = jnp.take_along_axis(
-            jax.nn.softmax(logits, axis=-1), expert, axis=1)
+        picked, expert = lax.top_k(logits, top_k)              # [T, K]
+        if gates_over_picks:
+            gate = jax.nn.softmax(picked, axis=-1)
+        else:
+            gate = jnp.take_along_axis(
+                jax.nn.softmax(logits, axis=-1), expert, axis=1)
         # Pairs in token order, then pick order. An expert outside the
         # window has no column: its row of the one-hot is all zero.
         local = expert.reshape(T * top_k) - first_expert
@@ -236,17 +243,19 @@ def combine_top_k(back, expert, pos, keep, gate, first_expert=0):
         return out[:T].astype(back.dtype)
 
 
-def gated_expert_ffn(w_gate, w_up, w_down, x):
+def gated_expert_ffn(w_gate, w_up, w_down, x, activation=jax.nn.silu):
     """``experts`` gated feed-forwards at once, one batched matmul a
     projection: ``x [experts, capacity, D]``, ``w_gate`` / ``w_up``
     ``[experts, D, H]``, ``w_down [experts, H, D]`` → ``w_down ·
-    (silu(w_gate · x) ⊙ (w_up · x))``. No bias, so an empty slot (zeros)
-    stays zero and needs no mask."""
+    (activation(w_gate · x) ⊙ (w_up · x))``, SiLU unless the model says
+    otherwise (``jax.nn.relu``: a ReGLU). No bias, and every such
+    activation keeps zero at zero, so an empty slot (zeros) stays zero and
+    needs no mask."""
     from ..attribution import SCOPE_MOE_EXPERTS
     from ..profiler import annotate_collective
 
     with annotate_collective(SCOPE_MOE_EXPERTS):
-        hidden = jax.nn.silu(jnp.einsum("ecd,edh->ech", x, w_gate)) \
+        hidden = activation(jnp.einsum("ecd,edh->ech", x, w_gate)) \
             * jnp.einsum("ecd,edh->ech", x, w_up)
         return jnp.einsum("ech,ehd->ecd", hidden, w_down)
 
