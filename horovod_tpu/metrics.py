@@ -903,8 +903,9 @@ ATTN_TILES_LAST = gauge(
     "hvd_attn_tiles_last",
     "(q, k) tile pairs a (batch x head) slice of the LAST traced multi-tile "
     "flash-attention call computes and skips (a causal call skips the "
-    "tiles its mask leaves nothing of): set at trace time, as "
-    "hvd_grad_sync_last_bytes is.",
+    "tiles its mask leaves nothing of), and the grid steps a slice takes "
+    "(kind=grid: the whole tile grid, under a window the band alone): set "
+    "at trace time, as hvd_grad_sync_last_bytes is.",
     ("kind",))
 ATTN_GROUP_LAST = gauge(
     "hvd_attn_group_last",

@@ -36,23 +36,33 @@ decode-style bottom-right), so ``flash_attention`` raises and asks for
 explicit offsets rather than silently picking one. The multi-tile causal
 kernels tell from a grid step's block indices and the static offsets
 whether the mask leaves anything of its tile (``_tile_visible``): a tile
-it leaves nothing of is neither computed nor fetched. The grid itself
-stays whole, so a skipped tile still costs its (empty) grid step; the
-gauge ``hvd_attn_tiles_last{kind}`` says how many a call computes and
-skips.
+it leaves nothing of is neither computed nor fetched. Without a window
+the grid itself stays whole, so a skipped tile still costs its (empty)
+grid step (0.13 to 0.2 us on a v5e: PERF.md, PR 33).
 
 A causal call may also be *windowed* (``window=W``): query ``i`` sees the
 keys ``j`` with ``0 <= i - j < W``, a band under the diagonal. The same
 predicate then throws away the tiles behind the band as well as those
-ahead of the diagonal, and the index maps are clamped at both ends (a
-first visible key tile for a query tile, a last visible query tile for a
-key tile). And ``k``, ``v`` may have fewer heads than ``q`` (*grouped*
-keys and values: query head ``n`` reads key/value head ``n // group``):
-the key/value block is found through the index map, nothing is repeated
-in HBM, and the dk/dv kernel sums over a group's query heads in its
-float32 accumulators (a grid axis of its own inside the K block's
-reduction). The gauge ``hvd_attn_kv_group_last`` says the group. Both go
-through the multi-tile kernels, whatever the length.
+ahead of the diagonal, and the innermost grid dimension runs over the
+band alone: ``band_kb`` steps a query tile (``band_qb`` a key tile in the
+dk/dv kernel), the most tiles the band leaves of any row (column),
+reckoned at trace time (``_tile_plan``). Step ``jj`` of query tile ``i``
+stands for key tile ``_first_k_block(i) + jj``, in the kernel and in the
+index map, which stops at ``_last_k_block(i)``; the dk/dv kernel is the
+mirror. Every visible tile is visited once and in the whole grid's order,
+so the results are the same bits. What stays empty is the corner where
+the band has not yet left the sequence's start: 36 of 288 steps a slice
+at SmallThinker's shapes, where the whole grid had 772 of 1,024. The
+gauge ``hvd_attn_tiles_last{kind}`` says how many tile pairs a call
+computes and skips and how many grid steps it takes.
+
+And ``k``, ``v`` may have fewer heads than ``q`` (*grouped* keys and
+values: query head ``n`` reads key/value head ``n // group``): the
+key/value block is found through the index map, nothing is repeated in
+HBM, and the dk/dv kernel sums over a group's query heads in its float32
+accumulators (a grid axis of its own inside the K block's reduction). The
+gauge ``hvd_attn_kv_group_last`` says the group. Both go through the
+multi-tile kernels, whatever the length.
 """
 
 from __future__ import annotations
@@ -318,48 +328,79 @@ def _last_q_block(kj, num_qb, block_q, block_k, q_offset, k_offset, window):
     return jnp.clip(last, 0, num_qb - 1)
 
 
-def _record_tiles(causal, num_qb, num_kb, block_q, block_k, q_offset,
-                  k_offset, window=None, group=1) -> None:
-    """At trace time, as ``optimizer._record_flush`` does for the wire:
-    the (q, k) tile pairs a slice of this multi-tile call computes and
-    skips, by the kernels' own predicate over the grid, and the query
-    heads that share one key/value head."""
+def _tile_plan(causal, num_qb, num_kb, block_q, block_k, q_offset, k_offset,
+               window=None):
+    """At trace time, by the kernels' own predicate over the whole tile
+    grid: ``(pairs, band_kb, band_qb)``, the (q, k) tile pairs a slice of a
+    multi-tile call computes and the extent of its grids' innermost
+    dimension, K blocks a q block for the forward and dq kernels and Q
+    blocks a k block for the dk/dv kernel. Without a window that is the
+    whole row or column. Under one it is the most tiles the band leaves
+    of any row (column): they are a run that starts at ``_first_k_block``
+    (``_first_q_block``), so that many steps from there reach them all."""
     import numpy as np
 
+    if not causal:
+        return num_qb * num_kb, num_kb, num_qb
+    visible = _tile_visible(
+        np.arange(num_qb)[:, None], np.arange(num_kb)[None, :], block_q,
+        block_k, q_offset, k_offset, window)
+    pairs = int(visible.sum())
+    if window is None:
+        return pairs, num_kb, num_qb
+    return (pairs, max(1, int(visible.sum(1).max())),
+            max(1, int(visible.sum(0).max())))
+
+
+def _record_tiles(causal, num_qb, num_kb, block_q, block_k, q_offset,
+                  k_offset, window=None, group=1):
+    """At trace time, as ``optimizer._record_flush`` does for the wire:
+    the (q, k) tile pairs a slice of this multi-tile call computes and
+    skips, the steps its K-innermost grids take a slice (the dk/dv
+    kernel's is the mirror, K blocks x ``band_qb``), and the query heads
+    that share one key/value head. Returns ``(band_kb, band_qb)``, the
+    innermost extents that count rests on (``_tile_plan``)."""
     from .. import metrics
 
-    computed = num_qb * num_kb
-    if causal:
-        computed = int(_tile_visible(
-            np.arange(num_qb)[:, None], np.arange(num_kb)[None, :], block_q,
-            block_k, q_offset, k_offset, window).sum())
+    computed, band_kb, band_qb = _tile_plan(
+        causal, num_qb, num_kb, block_q, block_k, q_offset, k_offset, window)
     metrics.ATTN_TILES_LAST.set(computed, kind="computed")
     metrics.ATTN_TILES_LAST.set(num_qb * num_kb - computed, kind="skipped")
+    metrics.ATTN_TILES_LAST.set(num_qb * band_kb, kind="grid")
     metrics.ATTN_KV_GROUP_LAST.set(group)
+    return band_kb, band_qb
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                       acc_scr, *, causal: bool, scale: float, block_q: int,
                       block_k: int, q_offset: int, k_offset: int,
-                      window: int | None = None):
-    # Grid (BH, num_q_blocks, num_k_blocks), K innermost: only ONE
-    # [block_k, D] K/V tile is VMEM-resident per step (long sequences never
-    # exceed VMEM); scratch carries (m, l, acc) across the K dimension.
+                      window: int | None = None, num_kb: int):
+    # Grid (BH, num_q_blocks, K steps), K innermost: only ONE [block_k, D]
+    # K/V tile is VMEM-resident per step (long sequences never exceed
+    # VMEM); scratch carries (m, l, acc) across the K dimension. A step is
+    # a k block, and under a window the k block that many past the first
+    # one its q block sees (``num_kb`` is then the sequence's count).
     qi = pl.program_id(1)
-    j = pl.program_id(2)
-    num_kb = pl.num_programs(2)
+    step = pl.program_id(2)
+    steps = pl.num_programs(2)
+    j = step if window is None else step + _first_k_block(
+        qi, num_kb, block_q, block_k, q_offset, k_offset, window)
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     # A causal call computes only the tiles its mask leaves something of.
-    # _init and _finalize_block stay outside: the last k tiles of a q block
+    # _init and _finalize_block stay outside: the last steps of a q block
     # are the skipped ones, and it still has to write its output.
-    @pl.when(_tile_visible(qi, j, block_q, block_k, q_offset, k_offset,
-                           window) if causal else True)
+    visible = _tile_visible(qi, j, block_q, block_k, q_offset, k_offset,
+                            window) if causal else True
+    if window is not None:
+        visible &= j < num_kb  # a step may pass the sequence's last block
+
+    @pl.when(visible)
     def _step():
         q = q_ref[0]       # [block_q, D]
         k_tile = k_ref[0]  # [block_k, D]
@@ -393,7 +434,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         )
         m_scr[:, 0] = m_new
 
-    @pl.when(j == num_kb - 1)
+    @pl.when(step == steps - 1)
     def _finalize_block():
         l = l_scr[:, 0]
         empty = l == 0.0
@@ -410,9 +451,9 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                      glse_ref, dq_ref, dq_scr, *, causal: bool,
                      scale: float, block_q: int, block_k: int,
                      q_offset: int, k_offset: int,
-                     window: int | None = None):
-    """dQ pass. Grid (BH, num_q_blocks, num_k_blocks), K innermost;
-    accumulates dq for one Q tile across all K tiles.
+                     window: int | None = None, num_kb: int):
+    """dQ pass. Grid (BH, num_q_blocks, K steps), K innermost as in the
+    forward; accumulates dq for one Q tile across the K tiles it sees.
 
     P_ij = exp(s_ij - lse_i); dS = P * (dO @ V^T - delta_i + g_lse_i);
     dQ_i = scale * sum_j dS_ij K_j. The g_lse term is the cotangent of the
@@ -420,15 +461,21 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     merge weights differentiate through lse, so it is NOT discardable.
     """
     qi = pl.program_id(1)
-    j = pl.program_id(2)
-    num_kb = pl.num_programs(2)
+    step = pl.program_id(2)
+    steps = pl.num_programs(2)
+    j = step if window is None else step + _first_k_block(
+        qi, num_kb, block_q, block_k, q_offset, k_offset, window)
 
-    @pl.when(j == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    @pl.when(_tile_visible(qi, j, block_q, block_k, q_offset, k_offset,
-                           window) if causal else True)
+    visible = _tile_visible(qi, j, block_q, block_k, q_offset, k_offset,
+                            window) if causal else True
+    if window is not None:
+        visible &= j < num_kb  # a step may pass the sequence's last block
+
+    @pl.when(visible)
     def _step():
         # Stored-dtype (bf16) matmul operands with f32 MXU accumulation —
         # see the forward kernel's note; f32 upcasts quartered throughput.
@@ -461,7 +508,7 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(j == num_kb - 1)
+    @pl.when(step == steps - 1)
     def _write():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
@@ -470,36 +517,46 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       glse_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
                       causal: bool, scale: float, block_q: int,
                       block_k: int, q_offset: int, k_offset: int,
-                      window: int | None = None, group: int = 1):
-    """dK/dV pass. Grid (BH, num_k_blocks, num_q_blocks), Q innermost;
-    accumulates dk, dv for one K/V tile across all Q tiles. With grouped
-    keys and values the grid is (B x KV heads, num_k_blocks, group,
-    num_q_blocks) and the tile's accumulators also run over the ``group``
-    query heads that read it: their sum is taken here, in float32.
+                      window: int | None = None, group: int = 1,
+                      num_qb: int):
+    """dK/dV pass. Grid (BH, num_k_blocks, Q steps), Q innermost;
+    accumulates dk, dv for one K/V tile across the Q tiles that see it. A
+    step is a q block, and under a window the q block that many past the
+    first one that sees the k block (``num_qb`` is then the sequence's
+    count). With grouped keys and values the grid is (B x KV heads,
+    num_k_blocks, group, Q steps) and the tile's accumulators also run
+    over the ``group`` query heads that read it: their sum is taken here,
+    in float32.
 
     dV_j = sum_i P_ij dO_i; dK_j = scale * sum_i dS_ij Q_i.
     """
     kj = pl.program_id(1)
     if group == 1:
-        i = pl.program_id(2)
-        num_qb = pl.num_programs(2)
+        step = pl.program_id(2)
+        steps = pl.num_programs(2)
     else:
-        head, i = pl.program_id(2), pl.program_id(3)
-        num_qb = pl.num_programs(3)
+        head, step = pl.program_id(2), pl.program_id(3)
+        steps = pl.num_programs(3)
+    i = step if window is None else step + _first_q_block(
+        kj, num_qb, block_q, block_k, q_offset, k_offset)
 
-    def at_end(step, head_wanted):
+    def at_end(inner, head_wanted):
         """This K tile's first (0, head 0) or last inner step."""
-        return step if group == 1 else step & (head == head_wanted)
+        return inner if group == 1 else inner & (head == head_wanted)
 
-    @pl.when(at_end(i == 0, 0))
+    @pl.when(at_end(step == 0, 0))
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
     # The first q tiles of a k block are the skipped ones: _init zeroes
     # dk_scr / dv_scr all the same.
-    @pl.when(_tile_visible(i, kj, block_q, block_k, q_offset, k_offset,
-                           window) if causal else True)
+    visible = _tile_visible(i, kj, block_q, block_k, q_offset, k_offset,
+                            window) if causal else True
+    if window is not None:
+        visible &= i < num_qb  # a step may pass the sequence's last block
+
+    @pl.when(visible)
     def _step():
         # Stored-dtype (bf16) matmul operands with f32 MXU accumulation —
         # see the forward kernel's note; f32 upcasts quartered throughput.
@@ -536,7 +593,7 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(at_end(i == num_qb - 1, group - 1))
+    @pl.when(at_end(step == steps - 1, group - 1))
     def _write():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -639,41 +696,41 @@ def _kv_head(bh, group):
 
 def _kv_index_map(causal, num_kb, block_q, block_k, q_offset, k_offset,
                   window=None, group=1):
-    """K/V block of grid step (bh, q block i, k block j), K innermost. A
+    """K/V block of grid step (bh, q block i, K step j), K innermost. A
     causal call stops at the last tile q block ``i`` computes: a
     ``pl.when`` alone would still have the pipeline fetch the skipped
     steps' blocks, and a block index that does not change fetches nothing.
-    A windowed one also starts at the first tile it computes.
+    Under a window step ``j`` is the k block ``j`` past the first that
+    ``i`` sees, as in the kernels, stopped at the last in the same way.
     """
     if not causal:
         return lambda bh, i, j: (_kv_head(bh, group), j, 0)
 
     def block(i, j):
-        j = jnp.minimum(j, _last_k_block(
-            i, num_kb, block_q, block_k, q_offset, k_offset))
+        last = _last_k_block(i, num_kb, block_q, block_k, q_offset, k_offset)
         if window is None:
-            return j
-        return jnp.maximum(j, _first_k_block(
-            i, num_kb, block_q, block_k, q_offset, k_offset, window))
+            return jnp.minimum(j, last)
+        return jnp.minimum(j + _first_k_block(
+            i, num_kb, block_q, block_k, q_offset, k_offset, window), last)
 
     return lambda bh, i, j: (_kv_head(bh, group), block(i, j), 0)
 
 
 def _q_block(causal, num_qb, block_q, block_k, q_offset, k_offset,
              window=None):
-    """``(k block j, q block i) ->`` the Q-side block (q, dO) of a grid
+    """``(k block j, Q step i) ->`` the Q-side block (q, dO) of a grid
     step of the Q-innermost dk/dv call: a causal call starts at the first
-    tile k block ``j`` computes (``_kv_index_map``'s mirror), a windowed
-    one also stops at the last."""
+    tile k block ``j`` computes (``_kv_index_map``'s mirror); under a
+    window step ``i`` counts on from there and stops at the last."""
     if not causal:
         return lambda j, i: i
 
     def block(j, i):
-        i = jnp.maximum(i, _first_q_block(
-            j, num_qb, block_q, block_k, q_offset, k_offset))
+        first = _first_q_block(j, num_qb, block_q, block_k, q_offset,
+                               k_offset)
         if window is None:
-            return i
-        return jnp.minimum(i, _last_q_block(
+            return jnp.maximum(i, first)
+        return jnp.minimum(i + first, _last_q_block(
             j, num_qb, block_q, block_k, q_offset, k_offset, window))
 
     return block
@@ -715,20 +772,20 @@ def _fwd_kernels(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
             interpret=interpret,
             name=KERNEL_NAME,
         )(qr, kr, vr)
+    num_qb, num_kb = Sq // block_q, Sk // block_k
     kernel = functools.partial(
         _flash_fwd_kernel, causal=causal, scale=scale,
         block_q=block_q, block_k=block_k,
-        q_offset=q_offset, k_offset=k_offset, window=window,
+        q_offset=q_offset, k_offset=k_offset, window=window, num_kb=num_kb,
     )
-    num_qb, num_kb = Sq // block_q, Sk // block_k
-    _record_tiles(causal, num_qb, num_kb, block_q, block_k, q_offset,
-                  k_offset, window, group)
+    band_kb, _ = _record_tiles(causal, num_qb, num_kb, block_q, block_k,
+                               q_offset, k_offset, window, group)
     kv_spec = pl.BlockSpec((1, block_k, D),
                            _kv_index_map(causal, num_kb, block_q, block_k,
                                          q_offset, k_offset, window, group))
     return pl.pallas_call(
         kernel,
-        grid=(BH, num_qb, num_kb),
+        grid=(BH, num_qb, band_kb),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
             kv_spec,
@@ -806,8 +863,9 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
         return dq, dk, dv
 
     num_qb, num_kb = Sq // block_q, Sk // block_k
-    _record_tiles(causal, num_qb, num_kb, block_q, block_k, q_offset,
-                  k_offset, window, group)
+    band_kb, band_qb = _record_tiles(causal, num_qb, num_kb, block_q,
+                                     block_k, q_offset, k_offset, window,
+                                     group)
     kv_spec = pl.BlockSpec((1, block_k, D),
                            _kv_index_map(causal, num_kb, block_q, block_k,
                                          q_offset, k_offset, window, group))
@@ -824,9 +882,9 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
         functools.partial(
             _flash_dq_kernel, causal=causal, scale=scale, block_q=block_q,
             block_k=block_k, q_offset=q_offset, k_offset=k_offset,
-            window=window,
+            window=window, num_kb=num_kb,
         ),
-        grid=(BH, num_qb, num_kb),
+        grid=(BH, num_qb, band_kb),
         in_specs=q_specs,
         out_specs=pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, D), qr.dtype),
@@ -838,7 +896,7 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
     q_block = _q_block(causal, num_qb, block_q, block_k, q_offset, k_offset,
                        window)
     if group == 1:
-        grid = (BH, num_kb, num_qb)
+        grid = (BH, num_kb, band_qb)
         q_spec = pl.BlockSpec((1, block_q, D),
                               lambda bh, j, i: (bh, q_block(j, i), 0))
         k_spec = pl.BlockSpec((1, block_k, D), lambda bh, j, i: (bh, j, 0))
@@ -846,7 +904,7 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
     else:
         # One key/value head's tile stays resident while the group's query
         # heads, and every q block of each, go by.
-        grid = (BHkv, num_kb, group, num_qb)
+        grid = (BHkv, num_kb, group, band_qb)
         q_spec = pl.BlockSpec(
             (1, block_q, D),
             lambda bh, j, h, i: (bh * group + h, q_block(j, i), 0))
@@ -858,7 +916,7 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
         functools.partial(
             _flash_dkv_kernel, causal=causal, scale=scale, block_q=block_q,
             block_k=block_k, q_offset=q_offset, k_offset=k_offset,
-            window=window, group=group,
+            window=window, group=group, num_qb=num_qb,
         ),
         grid=grid,
         in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec,
@@ -979,11 +1037,13 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int | None = None,
     """Pallas flash attention. q: [B, H, S, D], k, v: [B, KV heads, S, D]
     → [B, H, S, D].
 
-    Forward grid: (B*H, Sq/block_q, Sk/block_k); each program streams K/V
-    tiles from VMEM blocks with fp32 running-max/normalizer/accumulator
-    scratch. S must divide by the block sizes (pad upstream — XLA-style
-    static shapes). Differentiable via ``jax.custom_vjp`` with Pallas
-    backward kernels (saved residuals: output + per-row logsumexp).
+    Forward grid: (B*H, Sq/block_q, Sk/block_k), under a ``window`` (B*H,
+    Sq/block_q, the K tiles of the band's widest row); each program
+    streams K/V tiles from VMEM blocks with fp32 running-max / normalizer
+    / accumulator scratch. S must divide by the block sizes (pad upstream
+    — XLA-style static shapes). Differentiable via ``jax.custom_vjp``
+    with Pallas backward kernels (saved residuals: output + per-row
+    logsumexp).
 
     ``q_offset``/``k_offset``: global positions of element 0 of Q/K (static
     ints) — how ring attention applies a causal mask across shards. When

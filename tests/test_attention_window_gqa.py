@@ -1,8 +1,12 @@
 """The windowed and the grouped flash kernels (``flash_attention(...,
 window=W)``, ``k`` / ``v`` with fewer heads than ``q``): against a dense
-masked softmax forward and in all three gradients, the tile predicate and
-the index maps' clamps against a brute-force table, what a window that
-hides nothing lowers to, and the gauges. Interpret mode, small shapes."""
+masked softmax forward and in all three gradients, the tile predicate, the
+band grid and the index maps against a brute-force table, what a window
+that hides nothing lowers to, what the calls without a window lowered to
+and the windowed ones returned before their grid ran over the band alone,
+and the gauges. Interpret mode, small shapes."""
+
+import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -176,6 +180,12 @@ TILE_CASES = [
     pytest.param(64, 64, 32, 32, 500, 0, 100, id="band-left-behind"),
     pytest.param(64, 64, 32, 32, 0, 64, 10, id="nothing-visible"),
     pytest.param(16384, 16384, 512, 512, 0, 0, 4096, id="smallthinker"),
+    pytest.param(128, 128, 32, 32, 0, 0, 70, id="window-no-multiple"),
+    pytest.param(128, 128, 32, 32, 0, 0, 5, id="window-under-a-tile"),
+    pytest.param(128, 128, 16, 64, 0, 0, 7, id="window-under-a-k-tile"),
+    pytest.param(96, 96, 32, 32, 0, 32, 40, id="a-row-and-a-column-empty"),
+    pytest.param(96, 96, 32, 32, 1000, 990, 50, id="queries-past-the-keys"),
+    pytest.param(64, 128, 16, 32, 17, 0, 23, id="odd-offset"),
 ]
 
 
@@ -221,29 +231,195 @@ class TestTilePredicateAndClamps:
             if table[:, j].any():
                 found = np.flatnonzero(table[:, j])
                 assert (first, last) == (found[0], found[-1]), j
-
-    def test_the_index_maps_name_only_computed_or_resident_blocks(self):
-        nq = nk = 8
-        args = (32, 32, 0, 0)
-        kv_map = att._kv_index_map(True, nk, *args, window=70, group=7)
-        q_block = att._q_block(True, nq, *args, window=70)
-        visible = np.asarray(att._tile_visible(
-            np.arange(nq)[:, None], np.arange(nk)[None, :], *args, 70))
+        # The band grid: a row's (column's) inner steps count on from its
+        # first block, so step jj of q block i stands for k block
+        # first(i) + jj, computed if the predicate says so and the block
+        # is one of the sequence's. Every visible tile exactly once, in
+        # ascending order, in the least number of steps that does it.
+        pairs, band_kb, band_qb = att._tile_plan(True, nq, nk, bq, bk, q_off,
+                                                 k_off, window)
+        assert pairs == table.sum()
+        assert band_kb == max(1, table.sum(1).max())
+        assert band_qb == max(1, table.sum(0).max())
         for i in range(nq):
-            row = [int(kv_map(15, i, j)[1]) for j in range(nk)]
-            assert all(visible[i, block] for block in row)
+            first = int(att._first_k_block(i, nk, bq, bk, q_off, k_off,
+                                           window))
+            named = [first + jj for jj in range(band_kb)]
+            computed = [j for j in named if j < nk and att._tile_visible(
+                i, j, bq, bk, q_off, k_off, window)]
+            assert computed == list(np.flatnonzero(table[i])), i
+        for j in range(nk):
+            first = int(att._first_q_block(j, nq, bq, bk, q_off, k_off))
+            named = [first + ii for ii in range(band_qb)]
+            computed = [i for i in named if i < nq and att._tile_visible(
+                i, j, bq, bk, q_off, k_off, window)]
+            assert computed == list(np.flatnonzero(table[:, j])), j
+
+    @pytest.mark.parametrize("Sq, Sk, bq, bk, q_off, k_off, window",
+                             [case for case in TILE_CASES
+                              if case.values[0] <= 256])
+    def test_the_index_maps_name_only_computed_or_resident_blocks(
+            self, Sq, Sk, bq, bk, q_off, k_off, window):
+        """Over a row's ``band_kb`` (a column's ``band_qb``) inner steps
+        the fetched block is the step's own tile where that is computed
+        and the last computed one after, so a step that computes nothing
+        fetches nothing; and an index never goes back."""
+        nq, nk = Sq // bq, Sk // bk
+        args = (bq, bk, q_off, k_off)
+        _, band_kb, band_qb = att._tile_plan(True, nq, nk, *args, window)
+        kv_map = att._kv_index_map(True, nk, *args, window=window, group=7)
+        q_block = att._q_block(True, nq, *args, window=window)
+        visible = np.asarray(att._tile_visible(
+            np.arange(nq)[:, None], np.arange(nk)[None, :], *args, window))
+        for i in range(nq):
+            row = [int(kv_map(15, i, jj)[1]) for jj in range(band_kb)]
+            found = list(np.flatnonzero(visible[i]))
+            if found:  # its tiles, then the last of them again
+                assert row == (found + [found[-1]] * band_kb)[:band_kb], i
+            else:      # one block of the sequence, the same at every step
+                assert len(set(row)) == 1 and 0 <= row[0] < nk, i
             # a block index only ever moves forward: nothing is fetched twice
             assert row == sorted(row)
-            assert {int(kv_map(15, i, j)[0]) for j in range(nk)} == {2}
+            assert {int(kv_map(15, i, jj)[0])
+                    for jj in range(band_kb)} == {2}
         for j in range(nk):
-            column = [int(q_block(j, i)) for i in range(nq)]
-            assert all(visible[block, j] for block in column)
+            column = [int(q_block(j, ii)) for ii in range(band_qb)]
+            found = list(np.flatnonzero(visible[:, j]))
+            if found:
+                assert column == (found + [found[-1]] * band_qb)[:band_qb], j
+            else:
+                assert len(set(column)) == 1 and 0 <= column[0] < nq, j
             assert column == sorted(column)
+
+    def test_without_a_window_the_inner_step_is_the_block_itself(self):
+        """A causal call keeps the whole row and column: the K-side index
+        stops at the diagonal and the Q-side one starts there, as before
+        the band grid."""
+        args = (32, 32, 0, 0)
+        assert att._tile_plan(True, 8, 8, *args) == (36, 8, 8)
+        assert att._tile_plan(False, 8, 4, *args) == (32, 4, 8)
+        kv_map = att._kv_index_map(True, 8, *args)
+        q_block = att._q_block(True, 8, *args)
+        for i in range(8):
+            assert [int(kv_map(3, i, j)[1]) for j in range(8)] == [
+                min(j, i) for j in range(8)]
+            assert [int(q_block(i, j)) for j in range(8)] == [
+                max(j, i) for j in range(8)]
 
     def test_a_group_of_one_keeps_the_slice_index_itself(self):
         assert att._kv_head(5, 1) == 5
         assert [att._kv_head(bh, 7) for bh in (0, 6, 7, 27, 28)] == [
             0, 0, 1, 3, 4]
+
+
+def digest(*arrays):
+    found = hashlib.sha256()
+    for array in arrays:
+        found.update(np.asarray(array).tobytes())
+    return found.hexdigest()
+
+
+# batch, heads, kv heads, Sq, Sk, bq, bk, q_off, k_off, window -> sha256 over
+# (out, lse, dq, dk, dv) as the parent of the band grid (2fde1b5) returned
+# them, its grid whole: one case a kernel path.
+RECORDED = {
+    "group1": (
+        (2, 4, 4, 128, 128, 32, 32, 0, 0, 40),
+        "17b13ba41bdd45c681c5842a8194809a35bf220b738efa7ae479a95d6adb266e"),
+    "group7": (
+        (1, 14, 2, 96, 96, 32, 32, 0, 0, 65),
+        "3549f8c89b3c5f6df1e6a083afbe9a727c117d7e6938d9054ef8aeead092e71a"),
+    "group7-window-under-a-tile": (
+        (1, 14, 2, 64, 64, 16, 16, 0, 0, 5),
+        "294d20016af64ddf3c66198f8daa10f1cad8eb59537c3f3419b027163a0955c3"),
+    "group2-offsets": (
+        (1, 4, 2, 64, 128, 32, 32, 64, 0, 40),
+        "02a5b572e0842818700b312808cb854771bcb166af2bab2741eaca4e52eeabdc"),
+    "group2-a-row-and-a-column-with-no-tile": (
+        (1, 4, 2, 96, 96, 32, 32, 0, 32, 40),
+        "0d37433731c084bc460cab85d8b0081de14fd4a714bfdbcd4d420dc8dcb6e285"),
+    "group1-unequal-tiles": (
+        (1, 4, 4, 128, 128, 16, 64, 0, 0, 50),
+        "e87b7b3dc891e936f268817db4716755520bbdaaa282f96ea7be0799d7dec053"),
+    "group1-nothing-visible": (
+        (1, 2, 2, 64, 64, 32, 32, 500, 0, 100),
+        "948f045d9c451579f1bb9670a3026cba5ee7b1c7db2d8c25c0df33b992778d1a"),
+}
+# Plain XLA arithmetic on like operands, recorded in the same process: where
+# this machine's float32 products and sums are not the recording one's, the
+# kernels' digests say nothing and the wider grid below is the witness.
+CANARY = "8d718dccdf6affc9c8d702b21266a3847cfa72bd006eccd0ebd7217587fae51b"
+# sha256 of jit(grad(flash_attention(...).sum())).lower(q, k, v).as_text() at
+# S=128 in tiles of 32, interpreted, on the same parent: (causal, heads,
+# kv heads). None has a window, so none may change.
+LOWERED = {
+    "causal": (
+        (True, 4, 4),
+        "3574fa59e8374733f81a9bb3c3c06f715326259bf50bb2a9db6b857428344863"),
+    "causal-group7": (
+        (True, 14, 2),
+        "f9eada1fe7bad62172dfb8111a145dc59993682199c72e541a42af4d3719e9f7"),
+    "not-causal": (
+        (False, 4, 4),
+        "ac5d999b7d588e04f4de8b9f6988556e944307eb6cbca7f375fec797ae5b3b89"),
+    "not-causal-group2": (
+        (False, 4, 2),
+        "7201e3f21fadbf534e573ce098a2e916ab27ac748e58f3ceb46f9e9a7e2c11c5"),
+}
+
+
+def windowed(case, flash=flash_attention_lse):
+    """``(out, lse, dq, dk, dv)`` of one windowed call, the logsumexp's
+    cotangent not zero."""
+    batch, heads, kv_heads, sq, sk, bq, bk, q_off, k_off, window = case
+    q, k, v, weight = operands(batch, heads, kv_heads, sq, sk, seed=13)
+    found, vjp = jax.vjp(
+        lambda q, k, v: flash(q, k, v, True, bq, bk, q_off, k_off, True,
+                              window), q, k, v)
+    return tuple(found) + vjp((weight, jnp.cos(weight[..., 0])))
+
+
+@pytest.fixture(scope="module")
+def the_recording_machines_arithmetic():
+    q, k, v, _ = operands(1, 4, 4, 128, 128, seed=13)
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / 4.0
+    if digest(jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(scores, -1), v),
+              jnp.exp(scores).sum(-1)) != CANARY:
+        pytest.skip("another machine's float32 arithmetic than the one the "
+                    "digests were recorded on")
+
+
+class TestTheBandGridChangesNoBit:
+    @pytest.mark.parametrize("name", sorted(RECORDED))
+    def test_windowed_calls_return_what_the_whole_grid_did(
+            self, name, the_recording_machines_arithmetic):
+        case, recorded = RECORDED[name]
+        assert digest(*windowed(case)) == recorded
+
+    @pytest.mark.parametrize("name", sorted(RECORDED))
+    def test_a_grid_wider_than_the_band_returns_the_same_bits(
+            self, name, monkeypatch):
+        """The whole row and column as the innermost extent, the parent's
+        grid: the steps past the band compute nothing, on any machine."""
+        case = RECORDED[name][0]
+        band = windowed(case, att._flash)
+        plan = att._tile_plan
+        monkeypatch.setattr(
+            att, "_tile_plan", lambda causal, num_qb, num_kb, *rest: (
+                plan(causal, num_qb, num_kb, *rest)[0], num_kb, num_qb))
+        for a, b in zip(band, windowed(case, att._flash)):
+            np.testing.assert_array_equal(a, b)
+        assert int(metrics.ATTN_TILES_LAST.labels(kind="grid").get()) == (
+            case[3] // case[5]) * (case[4] // case[6])
+
+    @pytest.mark.parametrize("name", sorted(LOWERED))
+    def test_calls_without_a_window_lower_to_the_parents_text(self, name):
+        (causal, heads, kv_heads), recorded = LOWERED[name]
+        q, k, v, _ = operands(1, heads, kv_heads, 128, 128)
+        text = jax.jit(jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, causal=causal, block_q=32, block_k=32,
+            interpret=True).sum(), (0, 1, 2))).lower(q, k, v).as_text()
+        assert hashlib.sha256(text.encode()).hexdigest() == recorded
 
 
 class TestGaugesAndGuards:
@@ -263,6 +439,24 @@ class TestGaugesAndGuards:
             (("kind", "computed"),)] == 528
         assert self.gauge("hvd_attn_kv_group_last")[()] == 1
 
+    @pytest.mark.parametrize("blocks, window, grid, extents", [
+        pytest.param(32, 4096, 288, (9, 9), id="smallthinker-window-layer"),
+        pytest.param(32, None, 1024, (32, 32), id="smallthinker-full-layer"),
+        pytest.param(8, None, 64, (8, 8), id="olmoe"),
+    ])
+    def test_the_grid_a_slice_runs_over(self, blocks, window, grid, extents):
+        """A windowed call's grid is the band's 9 steps a row; what is
+        still empty of it is ``grid - computed`` (36 of 288), while
+        ``skipped`` stays the tile pairs the mask throws away."""
+        assert att._record_tiles(True, blocks, blocks, 512, 512, 0, 0,
+                                 window, 7) == extents
+        tiles = self.gauge("hvd_attn_tiles_last")
+        assert tiles[(("kind", "grid"),)] == grid
+        assert (tiles[(("kind", "computed"),)]
+                + tiles[(("kind", "skipped"),)]) == blocks * blocks
+        if window:
+            assert grid - tiles[(("kind", "computed"),)] == 36
+
     def test_a_traced_call_sets_both(self):
         q, k, v, _ = operands(1, 14, 2, 64, 64)
         jax.jit(lambda q, k, v: flash_attention(
@@ -271,6 +465,7 @@ class TestGaugesAndGuards:
         tiles = self.gauge("hvd_attn_tiles_last")
         # rows of 16 see 1, 2, 3, 3 tiles of 16 under a window of 20
         assert tiles[(("kind", "computed"),)] == 9
+        assert tiles[(("kind", "grid"),)] == 4 * 3
         assert self.gauge("hvd_attn_kv_group_last")[()] == 7
 
     def test_a_window_needs_the_causal_mask(self):

@@ -184,6 +184,57 @@ def test_olmoes_causal_kernels_compile_at_its_widths(one_chip):
     assert sorted(phases) == ["hvd.attn.bwd", "hvd.attn.bwd", "hvd.attn.fwd"]
 
 
+@pytest.mark.parametrize("window, fwd_grid, dkv_grid", [
+    pytest.param(4096, "28, 32, 9", "4, 32, 7, 9", id="window-layer"),
+    pytest.param(None, "28, 32, 32", "4, 32, 7, 32", id="full-layer"),
+])
+def test_smallthinkers_kernels_compile_on_the_grid_they_should(
+        one_chip, window, fwd_grid, dkv_grid):
+    """One sequence of 16,384 in 28 query heads on 4 key/value heads of
+    128, tiles of 512. Under the window of 4,096 the innermost grid
+    dimension is the band's 9 tiles (PR 33), in the forward, the dq and
+    the dk/dv kernel, each stepping on from its row's (column's) first
+    block and stopping its index at the last; the full causal layer keeps
+    the whole 32. Three kernels either way, under the name the cell's
+    readers find them by, a windowed call's under ``hvd.attn.window``."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu import attribution, metrics, profiler
+    from horovod_tpu.models import smallthinker
+
+    def loss(q, k, v):
+        out = smallthinker.flash_attention_fn(q, k, v, jnp.bfloat16,
+                                              window=window)
+        return out.astype(jnp.float32).sum()
+
+    def shaped(heads):
+        return jax.ShapeDtypeStruct((1, 16384, heads, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        shaped(28), shaped(4), shaped(4))
+    tiles = {kind: int(metrics.ATTN_TILES_LAST.labels(kind=kind).get())
+             for kind in ("computed", "skipped", "grid")}
+    assert tiles == (dict(computed=252, skipped=772, grid=288) if window
+                     else dict(computed=528, skipped=496, grid=1024))
+    bodies = kernel_bodies(lowered.as_text())
+    assert [re.search(r"iteration_bounds = array<i64: ([^>]*)>", body)
+            .group(1) for body in bodies] == [fwd_grid, fwd_grid, dkv_grid]
+    assert all(CLAMP.search(body) for body in bodies)
+    assert all(body.count("scf.if") == 3 for body in bodies)
+    found = kernel_instructions(lowered.compile().as_text())
+    assert len(found) == 3
+    with open(os.path.join(REPO_ROOT, "benchmark", "layer_metrics",
+                           "window_attn_kernel_ms.json")) as f:
+        wanted = re.compile(json.load(f)["kernel_names"])
+    assert all(wanted.search(name) for name, _ in found), found
+    assert sorted(profiler.phase_of(scope) for _, scope in found) == [
+        "hvd.attn.bwd", "hvd.attn.bwd", "hvd.attn.fwd"]
+    assert all((attribution.SCOPE_ATTN_WINDOW in scope) == bool(window)
+               for _, scope in found)
+
+
 def test_a_call_that_is_not_causal_holds_no_clamp_and_no_tile_branch(
         one_chip):
     """The skipping exists only under ``causal``: the multi-tile kernels
