@@ -135,6 +135,30 @@ PHASE_SCOPE_NAMES = tuple(SCOPE_PREFIX + name for name in (
     SCOPE_MOE_ROUTE, SCOPE_MOE_DISPATCH, SCOPE_MOE_EXPERTS,
     SCOPE_MOE_COMBINE, SCOPE_LINATTN_CONV, SCOPE_LINATTN_SCAN,
     SCOPE_LINATTN_GATE))
+#: Block scopes: the parts of a model (``models/*.py``) that no phase
+#: names, forward and backward. A phase inside a block stays the phase's
+#: (``profiler.owner_of``: the innermost phase scope, else the innermost
+#: block scope), so sums by phase read what they read without them.
+#: ``embed``: token / position / type embeddings and their norm. ``norm``:
+#: the norms on the residual stream and the residual adds beside them.
+#: ``attn_proj``: what an attention layer (softmax or linear) does beside
+#: its phases: q/k/v/o and gate projections, QK-norm, RoPE, the transposes
+#: and reshapes around the kernel call. ``ffn``: a dense feed-forward.
+#: ``head``: the final norm, the projection onto the vocabulary (or the
+#: masked positions, or the classes), log-softmax and the loss. ``stem`` /
+#: ``stage``: ResNet's first convolution with its pooling, and its
+#: bottleneck stages. No block scope sits directly around a
+#: ``pallas_call``: XLA would name the trace's event after it.
+SCOPE_BLOCK_EMBED = "block.embed"
+SCOPE_BLOCK_NORM = "block.norm"
+SCOPE_BLOCK_ATTN_PROJ = "block.attn_proj"
+SCOPE_BLOCK_FFN = "block.ffn"
+SCOPE_BLOCK_HEAD = "block.head"
+SCOPE_BLOCK_STEM = "block.stem"
+SCOPE_BLOCK_STAGE = "block.stage"
+BLOCK_SCOPE_NAMES = tuple(SCOPE_PREFIX + name for name in (
+    SCOPE_BLOCK_EMBED, SCOPE_BLOCK_NORM, SCOPE_BLOCK_ATTN_PROJ,
+    SCOPE_BLOCK_FFN, SCOPE_BLOCK_HEAD, SCOPE_BLOCK_STEM, SCOPE_BLOCK_STAGE))
 
 #: Span categories. ``phase``-cat spans are host-observable compute
 #: segments; ``collective``-cat spans are communication; the ``step``

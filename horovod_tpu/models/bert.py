@@ -19,7 +19,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..attribution import (SCOPE_BLOCK_ATTN_PROJ, SCOPE_BLOCK_EMBED,
+                           SCOPE_BLOCK_FFN, SCOPE_BLOCK_HEAD,
+                           SCOPE_BLOCK_NORM)
 from ..ops.attention import blockwise_attention_reference, flash_attention
+from ..profiler import annotate_collective
 
 
 @dataclasses.dataclass(frozen=True)
@@ -103,19 +107,23 @@ class TransformerLayer(nn.Module):
     def __call__(self, x, mask_bias, deterministic: bool):
         cfg = self.config
         # Post-LN (original BERT): sublayer -> residual -> LayerNorm.
-        attn = SelfAttention(cfg, self.attention_fn, name="attention")(
-            x, mask_bias, deterministic
-        )
-        x = nn.LayerNorm(dtype=jnp.float32, name="ln_attn")(x + attn)
-        x = x.astype(cfg.dtype)
-        h = nn.Dense(cfg.intermediate_size, dtype=cfg.dtype,
-                     param_dtype=jnp.float32, name="mlp_in")(x)
-        h = nn.gelu(h)
-        h = nn.Dense(cfg.hidden_size, dtype=cfg.dtype,
-                     param_dtype=jnp.float32, name="mlp_out")(h)
-        h = nn.Dropout(cfg.dropout_rate)(h, deterministic=deterministic)
-        x = nn.LayerNorm(dtype=jnp.float32, name="ln_mlp")(x + h)
-        return x.astype(cfg.dtype)
+        with annotate_collective(SCOPE_BLOCK_ATTN_PROJ):
+            attn = SelfAttention(cfg, self.attention_fn, name="attention")(
+                x, mask_bias, deterministic
+            )
+        with annotate_collective(SCOPE_BLOCK_NORM):
+            x = nn.LayerNorm(dtype=jnp.float32, name="ln_attn")(x + attn)
+            x = x.astype(cfg.dtype)
+        with annotate_collective(SCOPE_BLOCK_FFN):
+            h = nn.Dense(cfg.intermediate_size, dtype=cfg.dtype,
+                         param_dtype=jnp.float32, name="mlp_in")(x)
+            h = nn.gelu(h)
+            h = nn.Dense(cfg.hidden_size, dtype=cfg.dtype,
+                         param_dtype=jnp.float32, name="mlp_out")(h)
+            h = nn.Dropout(cfg.dropout_rate)(h, deterministic=deterministic)
+        with annotate_collective(SCOPE_BLOCK_NORM):
+            x = nn.LayerNorm(dtype=jnp.float32, name="ln_mlp")(x + h)
+            return x.astype(cfg.dtype)
 
 
 class Bert(nn.Module):
@@ -149,18 +157,19 @@ class Bert(nn.Module):
 
         tok_emb = nn.Embed(cfg.vocab_size, cfg.hidden_size,
                            param_dtype=jnp.float32, name="token_embeddings")
-        x = tok_emb(input_ids)
-        x = x + nn.Embed(
-            cfg.max_position_embeddings, cfg.hidden_size,
-            param_dtype=jnp.float32, name="position_embeddings",
-        )(jnp.arange(S)[None, :])
-        x = x + nn.Embed(
-            cfg.type_vocab_size, cfg.hidden_size,
-            param_dtype=jnp.float32, name="type_embeddings",
-        )(token_type_ids)
-        x = nn.LayerNorm(dtype=jnp.float32, name="ln_emb")(x)
-        x = nn.Dropout(cfg.dropout_rate)(x, deterministic=not train)
-        x = x.astype(cfg.dtype)
+        with annotate_collective(SCOPE_BLOCK_EMBED):
+            x = tok_emb(input_ids)
+            x = x + nn.Embed(
+                cfg.max_position_embeddings, cfg.hidden_size,
+                param_dtype=jnp.float32, name="position_embeddings",
+            )(jnp.arange(S)[None, :])
+            x = x + nn.Embed(
+                cfg.type_vocab_size, cfg.hidden_size,
+                param_dtype=jnp.float32, name="type_embeddings",
+            )(token_type_ids)
+            x = nn.LayerNorm(dtype=jnp.float32, name="ln_emb")(x)
+            x = nn.Dropout(cfg.dropout_rate)(x, deterministic=not train)
+            x = x.astype(cfg.dtype)
 
         # Additive mask bias [B, 1, 1, S]: 0 visible, -1e30 padding.
         mask_bias = (1.0 - attention_mask[:, None, None, :].astype(
@@ -180,24 +189,27 @@ class Bert(nn.Module):
         # logits matmul is ~10% of model FLOPs — run it bf16-in/f32-accum
         # on the MXU (a full-f32 matmul runs at 1/4 rate and would be the
         # single biggest line in the profile).
-        head_in = x
-        if masked_positions is not None:
-            head_in = jnp.take_along_axis(
-                x, masked_positions[..., None], axis=1
+        with annotate_collective(SCOPE_BLOCK_HEAD):
+            head_in = x
+            if masked_positions is not None:
+                head_in = jnp.take_along_axis(
+                    x, masked_positions[..., None], axis=1
+                )
+            h = nn.Dense(cfg.hidden_size, dtype=cfg.dtype,
+                         param_dtype=jnp.float32,
+                         name="mlm_transform")(head_in)
+            h = nn.gelu(h)
+            h = nn.LayerNorm(dtype=jnp.float32, name="mlm_ln")(h)
+            logits = jax.lax.dot_general(
+                h.astype(cfg.dtype),
+                tok_emb.embedding.astype(cfg.dtype),
+                (((h.ndim - 1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
             )
-        h = nn.Dense(cfg.hidden_size, dtype=cfg.dtype,
-                     param_dtype=jnp.float32, name="mlm_transform")(head_in)
-        h = nn.gelu(h)
-        h = nn.LayerNorm(dtype=jnp.float32, name="mlm_ln")(h)
-        logits = jax.lax.dot_general(
-            h.astype(cfg.dtype),
-            tok_emb.embedding.astype(cfg.dtype),
-            (((h.ndim - 1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        logits = logits + self.param(
-            "mlm_bias", nn.initializers.zeros, (cfg.vocab_size,), jnp.float32
-        )
+            logits = logits + self.param(
+                "mlm_bias", nn.initializers.zeros, (cfg.vocab_size,),
+                jnp.float32
+            )
         return x, logits
 
 
@@ -205,10 +217,11 @@ def mlm_loss(logits, labels, label_mask):
     """Masked-LM cross entropy: mean over positions where label_mask == 1."""
     import jax
 
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    ll = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-    mask = label_mask.astype(jnp.float32)
-    return -(ll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+    with annotate_collective(SCOPE_BLOCK_HEAD):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        ll = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+        mask = label_mask.astype(jnp.float32)
+        return -(ll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
 
 
 def flash_attention_fn(q, k, v, mask_bias, dtype, interpret: bool = False):

@@ -11,6 +11,9 @@ from __future__ import annotations
 import flax.linen as nn
 import jax.numpy as jnp
 
+from ..attribution import SCOPE_BLOCK_HEAD
+from ..profiler import annotate_collective
+
 
 class LeNet(nn.Module):
     num_classes: int = 10
@@ -34,6 +37,7 @@ class LeNet(nn.Module):
 def cross_entropy_loss(logits, labels, num_classes: int = 10):
     import jax.nn
 
-    one_hot = jnp.eye(num_classes, dtype=logits.dtype)[labels]
-    logp = jax.nn.log_softmax(logits)
-    return -jnp.mean(jnp.sum(one_hot * logp, axis=-1))
+    with annotate_collective(SCOPE_BLOCK_HEAD):
+        one_hot = jnp.eye(num_classes, dtype=logits.dtype)[labels]
+        logp = jax.nn.log_softmax(logits)
+        return -jnp.mean(jnp.sum(one_hot * logp, axis=-1))

@@ -47,7 +47,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..attribution import SCOPE_LINATTN_CONV, SCOPE_LINATTN_GATE
+from ..attribution import (SCOPE_BLOCK_ATTN_PROJ, SCOPE_BLOCK_EMBED,
+                           SCOPE_BLOCK_FFN, SCOPE_BLOCK_HEAD,
+                           SCOPE_BLOCK_NORM, SCOPE_LINATTN_CONV,
+                           SCOPE_LINATTN_GATE)
 from ..ops.linear_attention import gated_delta_rule, short_conv
 from ..profiler import annotate_collective
 from .olmoe import (  # noqa: F401 — the adapters are this model's too
@@ -245,15 +248,20 @@ class HybridLayer(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.config
-        if self.kind == LINEAR:
-            mixed = GatedDeltaNet(cfg, name="linear_attention")(x)
-        else:
-            mixed = FullAttention(cfg, self.attention_fn,
-                                  name="attention")(x)
-        x = x + RMSNorm(cfg.rms_norm_eps, name="ln_mixer")(mixed).astype(
-            cfg.dtype)
-        return x + RMSNorm(cfg.rms_norm_eps, name="ln_mlp")(
-            GatedMLP(cfg, name="mlp")(x)).astype(cfg.dtype)
+        with annotate_collective(SCOPE_BLOCK_ATTN_PROJ):
+            if self.kind == LINEAR:
+                mixed = GatedDeltaNet(cfg, name="linear_attention")(x)
+            else:
+                mixed = FullAttention(cfg, self.attention_fn,
+                                      name="attention")(x)
+        with annotate_collective(SCOPE_BLOCK_NORM):
+            x = x + RMSNorm(cfg.rms_norm_eps, name="ln_mixer")(mixed).astype(
+                cfg.dtype)
+        with annotate_collective(SCOPE_BLOCK_FFN):
+            hidden = GatedMLP(cfg, name="mlp")(x)
+        with annotate_collective(SCOPE_BLOCK_NORM):
+            return x + RMSNorm(cfg.rms_norm_eps, name="ln_mlp")(
+                hidden).astype(cfg.dtype)
 
 
 class OlmoHybrid(nn.Module):
@@ -266,19 +274,21 @@ class OlmoHybrid(nn.Module):
     @nn.compact
     def __call__(self, input_ids):
         cfg = self.config
-        x = nn.Embed(cfg.vocab_size, cfg.hidden_size,
-                     param_dtype=jnp.float32,
-                     name="token_embeddings")(input_ids).astype(cfg.dtype)
+        with annotate_collective(SCOPE_BLOCK_EMBED):
+            x = nn.Embed(cfg.vocab_size, cfg.hidden_size,
+                         param_dtype=jnp.float32,
+                         name="token_embeddings")(input_ids).astype(cfg.dtype)
         for i, kind in enumerate(cfg.kinds):
             x = HybridLayer(cfg, kind, self.attention_fn,
                             name=f"layer_{i}")(x)
-        x = RMSNorm(cfg.rms_norm_eps, name="ln_out")(x).astype(cfg.dtype)
-        # bf16 in, f32 out on the MXU, as models/olmoe.py's head.
-        head = self.param("lm_head", nn.initializers.lecun_normal(),
-                          (cfg.hidden_size, cfg.vocab_size), jnp.float32)
-        return jax.lax.dot_general(
-            x, head.astype(cfg.dtype), (((x.ndim - 1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        with annotate_collective(SCOPE_BLOCK_HEAD):
+            x = RMSNorm(cfg.rms_norm_eps, name="ln_out")(x).astype(cfg.dtype)
+            # bf16 in, f32 out on the MXU, as models/olmoe.py's head.
+            head = self.param("lm_head", nn.initializers.lecun_normal(),
+                              (cfg.hidden_size, cfg.vocab_size), jnp.float32)
+            return jax.lax.dot_general(
+                x, head.astype(cfg.dtype), (((x.ndim - 1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
 
 def causal_lm_loss(model: OlmoHybrid, params, tokens):
@@ -286,8 +296,10 @@ def causal_lm_loss(model: OlmoHybrid, params, tokens):
     ``0..S-1`` are read and ``1..S`` are their labels, as
     ``models.olmoe.causal_lm_loss`` without its auxiliary losses."""
     logits = model.apply({"params": params}, tokens[:, :-1])
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    return -jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1).mean()
+    with annotate_collective(SCOPE_BLOCK_HEAD):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        return -jnp.take_along_axis(
+            logp, tokens[:, 1:, None], axis=-1).mean()
 
 
 def take_head_window(params, whole: OlmoHybridConfig,

@@ -31,7 +31,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..attribution import SCOPE_MOE_ROUTE
+from ..attribution import (SCOPE_BLOCK_ATTN_PROJ, SCOPE_BLOCK_EMBED,
+                           SCOPE_BLOCK_HEAD, SCOPE_BLOCK_NORM,
+                           SCOPE_MOE_ROUTE)
 from ..ops.attention import flash_attention
 from ..parallel import moe
 from ..profiler import annotate_collective
@@ -238,12 +240,18 @@ class DecoderLayer(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.config
-        n1 = RMSNorm(cfg.rms_norm_eps, name="ln_attn")(x).astype(cfg.dtype)
-        x = x + CausalSelfAttention(cfg, self.attention_fn,
-                                    name="attention")(n1)
-        n2 = RMSNorm(cfg.rms_norm_eps, name="ln_moe")(x)
+        with annotate_collective(SCOPE_BLOCK_NORM):
+            n1 = RMSNorm(cfg.rms_norm_eps, name="ln_attn")(x).astype(
+                cfg.dtype)
+        with annotate_collective(SCOPE_BLOCK_ATTN_PROJ):
+            attn = CausalSelfAttention(cfg, self.attention_fn,
+                                       name="attention")(n1)
+        with annotate_collective(SCOPE_BLOCK_NORM):
+            x = x + attn
+            n2 = RMSNorm(cfg.rms_norm_eps, name="ln_moe")(x)
         out, balance, z = SparseExperts(cfg, name="moe")(n2)
-        return x + out, balance, z
+        with annotate_collective(SCOPE_BLOCK_NORM):
+            return x + out, balance, z
 
 
 class Olmoe(nn.Module):
@@ -257,21 +265,23 @@ class Olmoe(nn.Module):
     @nn.compact
     def __call__(self, input_ids):
         cfg = self.config
-        x = nn.Embed(cfg.vocab_size, cfg.hidden_size,
-                     param_dtype=jnp.float32,
-                     name="token_embeddings")(input_ids).astype(cfg.dtype)
+        with annotate_collective(SCOPE_BLOCK_EMBED):
+            x = nn.Embed(cfg.vocab_size, cfg.hidden_size,
+                         param_dtype=jnp.float32,
+                         name="token_embeddings")(input_ids).astype(cfg.dtype)
         balance = z = 0.0
         for i in range(cfg.num_layers):
             x, layer_balance, layer_z = DecoderLayer(
                 cfg, self.attention_fn, name=f"layer_{i}")(x)
             balance, z = balance + layer_balance, z + layer_z
-        x = RMSNorm(cfg.rms_norm_eps, name="ln_out")(x).astype(cfg.dtype)
-        # bf16 in, f32 out on the MXU, as models/bert.py's head.
-        head = self.param("lm_head", nn.initializers.lecun_normal(),
-                          (cfg.hidden_size, cfg.vocab_size), jnp.float32)
-        logits = jax.lax.dot_general(
-            x, head.astype(cfg.dtype), (((x.ndim - 1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        with annotate_collective(SCOPE_BLOCK_HEAD):
+            x = RMSNorm(cfg.rms_norm_eps, name="ln_out")(x).astype(cfg.dtype)
+            # bf16 in, f32 out on the MXU, as models/bert.py's head.
+            head = self.param("lm_head", nn.initializers.lecun_normal(),
+                              (cfg.hidden_size, cfg.vocab_size), jnp.float32)
+            logits = jax.lax.dot_general(
+                x, head.astype(cfg.dtype), (((x.ndim - 1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
         return logits, balance / cfg.num_layers, z / cfg.num_layers
 
 
@@ -281,10 +291,11 @@ def causal_lm_loss(model: Olmoe, params, tokens):
     model computes has one) plus the paper's two auxiliary losses."""
     cfg = model.config
     logits, balance, z = model.apply({"params": params}, tokens[:, :-1])
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    picked = jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)
-    return (-picked.mean() + cfg.load_balance_coef * balance
-            + cfg.router_z_coef * z)
+    with annotate_collective(SCOPE_BLOCK_HEAD):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        picked = jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)
+        return (-picked.mean() + cfg.load_balance_coef * balance
+                + cfg.router_z_coef * z)
 
 
 def routing_stats(model: Olmoe, params, input_ids):

@@ -16,6 +16,10 @@ from typing import Any, Callable, Sequence
 import flax.linen as nn
 import jax.numpy as jnp
 
+from ..attribution import (SCOPE_BLOCK_HEAD, SCOPE_BLOCK_STAGE,
+                           SCOPE_BLOCK_STEM)
+from ..profiler import annotate_collective
+
 ModuleDef = Any
 
 
@@ -64,25 +68,28 @@ class ResNet(nn.Module):
             epsilon=1e-5,
             dtype=self.dtype,
         )
-        x = x.astype(self.dtype)
-        x = conv(
-            self.num_filters, (7, 7), strides=(2, 2), use_bias=False,
-            padding=[(3, 3), (3, 3)],
-        )(x)
-        x = norm()(x)
-        x = nn.relu(x)
-        x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
-        for i, block_count in enumerate(self.stage_sizes):
-            for j in range(block_count):
-                strides = (2, 2) if i > 0 and j == 0 else (1, 1)
-                x = Bottleneck(
-                    self.num_filters * 2**i,
-                    strides=strides,
-                    conv=conv,
-                    norm=norm,
-                )(x)
-        x = jnp.mean(x, axis=(1, 2))
-        x = nn.Dense(self.num_classes, dtype=jnp.float32)(x)
+        with annotate_collective(SCOPE_BLOCK_STEM):
+            x = x.astype(self.dtype)
+            x = conv(
+                self.num_filters, (7, 7), strides=(2, 2), use_bias=False,
+                padding=[(3, 3), (3, 3)],
+            )(x)
+            x = norm()(x)
+            x = nn.relu(x)
+            x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
+        with annotate_collective(SCOPE_BLOCK_STAGE):
+            for i, block_count in enumerate(self.stage_sizes):
+                for j in range(block_count):
+                    strides = (2, 2) if i > 0 and j == 0 else (1, 1)
+                    x = Bottleneck(
+                        self.num_filters * 2**i,
+                        strides=strides,
+                        conv=conv,
+                        norm=norm,
+                    )(x)
+        with annotate_collective(SCOPE_BLOCK_HEAD):
+            x = jnp.mean(x, axis=(1, 2))
+            x = nn.Dense(self.num_classes, dtype=jnp.float32)(x)
         return x
 
 

@@ -52,7 +52,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..attribution import SCOPE_MOE_ROUTE
+from ..attribution import (SCOPE_BLOCK_ATTN_PROJ, SCOPE_BLOCK_EMBED,
+                           SCOPE_BLOCK_HEAD, SCOPE_BLOCK_NORM,
+                           SCOPE_MOE_ROUTE)
 from ..ops.attention import flash_attention
 from ..parallel import moe
 from ..profiler import annotate_collective
@@ -244,7 +246,8 @@ class DecoderLayer(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.config
-        n1 = RMSNorm(cfg.rms_norm_eps, name="ln_attn")(x)
+        with annotate_collective(SCOPE_BLOCK_NORM):
+            n1 = RMSNorm(cfg.rms_norm_eps, name="ln_attn")(x)
         router = self.param("router", nn.initializers.lecun_normal(),
                             (cfg.hidden_size, cfg.num_experts), jnp.float32)
         # The router reads the PRE-attention normalised input, in float32
@@ -253,11 +256,16 @@ class DecoderLayer(nn.Module):
         with annotate_collective(SCOPE_MOE_ROUTE):
             logits = jnp.matmul(n1, router,
                                 precision=jax.lax.Precision.HIGHEST)
-        x = x + GroupedAttention(
-            cfg, self.windowed, self.rotary, self.attention_fn,
-            name="attention")(n1.astype(cfg.dtype))
-        n2 = RMSNorm(cfg.rms_norm_eps, name="ln_moe")(x)
-        return x + SparseReGLU(cfg, name="moe")(n2, logits)
+        with annotate_collective(SCOPE_BLOCK_ATTN_PROJ):
+            attn = GroupedAttention(
+                cfg, self.windowed, self.rotary, self.attention_fn,
+                name="attention")(n1.astype(cfg.dtype))
+        with annotate_collective(SCOPE_BLOCK_NORM):
+            x = x + attn
+            n2 = RMSNorm(cfg.rms_norm_eps, name="ln_moe")(x)
+        out = SparseReGLU(cfg, name="moe")(n2, logits)
+        with annotate_collective(SCOPE_BLOCK_NORM):
+            return x + out
 
 
 def save_kernels_and_projections(prim, *args, **params) -> bool:
@@ -289,20 +297,22 @@ class SmallThinker(nn.Module):
         if cfg.remat:
             layer = nn.remat(DecoderLayer,
                              policy=save_kernels_and_projections)
-        x = nn.Embed(cfg.vocab_size, cfg.hidden_size,
-                     param_dtype=jnp.float32,
-                     name="token_embeddings")(input_ids).astype(cfg.dtype)
+        with annotate_collective(SCOPE_BLOCK_EMBED):
+            x = nn.Embed(cfg.vocab_size, cfg.hidden_size,
+                         param_dtype=jnp.float32,
+                         name="token_embeddings")(input_ids).astype(cfg.dtype)
         for i, (windowed, rotary) in enumerate(zip(cfg.windowed,
                                                    cfg.rotary)):
             x = layer(cfg, windowed, rotary, self.attention_fn,
                       name=f"layer_{i}")(x)
-        x = RMSNorm(cfg.rms_norm_eps, name="ln_out")(x).astype(cfg.dtype)
-        # bf16 in, f32 out on the MXU, as models/bert.py's head.
-        head = self.param("lm_head", nn.initializers.lecun_normal(),
-                          (cfg.hidden_size, cfg.vocab_size), jnp.float32)
-        return jax.lax.dot_general(
-            x, head.astype(cfg.dtype), (((x.ndim - 1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        with annotate_collective(SCOPE_BLOCK_HEAD):
+            x = RMSNorm(cfg.rms_norm_eps, name="ln_out")(x).astype(cfg.dtype)
+            # bf16 in, f32 out on the MXU, as models/bert.py's head.
+            head = self.param("lm_head", nn.initializers.lecun_normal(),
+                              (cfg.hidden_size, cfg.vocab_size), jnp.float32)
+            return jax.lax.dot_general(
+                x, head.astype(cfg.dtype), (((x.ndim - 1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
 
 def causal_lm_loss(model: SmallThinker, params, tokens):
@@ -310,8 +320,10 @@ def causal_lm_loss(model: SmallThinker, params, tokens):
     ``0..S-1`` are read and ``1..S`` are their labels. The source's config
     has no auxiliary-loss coefficient, so there is none."""
     logits = model.apply({"params": params}, tokens[:, :-1])
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    return -jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1).mean()
+    with annotate_collective(SCOPE_BLOCK_HEAD):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        return -jnp.take_along_axis(
+            logp, tokens[:, 1:, None], axis=-1).mean()
 
 
 def take_expert_window(params, share: SmallThinkerConfig):

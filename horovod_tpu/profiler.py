@@ -18,6 +18,12 @@ activities against GPU kernels. Here the same role is played by
   legs — so each is identifiable against the TPU op stream in the
   captured trace; :func:`instruction_scopes` reads them back from a
   compiled step's text, instruction by instruction.
+- The models open block scopes the same way (``hvd.block.*``,
+  ``attribution.BLOCK_SCOPE_NAMES``). :func:`owner_of` names the scope a
+  name stack's time belongs to (the innermost phase, else the innermost
+  block), and :func:`instruction_owners` reads an owner for every
+  instruction of a compiled step's text, looking inside fusions and loops
+  whose own instruction carries no name stack.
 - :func:`compile_account` is JAX's own account of tracing, lowering and
   compiling (``jax.monitoring``), heard by the program once per process
   and served as ``hvd.cache_stats()["compile"]``.
@@ -25,6 +31,7 @@ activities against GPU kernels. Here the same role is played by
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import re
 import threading
@@ -233,6 +240,186 @@ def phase_of(scope: str) -> str | None:
         if part in PHASE_SCOPE_NAMES:
             return part
     return None
+
+
+def owner_of(scope: str) -> str | None:
+    """The scope a name stack's device time belongs to: its innermost
+    phase scope (:func:`phase_of`) if it has one, else its innermost block
+    scope (``hvd.block.*``), else None. A block opened inside a phase
+    stays the phase's, and a phase inside a block (the flash kernels under
+    ``hvd.block.attn_proj``) keeps what it had before the blocks were
+    named. Transformations' wrappers are read through as ``phase_of``
+    reads them."""
+    from .attribution import BLOCK_SCOPE_NAMES, PHASE_SCOPE_NAMES
+
+    parts = [part.rsplit("(", 1)[-1].rstrip(")")
+             for part in reversed(scope.split("/"))]
+    for names in (PHASE_SCOPE_NAMES, BLOCK_SCOPE_NAMES):
+        for part in parts:
+            if part in names:
+                return part
+    return None
+
+
+#: What :func:`booked_to` books an instruction to when no one scope owns
+#: it: several owners inside it and none of its own, or none at all.
+OWNER_SHARED = "shared"
+OWNER_UNOWNED = "unowned"
+
+
+@dataclasses.dataclass(frozen=True)
+class Owner:
+    """What a compiled step's text says of who owns one instruction."""
+
+    own: str | None  # owner_of its own op_name
+    inside: frozenset  # owners of what the computations it calls compute
+    neighbours: tuple  # (of its first operand's producer, of its first user)
+    opcode: str
+    shape: str
+
+
+def booked_to(owner: Owner) -> str:
+    """The one booking rule: an instruction's time goes to ``own``; where
+    that is None, to the only member of ``inside``; where ``inside`` has
+    several, to :data:`OWNER_SHARED`; else to :data:`OWNER_UNOWNED`."""
+    if owner.own:
+        return owner.own
+    if len(owner.inside) == 1:
+        return next(iter(owner.inside))
+    return OWNER_SHARED if owner.inside else OWNER_UNOWNED
+
+
+#: ``[ROOT ]%name = shape opcode(operands), attributes``: the opcode is
+#: the first lower-case word before a parenthesis (a layout's ``T(8,128)``
+#: and ``S(1)`` are upper-case).
+_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT )?%?([\w.-]+) = (.*?) ?\b([a-z][a-z0-9-]*)\((.*)$")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.-]+) \(.*\{$")
+_OP_NAME = re.compile(r'metadata=\{op_name="([^"]*)"')
+_CALLED = re.compile(
+    r"\b(?:calls|body|condition|to_apply|true_computation|"
+    r"false_computation)=%?([\w.-]+)|branch_computations=\{([^}]*)\}")
+#: Instructions whose called computations are read for ``inside``.
+_CALLERS = ("fusion", "while", "call", "conditional")
+#: Instructions that compute nothing: no owner inside a computation.
+_NOT_COMPUTING = ("parameter", "constant", "tuple", "get-tuple-element",
+                  "bitcast")
+_NEIGHBOUR_REACH = 8  # instructions without an owner looked through
+
+
+def _operands(rest: str) -> list[str]:
+    """The operands' names in ``%a, /*index=1*/%b), attributes``: what
+    follows an instruction's opening parenthesis, up to the one that
+    closes it. A literal (``constant(0)``) is returned as it stands and
+    names no instruction."""
+    depth, start, found = 0, 0, []
+    for i, c in enumerate(rest):
+        if c in "([{":
+            depth += 1
+        elif c in ")]}" and depth:
+            depth -= 1
+        elif c in ")," and not depth:
+            words = rest[start:i].split()
+            if words:
+                found.append(words[-1].rsplit("*/", 1)[-1].lstrip("%"))
+            if c == ")":
+                break
+            start = i + 1
+    return found
+
+
+def instruction_owners(hlo_text: str) -> dict[str, Owner]:
+    """Instruction name → :class:`Owner`, for every instruction of a
+    compiled program's text, the ones inside fused computations and loop
+    bodies too. :func:`instruction_scopes` gives a fusion its root's name
+    stack and nothing where the root has none; here a ``fusion``,
+    ``while``, ``call`` or ``conditional`` also holds ``inside``, the
+    owners of the instructions that compute in the computations it calls
+    (all the way down), and ``neighbours``, the booking of the nearest
+    instruction that has one up the chain of first operands and down the
+    chain of first users (at most eight instructions away; a computation's
+    parameters end the chain). Book its time with :func:`booked_to`. A
+    fusion whose ``inside`` has several members is a *shared fusion*,
+    whoever it is booked to. Raises where the text holds no block scope:
+    no step of one of this package's models, or, as with
+    :func:`instruction_scopes`, an executable out of a persistent cache
+    that a tree from before the block scopes wrote."""
+    from .attribution import BLOCK_SCOPE_NAMES
+
+    parsed = {}  # name -> (opcode, shape, own, first operand, called)
+    members = {}  # computation -> [instruction names]
+    users = {}  # name -> its first user
+    computation = None
+    for line in hlo_text.splitlines():
+        found = _INSTRUCTION.match(line)
+        if found is None:
+            header = _COMPUTATION.match(line)
+            if header:
+                computation = members.setdefault(header.group(1), [])
+            continue
+        if computation is None:
+            continue
+        name, shape, opcode, rest = found.groups()
+        scope = _OP_NAME.search(rest)
+        called = []
+        if opcode in _CALLERS:
+            for one, several in _CALLED.findall(rest):
+                called.extend(
+                    [one] if one else
+                    [c.strip().lstrip("%") for c in several.split(",")])
+        operands = _operands(rest)
+        parsed[name] = (opcode, shape,
+                        owner_of(scope.group(1)) if scope else None,
+                        operands[0] if operands else None, called)
+        computation.append(name)
+        for operand in operands:
+            users.setdefault(operand, name)
+    if not any(own in BLOCK_SCOPE_NAMES for _, _, own, _, _ in
+               parsed.values()):
+        raise ValueError(
+            f"no block scope ({', '.join(BLOCK_SCOPE_NAMES)}) in the "
+            f"program's text ({len(parsed)} instructions): not a step of "
+            "one of this package's models (a model of your own opens them "
+            "with profiler.annotate_collective), or an executable loaded "
+            "from a persistent compilation cache that a tree from before "
+            "the block scopes wrote (the cache's key leaves metadata out): "
+            "compile it afresh, in a cache directory of its own or with "
+            "jax_compilation_cache_include_metadata_in_key set")
+
+    held = {}  # computation -> owners of what it computes, all the way down
+
+    def holds(name: str) -> frozenset:
+        if name not in held:
+            held[name] = frozenset()  # a computation that calls itself
+            found = set()
+            for member in members.get(name, ()):
+                opcode, _, own, _, called = parsed[member]
+                if own and opcode not in _NOT_COMPUTING:
+                    found.add(own)
+                for below in called:
+                    found |= holds(below)
+            held[name] = frozenset(found)
+        return held[name]
+
+    owners = {name: Owner(own, frozenset().union(*map(holds, called)),
+                          (None, None), opcode, shape)
+              for name, (opcode, shape, own, _, called) in parsed.items()}
+    booked = {name: booked_to(owner) for name, owner in owners.items()
+              if owner.own or owner.inside}
+
+    def nearest(name, step) -> str | None:
+        """The booking of the first instruction with one along ``step``."""
+        for _ in range(_NEIGHBOUR_REACH):
+            name = step(name)
+            if name not in parsed:
+                return None
+            if name in booked:
+                return booked[name]
+        return None
+
+    return {name: dataclasses.replace(owner, neighbours=(
+        nearest(name, lambda n: parsed[n][3]), nearest(name, users.get)))
+        for name, owner in owners.items()}
 
 
 _factory_steps: "weakref.WeakSet" = weakref.WeakSet()
