@@ -909,10 +909,19 @@ ATTN_TILES_LAST = gauge(
     ("kind",))
 ATTN_GROUP_LAST = gauge(
     "hvd_attn_group_last",
-    "(batch x head) slices a grid step of the LAST traced single-tile "
-    "flash-attention call takes, by kernel (fwd: the direct-softmax "
-    "forward; bwd: the fused backward): set at trace time, as "
-    "hvd_attn_tiles_last is.",
+    "Slices a grid step of the LAST traced single-tile flash-attention "
+    "call takes, by kernel (fwd: the direct-softmax forward; bwd: the "
+    "fused backward). A slice is a (batch x head) pair of a head-major "
+    "call and a batch row's block of hvd_attn_heads_per_block_last heads "
+    "of a tokens-major one: set at trace time, as hvd_attn_tiles_last is.",
+    ("kernel",))
+ATTN_HEADS_PER_BLOCK_LAST = gauge(
+    "hvd_attn_heads_per_block_last",
+    "Heads whose lanes one block of the LAST traced single-tile "
+    "flash-attention call holds, by kernel: 1 for a head-major call "
+    "([BH, S, D] blocks of D lanes), for a tokens-major one "
+    "([B, S, H * D]) the fewest that fill whole 128-lane tiles (2 at "
+    "D = 64): set at trace time, beside hvd_attn_group_last.",
     ("kernel",))
 ATTN_KV_GROUP_LAST = gauge(
     "hvd_attn_kv_group_last",

@@ -12,7 +12,8 @@ sequence-parallel schemes in ``horovod_tpu.parallel.sequence`` (pass
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
+import functools
+import math
 from typing import Any, Callable
 
 import flax.linen as nn
@@ -22,7 +23,7 @@ import jax.numpy as jnp
 from ..attribution import (SCOPE_BLOCK_ATTN_PROJ, SCOPE_BLOCK_EMBED,
                            SCOPE_BLOCK_FFN, SCOPE_BLOCK_HEAD,
                            SCOPE_BLOCK_NORM)
-from ..ops.attention import blockwise_attention_reference, flash_attention
+from ..ops.attention import flash_attention_tokens_major
 from ..profiler import annotate_collective
 
 
@@ -73,6 +74,54 @@ def default_attention(q, k, v, mask_bias, dtype):
     return out.transpose(0, 2, 1, 3).astype(dtype)
 
 
+class HeadsDense(nn.Module):
+    """A dense layer over attention heads that keeps them in the lanes.
+
+    Parameters, paths, shapes and initial values are
+    ``nn.DenseGeneral``'s, heads apart: ``kernel_shape`` is ``[E, H, D]``
+    for a projection into heads (``contract=1``: the kernel's first
+    dimension is summed over) and ``[H, D, E]`` for the one out of them
+    (``contract=2``); the bias has the kernel's other dimensions. The
+    product is made with the kernel merged to two dimensions (a copy of
+    a parameter's size at most), so the activation on the heads' side is
+    ``[B, S, H * D]``, tokens major, written and read as one array of
+    dense 128-lane tiles. ``"bse,ehd->bshd"`` computes the same numbers,
+    but the compiler lays its ``[B, S, H, D]`` out with the heads outside
+    the tokens and copies every q, k, v and output on the way to a kernel
+    that takes ``[B, S, H * D]`` (PERF.md, PR 35).
+    """
+
+    kernel_shape: tuple
+    contract: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        merged = (math.prod(self.kernel_shape[:self.contract]),
+                  math.prod(self.kernel_shape[self.contract:]))
+        kernel = self.param(
+            "kernel",
+            # as nn.DenseGeneral draws it: over the merged shape
+            lambda key, shape, dtype: nn.linear.default_kernel_init(
+                key, merged, dtype).reshape(shape),
+            self.kernel_shape, jnp.float32)
+        bias = self.param("bias", nn.initializers.zeros,
+                          self.kernel_shape[self.contract:], jnp.float32)
+        x, kernel, bias = nn.dtypes.promote_dtype(x, kernel, bias,
+                                                  dtype=self.dtype)
+        return x @ kernel.reshape(merged) + bias.reshape(merged[1])
+
+
+def takes_tokens_major(attention_fn) -> bool:
+    """Whether an ``attention_fn`` says of itself (``tokens_major``, seen
+    through ``functools.partial``) that it takes q, k, v as the
+    projections write them, ``[B, S, H * D]`` with ``num_heads=``, and
+    returns the same; any other is handed ``[B, S, H, D]``."""
+    while isinstance(attention_fn, functools.partial):
+        attention_fn = attention_fn.func
+    return getattr(attention_fn, "tokens_major", False)
+
+
 class SelfAttention(nn.Module):
     config: BertConfig
     attention_fn: Callable | None = None
@@ -80,21 +129,23 @@ class SelfAttention(nn.Module):
     @nn.compact
     def __call__(self, x, mask_bias, deterministic: bool):
         cfg = self.config
-        dense = partial(
-            nn.DenseGeneral, dtype=cfg.dtype, param_dtype=jnp.float32
-        )
-        qkv_shape = (cfg.num_heads, cfg.head_dim)
-        q = dense(features=qkv_shape, name="query")(x)
-        k = dense(features=qkv_shape, name="key")(x)
-        v = dense(features=qkv_shape, name="value")(x)
-        if self.attention_fn is not None:
-            out = self.attention_fn(q, k, v, mask_bias, cfg.dtype)
+        into_heads = functools.partial(
+            HeadsDense, (cfg.hidden_size, cfg.num_heads, cfg.head_dim), 1,
+            cfg.dtype)
+        q = into_heads(name="query")(x)  # [B, S, H * D]
+        k = into_heads(name="key")(x)
+        v = into_heads(name="value")(x)
+        if takes_tokens_major(self.attention_fn):
+            out = self.attention_fn(q, k, v, mask_bias, cfg.dtype,
+                                    num_heads=cfg.num_heads)
         else:
-            out = default_attention(q, k, v, mask_bias, cfg.dtype)
-        out = nn.DenseGeneral(
-            features=cfg.hidden_size, axis=(-2, -1), dtype=cfg.dtype,
-            param_dtype=jnp.float32, name="out",
-        )(out)
+            attend = self.attention_fn or default_attention
+            apart = x.shape[:2] + (cfg.num_heads, cfg.head_dim)
+            out = attend(q.reshape(apart), k.reshape(apart),
+                         v.reshape(apart), mask_bias, cfg.dtype)
+            out = out.reshape(q.shape)
+        out = HeadsDense((cfg.num_heads, cfg.head_dim, cfg.hidden_size), 2,
+                         cfg.dtype, name="out")(out)
         out = nn.Dropout(cfg.dropout_rate)(out, deterministic=deterministic)
         return out
 
@@ -224,12 +275,16 @@ def mlm_loss(logits, labels, label_mask):
         return -(ll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
 
 
-def flash_attention_fn(q, k, v, mask_bias, dtype, interpret: bool = False):
-    """Adapter plugging the Pallas flash kernel into ``Bert`` for unpadded
-    batches (mask_bias all-zero): [B, S, H, D] -> transpose -> kernel."""
+def flash_attention_fn(q, k, v, mask_bias, dtype, interpret: bool = False,
+                       *, num_heads: int):
+    """Adapter plugging the Pallas flash kernels into ``Bert`` for unpadded
+    batches (mask_bias all-zero). It takes q, k, v where the projections
+    wrote them, ``[B, S, H * D]`` with ``num_heads=H``, and returns the
+    context the same way (``tokens_major``, which ``SelfAttention`` reads):
+    the kernels' block maps split the heads and nothing is transposed."""
     del mask_bias  # full-visibility batches only; padded path uses default
-    out = flash_attention(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3), causal=False, interpret=interpret,
-    )
-    return out.transpose(0, 2, 1, 3).astype(dtype)
+    return flash_attention_tokens_major(
+        q, k, v, num_heads, causal=False, interpret=interpret).astype(dtype)
+
+
+flash_attention_fn.tokens_major = True

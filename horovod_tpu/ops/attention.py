@@ -18,7 +18,14 @@ Two implementations, one semantics:
   the units idle. ``_group_size`` picks G from the call's shapes (the
   largest divisor of batch*heads whose blocks and scores fit
   ``GROUP_BUDGET_BYTES`` of VMEM); the gauge
-  ``hvd_attn_group_last{kernel}`` says which. Longer ones run
+  ``hvd_attn_group_last{kernel}`` says which. The same two kernels
+  take operands where a projection wrote them, tokens major
+  (``flash_attention_tokens_major``: ``[B, S, H * D]``): a block is then
+  the lanes of whole heads, two at D = 64, of a group of batch rows, the
+  grid (B // G, lane blocks), and each head is computed out of its lanes
+  of the block (``_head_lanes``); ``hvd_attn_heads_per_block_last`` says
+  how many. Nothing is transposed in HBM on the way in or out. Longer
+  sequences run
   (batch*heads, Q blocks, K blocks) with K innermost in the forward and
   the dq kernel and (batch*heads, K blocks, Q blocks) with Q innermost in
   the dk/dv kernel, one K/V (or Q/dO) tile resident a step. Differentiable:
@@ -181,6 +188,8 @@ def _auto_block(seq_len: int) -> int:
     return seq_len if seq_len < 128 else 128
 
 
+LANES = 128  # of a vector register, and of a tile of any array in HBM
+
 # What a grid step of a single-tile kernel may hold in VMEM, as
 # ``_group_footprint`` counts it: three quarters of the 16 MiB a kernel
 # gets by default, the rest left for what the count does not see.
@@ -194,6 +203,9 @@ _FWD_SLICE = dict(q_blocks=2, k_blocks=2, rows=1, temporaries=2)
 # Fused backward: q, dO, dq / k, v, dk, dv / lse, delta, g_lse; the
 # probabilities, dP and dS beside their casts to the stored dtype.
 _BWD_SLICE = dict(q_blocks=3, k_blocks=4, rows=3, temporaries=4)
+# The same from the forward's output (``_flash_dqkv_from_out_kernel``): q,
+# dO, O, dq / k, v, dk, dv / lse.
+_BWD_FROM_OUT_SLICE = dict(q_blocks=4, k_blocks=4, rows=1, temporaries=4)
 
 
 def _vmem_bytes(rows: int, cols: int, itemsize: int) -> int:
@@ -205,33 +217,56 @@ def _vmem_bytes(rows: int, cols: int, itemsize: int) -> int:
 
 
 def _group_footprint(group, block_q, block_k, d, itemsize, q_blocks,
-                     k_blocks, rows, temporaries) -> int:
-    """VMEM bytes of one grid step that takes ``group`` slices: per slice
+                     k_blocks, rows, temporaries, heads=1) -> int:
+    """VMEM bytes of one grid step that takes ``group`` slices of ``heads``
+    heads each, ``d`` the lanes of a slice (all its heads'): per slice
     ``q_blocks`` [block_q, d] and ``k_blocks`` [block_k, d] blocks of the
-    stored dtype and ``rows`` float32 [1, block_q] rows, all held twice
+    stored dtype and ``rows`` float32 [heads, block_q] rows, all held twice
     (the pipeline fetches the next step's while this one computes), and
-    ``temporaries`` float32 [block_q, block_k] arrays, which the batched
-    computation keeps for every slice of the group at once."""
+    ``temporaries`` float32 [block_q, block_k] arrays a head, which the
+    batched computation keeps for every slice of the group at once (and,
+    counted here, for every head of a slice: their chains are independent
+    too)."""
     blocks = (q_blocks * _vmem_bytes(block_q, d, itemsize)
               + k_blocks * _vmem_bytes(block_k, d, itemsize)
-              + rows * _vmem_bytes(1, block_q, 4))
-    return group * (2 * blocks
-                    + temporaries * _vmem_bytes(block_q, block_k, 4))
+              + rows * _vmem_bytes(heads, block_q, 4))
+    return group * (2 * blocks + heads * temporaries
+                    * _vmem_bytes(block_q, block_k, 4))
 
 
-def _group_size(bh, block_q, block_k, d, itemsize, **slice_counts) -> int:
-    """How many (batch x head) slices a grid step of a single-tile kernel
-    takes: the largest divisor of ``bh`` whose ``_group_footprint`` fits
-    ``GROUP_BUDGET_BYTES``, and 1 where none does. What a 128 x 64 slice
-    costs alone is its own chain of product, softmax and product, each
-    waiting for the last; the slices of a group are independent chains in
-    one block of code, which the scheduler interleaves (0.76 -> 0.25 ms a
-    forward call at BERT-Large's S=128, in groups of 24). At S=512 a
-    slice's scores are 1 MB a temporary and the group is 3 or 2."""
+def _group_size(slices, block_q, block_k, d, itemsize,
+                **slice_counts) -> int:
+    """How many slices a grid step of a single-tile kernel takes: the
+    largest divisor of ``slices`` whose ``_group_footprint`` fits
+    ``GROUP_BUDGET_BYTES``, and 1 where none does. A slice is a (batch x
+    head) pair of a head-major call and a batch row's block of whole
+    heads' lanes (``heads=`` of them) of a tokens-major one. What a
+    128 x 64 slice costs alone is its own chain of product, softmax and
+    product, each waiting for the last; the slices of a group are
+    independent chains in one block of code, which the scheduler
+    interleaves (0.76 -> 0.25 ms a forward call at BERT-Large's S=128, in
+    groups of 24). At S=512 a head's scores are 1 MB a temporary and the
+    group is 3 or 2 heads, or one or two pairs."""
     fits = GROUP_BUDGET_BYTES // _group_footprint(
         1, block_q, block_k, d, itemsize, **slice_counts)
-    return max((group for group in range(1, min(bh, fits) + 1)
-                if bh % group == 0), default=1)
+    return max((group for group in range(1, min(slices, fits) + 1)
+                if slices % group == 0), default=1)
+
+
+def _heads_per_block(d: int, heads: int) -> int | None:
+    """How many heads a block of a tokens-major single-tile call takes:
+    the fewest whose lanes fill whole 128-lane tiles, two at D = 64 and
+    one at D = 128. ``None`` where heads of ``d`` lanes cannot: a width
+    that neither divides 128 nor is its multiple, or a count of heads
+    that leaves a block short (such a call transposes instead). More than
+    128 lanes of several heads are not taken: the kernels contract over a
+    block's every lane, which is one pass of a 128 x 128 array and no
+    more only up to there."""
+    if d % LANES == 0:
+        return 1
+    if LANES % d == 0 and heads % (LANES // d) == 0:
+        return LANES // d
+    return None
 
 
 def _group_index(ref):
@@ -253,21 +288,70 @@ def _dot(a, b, a_dim, b_dim):
         preferred_element_type=jnp.float32)
 
 
-def _group_specs(kernel, qr, block_q, block_k, slice_counts):
-    """The group a single-tile call's grid steps take and the block specs
-    of its operands: ``(G, [G, block_q, D], [G, block_k, D], float32
-    [G, 1, block_q])``. Sets ``hvd_attn_group_last{kernel}`` at trace
-    time, as ``_record_tiles`` does its gauge."""
+def _head_lanes(x, head, heads):
+    """``x`` ([..., rows, lanes of ``heads`` heads]) with every lane but
+    those of head ``head`` zeroed: a product that contracts over all the
+    lanes then contracts over that head's alone, and one whose other
+    operand it is comes out zero outside them. A block of one head is
+    itself."""
+    if heads == 1:
+        return x
+    return _put_head_lanes(jnp.zeros_like(x), x, head, heads)
+
+
+def _put_head_lanes(into, x, head, heads):
+    """``into`` with the lanes of head ``head`` taken from ``x``; ``x``
+    itself for the first head (``into`` is ``None``) and where a block is
+    one head. A select a lane: no lane moves."""
+    if heads == 1 or into is None:
+        return x
+    d = x.shape[-1] // heads
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    return jnp.where((lane >= head * d) & (lane < (head + 1) * d), x, into)
+
+
+def _group_specs(kernel, qr, block_q, block_k, slice_counts, heads=None):
+    """The grid of a single-tile call, the heads a block of it holds, the
+    block specs of its operands and the shape of its float32 rows (the
+    log-sum-exp and its like): ``(grid, heads a block, q spec, k/v spec,
+    row spec, rows' shape)``.
+
+    Head-major operands ``[BH, S, D]`` (``heads`` is ``None``): a slice is
+    a head, a step takes ``G`` of them, blocks ``[G, S, D]``, rows
+    ``[BH, 1, S]`` in blocks ``[G, 1, S]``, grid ``(BH // G,)``.
+    Tokens-major operands ``[B, S, H * D]``: a slice is a batch row's
+    block of the lanes of ``_heads_per_block`` whole heads, a step takes
+    the same lanes of ``G`` rows, blocks ``[G, S, heads a block * D]``,
+    rows ``[B, H // heads a block, heads a block, S]`` in blocks ``[G,
+    heads a block, S]``, grid ``(B // G, H // heads a block)``: the block
+    map does the head split. Sets ``hvd_attn_group_last{kernel}`` and
+    ``hvd_attn_heads_per_block_last{kernel}`` at trace time, as
+    ``_record_tiles`` does its gauges."""
     from .. import metrics
 
-    bh, _, d = qr.shape
-    group = _group_size(bh, block_q, block_k, d, qr.dtype.itemsize,
-                        **slice_counts)
+    slices, _, width = qr.shape
+    per_block = 1 if heads is None else _heads_per_block(
+        width // heads, heads)
+    lanes = width if heads is None else per_block * (width // heads)
+    group = _group_size(slices, block_q, block_k, lanes, qr.dtype.itemsize,
+                        heads=per_block, **slice_counts)
     metrics.ATTN_GROUP_LAST.set(group, kernel=kernel)
-    return (group,
-            pl.BlockSpec((group, block_q, d), lambda i: (i, 0, 0)),
-            pl.BlockSpec((group, block_k, d), lambda i: (i, 0, 0)),
-            pl.BlockSpec((group, 1, block_q), lambda i: (i, 0, 0)))
+    metrics.ATTN_HEADS_PER_BLOCK_LAST.set(per_block, kernel=kernel)
+    if heads is None:
+        grid, block_at = (slices // group,), lambda i: (i, 0, 0)
+        row_spec = pl.BlockSpec((group, 1, block_q), block_at)
+        rows = (slices, 1, block_q)
+    else:
+        lane_blocks = heads // per_block
+        grid, block_at = (slices // group, lane_blocks), \
+            lambda i, j: (i, 0, j)
+        row_spec = pl.BlockSpec((group, None, per_block, block_q),
+                                lambda i, j: (i, j, 0, 0))
+        rows = (slices, lane_blocks, per_block, block_q)
+    return (grid, per_block,
+            pl.BlockSpec((group, block_q, lanes), block_at),
+            pl.BlockSpec((group, block_k, lanes), block_at),
+            row_spec, rows)
 
 
 def _causal_mask(qi, j, block_q, block_k, q_offset, k_offset, window=None):
@@ -601,64 +685,115 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
                              causal: bool, scale: float, block_q: int,
-                             block_k: int, q_offset: int, k_offset: int):
+                             block_k: int, q_offset: int, k_offset: int,
+                             heads: int = 1):
     """Single-tile forward: when the sequence is ONE (block_q, block_k)
     tile there is nothing to run online-softmax OVER — the running-max
     rescale machinery (scratch init/rw, correction exp, accumulator
     rescale) is pure overhead. Direct softmax, same outputs/sentinels
-    as the general kernel. Grid (BH // G,): the blocks hold a group of G
-    slices, each computed as it was alone."""
+    as the general kernel. The blocks hold a group of G slices, each
+    computed as it was alone, and a slice the lanes of ``heads`` heads
+    (one of a head-major call: the kernel this was, text for text). Each
+    head is computed as it was alone too, out of the lanes where it lies:
+    its scores contract q over all the lanes with the other heads' zeroed
+    (``_head_lanes``), and of its [block_q, lanes] context its own lanes
+    are kept (``_put_head_lanes``)."""
     g = _group_index(q_ref)
     q = q_ref[g]
     k_tile = k_ref[g]
     v_tile = v_ref[g]
-    s = _dot(q, k_tile, 1, 1) * scale
-    if causal:
-        mask = _causal_mask(0, 0, block_q, block_k, q_offset, k_offset)
-        s = jnp.where(mask, s, NEG_INF)
-    m = s.max(axis=-1)
-    p = jnp.exp(s - m[..., None])
-    if causal:
-        p = jnp.where(mask, p, 0.0)
-    l = p.sum(axis=-1)
-    empty = l == 0.0
-    safe_l = jnp.where(empty, 1.0, l)
-    acc = _dot(p.astype(v_tile.dtype), v_tile, 1, 0)
-    o_ref[g] = (acc / safe_l[..., None]).astype(o_ref.dtype)
-    lse_ref[g, 0, :] = jnp.where(empty, LSE_MASKED, m + jnp.log(safe_l))
+    out, rows = None, []
+    for head in range(heads):
+        s = _dot(_head_lanes(q, head, heads), k_tile, 1, 1) * scale
+        if causal:
+            mask = _causal_mask(0, 0, block_q, block_k, q_offset, k_offset)
+            s = jnp.where(mask, s, NEG_INF)
+        m = s.max(axis=-1)
+        p = jnp.exp(s - m[..., None])
+        if causal:
+            p = jnp.where(mask, p, 0.0)
+        l = p.sum(axis=-1)
+        empty = l == 0.0
+        safe_l = jnp.where(empty, 1.0, l)
+        acc = _dot(p.astype(v_tile.dtype), v_tile, 1, 0)
+        out = _put_head_lanes(out, acc / safe_l[..., None], head, heads)
+        rows.append((empty, m, safe_l))
+    o_ref[g] = out.astype(o_ref.dtype)
+    for head, (empty, m, safe_l) in enumerate(rows):
+        lse_ref[g, head, :] = jnp.where(empty, LSE_MASKED,
+                                        m + jnp.log(safe_l))
 
 
 def _flash_dqkv_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref,
                              delta_ref, glse_ref, dq_ref, dk_ref, dv_ref,
                              *, causal: bool, scale: float, block_q: int,
-                             block_k: int, q_offset: int, k_offset: int):
+                             block_k: int, q_offset: int, k_offset: int,
+                             heads: int = 1, out_ref=None):
     """Fused single-tile backward: when the whole sequence is ONE
     (block_q, block_k) tile (the BERT-Large shapes), the separate
     dQ and dK/dV passes each recompute the identical s → p → dp → ds
     chain. This kernel computes the chain once and emits all three
     grads — roughly a third of the backward softmax/VPU work saved.
-    Grid (BH // G,), the blocks a group of G slices as in the forward;
-    the callers route here iff nq == nk == 1."""
+    The blocks are a group of G slices of ``heads`` heads each, as in the
+    forward; the callers route here iff nq == nk == 1. A head's five
+    products are the ones it had alone: q and dO enter with the other
+    heads' lanes zeroed, so the scores and dP contract over its lanes and
+    its dV and dK come out zero outside them; of dQ its lanes are kept. A
+    gradient is written as soon as its last head is in.
+
+    ``out_ref`` (``_flash_dqkv_from_out_kernel``): the forward's output
+    in place of ``delta_ref`` and no ``glse_ref``; delta is then summed
+    here, over a head's lanes of dO * O."""
     g = _group_index(q_ref)
     q = q_ref[g]
     k_tile = k_ref[g]
     v_tile = v_ref[g]
     do = do_ref[g]
-    lse = lse_ref[g, 0, :]
-    delta = delta_ref[g, 0, :]
-    glse = glse_ref[g, 0, :]
+    if out_ref is not None:
+        do_out = do.astype(jnp.float32) * out_ref[g].astype(jnp.float32)
+    dq = dk = dv = None
+    for head in range(heads):
+        last = head == heads - 1
+        lse = lse_ref[g, head, :]
+        if out_ref is None:
+            delta = delta_ref[g, head, :]
+            glse = glse_ref[g, head, :]
+        q_head = _head_lanes(q, head, heads)
+        do_head = _head_lanes(do, head, heads)
 
-    s = _dot(q, k_tile, 1, 1) * scale  # [block_q, block_k]
-    if causal:
-        mask = _causal_mask(0, 0, block_q, block_k, q_offset, k_offset)
-        s = jnp.where(mask, s, NEG_INF)
-    p = jnp.exp(s - lse[..., None])
-    pw = p.astype(do.dtype)
-    dv_ref[g] = _dot(pw, do, 0, 0).astype(dv_ref.dtype)
-    dp = _dot(do, v_tile, 1, 1)
-    ds = (p * (dp - delta[..., None] + glse[..., None])).astype(q.dtype)
-    dq_ref[g] = (scale * _dot(ds, k_tile, 1, 0)).astype(dq_ref.dtype)
-    dk_ref[g] = (scale * _dot(ds, q, 0, 0)).astype(dk_ref.dtype)
+        s = _dot(q_head, k_tile, 1, 1) * scale  # [block_q, block_k]
+        if causal:
+            mask = _causal_mask(0, 0, block_q, block_k, q_offset, k_offset)
+            s = jnp.where(mask, s, NEG_INF)
+        p = jnp.exp(s - lse[..., None])
+        pw = p.astype(do.dtype)
+        dv = _put_head_lanes(dv, _dot(pw, do_head, 0, 0), head, heads)
+        if last:
+            dv_ref[g] = dv.astype(dv_ref.dtype)
+        dp = _dot(do_head, v_tile, 1, 1)
+        if out_ref is None:
+            ds = p * (dp - delta[..., None] + glse[..., None])
+        else:
+            ds = p * (dp - _head_lanes(do_out, head, heads).sum(
+                axis=-1, keepdims=True))
+        ds = ds.astype(q.dtype)
+        dq = _put_head_lanes(dq, scale * _dot(ds, k_tile, 1, 0), head, heads)
+        if last:
+            dq_ref[g] = dq.astype(dq_ref.dtype)
+        dk = _put_head_lanes(dk, scale * _dot(ds, q_head, 0, 0), head, heads)
+        if last:
+            dk_ref[g] = dk.astype(dk_ref.dtype)
+
+
+def _flash_dqkv_from_out_kernel(q_ref, k_ref, v_ref, do_ref, out_ref,
+                                lse_ref, dq_ref, dk_ref, dv_ref, **static):
+    """The fused backward for a caller that has the forward's output and
+    no cotangent of the log-sum-exp (the tokens-major entry): a pass of
+    XLA's over dO and O to make delta a head would first re-lay both out,
+    the copies that entry exists to save."""
+    _flash_dqkv_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, None,
+                             None, dq_ref, dk_ref, dv_ref, out_ref=out_ref,
+                             **static)
 
 
 # ---------------------------------------------------------------------------
@@ -745,6 +880,69 @@ def _single_tile(Sq, Sk, block_q, block_k, window, group) -> bool:
             and group == 1)
 
 
+def _single_tile_fwd(qr, kr, vr, causal, block_q, block_k, q_offset,
+                     k_offset, interpret, heads=None):
+    """The direct-softmax forward on operands of one tile: head-major
+    ``[BH, S, D]``, or with ``heads`` tokens-major ``[B, S, heads * D]``
+    (``_group_specs`` has the two grids). ``(out, lse)``, the output in
+    the operands' layout. Single-tile sequences skip the online-softmax
+    machinery, and a grid step takes a group of them."""
+    grid, per_block, q_spec, kv_spec, row_spec, rows = _group_specs(
+        "fwd", qr, block_q, block_k, _FWD_SLICE, heads)
+    return pl.pallas_call(
+        functools.partial(
+            _flash_fwd_single_kernel, causal=causal,
+            scale=1.0 / ((qr.shape[2] // (heads or 1)) ** 0.5),
+            block_q=block_q, block_k=block_k,
+            q_offset=q_offset, k_offset=k_offset, heads=per_block,
+        ),
+        grid=grid,
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, row_spec],
+        out_shape=[
+            jax.ShapeDtypeStruct(qr.shape, qr.dtype),
+            jax.ShapeDtypeStruct(rows, jnp.float32),
+        ],
+        interpret=interpret,
+        name=KERNEL_NAME,
+    )(qr, kr, vr)
+
+
+def _single_tile_bwd(qr, kr, vr, do, lse, delta, g_lse, causal, block_q,
+                     block_k, q_offset, k_offset, interpret, heads=None,
+                     out=None):
+    """The fused backward on operands of one tile (BERT-Large with
+    auto-block), laid out as ``_single_tile_fwd``'s and the rows as it
+    returned ``lse``: one kernel computes dq, dk, dv for a group of slices
+    a grid step — the two-pass split of the multi-tile kernels exists only
+    to bound VMEM for many-tile sequences. A tokens-major caller hands
+    the forward's output ``out`` and neither ``delta`` nor ``g_lse``."""
+    from_out = out is not None
+    grid, per_block, q_spec, kv_spec, row_spec, _ = _group_specs(
+        "bwd", qr, block_q, block_k,
+        _BWD_FROM_OUT_SLICE if from_out else _BWD_SLICE, heads)
+    return tuple(pl.pallas_call(
+        functools.partial(
+            _flash_dqkv_from_out_kernel if from_out
+            else _flash_dqkv_fused_kernel, causal=causal,
+            scale=1.0 / ((qr.shape[2] // (heads or 1)) ** 0.5),
+            block_q=block_q, block_k=block_k, q_offset=q_offset,
+            k_offset=k_offset, heads=per_block,
+        ),
+        grid=grid,
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec] + (
+            [q_spec, row_spec] if from_out else [row_spec] * 3),
+        out_specs=[q_spec, kv_spec, kv_spec],
+        out_shape=[
+            jax.ShapeDtypeStruct(qr.shape, qr.dtype),
+            jax.ShapeDtypeStruct(kr.shape, kr.dtype),
+            jax.ShapeDtypeStruct(vr.shape, vr.dtype),
+        ],
+        interpret=interpret,
+        name=KERNEL_NAME,
+    )(qr, kr, vr, do, *((out, lse) if from_out else (lse, delta, g_lse))))
+
+
 def _fwd_kernels(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
                  interpret, window=None):
     BH, Sq, D = qr.shape
@@ -752,26 +950,8 @@ def _fwd_kernels(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
     group = BH // kr.shape[0]
     scale = 1.0 / (D ** 0.5)
     if _single_tile(Sq, Sk, block_q, block_k, window, group):
-        # Single-tile sequences skip the online-softmax machinery, and a
-        # grid step takes a group of them.
-        group, q_spec, kv_spec, row_spec = _group_specs(
-            "fwd", qr, block_q, block_k, _FWD_SLICE)
-        return pl.pallas_call(
-            functools.partial(
-                _flash_fwd_single_kernel, causal=causal, scale=scale,
-                block_q=block_q, block_k=block_k,
-                q_offset=q_offset, k_offset=k_offset,
-            ),
-            grid=(BH // group,),
-            in_specs=[q_spec, kv_spec, kv_spec],
-            out_specs=[q_spec, row_spec],
-            out_shape=[
-                jax.ShapeDtypeStruct((BH, Sq, D), qr.dtype),
-                jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32),
-            ],
-            interpret=interpret,
-            name=KERNEL_NAME,
-        )(qr, kr, vr)
+        return _single_tile_fwd(qr, kr, vr, causal, block_q, block_k,
+                                q_offset, k_offset, interpret)
     num_qb, num_kb = Sq // block_q, Sk // block_k
     kernel = functools.partial(
         _flash_fwd_kernel, causal=causal, scale=scale,
@@ -836,31 +1016,9 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
                     axis=-1)[:, None, :]  # [BH, 1, Sq]
 
     if _single_tile(Sq, Sk, block_q, block_k, window, group):
-        # Single-tile sequences (BERT-Large with auto-block): one fused
-        # kernel computes dq, dk, dv for a group of slices a grid step —
-        # the two-pass split below exists only to bound VMEM for
-        # many-tile sequences.
-        group, q_spec, kv_spec, row_spec = _group_specs(
-            "bwd", qr, block_q, block_k, _BWD_SLICE)
-        dq, dk, dv = pl.pallas_call(
-            functools.partial(
-                _flash_dqkv_fused_kernel, causal=causal, scale=scale,
-                block_q=block_q, block_k=block_k, q_offset=q_offset,
-                k_offset=k_offset,
-            ),
-            grid=(BH // group,),
-            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec,
-                      row_spec],
-            out_specs=[q_spec, kv_spec, kv_spec],
-            out_shape=[
-                jax.ShapeDtypeStruct((BH, Sq, D), qr.dtype),
-                jax.ShapeDtypeStruct((BH, Sk, D), kr.dtype),
-                jax.ShapeDtypeStruct((BH, Sk, D), vr.dtype),
-            ],
-            interpret=interpret,
-            name=KERNEL_NAME,
-        )(qr, kr, vr, do, lse, delta, g_lse)
-        return dq, dk, dv
+        return _single_tile_bwd(qr, kr, vr, do, lse, delta, g_lse, causal,
+                                block_q, block_k, q_offset, k_offset,
+                                interpret)
 
     num_qb, num_kb = Sq // block_q, Sk // block_k
     band_kb, band_qb = _record_tiles(causal, num_qb, num_kb, block_q,
@@ -962,6 +1120,39 @@ def _flash_with_lse_bwd(causal, block_q, block_k, q_offset, k_offset,
 
 
 _flash_with_lse.defvjp(_flash_with_lse_fwd, _flash_with_lse_bwd)
+
+
+# The single-tile kernels on tokens-major operands: q, k, v, the output and
+# every gradient ``[B, S, H * D]``, as a projection writes and reads them.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash_tokens_major(q, k, v, heads, causal, block_q, block_k, q_offset,
+                        k_offset, interpret):
+    return _flash_tokens_major_fwd(q, k, v, heads, causal, block_q, block_k,
+                                   q_offset, k_offset, interpret)[0]
+
+
+def _flash_tokens_major_fwd(q, k, v, heads, causal, block_q, block_k,
+                            q_offset, k_offset, interpret):
+    from ..profiler import annotate_collective
+
+    with annotate_collective(SCOPE_ATTN_FWD):
+        out, lse = _single_tile_fwd(q, k, v, causal, block_q, block_k,
+                                    q_offset, k_offset, interpret, heads)
+    return out, (q, k, v, out, lse)
+
+
+def _flash_tokens_major_bwd(heads, causal, block_q, block_k, q_offset,
+                            k_offset, interpret, res, do):
+    from ..profiler import annotate_collective
+
+    q, k, v, out, lse = res
+    with annotate_collective(SCOPE_ATTN_BWD):
+        return _single_tile_bwd(q, k, v, do, lse, None, None, causal,
+                                block_q, block_k, q_offset, k_offset,
+                                interpret, heads, out)
+
+
+_flash_tokens_major.defvjp(_flash_tokens_major_fwd, _flash_tokens_major_bwd)
 
 
 def _prepare_flash(q, k, v, causal, block_q, block_k, q_offset, k_offset,
@@ -1083,3 +1274,51 @@ def flash_attention_lse(q, k, v, causal: bool = False,
     makes logsumexp-merged schemes like ring-flash train exactly."""
     return _flash(q, k, v, causal, block_q, block_k, q_offset, k_offset,
                   interpret, window)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("num_heads", "causal", "block_q", "block_k", "q_offset",
+                     "k_offset", "interpret", "window"),
+)
+def flash_attention_tokens_major(q, k, v, num_heads: int,
+                                 causal: bool = False,
+                                 block_q: int | None = None,
+                                 block_k: int | None = None,
+                                 q_offset: int = 0, k_offset: int = 0,
+                                 interpret: bool = False,
+                                 window: int | None = None):
+    """:func:`flash_attention` for operands where a projection wrote
+    them, tokens major: q ``[B, S, H * D]`` with ``H = num_heads``, k, v
+    ``[B, S, KV heads * D]`` → ``[B, S, H * D]``, the gradients of q, k, v
+    in their layouts too. Which layout an array holds cannot be read from
+    its shape, so the caller says it by the entry it calls.
+
+    A sequence that is one tile, with a head of keys and values a query
+    head, no window and heads whose lanes tile 128 (``_heads_per_block``),
+    goes to the single-tile kernels as it lies: a block is the lanes of
+    whole heads (two at D = 64) of a group of batch rows, found by the
+    block's index map, and nothing is transposed in HBM. Any other call
+    transposes to ``[B, H, S, D]`` and is :func:`flash_attention`'s, bit
+    for bit, so no caller needs to know which it is."""
+    B, Sq, width = q.shape
+    if width % num_heads or k.shape[2] % (width // num_heads):
+        raise ValueError(
+            f"tokens-major flash attention wants q [B, S, heads * D] and "
+            f"k, v [B, S, KV heads * D]; got q={q.shape}, k={k.shape} with "
+            f"num_heads={num_heads}")
+    d = width // num_heads
+
+    def head_major(x):
+        return x.reshape(x.shape[:2] + (-1, d)).transpose(0, 2, 1, 3)
+
+    tile_q, tile_k, seen = _prepare_flash(
+        *(jax.eval_shape(head_major, x) for x in (q, k, v)), causal,
+        block_q, block_k, q_offset, k_offset, window)
+    if (_heads_per_block(d, num_heads) is not None and _single_tile(
+            Sq, k.shape[1], tile_q, tile_k, seen, width // k.shape[2])):
+        return _flash_tokens_major(q, k, v, num_heads, causal, tile_q,
+                                   tile_k, q_offset, k_offset, interpret)
+    out = _flash(head_major(q), head_major(k), head_major(v), causal,
+                 block_q, block_k, q_offset, k_offset, interpret, window)[0]
+    return out.transpose(0, 2, 1, 3).reshape(B, Sq, width)

@@ -304,3 +304,70 @@ def test_olmo_hybrids_delta_rule_compiles_at_its_widths(one_chip):
                for line in loops)
     assert "operand_precision={highest,highest}" in text
     assert "custom_call_target=\"tpu_custom_call\"" not in text
+
+
+@pytest.mark.parametrize("rows, seq, groups", [(24, 512, (2, 1)),
+                                               (96, 128, (16, 8))])
+def test_a_bert_layer_hands_the_kernels_what_its_projections_wrote(
+        one_chip, rows, seq, groups):
+    """A BERT-Large layer at the benchmark's two shapes, forward and
+    backward: the projections write ``bf16[rows, seq, 1024]`` and the two
+    flash kernels take it in blocks of 128 lanes, two heads, of ``G`` rows
+    (``hvd_attn_heads_per_block_last`` 2), their bodies two and five
+    products a head. Nothing of an activation's size is copied or
+    transposed anywhere in the compiled layer: not q, k, v, the context or
+    their gradients under ``hvd.block.attn_proj`` (8 copies a layer of
+    ``bf16[rows, 16, seq, 64]`` until PR 35), nor anything else."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu import metrics, profiler
+    from horovod_tpu.models import bert
+
+    cfg = bert.BERT_LARGE
+    layer = bert.TransformerLayer(cfg, bert.flash_attention_fn)
+    x = jax.ShapeDtypeStruct((rows, seq, cfg.hidden_size), jnp.bfloat16,
+                             sharding=one_chip)
+    bias = jax.ShapeDtypeStruct((rows, 1, 1, seq), jnp.float32,
+                                sharding=one_chip)
+    params = jax.tree.map(
+        lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                          sharding=one_chip),
+        jax.eval_shape(lambda: layer.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8, cfg.hidden_size)),
+            jnp.zeros((1, 1, 1, 8)), True)))
+
+    def loss(params, x, bias):
+        return layer.apply(params, x, bias, True).astype(jnp.float32).sum()
+
+    lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x, bias)
+    assert [int(metrics.ATTN_HEADS_PER_BLOCK_LAST.labels(kernel=k).get())
+            for k in ("fwd", "bwd")] == [2, 2]
+    assert tuple(int(metrics.ATTN_GROUP_LAST.labels(kernel=k).get())
+                 for k in ("fwd", "bwd")) == groups
+    bodies = kernel_bodies(lowered.as_text())
+    assert len(bodies) == 2
+    for group, body, products in zip(groups, bodies, (2, 5)):
+        assert f"iteration_bounds = array<i64: {rows // group}, 8>" in body
+        assert f"window_bounds = array<i64: {group}, {seq}, 128>" in body
+        assert f"window_bounds = array<i64: {group}, 1, 2, {seq}>" in body
+        assert "scf.for" not in body
+        assert body.count("tpu.matmul") == 2 * products
+
+    text = lowered.compile().as_text()
+    found = kernel_instructions(text)
+    assert sorted(profiler.phase_of(scope) for _, scope in found) == [
+        "hvd.attn.bwd", "hvd.attn.fwd"]
+    assert all("hvd.block.attn_proj" in scope for _, scope in found)
+    activation = rows * seq * cfg.hidden_size
+    moved = [
+        (name, owner.opcode, owner.shape)
+        for name, owner in profiler.instruction_owners(text).items()
+        if owner.opcode in ("copy", "transpose")
+        and math.prod(int(n) for n in re.match(
+            r"\w+\[([\d,]*)\]", owner.shape).group(1).split(",")
+            if n) >= activation]
+    assert not moved, moved

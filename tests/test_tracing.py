@@ -755,13 +755,17 @@ class TestFactoryStepSpans:
         for _ in range(3):
             step(params, opt_state, batch())
         assert metrics.STEP_RECOMPILES.labels(step="train_step").get() == 0
+        # The account is the process's, by step name: whatever test file
+        # this worker ran before may have left recompiles of its own there.
+        before = hvd.cache_stats()["compile"]["steps"]["train_step"][
+            "recompiles"]
         step(params, opt_state, batch(rows=16))  # a shape it has not seen
         step(params, opt_state, batch(rows=16))
         assert metrics.STEP_RECOMPILES.labels(step="train_step").get() == 1
         account = hvd.cache_stats()["compile"]
         assert account["listening"] and account["programs"] >= 2
         booked = account["steps"]["train_step"]
-        assert booked["recompiles"] == 1
+        assert booked["recompiles"] == before + 1
         assert booked["last_recompile"]["call"] == 4
         assert booked["first_call"]["programs"] >= 1
         (journaled,) = [e for e in _read_events(events)

@@ -5,10 +5,12 @@
 ``flash_attention`` forward and backward in bf16 against
 ``blockwise_attention_reference`` in float32 at the single-tile shapes
 BERT-Large uses (S512 and S128, D64: a grid step takes a group of slices),
-at 15 causal single-tile slices, at a multi-tile causal shape (S2048 D128)
-and at OLMoE's (one sequence of S4096, 16 heads of D128: a grid of 16 x 8 x 8
-tiles of 512). Compiled, never ``interpret=True``: off a TPU this exits
-non-zero.
+at 15 causal single-tile slices, the same two kernels on tokens-major
+operands (``flash_attention_tokens_major``: ``[B, S, H * D]``, two heads of
+D64 or one of D128 a 128-lane block), at a multi-tile causal shape (S2048
+D128) and at OLMoE's (one sequence of S4096, 16 heads of D128: a grid of
+16 x 8 x 8 tiles of 512). Compiled, never ``interpret=True``: off a TPU
+this exits non-zero.
 
 The tolerance is the one ``tests/test_sequence_parallel.py`` uses for bf16
 inputs (rtol = atol = 2e-2) with atol multiplied by the reference's
@@ -45,23 +47,33 @@ def _close(name, got, want) -> None:
                                atol=BF16_TOL * scale, err_msg=name)
 
 
-def check_flash(batch, heads, seq, dim, causal) -> None:
+def check_flash(batch, heads, seq, dim, causal, tokens_major=False) -> None:
     import jax
     import jax.numpy as jnp
 
     from horovod_tpu.ops.attention import (
         blockwise_attention_reference,
         flash_attention,
+        flash_attention_tokens_major,
     )
 
     print(f"flash_attention B{batch} H{heads} S{seq} D{dim} "
-          f"causal={causal} bf16")
+          f"causal={causal} bf16{' tokens-major' if tokens_major else ''}")
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
     q, k, v = (jax.random.normal(key, (batch, heads, seq, dim), jnp.bfloat16)
                for key in keys)
 
+    def across(x):  # [B, H, S, D] <-> [B, S, H, D]
+        return x.transpose(0, 2, 1, 3)
+
     def loss_flash(q, k, v):
-        out = flash_attention(q, k, v, causal=causal)
+        if tokens_major:  # the transposes are the check's, not the entry's
+            out = across(flash_attention_tokens_major(
+                *(across(x).reshape(batch, seq, heads * dim)
+                  for x in (q, k, v)), heads, causal=causal).reshape(
+                      batch, seq, heads, dim))
+        else:
+            out = flash_attention(q, k, v, causal=causal)
         return jnp.sum(out.astype(jnp.float32) ** 2), out
 
     def loss_ref(q, k, v):
@@ -76,6 +88,15 @@ def check_flash(batch, heads, seq, dim, causal) -> None:
     _close("out", out, want_out)
     for name, g, w in zip(("dq", "dk", "dv"), grads, want_grads):
         _close(name, g, w)
+
+
+def check_tokens_major() -> None:
+    """BERT's two shapes as its projections write them, an odd group of
+    causal pairs, and a head a block."""
+    check_flash(4, 16, 512, 64, causal=False, tokens_major=True)
+    check_flash(96, 16, 128, 64, causal=False, tokens_major=True)
+    check_flash(3, 6, 128, 64, causal=True, tokens_major=True)
+    check_flash(2, 4, 128, 128, causal=False, tokens_major=True)
 
 
 def main() -> None:
@@ -94,6 +115,7 @@ def main() -> None:
     # and a count of slices that no power of two divides
     check_flash(96, 16, 128, 64, causal=False)
     check_flash(3, 5, 128, 64, causal=True)
+    check_tokens_major()
     check_flash(2, 4, 2048, 128, causal=True)
     check_flash(1, 16, 4096, 128, causal=True)
     print("kernels ok")
