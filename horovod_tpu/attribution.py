@@ -109,6 +109,13 @@ SCOPE_ATTN_BWD = "attn.bwd"
 #: which is what sums by phase read): it tells a window layer's kernels
 #: from a full layer's in a model that has both (``models/smallthinker``).
 SCOPE_ATTN_WINDOW = "attn.window"
+#: Around the same two where the call has a block mask (``flash_attention(
+#: ..., block_length=B)``), and around all of
+#: ``ops.attention.block_diffusion_attention``: the two kernel calls of a
+#: block-diffusion step *and* what joins the noisy stream's two sources
+#: beside them (the own block's four keys a query in plain XLA, the
+#: log-sum-exp merge, the split and the concatenation). No phase either.
+SCOPE_ATTN_BLOCKDIFF = "attn.blockdiff"
 #: JAX's own name-stack component for the forward operations that a
 #: ``jax.checkpoint`` (``nn.remat``) runs again inside the backward pass:
 #: ``transpose(jvp(...))/rematted_computation/...``. Not a scope this

@@ -905,7 +905,11 @@ ATTN_TILES_LAST = gauge(
     "flash-attention call computes and skips (a causal call skips the "
     "tiles its mask leaves nothing of), and the grid steps a slice takes "
     "(kind=grid: the whole tile grid, under a window the band alone): set "
-    "at trace time, as hvd_grad_sync_last_bytes is.",
+    "at trace time, as hvd_grad_sync_last_bytes is. "
+    "kind=blockdiff_computed|blockdiff_skipped|blockdiff_grid: the same "
+    "three for the LAST traced block_diffusion_attention as a whole, its "
+    "two kernel calls together (computed and grid steps summed; skipped "
+    "out of the doubled stream's 2S x 2S square of tiles).",
     ("kind",))
 ATTN_GROUP_LAST = gauge(
     "hvd_attn_group_last",
@@ -928,6 +932,12 @@ ATTN_KV_GROUP_LAST = gauge(
     "Query heads that read one key/value head in the LAST traced multi-tile "
     "flash-attention call (1: a head of keys and values a query head): set "
     "at trace time, beside hvd_attn_tiles_last.")
+DIFFUSION_MASKED_SHARE_LAST = gauge(
+    "hvd_diffusion_masked_share_last",
+    "Share of the positions that the LAST block-diffusion batch made "
+    "(models.sdar.noisy_batch) replaced by the mask token: a run-time "
+    "value, set by a host callback where the batch is made (expected "
+    "(t_min + 1) / 2 under the clipped linear schedule).")
 ALLTOALL_LATENCY = histogram(
     "hvd_alltoall_latency_seconds",
     "Wall time of alltoall exchanges (eager dispatches and MoE "

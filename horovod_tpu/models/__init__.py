@@ -15,6 +15,7 @@ from .olmoe import (  # noqa: F401
     OlmoeConfig,
     causal_lm_loss,
     routing_stats,
+    take_expert_window,
 )
 from .olmo_hybrid import (  # noqa: F401
     OLMO_HYBRID_7B,
@@ -28,5 +29,12 @@ from .smallthinker import (  # noqa: F401
     SMALLTHINKER_TINY,
     SmallThinker,
     SmallThinkerConfig,
-    take_expert_window,
+)
+from .sdar import (  # noqa: F401
+    SDAR_30B_A3B,
+    SDAR_TINY,
+    Sdar,
+    SdarConfig,
+    block_diffusion_loss,
+    noisy_batch,
 )

@@ -62,11 +62,13 @@ class OlmoeConfig:
         return self.hidden_size // self.num_heads
 
     @property
-    def window(self) -> int:
+    def experts_held(self) -> int:
         """Experts this model holds."""
         if self.experts_here is None:
             return self.num_experts - self.first_expert
         return self.experts_here
+
+    window = experts_held  # its name before the decoders shared SparseExperts
 
     def capacity(self, seq_len: int) -> int:
         return moe.expert_capacity(self.capacity_factor, seq_len, self.top_k,
@@ -92,13 +94,18 @@ class RMSNorm(nn.Module):
             jnp.mean(jnp.square(x), -1, keepdims=True) + self.eps) * scale
 
 
-def rope(x, theta: float):
+def rope(x, theta: float, positions=None):
     """Rotary position embedding of ``x [B, S, H, D]`` in float32, the
-    half-split form (``rotate_half``): lane ``i`` pairs with ``i + D/2``."""
+    half-split form (``rotate_half``): lane ``i`` pairs with ``i + D/2``.
+    ``positions`` (``[S]`` or ``[B, S]``) are the position ids where they
+    are not ``0..S-1``: a stream that holds two sequences side by side
+    (``models/sdar.py``) counts each from zero."""
     half = x.shape[-1] // 2
     inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv_freq
-    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    if positions is None:
+        positions = jnp.arange(x.shape[1], dtype=jnp.float32)
+    angle = positions.astype(jnp.float32)[..., None] * inv_freq
+    cos, sin = jnp.cos(angle)[..., None, :], jnp.sin(angle)[..., None, :]
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
@@ -151,19 +158,31 @@ class CausalSelfAttention(nn.Module):
 
 
 class SparseExperts(nn.Module):
-    """The router and this model's window of the experts. Returns the
-    experts' weighted outputs ``[B, S, D]`` (no residual) and the layer's
-    two auxiliary losses, each the mean over the batch's sequences."""
+    """A model's window of the experts in capacity slots, for every
+    mixture of experts here: ``tokens [B, S, D] ->`` the experts' weighted
+    outputs ``[B, S, D]`` (no residual), one row a routing group.
+    ``config`` is the model's (``hidden_size``, ``intermediate_size``,
+    ``num_experts``, ``top_k``, ``first_expert``, ``experts_held``,
+    ``capacity``, ``dtype``). The router is this module's (``logits`` is
+    ``None``: a float32 parameter ``router``) or the caller's, who then
+    hands in its ``logits [B, S, num_experts]``. ``auxiliary(logits,
+    expert)`` is a routing group's auxiliary losses, a tuple of scalars;
+    their means over the groups are returned after the output."""
 
-    config: OlmoeConfig
+    config: Any
+    activation: Callable = jax.nn.silu
+    gates_over_picks: bool = False
+    auxiliary: Callable | None = None
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, logits=None):
         cfg = self.config
         hidden, width, here = (cfg.hidden_size, cfg.intermediate_size,
-                               cfg.window)
-        router = self.param("router", nn.initializers.lecun_normal(),
-                            (hidden, cfg.num_experts), jnp.float32)
+                               cfg.experts_held)
+        router = None
+        if logits is None:
+            router = self.param("router", nn.initializers.lecun_normal(),
+                                (hidden, cfg.num_experts), jnp.float32)
         stacked = nn.initializers.lecun_normal(batch_axis=(0,))
         w_gate = self.param("experts_gate", stacked, (here, hidden, width),
                             jnp.float32)
@@ -174,34 +193,39 @@ class SparseExperts(nn.Module):
         capacity = cfg.capacity(x.shape[1])
         _record_slots(here, capacity, cfg.top_k)
 
-        def one_sequence(tokens):
-            # The router in float32 all the way: a TPU's default float32
-            # matmul is one bfloat16 pass, and a pick is a discontinuity.
-            with annotate_collective(SCOPE_MOE_ROUTE):
-                logits = jnp.matmul(tokens, router,
-                                    precision=jax.lax.Precision.HIGHEST)
+        def one_group(tokens, logits):
+            if router is not None:
+                # The router in float32 all the way: a TPU's default
+                # float32 matmul is one bfloat16 pass, and a pick is a
+                # discontinuity.
+                with annotate_collective(SCOPE_MOE_ROUTE):
+                    logits = jnp.matmul(tokens, router,
+                                        precision=jax.lax.Precision.HIGHEST)
             send, expert, pos, keep, gate, counts = moe.route_to_capacity(
                 tokens.astype(cfg.dtype), logits, cfg.num_experts, capacity,
                 top_k=cfg.top_k, first_expert=cfg.first_expert,
-                experts_here=here)
+                experts_here=here, gates_over_picks=self.gates_over_picks)
             back = moe.gated_expert_ffn(
                 w_gate.astype(cfg.dtype), w_up.astype(cfg.dtype),
-                w_down.astype(cfg.dtype), send[..., :hidden])
+                w_down.astype(cfg.dtype), send[..., :hidden],
+                activation=self.activation)
             out = moe.combine_top_k(back, expert, pos, keep, gate,
                                     cfg.first_expert)
-            with annotate_collective(SCOPE_MOE_ROUTE):
-                balance, z = (load_balance_loss(logits, expert),
-                              router_z_loss(logits))
+            losses = ()
+            if self.auxiliary is not None:
+                with annotate_collective(SCOPE_MOE_ROUTE):
+                    losses = self.auxiliary(logits, expert)
             in_window = (expert >= cfg.first_expert) & (
                 expert < cfg.first_expert + here)
-            dropped = jnp.sum(in_window & ~keep)
-            return out, balance, z, counts, dropped
+            return out, counts, jnp.sum(in_window & ~keep), losses
 
-        out, balance, z, counts, dropped = jax.vmap(one_sequence)(x)
+        out, counts, dropped, losses = jax.vmap(one_group)(x, logits)
         self.sow("intermediates", "routing",
                  {"load": counts.sum(0), "dropped": dropped.sum(),
                   "pairs": counts.sum() + dropped.sum()})
-        return out, balance.mean(), z.mean()
+        if self.auxiliary is None:
+            return out
+        return (out,) + tuple(loss.mean() for loss in losses)
 
 
 def _record_slots(experts_here: int, capacity: int, top_k: int) -> None:
@@ -233,6 +257,11 @@ def router_z_loss(logits):
     return jnp.mean(jnp.square(jax.nn.logsumexp(logits, -1)))
 
 
+def auxiliary_losses(logits, expert):
+    """A routing group's ``(load balance, router z)`` losses."""
+    return load_balance_loss(logits, expert), router_z_loss(logits)
+
+
 class DecoderLayer(nn.Module):
     config: OlmoeConfig
     attention_fn: Callable | None = None
@@ -249,7 +278,8 @@ class DecoderLayer(nn.Module):
         with annotate_collective(SCOPE_BLOCK_NORM):
             x = x + attn
             n2 = RMSNorm(cfg.rms_norm_eps, name="ln_moe")(x)
-        out, balance, z = SparseExperts(cfg, name="moe")(n2)
+        out, balance, z = SparseExperts(
+            cfg, auxiliary=auxiliary_losses, name="moe")(n2)
         with annotate_collective(SCOPE_BLOCK_NORM):
             return x + out, balance, z
 
@@ -298,13 +328,18 @@ def causal_lm_loss(model: Olmoe, params, tokens):
                 + cfg.router_z_coef * z)
 
 
-def routing_stats(model: Olmoe, params, input_ids):
-    """What the routing did with ``input_ids [B, S]``, layer by layer:
-    ``{"load": [layers, experts_here]`` kept pairs an expert, ``"dropped":
-    [layers]`` pairs of this window past capacity, ``"dropped_share":
-    [layers]`` of the window's pairs``}``. Run-time values, so a function
-    of their own and nothing the train step carries; jit it."""
-    _, state = model.apply({"params": params}, input_ids,
+def routing_stats(model, params, *inputs):
+    """What the routing of a mixture of experts here (``Olmoe``,
+    ``SmallThinker``, ``Sdar``) did with ``inputs``, what the model is
+    called on, layer by layer: ``{"load": [layers, experts_here]`` kept
+    pairs an expert, ``"dropped": [layers]`` pairs of this window past
+    capacity, ``"dropped_share": [layers]`` of the window's pairs``}``.
+    Run-time values, so a program of its own, without recomputation, and
+    nothing the train step carries; jit it."""
+    if getattr(model.config, "remat", False):
+        model = model.clone(
+            config=dataclasses.replace(model.config, remat=False))
+    _, state = model.apply({"params": params}, *inputs,
                            mutable=["intermediates"])
     layers = [state["intermediates"][f"layer_{i}"]["moe"]["routing"][0]
               for i in range(model.config.num_layers)]
@@ -313,3 +348,20 @@ def routing_stats(model: Olmoe, params, input_ids):
     pairs = jnp.stack([layer["pairs"] for layer in layers])
     return {"load": load, "dropped": dropped,
             "dropped_share": dropped / jnp.maximum(pairs, 1)}
+
+
+def take_expert_window(params, share):
+    """The parameters ``share`` holds (a model's config: its
+    ``experts_held`` experts from ``first_expert`` on), cut out of the
+    tree of the same model with all its experts: the stacked expert
+    weights lose the other experts' rows; attention, router, norms,
+    embedding and head are every window's alike."""
+    first, last = share.first_expert, share.first_expert + share.experts_held
+    out = dict(params)
+    for i in range(share.num_layers):
+        layer = dict(params[f"layer_{i}"])
+        layer["moe"] = {
+            name: leaf[first:last] if name.startswith("experts_") else leaf
+            for name, leaf in layer["moe"].items()}
+        out[f"layer_{i}"] = layer
+    return out

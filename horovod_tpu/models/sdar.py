@@ -1,0 +1,289 @@
+"""SDAR — the framework's block-diffusion decoder: a mixture of experts
+trained to denoise blocks of tokens given the clean text before them.
+
+JetLM's ``SDAR-30B-A3B-Chat`` (``config.json``, ``model_type``
+``sdar_moe``) is a Qwen3-MoE-shaped pre-norm decoder: 48 layers, hidden
+2,048, 32 query heads on 4 key/value heads of 128 with a per-head QK-norm
+before RoPE (theta 1e6), 128 SiLU-gated experts of width 768 of which a
+token takes 8 with gates renormalised over the eight, no shared expert, an
+untied head. What makes it a block-diffusion model is how it is trained
+(BD3-LMs, arXiv:2503.09573). A step reads **two streams** of the same ``S``
+tokens side by side, ``[x_t ; x_0]``: the noisy one, in which every block
+of ``block_length`` positions has had each token replaced by the mask token
+with that block's probability ``t``, then the clean one. Both count their
+positions from zero. With ``blk(i) = pos(i) // block_length``, query ``i``
+sees key ``j`` iff
+
+* both noisy and ``blk(j) == blk(i)`` (its own block, noisy), or
+* ``i`` noisy, ``j`` clean and ``blk(j) < blk(i)`` (the clean past), or
+* both clean and ``blk(j) <= blk(i)`` (block-causal);
+
+a clean query never sees a noisy key. Of the ``(2S)^2`` pairs that leaves
+``S (S + block_length)``: ``ops.attention.block_diffusion_attention`` runs
+two causal tile plans over the clean keys and never builds the square. The
+head reads the noisy half only, and the loss is the cross entropy of the
+masked positions' own tokens (labels in place, no shift), each weighted by
+``1 / t`` of its block:
+
+    L = 1 / (R S) * sum_i m_i / t_blk(i) * -log softmax(z_i)[x0_i]
+
+TPU-first choices, as ``models/smallthinker.py`` (whose recomputation
+policy this model shares, ``models/recompute.py``) and ``models/olmoe.py``
+(whose ``RMSNorm``, ``rope`` and experts module, ``SparseExperts``, it
+uses): bfloat16 activations with float32 parameters, norms, RoPE and
+router; the experts through ``parallel/moe.py``'s slots, one row of the
+doubled stream a
+routing group (pairs take an expert's slots in stream order, the noisy
+half first, then pick order). A model may hold a window of the experts
+(``experts_here`` from ``first_expert`` on), one chip's share of expert
+parallelism: the router keeps its width and a token's gates are normalised
+over all eight picks wherever they live, so the shares' outputs add up to
+the whole layer's.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Callable
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ..attribution import (SCOPE_BLOCK_ATTN_PROJ, SCOPE_BLOCK_EMBED,
+                           SCOPE_BLOCK_HEAD, SCOPE_BLOCK_NORM)
+from ..ops.attention import block_diffusion_attention
+from ..parallel import moe
+from ..profiler import annotate_collective
+from .olmoe import (RMSNorm, SparseExperts, rope,  # noqa: F401
+                    routing_stats, take_expert_window)
+from .recompute import save_kernels_and_projections
+
+
+@dataclasses.dataclass(frozen=True)
+class SdarConfig:
+    vocab_size: int = 151936
+    hidden_size: int = 2048
+    num_layers: int = 48
+    num_heads: int = 32
+    num_kv_heads: int = 4
+    head_dim: int = 128
+    intermediate_size: int = 768  # one expert's width
+    num_experts: int = 128
+    top_k: int = 8
+    experts_here: int | None = None  # None: all from first_expert on
+    first_expert: int = 0
+    capacity_factor: float = 1.25
+    block_length: int = 4
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 1e6
+    remat: bool = True
+    dtype: Any = jnp.bfloat16
+
+    def __post_init__(self):
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError(
+                f"{self.num_heads} query heads cannot share "
+                f"{self.num_kv_heads} key/value heads evenly")
+
+    @property
+    def experts_held(self) -> int:
+        if self.experts_here is None:
+            return self.num_experts - self.first_expert
+        return self.experts_here
+
+    @property
+    def mask_id(self) -> int:
+        """The mask token: the last id of the vocabulary held, which the
+        data never draws and no label is."""
+        return self.vocab_size - 1
+
+    def capacity(self, stream_len: int) -> int:
+        """Slots an expert gets for one row of ``stream_len`` positions,
+        both halves of it."""
+        return moe.expert_capacity(self.capacity_factor, stream_len,
+                                   self.top_k, self.num_experts)
+
+
+SDAR_30B_A3B = SdarConfig()
+SDAR_TINY = SdarConfig(  # test-sized: 8 heads on 2, blocks of 4
+    vocab_size=256, hidden_size=64, num_layers=2, num_heads=8,
+    num_kv_heads=2, head_dim=8, intermediate_size=24, num_experts=8,
+    top_k=2, capacity_factor=2.0,
+)
+
+
+def visible(block_length: int, seq_len: int):
+    """The ``[2S, 2S]`` mask of the doubled stream from its three
+    predicates: for the dense fallback and for tests, never for the
+    kernels."""
+    pos = jnp.concatenate([jnp.arange(seq_len)] * 2)
+    noisy = jnp.arange(2 * seq_len) < seq_len
+    q_blk, k_blk = (pos // block_length)[:, None], (pos // block_length)[None]
+    q_noisy, k_noisy = noisy[:, None], noisy[None]
+    return ((q_noisy & k_noisy & (k_blk == q_blk))
+            | (q_noisy & ~k_noisy & (k_blk < q_blk))
+            | (~q_noisy & ~k_noisy & (k_blk <= q_blk)))
+
+
+def dense_block_diffusion_attention(q, k, v, dtype, block_length):
+    """``q [B, 2S, H, D]``, ``k``, ``v [B, 2S, KV heads, D]``, the noisy
+    half first; the masked softmax in float32 over the whole square, the
+    keys and values of a group repeated: the fallback where no kernel
+    runs."""
+    group = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(x.astype(jnp.float32), group, axis=2) for x in (k, v))
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                        k) / (q.shape[-1] ** 0.5)
+    seen = visible(block_length, q.shape[1] // 2)
+    out = jnp.einsum("bhqk,bkhd->bqhd",
+                     jax.nn.softmax(jnp.where(seen, scores, -1e30), -1), v)
+    return out.astype(dtype)
+
+
+def flash_attention_fn(q, k, v, dtype, block_length, interpret: bool = False,
+                       block: int | None = None):
+    """Adapter plugging ``block_diffusion_attention`` into ``Sdar``:
+    ``[B, 2S, heads, D]`` -> transpose -> the two kernel calls and the
+    merge, the keys and values with their own, smaller number of heads.
+    ``block`` is for tests that want several tiles of a short sequence."""
+    out = block_diffusion_attention(
+        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+        v.transpose(0, 2, 1, 3), block_length, block_q=block, block_k=block,
+        interpret=interpret)
+    return out.transpose(0, 2, 1, 3).astype(dtype)
+
+
+class TwoStreamAttention(nn.Module):
+    config: SdarConfig
+    attention_fn: Callable | None = None
+
+    @nn.compact
+    def __call__(self, x, positions):
+        cfg = self.config
+
+        def project(name, width):
+            return nn.Dense(width, use_bias=False, dtype=cfg.dtype,
+                            param_dtype=jnp.float32, name=name)
+
+        def heads(y, count):
+            return y.reshape(x.shape[:2] + (count, cfg.head_dim))
+
+        q = heads(project("query", cfg.num_heads * cfg.head_dim)(x),
+                  cfg.num_heads)
+        k = heads(project("key", cfg.num_kv_heads * cfg.head_dim)(x),
+                  cfg.num_kv_heads)
+        v = heads(project("value", cfg.num_kv_heads * cfg.head_dim)(x),
+                  cfg.num_kv_heads)
+        # QK-norm a head: over its 128 lanes, one learned scale for all
+        # heads, before RoPE (OLMoE's is over the whole projection).
+        q = RMSNorm(cfg.rms_norm_eps, name="q_norm")(q)
+        k = RMSNorm(cfg.rms_norm_eps, name="k_norm")(k)
+        q = rope(q, cfg.rope_theta, positions).astype(cfg.dtype)
+        k = rope(k, cfg.rope_theta, positions).astype(cfg.dtype)
+        attend = self.attention_fn or dense_block_diffusion_attention
+        out = attend(q, k, v, cfg.dtype, cfg.block_length)
+        return project("out", cfg.hidden_size)(
+            out.reshape(x.shape[:2] + (-1,)))
+
+
+class DecoderLayer(nn.Module):
+    config: SdarConfig
+    attention_fn: Callable | None = None
+
+    @nn.compact
+    def __call__(self, x, positions):
+        cfg = self.config
+        with annotate_collective(SCOPE_BLOCK_NORM):
+            n1 = RMSNorm(cfg.rms_norm_eps, name="ln_attn")(x).astype(
+                cfg.dtype)
+        with annotate_collective(SCOPE_BLOCK_ATTN_PROJ):
+            attn = TwoStreamAttention(cfg, self.attention_fn,
+                                      name="attention")(n1, positions)
+        with annotate_collective(SCOPE_BLOCK_NORM):
+            x = x + attn
+            n2 = RMSNorm(cfg.rms_norm_eps, name="ln_moe")(x)
+        # norm_topk_prob: softmax over the 128, top 8, renormalised, which
+        # is the softmax over the eight picked logits. A row of the doubled
+        # stream is one routing group.
+        out = SparseExperts(cfg, gates_over_picks=True, name="moe")(n2)
+        with annotate_collective(SCOPE_BLOCK_NORM):
+            return x + out
+
+
+class Sdar(nn.Module):
+    """Call: ``model.apply(vars, noisy_ids, clean_ids)``, both ``[B, S]``
+    -> the noisy positions' logits ``[B, S, V]`` in float32."""
+
+    config: SdarConfig = SDAR_30B_A3B
+    attention_fn: Callable | None = None
+
+    @nn.compact
+    def __call__(self, noisy_ids, clean_ids):
+        cfg = self.config
+        seq_len = noisy_ids.shape[1]
+        layer = DecoderLayer
+        if cfg.remat:
+            layer = nn.remat(DecoderLayer,
+                             policy=save_kernels_and_projections)
+        # Two streams side by side, each counting its positions from zero.
+        positions = jnp.concatenate([jnp.arange(seq_len)] * 2)
+        with annotate_collective(SCOPE_BLOCK_EMBED):
+            x = nn.Embed(cfg.vocab_size, cfg.hidden_size,
+                         param_dtype=jnp.float32, name="token_embeddings")(
+                jnp.concatenate([noisy_ids, clean_ids], 1)).astype(cfg.dtype)
+        for i in range(cfg.num_layers):
+            x = layer(cfg, self.attention_fn, name=f"layer_{i}")(
+                x, positions)
+        with annotate_collective(SCOPE_BLOCK_HEAD):
+            # The head reads the noisy half: only its positions are scored.
+            x = RMSNorm(cfg.rms_norm_eps, name="ln_out")(
+                x[:, :seq_len]).astype(cfg.dtype)
+            # bf16 in, f32 out on the MXU, as models/bert.py's head.
+            head = self.param("lm_head", nn.initializers.lecun_normal(),
+                              (cfg.hidden_size, cfg.vocab_size), jnp.float32)
+            return jax.lax.dot_general(
+                x, head.astype(cfg.dtype), (((x.ndim - 1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+
+def noisy_batch(key, clean_ids, block_length: int, mask_id: int):
+    """One block-diffusion batch from ``clean_ids [R, S]``: ``{"clean",
+    "noisy", "weight"}``, each ``[R, S]``. Every block of a row draws its
+    noise level ``t ~ U[1 / block_length, 1]`` (the linear schedule
+    clipped from below, under which a block masks one token in expectation
+    at the least), every position is replaced by ``mask_id`` with its
+    block's probability, and a masked position's loss weight is ``1 / t``
+    (others 0). Sets ``hvd_diffusion_masked_share_last`` when it runs, by
+    a host callback: the share is a run-time value."""
+    from .. import metrics
+
+    rows, seq_len = clean_ids.shape
+    if seq_len % block_length:
+        raise ValueError(
+            f"{seq_len} tokens are no whole blocks of {block_length}")
+    level_key, mask_key = jax.random.split(key)
+    level = jax.random.uniform(
+        level_key, (rows, seq_len // block_length), jnp.float32,
+        1.0 / block_length, 1.0)
+    level = jnp.repeat(level, block_length, axis=1)
+    masked = jax.random.uniform(mask_key, (rows, seq_len)) < level
+    jax.debug.callback(
+        lambda share: metrics.DIFFUSION_MASKED_SHARE_LAST.set(float(share)),
+        masked.mean())
+    return {"clean": clean_ids,
+            "noisy": jnp.where(masked, mask_id, clean_ids),
+            "weight": jnp.where(masked, 1.0 / level, 0.0)}
+
+
+def block_diffusion_loss(model: Sdar, params, batch):
+    """The weighted denoising cross entropy of a ``noisy_batch``: a masked
+    position is scored on its own clean token (labels in place, no shift),
+    weighted by ``1 / t`` of its block, and the sum is divided by all ``R
+    S`` positions. The source's config has no auxiliary-loss coefficient,
+    so there is none."""
+    logits = model.apply({"params": params}, batch["noisy"], batch["clean"])
+    with annotate_collective(SCOPE_BLOCK_HEAD):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        picked = jnp.take_along_axis(logp, batch["clean"][..., None], -1)
+        return -(batch["weight"] * picked[..., 0]).mean()

@@ -58,7 +58,9 @@ from ..attribution import (SCOPE_BLOCK_ATTN_PROJ, SCOPE_BLOCK_EMBED,
 from ..ops.attention import flash_attention
 from ..parallel import moe
 from ..profiler import annotate_collective
-from .olmoe import RMSNorm, _record_slots, rope
+from .olmoe import (RMSNorm, SparseExperts, rope,  # noqa: F401
+                    routing_stats, take_expert_window)
+from .recompute import save_kernels_and_projections
 
 PERIOD = (0, 1, 1, 1)  # one period of both layouts: full + NoPE, then 3 x
 
@@ -193,50 +195,6 @@ class GroupedAttention(nn.Module):
             out.reshape(x.shape[:2] + (-1,)))
 
 
-class SparseReGLU(nn.Module):
-    """This model's window of the experts, on picks the router made before
-    attention: ``(tokens [B, S, D], logits [B, S, num_experts]) ->`` the
-    experts' weighted outputs ``[B, S, D]`` (no residual)."""
-
-    config: SmallThinkerConfig
-
-    @nn.compact
-    def __call__(self, x, logits):
-        cfg = self.config
-        hidden, width, here = (cfg.hidden_size, cfg.intermediate_size,
-                               cfg.experts_held)
-        stacked = nn.initializers.lecun_normal(batch_axis=(0,))
-        w_gate = self.param("experts_gate", stacked, (here, hidden, width),
-                            jnp.float32)
-        w_up = self.param("experts_up", stacked, (here, hidden, width),
-                          jnp.float32)
-        w_down = self.param("experts_down", stacked, (here, width, hidden),
-                            jnp.float32)
-        capacity = cfg.capacity(x.shape[1])
-        _record_slots(here, capacity, cfg.top_k)
-
-        def one_sequence(tokens, logits):
-            send, expert, pos, keep, gate, counts = moe.route_to_capacity(
-                tokens.astype(cfg.dtype), logits, cfg.num_experts, capacity,
-                top_k=cfg.top_k, first_expert=cfg.first_expert,
-                experts_here=here, gates_over_picks=True)
-            back = moe.gated_expert_ffn(
-                w_gate.astype(cfg.dtype), w_up.astype(cfg.dtype),
-                w_down.astype(cfg.dtype), send[..., :hidden],
-                activation=jax.nn.relu)
-            out = moe.combine_top_k(back, expert, pos, keep, gate,
-                                    cfg.first_expert)
-            in_window = (expert >= cfg.first_expert) & (
-                expert < cfg.first_expert + here)
-            return out, counts, jnp.sum(in_window & ~keep)
-
-        out, counts, dropped = jax.vmap(one_sequence)(x, logits)
-        self.sow("intermediates", "routing",
-                 {"load": counts.sum(0), "dropped": dropped.sum(),
-                  "pairs": counts.sum() + dropped.sum()})
-        return out
-
-
 class DecoderLayer(nn.Module):
     config: SmallThinkerConfig
     windowed: bool
@@ -263,24 +221,12 @@ class DecoderLayer(nn.Module):
         with annotate_collective(SCOPE_BLOCK_NORM):
             x = x + attn
             n2 = RMSNorm(cfg.rms_norm_eps, name="ln_moe")(x)
-        out = SparseReGLU(cfg, name="moe")(n2, logits)
+        # ReLU-gated experts on the picks made before attention, the gates
+        # a softmax over the picked logits.
+        out = SparseExperts(cfg, activation=jax.nn.relu,
+                            gates_over_picks=True, name="moe")(n2, logits)
         with annotate_collective(SCOPE_BLOCK_NORM):
             return x + out
-
-
-def save_kernels_and_projections(prim, *args, **params) -> bool:
-    """The ``jax.checkpoint`` policy of a recomputed layer. Beside its
-    input the forward pass keeps what a Pallas kernel returned (the only
-    kernel of a layer's forward pass is the flash forward kernel, whose
-    output and log-sum-exp are the residuals the dq and dkv kernels want,
-    so it never runs again) and the results of the matrix products without
-    a batch dimension (the four attention projections and the router:
-    0.2 GiB a layer at 16,384 tokens for 4 ms of recomputation each).
-    Norms, RoPE, the slots' gathers, the experts' batched products and the
-    combine are computed again."""
-    return prim.name == "pallas_call" or (
-        jax.checkpoint_policies.dots_with_no_batch_dims_saveable(
-            prim, *args, **params))
 
 
 class SmallThinker(nn.Module):
@@ -324,37 +270,3 @@ def causal_lm_loss(model: SmallThinker, params, tokens):
         logp = jax.nn.log_softmax(logits, axis=-1)
         return -jnp.take_along_axis(
             logp, tokens[:, 1:, None], axis=-1).mean()
-
-
-def take_expert_window(params, share: SmallThinkerConfig):
-    """The parameters ``share`` holds (its ``experts_here`` experts from
-    ``first_expert`` on), cut out of the tree of the same model with all
-    its experts: the stacked expert weights lose the other experts' rows;
-    attention, router, norms, embedding and head are every window's
-    alike."""
-    first, last = share.first_expert, share.first_expert + share.experts_held
-    out = dict(params)
-    for i in range(share.num_layers):
-        layer = dict(params[f"layer_{i}"])
-        layer["moe"] = {name: leaf[first:last]
-                        for name, leaf in layer["moe"].items()}
-        out[f"layer_{i}"] = layer
-    return out
-
-
-def routing_stats(model: SmallThinker, params, input_ids):
-    """What the routing did with ``input_ids [B, S]``, layer by layer, as
-    ``models.olmoe.routing_stats``: ``{"load": [layers, experts_here],
-    "dropped": [layers], "dropped_share": [layers]}``. A program of its
-    own, without recomputation; jit it."""
-    plain = SmallThinker(dataclasses.replace(model.config, remat=False),
-                         model.attention_fn)
-    _, state = plain.apply({"params": params}, input_ids,
-                           mutable=["intermediates"])
-    layers = [state["intermediates"][f"layer_{i}"]["moe"]["routing"][0]
-              for i in range(model.config.num_layers)]
-    load = jnp.stack([layer["load"] for layer in layers])
-    dropped = jnp.stack([layer["dropped"] for layer in layers])
-    pairs = jnp.stack([layer["pairs"] for layer in layers])
-    return {"load": load, "dropped": dropped,
-            "dropped_share": dropped / jnp.maximum(pairs, 1)}

@@ -70,6 +70,19 @@ HBM, and the dk/dv kernel sums over a group's query heads in its float32
 accumulators (a grid axis of its own inside the K block's reduction). The
 gauge ``hvd_attn_kv_group_last`` says the group. Both go through the
 multi-tile kernels, whatever the length.
+
+A causal call may round its diagonal to *blocks* instead
+(``block_length=B``): query ``i`` sees the keys of the blocks up to its own
+(``j // B <= i // B``) or, with ``before_block``, of the blocks before it.
+``B`` divides tiles and offsets, so the tile plan is the causal one and only
+the mask inside the diagonal tiles differs. ``block_diffusion_attention``
+puts the two together for block-diffusion training, whose step attends over
+a noisy and a clean copy of a sequence side by side: two calls over the
+clean keys, each on the grid of an ``S x S`` causal call, and the noisy
+queries' own block of ``B`` keys in plain XLA, merged through the
+log-sum-exp. No array of ``2S x 2S`` exists. Under the scope
+``hvd.attn.blockdiff``; ``hvd_attn_tiles_last{kind=blockdiff_*}`` counts
+both calls.
 """
 
 from __future__ import annotations
@@ -82,8 +95,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..attribution import (SCOPE_ATTN_BWD, SCOPE_ATTN_FWD,
-                           SCOPE_ATTN_WINDOW)
+from ..attribution import (SCOPE_ATTN_BLOCKDIFF, SCOPE_ATTN_BWD,
+                           SCOPE_ATTN_FWD, SCOPE_ATTN_WINDOW)
 
 # Every ``pallas_call`` here carries this one name. XLA names a custom
 # call's instruction after the innermost component of its name stack, a
@@ -354,44 +367,67 @@ def _group_specs(kernel, qr, block_q, block_k, slice_counts, heads=None):
             row_spec, rows)
 
 
-def _causal_mask(qi, j, block_q, block_k, q_offset, k_offset, window=None):
+def _causal_mask(qi, j, block_q, block_k, q_offset, k_offset, window=None,
+                 blocks=None):
     qpos = q_offset + qi * block_q + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0)
     kpos = k_offset + j * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1)
+    if blocks is not None:
+        # The diagonal rounded to a block of ``length`` positions: down to
+        # the query's block's first position (the blocks before its own),
+        # or up past its last (its own block too).
+        length, before = blocks
+        own = qpos - jax.lax.rem(qpos, length)
+        return kpos < (own if before else own + length)
     if window is None:
         return qpos >= kpos
     return (qpos >= kpos) & (qpos - kpos < window)
 
 
-def _tile_visible(qi, kj, block_q, block_k, q_offset, k_offset, window=None):
+def _behind(blocks) -> int:
+    """Positions by which a block mask pulls the tile plan's diagonal
+    back: a block's length where a query sees only the blocks *before* its
+    own, none where it sees its own as well (tiles and offsets are whole
+    blocks, so rounding the diagonal up moves no tile: the causal plan)."""
+    return blocks[0] if blocks is not None and blocks[1] else 0
+
+
+def _tile_visible(qi, kj, block_q, block_k, q_offset, k_offset, window=None,
+                  behind=0):
     """Whether the causal mask leaves anything of tile (q block ``qi``, k
-    block ``kj``): its last query is at or after its first key and, under
-    a ``window``, its first query is less than ``window`` past its last
-    key (the differences ``i - j`` of a tile are a run of integers, so the
-    two ends decide). The multi-tile causal kernels skip every other tile,
-    where what the masked step adds to its accumulators is exactly zero.
-    Plain arithmetic, so Python ints, numpy grids and traced program ids
-    all do."""
-    ahead = q_offset + (qi + 1) * block_q - 1 >= k_offset + kj * block_k
+    block ``kj``): its last query is at or after its first key (``behind``
+    positions after it, under a block mask that hides a query's own block:
+    ``_behind``) and, under a ``window``, its first query is less than
+    ``window`` past its last key (the differences ``i - j`` of a tile are
+    a run of integers, so the two ends decide). The multi-tile causal
+    kernels skip every other tile, where what the masked step adds to its
+    accumulators is exactly zero. Plain arithmetic, so Python ints, numpy
+    grids and traced program ids all do."""
+    last_query = q_offset + (qi + 1) * block_q - 1
+    if behind:
+        last_query = last_query - behind
+    ahead = last_query >= k_offset + kj * block_k
     if window is None:
         return ahead
     return ahead & (q_offset + qi * block_q
                     - (k_offset + (kj + 1) * block_k - 1) < window)
 
 
-def _last_k_block(qi, num_kb, block_q, block_k, q_offset, k_offset):
+def _last_k_block(qi, num_kb, block_q, block_k, q_offset, k_offset,
+                  behind=0):
     """The last k block of which q block ``qi`` sees anything, clamped into
     the grid: the index maps of the K-innermost calls stop there, so the
     steps past it name a block that is already resident and fetch nothing."""
-    last = (q_offset - k_offset + (qi + 1) * block_q - 1) // block_k
+    last = (q_offset - k_offset - behind + (qi + 1) * block_q - 1) // block_k
     return jnp.clip(last, 0, num_kb - 1)
 
 
-def _first_q_block(kj, num_qb, block_q, block_k, q_offset, k_offset):
+def _first_q_block(kj, num_qb, block_q, block_k, q_offset, k_offset,
+                   behind=0):
     """The first q block that sees anything of k block ``kj``, clamped into
     the grid: ``_last_k_block``'s mirror for the Q-innermost dk/dv call."""
-    first = (k_offset - q_offset + kj * block_k) // block_q
+    first = (k_offset - q_offset + behind + kj * block_k) // block_q
     return jnp.clip(first, 0, num_qb - 1)
 
 
@@ -413,7 +449,7 @@ def _last_q_block(kj, num_qb, block_q, block_k, q_offset, k_offset, window):
 
 
 def _tile_plan(causal, num_qb, num_kb, block_q, block_k, q_offset, k_offset,
-               window=None):
+               window=None, behind=0):
     """At trace time, by the kernels' own predicate over the whole tile
     grid: ``(pairs, band_kb, band_qb)``, the (q, k) tile pairs a slice of a
     multi-tile call computes and the extent of its grids' innermost
@@ -428,7 +464,7 @@ def _tile_plan(causal, num_qb, num_kb, block_q, block_k, q_offset, k_offset,
         return num_qb * num_kb, num_kb, num_qb
     visible = _tile_visible(
         np.arange(num_qb)[:, None], np.arange(num_kb)[None, :], block_q,
-        block_k, q_offset, k_offset, window)
+        block_k, q_offset, k_offset, window, behind)
     pairs = int(visible.sum())
     if window is None:
         return pairs, num_kb, num_qb
@@ -437,7 +473,7 @@ def _tile_plan(causal, num_qb, num_kb, block_q, block_k, q_offset, k_offset,
 
 
 def _record_tiles(causal, num_qb, num_kb, block_q, block_k, q_offset,
-                  k_offset, window=None, group=1):
+                  k_offset, window=None, group=1, behind=0):
     """At trace time, as ``optimizer._record_flush`` does for the wire:
     the (q, k) tile pairs a slice of this multi-tile call computes and
     skips, the steps its K-innermost grids take a slice (the dk/dv
@@ -447,7 +483,8 @@ def _record_tiles(causal, num_qb, num_kb, block_q, block_k, q_offset,
     from .. import metrics
 
     computed, band_kb, band_qb = _tile_plan(
-        causal, num_qb, num_kb, block_q, block_k, q_offset, k_offset, window)
+        causal, num_qb, num_kb, block_q, block_k, q_offset, k_offset, window,
+        behind)
     metrics.ATTN_TILES_LAST.set(computed, kind="computed")
     metrics.ATTN_TILES_LAST.set(num_qb * num_kb - computed, kind="skipped")
     metrics.ATTN_TILES_LAST.set(num_qb * band_kb, kind="grid")
@@ -458,7 +495,8 @@ def _record_tiles(causal, num_qb, num_kb, block_q, block_k, q_offset,
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                       acc_scr, *, causal: bool, scale: float, block_q: int,
                       block_k: int, q_offset: int, k_offset: int,
-                      window: int | None = None, num_kb: int):
+                      window: int | None = None, num_kb: int,
+                      blocks: tuple | None = None):
     # Grid (BH, num_q_blocks, K steps), K innermost: only ONE [block_k, D]
     # K/V tile is VMEM-resident per step (long sequences never exceed
     # VMEM); scratch carries (m, l, acc) across the K dimension. A step is
@@ -480,7 +518,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     # _init and _finalize_block stay outside: the last steps of a q block
     # are the skipped ones, and it still has to write its output.
     visible = _tile_visible(qi, j, block_q, block_k, q_offset, k_offset,
-                            window) if causal else True
+                            window, _behind(blocks)) if causal else True
     if window is not None:
         visible &= j < num_kb  # a step may pass the sequence's last block
 
@@ -500,7 +538,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         ) * scale  # [block_q, block_k]
         if causal:
             mask = _causal_mask(qi, j, block_q, block_k, q_offset, k_offset,
-                                window)
+                                window, blocks)
             s = jnp.where(mask, s, NEG_INF)
         m_prev = m_scr[:, 0]
         m_new = jnp.maximum(m_prev, s.max(axis=-1))
@@ -535,7 +573,8 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                      glse_ref, dq_ref, dq_scr, *, causal: bool,
                      scale: float, block_q: int, block_k: int,
                      q_offset: int, k_offset: int,
-                     window: int | None = None, num_kb: int):
+                     window: int | None = None, num_kb: int,
+                     blocks: tuple | None = None):
     """dQ pass. Grid (BH, num_q_blocks, K steps), K innermost as in the
     forward; accumulates dq for one Q tile across the K tiles it sees.
 
@@ -555,7 +594,7 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
     visible = _tile_visible(qi, j, block_q, block_k, q_offset, k_offset,
-                            window) if causal else True
+                            window, _behind(blocks)) if causal else True
     if window is not None:
         visible &= j < num_kb  # a step may pass the sequence's last block
 
@@ -579,7 +618,7 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         ) * scale
         if causal:
             mask = _causal_mask(qi, j, block_q, block_k, q_offset, k_offset,
-                                window)
+                                window, blocks)
             s = jnp.where(mask, s, NEG_INF)
         p = jnp.exp(s - lse[:, None])
         dp = jax.lax.dot_general(
@@ -602,7 +641,7 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       causal: bool, scale: float, block_q: int,
                       block_k: int, q_offset: int, k_offset: int,
                       window: int | None = None, group: int = 1,
-                      num_qb: int):
+                      num_qb: int, blocks: tuple | None = None):
     """dK/dV pass. Grid (BH, num_k_blocks, Q steps), Q innermost;
     accumulates dk, dv for one K/V tile across the Q tiles that see it. A
     step is a q block, and under a window the q block that many past the
@@ -621,6 +660,7 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     else:
         head, step = pl.program_id(2), pl.program_id(3)
         steps = pl.num_programs(3)
+    behind = _behind(blocks)
     i = step if window is None else step + _first_q_block(
         kj, num_qb, block_q, block_k, q_offset, k_offset)
 
@@ -636,7 +676,7 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     # The first q tiles of a k block are the skipped ones: _init zeroes
     # dk_scr / dv_scr all the same.
     visible = _tile_visible(i, kj, block_q, block_k, q_offset, k_offset,
-                            window) if causal else True
+                            window, behind) if causal else True
     if window is not None:
         visible &= i < num_qb  # a step may pass the sequence's last block
 
@@ -658,7 +698,7 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         ) * scale  # [block_q, block_k]
         if causal:
             mask = _causal_mask(i, kj, block_q, block_k, q_offset, k_offset,
-                                window)
+                                window, blocks)
             s = jnp.where(mask, s, NEG_INF)
         p = jnp.exp(s - lse[:, None])  # [block_q, block_k]
         # dV_j += P^T @ dO (P rounds to the stored dtype for the MXU pass)
@@ -803,9 +843,11 @@ def _flash_dqkv_from_out_kernel(q_ref, k_ref, v_ref, do_ref, out_ref,
 
 def _kind_scope(window):
     """A windowed call's kernels sit under ``hvd.attn.window`` as well,
-    outside ``hvd.attn.fwd`` / ``hvd.attn.bwd``: a model with both kinds of
-    layer tells them apart in its step's text, and whoever sums by the
-    innermost phase scope still finds forward and backward."""
+    outside ``hvd.attn.fwd`` / ``hvd.attn.bwd``: a model with several
+    kinds of layer tells them apart in its step's text, and whoever sums
+    by the innermost phase scope still finds forward and backward. (A
+    block-diffusion step's are under ``hvd.attn.blockdiff``, which
+    ``block_diffusion_attention`` opens around its kernels and its glue.)"""
     from ..profiler import annotate_collective
 
     if window is None:
@@ -814,12 +856,12 @@ def _kind_scope(window):
 
 
 def _fwd_call(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
-              interpret, window=None):
+              interpret, window=None, blocks=None):
     from ..profiler import annotate_collective
 
     with _kind_scope(window), annotate_collective(SCOPE_ATTN_FWD):
         return _fwd_kernels(qr, kr, vr, causal, block_q, block_k, q_offset,
-                            k_offset, interpret, window)
+                            k_offset, interpret, window, blocks)
 
 
 def _kv_head(bh, group):
@@ -830,7 +872,7 @@ def _kv_head(bh, group):
 
 
 def _kv_index_map(causal, num_kb, block_q, block_k, q_offset, k_offset,
-                  window=None, group=1):
+                  window=None, group=1, behind=0):
     """K/V block of grid step (bh, q block i, K step j), K innermost. A
     causal call stops at the last tile q block ``i`` computes: a
     ``pl.when`` alone would still have the pipeline fetch the skipped
@@ -842,7 +884,8 @@ def _kv_index_map(causal, num_kb, block_q, block_k, q_offset, k_offset,
         return lambda bh, i, j: (_kv_head(bh, group), j, 0)
 
     def block(i, j):
-        last = _last_k_block(i, num_kb, block_q, block_k, q_offset, k_offset)
+        last = _last_k_block(i, num_kb, block_q, block_k, q_offset, k_offset,
+                             behind)
         if window is None:
             return jnp.minimum(j, last)
         return jnp.minimum(j + _first_k_block(
@@ -852,7 +895,7 @@ def _kv_index_map(causal, num_kb, block_q, block_k, q_offset, k_offset,
 
 
 def _q_block(causal, num_qb, block_q, block_k, q_offset, k_offset,
-             window=None):
+             window=None, behind=0):
     """``(k block j, Q step i) ->`` the Q-side block (q, dO) of a grid
     step of the Q-innermost dk/dv call: a causal call starts at the first
     tile k block ``j`` computes (``_kv_index_map``'s mirror); under a
@@ -862,7 +905,7 @@ def _q_block(causal, num_qb, block_q, block_k, q_offset, k_offset,
 
     def block(j, i):
         first = _first_q_block(j, num_qb, block_q, block_k, q_offset,
-                               k_offset)
+                               k_offset, behind)
         if window is None:
             return jnp.maximum(i, first)
         return jnp.minimum(i + first, _last_q_block(
@@ -871,13 +914,14 @@ def _q_block(causal, num_qb, block_q, block_k, q_offset, k_offset,
     return block
 
 
-def _single_tile(Sq, Sk, block_q, block_k, window, group) -> bool:
+def _single_tile(Sq, Sk, block_q, block_k, window, group,
+                 blocks=None) -> bool:
     """The direct-softmax forward and the fused backward take one-tile
-    sequences with a head of keys and values a query head and no window;
-    anything else is the multi-tile kernels', on a grid of one tile if
-    need be."""
+    sequences with a head of keys and values a query head, no window and
+    no block mask; anything else is the multi-tile kernels', on a grid of
+    one tile if need be."""
     return (Sq == block_q and Sk == block_k and window is None
-            and group == 1)
+            and group == 1 and blocks is None)
 
 
 def _single_tile_fwd(qr, kr, vr, causal, block_q, block_k, q_offset,
@@ -944,12 +988,12 @@ def _single_tile_bwd(qr, kr, vr, do, lse, delta, g_lse, causal, block_q,
 
 
 def _fwd_kernels(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
-                 interpret, window=None):
+                 interpret, window=None, blocks=None):
     BH, Sq, D = qr.shape
     Sk = kr.shape[1]
     group = BH // kr.shape[0]
     scale = 1.0 / (D ** 0.5)
-    if _single_tile(Sq, Sk, block_q, block_k, window, group):
+    if _single_tile(Sq, Sk, block_q, block_k, window, group, blocks):
         return _single_tile_fwd(qr, kr, vr, causal, block_q, block_k,
                                 q_offset, k_offset, interpret)
     num_qb, num_kb = Sq // block_q, Sk // block_k
@@ -957,12 +1001,15 @@ def _fwd_kernels(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
         _flash_fwd_kernel, causal=causal, scale=scale,
         block_q=block_q, block_k=block_k,
         q_offset=q_offset, k_offset=k_offset, window=window, num_kb=num_kb,
+        blocks=blocks,
     )
+    behind = _behind(blocks)
     band_kb, _ = _record_tiles(causal, num_qb, num_kb, block_q, block_k,
-                               q_offset, k_offset, window, group)
+                               q_offset, k_offset, window, group, behind)
     kv_spec = pl.BlockSpec((1, block_k, D),
                            _kv_index_map(causal, num_kb, block_q, block_k,
-                                         q_offset, k_offset, window, group))
+                                         q_offset, k_offset, window, group,
+                                         behind))
     return pl.pallas_call(
         kernel,
         grid=(BH, num_qb, band_kb),
@@ -990,16 +1037,16 @@ def _fwd_kernels(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
 
 
 def _flash_bwd(causal, block_q, block_k, q_offset, k_offset, interpret,
-               res, g, g_lse=None, window=None):
+               res, g, g_lse=None, window=None, blocks=None):
     from ..profiler import annotate_collective
 
     with _kind_scope(window), annotate_collective(SCOPE_ATTN_BWD):
         return _bwd_kernels(causal, block_q, block_k, q_offset, k_offset,
-                            interpret, res, g, g_lse, window)
+                            interpret, res, g, g_lse, window, blocks)
 
 
 def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
-                 res, g, g_lse, window=None):
+                 res, g, g_lse, window=None, blocks=None):
     qr, kr, vr, out, lse = res
     BH, Sq, D = qr.shape
     BHkv, Sk = kr.shape[:2]
@@ -1015,18 +1062,20 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)[:, None, :]  # [BH, 1, Sq]
 
-    if _single_tile(Sq, Sk, block_q, block_k, window, group):
+    if _single_tile(Sq, Sk, block_q, block_k, window, group, blocks):
         return _single_tile_bwd(qr, kr, vr, do, lse, delta, g_lse, causal,
                                 block_q, block_k, q_offset, k_offset,
                                 interpret)
 
     num_qb, num_kb = Sq // block_q, Sk // block_k
+    behind = _behind(blocks)
     band_kb, band_qb = _record_tiles(causal, num_qb, num_kb, block_q,
                                      block_k, q_offset, k_offset, window,
-                                     group)
+                                     group, behind)
     kv_spec = pl.BlockSpec((1, block_k, D),
                            _kv_index_map(causal, num_kb, block_q, block_k,
-                                         q_offset, k_offset, window, group))
+                                         q_offset, k_offset, window, group,
+                                         behind))
     q_specs = [
         pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
         kv_spec,
@@ -1040,7 +1089,7 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
         functools.partial(
             _flash_dq_kernel, causal=causal, scale=scale, block_q=block_q,
             block_k=block_k, q_offset=q_offset, k_offset=k_offset,
-            window=window, num_kb=num_kb,
+            window=window, num_kb=num_kb, blocks=blocks,
         ),
         grid=(BH, num_qb, band_kb),
         in_specs=q_specs,
@@ -1052,7 +1101,7 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
     )(qr, kr, vr, do, lse, delta, g_lse)
 
     q_block = _q_block(causal, num_qb, block_q, block_k, q_offset, k_offset,
-                       window)
+                       window, behind)
     if group == 1:
         grid = (BH, num_kb, band_qb)
         q_spec = pl.BlockSpec((1, block_q, D),
@@ -1074,7 +1123,7 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
         functools.partial(
             _flash_dkv_kernel, causal=causal, scale=scale, block_q=block_q,
             block_k=block_k, q_offset=q_offset, k_offset=k_offset,
-            window=window, group=group, num_qb=num_qb,
+            window=window, group=group, num_qb=num_qb, blocks=blocks,
         ),
         grid=grid,
         in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec,
@@ -1095,28 +1144,29 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
 
 
 # custom_vjp over the (out, lse)-returning primal so residuals are exact.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def _flash_with_lse(qr, kr, vr, causal, block_q, block_k, q_offset,
-                    k_offset, interpret, window=None):
+                    k_offset, interpret, window=None, blocks=None):
     return _fwd_call(qr, kr, vr, causal, block_q, block_k, q_offset,
-                     k_offset, interpret, window)
+                     k_offset, interpret, window, blocks)
 
 
 def _flash_with_lse_fwd(qr, kr, vr, causal, block_q, block_k, q_offset,
-                        k_offset, interpret, window):
+                        k_offset, interpret, window, blocks):
     out, lse = _fwd_call(qr, kr, vr, causal, block_q, block_k, q_offset,
-                         k_offset, interpret, window)
+                         k_offset, interpret, window, blocks)
     return (out, lse), (qr, kr, vr, out, lse)
 
 
 def _flash_with_lse_bwd(causal, block_q, block_k, q_offset, k_offset,
-                        interpret, window, res, gs):
+                        interpret, window, blocks, res, gs):
     g, g_lse = gs
     # float0 cotangent (lse unused downstream) -> zeros.
     if g_lse is None or g_lse.dtype == jax.dtypes.float0:
         g_lse = None
     return _flash_bwd(causal, block_q, block_k, q_offset, k_offset,
-                      interpret, res, g, g_lse, window)
+                      interpret, res, g, g_lse, window, blocks)
 
 
 _flash_with_lse.defvjp(_flash_with_lse_fwd, _flash_with_lse_bwd)
@@ -1156,11 +1206,12 @@ _flash_tokens_major.defvjp(_flash_tokens_major_fwd, _flash_tokens_major_bwd)
 
 
 def _prepare_flash(q, k, v, causal, block_q, block_k, q_offset, k_offset,
-                   window=None):
+                   window=None, blocks=None):
     """Shared validation + block selection for the flash entry points —
     one implementation so the guards cannot drift between them:
     ``(block_q, block_k, window)``, the window ``None`` where it hides
-    nothing the causal mask leaves."""
+    nothing the causal mask leaves. ``blocks`` is a block mask's
+    ``(block length, before the own block)`` or ``None``."""
     Sq, Sk = q.shape[2], k.shape[2]
     if not (q.dtype == k.dtype == v.dtype):
         # The kernels run stored-dtype matmuls (f32 MXU accumulation);
@@ -1199,32 +1250,53 @@ def _prepare_flash(q, k, v, causal, block_q, block_k, q_offset, k_offset,
                 "causal=True and a window of at least 1")
         if window >= q_offset + Sq - k_offset:
             window = None  # no query is that far past any key
+    if blocks is not None:
+        length = blocks[0]
+        if not causal or window is not None or length < 1:
+            raise ValueError(
+                f"block_length={length} rounds the causal diagonal to "
+                "blocks of that many positions: it needs causal=True, a "
+                "length of at least 1 and no window")
+        if any(size % length for size in (block_q, block_k, q_offset,
+                                          k_offset)):
+            raise ValueError(
+                f"block_length={length} must divide the tiles ({block_q}, "
+                f"{block_k}) and the offsets ({q_offset}, {k_offset}): the "
+                "kernels take whole blocks a tile")
     return block_q, block_k, window
 
 
 def _flash(q, k, v, causal, block_q, block_k, q_offset, k_offset, interpret,
-           window):
+           window, block_length=None, before_block=False):
     """``[B, H, Sq, D]``, ``[B, KV heads, Sk, D]`` twice -> ``(out [B, H,
     Sq, D], lse [B, H, Sq])``: both entry points' one way to the kernels."""
     B, H, Sq, D = q.shape
+    if before_block and block_length is None:
+        raise ValueError("before_block=True hides a query's own block: it "
+                         "needs a block_length")
+    blocks = None if block_length is None else (block_length,
+                                                bool(before_block))
     block_q, block_k, window = _prepare_flash(
-        q, k, v, causal, block_q, block_k, q_offset, k_offset, window)
+        q, k, v, causal, block_q, block_k, q_offset, k_offset, window,
+        blocks)
     out, lse = _flash_with_lse(
         q.reshape(B * H, Sq, D), k.reshape((-1,) + k.shape[2:]),
         v.reshape((-1,) + v.shape[2:]), causal, block_q, block_k, q_offset,
-        k_offset, interpret, window)
+        k_offset, interpret, window, blocks)
     return out.reshape(B, H, Sq, D), lse.reshape(B, H, Sq)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "block_q", "block_k", "q_offset", "k_offset",
-                     "interpret", "window"),
+                     "interpret", "window", "block_length", "before_block"),
 )
 def flash_attention(q, k, v, causal: bool = False, block_q: int | None = None,
                     block_k: int | None = None, q_offset: int = 0,
                     k_offset: int = 0, interpret: bool = False,
-                    window: int | None = None):
+                    window: int | None = None,
+                    block_length: int | None = None,
+                    before_block: bool = False):
     """Pallas flash attention. q: [B, H, S, D], k, v: [B, KV heads, S, D]
     → [B, H, S, D].
 
@@ -1249,21 +1321,32 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int | None = None,
     have fewer heads than ``q``, a divisor of its count: query head ``n``
     reads key/value head ``n // (H // KV heads)``, and their gradients come
     back summed over the group, in the shape they came in.
+
+    ``block_length`` (static, needs ``causal`` and no window; it must
+    divide tiles and offsets): the diagonal rounded to blocks of that many
+    positions. Query ``i`` sees the keys ``j`` with ``j // block_length <=
+    i // block_length``, its own block whole, or with ``before_block`` only
+    ``j // block_length < i // block_length``, the blocks before its own (a
+    query of the first block then sees nothing: its row is zero, its
+    log-sum-exp ``LSE_MASKED``). The two masks of block-diffusion training
+    (:func:`block_diffusion_attention`); the tile plan is the causal one.
     """
     return _flash(q, k, v, causal, block_q, block_k, q_offset, k_offset,
-                  interpret, window)[0]
+                  interpret, window, block_length, before_block)[0]
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "block_q", "block_k", "q_offset", "k_offset",
-                     "interpret", "window"),
+                     "interpret", "window", "block_length", "before_block"),
 )
 def flash_attention_lse(q, k, v, causal: bool = False,
                         block_q: int | None = None,
                         block_k: int | None = None, q_offset: int = 0,
                         k_offset: int = 0, interpret: bool = False,
-                        window: int | None = None):
+                        window: int | None = None,
+                        block_length: int | None = None,
+                        before_block: bool = False):
     """Like :func:`flash_attention` but also returns the per-row
     logsumexp ``[B, H, Sq]`` (fp32) — the hook ring attention uses to
     merge per-shard partial attentions exactly:
@@ -1273,7 +1356,7 @@ def flash_attention_lse(q, k, v, causal: bool = False,
     propagates into the backward kernels (dS += P * g_lse), which is what
     makes logsumexp-merged schemes like ring-flash train exactly."""
     return _flash(q, k, v, causal, block_q, block_k, q_offset, k_offset,
-                  interpret, window)
+                  interpret, window, block_length, before_block)
 
 
 @functools.partial(
@@ -1322,3 +1405,155 @@ def flash_attention_tokens_major(q, k, v, num_heads: int,
     out = _flash(head_major(q), head_major(k), head_major(v), causal,
                  block_q, block_k, q_offset, k_offset, interpret, window)[0]
     return out.transpose(0, 2, 1, 3).reshape(B, Sq, width)
+
+
+# ---------------------------------------------------------------------------
+# Block-diffusion training: a noisy and a clean stream, three mask terms
+# ---------------------------------------------------------------------------
+
+
+def _keys_of_own_block(x, length):
+    """``x [B, KV heads, S, D]`` -> ``length`` arrays of that shape in
+    float32, the ``c``-th of which holds in row ``i`` row ``c`` of
+    ``i``'s block of ``x``: ``x[i - i % length + c]``. Row ``i`` is at
+    place ``r = i % length`` of its block, so that is ``x`` shifted by ``c
+    - r`` rows, picked by ``r``: shifts and selects of the *small* arrays
+    in the layout they have. (A reshape to ``[S / length, length, D]`` and
+    a repeat say the same, and make a TPU pad four rows to eight and copy
+    every query-sized array that meets them: 2.1 GB a layer.)"""
+    S = x.shape[2]
+    x = x.astype(jnp.float32)
+    place = (jnp.arange(S) % length)[:, None]
+
+    def shifted(d):  # row i holds x[i + d]; rows past the ends are unused
+        pad = jnp.zeros_like(x[:, :, :abs(d)])
+        if d > 0:
+            return jnp.concatenate([x[:, :, d:], pad], axis=2)
+        return jnp.concatenate([pad, x[:, :, :d]], axis=2) if d else x
+
+    shifts = {d: shifted(d) for d in range(1 - length, length)}
+    return [sum(jnp.where(place == r, shifts[c - r], 0.0)
+                for r in range(length)) for c in range(length)]
+
+
+def _own_block_attention(q, k, v, length):
+    """The block-diagonal term in plain XLA: ``q [B, KV heads, S, D]``,
+    one query head of every group, against the ``length`` keys and values
+    of its own block (``k``, ``v``: what ``_keys_of_own_block`` made of
+    them) -> ``(out [B, KV heads, S, D], lse [B, KV heads, S])`` in
+    float32. Four keys a query are no work for a kernel's tiles: the
+    query-sized arrays are read where they lie, row by row, multiplied
+    elementwise with key ``c`` of the row's block and summed along their
+    lanes."""
+    q = q.astype(jnp.float32)
+    s = [(q * k_c).sum(-1) / (q.shape[-1] ** 0.5) for k_c in k]
+    m = functools.reduce(jnp.maximum, s)
+    p = [jnp.exp(s_c - m) for s_c in s]
+    l = sum(p)
+    out = sum((p_c / l)[..., None] * v_c for p_c, v_c in zip(p, v))
+    return out, m + jnp.log(l)
+
+
+def _merge_own_block(q, k, v, past, lse_past, length):
+    """The noisy queries' two sources joined: ``past [B, H, S, D]`` and
+    ``lse_past [B, H, S]``, what the kernels made of the clean keys before
+    each query's block, merged through the log-sum-exp with the query's own
+    block of ``k``, ``v [B, KV heads, S, D]`` (``_own_block_attention``),
+    as ring attention merges its shards. A query of the first block has no
+    clean past: the kernel says so by its sentinel, which merges as minus
+    infinity. **A group's query heads one after another**, each against
+    the key/value heads as they are: broadcast over the group, XLA writes
+    every one of the ``2 x length`` small arrays out at the queries' size
+    (134 MB each at SDAR's shapes, forward and recomputed)."""
+    B, H, S, D = q.shape
+    kv_heads = k.shape[1]
+    group = H // kv_heads
+    keys, values = (_keys_of_own_block(x, length) for x in (k, v))
+
+    def by_group(x):  # [B, H, S, ...] -> group x [B, KV heads, S, ...]
+        # split, whose transpose is one concatenation (a slice a head's
+        # is a pad and an add of the whole array a head)
+        parts = jnp.split(x.reshape((B, kv_heads, group) + x.shape[2:]),
+                          group, axis=2)
+        return [part[:, :, 0] for part in parts]
+
+    merged = []
+    for q_g, past_g, before in zip(by_group(q), by_group(past),
+                                   by_group(lse_past)):
+        own, lse_own = _own_block_attention(q_g, keys, values, length)
+        before = jnp.where(before >= 0.5 * LSE_MASKED, NEG_INF, before)
+        lse = jnp.logaddexp(before, lse_own)
+        merged.append(
+            jnp.exp(before - lse)[..., None] * past_g.astype(jnp.float32)
+            + jnp.exp(lse_own - lse)[..., None] * own)
+    return jnp.stack(merged, axis=2).reshape(B, H, S, D).astype(q.dtype)
+
+
+def _record_blockdiff_tiles(S, block_length, block_q, block_k):
+    """At trace time, from the two calls' tile plans alone:
+    ``hvd_attn_tiles_last{kind=blockdiff_*}``, the (q, k) tile pairs of
+    the doubled stream's ``2S x 2S`` square that a slice computes and
+    skips, and the steps the two K-innermost grids take."""
+    from .. import metrics
+
+    block_q = block_q if block_q is not None else _auto_block(S)
+    block_k = block_k if block_k is not None else _auto_block(S)
+    num_qb, num_kb = S // block_q, S // block_k
+    plans = [_tile_plan(True, num_qb, num_kb, block_q, block_k, 0, 0,
+                        behind=behind) for behind in (0, block_length)]
+    computed = sum(pairs for pairs, _, _ in plans)
+    metrics.ATTN_TILES_LAST.set(computed, kind="blockdiff_computed")
+    metrics.ATTN_TILES_LAST.set(4 * num_qb * num_kb - computed,
+                                kind="blockdiff_skipped")
+    metrics.ATTN_TILES_LAST.set(sum(num_qb * band_kb
+                                    for _, band_kb, _ in plans),
+                                kind="blockdiff_grid")
+
+
+def block_diffusion_attention(q, k, v, block_length: int,
+                              block_q: int | None = None,
+                              block_k: int | None = None,
+                              interpret: bool = False):
+    """Attention of one block-diffusion training step (BD3-LMs,
+    arXiv:2503.09573) over the doubled stream ``[x_t ; x_0]``: ``q [B, H,
+    2S, D]``, ``k``, ``v [B, KV heads, 2S, D]``, the noisy half first, both
+    halves at positions ``0..S-1`` -> ``[B, H, 2S, D]``. With ``blk(i) =
+    pos(i) // block_length``:
+
+    * a clean query sees the clean keys of ``blk(j) <= blk(i)``;
+    * a noisy query sees the clean keys of ``blk(j) < blk(i)`` and the
+      noisy keys of its own block;
+    * a clean query sees no noisy key.
+
+    Two calls of the multi-tile kernels over the clean keys and values
+    alone, each on the causal tile plan of an ``S x S`` square (the mask
+    inside the diagonal tiles differs, no tile does), so no tile outside
+    the two triangles is computed or fetched and no ``[2S, 2S]`` array
+    exists. The noisy queries' second source, their own block, is
+    ``block_length`` keys a query: plain XLA (``_merge_own_block``),
+    merged with the kernels' result through the log-sum-exp as ring
+    attention merges its shards, differentiably on both sides. All of it
+    under ``hvd.attn.blockdiff``, which is opened here and nowhere else;
+    the gauge ``hvd_attn_tiles_last{kind=blockdiff_*}`` counts both calls
+    (``_record_blockdiff_tiles``)."""
+    from ..profiler import annotate_collective
+
+    S = q.shape[2] // 2
+    if q.shape[2] != 2 * S or k.shape[2] != 2 * S or S % block_length:
+        raise ValueError(
+            f"block-diffusion attention wants a noisy and a clean half of "
+            f"whole blocks of {block_length}; got q={q.shape}, k={k.shape}")
+    with annotate_collective(SCOPE_ATTN_BLOCKDIFF):
+        q_noisy, q_clean = q[:, :, :S], q[:, :, S:]
+        k_noisy, k_clean = k[:, :, :S], k[:, :, S:]
+        v_noisy, v_clean = v[:, :, :S], v[:, :, S:]
+        _record_blockdiff_tiles(S, block_length, block_q, block_k)
+        tiles = dict(block_q=block_q, block_k=block_k, interpret=interpret)
+        clean = flash_attention(q_clean, k_clean, v_clean, causal=True,
+                                block_length=block_length, **tiles)
+        past, lse_past = flash_attention_lse(
+            q_noisy, k_clean, v_clean, causal=True,
+            block_length=block_length, before_block=True, **tiles)
+        noisy = _merge_own_block(q_noisy, k_noisy, v_noisy, past, lse_past,
+                                 block_length)
+        return jnp.concatenate([noisy, clean], axis=2)

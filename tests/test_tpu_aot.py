@@ -371,3 +371,55 @@ def test_a_bert_layer_hands_the_kernels_what_its_projections_wrote(
             r"\w+\[([\d,]*)\]", owner.shape).group(1).split(",")
             if n) >= activation]
     assert not moved, moved
+
+
+def test_sdars_two_streams_compile_on_two_causal_grids(one_chip):
+    """One row of 8,192 clean tokens beside its noisy twin, 32 query heads
+    on 4 key/value heads of 128, blocks of 4, tiles of 512: two calls of
+    the multi-tile kernels over the clean keys, each on the whole 16 x 16
+    grid of an S x S causal call (never the 32 x 32 of the doubled stream),
+    six kernels under the name the cell's readers find them by and under
+    ``hvd.attn.blockdiff``; the block mask is an integer remainder in the
+    kernel, which the chip's compiler takes; and no array of the compiled
+    program holds two dimensions of the stream's length."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu import attribution, metrics, profiler
+    from horovod_tpu.models import sdar
+
+    def loss(q, k, v):
+        out = sdar.flash_attention_fn(q, k, v, jnp.bfloat16, 4)
+        return out.astype(jnp.float32).sum()
+
+    def shaped(heads):
+        return jax.ShapeDtypeStruct((1, 16384, heads, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        shaped(32), shaped(4), shaped(4))
+    tiles = {kind: int(metrics.ATTN_TILES_LAST.labels(kind=kind).get())
+             for kind in ("blockdiff_computed", "blockdiff_skipped",
+                          "blockdiff_grid")}
+    assert tiles == dict(blockdiff_computed=272, blockdiff_skipped=752,
+                         blockdiff_grid=512)
+    bodies = kernel_bodies(lowered.as_text())
+    grids = sorted(re.search(r"iteration_bounds = array<i64: ([^>]*)>", body)
+                   .group(1) for body in bodies)
+    assert grids == ["32, 16, 16"] * 4 + ["4, 16, 8, 16"] * 2
+    assert all(CLAMP.search(body) for body in bodies)
+    assert all("arith.remsi" in body for body in bodies)
+    compiled = lowered.compile().as_text()
+    found = kernel_instructions(compiled)
+    assert len(found) == 6
+    with open(os.path.join(REPO_ROOT, "benchmark", "layer_metrics",
+                           "blockdiff_attn_kernel_ms.json")) as f:
+        wanted = re.compile(json.load(f)["kernel_names"])
+    assert all(wanted.search(name) for name, _ in found), found
+    assert sorted(profiler.phase_of(scope) for _, scope in found) == [
+        "hvd.attn.bwd"] * 4 + ["hvd.attn.fwd"] * 2
+    assert all(attribution.SCOPE_ATTN_BLOCKDIFF in scope
+               for _, scope in found)
+    square = [shape for shape in re.findall(r"\[([0-9,]+)\]", compiled)
+              if sum(int(d) >= 8192 for d in shape.split(",")) >= 2]
+    assert not square, sorted(set(square))

@@ -9,8 +9,14 @@ at 15 causal single-tile slices, the same two kernels on tokens-major
 operands (``flash_attention_tokens_major``: ``[B, S, H * D]``, two heads of
 D64 or one of D128 a 128-lane block), at a multi-tile causal shape (S2048
 D128) and at OLMoE's (one sequence of S4096, 16 heads of D128: a grid of
-16 x 8 x 8 tiles of 512). Compiled, never ``interpret=True``: off a TPU
-this exits non-zero.
+16 x 8 x 8 tiles of 512); then the masks and layouts the later decoders
+brought, each against a dense masked softmax in float32 (``check_masked``):
+a window under the diagonal, grouped keys and values (8 query heads on 2
+key/value heads), the two block masks of block-diffusion training and
+``block_diffusion_attention`` over a doubled stream (SDAR's: blocks of 4, 8
+query heads on one key/value head), and that call once more at the SDAR
+cell's own shapes on a sample of rows (``check_sdar_rows``). Compiled, never
+``interpret=True``: off a TPU this exits non-zero.
 
 The tolerance is the one ``tests/test_sequence_parallel.py`` uses for bf16
 inputs (rtol = atol = 2e-2) with atol multiplied by the reference's
@@ -90,6 +96,130 @@ def check_flash(batch, heads, seq, dim, causal, tokens_major=False) -> None:
         _close(name, g, w)
 
 
+def check_masked(name, attend, mask, heads, kv_heads, seq, dim=128) -> None:
+    """``attend(q, k, v)`` (``q [1, heads, seq, dim]``, ``k``, ``v [1,
+    kv_heads, seq, dim]`` in bf16) and its three gradients against the
+    float32 softmax under the dense ``mask [seq, seq]``, the keys and
+    values of a group repeated."""
+    import jax
+    import jax.numpy as jnp
+
+    print(f"{name}: H{heads} on KV{kv_heads} S{seq} D{dim} bf16, "
+          f"{int(mask.sum())} of {mask.size} pairs a head")
+    keys = jax.random.split(jax.random.PRNGKey(1), 3)
+    q = jax.random.normal(keys[0], (1, heads, seq, dim), jnp.bfloat16)
+    k, v = (jax.random.normal(key, (1, kv_heads, seq, dim), jnp.bfloat16)
+            for key in keys[1:])
+
+    def dense(q, k, v):
+        k, v = (jnp.repeat(x, heads // kv_heads, axis=1) for x in (k, v))
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) / dim ** 0.5
+        return jnp.einsum(
+            "bhqk,bhkd->bhqd",
+            jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), -1), v)
+
+    def loss(fn, q, k, v):
+        out = fn(q, k, v)
+        return jnp.sum(out.astype(jnp.float32) ** 2), out
+
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        lambda *a: loss(attend, *a), argnums=(0, 1, 2), has_aux=True))(
+            q, k, v)
+    with jax.default_matmul_precision("highest"):
+        (_, want_out), want_grads = jax.jit(jax.value_and_grad(
+            lambda *a: loss(dense, *a), argnums=(0, 1, 2), has_aux=True))(
+                *(x.astype(jnp.float32) for x in (q, k, v)))
+    _close("out", out, want_out)
+    for name, g, w in zip(("dq", "dk", "dv"), grads, want_grads):
+        _close(name, g, w)
+
+
+def check_masks() -> None:
+    """A window, grouped keys and values, the two block masks and the
+    doubled stream of block-diffusion training: several tiles of 512 each,
+    so the tile plan, the clamps and the in-tile masks all run."""
+    from functools import partial
+
+    import numpy as np
+
+    from horovod_tpu.models.sdar import visible
+    from horovod_tpu.ops.attention import (block_diffusion_attention,
+                                           flash_attention)
+
+    seq = 2048
+    ahead = np.arange(seq)[:, None] - np.arange(seq)[None, :]
+    check_masked("window 768, grouped",
+                 partial(flash_attention, causal=True, window=768),
+                 (ahead >= 0) & (ahead < 768), 8, 2, seq)
+    check_masked("full causal, grouped",
+                 partial(flash_attention, causal=True), ahead >= 0, 8, 2,
+                 seq)
+    blk = np.arange(seq) // 4
+    check_masked("block-causal, blocks of 4",
+                 partial(flash_attention, causal=True, block_length=4),
+                 blk[None, :] <= blk[:, None], 8, 1, seq)
+    check_masked("doubled stream, blocks of 4",
+                 partial(block_diffusion_attention, block_length=4),
+                 np.asarray(visible(4, seq // 2)), 8, 1, seq)
+
+
+def check_sdar_rows(heads=32, kv_heads=4, seq=8192, dim=128,
+                    length=4) -> None:
+    """``block_diffusion_attention`` at the SDAR cell's own shapes (one
+    row, 32 query heads on 4 key/value heads of 128, 8,192 noisy and 8,192
+    clean positions, blocks of 4), forward, on a sample of the query rows
+    of both halves against the float32 softmax under the three predicates
+    written out here: the first blocks, where four keys too many or too
+    few are a third of what a query sees (the cell's ``correct`` cannot
+    see them: PERF.md), the rows on either side of a tile's edge, the last
+    ones and rows drawn at random. ``[rows, 2 seq]`` scores a head: the
+    square is never built."""
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from horovod_tpu.ops.attention import block_diffusion_attention
+
+    print(f"doubled stream at the cell's shapes: H{heads} on KV{kv_heads} "
+          f"S{seq}+{seq} D{dim} bf16, blocks of {length}, sampled rows")
+    keys = jax.random.split(jax.random.PRNGKey(2), 3)
+    q = jax.random.normal(keys[0], (1, heads, 2 * seq, dim), jnp.bfloat16)
+    k, v = (jax.random.normal(key, (1, kv_heads, 2 * seq, dim),
+                              jnp.bfloat16) for key in keys[1:])
+    out = jax.jit(partial(block_diffusion_attention,
+                          block_length=length))(q, k, v)
+
+    half = np.unique(np.concatenate([
+        np.arange(16), np.arange(504, 520), np.arange(seq - 16, seq),
+        np.random.default_rng(0).integers(0, seq, 48)]))
+    rows = np.concatenate([half, seq + half])
+    pos, noisy = np.arange(2 * seq) % seq, np.arange(2 * seq) < seq
+    q_blk, k_blk = pos[rows, None] // length, pos[None, :] // length
+    q_noisy, k_noisy = noisy[rows, None], noisy[None, :]
+    seen = ((q_noisy & k_noisy & (k_blk == q_blk))
+            | (q_noisy & ~k_noisy & (k_blk < q_blk))
+            | (~q_noisy & ~k_noisy & (k_blk <= q_blk)))
+    assert int(seen.sum()) == int(
+        (pos[rows] // length * length + length).sum())
+
+    @jax.jit
+    def dense(q, k, v):
+        q, k, v = (x[0].astype(jnp.float32) for x in (q, k, v))
+        k, v = (jnp.repeat(x, heads // kv_heads, axis=0) for x in (k, v))
+        scores = jnp.einsum("hqd,hkd->hqk", q[:, rows], k) / dim ** 0.5
+        return jnp.einsum(
+            "hqk,hkd->hqd",
+            jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), -1), v)
+
+    with jax.default_matmul_precision("highest"):
+        want = dense(q, k, v)
+    for name, pick in (("noisy rows", slice(0, len(half))),
+                       ("clean rows", slice(len(half), None))):
+        _close(name, out[0][:, rows][:, pick], want[:, pick])
+
+
 def check_tokens_major() -> None:
     """BERT's two shapes as its projections write them, an odd group of
     causal pairs, and a head a block."""
@@ -118,6 +248,8 @@ def main() -> None:
     check_tokens_major()
     check_flash(2, 4, 2048, 128, causal=True)
     check_flash(1, 16, 4096, 128, causal=True)
+    check_masks()
+    check_sdar_rows()
     print("kernels ok")
 
 
