@@ -633,8 +633,10 @@ GRAD_SYNC_LAST_BUCKETS = gauge(
 GRAD_SYNC_LAST_PACKED_BYTES = gauge(
     "hvd_grad_sync_last_packed_bytes",
     "The part of hvd_grad_sync_last_bytes that went through a bucket's "
-    "packed vector (copied in, reduced, cut back): on the flat allreduce "
-    "wire only leaves under ops.fusion.PACK_CUTOFF_BYTES.", ("sync_mode",))
+    "packed vector (copied in, reduced, cut back): only leaves under "
+    "ops.fusion.PACK_CUTOFF_BYTES, but for a planned bucket, a "
+    "hierarchical axis tuple and the int8 exchanges, which pack every "
+    "leaf.", ("sync_mode",))
 STEP_RECOMPILES = counter(
     "hvd_step_recompiles_total",
     "Calls of a factory step, after its first, in which a program was "
@@ -707,6 +709,14 @@ PARAM_GATHER_BYTES = histogram(
     "by mesh axis: 'batch' is the bucketed data-axis leg (the flat 1-D "
     "wire records here too), 'model' the intra-layer ICI leg of the 2-D "
     "mesh.", ("axis",), BYTE_BUCKETS)
+PARAM_GATHER_PACKED_BYTES = histogram(
+    "hvd_param_gather_packed_bytes",
+    "The part of hvd_param_gather_bytes whose shards went through a "
+    "bucket's packed row (concatenated, gathered, cut back out of the "
+    "grid): only leaves under ops.fusion.PACK_CUTOFF_BYTES, but for a "
+    "planned bucket and the int8 gather, which pack every leaf; the "
+    "'model' leg gathers leaf by leaf and reads 0.", ("axis",),
+    BYTE_BUCKETS)
 RESIDENT_BYTES = gauge(
     "hvd_resident_state_bytes",
     "Per-rank resident bytes of sharded training state at rest, by kind "
@@ -982,6 +992,7 @@ def _materialize_checkpoint_cells() -> None:
     PEER_POOL_REPLICAS.labels()
     for axis in ("batch", "model"):
         PARAM_GATHER_BYTES.labels(axis=axis)
+        PARAM_GATHER_PACKED_BYTES.labels(axis=axis)
         MESH_AXIS_SIZE.labels(axis=axis)
     for mode in ("sharded", "fsdp"):
         RESIDENT_BYTES.labels(kind="opt_state", sync_mode=mode)

@@ -11,13 +11,21 @@ bucket only the leaves under :data:`PACK_CUTOFF_BYTES` are packed: one
 concat + one AllReduce + one split for them, and one AllReduce per larger
 leaf in its own shape, which the compiler's combiner merges with its
 neighbours into an all-reduce that takes its operands where they lie.
+The ``sharded`` and ``fsdp`` wires (:func:`fused_reducescatter`,
+:func:`fused_allgather_shards`) obey the same rule through the same
+split (:func:`_alone_and_small`): a large leaf is scattered and gathered
+as itself, the small ones share the bucket's ``(world, R)`` block. A
+bucket the comms planner schedules, one on a hierarchical axis tuple and
+the int8 exchanges (``ops/quantization.py``) pack every leaf: those work on
+one vector, or scale per packed block.
 
 Until PR 25 every leaf was packed, on the expectation that XLA would fuse
 the pack/unpack copies into neighboring ops (the role of
 ``cuda_kernels.cu``'s batched memcpy kernels in the reference). The chip's
 trace said it does not: a flat vector and a 2-D leaf are tiled differently
 there, so every slice out of a reduced bucket is a re-tiling copy of its
-own (PERF.md §5.3).
+own (PERF.md §5.3), and flattening a ``(world, R)`` block whose ``R`` is no
+multiple of a tile is a loop over its rows (PERF.md §6, PR 37).
 
 This "static negotiation" is why no background controller thread exists in
 the JAX path: readiness ordering is a dataflow fact inside the compiled
@@ -246,8 +254,17 @@ def bucket_leaves(
     return buckets
 
 
-#: A leaf of at least this many wire bytes rides the all-reduce as itself;
-#: smaller ones share a bucket's packed vector. Packing spares a leaf a
+def _bucket_order(buckets, issue_reversed):
+    """``(index, bucket)`` pairs in the order they are emitted."""
+    pairs = list(enumerate(buckets))
+    return reversed(pairs) if issue_reversed else pairs
+
+
+#: A leaf of at least this many wire bytes rides its collective as itself
+#: (an all-reduce, and since PR 37 the reduce-scatter and all-gather of the
+#: ``sharded`` and ``fsdp`` wires); smaller ones share a bucket's packed
+#: vector. Planned buckets, a hierarchical axis tuple and the int8
+#: exchanges do not ask: they pack whole buckets. Packing spares a leaf a
 #: collective's fixed cost and charges it a copy in and a copy out, and on
 #: a TPU the copy out is a re-tiling (a flat vector and a 2-D leaf are
 #: tiled differently), which XLA does not fuse away: cutting 670 MB back
@@ -345,6 +362,21 @@ def _reduce_bucket_planned(flat, op, axis_name, prescale_factor,
         postscale_factor)
 
 
+def _alone_and_small(bucket, nbytes, plan=None, whole=False):
+    """Split a bucket's leaves into those that ride the collective as
+    themselves and those that share its packed vector: ``(alone, small)``,
+    each in the bucket's order. ``nbytes[i]`` is leaf ``i``'s bytes on the
+    wire. A planned bucket (rhd and two_level are schedules over one flat
+    vector) and a ``whole`` one pack every leaf; a vector of one leaf would
+    be a copy for nothing, so a lone small leaf goes alone."""
+    whole = whole or plan is not None
+    small = [i for i in bucket if whole or nbytes[i] < PACK_CUTOFF_BYTES]
+    if plan is None and len(small) == 1:
+        small = []
+    in_vector = set(small)
+    return [i for i in bucket if i not in in_vector], small
+
+
 def _unpack_bucket(reduced, bucket, tensors, out) -> None:
     """Cut a reduced bucket back into its leaves (``out[i]`` for ``i`` in
     ``bucket``), under the wire's unpack scope: the slices and reshapes
@@ -383,12 +415,10 @@ def _fused_allreduce(tensors, op, axis_name, threshold_bytes,
     # The two-level composition's reduce-scatter leg cuts ONE vector into
     # the local axis's shares, so a bucket on an axis tuple stays whole.
     hierarchical = isinstance(axis_name, (tuple, list))
-    buckets = bucket_leaves(tensors, threshold_bytes)
     out: list[Any] = [None] * len(tensors)
     packed_bytes = 0
-    for bi, bucket in (
-            reversed(list(enumerate(buckets))) if issue_reversed
-            else enumerate(buckets)):
+    for bi, bucket in _bucket_order(
+            bucket_leaves(tensors, threshold_bytes), issue_reversed):
         # Annotation names carry the bucket's static wire bytes so a
         # profile of the step attributes transfer time to sized buckets
         # (the tracing plane's per-collective vocabulary, trace-time leg).
@@ -396,14 +426,7 @@ def _fused_allreduce(tensors, op, axis_name, threshold_bytes,
         nbytes = sum(sizes.values())
         plan = (_plan_bucket("allreduce", nbytes, axis_name, world_size)
                 if plannable else None)
-        # rhd and two_level are schedules over one flat vector too.
-        whole = plan is not None or hierarchical
-        small = [i for i in bucket
-                 if whole or sizes[i] < PACK_CUTOFF_BYTES]
-        if plan is None and len(small) == 1:
-            small = []  # a vector of one leaf would be a copy for nothing
-        in_vector = set(small)
-        alone = [i for i in bucket if i not in in_vector]
+        alone, small = _alone_and_small(bucket, sizes, plan, hierarchical)
         with annotate_collective(
                 f"allreduce.bucket{bi}.{nbytes}B{_bucket_suffix(plan)}"):
             # Next to each other, in their own shapes: the compiler's
@@ -539,16 +562,21 @@ def shard_ownership_2d(leaves: Sequence[Any], batch: int, model: int,
     return [(b * s, s) for s in shards]
 
 
+def _flat_padded(leaf, length: int):
+    """``leaf`` as a flat vector zero-padded to ``length`` elements."""
+    flat = leaf.ravel()
+    pad = length - int(flat.size)
+    return jnp.pad(flat, (0, pad)) if pad else flat
+
+
 def _pack_shard_rows(leaves, shard_sizes, world_size):
     """Pack same-dtype leaves into one ``(world_size, R)`` block whose row
     ``r`` is the concatenation of rank r's per-leaf owned slices — the
     layout under which a tiled reduce-scatter of the flattened block hands
     each rank exactly its owned slices, contiguously."""
     n = world_size
-    rows = [
-        jnp.pad(leaf.ravel(), (0, n * s - int(leaf.size))).reshape(n, s)
-        for leaf, s in zip(leaves, shard_sizes)
-    ]
+    rows = [_flat_padded(leaf, n * s).reshape(n, s)
+            for leaf, s in zip(leaves, shard_sizes)]
     return rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=1)
 
 
@@ -561,6 +589,69 @@ def _split_shard_row(row, shard_sizes):
         out.append(row[offset:offset + s])
         offset += s
     return out
+
+
+def _fused_reducescatter(tensors, op, axis_name, world_size,
+                         threshold_bytes, prescale_factor, postscale_factor,
+                         issue_reversed):
+    """:func:`fused_reducescatter`, and the wire bytes of ``tensors`` that
+    went through a packed block (the flush gauge's count, as
+    :func:`_fused_allreduce` makes it)."""
+    from jax import lax
+
+    from ..attribution import SCOPE_WIRE_UNPACK
+    from ..profiler import annotate_collective
+    from .collective_ops import Average, Sum
+
+    if op not in (Sum, Average):
+        raise ValueError(f"fused_reducescatter supports Sum/Average, got {op!r}")
+    n = int(world_size)
+    tensors = [jnp.asarray(t) for t in tensors]
+    _note_leaf_sizes(tensors)
+    sizes = shard_ownership(tensors, n)
+    scale = postscale_factor / n if op == Average else postscale_factor
+    out: list[Any] = [None] * len(tensors)
+    packed_bytes = 0
+
+    def scatter(flat, plan):
+        if prescale_factor != 1.0:
+            flat = flat * jnp.asarray(prescale_factor, flat.dtype)
+        if plan is not None:
+            from . import comms_planner
+
+            row = comms_planner.apply_reducescatter_sum(plan, flat, axis_name)
+        else:
+            row = lax.psum_scatter(
+                flat, axis_name, scatter_dimension=0, tiled=True)
+        if scale != 1.0:
+            row = row * jnp.asarray(scale, row.dtype)
+        return row
+
+    for bi, bucket in _bucket_order(
+            bucket_leaves(tensors, threshold_bytes), issue_reversed):
+        wire = {i: _wire_bytes(tensors[i]) for i in bucket}
+        nbytes = sum(wire.values())
+        plan = _plan_bucket("reducescatter", nbytes, axis_name, n)
+        alone, small = _alone_and_small(bucket, wire, plan)
+        small_sizes = [sizes[i] for i in small]
+        with annotate_collective(
+                f"reducescatter.bucket{bi}.{nbytes}B{_bucket_suffix(plan)}"):
+            # A leaf alone: the tiled scatter of its flat view is its
+            # owned shard, nothing packed and nothing cut.
+            for i in (reversed(alone) if issue_reversed else alone):
+                out[i] = scatter(
+                    _flat_padded(tensors[i], n * sizes[i]), plan)
+            if small:
+                packed_bytes += sum(wire[i] for i in small)
+                row = scatter(_pack_shard_rows(
+                    [tensors[i] for i in small], small_sizes, n).ravel(),
+                    plan)
+        if small:
+            with annotate_collective(SCOPE_WIRE_UNPACK):
+                for i, shard in zip(small,
+                                    _split_shard_row(row, small_sizes)):
+                    out[i] = shard
+    return out, packed_bytes
 
 
 def fused_reducescatter(
@@ -580,54 +671,73 @@ def fused_reducescatter(
     path).
 
     Buckets ride :func:`bucket_leaves` exactly like :func:`fused_allreduce`
-    (same-dtype, threshold-capped); within each bucket the leaves are
-    packed in the :func:`_pack_shard_rows` interleaved layout so ONE tiled
-    ``psum_scatter`` per bucket hands every rank its per-leaf owned slices
-    (ownership map: :func:`shard_ownership`). Returns one 1-D shard per
+    (same-dtype, threshold-capped), one ``hvd.reducescatter.bucket<i>.<n>B``
+    scope each. Within a bucket a leaf of at least
+    :data:`PACK_CUTOFF_BYTES` is scattered as itself (a tiled
+    ``psum_scatter`` of its flat view *is* its owned shard: the ownership
+    map, :func:`shard_ownership`, is per leaf and contiguous), and the
+    smaller ones are packed in the :func:`_pack_shard_rows` interleaved
+    layout so ONE tiled ``psum_scatter`` hands every rank their owned
+    slices. A planned bucket packs every leaf. Returns one 1-D shard per
     input tensor, length ``shard_ownership(tensors, world_size)[i]``.
     """
+    return _fused_reducescatter(
+        tensors, op, axis_name, world_size, threshold_bytes,
+        prescale_factor, postscale_factor, issue_reversed)[0]
+
+
+def _fused_allgather_shards(shards, templates, axis_name, world_size,
+                            threshold_bytes=None, issue_reversed=False):
+    """:func:`fused_allgather_shards`, and the wire bytes of the templates
+    whose shards went through a packed row."""
     from jax import lax
 
     from ..attribution import SCOPE_WIRE_UNPACK
     from ..profiler import annotate_collective
-    from .collective_ops import Average, Sum
 
-    if op not in (Sum, Average):
-        raise ValueError(f"fused_reducescatter supports Sum/Average, got {op!r}")
     n = int(world_size)
-    tensors = [jnp.asarray(t) for t in tensors]
-    _note_leaf_sizes(tensors)
-    sizes = shard_ownership(tensors, n)
-    scale = postscale_factor / n if op == Average else postscale_factor
-    out: list[Any] = [None] * len(tensors)
-    buckets = bucket_leaves(tensors, threshold_bytes)
-    for bi, bucket in (
-            reversed(list(enumerate(buckets))) if issue_reversed
-            else enumerate(buckets)):
-        bucket_sizes = [sizes[i] for i in bucket]
-        nbytes = sum(_wire_bytes(tensors[i]) for i in bucket)
-        plan = _plan_bucket("reducescatter", nbytes, axis_name, n)
+    templates = list(templates)
+    sizes = shard_ownership(templates, n)
+    out: list[Any] = [None] * len(templates)
+    packed_bytes = 0
+    for bi, bucket in _bucket_order(
+            bucket_leaves(templates, threshold_bytes), issue_reversed):
+        itemsize = {i: jnp.dtype(shards[i].dtype).itemsize for i in bucket}
+        wire = {i: int(templates[i].size) * itemsize[i] for i in bucket}
+        nbytes = sum(n * sizes[i] * itemsize[i] for i in bucket)
+        plan = _plan_bucket("allgather", nbytes, axis_name, n)
+        alone, small = _alone_and_small(bucket, wire, plan)
+        small_sizes = [sizes[i] for i in small]
+        full = {}
         with annotate_collective(
-                f"reducescatter.bucket{bi}.{nbytes}B{_bucket_suffix(plan)}"):
-            flat = _pack_shard_rows(
-                [tensors[i] for i in bucket], bucket_sizes, n).ravel()
-            if prescale_factor != 1.0:
-                flat = flat * jnp.asarray(prescale_factor, flat.dtype)
-            if plan is not None:
-                from . import comms_planner
+                f"allgather.bucket{bi}.{nbytes}B{_bucket_suffix(plan)}"):
+            # A leaf alone: the tiled gather of its shards is its flat
+            # view, nothing concatenated and nothing cut out of a grid.
+            for i in (reversed(alone) if issue_reversed else alone):
+                full[i] = lax.all_gather(
+                    shards[i], axis_name, axis=0, tiled=True)
+            if small:
+                packed_bytes += sum(wire[i] for i in small)
+                row = (shards[small[0]] if len(small) == 1
+                       else jnp.concatenate([shards[i] for i in small]))
+                if plan is not None:
+                    from . import comms_planner
 
-                row = comms_planner.apply_reducescatter_sum(
-                    plan, flat, axis_name)
-            else:
-                row = lax.psum_scatter(
-                    flat, axis_name, scatter_dimension=0, tiled=True)
-            if scale != 1.0:
-                row = row * jnp.asarray(scale, row.dtype)
+                    grid = comms_planner.apply_allgather_row(
+                        plan, row, axis_name)
+                else:
+                    grid = lax.all_gather(row, axis_name, axis=0, tiled=True)
         with annotate_collective(SCOPE_WIRE_UNPACK):
-            for i, shard in zip(bucket,
-                                _split_shard_row(row, bucket_sizes)):
-                out[i] = shard
-    return out
+            if small:
+                grid = grid.reshape(n, -1)
+                offset = 0
+                for i, s in zip(small, small_sizes):
+                    full[i] = grid[:, offset:offset + s].reshape(-1)
+                    offset += s
+            for i in bucket:
+                t = templates[i]
+                out[i] = full[i][: int(t.size)].reshape(t.shape)
+    return out, packed_bytes
 
 
 def fused_allgather_shards(
@@ -645,45 +755,16 @@ def fused_allgather_shards(
     critical path where XLA can overlap it with neighboring compute.
 
     Bucketing follows ``bucket_leaves(templates)`` so the grouping is
-    derived from the same static facts on every rank.
+    derived from the same static facts on every rank; one
+    ``hvd.allgather.bucket<i>.<n>B`` scope a bucket. Within a bucket a
+    leaf of at least :data:`PACK_CUTOFF_BYTES` on the wire is gathered as
+    itself and the smaller ones' shards are concatenated into one row,
+    gathered, and cut back out of the ``(world, R)`` grid
+    (``hvd.wire.unpack``); a planned bucket packs every leaf.
     """
-    from jax import lax
-
-    from ..attribution import SCOPE_WIRE_UNPACK
-    from ..profiler import annotate_collective
-
-    n = int(world_size)
-    templates = list(templates)
-    sizes = shard_ownership(templates, n)
-    out: list[Any] = [None] * len(templates)
-    buckets = bucket_leaves(templates, threshold_bytes)
-    for bi, bucket in (
-            reversed(list(enumerate(buckets))) if issue_reversed
-            else enumerate(buckets)):
-        bucket_sizes = [sizes[i] for i in bucket]
-        row = (shards[bucket[0]] if len(bucket) == 1
-               else jnp.concatenate([shards[i] for i in bucket]))
-        nbytes = sum(n * s * jnp.dtype(shards[i].dtype).itemsize
-                     for i, s in zip(bucket, bucket_sizes))
-        plan = _plan_bucket("allgather", nbytes, axis_name, n)
-        with annotate_collective(
-                f"allgather.bucket{bi}.{nbytes}B{_bucket_suffix(plan)}"):
-            if plan is not None:
-                from . import comms_planner
-
-                full = comms_planner.apply_allgather_row(
-                    plan, row, axis_name)
-            else:
-                full = lax.all_gather(row, axis_name, axis=0, tiled=True)
-        with annotate_collective(SCOPE_WIRE_UNPACK):
-            grid = full.reshape(n, -1)
-            offset = 0
-            for i, s in zip(bucket, bucket_sizes):
-                t = templates[i]
-                out[i] = (grid[:, offset:offset + s]
-                          .reshape(-1)[: int(t.size)].reshape(t.shape))
-                offset += s
-    return out
+    return _fused_allgather_shards(
+        shards, templates, axis_name, world_size, threshold_bytes,
+        issue_reversed)[0]
 
 
 def pipeline_interleave(n_segments: int, launch, consume):

@@ -92,9 +92,9 @@ def _record_flush(sync_mode: str, wire_leaves, threshold_bytes,
     not the leaves' dtype (int8: the leaves passed in are the f32
     bucketing view, but the wire carries 1 byte/element).
     ``packed_bytes`` is the part of those bytes that went through a
-    bucket's packed vector; left out, all of them did (every wire but the
-    flat allreduce packs whole buckets). Never raises: observability must
-    not break tracing."""
+    bucket's packed vector; left out, all of them did (the int8 exchanges
+    pack whole buckets). Never raises: observability must not break
+    tracing."""
     try:
         from . import metrics
         from .ops.fusion import bucket_leaves
@@ -390,7 +390,7 @@ def _reducescatter_grads(
     if op not in (collective_ops.Average, collective_ops.Sum):
         raise ValueError(
             f"sync_mode='sharded' supports op=Average/Sum, got {op!r}")
-    from .ops.fusion import fused_reducescatter
+    from .ops.fusion import _fused_reducescatter
     from .profiler import annotate_collective
 
     n = int(world_size)
@@ -420,14 +420,12 @@ def _reducescatter_grads(
         wire, ctxs = _compress_leaves(compression, leaves)
         sharded_threshold = _sharded_threshold(
             wire, threshold_bytes, num_groups)
-        _record_flush(flush_label, wire, sharded_threshold)
         with annotate_collective("grad_reducescatter"):
-            shards = fused_reducescatter(
-                wire, op, axis_name, n,
-                threshold_bytes=sharded_threshold,
-                prescale_factor=prescale_factor,
-                postscale_factor=postscale_factor,
-                issue_reversed=issue_reversed)
+            shards, packed_bytes = _fused_reducescatter(
+                wire, op, axis_name, n, sharded_threshold, prescale_factor,
+                postscale_factor, issue_reversed)
+        _record_flush(flush_label, wire, sharded_threshold,
+                      packed_bytes=packed_bytes)
         restored = _decompress_leaves(compression, shards, ctxs)
     return jax.tree.unflatten(treedef, restored)
 
@@ -491,7 +489,10 @@ def _gather_param_shards(
     optimizer's wire (cast compression halves the allgather bytes; int8
     rides the quantized gather — the second half of the EQuARX
     exchange). ``templates`` is a pytree of full-shape leaves (arrays or
-    ShapeDtypeStructs); the result matches its structure/shapes/dtypes."""
+    ShapeDtypeStructs); the result matches its structure/shapes/dtypes.
+    Returns it with the wire bytes that went through a bucket's packed
+    row (None: all of them, the int8 gather), for whoever records the
+    gather."""
     from .profiler import annotate_collective
 
     n = int(world_size)
@@ -509,19 +510,18 @@ def _gather_param_shards(
                     t_leaves, threshold_bytes, num_groups),
                 salt=quant_salt)
             full = [f.astype(t.dtype) for f, t in zip(full, t_leaves)]
-        return jax.tree.unflatten(treedef, full)
-    from .ops.fusion import fused_allgather_shards
+        return jax.tree.unflatten(treedef, full), None
+    from .ops.fusion import _fused_allgather_shards
 
     with annotate_collective(SCOPE_WIRE):
         wire, ctxs = _compress_leaves(compression, s_leaves)
         with annotate_collective("param_allgather"):
-            full = fused_allgather_shards(
+            full, packed_bytes = _fused_allgather_shards(
                 wire, t_leaves, axis_name, n,
-                threshold_bytes=_sharded_threshold(
-                    t_leaves, threshold_bytes, num_groups))
+                _sharded_threshold(t_leaves, threshold_bytes, num_groups))
         restored = [r.astype(t.dtype) for r, t in zip(
             _decompress_leaves(compression, full, ctxs), t_leaves)]
-    return jax.tree.unflatten(treedef, restored)
+    return jax.tree.unflatten(treedef, restored), packed_bytes
 
 
 def _known_size(ps) -> int | None:
@@ -823,7 +823,7 @@ def sharded_step_update(spec, grads, local_state, params, axis_name=None,
     new_local = _SaltState(new_inner, salt + 1) if int8 else new_inner
     if not gather:
         return new_param_shards, new_local
-    new_params = _gather_param_shards(
+    new_params, _ = _gather_param_shards(
         new_param_shards, params, spec.compression, axis_name, n,
         spec.fusion_threshold_bytes, spec.num_groups, quant_salt=salt)
     return new_params, new_local
@@ -1086,7 +1086,7 @@ def DistributedOptimizer(
                 optimizer, grad_shards, inner_local, param_shards)
             updates_sh, new_inner = _tripwire_guard(
                 action, flag, updates_sh, new_inner, inner_local)
-            updates_full = _gather_param_shards(
+            updates_full, _ = _gather_param_shards(
                 updates_sh, params, compression, effective, n,
                 fusion_threshold_bytes, num_groups, quant_salt=salt)
             if int8:

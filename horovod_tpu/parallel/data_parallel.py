@@ -669,7 +669,7 @@ def _make_sharded_train_step(loss_fn, spec, mesh, axis_name, donate,
                 return _gather_param_shards(
                     local, templates, spec.compression, axis_name, n,
                     spec.fusion_threshold_bytes, spec.num_groups,
-                    quant_salt=salt)
+                    quant_salt=salt)[0]
 
             in_specs = ((P(axis_name), P(axis_name)) if int8
                         else (P(axis_name),))

@@ -297,13 +297,16 @@ def _wire_itemsize(compression, dtype) -> int:
         return jnp.dtype(dtype).itemsize
 
 
-def _record_gather(templates, compression, axis: str = "batch") -> None:
+def _record_gather(templates, compression, axis: str = "batch",
+                   packed_bytes: int | None = None) -> None:
     """Trace-time metrics record of one parameter-gather program segment
     (static wire bytes — the per-trace shape, not a per-step rate, same
     contract as the grad-sync flush counters), labeled by the mesh axis
     the collective runs over: the flat 1-D wire and the 2-D batch leg
     record under ``axis="batch"``, the 2-D intra-layer leg under
-    ``axis="model"``. Never raises."""
+    ``axis="model"``. ``packed_bytes`` is the part of those bytes that
+    went through a bucket's packed row (``_gather_param_shards`` counts
+    it); left out, all of them did. Never raises."""
     try:
         from .. import metrics
 
@@ -312,6 +315,8 @@ def _record_gather(templates, compression, axis: str = "batch") -> None:
             * _wire_itemsize(compression, t.dtype)
             for t in templates)
         metrics.PARAM_GATHER_BYTES.observe(nbytes, axis=axis)
+        metrics.PARAM_GATHER_PACKED_BYTES.observe(
+            nbytes if packed_bytes is None else packed_bytes, axis=axis)
     except Exception:  # noqa: BLE001 — instrumentation is best-effort
         pass
 
@@ -348,11 +353,12 @@ def _gather_boundary(shard_leaves, templates, seg_index, spec, axis_name,
     templates = list(templates)
 
     def gather(ls, s):
-        _record_gather(templates, spec.compression)
         with annotate_collective(f"fsdp.param_gather.seg{seg_index}"):
-            full = _gather_param_shards(
+            full, packed_bytes = _gather_param_shards(
                 list(ls), templates, spec.compression, axis_name, n,
                 spec.fusion_threshold_bytes, 0, quant_salt=s)
+        _record_gather(templates, spec.compression,
+                       packed_bytes=packed_bytes)
         return list(full)
 
     def reduce_cts(cts, s):
@@ -511,13 +517,15 @@ def _gather_boundary_2d(shard_leaves, templates, seg_index, spec,
     ]
 
     def gather(ls, s):
-        _record_gather(block_templates, spec.compression, axis="batch")
         with annotate_collective(
                 f"fsdp.param_gather.batch.seg{seg_index}"):
-            blocks = _gather_param_shards(
+            blocks, packed_bytes = _gather_param_shards(
                 list(ls), block_templates, spec.compression, batch_axis,
                 b, spec.fusion_threshold_bytes, 0, quant_salt=s)
-        _record_gather(templates, None, axis="model")
+        _record_gather(block_templates, spec.compression, axis="batch",
+                       packed_bytes=packed_bytes)
+        # The model legs go leaf by leaf: nothing packed.
+        _record_gather(templates, None, axis="model", packed_bytes=0)
         full = []
         # The batch legs are the wire's own functions and scope
         # themselves; the model legs are plain collectives of this file.

@@ -72,6 +72,40 @@ def _mlp_problem(n_layers=3, dim=8, batch=16, seed=0):
     return params, (x, y), loss_fn
 
 
+def _wide_problem(batch=16, seed=0):
+    """An MLP whose first matrix, ``[512, 1024]`` float32, is 2 MiB on the
+    wire: over ``ops.fusion.PACK_CUTOFF_BYTES``, so it is gathered and
+    scattered as itself beside the packed small leaves."""
+    rng = np.random.RandomState(seed)
+    params = {
+        "wide": {"w": jnp.asarray(
+            (rng.randn(512, 1024) / 32).astype(np.float32)),
+            "b": jnp.asarray(rng.randn(1024).astype(np.float32))},
+        "out": {"w": jnp.asarray(
+            (rng.randn(1024, 8) / 32).astype(np.float32)),
+            "b": jnp.asarray(rng.randn(8).astype(np.float32))},
+    }
+
+    def loss_fn(p, b):
+        x, y = b
+        h = jnp.tanh(x @ p["wide"]["w"] + p["wide"]["b"])
+        h = jnp.tanh(h @ p["out"]["w"] + p["out"]["b"])
+        return jnp.mean((h.sum(axis=-1) - y) ** 2)
+
+    x = rng.randn(batch, 512).astype(np.float32)
+    y = rng.randn(batch).astype(np.float32)
+    return params, (x, y), loss_fn
+
+
+def _rows_by_hand(leaf, world):
+    """A leaf's resident ``(world, s)`` rows as ``shard_ownership`` lays
+    them out, written down without the library: the layout every
+    checkpoint and peer replica was written in."""
+    flat = np.asarray(leaf).ravel()
+    s = -(-flat.size // world)
+    return np.pad(flat, (0, world * s - flat.size)).reshape(world, s)
+
+
 def _assert_tree_close(a, b, rtol=1e-5, atol=1e-6):
     jax.tree.map(
         lambda x, y: np.testing.assert_allclose(
@@ -194,6 +228,31 @@ class TestFsdpEquivalence:
         full_p = unshard_params(jax.device_get(pf))
         full_s = hvd.unshard_opt_state(fsdp, jax.device_get(sf), full_p)
         _assert_tree_close(jax.device_get(sm), full_s)
+
+    def test_a_leaf_over_the_pack_cutoff_from_rows_in_the_old_layout(
+            self, hvd, monkeypatch):
+        from horovod_tpu.ops import fusion
+
+        params, batch, loss_fn = _wide_problem()
+        assert params["wide"]["w"].nbytes >= fusion.PACK_CUTOFF_BYTES
+        mono = hvd.DistributedOptimizer(optax.adam(0.01))
+        fsdp = hvd.DistributedOptimizer(optax.adam(0.01), sync_mode="fsdp")
+        pm, sm, lm = self._run_mono(hvd, mono, params, batch, loss_fn, 3)
+        # A checkpoint's rows, laid out by hand: what shard_params makes,
+        # to the bit, whatever the wire packs.
+        resident = hvd.shard_params(params)
+        by_hand = ShardedParams(
+            [jnp.asarray(_rows_by_hand(leaf, hvd.size()))
+             for leaf in jax.tree.leaves(params)], resident.meta)
+        _assert_tree_exact(jax.device_get(resident), by_hand)
+        monkeypatch.setattr(hvd, "shard_params", lambda _: by_hand)
+        pf, sf, lf = self._run_fsdp(hvd, fsdp, params, batch, loss_fn, 3)
+        assert lm == pytest.approx(lf, rel=1e-6)
+        full_p = unshard_params(jax.device_get(pf))
+        _assert_tree_close(pm, full_p)
+        _assert_tree_close(
+            jax.device_get(sm),
+            hvd.unshard_opt_state(fsdp, jax.device_get(sf), full_p))
 
     def test_overlapped_factory_and_explicit_segments(self, hvd):
         params, batch, loss_fn = _mlp_problem()

@@ -61,6 +61,31 @@ def _mlp_problem(n_layers=3, dim=8, batch=16, seed=0):
     return params, (x, y), loss_fn
 
 
+def _wide_problem(batch=16, seed=0):
+    """An MLP whose first matrix, ``[512, 1024]`` float32, is 2 MiB on the
+    wire: over ``ops.fusion.PACK_CUTOFF_BYTES``, so it is scattered and
+    gathered as itself beside the packed small leaves."""
+    rng = np.random.RandomState(seed)
+    params = {
+        "wide": {"w": jnp.asarray(
+            (rng.randn(512, 1024) / 32).astype(np.float32)),
+            "b": jnp.asarray(rng.randn(1024).astype(np.float32))},
+        "out": {"w": jnp.asarray(
+            (rng.randn(1024, 8) / 32).astype(np.float32)),
+            "b": jnp.asarray(rng.randn(8).astype(np.float32))},
+    }
+
+    def loss_fn(p, b):
+        x, y = b
+        h = jnp.tanh(x @ p["wide"]["w"] + p["wide"]["b"])
+        h = jnp.tanh(h @ p["out"]["w"] + p["out"]["b"])
+        return jnp.mean((h.sum(axis=-1) - y) ** 2)
+
+    x = rng.randn(batch, 512).astype(np.float32)
+    y = rng.randn(batch).astype(np.float32)
+    return params, (x, y), loss_fn
+
+
 def _get_or_add_ps(hvd, ranks):
     """Process sets persist for the whole test session; re-adding the
     same ranks raises, so look it up first."""
@@ -225,6 +250,47 @@ class TestShardedEquivalence:
         _assert_tree_close(pm, ps_)
         full = hvd.unshard_opt_state(shrd, jax.device_get(ss), params)
         _assert_tree_close(jax.device_get(sm), full)
+
+    def test_a_leaf_over_the_pack_cutoff_and_state_rows_in_the_old_layout(
+            self, hvd):
+        from horovod_tpu.ops import fusion
+
+        dp = hvd.data_parallel
+        params, batch, loss_fn = _wide_problem()
+        assert params["wide"]["w"].nbytes >= fusion.PACK_CUTOFF_BYTES
+        mono = hvd.DistributedOptimizer(optax.adam(0.01))
+        shrd = hvd.DistributedOptimizer(optax.adam(0.01),
+                                        sync_mode="sharded")
+        step_m = dp.make_train_step(loss_fn, mono, donate=False)
+        step_s = dp.make_train_step(loss_fn, shrd, donate=False)
+        pm, sm, lm = self._run(hvd, step_m, mono, params, batch, 3, False)
+        ps_, ss, ls = self._run(hvd, step_s, shrd, params, batch, 3, True)
+        assert lm == pytest.approx(ls, rel=1e-6)
+        _assert_tree_close(pm, ps_)
+        _assert_tree_close(
+            jax.device_get(sm),
+            hvd.unshard_opt_state(shrd, jax.device_get(ss), params))
+
+        # A checkpoint's state rows, laid out by hand as shard_ownership
+        # always has (rank r owns flat[r*s:(r+1)*s]): the fourth step from
+        # them is the monolithic run's fourth step.
+        n = hvd.size()
+
+        def rows(leaf):
+            if not np.ndim(leaf):  # Adam's count: every rank keeps it
+                return jnp.full((n,), leaf)
+            flat = np.asarray(leaf).ravel()
+            s = -(-flat.size // n)
+            return jnp.asarray(
+                np.pad(flat, (0, n * s - flat.size)).reshape(n, s))
+
+        by_hand = jax.tree.map(rows, jax.device_get(sm))
+        _assert_tree_close(jax.device_get(ss), by_hand)
+        b = dp.shard_batch(batch)
+        p4m, _, l4m = step_m(pm, sm, b)
+        p4s, _, l4s = step_s(pm, dp.shard_state(by_hand), b)
+        assert float(l4m) == pytest.approx(float(l4s), rel=1e-6)
+        _assert_tree_close(p4m, p4s)
 
     def test_matches_monolithic_under_overlap_scheduler(self, hvd):
         dp = hvd.data_parallel

@@ -423,3 +423,84 @@ def test_sdars_two_streams_compile_on_two_causal_grids(one_chip):
     square = [shape for shape in re.findall(r"\[([0-9,]+)\]", compiled)
               if sum(int(d) >= 8192 for d in shape.split(",")) >= 2]
     assert not square, sorted(set(square))
+
+
+@pytest.fixture(scope="module")
+def four_chips():
+    """The devices of a described ``v5e:2x2`` host."""
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topology = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2",
+            chips_per_host_bounds=(2, 2, 1))
+    except Exception as e:  # noqa: BLE001 — no libtpu here, or it is taken
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return topology.devices
+
+
+def test_an_fsdp_step_with_large_leaves_compiles_without_a_loop(
+        four_chips, monkeypatch):
+    """``make_train_step`` under ``fsdp`` on four chips, two feed-forward
+    layers at BERT-Large's widths (four matrices over
+    ``PACK_CUTOFF_BYTES`` on the bf16 wire, four biases under it). Packed
+    whole, as every bucket was until PR 37, a ``(4, R)`` block and the flat
+    vector of the collective are tiled differently, and the chip's compiler
+    writes the copy between them as a ``while`` over the rows, once a
+    direction; with the matrices gathered and scattered as themselves there
+    is none."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    import horovod_tpu as hvd
+    from horovod_tpu.ops import fusion
+
+    layers, width, inner = 2, 1024, 4096
+
+    def init():
+        return {f"layer{i}": {
+            "w1": jnp.zeros((width, inner)), "b1": jnp.zeros((inner,)),
+            "w2": jnp.zeros((inner, width)), "b2": jnp.zeros((width,)),
+        } for i in range(layers)}
+
+    def loss_fn(params, batch):
+        h = batch.astype(jnp.bfloat16)
+        for i in range(layers):
+            p = jax.tree.map(lambda a: a.astype(jnp.bfloat16),
+                             params[f"layer{i}"])
+            h = h + jnp.tanh(h @ p["w1"] + p["b1"]) @ p["w2"] + p["b2"]
+        return jnp.mean(h.astype(jnp.float32) ** 2)
+
+    def whiles():
+        optimizer = hvd.DistributedOptimizer(
+            optax.adamw(1e-4), compression=hvd.Compression.bf16,
+            sync_mode="fsdp")
+        step = hvd.data_parallel.make_train_step(loss_fn, optimizer)
+        sharded = NamedSharding(hvd.global_mesh(),
+                                P(hvd.global_axis_name()))
+
+        def placed(tree):
+            return jax.tree.map(lambda leaf: jax.ShapeDtypeStruct(
+                leaf.shape, leaf.dtype, sharding=sharded), tree)
+
+        params = jax.eval_shape(init)
+        text = step.lower(
+            placed(jax.eval_shape(hvd.shard_params, params)),
+            placed(jax.eval_shape(optimizer.init, params)),
+            placed(jax.ShapeDtypeStruct((4 * 256, width), jnp.float32)),
+        ).compile().as_text()
+        assert " all-gather(" in text
+        return len(re.findall(r" while\(", text))
+
+    hvd.shutdown()
+    try:
+        hvd.init(devices=four_chips)
+        assert whiles() == 0
+        monkeypatch.setattr(fusion, "PACK_CUTOFF_BYTES", 1 << 40)
+        assert whiles() >= 1
+    finally:
+        hvd.shutdown()
+        hvd.init()
