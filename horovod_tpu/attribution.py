@@ -137,11 +137,19 @@ SCOPE_MOE_COMBINE = "moe.combine"
 SCOPE_LINATTN_CONV = "linattn.conv"
 SCOPE_LINATTN_SCAN = "linattn.scan"
 SCOPE_LINATTN_GATE = "linattn.gate"
+#: A state-space layer (``ops/ssd.py``, ``models/granite.py``'s Mamba-2
+#: mixer): the short convolution over x, B and C with its bias and SiLU and
+#: the steps' softplus, the chunked scan itself (decay matrices, chunk
+#: products, the states' way across the chunks), and the gated RMSNorm of
+#: its output. Backward too.
+SCOPE_SSM_CONV = "ssm.conv"
+SCOPE_SSM_SCAN = "ssm.scan"
+SCOPE_SSM_GATE = "ssm.gate"
 PHASE_SCOPE_NAMES = tuple(SCOPE_PREFIX + name for name in (
     SCOPE_WIRE, SCOPE_OPTIMIZER, SCOPE_ATTN_FWD, SCOPE_ATTN_BWD,
     SCOPE_MOE_ROUTE, SCOPE_MOE_DISPATCH, SCOPE_MOE_EXPERTS,
     SCOPE_MOE_COMBINE, SCOPE_LINATTN_CONV, SCOPE_LINATTN_SCAN,
-    SCOPE_LINATTN_GATE))
+    SCOPE_LINATTN_GATE, SCOPE_SSM_CONV, SCOPE_SSM_SCAN, SCOPE_SSM_GATE))
 #: Block scopes: the parts of a model (``models/*.py``) that no phase
 #: names, forward and backward. A phase inside a block stays the phase's
 #: (``profiler.owner_of``: the innermost phase scope, else the innermost

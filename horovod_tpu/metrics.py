@@ -909,6 +909,12 @@ LINATTN_CHUNKS_LAST = gauge(
     "(sequence length / chunk), one scan step each: set at trace time, as "
     "hvd_grad_sync_last_bytes is.",
     ("chunk", "heads_here"))
+SSM_CHUNKS_LAST = gauge(
+    "hvd_ssm_chunks_last",
+    "Chunks a sequence that the LAST traced Mamba-2 scan (ops/ssd.py "
+    "ssd_scan) cuts its tokens into (sequence length / chunk), for "
+    "`heads` heads: set at trace time, as hvd_linattn_chunks_last is.",
+    ("chunk", "heads"))
 ATTN_TILES_LAST = gauge(
     "hvd_attn_tiles_last",
     "(q, k) tile pairs a (batch x head) slice of the LAST traced multi-tile "

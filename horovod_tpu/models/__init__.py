@@ -38,3 +38,9 @@ from .sdar import (  # noqa: F401
     block_diffusion_loss,
     noisy_batch,
 )
+from .granite import (  # noqa: F401
+    GRANITE_4_0_H_MICRO,
+    GRANITE_TINY,
+    Granite,
+    GraniteConfig,
+)

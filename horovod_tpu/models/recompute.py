@@ -1,7 +1,8 @@
 """What a recomputed decoder layer keeps: the ``jax.checkpoint`` policy that
-``models/smallthinker.py`` and ``models/sdar.py`` wrap their layers in
-(``nn.remat(DecoderLayer, policy=...)``). The first of the decoders'
-shared parts to live outside one of the decoders (ROADMAP D13)."""
+``models/smallthinker.py``, ``models/sdar.py`` and ``models/granite.py``
+wrap their layers in (``nn.remat(DecoderLayer, policy=...)``). The first of
+the decoders' shared parts to live outside one of the decoders (ROADMAP
+D13)."""
 
 from __future__ import annotations
 

@@ -55,16 +55,19 @@ from ..attribution import SCOPE_LINATTN_SCAN
 from ..profiler import annotate_collective
 
 
-def short_conv(x, w):
+def short_conv(x, w, bias=None):
     """Depth-wise causal convolution over time: ``x [B, S, channels]``,
     ``w [channels, width]`` → ``y_t = Σ_i w[:, i] · x_{t - (width - 1) +
     i}`` with zeros to the left of the sequence (``w[:, -1]`` weighs the
     token itself; ``torch.nn.Conv1d(groups=channels, padding=width - 1)``
-    cut to the sequence). Float32 accumulation, ``x``'s type out."""
+    cut to the sequence), plus ``bias [channels]`` where there is one.
+    Float32 accumulation, ``x``'s type out."""
     width, seq = w.shape[1], x.shape[1]
     padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
     out = sum(padded[:, i:i + seq].astype(jnp.float32) * w[:, i]
               for i in range(width))
+    if bias is not None:
+        out = out + bias
     return out.astype(x.dtype)
 
 

@@ -228,8 +228,8 @@ def instruction_scopes(hlo_text: str) -> dict[str, str]:
 
 def phase_of(scope: str) -> str | None:
     """The innermost phase scope (``hvd.wire``, ``hvd.optimizer``,
-    ``hvd.attn.fwd``, ``hvd.attn.bwd``, ``hvd.moe.*``, ``hvd.linattn.*``)
-    among the components of a name stack, wherever it sits (the overlapped
+    ``hvd.attn.fwd``, ``hvd.attn.bwd``, ``hvd.moe.*``, ``hvd.linattn.*``,
+    ``hvd.ssm.*``) among the components of a name stack, wherever it sits (the overlapped
     step's wire is under ``transpose``), or None. A transformation wraps the
     outermost name of what it transforms (``vmap(hvd.moe.route)``,
     ``transpose(jvp(hvd.moe.experts))``): the name inside is the scope."""

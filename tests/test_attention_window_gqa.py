@@ -81,6 +81,37 @@ class TestAgainstDenseSoftmax:
             np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5,
                                        err_msg=f"d{name}")
 
+    @pytest.mark.parametrize("seq, tile", [(128, 32), (192, 64)])
+    def test_granites_heads_and_its_score_scale(self, seq, tile):
+        """32 query heads on 8 key/value heads of 64 through the
+        multi-tile causal kernels (``models/granite.py``'s attention layer;
+        BERT's D = 64 goes through the single-tile ones), with the queries
+        scaled by 2^-3 ahead of the call: against a plain softmax of
+        ``q k^T * 0.015625``, the model's ``attention_multiplier``, values
+        and all three gradients."""
+        q, k, v, weight = operands(1, 32, 8, seq, seq, dim=64, seed=3)
+
+        def flash(q, k, v):
+            return flash_attention(q * 2.0 ** -3, k, v, causal=True,
+                                   block_q=tile, block_k=tile,
+                                   interpret=True)
+
+        def plain(q, k, v):
+            k4, v4 = jnp.repeat(k, 4, 1), jnp.repeat(v, 4, 1)
+            scores = jnp.einsum("bhqd,bhkd->bhqk", q, k4) * 0.015625
+            seen = jnp.tril(jnp.ones((seq, seq), bool))
+            weights = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), -1)
+            return jnp.einsum("bhqk,bhkd->bhqd", weights, v4)
+
+        got, got_vjp = jax.vjp(flash, q, k, v)
+        want, want_vjp = jax.vjp(plain, q, k, v)
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+        for name, a, b in zip("qkv", got_vjp(weight), want_vjp(weight)):
+            assert a.shape == b.shape, name
+            np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5,
+                                       err_msg=f"d{name}")
+        assert metrics.ATTN_KV_GROUP_LAST.labels().get() == 4
+
     @pytest.mark.parametrize("sq, sk, q_off, k_off, window", [
         pytest.param(64, 128, 64, 0, 40, id="bottom-right"),
         pytest.param(64, 128, 17, 0, 23, id="odd-offset"),

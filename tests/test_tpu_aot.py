@@ -425,6 +425,76 @@ def test_sdars_two_streams_compile_on_two_causal_grids(one_chip):
     assert not square, sorted(set(square))
 
 
+def test_granites_layers_compile_at_their_widths(one_chip):
+    """One sequence of 4,096. The attention layer: 32 query heads on 8
+    key/value heads of 64 through the multi-tile causal kernels (every
+    other multi-tile cell is D = 128, and BERT's D = 64 is single-tile):
+    grids of 32 x 8 x 8 and, for dkv, 8 x 8 x 4 x 8, three kernels under
+    the name the readers find them by, the queries scaled by 2^-3 outside
+    them. The Mamba-2 scan: 64 heads of 64 with a state of 128 in 16
+    chunks of 256, forward and backward, as the v5e's compiler takes it:
+    no kernel, the states crossing the chunks in one loop each way with
+    the float32 ``[1, 1, 64, 64, 128]`` state as its carry, every
+    operation under the scope the cell's readers sum, and arguments +
+    temporaries under 1.5 GiB (the decay matrices are 268 MB in float32:
+    a form that kept several copies of them a layer would pass it)."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu import metrics, profiler
+    from horovod_tpu.models import granite
+    from horovod_tpu.ops import ssd
+
+    def shaped(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def attend(q, k, v):
+        out = granite.flash_attention_fn(
+            q * granite.GRANITE_4_0_H_MICRO.query_scale, k, v, jnp.bfloat16)
+        return out.astype(jnp.float32).sum()
+
+    lowered = jax.jit(jax.value_and_grad(attend, argnums=(0, 1, 2))).lower(
+        shaped(1, 4096, 32, 64), shaped(1, 4096, 8, 64),
+        shaped(1, 4096, 8, 64))
+    assert metrics.ATTN_KV_GROUP_LAST.labels().get() == 4
+    bodies = kernel_bodies(lowered.as_text())
+    grids = sorted(re.search(r"iteration_bounds = array<i64: ([^>]*)>", body)
+                   .group(1) for body in bodies)
+    assert grids == ["32, 8, 8", "32, 8, 8", "8, 8, 4, 8"]
+    found = kernel_instructions(lowered.compile().as_text())
+    assert len(found) == 3
+    with open(os.path.join(REPO_ROOT, "benchmark", "layer_metrics",
+                           "causal_attn_kernel_ms.json")) as f:
+        wanted = re.compile(json.load(f)["kernel_names"])
+    assert all(wanted.search(name) for name, _ in found), found
+    assert sorted(profiler.phase_of(scope) for _, scope in found) == [
+        "hvd.attn.bwd", "hvd.attn.bwd", "hvd.attn.fwd"]
+
+    def scan(x, dt, a, b, c, d):
+        return ssd.ssd_scan(x, dt, a, b, c, d, chunk=256).astype(
+            jnp.float32).sum()
+
+    heads = shaped(64, dtype=jnp.float32)
+    compiled = jax.jit(jax.grad(scan, argnums=range(6))).lower(
+        shaped(1, 4096, 64, 64), shaped(1, 4096, 64, dtype=jnp.float32),
+        heads, shaped(1, 4096, 1, 128), shaped(1, 4096, 1, 128),
+        heads).compile()
+    assert metrics.SSM_CHUNKS_LAST.labels(
+        chunk="256", heads="64").get() == 16
+    text = compiled.as_text()
+    assert "custom_call_target=\"tpu_custom_call\"" not in text
+    planned = compiled.memory_analysis()
+    assert (planned.argument_size_in_bytes + planned.temp_size_in_bytes
+            <= 1.5 * 2 ** 30)
+    scopes = profiler.instruction_scopes(text)
+    # (the loss's own cast and sum are the only operations outside it)
+    assert {profiler.phase_of(scope) for scope in scopes.values()} == {
+        "hvd.ssm.scan", None}
+    loops = [line for line in text.splitlines() if " while(" in line]
+    assert len(loops) == 2, len(loops)
+    assert all("f32[1,1,64,64,128]" in line for line in loops)
+
+
 @pytest.fixture(scope="module")
 def four_chips():
     """The devices of a described ``v5e:2x2`` host."""
