@@ -948,6 +948,14 @@ ATTN_KV_GROUP_LAST = gauge(
     "Query heads that read one key/value head in the LAST traced multi-tile "
     "flash-attention call (1: a head of keys and values a query head): set "
     "at trace time, beside hvd_attn_tiles_last.")
+HEAD_LOGITS_BYTES_LAST = gauge(
+    "hvd_head_logits_bytes_last",
+    "Bytes of the logits that the LAST traced token cross entropy "
+    "(models/loss.py token_cross_entropy) was traced over, by the rule "
+    "that differentiates it (custom_vjp: softmax minus the one-hot in one "
+    "pass, nothing else of the logits' size kept): set at trace time, as "
+    "hvd_attn_tiles_last is.",
+    ("rule",))
 DIFFUSION_MASKED_SHARE_LAST = gauge(
     "hvd_diffusion_masked_share_last",
     "Share of the positions that the LAST block-diffusion batch made "

@@ -62,11 +62,8 @@ from ..attribution import (SCOPE_BLOCK_ATTN_PROJ, SCOPE_BLOCK_EMBED,
 from ..ops.linear_attention import short_conv
 from ..ops.ssd import ssd_scan
 from ..profiler import annotate_collective
-from .olmo_hybrid import (  # noqa: F401 — the loss is this model's too
-    _decay_rate,
-    _step_bias,
-    causal_lm_loss,
-)
+from .loss import token_cross_entropy
+from .olmo_hybrid import _decay_rate, _step_bias
 from .olmoe import RMSNorm
 from .recompute import save_kernels_and_projections
 from .smallthinker import (  # noqa: F401 — the adapters are this model's too
@@ -299,3 +296,11 @@ class Granite(nn.Module):
                 (((x.ndim - 1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
             return logits / cfg.logits_scaling
+
+
+def causal_lm_loss(model: Granite, params, tokens):
+    """Next-token cross entropy of ``tokens [B, S + 1]``: positions
+    ``0..S-1`` are read and ``1..S`` are their labels. The source's config
+    has no auxiliary loss, so there is none."""
+    logits = model.apply({"params": params}, tokens[:, :-1])
+    return token_cross_entropy(logits, tokens[:, 1:])

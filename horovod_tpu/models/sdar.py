@@ -55,6 +55,7 @@ from ..attribution import (SCOPE_BLOCK_ATTN_PROJ, SCOPE_BLOCK_EMBED,
 from ..ops.attention import block_diffusion_attention
 from ..parallel import moe
 from ..profiler import annotate_collective
+from .loss import token_cross_entropy
 from .olmoe import (RMSNorm, SparseExperts, rope,  # noqa: F401
                     routing_stats, take_expert_window)
 from .recompute import save_kernels_and_projections
@@ -283,7 +284,4 @@ def block_diffusion_loss(model: Sdar, params, batch):
     S`` positions. The source's config has no auxiliary-loss coefficient,
     so there is none."""
     logits = model.apply({"params": params}, batch["noisy"], batch["clean"])
-    with annotate_collective(SCOPE_BLOCK_HEAD):
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        picked = jnp.take_along_axis(logp, batch["clean"][..., None], -1)
-        return -(batch["weight"] * picked[..., 0]).mean()
+    return token_cross_entropy(logits, batch["clean"], batch["weight"])

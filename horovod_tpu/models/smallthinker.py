@@ -58,6 +58,7 @@ from ..attribution import (SCOPE_BLOCK_ATTN_PROJ, SCOPE_BLOCK_EMBED,
 from ..ops.attention import flash_attention
 from ..parallel import moe
 from ..profiler import annotate_collective
+from .loss import token_cross_entropy
 from .olmoe import (RMSNorm, SparseExperts, rope,  # noqa: F401
                     routing_stats, take_expert_window)
 from .recompute import save_kernels_and_projections
@@ -266,7 +267,4 @@ def causal_lm_loss(model: SmallThinker, params, tokens):
     ``0..S-1`` are read and ``1..S`` are their labels. The source's config
     has no auxiliary-loss coefficient, so there is none."""
     logits = model.apply({"params": params}, tokens[:, :-1])
-    with annotate_collective(SCOPE_BLOCK_HEAD):
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        return -jnp.take_along_axis(
-            logp, tokens[:, 1:, None], axis=-1).mean()
+    return token_cross_entropy(logits, tokens[:, 1:])

@@ -266,6 +266,24 @@ def test_the_model_is_causal():
     assert float(jnp.abs(changed[0, 20:] - logits[0, 20:]).max()) > 1e-3
 
 
+def test_the_loss_is_the_old_expression_and_the_models_own():
+    """``models/loss.py``'s rule against the ``log_softmax`` and the pick
+    that Olmo Hybrid's ``causal_lm_loss`` is, which Granite imported until
+    PR 39 and no longer does."""
+    from horovod_tpu.models import olmo_hybrid
+
+    config = dataclasses.replace(granite.GRANITE_TINY, dtype=jnp.float32)
+    model = granite.Granite(config)
+    key = jax.random.PRNGKey(4)
+    params = jax.jit(model.init)(key, jnp.zeros((1, 8), jnp.int32))["params"]
+    tokens = jax.random.randint(key, (2, 33), 0, config.vocab_size)
+    old = olmo_hybrid.causal_lm_loss(model, params, tokens)
+    new = granite.causal_lm_loss(model, params, tokens)
+    assert abs(float(new) - float(old)) <= 1e-6 * abs(float(old))
+    assert granite.causal_lm_loss is not olmo_hybrid.causal_lm_loss
+    assert granite.causal_lm_loss.__module__ == granite.__name__
+
+
 def test_a_factory_step_names_the_state_space_phases_and_the_blocks():
     import optax
 

@@ -313,6 +313,20 @@ class TestTheNoisyBatch:
         assert abs(float(other) - float(base)) > 1e-3
 
 
+class TestTheLossIsTheOldExpression:
+    def test_on_the_models_own_logits(self, params, batch):
+        """``models/loss.py``'s rule against the ``log_softmax``, the pick
+        and the weights that ``block_diffusion_loss`` was until PR 39."""
+        model = sdar.Sdar(TINY)
+        logits = model.apply({"params": params}, batch["noisy"],
+                             batch["clean"])
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        picked = jnp.take_along_axis(logp, batch["clean"][..., None], -1)
+        old = -(batch["weight"] * picked[..., 0]).mean()
+        new = sdar.block_diffusion_loss(model, params, batch)
+        assert abs(float(new) - float(old)) <= 1e-6 * abs(float(old))
+
+
 class TestRopeWithPositionIds:
     def x(self):
         return jax.random.normal(jax.random.PRNGKey(6), (2, 32, 3, 16))

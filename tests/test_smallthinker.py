@@ -156,6 +156,18 @@ class TestAgainstTheReference:
             assert abs(float(other) - float(base)) > 1e-5, change
 
 
+class TestTheLossIsTheOldExpression:
+    def test_on_the_models_own_logits(self, params, tokens):
+        """``models/loss.py``'s rule against the ``log_softmax`` and the
+        pick that ``causal_lm_loss`` was until PR 39."""
+        model = st.SmallThinker(TINY)
+        logits = model.apply({"params": params}, tokens[:, :-1])
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        old = -jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1).mean()
+        new = st.causal_lm_loss(model, params, tokens)
+        assert abs(float(new) - float(old)) <= 1e-6 * abs(float(old))
+
+
 class TestTheShareOfTheExperts:
     def layer(self, cfg, params, x, index=1):
         module = st.DecoderLayer(cfg, cfg.windowed[index],
