@@ -943,6 +943,14 @@ ATTN_HEADS_PER_BLOCK_LAST = gauge(
     "([B, S, H * D]) the fewest that fill whole 128-lane tiles (2 at "
     "D = 64): set at trace time, beside hvd_attn_group_last.",
     ("kernel",))
+ATTN_OPERAND_LAYOUT_LAST = gauge(
+    "hvd_attn_operand_layout_last",
+    "How the LAST traced multi-tile flash-attention call of each kind "
+    "(kernel = fwd, dq, dkv) took its operands: 1 tokens-major ([B, S, "
+    "H * D] as a projection wrote them, a head a 128-lane block the index "
+    "map finds: nothing transposed in HBM), 0 head-major ([BH, S, D]): set "
+    "at trace time, beside hvd_attn_tiles_last.",
+    ("kernel",))
 ATTN_KV_GROUP_LAST = gauge(
     "hvd_attn_kv_group_last",
     "Query heads that read one key/value head in the LAST traced multi-tile "

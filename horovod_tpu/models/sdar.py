@@ -52,7 +52,7 @@ import jax.numpy as jnp
 
 from ..attribution import (SCOPE_BLOCK_ATTN_PROJ, SCOPE_BLOCK_EMBED,
                            SCOPE_BLOCK_HEAD, SCOPE_BLOCK_NORM)
-from ..ops.attention import block_diffusion_attention
+from ..ops.attention import block_diffusion_streams
 from ..parallel import moe
 from ..profiler import annotate_collective
 from .loss import token_cross_entropy
@@ -127,11 +127,13 @@ def visible(block_length: int, seq_len: int):
             | (~q_noisy & ~k_noisy & (k_blk <= q_blk)))
 
 
-def dense_block_diffusion_attention(q, k, v, dtype, block_length):
-    """``q [B, 2S, H, D]``, ``k``, ``v [B, 2S, KV heads, D]``, the noisy
-    half first; the masked softmax in float32 over the whole square, the
+def dense_block_diffusion_attention(noisy, clean, dtype, block_length):
+    """Each stream's ``(q [B, S, H, D], k, v [B, S, KV heads, D])`` ->
+    each stream's context ``[B, S, H, D]``; the masked softmax in float32
+    over the whole square of the doubled stream, the noisy half first, the
     keys and values of a group repeated: the fallback where no kernel
     runs."""
+    q, k, v = (jnp.concatenate(pair, axis=1) for pair in zip(noisy, clean))
     group = q.shape[2] // k.shape[2]
     k, v = (jnp.repeat(x.astype(jnp.float32), group, axis=2) for x in (k, v))
     scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
@@ -139,53 +141,69 @@ def dense_block_diffusion_attention(q, k, v, dtype, block_length):
     seen = visible(block_length, q.shape[1] // 2)
     out = jnp.einsum("bhqk,bkhd->bqhd",
                      jax.nn.softmax(jnp.where(seen, scores, -1e30), -1), v)
-    return out.astype(dtype)
+    return tuple(jnp.split(out.astype(dtype), 2, axis=1))
 
 
-def flash_attention_fn(q, k, v, dtype, block_length, interpret: bool = False,
-                       block: int | None = None):
-    """Adapter plugging ``block_diffusion_attention`` into ``Sdar``:
-    ``[B, 2S, heads, D]`` -> transpose -> the two kernel calls and the
-    merge, the keys and values with their own, smaller number of heads.
-    ``block`` is for tests that want several tiles of a short sequence."""
-    out = block_diffusion_attention(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3), block_length, block_q=block, block_k=block,
-        interpret=interpret)
-    return out.transpose(0, 2, 1, 3).astype(dtype)
+def flash_attention_fn(noisy, clean, dtype, block_length,
+                       interpret: bool = False, block: int | None = None):
+    """Adapter plugging ``block_diffusion_streams`` into ``Sdar``: each
+    stream's ``(q [B, S, heads, D], k, v [B, S, KV heads, D])`` ->
+    transpose -> the two kernel calls and the merge, the keys and values
+    with their own, smaller number of heads -> each stream's context ``[B,
+    S, heads, D]``. ``block`` is for tests that want several tiles of a
+    short sequence."""
+    outs = block_diffusion_streams(
+        *([x.transpose(0, 2, 1, 3) for x in stream]
+          for stream in (noisy, clean)),
+        block_length, block_q=block, block_k=block, interpret=interpret)
+    return tuple(out.transpose(0, 2, 1, 3).astype(dtype) for out in outs)
 
 
 class TwoStreamAttention(nn.Module):
+    """``attention_fn(noisy, clean, dtype, block_length)`` is handed each
+    stream's ``(q, k, v)`` and returns each stream's context."""
     config: SdarConfig
     attention_fn: Callable | None = None
 
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.config
+        half = x.shape[1] // 2
 
         def project(name, width):
             return nn.Dense(width, use_bias=False, dtype=cfg.dtype,
                             param_dtype=jnp.float32, name=name)
 
-        def heads(y, count):
-            return y.reshape(x.shape[:2] + (count, cfg.head_dim))
-
-        q = heads(project("query", cfg.num_heads * cfg.head_dim)(x),
-                  cfg.num_heads)
-        k = heads(project("key", cfg.num_kv_heads * cfg.head_dim)(x),
-                  cfg.num_kv_heads)
-        v = heads(project("value", cfg.num_kv_heads * cfg.head_dim)(x),
-                  cfg.num_kv_heads)
+        projections = [
+            (project(name, count * cfg.head_dim), count)
+            for name, count in (("query", cfg.num_heads),
+                                ("key", cfg.num_kv_heads),
+                                ("value", cfg.num_kv_heads))]
         # QK-norm a head: over its 128 lanes, one learned scale for all
         # heads, before RoPE (OLMoE's is over the whole projection).
-        q = RMSNorm(cfg.rms_norm_eps, name="q_norm")(q)
-        k = RMSNorm(cfg.rms_norm_eps, name="k_norm")(k)
-        q = rope(q, cfg.rope_theta, positions).astype(cfg.dtype)
-        k = rope(k, cfg.rope_theta, positions).astype(cfg.dtype)
+        norms = [RMSNorm(cfg.rms_norm_eps, name="q_norm"),
+                 RMSNorm(cfg.rms_norm_eps, name="k_norm")]
+        out = project("out", cfg.hidden_size)
+
+        def stream(rows):
+            """One stream's ``(q, k, v)``. The two are cut here, where a
+            row is the hidden size wide: cut after the projections, the
+            halves of q, k, v and of the context are 134 MB copies a
+            layer around the kernels, which take a stream each."""
+            q, k, v = (
+                dense(x[:, rows]).reshape(x.shape[0], half, count,
+                                          cfg.head_dim)
+                for dense, count in projections)
+            q, k = (rope(norm(y), cfg.rope_theta, positions[rows]).astype(
+                cfg.dtype) for norm, y in zip(norms, (q, k)))
+            return q, k, v
+
         attend = self.attention_fn or dense_block_diffusion_attention
-        out = attend(q, k, v, cfg.dtype, cfg.block_length)
-        return project("out", cfg.hidden_size)(
-            out.reshape(x.shape[:2] + (-1,)))
+        return jnp.concatenate([
+            out(context.reshape(x.shape[0], half, -1))
+            for context in attend(stream(slice(None, half)),
+                                  stream(slice(half, None)), cfg.dtype,
+                                  cfg.block_length)], axis=1)
 
 
 class DecoderLayer(nn.Module):

@@ -46,16 +46,19 @@ attention kernels are not run twice. With and without
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..attribution import (SCOPE_BLOCK_ATTN_PROJ, SCOPE_BLOCK_EMBED,
                            SCOPE_BLOCK_HEAD, SCOPE_BLOCK_NORM,
                            SCOPE_MOE_ROUTE)
-from ..ops.attention import flash_attention
+from ..ops.attention import flash_attention, flash_attention_tokens_major
+from ..ops.heads import map_heads
 from ..parallel import moe
 from ..profiler import annotate_collective
 from .loss import token_cross_entropy
@@ -150,17 +153,77 @@ def dense_window_attention(q, k, v, dtype, window=None):
     return out.astype(dtype)
 
 
+def rotary_tables(dim: int, theta: float, positions):
+    """``(cos, sin) [..., S, dim]`` of ``rope``'s half-split rotation at
+    ``positions`` (``[S]`` or ``[B, S]``) for ``rotate_head``: each half
+    written out twice, the sine's first half negated."""
+    half = dim // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = positions.astype(jnp.float32)[..., None] * inv_freq
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    return (jnp.concatenate([cos, cos], -1),
+            jnp.concatenate([-sin, sin], -1))
+
+
+def rotate_head(head, cos, sin, dtype):
+    """``rope`` of one head ``[B, S, D]`` as ``dtype``, the same products
+    and sums lane for lane: lane ``i`` pairs with lane ``i +- D/2``, turned
+    in beside it **by the MXU**, as a product with the permutation matrix
+    (a ``jnp.roll`` of the lanes is two slices of half a 128-lane tile,
+    which XLA writes out padded and reads back). One 1 a column makes the
+    product exact: ``HIGHEST`` sends a float32 head, and the float32
+    cotangent on the way back, through as three bfloat16 pieces that sum
+    to it again. Rounded to ``dtype`` here, a head at a time: the
+    cotangent of a rounding of all heads at once is the whole array in
+    float32 (256 MB at SDAR's shapes, live at the step's peak)."""
+    dim = head.shape[-1]
+    turn = jnp.asarray(np.roll(np.eye(dim, dtype=np.float32), dim // 2, 1),
+                       head.dtype)
+    turned = jnp.dot(head, turn, precision=jax.lax.Precision.HIGHEST,
+                     preferred_element_type=jnp.float32)
+    return (head * cos + turned * sin).astype(dtype)
+
+
+def rope_tokens_major(x, heads: int, theta: float, dtype, positions=None):
+    """``rope(...).astype(dtype)`` of ``x [B, S, heads * D]`` where a
+    projection wrote it: a head after another on the lanes that hold it
+    (``ops/heads.py``), nothing re-tiled on the way to the kernels."""
+    if positions is None:
+        positions = jnp.arange(x.shape[1])
+    return map_heads(
+        functools.partial(rotate_head, dtype=dtype), heads, (x,),
+        constants=rotary_tables(x.shape[-1] // heads, theta, positions))
+
+
 def flash_attention_fn(q, k, v, dtype, window=None, interpret: bool = False,
                        block: int | None = None):
     """Adapter plugging the causal Pallas flash kernels into
-    ``SmallThinker``: ``[B, S, heads, D]`` -> transpose -> kernel, the keys
-    and values with their own, smaller number of heads. ``block`` is for
-    tests that want several tiles of a short sequence."""
-    out = flash_attention(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3), causal=True, window=window, block_q=block,
-        block_k=block, interpret=interpret)
-    return out.transpose(0, 2, 1, 3).astype(dtype)
+    ``SmallThinker``, the keys and values with their own, smaller number of
+    heads. **A windowed layer's** ``[B, S, heads, D]`` is ``[B, S, heads *
+    D]`` as the projections wrote it, and the tokens-major entry takes
+    that: heads of whole 128-lane blocks reach the kernels where they lie
+    (narrower ones, ``models/granite.py``'s 64, are transposed inside the
+    entry). **A layer of full attention** is transposed to ``[B, heads, S,
+    D]`` here, as every layer was until PR 40: fed tokens-major the kernels
+    take 3 to 8% longer (a tile is 32 pieces of 4 KB where it was 128 KB in
+    a row), a full layer computes twice a windowed layer's tiles, and two
+    such layers took 202.8 ms where these take 192.6 (windowed ones with
+    RoPE 109.5 against 118.1: PERF.md, PR 40). ``block`` is for tests that
+    want several tiles of a short sequence."""
+    tiles = dict(causal=True, window=window, block_q=block, block_k=block,
+                 interpret=interpret)
+    if window is None:
+        out = flash_attention(
+            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3), **tiles)
+        return out.transpose(0, 2, 1, 3).astype(dtype)
+
+    def rows(x):
+        return x.reshape(x.shape[:2] + (-1,))
+
+    out = flash_attention_tokens_major(rows(q), rows(k), rows(v),
+                                       q.shape[2], **tiles)
+    return out.reshape(q.shape).astype(dtype)
 
 
 class GroupedAttention(nn.Module):
@@ -180,17 +243,16 @@ class GroupedAttention(nn.Module):
         def heads(y, count):
             return y.reshape(x.shape[:2] + (count, cfg.head_dim))
 
-        q = heads(project("query", cfg.num_heads * cfg.head_dim)(x),
-                  cfg.num_heads)
-        k = heads(project("key", cfg.num_kv_heads * cfg.head_dim)(x),
-                  cfg.num_kv_heads)
-        v = heads(project("value", cfg.num_kv_heads * cfg.head_dim)(x),
-                  cfg.num_kv_heads)
+        q = project("query", cfg.num_heads * cfg.head_dim)(x)
+        k = project("key", cfg.num_kv_heads * cfg.head_dim)(x)
+        v = project("value", cfg.num_kv_heads * cfg.head_dim)(x)
         if self.rotary:
-            q = rope(q, cfg.rope_theta).astype(cfg.dtype)
-            k = rope(k, cfg.rope_theta).astype(cfg.dtype)
+            q = rope_tokens_major(q, cfg.num_heads, cfg.rope_theta, cfg.dtype)
+            k = rope_tokens_major(k, cfg.num_kv_heads, cfg.rope_theta,
+                                  cfg.dtype)
         attend = self.attention_fn or dense_window_attention
-        out = attend(q, k, v, cfg.dtype,
+        out = attend(heads(q, cfg.num_heads), heads(k, cfg.num_kv_heads),
+                     heads(v, cfg.num_kv_heads), cfg.dtype,
                      cfg.window if self.windowed else None)
         return project("out", cfg.hidden_size)(
             out.reshape(x.shape[:2] + (-1,)))

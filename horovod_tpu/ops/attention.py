@@ -28,7 +28,12 @@ Two implementations, one semantics:
   sequences run
   (batch*heads, Q blocks, K blocks) with K innermost in the forward and
   the dq kernel and (batch*heads, K blocks, Q blocks) with Q innermost in
-  the dk/dv kernel, one K/V (or Q/dO) tile resident a step. Differentiable:
+  the dk/dv kernel, one K/V (or Q/dO) tile resident a step. These too
+  take tokens-major operands where a head is whole 128-lane blocks (``D %
+  128 == 0``): grids, bodies and the order of tiles stay, a head is the
+  lane block the index maps find (``_block_at``), so the results are the
+  head-major call's bits, and ``hvd_attn_operand_layout_last{kernel}``
+  says which way a call was fed. Differentiable:
   a ``jax.custom_vjp`` supplies the backward kernels from saved
   (out, logsumexp) residuals, so ring attention trains end-to-end.
 - ``blockwise_attention_reference``: pure-jnp same math; the numerics
@@ -75,12 +80,14 @@ A causal call may round its diagonal to *blocks* instead
 (``block_length=B``): query ``i`` sees the keys of the blocks up to its own
 (``j // B <= i // B``) or, with ``before_block``, of the blocks before it.
 ``B`` divides tiles and offsets, so the tile plan is the causal one and only
-the mask inside the diagonal tiles differs. ``block_diffusion_attention``
+the mask inside the diagonal tiles differs. ``block_diffusion_streams``
 puts the two together for block-diffusion training, whose step attends over
 a noisy and a clean copy of a sequence side by side: two calls over the
 clean keys, each on the grid of an ``S x S`` causal call, and the noisy
 queries' own block of ``B`` keys in plain XLA, merged through the
-log-sum-exp. No array of ``2S x 2S`` exists. Under the scope
+log-sum-exp. No array of ``2S x 2S`` exists. It takes the two streams apart
+and returns them apart (a model cuts them where a row is narrowest);
+``block_diffusion_attention`` is the same over one array. Under the scope
 ``hvd.attn.blockdiff``; ``hvd_attn_tiles_last{kind=blockdiff_*}`` counts
 both calls.
 """
@@ -97,6 +104,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..attribution import (SCOPE_ATTN_BLOCKDIFF, SCOPE_ATTN_BWD,
                            SCOPE_ATTN_FWD, SCOPE_ATTN_WINDOW)
+from .heads import map_heads
 
 # Every ``pallas_call`` here carries this one name. XLA names a custom
 # call's instruction after the innermost component of its name stack, a
@@ -856,12 +864,12 @@ def _kind_scope(window):
 
 
 def _fwd_call(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
-              interpret, window=None, blocks=None):
+              interpret, window=None, blocks=None, heads=None):
     from ..profiler import annotate_collective
 
     with _kind_scope(window), annotate_collective(SCOPE_ATTN_FWD):
         return _fwd_kernels(qr, kr, vr, causal, block_q, block_k, q_offset,
-                            k_offset, interpret, window, blocks)
+                            k_offset, interpret, window, blocks, heads)
 
 
 def _kv_head(bh, group):
@@ -871,17 +879,32 @@ def _kv_head(bh, group):
     return bh if group == 1 else bh // group
 
 
+def _block_at(heads=None):
+    """``(slice, block of its rows) ->`` that block's index in an operand
+    of the multi-tile kernels, a slice a (batch, head) pair counted in that
+    order. Head-major operands ``[BH, S, D]`` (``heads`` is ``None``) hold
+    a slice whole; tokens-major ones ``[B, S, heads * D]`` hold it as the
+    ``D`` lanes of head ``slice % heads`` in row ``slice // heads``, a lane
+    block of its own where ``D`` is whole 128-lane tiles. The blocks are
+    ``(1, block, D)`` either way: the same tiles reach the kernels in the
+    same order, so what they return is the same bits."""
+    if heads is None:
+        return lambda n, block: (n, block, 0)
+    return lambda n, block: (n // heads, block, n % heads)
+
+
 def _kv_index_map(causal, num_kb, block_q, block_k, q_offset, k_offset,
-                  window=None, group=1, behind=0):
+                  window=None, group=1, behind=0, at=_block_at()):
     """K/V block of grid step (bh, q block i, K step j), K innermost. A
     causal call stops at the last tile q block ``i`` computes: a
     ``pl.when`` alone would still have the pipeline fetch the skipped
     steps' blocks, and a block index that does not change fetches nothing.
     Under a window step ``j`` is the k block ``j`` past the first that
     ``i`` sees, as in the kernels, stopped at the last in the same way.
+    ``at`` is the keys' and values' ``_block_at``.
     """
     if not causal:
-        return lambda bh, i, j: (_kv_head(bh, group), j, 0)
+        return lambda bh, i, j: at(_kv_head(bh, group), j)
 
     def block(i, j):
         last = _last_k_block(i, num_kb, block_q, block_k, q_offset, k_offset,
@@ -891,7 +914,7 @@ def _kv_index_map(causal, num_kb, block_q, block_k, q_offset, k_offset,
         return jnp.minimum(j + _first_k_block(
             i, num_kb, block_q, block_k, q_offset, k_offset, window), last)
 
-    return lambda bh, i, j: (_kv_head(bh, group), block(i, j), 0)
+    return lambda bh, i, j: at(_kv_head(bh, group), block(i, j))
 
 
 def _q_block(causal, num_qb, block_q, block_k, q_offset, k_offset,
@@ -987,13 +1010,39 @@ def _single_tile_bwd(qr, kr, vr, do, lse, delta, g_lse, causal, block_q,
     )(qr, kr, vr, do, *((out, lse) if from_out else (lse, delta, g_lse))))
 
 
+def _tiled_shapes(qr, kr, heads):
+    """``(slices BH, Sq, Sk, D, query heads a key/value head)`` of the
+    multi-tile kernels' operands, head-major ``[BH, S, D]`` or, with
+    ``heads``, tokens-major ``[B, S, heads * D]`` (``_block_at``)."""
+    if heads is None:
+        BH, Sq, D = qr.shape
+        return BH, Sq, kr.shape[1], D, BH // kr.shape[0]
+    B, Sq, width = qr.shape
+    return (B * heads, Sq, kr.shape[1], width // heads,
+            width // kr.shape[2])
+
+
+def _record_layout(heads, *kernels):
+    """At trace time, beside ``_record_tiles``:
+    ``hvd_attn_operand_layout_last{kernel}``, 1 where the multi-tile
+    kernel took its operands tokens-major and 0 where head-major."""
+    from .. import metrics
+
+    for kernel in kernels:
+        metrics.ATTN_OPERAND_LAYOUT_LAST.set(int(heads is not None),
+                                             kernel=kernel)
+
+
 def _fwd_kernels(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
-                 interpret, window=None, blocks=None):
-    BH, Sq, D = qr.shape
-    Sk = kr.shape[1]
-    group = BH // kr.shape[0]
+                 interpret, window=None, blocks=None, heads=None):
+    """``(out, lse)``: the output in the operands' layout, head-major
+    ``[BH, S, D]`` or with ``heads`` tokens-major ``[B, S, heads * D]``
+    (the multi-tile kernels alone: a one-tile tokens-major call is
+    ``_flash_tokens_major``'s), the log-sum-exp ``[BH, 1, S]`` rows."""
+    BH, Sq, Sk, D, group = _tiled_shapes(qr, kr, heads)
     scale = 1.0 / (D ** 0.5)
-    if _single_tile(Sq, Sk, block_q, block_k, window, group, blocks):
+    if heads is None and _single_tile(Sq, Sk, block_q, block_k, window,
+                                      group, blocks):
         return _single_tile_fwd(qr, kr, vr, causal, block_q, block_k,
                                 q_offset, k_offset, interpret)
     num_qb, num_kb = Sq // block_q, Sk // block_k
@@ -1006,24 +1055,27 @@ def _fwd_kernels(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
     behind = _behind(blocks)
     band_kb, _ = _record_tiles(causal, num_qb, num_kb, block_q, block_k,
                                q_offset, k_offset, window, group, behind)
+    _record_layout(heads, "fwd")
+    q_at = _block_at(heads)
     kv_spec = pl.BlockSpec((1, block_k, D),
                            _kv_index_map(causal, num_kb, block_q, block_k,
                                          q_offset, k_offset, window, group,
-                                         behind))
+                                         behind,
+                                         _block_at(heads and heads // group)))
     return pl.pallas_call(
         kernel,
         grid=(BH, num_qb, band_kb),
         in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((1, block_q, D), lambda bh, i, j: q_at(bh, i)),
             kv_spec,
             kv_spec,
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((1, block_q, D), lambda bh, i, j: q_at(bh, i)),
             pl.BlockSpec((1, 1, Sq), lambda bh, i, j: (bh, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, Sq, D), qr.dtype),
+            jax.ShapeDtypeStruct(qr.shape, qr.dtype),
             jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32),
         ],
         scratch_shapes=[
@@ -1037,20 +1089,22 @@ def _fwd_kernels(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
 
 
 def _flash_bwd(causal, block_q, block_k, q_offset, k_offset, interpret,
-               res, g, g_lse=None, window=None, blocks=None):
+               res, g, g_lse=None, window=None, blocks=None, heads=None):
     from ..profiler import annotate_collective
 
     with _kind_scope(window), annotate_collective(SCOPE_ATTN_BWD):
         return _bwd_kernels(causal, block_q, block_k, q_offset, k_offset,
-                            interpret, res, g, g_lse, window, blocks)
+                            interpret, res, g, g_lse, window, blocks, heads)
 
 
 def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
-                 res, g, g_lse, window=None, blocks=None):
+                 res, g, g_lse, window=None, blocks=None, heads=None):
+    """``(dq, dk, dv)`` in the layout of the residuals' q, k, v, which
+    ``heads`` says as in ``_fwd_kernels``; the cotangent ``g`` is laid out
+    as the output, ``g_lse`` as the log-sum-exp."""
     qr, kr, vr, out, lse = res
-    BH, Sq, D = qr.shape
-    BHkv, Sk = kr.shape[:2]
-    group = BH // BHkv
+    BH, Sq, Sk, D, group = _tiled_shapes(qr, kr, heads)
+    BHkv = BH // group
     scale = 1.0 / (D ** 0.5)
     do = g
     if g_lse is None:
@@ -1059,10 +1113,20 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
         g_lse = jnp.asarray(g_lse, jnp.float32).reshape(lse.shape)
     # delta_i = rowsum(dO_i * O_i) — the softmax-jacobian correction term;
     # cheap elementwise reduce, XLA fuses it.
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)[:, None, :]  # [BH, 1, Sq]
+    if heads is None:
+        delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                        axis=-1)[:, None, :]  # [BH, 1, Sq]
+    else:
+        # the same sum a head, over the lanes that hold it (``map_heads``:
+        # to sum over part of the lanes of a [B, S, H * D] array, XLA
+        # re-lays both operands out, 117 MB each at SmallThinker's shapes)
+        delta = map_heads(
+            lambda do, out: jnp.sum(
+                do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1),
+            heads, (do, out), rows_out=True).reshape(BH, 1, Sq)
 
-    if _single_tile(Sq, Sk, block_q, block_k, window, group, blocks):
+    if heads is None and _single_tile(Sq, Sk, block_q, block_k, window,
+                                      group, blocks):
         return _single_tile_bwd(qr, kr, vr, do, lse, delta, g_lse, causal,
                                 block_q, block_k, q_offset, k_offset,
                                 interpret)
@@ -1072,15 +1136,17 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
     band_kb, band_qb = _record_tiles(causal, num_qb, num_kb, block_q,
                                      block_k, q_offset, k_offset, window,
                                      group, behind)
+    _record_layout(heads, "dq", "dkv")
+    q_at, kv_at = _block_at(heads), _block_at(heads and heads // group)
     kv_spec = pl.BlockSpec((1, block_k, D),
                            _kv_index_map(causal, num_kb, block_q, block_k,
                                          q_offset, k_offset, window, group,
-                                         behind))
+                                         behind, kv_at))
     q_specs = [
-        pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
+        pl.BlockSpec((1, block_q, D), lambda bh, i, j: q_at(bh, i)),
         kv_spec,
         kv_spec,
-        pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
+        pl.BlockSpec((1, block_q, D), lambda bh, i, j: q_at(bh, i)),
         pl.BlockSpec((1, 1, Sq), lambda bh, i, j: (bh, 0, 0)),
         pl.BlockSpec((1, 1, Sq), lambda bh, i, j: (bh, 0, 0)),
         pl.BlockSpec((1, 1, Sq), lambda bh, i, j: (bh, 0, 0)),
@@ -1093,8 +1159,9 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
         ),
         grid=(BH, num_qb, band_kb),
         in_specs=q_specs,
-        out_specs=pl.BlockSpec((1, block_q, D), lambda bh, i, j: (bh, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, Sq, D), qr.dtype),
+        out_specs=pl.BlockSpec((1, block_q, D),
+                               lambda bh, i, j: q_at(bh, i)),
+        out_shape=jax.ShapeDtypeStruct(qr.shape, qr.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
         name=KERNEL_NAME,
@@ -1105,8 +1172,8 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
     if group == 1:
         grid = (BH, num_kb, band_qb)
         q_spec = pl.BlockSpec((1, block_q, D),
-                              lambda bh, j, i: (bh, q_block(j, i), 0))
-        k_spec = pl.BlockSpec((1, block_k, D), lambda bh, j, i: (bh, j, 0))
+                              lambda bh, j, i: q_at(bh, q_block(j, i)))
+        k_spec = pl.BlockSpec((1, block_k, D), lambda bh, j, i: kv_at(bh, j))
         row_spec = pl.BlockSpec((1, 1, Sq), lambda bh, j, i: (bh, 0, 0))
     else:
         # One key/value head's tile stays resident while the group's query
@@ -1114,9 +1181,9 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
         grid = (BHkv, num_kb, group, band_qb)
         q_spec = pl.BlockSpec(
             (1, block_q, D),
-            lambda bh, j, h, i: (bh * group + h, q_block(j, i), 0))
+            lambda bh, j, h, i: q_at(bh * group + h, q_block(j, i)))
         k_spec = pl.BlockSpec((1, block_k, D),
-                              lambda bh, j, h, i: (bh, j, 0))
+                              lambda bh, j, h, i: kv_at(bh, j))
         row_spec = pl.BlockSpec((1, 1, Sq),
                                 lambda bh, j, h, i: (bh * group + h, 0, 0))
     dk, dv = pl.pallas_call(
@@ -1130,8 +1197,8 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
                   row_spec],
         out_specs=[k_spec, k_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((BHkv, Sk, D), kr.dtype),
-            jax.ShapeDtypeStruct((BHkv, Sk, D), vr.dtype),
+            jax.ShapeDtypeStruct(kr.shape, kr.dtype),
+            jax.ShapeDtypeStruct(vr.shape, vr.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
@@ -1205,6 +1272,42 @@ def _flash_tokens_major_bwd(heads, causal, block_q, block_k, q_offset,
 _flash_tokens_major.defvjp(_flash_tokens_major_fwd, _flash_tokens_major_bwd)
 
 
+# The multi-tile kernels on tokens-major operands whose heads are whole
+# 128-lane blocks: q, k, v, the output and every gradient ``[B, S, H * D]``,
+# the log-sum-exp and its cotangent ``[BH, 1, S]`` rows as ever. The kernels,
+# their grids and their order of tiles are ``_flash_with_lse``'s; only the
+# index maps differ (``_block_at``).
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11))
+def _flash_tokens_major_tiles(q, k, v, heads, causal, block_q, block_k,
+                              q_offset, k_offset, interpret, window=None,
+                              blocks=None):
+    return _fwd_call(q, k, v, causal, block_q, block_k, q_offset, k_offset,
+                     interpret, window, blocks, heads)
+
+
+def _flash_tokens_major_tiles_fwd(q, k, v, heads, causal, block_q, block_k,
+                                  q_offset, k_offset, interpret, window,
+                                  blocks):
+    out, lse = _fwd_call(q, k, v, causal, block_q, block_k, q_offset,
+                         k_offset, interpret, window, blocks, heads)
+    return (out, lse), (q, k, v, out, lse)
+
+
+def _flash_tokens_major_tiles_bwd(heads, causal, block_q, block_k, q_offset,
+                                  k_offset, interpret, window, blocks, res,
+                                  gs):
+    g, g_lse = gs
+    if g_lse is None or g_lse.dtype == jax.dtypes.float0:
+        g_lse = None
+    return _flash_bwd(causal, block_q, block_k, q_offset, k_offset,
+                      interpret, res, g, g_lse, window, blocks, heads)
+
+
+_flash_tokens_major_tiles.defvjp(_flash_tokens_major_tiles_fwd,
+                                 _flash_tokens_major_tiles_bwd)
+
+
 def _prepare_flash(q, k, v, causal, block_q, block_k, q_offset, k_offset,
                    window=None, blocks=None):
     """Shared validation + block selection for the flash entry points —
@@ -1271,11 +1374,7 @@ def _flash(q, k, v, causal, block_q, block_k, q_offset, k_offset, interpret,
     """``[B, H, Sq, D]``, ``[B, KV heads, Sk, D]`` twice -> ``(out [B, H,
     Sq, D], lse [B, H, Sq])``: both entry points' one way to the kernels."""
     B, H, Sq, D = q.shape
-    if before_block and block_length is None:
-        raise ValueError("before_block=True hides a query's own block: it "
-                         "needs a block_length")
-    blocks = None if block_length is None else (block_length,
-                                                bool(before_block))
+    blocks = _block_mask(block_length, before_block)
     block_q, block_k, window = _prepare_flash(
         q, k, v, causal, block_q, block_k, q_offset, k_offset, window,
         blocks)
@@ -1359,31 +1458,24 @@ def flash_attention_lse(q, k, v, causal: bool = False,
                   interpret, window, block_length, before_block)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("num_heads", "causal", "block_q", "block_k", "q_offset",
-                     "k_offset", "interpret", "window"),
-)
-def flash_attention_tokens_major(q, k, v, num_heads: int,
-                                 causal: bool = False,
-                                 block_q: int | None = None,
-                                 block_k: int | None = None,
-                                 q_offset: int = 0, k_offset: int = 0,
-                                 interpret: bool = False,
-                                 window: int | None = None):
-    """:func:`flash_attention` for operands where a projection wrote
-    them, tokens major: q ``[B, S, H * D]`` with ``H = num_heads``, k, v
-    ``[B, S, KV heads * D]`` → ``[B, S, H * D]``, the gradients of q, k, v
-    in their layouts too. Which layout an array holds cannot be read from
-    its shape, so the caller says it by the entry it calls.
+def _block_mask(block_length, before_block):
+    """A block mask's ``(block length, before the own block)``, or
+    ``None`` for a call without one."""
+    if before_block and block_length is None:
+        raise ValueError("before_block=True hides a query's own block: it "
+                         "needs a block_length")
+    return None if block_length is None else (block_length,
+                                              bool(before_block))
 
-    A sequence that is one tile, with a head of keys and values a query
-    head, no window and heads whose lanes tile 128 (``_heads_per_block``),
-    goes to the single-tile kernels as it lies: a block is the lanes of
-    whole heads (two at D = 64) of a group of batch rows, found by the
-    block's index map, and nothing is transposed in HBM. Any other call
-    transposes to ``[B, H, S, D]`` and is :func:`flash_attention`'s, bit
-    for bit, so no caller needs to know which it is."""
+
+def _flash_tokens(q, k, v, num_heads, causal, block_q, block_k, q_offset,
+                  k_offset, interpret, window, block_length=None,
+                  before_block=False, with_lse=False):
+    """``[B, Sq, H * D]``, ``[B, Sk, KV heads * D]`` twice -> ``(out [B,
+    Sq, H * D], lse [B, H, Sq])``, the log-sum-exp ``None`` unless asked
+    for: the tokens-major entry points' one way to the kernels. What
+    decides the way is what the call's shapes show: whether it is one tile
+    (``_single_tile``) and whether a head is whole 128-lane blocks."""
     B, Sq, width = q.shape
     if width % num_heads or k.shape[2] % (width // num_heads):
         raise ValueError(
@@ -1391,20 +1483,95 @@ def flash_attention_tokens_major(q, k, v, num_heads: int,
             f"k, v [B, S, KV heads * D]; got q={q.shape}, k={k.shape} with "
             f"num_heads={num_heads}")
     d = width // num_heads
+    blocks = _block_mask(block_length, before_block)
 
     def head_major(x):
         return x.reshape(x.shape[:2] + (-1, d)).transpose(0, 2, 1, 3)
 
     tile_q, tile_k, seen = _prepare_flash(
         *(jax.eval_shape(head_major, x) for x in (q, k, v)), causal,
-        block_q, block_k, q_offset, k_offset, window)
-    if (_heads_per_block(d, num_heads) is not None and _single_tile(
-            Sq, k.shape[1], tile_q, tile_k, seen, width // k.shape[2])):
+        block_q, block_k, q_offset, k_offset, window, blocks)
+    one_tile = _single_tile(Sq, k.shape[1], tile_q, tile_k, seen,
+                            width // k.shape[2], blocks)
+    if one_tile and not with_lse \
+            and _heads_per_block(d, num_heads) is not None:
         return _flash_tokens_major(q, k, v, num_heads, causal, tile_q,
-                                   tile_k, q_offset, k_offset, interpret)
-    out = _flash(head_major(q), head_major(k), head_major(v), causal,
-                 block_q, block_k, q_offset, k_offset, interpret, window)[0]
-    return out.transpose(0, 2, 1, 3).reshape(B, Sq, width)
+                                   tile_k, q_offset, k_offset,
+                                   interpret), None
+    if d % LANES == 0 and not one_tile:
+        out, lse = _flash_tokens_major_tiles(
+            q, k, v, num_heads, causal, tile_q, tile_k, q_offset, k_offset,
+            interpret, seen, blocks)
+        return out, lse.reshape(B, num_heads, Sq)
+    out, lse = _flash(head_major(q), head_major(k), head_major(v), causal,
+                      block_q, block_k, q_offset, k_offset, interpret,
+                      window, block_length, before_block)
+    return out.transpose(0, 2, 1, 3).reshape(B, Sq, width), lse
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("num_heads", "causal", "block_q", "block_k", "q_offset",
+                     "k_offset", "interpret", "window", "block_length",
+                     "before_block"),
+)
+def flash_attention_tokens_major(q, k, v, num_heads: int,
+                                 causal: bool = False,
+                                 block_q: int | None = None,
+                                 block_k: int | None = None,
+                                 q_offset: int = 0, k_offset: int = 0,
+                                 interpret: bool = False,
+                                 window: int | None = None,
+                                 block_length: int | None = None,
+                                 before_block: bool = False):
+    """:func:`flash_attention` for operands where a projection wrote
+    them, tokens major: q ``[B, S, H * D]`` with ``H = num_heads``, k, v
+    ``[B, S, KV heads * D]`` → ``[B, S, H * D]``, the gradients of q, k, v
+    in their layouts too. Which layout an array holds cannot be read from
+    its shape, so the caller says it by the entry it calls.
+
+    A sequence that is one tile, with a head of keys and values a query
+    head, no window, no block mask and heads whose lanes tile 128
+    (``_heads_per_block``), goes to the single-tile kernels as it lies: a
+    block is the lanes of whole heads (two at D = 64) of a group of batch
+    rows, found by the block's index map. Any other call whose heads are
+    whole 128-lane blocks (``D % 128 == 0``) goes to the multi-tile
+    kernels as it lies: their grids, bodies and order of tiles are the
+    head-major call's and a head is the lane block the index maps find
+    (``_block_at``), so the context and every gradient are
+    :func:`flash_attention`'s bit for bit, under a window, grouped keys
+    and values and a block mask too. Nothing is transposed in HBM either
+    way; ``hvd_attn_operand_layout_last`` says which way the multi-tile
+    kernels were fed. What is left (several tiles of narrower heads: two
+    heads a lane block would want lane masks in three more kernels)
+    transposes to ``[B, H, S, D]`` and is :func:`flash_attention`'s, so no
+    caller needs to know which it is."""
+    return _flash_tokens(q, k, v, num_heads, causal, block_q, block_k,
+                         q_offset, k_offset, interpret, window, block_length,
+                         before_block)[0]
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("num_heads", "causal", "block_q", "block_k", "q_offset",
+                     "k_offset", "interpret", "window", "block_length",
+                     "before_block"),
+)
+def flash_attention_tokens_major_lse(q, k, v, num_heads: int,
+                                     causal: bool = False,
+                                     block_q: int | None = None,
+                                     block_k: int | None = None,
+                                     q_offset: int = 0, k_offset: int = 0,
+                                     interpret: bool = False,
+                                     window: int | None = None,
+                                     block_length: int | None = None,
+                                     before_block: bool = False):
+    """:func:`flash_attention_tokens_major` that also returns the per-row
+    logsumexp ``[B, H, Sq]`` (fp32), as :func:`flash_attention_lse` does
+    and differentiable through it in the same way."""
+    return _flash_tokens(q, k, v, num_heads, causal, block_q, block_k,
+                         q_offset, k_offset, interpret, window, block_length,
+                         before_block, with_lse=True)
 
 
 # ---------------------------------------------------------------------------
@@ -1510,15 +1677,15 @@ def _record_blockdiff_tiles(S, block_length, block_q, block_k):
                                 kind="blockdiff_grid")
 
 
-def block_diffusion_attention(q, k, v, block_length: int,
-                              block_q: int | None = None,
-                              block_k: int | None = None,
-                              interpret: bool = False):
+def block_diffusion_streams(noisy, clean, block_length: int,
+                            block_q: int | None = None,
+                            block_k: int | None = None,
+                            interpret: bool = False):
     """Attention of one block-diffusion training step (BD3-LMs,
-    arXiv:2503.09573) over the doubled stream ``[x_t ; x_0]``: ``q [B, H,
-    2S, D]``, ``k``, ``v [B, KV heads, 2S, D]``, the noisy half first, both
-    halves at positions ``0..S-1`` -> ``[B, H, 2S, D]``. With ``blk(i) =
-    pos(i) // block_length``:
+    arXiv:2503.09573) over a noisy and a clean copy of the same ``S``
+    tokens, each stream given apart as its ``(q [B, H, S, D], k, v [B, KV
+    heads, S, D])``, both at positions ``0..S-1`` -> ``(noisy [B, H, S,
+    D], clean [B, H, S, D])``. With ``blk(i) = pos(i) // block_length``:
 
     * a clean query sees the clean keys of ``blk(j) <= blk(i)``;
     * a noisy query sees the clean keys of ``blk(j) < blk(i)`` and the
@@ -1535,18 +1702,20 @@ def block_diffusion_attention(q, k, v, block_length: int,
     attention merges its shards, differentiably on both sides. All of it
     under ``hvd.attn.blockdiff``, which is opened here and nowhere else;
     the gauge ``hvd_attn_tiles_last{kind=blockdiff_*}`` counts both calls
-    (``_record_blockdiff_tiles``)."""
+    (``_record_blockdiff_tiles``). A caller that holds the two streams in
+    one array cuts it before the projections, where a row is narrowest
+    (``models/sdar.py``), or calls :func:`block_diffusion_attention`."""
     from ..profiler import annotate_collective
 
-    S = q.shape[2] // 2
-    if q.shape[2] != 2 * S or k.shape[2] != 2 * S or S % block_length:
+    (q_noisy, k_noisy, v_noisy), (q_clean, k_clean, v_clean) = noisy, clean
+    S = q_clean.shape[2]
+    if (q_noisy.shape[2] != S or k_noisy.shape[2] != S
+            or k_clean.shape[2] != S or S % block_length):
         raise ValueError(
             f"block-diffusion attention wants a noisy and a clean half of "
-            f"whole blocks of {block_length}; got q={q.shape}, k={k.shape}")
+            f"whole blocks of {block_length}; got q={q_noisy.shape} and "
+            f"{q_clean.shape}, k={k_noisy.shape} and {k_clean.shape}")
     with annotate_collective(SCOPE_ATTN_BLOCKDIFF):
-        q_noisy, q_clean = q[:, :, :S], q[:, :, S:]
-        k_noisy, k_clean = k[:, :, :S], k[:, :, S:]
-        v_noisy, v_clean = v[:, :, :S], v[:, :, S:]
         _record_blockdiff_tiles(S, block_length, block_q, block_k)
         tiles = dict(block_q=block_q, block_k=block_k, interpret=interpret)
         clean = flash_attention(q_clean, k_clean, v_clean, causal=True,
@@ -1554,6 +1723,24 @@ def block_diffusion_attention(q, k, v, block_length: int,
         past, lse_past = flash_attention_lse(
             q_noisy, k_clean, v_clean, causal=True,
             block_length=block_length, before_block=True, **tiles)
-        noisy = _merge_own_block(q_noisy, k_noisy, v_noisy, past, lse_past,
-                                 block_length)
-        return jnp.concatenate([noisy, clean], axis=2)
+        return _merge_own_block(q_noisy, k_noisy, v_noisy, past, lse_past,
+                                block_length), clean
+
+
+def block_diffusion_attention(q, k, v, block_length: int,
+                              block_q: int | None = None,
+                              block_k: int | None = None,
+                              interpret: bool = False):
+    """:func:`block_diffusion_streams` over the doubled stream ``[x_t ;
+    x_0]`` in one array: ``q [B, H, 2S, D]``, ``k``, ``v [B, KV heads, 2S,
+    D]``, the noisy half first -> ``[B, H, 2S, D]``."""
+    S = q.shape[2] // 2
+    if q.shape[2] != 2 * S or k.shape[2] != 2 * S:
+        raise ValueError(
+            f"block-diffusion attention wants a noisy and a clean half of "
+            f"whole blocks of {block_length}; got q={q.shape}, k={k.shape}")
+    noisy, clean = block_diffusion_streams(
+        (q[:, :, :S], k[:, :, :S], v[:, :, :S]),
+        (q[:, :, S:], k[:, :, S:], v[:, :, S:]), block_length,
+        block_q=block_q, block_k=block_k, interpret=interpret)
+    return jnp.concatenate([noisy, clean], axis=2)

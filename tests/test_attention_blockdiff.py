@@ -17,6 +17,7 @@ import pytest
 from horovod_tpu import attribution, metrics
 from horovod_tpu.ops import attention
 from horovod_tpu.ops.attention import (LSE_MASKED, block_diffusion_attention,
+                                       block_diffusion_streams,
                                        flash_attention, flash_attention_lse)
 
 
@@ -308,6 +309,37 @@ class TestBlockDiffusionAttention:
         assert re.search(r"jvp\(hvd\.attn\.blockdiff\)/exp", text)
         assert re.search(
             r"transpose\(jvp\(hvd\.attn\.blockdiff\)\)/mul", text)
+
+    @pytest.mark.parametrize("name", ["a-head-each", "grouped-by-4"])
+    def test_the_streams_given_apart_are_the_doubled_stream_cut(self, name):
+        """``block_diffusion_streams`` takes each stream's q, k, v and
+        returns each stream's result (PR 40: ``models/sdar.py`` cuts the
+        streams before the projections); the entry over one array is that
+        with a cut and a concatenation around it, bit for bit, forward
+        and in the three gradients."""
+        heads, kv_heads, seq, dim, tile, length = CASES[name]
+        q, k, v, w = operands(2, heads, kv_heads, 2 * seq, dim, seed=8)
+        call = dict(block_q=tile, block_k=tile, interpret=True)
+
+        def apart(q, k, v):
+            return jnp.concatenate(block_diffusion_streams(
+                (q[:, :, :seq], k[:, :, :seq], v[:, :, :seq]),
+                (q[:, :, seq:], k[:, :, seq:], v[:, :, seq:]), length,
+                **call), axis=2)
+
+        def doubled(q, k, v):
+            return block_diffusion_attention(q, k, v, length, **call)
+
+        got, pull_back = jax.vjp(apart, q, k, v)
+        want, want_back = jax.vjp(doubled, q, k, v)
+        for a, b in zip((got,) + pull_back(w), (want,) + want_back(w)):
+            np.testing.assert_array_equal(a, b)
+
+    def test_streams_of_another_length_are_refused(self):
+        q, k, v, _ = operands(1, 2, 2, 64, 16)
+        with pytest.raises(ValueError, match="whole blocks"):
+            block_diffusion_streams((q[:, :, :32], k, v), (q, k, v), 4,
+                                    interpret=True)
 
     def test_the_gauge_counts_both_plans_where_they_differ(self):
         """Blocks of a tile's length: the noisy stream's call leaves the

@@ -154,6 +154,179 @@ class TestAnyOtherCallTransposes:
             flash_attention_tokens_major(q, q, q, 3, interpret=True)
 
 
+# ---------------------------------------------------------------------------
+# PR 40: several tiles of heads that are whole 128-lane blocks go to the
+# multi-tile kernels as they lie, a head the lane block the index maps find
+# ---------------------------------------------------------------------------
+
+# batch, S, H, KV heads, tile, call. D = 128 throughout: the narrowest head
+# that is a lane block of its own.
+TILED = {
+    "full": ((2, 64, 2, 2, 16), dict()),
+    "causal": ((2, 64, 2, 2, 16), dict(causal=True)),
+    "causal-tiles-of-32-on-16": ((1, 64, 2, 2, (32, 16)), dict(causal=True)),
+    "a-window": ((1, 64, 2, 2, 16), dict(causal=True, window=24)),
+    "grouped-7-on-1": ((1, 64, 7, 1, 16), dict(causal=True)),
+    "grouped-8-on-1-under-a-window": (
+        (1, 64, 8, 1, 16), dict(causal=True, window=24)),
+    "grouped-4-on-2-two-rows": ((2, 48, 4, 2, 16), dict(causal=True)),
+    "blocks-of-4": ((1, 64, 4, 2, 16), dict(causal=True, block_length=4)),
+    "before-the-own-block": (
+        (1, 64, 4, 2, 16),
+        dict(causal=True, block_length=4, before_block=True)),
+    "offsets": ((1, 64, 2, 1, 16),
+                dict(causal=True, q_offset=32, k_offset=16)),
+}
+LAYOUT_KERNELS = ("fwd", "dq", "dkv")
+
+
+def layout_gauge():
+    return [int(metrics.ATTN_OPERAND_LAYOUT_LAST.labels(kernel=kernel).get())
+            for kernel in LAYOUT_KERNELS]
+
+
+def mark_layout_gauge(value):
+    for kernel in LAYOUT_KERNELS:
+        metrics.ATTN_OPERAND_LAYOUT_LAST.set(value, kernel=kernel)
+
+
+def tiled_both(name, dtype, with_lse):
+    """``(out, lse, dq, dk, dv)`` of the tokens-major entry and of the
+    head-major one on the transposed operands (``lse`` left out without
+    ``with_lse``), a cotangent on the log-sum-exp too where there is one,
+    and what each left in ``hvd_attn_operand_layout_last``."""
+    (batch, seq, heads, kv_heads, tile), call = TILED[name]
+    block_q, block_k = tile if isinstance(tile, tuple) else (tile, tile)
+    call = dict(call, block_q=block_q, block_k=block_k, interpret=True)
+    dim = 128
+    q, k, v, weight = operands(batch, seq, heads, kv_heads, dim, dtype)
+    lse_weight = jax.random.normal(jax.random.PRNGKey(7),
+                                   (batch, heads, seq), jnp.float32)
+
+    def here(q, k, v):
+        if with_lse:
+            return att.flash_attention_tokens_major_lse(
+                q, k, v, num_heads=heads, **call)
+        return (flash_attention_tokens_major(q, k, v, num_heads=heads,
+                                             **call),)
+
+    def there(q, k, v):
+        operands_ = [head_major(x, dim) for x in (q, k, v)]
+        if with_lse:
+            out, lse = att.flash_attention_lse(*operands_, **call)
+            return tokens_major(out), lse
+        return (tokens_major(flash_attention(*operands_, **call)),)
+
+    found = []
+    for fn in (here, there):
+        mark_layout_gauge(-1)
+        outs, vjp = jax.vjp(fn, q, k, v)
+        cotangents = (weight.astype(outs[0].dtype),) + (
+            (lse_weight,) if with_lse else ())
+        found.append((tuple(outs) + vjp(cotangents), layout_gauge()))
+    return found
+
+
+class TestSeveralTilesOfWholeLaneBlocksGoAsTheyLie:
+    @pytest.mark.parametrize("with_lse", [False, True],
+                             ids=["context", "context-and-lse"])
+    @pytest.mark.parametrize("name", sorted(TILED))
+    def test_every_result_is_the_head_major_calls_bit_for_bit(self, name,
+                                                              with_lse):
+        (here, layout), (there, head_major_layout) = tiled_both(
+            name, jnp.float32, with_lse)
+        assert len(here) == len(there) == (5 if with_lse else 4)
+        for a, b in zip(here, there):
+            assert a.shape == b.shape and a.dtype == b.dtype
+            np.testing.assert_array_equal(a, b)
+        assert np.asarray(here[0]).any() and np.asarray(here[-1]).any()
+        assert layout == [1, 1, 1]
+        assert head_major_layout == [0, 0, 0]
+
+    @pytest.mark.parametrize("name", ["a-window", "grouped-7-on-1",
+                                      "before-the-own-block"])
+    def test_in_bfloat16_as_the_cells_run_it(self, name):
+        (here, layout), (there, _) = tiled_both(name, jnp.bfloat16, True)
+        for a, b in zip(here, there):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                          np.asarray(b, np.float32))
+        assert here[0].dtype == jnp.bfloat16 and layout == [1, 1, 1]
+
+    def test_the_kernels_are_the_head_major_calls_but_for_the_index_maps(
+            self):
+        """Same grid, same three kernels, same number of tiles computed:
+        what differs between the two lowered programs' kernels is where a
+        block is looked for."""
+        q, k, v, _ = operands(1, 64, 4, 2, 128, jnp.float32)
+        call = dict(causal=True, window=24, block_q=16, block_k=16,
+                    interpret=True)
+
+        def tiles():
+            return {kind: int(metrics.ATTN_TILES_LAST.labels(kind=kind).get())
+                    for kind in ("computed", "skipped", "grid")}
+
+        jax.grad(lambda q, k, v: flash_attention_tokens_major(
+            q, k, v, num_heads=4, **call).sum(), (0, 1, 2))(q, k, v)
+        lying = tiles()
+        jax.grad(lambda q, k, v: flash_attention(
+            head_major(q, 128), head_major(k, 128), head_major(v, 128),
+            **call).sum(), (0, 1, 2))(q, k, v)
+        assert lying == tiles() and lying["computed"] > 0
+
+    @pytest.mark.parametrize("name, shape, call, wanted", [
+        # two heads a lane block: lane masks are the one-tile kernels' alone
+        ("narrow-heads", (3, 128, 2, 2, 64), dict(block_q=64, block_k=64),
+         [0, 0, 0]),
+        ("narrow-grouped-heads-as-granite", (1, 128, 4, 1, 64),
+         dict(causal=True, block_q=64, block_k=64), [0, 0, 0]),
+        # one tile of whole lane blocks: the single-tile kernels, as since
+        # PR 35, which the gauge does not count
+        ("one-tile", (3, 128, 2, 2, 128), dict(), [-1, -1, -1]),
+        ("one-tile-of-several-blocks-a-head", (1, 128, 2, 2, 256), dict(),
+         [-1, -1, -1]),
+        ("several-blocks-a-head", (1, 64, 2, 1, 256),
+         dict(causal=True, block_q=16, block_k=16), [1, 1, 1]),
+    ])
+    def test_which_way_a_call_goes_is_read_off_its_shapes(self, name, shape,
+                                                          call, wanted):
+        """The gauge is set where a call is traced, so each case has shapes
+        no other test of this file traces."""
+        batch, seq, heads, kv_heads, dim = shape
+        q, k, v, weight = operands(batch, seq, heads, kv_heads, dim,
+                                   jnp.float32)
+        mark_layout_gauge(-1)
+        out, vjp = jax.vjp(functools.partial(
+            flash_attention_tokens_major, num_heads=heads, interpret=True,
+            **call), q, k, v)
+        here = (out,) + vjp(weight)
+        assert layout_gauge() == wanted
+        out, vjp = jax.vjp(
+            lambda q, k, v: tokens_major(flash_attention(
+                head_major(q, dim), head_major(k, dim), head_major(v, dim),
+                interpret=True, **call)), q, k, v)
+        for a, b in zip(here, (out,) + vjp(weight)):
+            if name.startswith("one-tile"):
+                np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+            else:
+                np.testing.assert_array_equal(a, b)
+
+    def test_the_one_tile_call_with_a_log_sum_exp_transposes(self):
+        """The single-tile tokens-major kernels return no log-sum-exp: a
+        caller that wants one of a one-tile call gets the head-major
+        kernels', as before."""
+        q, k, v, _ = operands(1, 128, 2, 2, 128, jnp.float32)
+        mark_layout_gauge(-1)
+        out, lse = att.flash_attention_tokens_major_lse(
+            q, k, v, num_heads=2, interpret=True)
+        want, want_lse = att.flash_attention_lse(
+            head_major(q, 128), head_major(k, 128), head_major(v, 128),
+            interpret=True)
+        np.testing.assert_array_equal(out, tokens_major(want))
+        np.testing.assert_array_equal(lse, want_lse)
+        assert layout_gauge() == [-1, -1, -1]
+
+
 class TestHeadsABlockAndTheGroup:
     @pytest.mark.parametrize("dim, heads, wanted", [
         (64, 16, 2), (64, 2, 2), (64, 3, None), (128, 7, 1), (256, 2, 1),

@@ -365,6 +365,45 @@ class TestRopeWithPositionIds:
         np.testing.assert_array_equal(out[:, :32], olmoe.rope(x, 1e6))
 
 
+class TestTheStreamsAreCutBeforeTheProjections:
+    """``TwoStreamAttention`` projects, norms and rotates each stream apart
+    and hands its ``attention_fn`` the two of them (PR 40): q, k, v and
+    the context never exist at both streams' length."""
+
+    def test_what_the_adapter_is_handed_and_returns(self, params, batch):
+        seen = []
+
+        def watched(noisy, clean, dtype, block_length):
+            seen.append([tuple(x.shape for x in stream)
+                         for stream in (noisy, clean)])
+            outs = sdar.flash_attention_fn(
+                noisy, clean, dtype, block_length, interpret=True, block=16)
+            assert [out.shape for out in outs] == [noisy[0].shape] * 2
+            return outs
+
+        cfg = dataclasses.replace(TINY, remat=False)
+        sdar.block_diffusion_loss(sdar.Sdar(cfg, attention_fn=watched),
+                                  params, batch)
+        rows, seq = batch["clean"].shape
+        stream = ((rows, seq, cfg.num_heads, cfg.head_dim),) + (
+            (rows, seq, cfg.num_kv_heads, cfg.head_dim),) * 2
+        assert seen == [[stream, stream]] * cfg.num_layers
+
+    def test_the_dense_fallback_takes_and_returns_the_streams_too(self):
+        keys = jax.random.split(jax.random.PRNGKey(14), 6)
+        noisy, clean = (tuple(
+            jax.random.normal(key, (2, 16, heads, 8))
+            for key, heads in zip(three, (4, 2, 2)))
+            for three in (keys[:3], keys[3:]))
+        dense = sdar.dense_block_diffusion_attention(noisy, clean,
+                                                     jnp.float32, 4)
+        flash = sdar.flash_attention_fn(noisy, clean, jnp.float32, 4,
+                                        interpret=True, block=8)
+        for a, b in zip(dense, flash):
+            assert a.shape == b.shape == noisy[0].shape
+            np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-6)
+
+
 class TestConfig:
     def test_the_published_model(self):
         cfg = sdar.SDAR_30B_A3B

@@ -226,6 +226,77 @@ class TestTheShareOfTheExperts:
         assert int(roomy["load"].sum()) == 4 * 2 * SEQ * 3
 
 
+class TestRopeWhereTheProjectionWrote:
+    """``rope_tokens_major``: ``rope`` of ``[B, S, heads * D]``, a head at
+    a time on the lanes that hold it (PR 40), the half turn a product with
+    a permutation matrix."""
+
+    HEADS, DIM = 3, 16
+
+    def x(self, dtype):
+        return jax.random.normal(jax.random.PRNGKey(8),
+                                 (2, 40, self.HEADS * self.DIM),
+                                 jnp.float32).astype(dtype)
+
+    def apart(self, x):
+        return x.reshape(x.shape[:2] + (self.HEADS, self.DIM))
+
+    @pytest.mark.parametrize("positions", [None, "[S]", "[B, S]"])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["float32", "bfloat16"])
+    def test_it_is_rope_of_the_heads_apart(self, dtype, positions):
+        x = self.x(dtype)
+        if positions is not None:
+            per_row = positions == "[B, S]"
+            positions = jnp.arange(40)[::-1] * 3
+            if per_row:
+                positions = jnp.stack([positions, positions[::-1]])
+        got = st.rope_tokens_major(x, self.HEADS, 1e4, jnp.float32,
+                                   positions)
+        want = st.rope(self.apart(x), 1e4, positions).reshape(x.shape)
+        assert got.dtype == want.dtype == jnp.float32
+        # the same products and sums; which of them the compiler contracts
+        # to one rounding is its own choice
+        np.testing.assert_allclose(got, want, rtol=0, atol=5e-7)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["float32", "bfloat16"])
+    def test_and_so_is_its_gradient(self, dtype):
+        x = self.x(dtype)
+        weight = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+
+        def here(x):
+            return (st.rope_tokens_major(x, self.HEADS, 1e4, dtype).astype(
+                jnp.float32) * weight).sum()
+
+        def there(x):
+            return (st.rope(self.apart(x), 1e4).astype(dtype).reshape(
+                x.shape).astype(jnp.float32) * weight).sum()
+
+        got, want = jax.grad(here)(x), jax.grad(there)(x)
+        assert got.dtype == want.dtype == dtype
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want, np.float32),
+            rtol=0, atol=1e-6 if dtype == jnp.float32 else 2 ** -7)
+
+    def test_the_rounding_is_a_heads_own(self):
+        """``dtype`` is applied inside the function a head goes through:
+        no operation of the traced program holds all heads in float32."""
+        x = self.x(jnp.bfloat16)
+
+        def pulled_back(x, cotangent):
+            out, vjp = jax.vjp(lambda x: st.rope_tokens_major(
+                x, self.HEADS, 1e4, jnp.bfloat16), x)
+            return out, vjp(cotangent)
+
+        jaxpr = jax.make_jaxpr(pulled_back)(x, x)
+        whole = [str(var.aval) for eqn in jaxpr.jaxpr.eqns
+                 for var in eqn.outvars
+                 if getattr(var.aval, "shape", ()) == x.shape
+                 and var.aval.dtype == jnp.float32]
+        assert not whole, whole
+
+
 class TestConfig:
     def test_the_published_model(self):
         cfg = st.SMALLTHINKER_21B_A3B

@@ -389,7 +389,10 @@ def test_sdars_two_streams_compile_on_two_causal_grids(one_chip):
     from horovod_tpu.models import sdar
 
     def loss(q, k, v):
-        out = sdar.flash_attention_fn(q, k, v, jnp.bfloat16, 4)
+        streams = ([x[:, rows] for x in (q, k, v)]
+                   for rows in (slice(None, 8192), slice(8192, None)))
+        out = jnp.concatenate(sdar.flash_attention_fn(
+            *streams, jnp.bfloat16, 4), axis=1)
         return out.astype(jnp.float32).sum()
 
     def shaped(heads):
@@ -423,6 +426,135 @@ def test_sdars_two_streams_compile_on_two_causal_grids(one_chip):
     square = [shape for shape in re.findall(r"\[([0-9,]+)\]", compiled)
               if sum(int(d) >= 8192 for d in shape.split(",")) >= 2]
     assert not square, sorted(set(square))
+
+
+def moved_activations(text: str, elements: int) -> list:
+    """``copy`` and ``transpose`` instructions of a compiled program whose
+    result has ``elements`` elements, under a layer's ``attention``
+    module: a query-sized array re-laid out between a projection and a
+    kernel."""
+    import math
+
+    found = []
+    for line in text.splitlines():
+        at = re.match(r"\s*(?:ROOT )?%?(\S+) = \w+\[([\d,]*)\]\S* "
+                      r"(copy|transpose)\(", line)
+        scope = re.search(r'op_name="([^"]*)"', line)
+        if (at and scope and "/attention/" in scope.group(1)
+                and math.prod(int(n) for n in at.group(2).split(",")
+                              if n) == elements):
+            found.append((at.group(1), scope.group(1)))
+    return found
+
+
+def layout_gauge() -> list:
+    from horovod_tpu import metrics
+
+    return [int(metrics.ATTN_OPERAND_LAYOUT_LAST.labels(kernel=kernel).get())
+            for kernel in ("fwd", "dq", "dkv")]
+
+
+def test_a_toy_smallthinker_step_moves_no_query_sized_array(one_chip):
+    """Four layers (full + NoPE, then three windowed with RoPE) of 4 query
+    heads of 128 on 2, one sequence of 2,048 in tiles of 512. **The
+    windowed layers'** projections reach their nine kernels as they lie,
+    RoPE turns a head at a time on the lanes that hold it, and the compiled
+    step holds no ``copy`` or ``transpose`` of a ``[1, 2048, 4 * 128]``
+    array under those layers' attention (the parent's held the context's,
+    forward and recomputed, and dq's, a layer). **The layer of full
+    attention** is fed head-major, as the parent's (its kernels run twice
+    as long, and longer still tokens-major: PERF.md, PR 40). Granite's
+    narrower heads take the same adapter to the transposing call."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models import granite, smallthinker
+
+    cfg = smallthinker.SmallThinkerConfig(
+        vocab_size=512, hidden_size=256, num_layers=4, num_heads=4,
+        num_kv_heads=2, head_dim=128, intermediate_size=128, num_experts=4,
+        top_k=2, capacity_factor=2.0, window=1024)
+    model = smallthinker.SmallThinker(
+        cfg, attention_fn=smallthinker.flash_attention_fn)
+    params = jax.eval_shape(
+        lambda: smallthinker.SmallThinker(cfg).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+
+    def placed(tree):
+        return jax.tree.map(lambda leaf: jax.ShapeDtypeStruct(
+            leaf.shape, leaf.dtype, sharding=one_chip), tree)
+
+    tokens = jax.ShapeDtypeStruct((1, 2049), jnp.int32, sharding=one_chip)
+    lowered = jax.jit(jax.value_and_grad(
+        lambda params, tokens: smallthinker.causal_lm_loss(
+            model, params, tokens))).lower(placed(params), tokens)
+    # the last forward traced is a windowed layer's, the last backward
+    # the first layer's, which is full
+    assert layout_gauge() == [1, 0, 0]
+    text = lowered.compile().as_text()
+    assert len(kernel_instructions(text)) == 3 * cfg.num_layers
+    moved = moved_activations(text, 2048 * 4 * 128)
+    assert moved and all("/layer_0/" in scope for _, scope in moved), moved
+
+    def attend(q, k, v):
+        return granite.flash_attention_fn(q, k, v, jnp.bfloat16).astype(
+            jnp.float32).sum()
+
+    def shaped(heads):
+        return jax.ShapeDtypeStruct((1, 2048, heads, 64), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    jax.jit(jax.grad(attend, argnums=(0, 1, 2))).lower(
+        shaped(8), shaped(2), shaped(2))
+    assert layout_gauge() == [0, 0, 0]
+
+
+def test_a_toy_sdar_step_never_holds_both_streams_heads_in_one_array(
+        one_chip):
+    """Two layers of 4 query heads of 128 on 2 over a noisy and a clean
+    stream of 1,024 each, blocks of 4, tiles of 512. The streams are cut
+    before the projections (PR 40), where a row is the hidden size wide,
+    and the kernels and the merge take a stream each: six kernels a layer,
+    fed head-major (their tiles are contiguous: fed tokens-major they took
+    8.7% longer in the cell, PERF.md), and no array of the compiled step
+    holds the doubled stream's 2,048 rows at the heads' width (the
+    parent's held q, k, v and the context so, and cut and joined them
+    around the kernels)."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models import sdar
+
+    cfg = sdar.SdarConfig(
+        vocab_size=384, hidden_size=256, num_layers=2, num_heads=4,
+        num_kv_heads=2, head_dim=128, intermediate_size=96, num_experts=4,
+        top_k=2, capacity_factor=2.0, block_length=4)
+    model = sdar.Sdar(cfg, attention_fn=sdar.flash_attention_fn)
+    ids = jnp.zeros((1, 8), jnp.int32)
+    params = jax.eval_shape(lambda: sdar.Sdar(cfg).init(
+        jax.random.PRNGKey(0), ids, ids)["params"])
+
+    def placed(tree):
+        return jax.tree.map(lambda leaf: jax.ShapeDtypeStruct(
+            leaf.shape, leaf.dtype, sharding=one_chip), tree)
+
+    def row(dtype):
+        return jax.ShapeDtypeStruct((1, 1024), dtype, sharding=one_chip)
+
+    batch = dict(clean=row(jnp.int32), noisy=row(jnp.int32),
+                 weight=row(jnp.float32))
+    lowered = jax.jit(jax.value_and_grad(
+        lambda params, batch: sdar.block_diffusion_loss(
+            model, params, batch))).lower(placed(params), batch)
+    assert layout_gauge() == [0, 0, 0]
+    text = lowered.compile().as_text()
+    assert len(kernel_instructions(text)) == 6 * cfg.num_layers
+    doubled = sorted({
+        shape for shape in re.findall(r"\w+\[([0-9,]+)\]", text)
+        if "2048" in shape.split(",")
+        and ({"4", "128"} <= set(shape.split(","))
+             or "512" in shape.split(","))})
+    assert not doubled, doubled
 
 
 def test_granites_layers_compile_at_their_widths(one_chip):

@@ -15,7 +15,9 @@ a window under the diagonal, grouped keys and values (8 query heads on 2
 key/value heads), the two block masks of block-diffusion training and
 ``block_diffusion_attention`` over a doubled stream (SDAR's: blocks of 4, 8
 query heads on one key/value head), and that call once more at the SDAR
-cell's own shapes on a sample of rows (``check_sdar_rows``). Compiled, never
+cell's own shapes on a sample of rows (``check_sdar_rows``); and the
+multi-tile kernels fed tokens-major against themselves fed head-major, bit
+for bit (``check_tiles_as_they_lie``). Compiled, never
 ``interpret=True``: off a TPU this exits non-zero.
 
 The tolerance is the one ``tests/test_sequence_parallel.py`` uses for bf16
@@ -229,6 +231,76 @@ def check_tokens_major() -> None:
     check_flash(2, 4, 128, 128, causal=False, tokens_major=True)
 
 
+def check_tiles_as_they_lie() -> None:
+    """The multi-tile kernels on tokens-major operands (PR 40: a head the
+    128-lane block the index maps find) against the same kernels on the
+    transposed operands, compiled: the context, the log-sum-exp and the
+    three gradients **bit for bit**, at SmallThinker's windowed and full
+    layers (28 heads on 4, one sequence of 16,384), at SDAR's two calls (32
+    on 4, 8,192, blocks of 4) and at a plain causal shape; and
+    ``rope_tokens_major`` against ``rope``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from horovod_tpu.models import smallthinker
+    from horovod_tpu.ops.attention import (flash_attention_lse,
+                                           flash_attention_tokens_major_lse)
+
+    def across(x, dim):  # [B, S, H * D] -> [B, H, S, D]
+        return x.reshape(x.shape[:2] + (-1, dim)).transpose(0, 2, 1, 3)
+
+    def back(x):  # [B, H, S, D] -> [B, S, H * D]
+        return x.transpose(0, 2, 1, 3).reshape(x.shape[0], x.shape[2], -1)
+
+    for name, (batch, heads, kv_heads, seq), call in (
+            ("causal", (2, 4, 4, 2048), dict(causal=True)),
+            ("SmallThinker's window", (1, 28, 4, 16384),
+             dict(causal=True, window=4096)),
+            ("SmallThinker's full layer", (1, 28, 4, 16384),
+             dict(causal=True)),
+            ("SDAR's clean stream", (1, 32, 4, 8192),
+             dict(causal=True, block_length=4)),
+            ("SDAR's noisy stream", (1, 32, 4, 8192),
+             dict(causal=True, block_length=4, before_block=True))):
+        dim = 128
+        keys = jax.random.split(jax.random.PRNGKey(3), 5)
+        q, k, v, g = (
+            jax.random.normal(key, (batch, seq, n * dim), jnp.bfloat16)
+            for key, n in zip(keys, (heads, kv_heads, kv_heads, heads)))
+        g_lse = jax.random.normal(keys[4], (batch, heads, seq), jnp.float32)
+
+        def lying(q, k, v):
+            return flash_attention_tokens_major_lse(q, k, v, heads, **call)
+
+        def transposed(q, k, v):
+            out, lse = flash_attention_lse(
+                across(q, dim), across(k, dim), across(v, dim), **call)
+            return back(out), lse
+
+        found = []
+        for fn in (lying, transposed):
+            outs, pull_back = jax.vjp(jax.jit(fn), q, k, v)
+            found.append(tuple(outs) + pull_back((g, g_lse)))
+        for what, a, b in zip(("out", "lse", "dq", "dk", "dv"), *found):
+            np.testing.assert_array_equal(
+                np.asarray(a, np.float32), np.asarray(b, np.float32),
+                err_msg=f"{name}: {what}")
+        print(f"  tokens-major == head-major bit for bit: {name} "
+              f"(H{heads} on KV{kv_heads} S{seq} D{dim})")
+
+    x = jax.random.normal(jax.random.PRNGKey(4), (1, 16384, 28 * 128),
+                          jnp.bfloat16)
+    got = jax.jit(lambda x: smallthinker.rope_tokens_major(
+        x, 28, 1.5e6, jnp.float32))(x)
+    want = jax.jit(lambda x: smallthinker.rope(
+        x.reshape(1, 16384, 28, 128), 1.5e6).reshape(x.shape))(x)
+    worst = float(jnp.abs(got - want).max())
+    print(f"  rope_tokens_major against rope, float32 results of bf16 "
+          f"heads: max |difference| {worst:.3e}")
+    assert worst <= 1e-6 * float(jnp.abs(want).max()), worst
+
+
 def main() -> None:
     import jax
 
@@ -250,6 +322,7 @@ def main() -> None:
     check_flash(1, 16, 4096, 128, causal=True)
     check_masks()
     check_sdar_rows()
+    check_tiles_as_they_lie()
     print("kernels ok")
 
 
