@@ -14,9 +14,8 @@ from .olmoe import (  # noqa: F401
     Olmoe,
     OlmoeConfig,
     causal_lm_loss,
-    routing_stats,
-    take_expert_window,
 )
+from .experts import routing_stats, take_expert_window  # noqa: F401
 from .olmo_hybrid import (  # noqa: F401
     OLMO_HYBRID_7B,
     OLMO_HYBRID_TINY,

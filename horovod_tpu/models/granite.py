@@ -36,7 +36,7 @@ with their own 8 heads.
 
 **Recomputation** (``remat``, on by default): every layer is wrapped in
 ``nn.remat`` with the policy SmallThinker's and SDAR's layers have
-(``recompute.save_kernels_and_projections``), so the forward pass keeps a
+(``parts.save_kernels_and_projections``), so the forward pass keeps a
 layer's input, what the flash forward kernel returned and the results of
 the products without a batch dimension (a Mamba-2 layer's 8,512- and
 16,384-wide rows: 0.23 GiB a layer at 4,096 tokens), and the backward pass
@@ -63,13 +63,11 @@ from ..ops.linear_attention import short_conv
 from ..ops.ssd import ssd_scan
 from ..profiler import annotate_collective
 from .loss import token_cross_entropy
-from .olmo_hybrid import _decay_rate, _step_bias
-from .olmoe import RMSNorm
-from .recompute import save_kernels_and_projections
-from .smallthinker import (  # noqa: F401 — the adapters are this model's too
-    dense_window_attention,
-    flash_attention_fn,
-)
+from .parts import (RMSNorm, decay_rate, dense_window_attention,
+                    grouped_flash_attention, projection, recomputed,
+                    step_bias)
+
+flash_attention_fn = grouped_flash_attention  # benchmark/configs' name
 
 MAMBA, ATTENTION = "mamba", "attention"
 PERIOD = (MAMBA,) * 5 + (ATTENTION,) + (MAMBA,) * 4
@@ -153,11 +151,6 @@ GRANITE_TINY = GraniteConfig(  # test-sized: three Mamba layers, one attention
 )
 
 
-def _dense(cfg, features: int, name: str):
-    return nn.Dense(features, use_bias=False, dtype=cfg.dtype,
-                    param_dtype=jnp.float32, name=name)
-
-
 class Mamba2Mixer(nn.Module):
     config: GraniteConfig
 
@@ -169,7 +162,7 @@ class Mamba2Mixer(nn.Module):
         inner, f32 = cfg.mamba_inner, jnp.float32
         mixed = inner + 2 * groups * state  # x, B and C pass the convolution
         z, xbc, dt = jnp.split(
-            _dense(cfg, inner + mixed + heads, "in_proj")(x),
+            projection(cfg, inner + mixed + heads, "in_proj")(x),
             [inner, inner + mixed], axis=-1)
         # torch's Conv1d default: weights and bias uniform within
         # 1 / sqrt(taps)
@@ -180,8 +173,8 @@ class Mamba2Mixer(nn.Module):
         conv_bias = self.param(
             "conv_bias", lambda key, shape, dtype: jax.random.uniform(
                 key, shape, dtype, -bound, bound), (mixed,), f32)
-        a_log = self.param("A_log", _decay_rate, (heads,), f32)
-        dt_bias = self.param("dt_bias", _step_bias, (heads,), f32)
+        a_log = self.param("A_log", decay_rate, (heads,), f32)
+        dt_bias = self.param("dt_bias", step_bias, (heads,), f32)
         skip = self.param("D", nn.initializers.ones, (heads,), f32)
         with annotate_collective(SCOPE_SSM_CONV):
             xbc = jax.nn.silu(short_conv(xbc, conv, conv_bias))
@@ -198,7 +191,7 @@ class Mamba2Mixer(nn.Module):
                 z.astype(f32))
             out = RMSNorm(cfg.rms_norm_eps, name="norm")(out).astype(
                 cfg.dtype)
-        return _dense(cfg, cfg.hidden_size, "out_proj")(out)
+        return projection(cfg, cfg.hidden_size, "out_proj")(out)
 
 
 class GroupedAttention(nn.Module):
@@ -212,14 +205,15 @@ class GroupedAttention(nn.Module):
         def heads(y, count):
             return y.reshape(x.shape[:2] + (count, cfg.head_dim))
 
-        kv_width = cfg.num_key_value_heads * cfg.head_dim
-        q = heads(_dense(cfg, cfg.hidden_size, "query")(x),
+        kv_heads = cfg.num_key_value_heads
+        q = heads(projection(cfg, cfg.hidden_size, "query")(x),
                   cfg.num_attention_heads)
-        k = heads(_dense(cfg, kv_width, "key")(x), cfg.num_key_value_heads)
-        v = heads(_dense(cfg, kv_width, "value")(x), cfg.num_key_value_heads)
+        k = heads(projection(cfg, kv_heads * cfg.head_dim, "key")(x), kv_heads)
+        v = heads(projection(cfg, kv_heads * cfg.head_dim, "value")(x),
+                  kv_heads)
         attend = self.attention_fn or dense_window_attention
         out = attend((q * cfg.query_scale).astype(cfg.dtype), k, v, cfg.dtype)
-        return _dense(cfg, cfg.hidden_size, "out")(
+        return projection(cfg, cfg.hidden_size, "out")(
             out.reshape(x.shape[:2] + (-1,)))
 
 
@@ -230,9 +224,9 @@ class GatedMLP(nn.Module):
     def __call__(self, x):
         cfg = self.config
         gate, up = jnp.split(
-            _dense(cfg, 2 * cfg.shared_intermediate_size, "input")(x), 2,
+            projection(cfg, 2 * cfg.shared_intermediate_size, "input")(x), 2,
             axis=-1)
-        return _dense(cfg, cfg.hidden_size, "output")(
+        return projection(cfg, cfg.hidden_size, "output")(
             jax.nn.silu(gate) * up)
 
 
@@ -274,9 +268,7 @@ class Granite(nn.Module):
     @nn.compact
     def __call__(self, input_ids):
         cfg = self.config
-        layer = HybridLayer
-        if cfg.remat:
-            layer = nn.remat(HybridLayer, policy=save_kernels_and_projections)
+        layer = recomputed(HybridLayer, cfg)
         # nn.Embed's initialiser; one leaf, read as rows here and as the
         # head's columns below (tie_word_embeddings)
         embedding = self.param(

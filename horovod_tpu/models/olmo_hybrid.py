@@ -40,7 +40,6 @@ parameters, norms, convolution weights and gates (``A_log``, ``dt_bias``,
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any, Callable
 
 import flax.linen as nn
@@ -53,12 +52,11 @@ from ..attribution import (SCOPE_BLOCK_ATTN_PROJ, SCOPE_BLOCK_EMBED,
                            SCOPE_LINATTN_GATE)
 from ..ops.linear_attention import gated_delta_rule, short_conv
 from ..profiler import annotate_collective
-from .olmoe import (  # noqa: F401 — the adapters are this model's too
-    RMSNorm,
-    dense_causal_attention,
-    flash_attention_fn,
-    rope,
-)
+from .parts import (RMSNorm, decay_rate, dense_causal_attention,
+                    head_major_flash_attention, projection, rope, step_bias,
+                    untied_head)
+
+flash_attention_fn = head_major_flash_attention  # benchmark/configs' name
 
 LINEAR, FULL = "linear_attention", "full_attention"
 PERIOD = (LINEAR, LINEAR, LINEAR, FULL)
@@ -135,24 +133,6 @@ OLMO_HYBRID_TINY = OlmoHybridConfig(  # test-sized: one period
 )
 
 
-def _dense(cfg, features: int, name: str):
-    return nn.Dense(features, use_bias=False, dtype=cfg.dtype,
-                    param_dtype=jnp.float32, name=name)
-
-
-def _decay_rate(key, shape, dtype=jnp.float32):
-    """``A_log``: the log of a rate drawn from (1, 16)."""
-    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
-
-
-def _step_bias(key, shape, dtype=jnp.float32):
-    """``dt_bias``: softplus's inverse of a step drawn log-uniformly from
-    (0.001, 0.1), so that at the seed ``g`` is about ``-rate x step``."""
-    step = jnp.exp(jax.random.uniform(
-        key, shape, dtype, math.log(1e-3), math.log(1e-1)))
-    return step + jnp.log(-jnp.expm1(-step))
-
-
 class GatedDeltaNet(nn.Module):
     """The linear-attention mixer over this model's window of the heads."""
 
@@ -168,16 +148,16 @@ class GatedDeltaNet(nn.Module):
         def projected(name, width):
             """``x``'s projection and the convolution's weights for it
             (torch's Conv1d default: uniform within 1 / sqrt(taps))."""
-            return _dense(cfg, heads * width, name)(x), self.param(
+            return projection(cfg, heads * width, name)(x), self.param(
                 name + "_conv", nn.initializers.variance_scaling(
                     1 / 3, "fan_in", "uniform", in_axis=-1, out_axis=-2),
                 (heads * width, cfg.linear_conv_kernel_dim), f32)
 
         before = [projected("query", d_k), projected("key", d_k),
                   projected("value", d_v)]
-        gate = _dense(cfg, heads * d_v, "gate")(x)
-        a_log = self.param("A_log", _decay_rate, (heads,), f32)
-        dt_bias = self.param("dt_bias", _step_bias, (heads,), f32)
+        gate = projection(cfg, heads * d_v, "gate")(x)
+        a_log = self.param("A_log", decay_rate, (heads,), f32)
+        dt_bias = self.param("dt_bias", step_bias, (heads,), f32)
         with annotate_collective(SCOPE_LINATTN_CONV):
             q, k, v = (
                 jax.nn.silu(short_conv(y, w)).reshape(
@@ -196,7 +176,7 @@ class GatedDeltaNet(nn.Module):
             out = RMSNorm(cfg.rms_norm_eps, name="o_norm")(out) \
                 * jax.nn.silu(gate.astype(f32)).reshape(out.shape)
             out = out.astype(cfg.dtype).reshape(x.shape[:2] + (-1,))
-        return _dense(cfg, cfg.hidden_size, "out")(out)
+        return projection(cfg, cfg.hidden_size, "out")(out)
 
 
 def _l2norm(x, eps: float = 1e-6):
@@ -217,15 +197,15 @@ class FullAttention(nn.Module):
         width = cfg.window * cfg.head_dim
         heads = x.shape[:2] + (cfg.window, cfg.head_dim)
         q = RMSNorm(cfg.rms_norm_eps, name="q_norm")(
-            _dense(cfg, width, "query")(x)).reshape(heads)
+            projection(cfg, width, "query")(x)).reshape(heads)
         k = RMSNorm(cfg.rms_norm_eps, name="k_norm")(
-            _dense(cfg, width, "key")(x)).reshape(heads)
+            projection(cfg, width, "key")(x)).reshape(heads)
         if cfg.rope_theta is not None:
             q, k = rope(q, cfg.rope_theta), rope(k, cfg.rope_theta)
-        v = _dense(cfg, width, "value")(x).reshape(heads)
+        v = projection(cfg, width, "value")(x).reshape(heads)
         attend = self.attention_fn or dense_causal_attention
         out = attend(q.astype(cfg.dtype), k.astype(cfg.dtype), v, cfg.dtype)
-        return _dense(cfg, cfg.hidden_size, "out")(
+        return projection(cfg, cfg.hidden_size, "out")(
             out.reshape(x.shape[:2] + (width,)))
 
 
@@ -235,9 +215,10 @@ class GatedMLP(nn.Module):
     @nn.compact
     def __call__(self, x):
         cfg = self.config
-        hidden = jax.nn.silu(_dense(cfg, cfg.intermediate_size, "gate")(x)) \
-            * _dense(cfg, cfg.intermediate_size, "up")(x)
-        return _dense(cfg, cfg.hidden_size, "down")(hidden)
+        width = cfg.intermediate_size
+        hidden = jax.nn.silu(projection(cfg, width, "gate")(x)) \
+            * projection(cfg, width, "up")(x)
+        return projection(cfg, cfg.hidden_size, "down")(hidden)
 
 
 class HybridLayer(nn.Module):
@@ -282,13 +263,7 @@ class OlmoHybrid(nn.Module):
             x = HybridLayer(cfg, kind, self.attention_fn,
                             name=f"layer_{i}")(x)
         with annotate_collective(SCOPE_BLOCK_HEAD):
-            x = RMSNorm(cfg.rms_norm_eps, name="ln_out")(x).astype(cfg.dtype)
-            # bf16 in, f32 out on the MXU, as models/olmoe.py's head.
-            head = self.param("lm_head", nn.initializers.lecun_normal(),
-                              (cfg.hidden_size, cfg.vocab_size), jnp.float32)
-            return jax.lax.dot_general(
-                x, head.astype(cfg.dtype), (((x.ndim - 1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            return untied_head(self, x)
 
 
 def causal_lm_loss(model: OlmoHybrid, params, tokens):

@@ -27,18 +27,16 @@ masked positions' own tokens (labels in place, no shift), each weighted by
 
     L = 1 / (R S) * sum_i m_i / t_blk(i) * -log softmax(z_i)[x0_i]
 
-TPU-first choices, as ``models/smallthinker.py`` (whose recomputation
-policy this model shares, ``models/recompute.py``) and ``models/olmoe.py``
-(whose ``RMSNorm``, ``rope`` and experts module, ``SparseExperts``, it
-uses): bfloat16 activations with float32 parameters, norms, RoPE and
-router; the experts through ``parallel/moe.py``'s slots, one row of the
-doubled stream a
-routing group (pairs take an expert's slots in stream order, the noisy
-half first, then pick order). A model may hold a window of the experts
-(``experts_here`` from ``first_expert`` on), one chip's share of expert
-parallelism: the router keeps its width and a token's gates are normalised
-over all eight picks wherever they live, so the shares' outputs add up to
-the whole layer's.
+TPU-first choices, as the other decoders (``models/parts.py`` holds the
+norm, RoPE and the recomputation policy, ``models/experts.py`` the experts
+module, ``SparseExperts``): bfloat16 activations with float32 parameters,
+norms, RoPE and router; the experts through ``parallel/moe.py``'s slots,
+one row of the doubled stream a routing group (pairs take an expert's
+slots in stream order, the noisy half first, then pick order). A model may
+hold a window of the experts (``experts_here`` from ``first_expert`` on),
+one chip's share of expert parallelism: the router keeps its width and a
+token's gates are normalised over all eight picks wherever they live, so
+the shares' outputs add up to the whole layer's.
 """
 
 from __future__ import annotations
@@ -53,16 +51,14 @@ import jax.numpy as jnp
 from ..attribution import (SCOPE_BLOCK_ATTN_PROJ, SCOPE_BLOCK_EMBED,
                            SCOPE_BLOCK_HEAD, SCOPE_BLOCK_NORM)
 from ..ops.attention import block_diffusion_streams
-from ..parallel import moe
 from ..profiler import annotate_collective
+from .experts import ExpertWindow, SparseExperts
 from .loss import token_cross_entropy
-from .olmoe import (RMSNorm, SparseExperts, rope,  # noqa: F401
-                    routing_stats, take_expert_window)
-from .recompute import save_kernels_and_projections
+from .parts import RMSNorm, projection, recomputed, rope, untied_head
 
 
 @dataclasses.dataclass(frozen=True)
-class SdarConfig:
+class SdarConfig(ExpertWindow):
     vocab_size: int = 151936
     hidden_size: int = 2048
     num_layers: int = 48
@@ -88,22 +84,10 @@ class SdarConfig:
                 f"{self.num_kv_heads} key/value heads evenly")
 
     @property
-    def experts_held(self) -> int:
-        if self.experts_here is None:
-            return self.num_experts - self.first_expert
-        return self.experts_here
-
-    @property
     def mask_id(self) -> int:
         """The mask token: the last id of the vocabulary held, which the
         data never draws and no label is."""
         return self.vocab_size - 1
-
-    def capacity(self, stream_len: int) -> int:
-        """Slots an expert gets for one row of ``stream_len`` positions,
-        both halves of it."""
-        return moe.expert_capacity(self.capacity_factor, stream_len,
-                                   self.top_k, self.num_experts)
 
 
 SDAR_30B_A3B = SdarConfig()
@@ -169,13 +153,8 @@ class TwoStreamAttention(nn.Module):
     def __call__(self, x, positions):
         cfg = self.config
         half = x.shape[1] // 2
-
-        def project(name, width):
-            return nn.Dense(width, use_bias=False, dtype=cfg.dtype,
-                            param_dtype=jnp.float32, name=name)
-
         projections = [
-            (project(name, count * cfg.head_dim), count)
+            (projection(cfg, count * cfg.head_dim, name), count)
             for name, count in (("query", cfg.num_heads),
                                 ("key", cfg.num_kv_heads),
                                 ("value", cfg.num_kv_heads))]
@@ -183,7 +162,7 @@ class TwoStreamAttention(nn.Module):
         # heads, before RoPE (OLMoE's is over the whole projection).
         norms = [RMSNorm(cfg.rms_norm_eps, name="q_norm"),
                  RMSNorm(cfg.rms_norm_eps, name="k_norm")]
-        out = project("out", cfg.hidden_size)
+        out = projection(cfg, cfg.hidden_size, "out")
 
         def stream(rows):
             """One stream's ``(q, k, v)``. The two are cut here, where a
@@ -241,10 +220,7 @@ class Sdar(nn.Module):
     def __call__(self, noisy_ids, clean_ids):
         cfg = self.config
         seq_len = noisy_ids.shape[1]
-        layer = DecoderLayer
-        if cfg.remat:
-            layer = nn.remat(DecoderLayer,
-                             policy=save_kernels_and_projections)
+        layer = recomputed(DecoderLayer, cfg)
         # Two streams side by side, each counting its positions from zero.
         positions = jnp.concatenate([jnp.arange(seq_len)] * 2)
         with annotate_collective(SCOPE_BLOCK_EMBED):
@@ -256,14 +232,7 @@ class Sdar(nn.Module):
                 x, positions)
         with annotate_collective(SCOPE_BLOCK_HEAD):
             # The head reads the noisy half: only its positions are scored.
-            x = RMSNorm(cfg.rms_norm_eps, name="ln_out")(
-                x[:, :seq_len]).astype(cfg.dtype)
-            # bf16 in, f32 out on the MXU, as models/bert.py's head.
-            head = self.param("lm_head", nn.initializers.lecun_normal(),
-                              (cfg.hidden_size, cfg.vocab_size), jnp.float32)
-            return jax.lax.dot_general(
-                x, head.astype(cfg.dtype), (((x.ndim - 1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            return untied_head(self, x[:, :seq_len])
 
 
 def noisy_batch(key, clean_ids, block_length: int, mask_id: int):

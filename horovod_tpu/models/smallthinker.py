@@ -21,20 +21,21 @@ expert block's:
 The gates are the softmax over the six picked logits. A final RMSNorm and
 an untied head over every position; no auxiliary loss.
 
-TPU-first choices, as ``models/olmoe.py`` (whose ``RMSNorm``, ``rope`` and
-capacity slots this model uses): bfloat16 activations with float32
-parameters, norms, RoPE and router; attention through the framework's flash
-kernels (``attention_fn=``), which take the window and the grouped keys and
-values as they are (nothing is repeated in HBM); the experts through
-``parallel/moe.py``'s slots, one sequence a routing group. A model may hold
-a window of the experts (``experts_here`` from ``first_expert`` on), one
-chip's share of expert parallelism: the router keeps its width and a
-token's gates are normalised over all six picks, wherever they live, so
-the shares' outputs add up to the whole layer's.
+TPU-first choices, as the other decoders (``models/parts.py`` holds the
+norm, RoPE and the adapters, ``models/experts.py`` the capacity slots):
+bfloat16 activations with float32 parameters, norms, RoPE and router;
+attention through the framework's flash kernels (``attention_fn=``), which
+take the window and the grouped keys and values as they are (nothing is
+repeated in HBM); the experts through ``parallel/moe.py``'s slots, one
+sequence a routing group. A model may hold a window of the experts
+(``experts_here`` from ``first_expert`` on), one chip's share of expert
+parallelism: the router keeps its width and a token's gates are normalised
+over all six picks, wherever they live, so the shares' outputs add up to
+the whole layer's.
 
 **Recomputation** (``remat``, on by default): a decoder layer is wrapped in
 ``nn.remat`` (``jax.checkpoint``), so the forward pass keeps a layer's
-input and, by the policy (``save_kernels_and_projections``), the flash
+input and, by the policy (``parts.save_kernels_and_projections``), the flash
 kernels' output and log-sum-exp and the projections' results; the backward
 pass computes the rest of the layer again, one layer's activations alive
 at a time: 10.6 GiB for one sequence of the model's own 16,384 positions
@@ -46,31 +47,28 @@ attention kernels are not run twice. With and without
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Callable
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..attribution import (SCOPE_BLOCK_ATTN_PROJ, SCOPE_BLOCK_EMBED,
                            SCOPE_BLOCK_HEAD, SCOPE_BLOCK_NORM,
                            SCOPE_MOE_ROUTE)
-from ..ops.attention import flash_attention, flash_attention_tokens_major
-from ..ops.heads import map_heads
-from ..parallel import moe
 from ..profiler import annotate_collective
+from .experts import ExpertWindow, SparseExperts
 from .loss import token_cross_entropy
-from .olmoe import (RMSNorm, SparseExperts, rope,  # noqa: F401
-                    routing_stats, take_expert_window)
-from .recompute import save_kernels_and_projections
+from .parts import (RMSNorm, dense_window_attention, grouped_flash_attention,
+                    projection, recomputed, rope_tokens_major, untied_head)
+
+flash_attention_fn = grouped_flash_attention  # benchmark/configs' name
 
 PERIOD = (0, 1, 1, 1)  # one period of both layouts: full + NoPE, then 3 x
 
 
 @dataclasses.dataclass(frozen=True)
-class SmallThinkerConfig:
+class SmallThinkerConfig(ExpertWindow):
     vocab_size: int = 151936
     hidden_size: int = 2560
     num_layers: int = 52
@@ -119,16 +117,6 @@ class SmallThinkerConfig:
         """Layer by layer: do queries and keys get RoPE?"""
         return self._layout(self.rope_layout)
 
-    @property
-    def experts_held(self) -> int:
-        if self.experts_here is None:
-            return self.num_experts - self.first_expert
-        return self.experts_here
-
-    def capacity(self, seq_len: int) -> int:
-        return moe.expert_capacity(self.capacity_factor, seq_len, self.top_k,
-                                   self.num_experts)
-
 
 SMALLTHINKER_21B_A3B = SmallThinkerConfig()
 SMALLTHINKER_TINY = SmallThinkerConfig(  # test-sized: 14 heads on 2, 7 to 1
@@ -136,94 +124,6 @@ SMALLTHINKER_TINY = SmallThinkerConfig(  # test-sized: 14 heads on 2, 7 to 1
     num_kv_heads=2, head_dim=8, intermediate_size=24, num_experts=8,
     top_k=3, capacity_factor=8 / 3, window=24,
 )
-
-
-def dense_window_attention(q, k, v, dtype, window=None):
-    """``q [B, S, H, D]``, ``k``, ``v [B, S, KV heads, D]``; the banded (or
-    full) causal softmax in float32, the keys and values of a group
-    repeated: the fallback where no kernel runs."""
-    group = q.shape[2] // k.shape[2]
-    k, v = (jnp.repeat(x.astype(jnp.float32), group, axis=2) for x in (k, v))
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
-                        k) / (q.shape[-1] ** 0.5)
-    ahead = jnp.arange(q.shape[1])[:, None] - jnp.arange(k.shape[1])[None, :]
-    seen = ahead >= 0 if window is None else (ahead >= 0) & (ahead < window)
-    out = jnp.einsum("bhqk,bkhd->bqhd",
-                     jax.nn.softmax(jnp.where(seen, scores, -1e30), -1), v)
-    return out.astype(dtype)
-
-
-def rotary_tables(dim: int, theta: float, positions):
-    """``(cos, sin) [..., S, dim]`` of ``rope``'s half-split rotation at
-    ``positions`` (``[S]`` or ``[B, S]``) for ``rotate_head``: each half
-    written out twice, the sine's first half negated."""
-    half = dim // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    angle = positions.astype(jnp.float32)[..., None] * inv_freq
-    cos, sin = jnp.cos(angle), jnp.sin(angle)
-    return (jnp.concatenate([cos, cos], -1),
-            jnp.concatenate([-sin, sin], -1))
-
-
-def rotate_head(head, cos, sin, dtype):
-    """``rope`` of one head ``[B, S, D]`` as ``dtype``, the same products
-    and sums lane for lane: lane ``i`` pairs with lane ``i +- D/2``, turned
-    in beside it **by the MXU**, as a product with the permutation matrix
-    (a ``jnp.roll`` of the lanes is two slices of half a 128-lane tile,
-    which XLA writes out padded and reads back). One 1 a column makes the
-    product exact: ``HIGHEST`` sends a float32 head, and the float32
-    cotangent on the way back, through as three bfloat16 pieces that sum
-    to it again. Rounded to ``dtype`` here, a head at a time: the
-    cotangent of a rounding of all heads at once is the whole array in
-    float32 (256 MB at SDAR's shapes, live at the step's peak)."""
-    dim = head.shape[-1]
-    turn = jnp.asarray(np.roll(np.eye(dim, dtype=np.float32), dim // 2, 1),
-                       head.dtype)
-    turned = jnp.dot(head, turn, precision=jax.lax.Precision.HIGHEST,
-                     preferred_element_type=jnp.float32)
-    return (head * cos + turned * sin).astype(dtype)
-
-
-def rope_tokens_major(x, heads: int, theta: float, dtype, positions=None):
-    """``rope(...).astype(dtype)`` of ``x [B, S, heads * D]`` where a
-    projection wrote it: a head after another on the lanes that hold it
-    (``ops/heads.py``), nothing re-tiled on the way to the kernels."""
-    if positions is None:
-        positions = jnp.arange(x.shape[1])
-    return map_heads(
-        functools.partial(rotate_head, dtype=dtype), heads, (x,),
-        constants=rotary_tables(x.shape[-1] // heads, theta, positions))
-
-
-def flash_attention_fn(q, k, v, dtype, window=None, interpret: bool = False,
-                       block: int | None = None):
-    """Adapter plugging the causal Pallas flash kernels into
-    ``SmallThinker``, the keys and values with their own, smaller number of
-    heads. **A windowed layer's** ``[B, S, heads, D]`` is ``[B, S, heads *
-    D]`` as the projections wrote it, and the tokens-major entry takes
-    that: heads of whole 128-lane blocks reach the kernels where they lie
-    (narrower ones, ``models/granite.py``'s 64, are transposed inside the
-    entry). **A layer of full attention** is transposed to ``[B, heads, S,
-    D]`` here, as every layer was until PR 40: fed tokens-major the kernels
-    take 3 to 8% longer (a tile is 32 pieces of 4 KB where it was 128 KB in
-    a row), a full layer computes twice a windowed layer's tiles, and two
-    such layers took 202.8 ms where these take 192.6 (windowed ones with
-    RoPE 109.5 against 118.1: PERF.md, PR 40). ``block`` is for tests that
-    want several tiles of a short sequence."""
-    tiles = dict(causal=True, window=window, block_q=block, block_k=block,
-                 interpret=interpret)
-    if window is None:
-        out = flash_attention(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), **tiles)
-        return out.transpose(0, 2, 1, 3).astype(dtype)
-
-    def rows(x):
-        return x.reshape(x.shape[:2] + (-1,))
-
-    out = flash_attention_tokens_major(rows(q), rows(k), rows(v),
-                                       q.shape[2], **tiles)
-    return out.reshape(q.shape).astype(dtype)
 
 
 class GroupedAttention(nn.Module):
@@ -236,16 +136,12 @@ class GroupedAttention(nn.Module):
     def __call__(self, x):
         cfg = self.config
 
-        def project(name, width):
-            return nn.Dense(width, use_bias=False, dtype=cfg.dtype,
-                            param_dtype=jnp.float32, name=name)
-
         def heads(y, count):
             return y.reshape(x.shape[:2] + (count, cfg.head_dim))
 
-        q = project("query", cfg.num_heads * cfg.head_dim)(x)
-        k = project("key", cfg.num_kv_heads * cfg.head_dim)(x)
-        v = project("value", cfg.num_kv_heads * cfg.head_dim)(x)
+        q = projection(cfg, cfg.num_heads * cfg.head_dim, "query")(x)
+        k = projection(cfg, cfg.num_kv_heads * cfg.head_dim, "key")(x)
+        v = projection(cfg, cfg.num_kv_heads * cfg.head_dim, "value")(x)
         if self.rotary:
             q = rope_tokens_major(q, cfg.num_heads, cfg.rope_theta, cfg.dtype)
             k = rope_tokens_major(k, cfg.num_kv_heads, cfg.rope_theta,
@@ -254,7 +150,7 @@ class GroupedAttention(nn.Module):
         out = attend(heads(q, cfg.num_heads), heads(k, cfg.num_kv_heads),
                      heads(v, cfg.num_kv_heads), cfg.dtype,
                      cfg.window if self.windowed else None)
-        return project("out", cfg.hidden_size)(
+        return projection(cfg, cfg.hidden_size, "out")(
             out.reshape(x.shape[:2] + (-1,)))
 
 
@@ -302,10 +198,7 @@ class SmallThinker(nn.Module):
     @nn.compact
     def __call__(self, input_ids):
         cfg = self.config
-        layer = DecoderLayer
-        if cfg.remat:
-            layer = nn.remat(DecoderLayer,
-                             policy=save_kernels_and_projections)
+        layer = recomputed(DecoderLayer, cfg)
         with annotate_collective(SCOPE_BLOCK_EMBED):
             x = nn.Embed(cfg.vocab_size, cfg.hidden_size,
                          param_dtype=jnp.float32,
@@ -315,13 +208,7 @@ class SmallThinker(nn.Module):
             x = layer(cfg, windowed, rotary, self.attention_fn,
                       name=f"layer_{i}")(x)
         with annotate_collective(SCOPE_BLOCK_HEAD):
-            x = RMSNorm(cfg.rms_norm_eps, name="ln_out")(x).astype(cfg.dtype)
-            # bf16 in, f32 out on the MXU, as models/bert.py's head.
-            head = self.param("lm_head", nn.initializers.lecun_normal(),
-                              (cfg.hidden_size, cfg.vocab_size), jnp.float32)
-            return jax.lax.dot_general(
-                x, head.astype(cfg.dtype), (((x.ndim - 1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            return untied_head(self, x)
 
 
 def causal_lm_loss(model: SmallThinker, params, tokens):

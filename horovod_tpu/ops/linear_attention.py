@@ -208,7 +208,7 @@ solve_unit_lower.defvjp(_solve_forward, _solve_backward)
 
 
 def _record_chunks(count: int, chunk: int, heads: int) -> None:
-    """At trace time, as ``models.olmoe._record_slots``: the step that
+    """At trace time, as ``models.experts._record_slots``: the step that
     runs scans this many chunks a sequence."""
     from .. import metrics
 

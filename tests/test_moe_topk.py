@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 from jax import lax
 
-from horovod_tpu.models import olmoe
+from horovod_tpu.models import experts, olmoe
 from horovod_tpu.parallel import moe
 
 T, D, E = 48, 16, 8
@@ -172,7 +172,7 @@ def test_routing_stats_count_load_and_drops():
     key = jax.random.PRNGKey(1)
     params = jax.jit(model.init)(key, jnp.zeros((1, 8), jnp.int32))["params"]
     ids = jax.random.randint(key, (3, 32), 0, config.vocab_size)
-    stats = jax.jit(partial(olmoe.routing_stats, model))(params, ids)
+    stats = jax.jit(partial(experts.routing_stats, model))(params, ids)
     capacity = config.capacity(32)
     assert capacity == 8
     assert stats["load"].shape == (2, 4) and stats["dropped"].shape == (2,)

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.models import olmoe
+from horovod_tpu.models import olmoe, parts
 
 BENCHMARK_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
@@ -141,22 +141,22 @@ def test_the_published_sizes_and_the_tree():
     assert params["lm_head"].shape == (2048, 50304)
     assert all(leaf.dtype == jnp.float32 for leaf in leaves)
     config = model.config
-    assert (config.head_dim, config.window, config.capacity(4096)) == (
+    assert (config.head_dim, config.experts_held, config.capacity(4096)) == (
         128, 16, 640)
     whole = olmoe.OLMOE_1B_7B
-    assert (whole.window, whole.num_layers, whole.top_k) == (64, 16, 8)
+    assert (whole.experts_held, whole.num_layers, whole.top_k) == (64, 16, 8)
 
 
 def test_rope_rotates_pairs_and_keeps_position_zero():
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 5, 2, 8))
-    y = olmoe.rope(x, 10000.0)
+    y = parts.rope(x, 10000.0)
     np.testing.assert_allclose(y[:, 0], x[:, 0], rtol=1e-6)
     np.testing.assert_allclose(  # a rotation: norms of each pair are kept
         y[..., :4] ** 2 + y[..., 4:] ** 2, x[..., :4] ** 2 + x[..., 4:] ** 2,
         rtol=1e-5)
     # scores depend on the distance only
     q, k = x[:, :, :1], x[:, :, 1:]
-    shifted = olmoe.rope(jnp.pad(x, ((0, 0), (3, 0), (0, 0), (0, 0))),
+    shifted = parts.rope(jnp.pad(x, ((0, 0), (3, 0), (0, 0), (0, 0))),
                          10000.0)[:, 3:]
     np.testing.assert_allclose(
         jnp.einsum("bqhd,bkhd->bqk", y[:, :, :1], y[:, :, 1:]),

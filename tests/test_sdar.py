@@ -17,8 +17,7 @@ import numpy as np
 import pytest
 
 from horovod_tpu import metrics
-from horovod_tpu.models import olmoe, sdar, smallthinker
-from horovod_tpu.models.recompute import save_kernels_and_projections
+from horovod_tpu.models import experts, parts, sdar, smallthinker
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -135,9 +134,21 @@ class TestAgainstTheReference:
 
         assert count(True) == count(False) == 6 * TINY.num_layers
 
-    def test_the_policy_is_the_one_smallthinker_shares(self):
-        assert smallthinker.save_kernels_and_projections is (
-            save_kernels_and_projections)
+    def test_the_policy_is_the_one_smallthinker_shares(self, params, batch):
+        """``models/parts.py``'s: what the ``jax.checkpoint`` equations of
+        either model's forward pass carry."""
+        def policies(model, variables, *inputs):
+            jaxpr = jax.make_jaxpr(model.apply)(variables, *inputs)
+            return {eqn.params["policy"] for eqn in jaxpr.eqns
+                    if "policy" in eqn.params}
+
+        small = smallthinker.SmallThinker(smallthinker.SMALLTHINKER_TINY)
+        ids = jnp.zeros((1, 32), jnp.int32)
+        assert policies(
+            sdar.Sdar(TINY), {"params": params}, batch["noisy"],
+            batch["clean"]) == policies(
+            small, jax.eval_shape(small.init, jax.random.PRNGKey(0), ids),
+            ids) == {parts.save_kernels_and_projections}
 
     @pytest.mark.parametrize("change", [
         dict(block_length=8), dict(block_length=2), dict(top_k=3),
@@ -225,7 +236,7 @@ class TestTheShareOfTheExperts:
         for first in range(8):
             share = dataclasses.replace(TINY, first_expert=first,
                                         experts_here=1)
-            cut = sdar.take_expert_window(params, share)
+            cut = experts.take_expert_window(params, share)
             assert cut["layer_1"]["moe"]["experts_up"].shape[0] == 1
             assert cut["layer_1"]["moe"]["router"].shape == (64, 8)
             total = total + self.layer(share, cut, x)
@@ -240,7 +251,7 @@ class TestTheShareOfTheExperts:
                                                            params, batch):
         share = dataclasses.replace(TINY, first_expert=first,
                                     experts_here=2)
-        cut = sdar.take_expert_window(params, share)
+        cut = experts.take_expert_window(params, share)
         loss, grads = loss_and_grads(share, "dense", cut, batch)
         want_loss, want = reference_loss_and_grads(share, cut, batch)
         np.testing.assert_allclose(loss, want_loss, rtol=2e-6)
@@ -252,12 +263,12 @@ class TestTheShareOfTheExperts:
         loss, _ = loss_and_grads(tight, "dense", params, batch)
         want = reference.loss(reference_config(tight), params, batch)
         np.testing.assert_allclose(loss, want, rtol=2e-6)
-        stats = jax.jit(partial(sdar.routing_stats, sdar.Sdar(tight)))(
+        stats = jax.jit(partial(experts.routing_stats, sdar.Sdar(tight)))(
             params, batch["noisy"], batch["clean"])
         assert stats["load"].shape == (2, 8)
         assert int(stats["dropped"].sum()) > 0
         # the model's own factor leaves room for every pair of these rows
-        roomy = jax.jit(partial(sdar.routing_stats, sdar.Sdar(
+        roomy = jax.jit(partial(experts.routing_stats, sdar.Sdar(
             dataclasses.replace(TINY, capacity_factor=8.0))))(
                 params, batch["noisy"], batch["clean"])
         assert int(roomy["dropped"].sum()) == 0
@@ -337,7 +348,7 @@ class TestRopeWithPositionIds:
         if shape == "[B, S]":
             positions = jnp.tile(positions, (2, 1))
         np.testing.assert_array_equal(
-            olmoe.rope(self.x(), 1e6), olmoe.rope(self.x(), 1e6, positions))
+            parts.rope(self.x(), 1e6), parts.rope(self.x(), 1e6, positions))
 
     def test_a_caller_without_them_lowers_to_the_same_text(self):
         """``None`` is ``arange`` before anything is traced: the older
@@ -352,7 +363,7 @@ class TestRopeWithPositionIds:
             return jnp.concatenate(
                 [x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
-        assert str(jax.make_jaxpr(partial(olmoe.rope, theta=1e4))(
+        assert str(jax.make_jaxpr(partial(parts.rope, theta=1e4))(
             self.x())) == str(jax.make_jaxpr(partial(old, theta=1e4))(
                 self.x()))
 
@@ -360,9 +371,9 @@ class TestRopeWithPositionIds:
         x = self.x()
         both = jnp.concatenate([x, x], axis=1)
         positions = jnp.concatenate([jnp.arange(32)] * 2)
-        out = olmoe.rope(both, 1e6, positions)
+        out = parts.rope(both, 1e6, positions)
         np.testing.assert_array_equal(out[:, :32], out[:, 32:])
-        np.testing.assert_array_equal(out[:, :32], olmoe.rope(x, 1e6))
+        np.testing.assert_array_equal(out[:, :32], parts.rope(x, 1e6))
 
 
 class TestTheStreamsAreCutBeforeTheProjections:

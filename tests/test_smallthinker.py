@@ -17,6 +17,7 @@ import numpy as np
 import pytest
 
 from horovod_tpu import metrics
+from horovod_tpu.models import experts, parts
 from horovod_tpu.models import smallthinker as st
 from horovod_tpu.parallel import moe
 
@@ -189,7 +190,7 @@ class TestTheShareOfTheExperts:
         for first in range(0, 8, 2):
             share = dataclasses.replace(TINY, first_expert=first,
                                         experts_here=2)
-            cut = st.take_expert_window(params, share)
+            cut = experts.take_expert_window(params, share)
             assert cut["layer_1"]["moe"]["experts_up"].shape[0] == 2
             total = total + self.layer(share, cut, x)
         np.testing.assert_allclose(total - 3 * after_attention, want,
@@ -203,7 +204,7 @@ class TestTheShareOfTheExperts:
                                                            params, tokens):
         share = dataclasses.replace(TINY, first_expert=first,
                                     experts_here=2)
-        cut = st.take_expert_window(params, share)
+        cut = experts.take_expert_window(params, share)
         loss, grads = loss_and_grads(share, "dense", cut, tokens)
         want_loss, want = jax.jit(jax.value_and_grad(partial(
             reference.loss, reference_config(share))))(cut, tokens)
@@ -216,11 +217,13 @@ class TestTheShareOfTheExperts:
         loss, _ = loss_and_grads(tight, "dense", params, tokens)
         want = reference.loss(reference_config(tight), params, tokens)
         np.testing.assert_allclose(loss, want, rtol=2e-6)
-        stats = jax.jit(partial(st.routing_stats, st.SmallThinker(tight)))(
+        stats = jax.jit(partial(
+            experts.routing_stats, st.SmallThinker(tight)))(
             params, tokens[:, :-1])
         assert stats["load"].shape == (4, 8)
         assert int(stats["dropped"].sum()) > 0
-        roomy = jax.jit(partial(st.routing_stats, st.SmallThinker(TINY)))(
+        roomy = jax.jit(partial(
+            experts.routing_stats, st.SmallThinker(TINY)))(
             params, tokens[:, :-1])
         assert int(roomy["dropped"].sum()) == 0
         assert int(roomy["load"].sum()) == 4 * 2 * SEQ * 3
@@ -251,9 +254,9 @@ class TestRopeWhereTheProjectionWrote:
             positions = jnp.arange(40)[::-1] * 3
             if per_row:
                 positions = jnp.stack([positions, positions[::-1]])
-        got = st.rope_tokens_major(x, self.HEADS, 1e4, jnp.float32,
+        got = parts.rope_tokens_major(x, self.HEADS, 1e4, jnp.float32,
                                    positions)
-        want = st.rope(self.apart(x), 1e4, positions).reshape(x.shape)
+        want = parts.rope(self.apart(x), 1e4, positions).reshape(x.shape)
         assert got.dtype == want.dtype == jnp.float32
         # the same products and sums; which of them the compiler contracts
         # to one rounding is its own choice
@@ -266,11 +269,11 @@ class TestRopeWhereTheProjectionWrote:
         weight = jax.random.normal(jax.random.PRNGKey(9), x.shape)
 
         def here(x):
-            return (st.rope_tokens_major(x, self.HEADS, 1e4, dtype).astype(
+            return (parts.rope_tokens_major(x, self.HEADS, 1e4, dtype).astype(
                 jnp.float32) * weight).sum()
 
         def there(x):
-            return (st.rope(self.apart(x), 1e4).astype(dtype).reshape(
+            return (parts.rope(self.apart(x), 1e4).astype(dtype).reshape(
                 x.shape).astype(jnp.float32) * weight).sum()
 
         got, want = jax.grad(here)(x), jax.grad(there)(x)
@@ -285,7 +288,7 @@ class TestRopeWhereTheProjectionWrote:
         x = self.x(jnp.bfloat16)
 
         def pulled_back(x, cotangent):
-            out, vjp = jax.vjp(lambda x: st.rope_tokens_major(
+            out, vjp = jax.vjp(lambda x: parts.rope_tokens_major(
                 x, self.HEADS, 1e4, jnp.bfloat16), x)
             return out, vjp(cotangent)
 

@@ -243,7 +243,7 @@ def check_tiles_as_they_lie() -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from horovod_tpu.models import smallthinker
+    from horovod_tpu.models import parts
     from horovod_tpu.ops.attention import (flash_attention_lse,
                                            flash_attention_tokens_major_lse)
 
@@ -291,9 +291,9 @@ def check_tiles_as_they_lie() -> None:
 
     x = jax.random.normal(jax.random.PRNGKey(4), (1, 16384, 28 * 128),
                           jnp.bfloat16)
-    got = jax.jit(lambda x: smallthinker.rope_tokens_major(
+    got = jax.jit(lambda x: parts.rope_tokens_major(
         x, 28, 1.5e6, jnp.float32))(x)
-    want = jax.jit(lambda x: smallthinker.rope(
+    want = jax.jit(lambda x: parts.rope(
         x.reshape(1, 16384, 28, 128), 1.5e6).reshape(x.shape))(x)
     worst = float(jnp.abs(got - want).max())
     print(f"  rope_tokens_major against rope, float32 results of bf16 "
