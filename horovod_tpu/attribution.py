@@ -116,6 +116,12 @@ SCOPE_ATTN_WINDOW = "attn.window"
 #: beside them (the own block's four keys a query in plain XLA, the
 #: log-sum-exp merge, the split and the concatenation). No phase either.
 SCOPE_ATTN_BLOCKDIFF = "attn.blockdiff"
+#: Around ``attn.fwd`` / ``attn.bwd`` where the call's values are not as
+#: wide as its keys (latent attention without its rotary split:
+#: ``models/kimi_linear.py`` scores over 192 lanes and reads values of
+#: 128), placed as ``attn.window`` is and no phase either: it tells the
+#: latent layer's kernels from any other's in the step's text.
+SCOPE_ATTN_MLA = "attn.mla"
 #: JAX's own name-stack component for the forward operations that a
 #: ``jax.checkpoint`` (``nn.remat``) runs again inside the backward pass:
 #: ``transpose(jvp(...))/rematted_computation/...``. Not a scope this
@@ -129,6 +135,11 @@ SCOPE_MOE_ROUTE = "moe.route"
 SCOPE_MOE_DISPATCH = "moe.dispatch"
 SCOPE_MOE_EXPERTS = "moe.experts"
 SCOPE_MOE_COMBINE = "moe.combine"
+#: The shared expert of a mixture that has one (``models/kimi_linear.py``):
+#: a dense gated feed-forward every token takes beside its routed experts,
+#: the model's own branch and no part of ``parallel/moe.py``'s slots. A
+#: phase: its time is neither the routed experts' nor a dense layer's.
+SCOPE_MOE_SHARED = "moe.shared"
 #: A linear-attention layer (``ops/linear_attention.py``,
 #: ``models/olmo_hybrid.py``): what turns the projections into the rule's
 #: operands (short convolutions, SiLU, l2-norms, ``beta`` and ``g``), the
@@ -149,7 +160,8 @@ PHASE_SCOPE_NAMES = tuple(SCOPE_PREFIX + name for name in (
     SCOPE_WIRE, SCOPE_OPTIMIZER, SCOPE_ATTN_FWD, SCOPE_ATTN_BWD,
     SCOPE_MOE_ROUTE, SCOPE_MOE_DISPATCH, SCOPE_MOE_EXPERTS,
     SCOPE_MOE_COMBINE, SCOPE_LINATTN_CONV, SCOPE_LINATTN_SCAN,
-    SCOPE_LINATTN_GATE, SCOPE_SSM_CONV, SCOPE_SSM_SCAN, SCOPE_SSM_GATE))
+    SCOPE_LINATTN_GATE, SCOPE_SSM_CONV, SCOPE_SSM_SCAN, SCOPE_SSM_GATE,
+    SCOPE_MOE_SHARED))
 #: Block scopes: the parts of a model (``models/*.py``) that no phase
 #: names, forward and backward. A phase inside a block stays the phase's
 #: (``profiler.owner_of``: the innermost phase scope, else the innermost

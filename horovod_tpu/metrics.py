@@ -909,6 +909,12 @@ LINATTN_CHUNKS_LAST = gauge(
     "(sequence length / chunk), one scan step each: set at trace time, as "
     "hvd_grad_sync_last_bytes is.",
     ("chunk", "heads_here"))
+LINATTN_DECAY_WIDTH_LAST = gauge(
+    "hvd_linattn_decay_width_last",
+    "Decays a head and token of the LAST traced delta-rule call: 1 for "
+    "gated_delta_rule (one scalar a head), d_k for kimi_delta_rule (one a "
+    "key channel, the pair terms formed sub-block by sub-block): set at "
+    "trace time, beside hvd_linattn_chunks_last.")
 SSM_CHUNKS_LAST = gauge(
     "hvd_ssm_chunks_last",
     "Chunks a sequence that the LAST traced Mamba-2 scan (ops/ssd.py "
@@ -956,6 +962,14 @@ ATTN_KV_GROUP_LAST = gauge(
     "Query heads that read one key/value head in the LAST traced multi-tile "
     "flash-attention call (1: a head of keys and values a query head): set "
     "at trace time, beside hvd_attn_tiles_last.")
+ATTN_HEAD_WIDTHS_LAST = gauge(
+    "hvd_attn_head_widths_last",
+    "Lanes of a head in the LAST traced multi-tile flash-attention call: "
+    "kind=qk the queries' and keys' (the scores contract over them), "
+    "kind=v the values', the context's and their gradients'. Equal in "
+    "every model here but latent attention (192 and 128): set at trace "
+    "time, beside hvd_attn_tiles_last.",
+    ("kind",))
 HEAD_LOGITS_BYTES_LAST = gauge(
     "hvd_head_logits_bytes_last",
     "Bytes of the logits that the LAST traced token cross entropy "

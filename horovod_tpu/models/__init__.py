@@ -43,3 +43,9 @@ from .granite import (  # noqa: F401
     Granite,
     GraniteConfig,
 )
+from .kimi_linear import (  # noqa: F401
+    KIMI_LINEAR_48B_A3B,
+    KIMI_LINEAR_TINY,
+    KimiLinear,
+    KimiLinearConfig,
+)

@@ -49,17 +49,23 @@ class SparseExperts(nn.Module):
     ``None``: a float32 parameter ``router``) or the caller's, who then
     hands in its ``logits [B, S, num_experts]``. ``auxiliary(logits,
     expert)`` is a routing group's auxiliary losses, a tuple of scalars;
-    their means over the groups are returned after the output."""
+    their means over the groups are returned after the output. ``scores``
+    and ``gate_scale`` are ``route_to_capacity``'s; ``width`` is an
+    expert's where the config's ``intermediate_size`` is a dense layer's."""
 
     config: Any
     activation: Callable = jax.nn.silu
     gates_over_picks: bool = False
     auxiliary: Callable | None = None
+    scores: str = "softmax"
+    gate_scale: float = 1.0
+    width: int | None = None
 
     @nn.compact
     def __call__(self, x, logits=None):
         cfg = self.config
-        hidden, width, here = (cfg.hidden_size, cfg.intermediate_size,
+        hidden, width, here = (cfg.hidden_size,
+                               self.width or cfg.intermediate_size,
                                cfg.experts_held)
         router = None
         if logits is None:
@@ -86,7 +92,8 @@ class SparseExperts(nn.Module):
             send, expert, pos, keep, gate, counts = moe.route_to_capacity(
                 tokens.astype(cfg.dtype), logits, cfg.num_experts, capacity,
                 top_k=cfg.top_k, first_expert=cfg.first_expert,
-                experts_here=here, gates_over_picks=self.gates_over_picks)
+                experts_here=here, gates_over_picks=self.gates_over_picks,
+                scores=self.scores, gate_scale=self.gate_scale)
             back = moe.gated_expert_ffn(
                 w_gate.astype(cfg.dtype), w_up.astype(cfg.dtype),
                 w_down.astype(cfg.dtype), send[..., :hidden],
@@ -157,8 +164,10 @@ def routing_stats(model, params, *inputs):
             config=dataclasses.replace(model.config, remat=False))
     _, state = model.apply({"params": params}, *inputs,
                            mutable=["intermediates"])
-    layers = [state["intermediates"][f"layer_{i}"]["moe"]["routing"][0]
-              for i in range(model.config.num_layers)]
+    layers = [layer["moe"]["routing"][0]  # a dense layer has no "moe"
+              for layer in (state["intermediates"].get(f"layer_{i}", {})
+                            for i in range(model.config.num_layers))
+              if "moe" in layer]
     load = jnp.stack([layer["load"] for layer in layers])
     dropped = jnp.stack([layer["dropped"] for layer in layers])
     pairs = jnp.stack([layer["pairs"] for layer in layers])
@@ -176,6 +185,8 @@ def take_expert_window(params, share):
     out = dict(params)
     for i in range(share.num_layers):
         layer = dict(params[f"layer_{i}"])
+        if "moe" not in layer:  # a dense layer: every window's alike
+            continue
         layer["moe"] = {
             name: leaf[first:last] if name.startswith("experts_") else leaf
             for name, leaf in layer["moe"].items()}

@@ -52,9 +52,9 @@ from ..attribution import (SCOPE_BLOCK_ATTN_PROJ, SCOPE_BLOCK_EMBED,
                            SCOPE_LINATTN_GATE)
 from ..ops.linear_attention import gated_delta_rule, short_conv
 from ..profiler import annotate_collective
-from .parts import (RMSNorm, decay_rate, dense_causal_attention,
-                    head_major_flash_attention, projection, rope, step_bias,
-                    untied_head)
+from .parts import (GatedMLP, RMSNorm, decay_rate, dense_causal_attention,
+                    head_major_flash_attention, l2norm, projection, rope,
+                    step_bias, untied_head)
 
 flash_attention_fn = head_major_flash_attention  # benchmark/configs' name
 
@@ -163,8 +163,8 @@ class GatedDeltaNet(nn.Module):
                 jax.nn.silu(short_conv(y, w)).reshape(
                     x.shape[:2] + (heads, -1))
                 for y, w in before)
-            q = (_l2norm(q) * d_k ** -0.5).astype(cfg.dtype)
-            k = _l2norm(k).astype(cfg.dtype)
+            q = (l2norm(q) * d_k ** -0.5).astype(cfg.dtype)
+            k = l2norm(k).astype(cfg.dtype)
             beta = jax.nn.sigmoid(nn.Dense(
                 heads, use_bias=False, dtype=f32, name="beta")(x))
             if cfg.linear_allow_neg_eigval:
@@ -177,11 +177,6 @@ class GatedDeltaNet(nn.Module):
                 * jax.nn.silu(gate.astype(f32)).reshape(out.shape)
             out = out.astype(cfg.dtype).reshape(x.shape[:2] + (-1,))
         return projection(cfg, cfg.hidden_size, "out")(out)
-
-
-def _l2norm(x, eps: float = 1e-6):
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True) + eps)
 
 
 class FullAttention(nn.Module):
@@ -209,18 +204,6 @@ class FullAttention(nn.Module):
             out.reshape(x.shape[:2] + (width,)))
 
 
-class GatedMLP(nn.Module):
-    config: OlmoHybridConfig
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.config
-        width = cfg.intermediate_size
-        hidden = jax.nn.silu(projection(cfg, width, "gate")(x)) \
-            * projection(cfg, width, "up")(x)
-        return projection(cfg, cfg.hidden_size, "down")(hidden)
-
-
 class HybridLayer(nn.Module):
     config: OlmoHybridConfig
     kind: str
@@ -239,7 +222,7 @@ class HybridLayer(nn.Module):
             x = x + RMSNorm(cfg.rms_norm_eps, name="ln_mixer")(mixed).astype(
                 cfg.dtype)
         with annotate_collective(SCOPE_BLOCK_FFN):
-            hidden = GatedMLP(cfg, name="mlp")(x)
+            hidden = GatedMLP(cfg, cfg.intermediate_size, name="mlp")(x)
         with annotate_collective(SCOPE_BLOCK_NORM):
             return x + RMSNorm(cfg.rms_norm_eps, name="ln_mlp")(
                 hidden).astype(cfg.dtype)

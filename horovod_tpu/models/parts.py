@@ -1,11 +1,12 @@
 """What the decoders share beside their mixers, owned by none of them:
-the norm, the projection, RoPE in its two forms, the dense attention
-fallbacks, the two adapters onto the flash kernels, the state-space
-initialisers, the recomputation policy and the untied head.
+the norm, the l2-norm, the projection, the gated feed-forward, RoPE in its
+two forms, the dense attention fallbacks, the two adapters onto the flash
+kernels, the state-space initialisers, the recomputation policy and the
+untied head.
 
-``models/olmoe.py``, ``olmo_hybrid.py``, ``smallthinker.py``, ``sdar.py``
-and ``granite.py`` import from here, from ``models/experts.py`` and
-``models/loss.py``, never from one another
+``models/olmoe.py``, ``olmo_hybrid.py``, ``smallthinker.py``, ``sdar.py``,
+``granite.py`` and ``kimi_linear.py`` import from here, from
+``models/experts.py`` and ``models/loss.py``, never from one another
 (``tests/test_decoder_imports.py``). What builds parameters here is a
 function called inside the model's own ``@nn.compact`` body, not a module
 of its own, so every leaf keeps its name and its place in the tree.
@@ -15,6 +16,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Any
 
 import flax.linen as nn
 import jax
@@ -37,11 +39,32 @@ class RMSNorm(nn.Module):
             jnp.mean(jnp.square(x), -1, keepdims=True) + self.eps) * scale
 
 
+def l2norm(x, eps: float = 1e-6):
+    """``x`` in float32 over its last axis' Euclidean length: a delta
+    rule's queries and keys, a head at a time."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), -1, keepdims=True) + eps)
+
+
 def projection(cfg, features: int, name: str):
     """The decoders' bias-free projection: a float32 kernel, ``cfg.dtype``
     out."""
     return nn.Dense(features, use_bias=False, dtype=cfg.dtype,
                     param_dtype=jnp.float32, name=name)
+
+
+class GatedMLP(nn.Module):
+    """The SiLU-gated feed-forward of ``width``: ``down(silu(gate(x)) *
+    up(x))``. A dense layer's, and a shared expert's."""
+    config: Any
+    width: int
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        hidden = jax.nn.silu(projection(cfg, self.width, "gate")(x)) \
+            * projection(cfg, self.width, "up")(x)
+        return projection(cfg, cfg.hidden_size, "down")(hidden)
 
 
 def untied_head(model, x):

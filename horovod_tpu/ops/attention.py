@@ -103,7 +103,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..attribution import (SCOPE_ATTN_BLOCKDIFF, SCOPE_ATTN_BWD,
-                           SCOPE_ATTN_FWD, SCOPE_ATTN_WINDOW)
+                           SCOPE_ATTN_FWD, SCOPE_ATTN_MLA,
+                           SCOPE_ATTN_WINDOW)
 from .heads import map_heads
 
 # Every ``pallas_call`` here carries this one name. XLA names a custom
@@ -849,15 +850,19 @@ def _flash_dqkv_from_out_kernel(q_ref, k_ref, v_ref, do_ref, out_ref,
 # ---------------------------------------------------------------------------
 
 
-def _kind_scope(window):
+def _kind_scope(window, two_widths=False):
     """A windowed call's kernels sit under ``hvd.attn.window`` as well,
     outside ``hvd.attn.fwd`` / ``hvd.attn.bwd``: a model with several
     kinds of layer tells them apart in its step's text, and whoever sums
     by the innermost phase scope still finds forward and backward. (A
     block-diffusion step's are under ``hvd.attn.blockdiff``, which
-    ``block_diffusion_attention`` opens around its kernels and its glue.)"""
+    ``block_diffusion_attention`` opens around its kernels and its glue.)
+    A call whose values are not as wide as its keys (latent attention's:
+    ``two_widths``) sits under ``hvd.attn.mla`` in the same way."""
     from ..profiler import annotate_collective
 
+    if two_widths:
+        return annotate_collective(SCOPE_ATTN_MLA)
     if window is None:
         return contextlib.nullcontext()
     return annotate_collective(SCOPE_ATTN_WINDOW)
@@ -867,7 +872,8 @@ def _fwd_call(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
               interpret, window=None, blocks=None, heads=None):
     from ..profiler import annotate_collective
 
-    with _kind_scope(window), annotate_collective(SCOPE_ATTN_FWD):
+    with _kind_scope(window, kr.shape[-1] != vr.shape[-1]), \
+            annotate_collective(SCOPE_ATTN_FWD):
         return _fwd_kernels(qr, kr, vr, causal, block_q, block_k, q_offset,
                             k_offset, interpret, window, blocks, heads)
 
@@ -1022,6 +1028,25 @@ def _tiled_shapes(qr, kr, heads):
             width // kr.shape[2])
 
 
+def _value_width(kr, vr, d: int) -> int:
+    """The lanes of a head's values, and of its context and their
+    gradients: ``d``, the queries' and keys', unless ``v`` comes narrower
+    or wider than ``k`` (latent attention reads keys of 192 lanes and
+    values of 128). The multi-tile kernels then take ``v``, give the
+    context, take ``dO`` and give ``dv`` in blocks of that width; their
+    bodies read every width off their blocks. Head-major operands only: a
+    tokens-major head is a lane block of one width, and ``_flash_tokens``
+    reads a ``v`` row of another width as another number of heads, which
+    ``_prepare_flash`` refuses. Sets ``hvd_attn_head_widths_last{kind}`` at
+    trace time."""
+    from .. import metrics
+
+    d_v = d * vr.shape[-1] // kr.shape[-1]
+    metrics.ATTN_HEAD_WIDTHS_LAST.set(d, kind="qk")
+    metrics.ATTN_HEAD_WIDTHS_LAST.set(d_v, kind="v")
+    return d_v
+
+
 def _record_layout(heads, *kernels):
     """At trace time, beside ``_record_tiles``:
     ``hvd_attn_operand_layout_last{kernel}``, 1 where the multi-tile
@@ -1040,9 +1065,10 @@ def _fwd_kernels(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
     (the multi-tile kernels alone: a one-tile tokens-major call is
     ``_flash_tokens_major``'s), the log-sum-exp ``[BH, 1, S]`` rows."""
     BH, Sq, Sk, D, group = _tiled_shapes(qr, kr, heads)
+    Dv = _value_width(kr, vr, D)
     scale = 1.0 / (D ** 0.5)
-    if heads is None and _single_tile(Sq, Sk, block_q, block_k, window,
-                                      group, blocks):
+    if heads is None and D == Dv and _single_tile(
+            Sq, Sk, block_q, block_k, window, group, blocks):
         return _single_tile_fwd(qr, kr, vr, causal, block_q, block_k,
                                 q_offset, k_offset, interpret)
     num_qb, num_kb = Sq // block_q, Sk // block_k
@@ -1057,31 +1083,30 @@ def _fwd_kernels(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
                                q_offset, k_offset, window, group, behind)
     _record_layout(heads, "fwd")
     q_at = _block_at(heads)
-    kv_spec = pl.BlockSpec((1, block_k, D),
-                           _kv_index_map(causal, num_kb, block_q, block_k,
-                                         q_offset, k_offset, window, group,
-                                         behind,
-                                         _block_at(heads and heads // group)))
+    kv_at = _kv_index_map(causal, num_kb, block_q, block_k, q_offset,
+                          k_offset, window, group, behind,
+                          _block_at(heads and heads // group))
     return pl.pallas_call(
         kernel,
         grid=(BH, num_qb, band_kb),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda bh, i, j: q_at(bh, i)),
-            kv_spec,
-            kv_spec,
+            pl.BlockSpec((1, block_k, D), kv_at),
+            pl.BlockSpec((1, block_k, Dv), kv_at),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda bh, i, j: q_at(bh, i)),
+            pl.BlockSpec((1, block_q, Dv), lambda bh, i, j: q_at(bh, i)),
             pl.BlockSpec((1, 1, Sq), lambda bh, i, j: (bh, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(qr.shape, qr.dtype),
+            jax.ShapeDtypeStruct(
+                qr.shape[:-1] + (qr.shape[-1] // D * Dv,), qr.dtype),
             jax.ShapeDtypeStruct((BH, 1, Sq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),  # running max m
             pltpu.VMEM((block_q, 1), jnp.float32),  # normalizer l
-            pltpu.VMEM((block_q, D), jnp.float32),  # fp32 accumulator
+            pltpu.VMEM((block_q, Dv), jnp.float32),  # fp32 accumulator
         ],
         interpret=interpret,
         name=KERNEL_NAME,
@@ -1092,7 +1117,8 @@ def _flash_bwd(causal, block_q, block_k, q_offset, k_offset, interpret,
                res, g, g_lse=None, window=None, blocks=None, heads=None):
     from ..profiler import annotate_collective
 
-    with _kind_scope(window), annotate_collective(SCOPE_ATTN_BWD):
+    with _kind_scope(window, res[1].shape[-1] != res[2].shape[-1]), \
+            annotate_collective(SCOPE_ATTN_BWD):
         return _bwd_kernels(causal, block_q, block_k, q_offset, k_offset,
                             interpret, res, g, g_lse, window, blocks, heads)
 
@@ -1104,6 +1130,7 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
     as the output, ``g_lse`` as the log-sum-exp."""
     qr, kr, vr, out, lse = res
     BH, Sq, Sk, D, group = _tiled_shapes(qr, kr, heads)
+    Dv = _value_width(kr, vr, D)
     BHkv = BH // group
     scale = 1.0 / (D ** 0.5)
     do = g
@@ -1125,8 +1152,8 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
                 do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1),
             heads, (do, out), rows_out=True).reshape(BH, 1, Sq)
 
-    if heads is None and _single_tile(Sq, Sk, block_q, block_k, window,
-                                      group, blocks):
+    if heads is None and D == Dv and _single_tile(
+            Sq, Sk, block_q, block_k, window, group, blocks):
         return _single_tile_bwd(qr, kr, vr, do, lse, delta, g_lse, causal,
                                 block_q, block_k, q_offset, k_offset,
                                 interpret)
@@ -1138,15 +1165,13 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
                                      group, behind)
     _record_layout(heads, "dq", "dkv")
     q_at, kv_at = _block_at(heads), _block_at(heads and heads // group)
-    kv_spec = pl.BlockSpec((1, block_k, D),
-                           _kv_index_map(causal, num_kb, block_q, block_k,
-                                         q_offset, k_offset, window, group,
-                                         behind, kv_at))
+    kv_seen = _kv_index_map(causal, num_kb, block_q, block_k, q_offset,
+                            k_offset, window, group, behind, kv_at)
     q_specs = [
         pl.BlockSpec((1, block_q, D), lambda bh, i, j: q_at(bh, i)),
-        kv_spec,
-        kv_spec,
-        pl.BlockSpec((1, block_q, D), lambda bh, i, j: q_at(bh, i)),
+        pl.BlockSpec((1, block_k, D), kv_seen),
+        pl.BlockSpec((1, block_k, Dv), kv_seen),
+        pl.BlockSpec((1, block_q, Dv), lambda bh, i, j: q_at(bh, i)),
         pl.BlockSpec((1, 1, Sq), lambda bh, i, j: (bh, 0, 0)),
         pl.BlockSpec((1, 1, Sq), lambda bh, i, j: (bh, 0, 0)),
         pl.BlockSpec((1, 1, Sq), lambda bh, i, j: (bh, 0, 0)),
@@ -1171,21 +1196,22 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
                        window, behind)
     if group == 1:
         grid = (BH, num_kb, band_qb)
-        q_spec = pl.BlockSpec((1, block_q, D),
-                              lambda bh, j, i: q_at(bh, q_block(j, i)))
-        k_spec = pl.BlockSpec((1, block_k, D), lambda bh, j, i: kv_at(bh, j))
+        q_rows = lambda bh, j, i: q_at(bh, q_block(j, i))  # noqa: E731
+        k_rows = lambda bh, j, i: kv_at(bh, j)  # noqa: E731
         row_spec = pl.BlockSpec((1, 1, Sq), lambda bh, j, i: (bh, 0, 0))
     else:
         # One key/value head's tile stays resident while the group's query
         # heads, and every q block of each, go by.
         grid = (BHkv, num_kb, group, band_qb)
-        q_spec = pl.BlockSpec(
-            (1, block_q, D),
-            lambda bh, j, h, i: q_at(bh * group + h, q_block(j, i)))
-        k_spec = pl.BlockSpec((1, block_k, D),
-                              lambda bh, j, h, i: kv_at(bh, j))
+        q_rows = lambda bh, j, h, i: q_at(  # noqa: E731
+            bh * group + h, q_block(j, i))
+        k_rows = lambda bh, j, h, i: kv_at(bh, j)  # noqa: E731
         row_spec = pl.BlockSpec((1, 1, Sq),
                                 lambda bh, j, h, i: (bh * group + h, 0, 0))
+    q_spec, do_spec = (pl.BlockSpec((1, block_q, width), q_rows)
+                       for width in (D, Dv))
+    k_spec, v_spec = (pl.BlockSpec((1, block_k, width), k_rows)
+                      for width in (D, Dv))
     dk, dv = pl.pallas_call(
         functools.partial(
             _flash_dkv_kernel, causal=causal, scale=scale, block_q=block_q,
@@ -1193,16 +1219,16 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
             window=window, group=group, num_qb=num_qb, blocks=blocks,
         ),
         grid=grid,
-        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec,
+        in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec,
                   row_spec],
-        out_specs=[k_spec, k_spec],
+        out_specs=[k_spec, v_spec],
         out_shape=[
             jax.ShapeDtypeStruct(kr.shape, kr.dtype),
             jax.ShapeDtypeStruct(vr.shape, vr.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, Dv), jnp.float32),
         ],
         interpret=interpret,
         name=KERNEL_NAME,
@@ -1324,12 +1350,12 @@ def _prepare_flash(q, k, v, causal, block_q, block_k, q_offset, k_offset,
             f"flash attention operands must share a dtype; got "
             f"q={q.dtype}, k={k.dtype}, v={v.dtype} — cast them to one "
             "dtype")
-    if k.shape != v.shape or k.shape[0] != q.shape[0] \
-            or q.shape[1] % k.shape[1]:
+    if k.shape[:-1] != v.shape[:-1] or k.shape[0] != q.shape[0] \
+            or q.shape[1] % k.shape[1] or q.shape[-1] != k.shape[-1]:
         raise ValueError(
-            f"flash attention wants k and v of one shape [B, KV heads, S, "
-            f"D] whose heads divide q's; got q={q.shape}, k={k.shape}, "
-            f"v={v.shape}")
+            f"flash attention wants k [B, KV heads, S, D] and v [B, KV "
+            f"heads, S, Dv] whose heads divide q's [B, H, S, D]; got "
+            f"q={q.shape}, k={k.shape}, v={v.shape}")
     block_q = block_q if block_q is not None else _auto_block(Sq)
     block_k = block_k if block_k is not None else _auto_block(Sk)
     if Sq % block_q or Sk % block_k:
@@ -1382,7 +1408,7 @@ def _flash(q, k, v, causal, block_q, block_k, q_offset, k_offset, interpret,
         q.reshape(B * H, Sq, D), k.reshape((-1,) + k.shape[2:]),
         v.reshape((-1,) + v.shape[2:]), causal, block_q, block_k, q_offset,
         k_offset, interpret, window, blocks)
-    return out.reshape(B, H, Sq, D), lse.reshape(B, H, Sq)
+    return out.reshape(B, H, Sq, v.shape[-1]), lse.reshape(B, H, Sq)
 
 
 @functools.partial(
@@ -1396,8 +1422,12 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int | None = None,
                     window: int | None = None,
                     block_length: int | None = None,
                     before_block: bool = False):
-    """Pallas flash attention. q: [B, H, S, D], k, v: [B, KV heads, S, D]
-    → [B, H, S, D].
+    """Pallas flash attention. q: [B, H, S, D], k: [B, KV heads, S, D], v:
+    [B, KV heads, S, Dv] → [B, H, S, Dv]. ``Dv`` is ``D`` in every model
+    here but one: latent attention scores over 192 lanes and reads values
+    of 128, and the multi-tile kernels then move ``v``, the context, ``dO``
+    and ``dv`` at their own width (nothing is padded in HBM; the scale is
+    ``D ** -0.5``, the queries' width).
 
     Forward grid: (B*H, Sq/block_q, Sk/block_k), under a ``window`` (B*H,
     Sq/block_q, the K tiles of the band's widest row); each program

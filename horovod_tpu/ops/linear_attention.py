@@ -43,6 +43,16 @@ it. The rest is plain JAX, differentiated by JAX: no kernel yet. The
 scope ``hvd.linattn.scan`` is around all of it, forward and backward, and
 the gauge ``hvd_linattn_chunks_last{chunk,heads_here}`` says at trace time
 how many chunks a sequence the step that runs scans.
+
+:func:`kimi_delta_rule` is the same recurrence with **a decay a key
+channel** (``g [B, S, H, d_k]``: ``S' = Diag(exp(g_t)) S``; Kimi Delta
+Attention, arXiv:2510.26692, ``flash-linear-attention``'s ``kda``). Its
+chunk form is the one above with ``gamma [C, d_k]``, but the decay now
+sits inside the contraction, ``A_ij = beta_i sum_c k_ic k_jc exp(gamma_ic -
+gamma_jc)``, so ``(k k^T) * decay`` is no more and the pair terms are
+:func:`_pair_terms`'s. The solve, the scan over chunks, the scope and the
+gauge are shared; ``hvd_linattn_decay_width_last`` says which rule the
+step that runs holds (1, or ``d_k``).
 """
 
 from __future__ import annotations
@@ -133,6 +143,125 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64):
         return out.transpose(1, 0, 3, 2, 4).reshape(batch, seq, heads, d_v)
 
 
+def _pair_terms(q, k, gamma, sub: int, dtype):
+    """``(sum_c q_ic k_jc e_ijc, sum_c k_ic k_jc e_ijc)`` with ``e_ijc =
+    exp(gamma_ic - gamma_jc)`` for ``j <= i`` and zero above the diagonal:
+    ``q``, ``k`` ``[..., C, d]``, ``gamma`` (float32, falling along ``C``)
+    alike, both results ``[..., C, C]`` float32.
+
+    **No exponent is positive.** The cheap factorisation ``(k e^gamma)(k
+    e^-gamma)^T`` overflows: ``-gamma`` grows all through a chunk (at 1.6
+    a token it passes float32 within 56 tokens). So the chunk is cut into
+    sub-blocks of ``sub`` rows. A pair whose rows lie in different
+    sub-blocks goes through a reference row between them, the first row
+    ``r`` of ``i``'s sub-block: ``exp(gamma_i - gamma_r)`` and
+    ``exp(gamma_r - gamma_j)`` are both at most 1 and each side is one
+    operand of a matrix product on the MXU (the left one ``[C, d]``, the
+    right one ``[C / sub, C, d]``: a sub-block's own view of the rows
+    before it). A factor that underflows to zero stands for a pair whose
+    decay is smaller still. Pairs inside a sub-block are summed directly,
+    ``sub x sub x d`` exponentials of masked differences in float32.
+    Under ``jax.checkpoint``: the backward pass forms the factors and the
+    sub-blocks' cubes again from ``q``, ``k`` and ``gamma`` instead of
+    keeping them."""
+    f32 = jnp.float32
+    size, width = k.shape[-2:]
+    lead, count = k.shape[:-2], size // sub
+
+    def blocks(x):  # [..., C, d] -> [..., C / sub, sub, d]
+        return x.reshape(lead + (count, sub, width))
+
+    gamma_b = blocks(gamma)
+    first = gamma_b[..., :1, :]                           # a sub-block's row r
+    left = jnp.exp(gamma_b - first)                       # rows i >= r
+    before = (jnp.arange(size)[None, :]
+              < (jnp.arange(count) * sub)[:, None])[..., None]
+    right = jnp.exp(jnp.where(                            # rows j < r
+        before, first - gamma[..., None, :, :], -jnp.inf))
+    k_right = (k[..., None, :, :] * right).astype(dtype)  # [..., n, C, d]
+    inside = jnp.exp(jnp.where(                           # one sub-block's
+        jnp.tril(jnp.ones((sub, sub), bool))[..., None],
+        gamma_b[..., :, None, :] - gamma_b[..., None, :, :], -jnp.inf))
+    k_b = blocks(k).astype(f32)
+    own = jnp.eye(count, dtype=f32)[:, None, :, None]
+
+    def pairs(a):
+        far = jnp.einsum(
+            "...nid,...njd->...nij", (blocks(a) * left).astype(dtype),
+            k_right, preferred_element_type=f32)
+        near = (blocks(a).astype(f32)[..., :, None, :]
+                * k_b[..., None, :, :] * inside).sum(-1)  # [..., n, sub, sub]
+        near = near[..., :, :, None, :] * own             # on the diagonal
+        return far.reshape(lead + (size, size)) + near.reshape(
+            lead + (size, size))
+
+    return pairs(q), pairs(k)
+
+
+def kimi_delta_rule(q, k, v, g, beta, chunk: int = 64, sub: int = 16):
+    """The delta rule with a decay a key channel: ``q``, ``k``, ``g``
+    ``[B, S, H, d_k]``, ``v [B, S, H, d_v]``, ``beta [B, S, H]`` -> ``o
+    [B, S, H, d_v]`` in ``v``'s type, from a zero state::
+
+        S' = Diag(exp(g_t)) S        (row c of S decays by exp(g_tc))
+        S_t = S' + beta_t * k_t (v_t - S'^T k_t)^T
+        o_t = S_t^T q_t
+
+    ``q`` and ``k`` come as the rule reads them. ``S`` must be a multiple
+    of ``chunk`` and ``chunk`` of ``sub``, the sub-block of
+    :func:`_pair_terms`. Types as :func:`gated_delta_rule`: the products'
+    operands in ``v``'s type with float32 accumulation; ``g``, ``gamma``,
+    the decays, the solve and the carried state float32."""
+    batch, seq, heads, d_v = v.shape
+    if seq % chunk or chunk % sub:
+        raise ValueError(
+            f"kimi_delta_rule: a sequence of {seq} is no multiple of the "
+            f"chunk of {chunk}, or the chunk none of the sub-block of "
+            f"{sub}; pad it upstream")
+    count, dtype, f32 = seq // chunk, v.dtype, jnp.float32
+    _record_chunks(count, chunk, heads, decay_width=k.shape[-1])
+
+    def chunks(x):  # [B, S, H, ...] -> [B, H, chunks, chunk, ...]
+        x = x.reshape((batch, count, chunk) + x.shape[2:])
+        return jnp.moveaxis(x, 3, 1)
+
+    def product(spec, a, b):
+        return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
+                          preferred_element_type=f32)
+
+    with annotate_collective(SCOPE_LINATTN_SCAN):
+        q, k, v = chunks(q), chunks(k), chunks(v)
+        beta = chunks(beta.astype(f32))[..., None]
+        gamma = jnp.cumsum(chunks(g.astype(f32)), -2)      # [B, H, N, C, d_k]
+        grow = jnp.exp(gamma)                              # from the chunk's start
+        rest = jnp.exp(gamma[..., -1:, :] - gamma)         # to its end
+
+        inside, a = jax.checkpoint(
+            lambda q, k, gamma: _pair_terms(q, k, gamma, sub, dtype))(
+                q, k, gamma)
+        solved = solve_unit_lower(
+            jnp.tril(beta * a, -1),
+            beta * jnp.concatenate([v.astype(f32), k * grow], -1))
+        u, w = solved[..., :d_v], solved[..., d_v:]
+
+        def one_chunk(state, xs):
+            u, w, inside, q_in, k_out, kept = xs
+            new = u - product("bhck,bhkv->bhcv", w, state)
+            out = (product("bhck,bhkv->bhcv", q_in, state)
+                   + product("bhij,bhjv->bhiv", inside, new))
+            state = kept * state + product("bhck,bhcv->bhkv", k_out, new)
+            return state, out.astype(dtype)
+
+        # as gated_delta_rule: left operands rounded once, outside the loop
+        per_chunk = (u, w.astype(dtype), inside.astype(dtype),
+                     (q * grow).astype(dtype), (k * rest).astype(dtype),
+                     jnp.exp(gamma[..., -1, :])[..., None])
+        state = jnp.zeros((batch, heads, k.shape[-1], d_v), f32)
+        _, out = lax.scan(one_chunk, state, jax.tree.map(
+            lambda x: jnp.moveaxis(x, 2, 0), per_chunk))
+        return out.transpose(1, 0, 3, 2, 4).reshape(batch, seq, heads, d_v)
+
+
 def _exact(a, b):
     """``a @ b`` over the last two axes, float32 at full precision (a
     float32 product at the TPU's default is one bfloat16 pass)."""
@@ -207,10 +336,13 @@ def _solve_backward(kept, x_bar):
 solve_unit_lower.defvjp(_solve_forward, _solve_backward)
 
 
-def _record_chunks(count: int, chunk: int, heads: int) -> None:
+def _record_chunks(count: int, chunk: int, heads: int,
+                   decay_width: int = 1) -> None:
     """At trace time, as ``models.experts._record_slots``: the step that
-    runs scans this many chunks a sequence."""
+    runs scans this many chunks a sequence, under a decay that many wide
+    a head (1: :func:`gated_delta_rule`'s scalar)."""
     from .. import metrics
 
     metrics.LINATTN_CHUNKS_LAST.set(
         count, chunk=str(chunk), heads_here=str(heads))
+    metrics.LINATTN_DECAY_WIDTH_LAST.set(decay_width)

@@ -132,7 +132,8 @@ def make_moe_step(axis_name: str = "hvd", capacity: int = 4, mesh=None):
 
 def route_to_capacity(tokens, logits, num_experts, capacity, top_k=1,
                       first_expert=0, experts_here=None,
-                      gates_over_picks=False):
+                      gates_over_picks=False, scores="softmax",
+                      gate_scale=1.0):
     """Capacity-factor top-k routing into fixed per-expert slots — the
     jit-compatible answer to ragged dispatch (the helper the uneven-split
     ``alltoall`` rejection points at).
@@ -148,7 +149,12 @@ def route_to_capacity(tokens, logits, num_experts, capacity, top_k=1,
     ``num_experts``, not renormalised over the picks; with
     ``gates_over_picks`` the softmax over the ``top_k`` picked logits
     alone, whichever window holds each pick, so that a token's gates add
-    up to one over all the windows), each ``[T]`` for
+    up to one over all the windows; with ``scores="sigmoid"`` the picks
+    are the ``top_k`` of ``sigmoid(logits)`` in float32 and a gate is its
+    pick's score, with ``gates_over_picks`` divided by the sum of the
+    token's picked scores (plus 1e-20), and in either case times
+    ``gate_scale``: the routing of the DeepSeek-V3 family, whose
+    selection bias this does not have), each ``[T]`` for
     ``top_k=1`` and ``[T, top_k]`` otherwise, and ``counts
     [experts_here]`` (kept pairs per expert — the
     ``hvd_moe_expert_load`` signal).
@@ -170,12 +176,24 @@ def route_to_capacity(tokens, logits, num_experts, capacity, top_k=1,
     if experts_here is None:
         experts_here = num_experts - first_expert
     with annotate_collective(SCOPE_MOE_ROUTE):
-        picked, expert = lax.top_k(logits, top_k)              # [T, K]
-        if gates_over_picks:
-            gate = jax.nn.softmax(picked, axis=-1)
+        if scores not in ("softmax", "sigmoid") or (
+                scores == "softmax" and gate_scale != 1.0):
+            raise ValueError(
+                f"route_to_capacity: scores={scores!r} with gate_scale="
+                f"{gate_scale}; 'softmax' (unscaled) or 'sigmoid'")
+        if scores == "sigmoid":
+            picked, expert = lax.top_k(
+                jax.nn.sigmoid(logits.astype(jnp.float32)), top_k)
+            if gates_over_picks:
+                picked = picked / (picked.sum(-1, keepdims=True) + 1e-20)
+            gate = picked * gate_scale
         else:
-            gate = jnp.take_along_axis(
-                jax.nn.softmax(logits, axis=-1), expert, axis=1)
+            picked, expert = lax.top_k(logits, top_k)          # [T, K]
+            if gates_over_picks:
+                gate = jax.nn.softmax(picked, axis=-1)
+            else:
+                gate = jnp.take_along_axis(
+                    jax.nn.softmax(logits, axis=-1), expert, axis=1)
         # Pairs in token order, then pick order. An expert outside the
         # window has no column: its row of the one-hot is all zero.
         local = expert.reshape(T * top_k) - first_expert

@@ -17,7 +17,10 @@ key/value heads), the two block masks of block-diffusion training and
 query heads on one key/value head), and that call once more at the SDAR
 cell's own shapes on a sample of rows (``check_sdar_rows``); and the
 multi-tile kernels fed tokens-major against themselves fed head-major, bit
-for bit (``check_tiles_as_they_lie``). Compiled, never
+for bit (``check_tiles_as_they_lie``); and latent attention's call, keys of
+192 lanes and values of 128, checked and timed beside 128 / 128 and 256 /
+128 at the Kimi Linear cell's shape (``check_two_widths``; ``python
+tools/chip_kernel_check.py two_widths`` runs that alone). Compiled, never
 ``interpret=True``: off a TPU this exits non-zero.
 
 The tolerance is the one ``tests/test_sequence_parallel.py`` uses for bf16
@@ -98,20 +101,24 @@ def check_flash(batch, heads, seq, dim, causal, tokens_major=False) -> None:
         _close(name, g, w)
 
 
-def check_masked(name, attend, mask, heads, kv_heads, seq, dim=128) -> None:
-    """``attend(q, k, v)`` (``q [1, heads, seq, dim]``, ``k``, ``v [1,
-    kv_heads, seq, dim]`` in bf16) and its three gradients against the
-    float32 softmax under the dense ``mask [seq, seq]``, the keys and
-    values of a group repeated."""
+def check_masked(name, attend, mask, heads, kv_heads, seq, dim=128,
+                 v_dim=None) -> None:
+    """``attend(q, k, v)`` (``q [1, heads, seq, dim]``, ``k [1, kv_heads,
+    seq, dim]``, ``v [1, kv_heads, seq, v_dim]`` in bf16; ``v_dim`` is
+    ``dim`` unless given) and its three gradients against the float32
+    softmax under the dense ``mask [seq, seq]``, the keys and values of a
+    group repeated."""
     import jax
     import jax.numpy as jnp
 
-    print(f"{name}: H{heads} on KV{kv_heads} S{seq} D{dim} bf16, "
+    v_dim = v_dim or dim
+    print(f"{name}: H{heads} on KV{kv_heads} S{seq} D{dim} "
+          f"{'' if v_dim == dim else f'values D{v_dim} '}bf16, "
           f"{int(mask.sum())} of {mask.size} pairs a head")
     keys = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(keys[0], (1, heads, seq, dim), jnp.bfloat16)
-    k, v = (jax.random.normal(key, (1, kv_heads, seq, dim), jnp.bfloat16)
-            for key in keys[1:])
+    k = jax.random.normal(keys[1], (1, kv_heads, seq, dim), jnp.bfloat16)
+    v = jax.random.normal(keys[2], (1, kv_heads, seq, v_dim), jnp.bfloat16)
 
     def dense(q, k, v):
         k, v = (jnp.repeat(x, heads // kv_heads, axis=1) for x in (k, v))
@@ -222,6 +229,45 @@ def check_sdar_rows(heads=32, kv_heads=4, seq=8192, dim=128,
         _close(name, out[0][:, rows][:, pick], want[:, pick])
 
 
+def check_two_widths(heads=32, seq=8192, calls=20) -> None:
+    """Latent attention's call (``models/kimi_linear.py``: the scores
+    contract over 192 lanes, the values are 128 wide): against the dense
+    softmax at S2048, then timed at the Kimi Linear cell's own shape, one
+    sequence of 8,192 and 32 heads, forward and backward, beside the same
+    call at 128 / 128 lanes (what a block of one and a half lane blocks
+    costs over one) and at 256 / 128 (what padding the keys in HBM would
+    cost): ``calls`` dispatched back to back, only the last result kept."""
+    import time
+    from functools import partial
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from horovod_tpu.ops.attention import flash_attention
+
+    ahead = np.arange(2048)[:, None] - np.arange(2048)[None, :]
+    check_masked("latent attention's widths, causal",
+                 partial(flash_attention, causal=True), ahead >= 0, 8, 8,
+                 2048, dim=192, v_dim=128)
+    step = jax.jit(jax.value_and_grad(
+        lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True).astype(jnp.float32)), argnums=(0, 1, 2)))
+    keys = jax.random.split(jax.random.PRNGKey(2), 3)
+    for dim in (192, 128, 256):
+        q, k = (jax.random.normal(key, (1, heads, seq, dim), jnp.bfloat16)
+                for key in keys[:2])
+        v = jax.random.normal(keys[2], (1, heads, seq, 128), jnp.bfloat16)
+        jax.block_until_ready(step(q, k, v))
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            out = step(q, k, v)
+        jax.block_until_ready(out)
+        print(f"  H{heads} S{seq} causal, keys D{dim}, values D128: "
+              f"{(time.perf_counter() - t0) / calls * 1e3:.3f} ms a forward "
+              f"and backward call (with the sum and its gradient)")
+
+
 def check_tokens_major() -> None:
     """BERT's two shapes as its projections write them, an odd group of
     causal pairs, and a head a block."""
@@ -312,6 +358,10 @@ def main() -> None:
     d = jax.devices()[0]
     print(f"device: platform={d.platform} device_kind={d.device_kind!r} "
           f"count={len(jax.devices())}")
+    if sys.argv[1:] == ["two_widths"]:  # that check alone: ~2 minutes
+        check_two_widths()
+        print("kernels ok")
+        return
     check_flash(4, 16, 512, 64, causal=False)
     # BERT's S=128: 1,536 single-tile slices, a group of them a grid step;
     # and a count of slices that no power of two divides
@@ -323,6 +373,7 @@ def main() -> None:
     check_masks()
     check_sdar_rows()
     check_tiles_as_they_lie()
+    check_two_widths()
     print("kernels ok")
 
 
