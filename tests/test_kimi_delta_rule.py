@@ -5,7 +5,14 @@ mild decays, at the initial draw's strongest (1.6 a token) and at e^-20 a
 token, where the cheap factorisation ``(k e^gamma)(k e^-gamma)^T`` would
 overflow inside one sub-block: nothing here is an ``inf`` or a ``nan``.
 With every channel's decay equal it is ``gated_delta_rule``. The scope, the
-two gauges and the refusal of a ragged sequence are there."""
+two gauges and the refusal of a ragged sequence are there. The running sum
+``gamma`` is a float32 product with a triangle of ones at
+``Precision.HIGHEST``: a float64 sum's value, and no ``reduce_window`` in
+the program, forward or backward; ``gated_delta_rule`` keeps its text."""
+
+import contextlib
+import hashlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -143,3 +150,103 @@ def test_the_scope_and_the_gauges_say_which_rule_the_step_holds():
     jax.jit(linear_attention.gated_delta_rule, static_argnames="chunk").lower(
         *args[:3], args[3][..., 0], args[4], chunk=32)
     assert metrics.LINATTN_DECAY_WIDTH_LAST.labels().get() == 1
+
+
+TEXT_CHUNK = 32  # of the cases that read the lowered text
+
+
+def lowered(fn, what: str, *args) -> str:
+    """``fn``'s lowered text, or that of its five gradients."""
+    if what == "gradient":
+        fn = jax.grad(lambda *a, fn=fn: fn(*a).sum(), argnums=range(5))
+    return jax.jit(fn).lower(*args).as_text()
+
+
+def running_sums_products(text: str):
+    """The precisions of the ``dot_general``s that take ``g`` in chunks,
+    or ``gamma``'s cotangent (a ``[B, H, N, C, d_k]`` float32 operand in
+    whatever order), against a ``[..., C, C]`` one: the running sum and
+    its transpose. No other product of the rule has a five-dimensional
+    operand ``d_k`` wide."""
+    chunk = TEXT_CHUNK
+    g_dims, found = sorted([B, H, S // chunk, chunk, DK]), []
+    for line in text.splitlines():
+        types = re.search(
+            r"stablehlo\.dot_general.*precision = \[(\w+), (\w+)\].*"
+            r": \(tensor<([\dx]+)xf32>, tensor<([\dx]+)xf32>\)", line)
+        if not types:
+            continue
+        shapes = [[int(n) for n in t.split("x")] for t in types.groups()[2:]]
+        for one, other in (shapes, shapes[::-1]):
+            if sorted(one) == g_dims and other[-2:] == [chunk, chunk]:
+                found.append(types.groups()[:2])
+    return found
+
+
+@pytest.mark.parametrize("what", ["value", "gradient"])
+def test_no_reduce_window_is_in_the_program(what):
+    """As ``solve_unit_lower``'s "no ``triangular_solve``": a ``cumsum``
+    over the rows of ``[C, d_k]`` lowers to a ``reduce_window``, which the
+    v5e ran at 7 G elements a second, 50 ms of the cell's step. The
+    transposed running sum of the backward pass is a product too."""
+    text = lowered(rule(TEXT_CHUNK, 16), what, *inputs(0.3))
+    assert "reduce_window" not in text and "cumsum" not in text
+    assert len(running_sums_products(text)) == {"value": 1, "gradient": 2}[what]
+
+
+@pytest.mark.parametrize("asked", [None, "bfloat16"])
+def test_the_running_sums_product_is_at_full_precision(asked):
+    """One bfloat16 pass would round ``g`` to 8 bits before it is summed.
+    A CPU computes float32 whatever is asked, so the text is what can be
+    held here: ``HIGHEST`` on both operands, forward and transposed, also
+    where the caller's default is lower."""
+    with (jax.default_matmul_precision(asked) if asked
+          else contextlib.nullcontext()):
+        text = lowered(rule(TEXT_CHUNK, 16), "gradient", *inputs(0.3))
+    assert running_sums_products(text) == [("HIGHEST", "HIGHEST")] * 2
+
+
+@pytest.mark.parametrize("rate", sorted(RATES))
+def test_the_running_sum_is_a_float64_sums(rate, monkeypatch):
+    """``gamma`` as the rule forms it (read where ``_pair_terms`` is handed
+    it, with the inner ``jax.checkpoint`` out of the way so that it is an
+    array) against ``numpy``'s float64 ``cumsum`` inside each chunk: float32's
+    sum in another order, 1e-6 of its largest."""
+    chunk, seen = 32, {}
+    pair_terms = linear_attention._pair_terms
+
+    def watched(q, k, gamma, sub, dtype):
+        seen["gamma"] = gamma
+        return pair_terms(q, k, gamma, sub, dtype)
+
+    monkeypatch.setattr(linear_attention, "_pair_terms", watched)
+    monkeypatch.setattr(jax, "checkpoint", lambda fn: fn)
+    args = inputs(RATES[rate], seed=4)
+    linear_attention.kimi_delta_rule(*args, chunk=chunk, sub=8)
+    g = np.asarray(args[3], np.float64).reshape(B, S // chunk, chunk, H, DK)
+    want = np.moveaxis(np.cumsum(g, 2), 3, 1)          # [B, H, N, C, d_k]
+    got = np.asarray(seen["gamma"])
+    assert got.dtype == np.float32 and got.shape == want.shape
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=1e-6 * np.abs(want).max())
+
+
+# sha256 of jit(gated_delta_rule).lower(...).as_text(), value and gradient,
+# at commit bee4942, the parent of the running sum as a product: the scalar
+# rule's cumsum is [B, H, N, C] without the 128 lanes, and stays
+SCALAR_RULES_TEXT = {
+    "value": "ffae072f98bc93e2cb127923d64ceb225aa48b7a5f04845b5b164a7bbd7e51b2",
+    "gradient": "94e46043824c91c990e077ab7372c3a8140faddcadc8b3b6c532f1951b6693e2",
+}
+
+
+@pytest.mark.parametrize("what", sorted(SCALAR_RULES_TEXT))
+def test_the_scalar_rule_lowers_to_the_text_it_had(what):
+    q, k, v, g, beta = inputs(0.3)
+
+    def fn(*a):  # the text holds the name
+        return linear_attention.gated_delta_rule(*a, chunk=TEXT_CHUNK)
+
+    text = lowered(fn, what, q, k, v, g[..., 0], beta)
+    assert "reduce_window" in text
+    assert hashlib.sha256(text.encode()).hexdigest() == SCALAR_RULES_TEXT[what]
