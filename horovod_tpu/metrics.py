@@ -915,6 +915,14 @@ LINATTN_DECAY_WIDTH_LAST = gauge(
     "gated_delta_rule (one scalar a head), d_k for kimi_delta_rule (one a "
     "key channel, the pair terms formed sub-block by sub-block): set at "
     "trace time, beside hvd_linattn_chunks_last.")
+LINATTN_PAIR_KERNEL_LAST = gauge(
+    "hvd_linattn_pair_kernel_last",
+    "Chunks a grid step of the Pallas kernel that forms kimi_delta_rule's "
+    "pair terms in the LAST lowered program takes, 0 where that program "
+    "holds the plain form (lowered for any platform but a TPU, or "
+    "sub-blocks that fill no tile): set as the program is lowered, since "
+    "the lowering platform chooses.",
+    ("sub",))
 SSM_CHUNKS_LAST = gauge(
     "hvd_ssm_chunks_last",
     "Chunks a sequence that the LAST traced Mamba-2 scan (ops/ssd.py "

@@ -39,7 +39,7 @@ multiply-adds with the systems along the lanes, applies it by one float32
 product at ``Precision.HIGHEST``, and is differentiated by a rule of its
 own that keeps the inverse and the solution and makes two such products.
 The loop's left operands are rounded to the compute type once, outside
-it. The rest is plain JAX, differentiated by JAX: no kernel yet. The
+it. The rest of the scalar rule is plain JAX, differentiated by JAX. The
 scope ``hvd.linattn.scan`` is around all of it, forward and backward, and
 the gauge ``hvd_linattn_chunks_last{chunk,heads_here}`` says at trace time
 how many chunks a sequence the step that runs scans.
@@ -52,17 +52,31 @@ sits inside the contraction, ``A_ij = beta_i sum_c k_ic k_jc exp(gamma_ic -
 gamma_jc)``, so ``(k k^T) * decay`` is no more and the pair terms are
 :func:`_pair_terms`'s. The solve, the scan over chunks, the scope and the
 gauge are shared; ``hvd_linattn_decay_width_last`` says which rule the
-step that runs holds (1, or ``d_k``). Its ``gamma`` is a float32 product of
-the chunk's lower triangle of ones with ``g`` at ``Precision.HIGHEST``:
-summed as ``jnp.cumsum`` over the rows of ``[C, d_k]`` it is a
-``reduce-window``, which the v5e runs at a fourteenth of its memory's pace.
+step that runs holds (1, or ``d_k``). Where the shapes fill a TPU's tiles
+(``d_k`` whole 128-lane blocks, ``sub`` whole sublane tiles) the pair terms
+are a primitive whose lowering the platform chooses: for a TPU
+:func:`pair_terms_kernel`'s Pallas kernel, which forms them in VMEM, with a
+backward kernel of its own; for anything else, and at any other shape, the
+plain :func:`_pair_terms` (``hvd_linattn_pair_kernel_last`` says, as the
+program is lowered, the chunks a grid step takes, or 0 for the plain form).
+Its ``gamma`` is a float32 product of the chunk's lower triangle of ones
+with ``g`` at ``Precision.HIGHEST``: summed as ``jnp.cumsum`` over the rows
+of ``[C, d_k]`` it is a ``reduce-window``, which the v5e runs at a
+fourteenth of its memory's pace.
 """
 
 from __future__ import annotations
 
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.extend.core import Primitive
+from jax.interpreters import mlir
 
 from ..attribution import SCOPE_LINATTN_SCAN
 from ..profiler import annotate_collective
@@ -164,9 +178,10 @@ def _pair_terms(q, k, gamma, sub: int, dtype):
     before it). A factor that underflows to zero stands for a pair whose
     decay is smaller still. Pairs inside a sub-block are summed directly,
     ``sub x sub x d`` exponentials of masked differences in float32.
-    Under ``jax.checkpoint``: the backward pass forms the factors and the
+    This is the plain form: what any platform but a TPU runs (under
+    ``jax.checkpoint``: the backward pass forms the factors and the
     sub-blocks' cubes again from ``q``, ``k`` and ``gamma`` instead of
-    keeping them."""
+    keeping them) and what the tests hold :func:`pair_terms_kernel` to."""
     f32 = jnp.float32
     size, width = k.shape[-2:]
     lead, count = k.shape[:-2], size // sub
@@ -199,6 +214,293 @@ def _pair_terms(q, k, gamma, sub: int, dtype):
             lead + (size, size))
 
     return pairs(q), pairs(k)
+
+
+# The kernels' name: not ``flash_attention``, by which the benchmark finds
+# the attention kernels. XLA names the custom call's instruction after it.
+PAIR_KERNEL_NAME = "kda_pair_terms"
+PAIR_CHUNKS_A_STEP = 8  # of a grid step, where that many divide the chunks
+_MASKED = -1e30  # an exponent above the diagonal: exp gives 0, never a nan
+_NT = (((1,), (1,)), ((), ()))  # a @ b^T
+_TN = (((0,), (0,)), ((), ()))  # a^T @ b
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+
+
+def _sub_block(q_ref, k_ref, gamma_ref, c, lo, hi, dtype):
+    """Sub-block ``[lo, hi)`` of chunk ``c`` of a grid step: its rows of
+    ``q`` and ``k`` in float32; the cube ``e_ijc`` of its own pairs in
+    pieces ``(first row, [rows, columns, d])`` of eight rows (a float32
+    tile's) against the columns up to their last, zero above the
+    diagonal: the triangle's tiles alone; and for the pairs with
+    the rows before it, through its first row ``r``: ``(q_i e^(gamma_i -
+    gamma_r), k_i e^(gamma_i - gamma_r))`` stacked and ``k_j e^(gamma_r -
+    gamma_j)``, rounded to ``dtype``, with the two float32 factors
+    (``None`` for the first sub-block). No exponent is positive."""
+    f32 = jnp.float32
+    gamma = gamma_ref[c, lo:hi, :]
+    q, k = q_ref[c, lo:hi, :].astype(f32), k_ref[c, lo:hi, :].astype(f32)
+    pieces, rows = [], min(8, hi - lo)
+    for top in range(0, hi - lo, rows):
+        shape = (rows, top + rows, gamma.shape[-1])
+        lower = (lax.broadcasted_iota(jnp.int32, shape, 1)
+                 <= lax.broadcasted_iota(jnp.int32, shape, 0) + top)
+        pieces.append((top, jnp.exp(jnp.where(
+            lower, gamma[top:top + rows][:, None, :]
+            - gamma[:top + rows][None, :, :], _MASKED))))
+    if not lo:
+        return q, k, pieces, None
+    left = jnp.exp(gamma - gamma[:1])
+    right = jnp.exp(gamma[:1] - gamma_ref[c, 0:lo, :])
+    far = (jnp.concatenate([(q * left).astype(dtype),
+                            (k * left).astype(dtype)], 0),
+           (k_ref[c, 0:lo, :].astype(f32) * right).astype(dtype), left, right)
+    return q, k, pieces, far
+
+
+def _pair_forward_kernel(q_ref, k_ref, gamma_ref, inside_ref, a_ref, *,
+                         sub, dtype):
+    """``inside`` and ``a`` of the grid step's chunks, ``[chunks, C, C]``
+    float32, sub-block by sub-block of rows: the columns before it one
+    product of ``dtype`` operands for both, its own the cube's lane sums,
+    those after a row's piece zero."""
+    chunks, size, _ = q_ref.shape
+    f32 = jnp.float32
+
+    def one_chunk(c, carry):
+        for lo in range(0, size, sub):
+            hi = lo + sub
+            q, k, pieces, far = _sub_block(q_ref, k_ref, gamma_ref, c, lo,
+                                           hi, dtype)
+            if far is not None:
+                both = lax.dot_general(far[0], far[1], _NT,
+                                       preferred_element_type=f32)
+                inside_ref[c, lo:hi, 0:lo] = both[:sub]
+                a_ref[c, lo:hi, 0:lo] = both[sub:]
+            for top, cube in pieces:
+                rows, columns = cube.shape[:2]
+                here = slice(lo + top, lo + top + rows)
+                near = cube * k[:columns][None, :, :]
+                inside_ref[c, here, lo:lo + columns] = (
+                    near * q[top:top + rows][:, None, :]).sum(-1)
+                a_ref[c, here, lo:lo + columns] = (
+                    near * k[top:top + rows][:, None, :]).sum(-1)
+                if lo + columns < size:
+                    above = jnp.zeros((rows, size - lo - columns), f32)
+                    inside_ref[c, here, lo + columns:size] = above
+                    a_ref[c, here, lo + columns:size] = above
+        return carry
+
+    lax.fori_loop(0, chunks, one_chunk, 0)
+
+
+def _pair_backward_kernel(q_ref, k_ref, gamma_ref, inside_bar_ref, a_bar_ref,
+                          q_bar_ref, k_bar_ref, gamma_bar_ref, right_ref, *,
+                          sub, dtype):
+    """``dq``, ``dk``, ``dgamma`` of the grid step's chunks from the two
+    cotangents: the forward's factors formed again, sub-blocks last to
+    first, so that a row's cotangent as a pair's right side (``right_ref``,
+    float32 scratch) is whole when its own sub-block is done. The sums run
+    over ``i`` or ``j``, never over lanes. By term ``dgamma_i = q_i dq_i +
+    k_i (dk_i as the left side - dk_i as the right side)``: no cube of its
+    own. Cotangents of ``dtype`` operands go into their products rounded
+    to ``dtype``, as the plain form's transposed products take them."""
+    chunks, size, _ = q_ref.shape
+    f32 = jnp.float32
+
+    def one_chunk(c, carry):
+        right_ref[...] = jnp.zeros_like(right_ref)
+        for lo in reversed(range(0, size, sub)):
+            hi = lo + sub
+            q, k, pieces, far = _sub_block(q_ref, k_ref, gamma_ref, c, lo,
+                                           hi, dtype)
+            q_bar, as_left = [], []
+            for top, cube in pieces:
+                rows, columns = cube.shape[:2]
+                here = slice(lo + top, lo + top + rows)
+                by_inside = inside_bar_ref[c, here, lo:lo + columns][
+                    :, :, None] * cube
+                by_a = a_bar_ref[c, here, lo:lo + columns][:, :, None] * cube
+                q_bar.append((by_inside * k[:columns][None, :, :]).sum(1))
+                as_left.append((by_a * k[:columns][None, :, :]).sum(1))
+                right_ref[lo:lo + columns, :] += (
+                    by_inside * q[top:top + rows][:, None, :]
+                    + by_a * k[top:top + rows][:, None, :]).sum(0)
+            q_bar, as_left = jnp.concatenate(q_bar), jnp.concatenate(as_left)
+            if far is not None:
+                stacked, k_right, left, right = far
+                bars = jnp.concatenate([inside_bar_ref[c, lo:hi, 0:lo],
+                                        a_bar_ref[c, lo:hi, 0:lo]],
+                                       0).astype(dtype)
+                to_left = lax.dot_general(bars, k_right, _NN,
+                                          preferred_element_type=f32)
+                q_bar = q_bar + to_left[:sub] * left
+                as_left = as_left + to_left[sub:] * left
+                right_ref[0:lo, :] += right * lax.dot_general(
+                    bars, stacked, _TN, preferred_element_type=f32)
+            as_right = right_ref[lo:hi, :]
+            q_bar_ref[c, lo:hi, :] = q_bar.astype(q_bar_ref.dtype)
+            k_bar_ref[c, lo:hi, :] = (as_left + as_right).astype(
+                k_bar_ref.dtype)
+            gamma_bar_ref[c, lo:hi, :] = q * q_bar + k * (as_left - as_right)
+        return carry
+
+    lax.fori_loop(0, chunks, one_chunk, 0)
+
+
+def _chunks_a_step(count: int) -> int:
+    """The largest divisor of ``count`` chunks up to
+    ``PAIR_CHUNKS_A_STEP``."""
+    return max(n for n in range(1, PAIR_CHUNKS_A_STEP + 1) if not count % n)
+
+
+def _pair_call(kernel, operands, widths, dtypes, scratch=(), *, step, sub,
+               dtype, interpret):
+    """``kernel`` over ``operands [chunks, C, width]`` in grid steps of
+    ``step`` chunks, giving ``[chunks, C, widths[n]]`` in ``dtypes[n]``."""
+    count, size = operands[0].shape[:2]
+
+    def block(width):
+        return pl.BlockSpec((step, size, width), lambda n: (n, 0, 0))
+
+    return pl.pallas_call(
+        functools.partial(kernel, sub=sub, dtype=dtype),
+        grid=(count // step,),
+        in_specs=[block(x.shape[-1]) for x in operands],
+        out_specs=[block(width) for width in widths],
+        out_shape=[jax.ShapeDtypeStruct((count, size, width), kind)
+                   for width, kind in zip(widths, dtypes)],
+        scratch_shapes=scratch,
+        interpret=interpret,
+        name=PAIR_KERNEL_NAME,
+    )(*operands)
+
+
+def _forward_by_kernel(q, k, gamma, **how):
+    size = k.shape[-2]
+    return _pair_call(_pair_forward_kernel, [q, k, gamma], [size, size],
+                      [jnp.float32] * 2, **how)
+
+
+def _backward_by_kernel(q, k, gamma, inside_bar, a_bar, **how):
+    size, width = k.shape[-2:]
+    return _pair_call(
+        _pair_backward_kernel, [q, k, gamma, inside_bar, a_bar], [width] * 3,
+        [q.dtype, k.dtype, jnp.float32],
+        [pltpu.VMEM((size, width), jnp.float32)], **how)
+
+
+def _forward_plain(q, k, gamma, *, sub, dtype, **_):
+    return _pair_terms(q, k, gamma, sub, dtype)
+
+
+def _backward_plain(q, k, gamma, *bars, sub, dtype, **_):
+    # the factors and the cubes again from the operands, as jax.checkpoint's
+    return jax.vjp(lambda *xs: _pair_terms(*xs, sub, dtype),
+                   q, k, gamma)[1](bars)
+
+
+def _where_lowered(name, results, by_kernel, plain):
+    """The primitive ``name`` over ``[chunks, C, width]`` operands whose
+    lowering for a TPU is ``by_kernel`` and for any other platform
+    ``plain`` (``by_kernel`` interpreted where the tests say ``interpret``):
+    the lowering platform is what the code can observe, and a trace does
+    not know it (``benchmark/aot.py`` lowers for a v5e from a CPU;
+    ``jax.default_backend()`` would say ``cpu`` there). Only the chosen form
+    is ever traced, and it says which it is (the gauge) as it is lowered.
+    ``results(*avals)`` are the results' abstract values."""
+    primitive = Primitive(name)
+    primitive.multiple_results = True
+    primitive.def_abstract_eval(lambda *avals, **_: results(*avals))
+
+    @functools.cache
+    def alone(**how):  # called outside any trace
+        return jax.jit(functools.partial(primitive.bind, **how))
+
+    primitive.def_impl(lambda *xs, **how: alone(**how)(*xs))
+
+    def lowering(on_tpu):
+        def form(*xs, step, sub, dtype, interpret):
+            kernel = on_tpu or interpret
+            _record_pair_path(step if kernel else 0, sub)
+            return (by_kernel if kernel else plain)(
+                *xs, step=step, sub=sub, dtype=dtype, interpret=interpret)
+
+        return mlir.lower_fun(form, multiple_results=True)
+
+    mlir.register_lowering(primitive, lowering(True), platform="tpu")
+    mlir.register_lowering(primitive, lowering(False))
+    return primitive
+
+
+_pair_forward_p = _where_lowered(
+    "hvd_kda_pair_terms",
+    lambda q, k, gamma: [gamma.update(shape=k.shape[:-1] + k.shape[-2:-1])] * 2,
+    _forward_by_kernel, _forward_plain)
+_pair_backward_p = _where_lowered(
+    "hvd_kda_pair_terms_backward",
+    lambda q, k, gamma, inside_bar, a_bar: [q, k, gamma],
+    _backward_by_kernel, _backward_plain)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def pair_terms_kernel(q, k, gamma, sub, dtype, interpret=False):
+    """:func:`_pair_terms` as one Pallas kernel, and its backward pass as
+    another, in a program lowered for a TPU (anywhere, interpreted, where
+    the tests say ``interpret``; the plain form itself on any other
+    platform): a grid step takes some chunks' ``q``, ``k`` and ``gamma``
+    into VMEM and nothing between them and the two ``[C, C]`` results is
+    written to HBM (the plain form writes ``k_right``, four times ``k``,
+    and the sub-blocks' cubes). The same reference rows, the same
+    rounding points: a pair in different sub-blocks is a product of
+    ``dtype`` operands with float32 accumulation, a pair inside one is
+    float32 throughout. The residuals are the operands; the backward
+    kernel forms the factors again in VMEM. Each pass is a primitive of
+    its own (:func:`_where_lowered`), so a recomputed layer's policy sees
+    no ``pallas_call`` whose results it would keep (134 MB a layer at
+    8,192 tokens that no backward kernel wants): they are formed again in
+    the backward pass, as the plain form's under ``jax.checkpoint``."""
+    return _pair_forward(q, k, gamma, sub, dtype, interpret)[0]
+
+
+def _flat(x):  # [..., C, width] -> [chunks, C, width]
+    return x.reshape((-1,) + x.shape[-2:])
+
+
+def _how(k, sub, dtype, interpret):
+    return dict(step=_chunks_a_step(math.prod(k.shape[:-2])), sub=sub,
+                dtype=jnp.dtype(dtype), interpret=interpret)
+
+
+def _pair_forward(q, k, gamma, sub, dtype, interpret):
+    inside, a = _pair_forward_p.bind(
+        _flat(q), _flat(k), _flat(gamma), **_how(k, sub, dtype, interpret))
+    lead = k.shape[:-1] + k.shape[-2:-1]
+    return (inside.reshape(lead), a.reshape(lead)), (q, k, gamma)
+
+
+def _pair_backward(sub, dtype, interpret, kept, bars):
+    k = kept[1]
+    out = _pair_backward_p.bind(
+        *(_flat(x) for x in kept + tuple(bars)),
+        **_how(k, sub, dtype, interpret))
+    return tuple(x.reshape(k.shape) for x in out)
+
+
+pair_terms_kernel.defvjp(_pair_forward, _pair_backward)
+
+
+def _pair_terms_where_lowered(q, k, gamma, sub, dtype):
+    """The pair terms by :func:`pair_terms_kernel` where a TPU's tiles are
+    filled (``d_k`` whole lanes, ``sub`` whole sublanes of ``q``'s and
+    ``k``'s type), so that the program lowered for a TPU holds the kernels
+    and any other the plain form; at any other shape the plain form under
+    ``jax.checkpoint`` whatever the platform."""
+    rows = 32 // min(q.dtype.itemsize, k.dtype.itemsize)  # a tile's sublanes
+    if k.shape[-1] % 128 == 0 and sub % rows == 0:
+        return pair_terms_kernel(q, k, gamma, sub, dtype)
+    _record_pair_path(0, sub)
+    return jax.checkpoint(
+        lambda q, k, gamma: _pair_terms(q, k, gamma, sub, dtype))(q, k, gamma)
 
 
 def kimi_delta_rule(q, k, v, g, beta, chunk: int = 64, sub: int = 16):
@@ -253,9 +555,7 @@ def kimi_delta_rule(q, k, v, g, beta, chunk: int = 64, sub: int = 16):
         grow = jnp.exp(gamma)                              # from the chunk's start
         rest = jnp.exp(gamma[..., -1:, :] - gamma)         # to its end
 
-        inside, a = jax.checkpoint(
-            lambda q, k, gamma: _pair_terms(q, k, gamma, sub, dtype))(
-                q, k, gamma)
+        inside, a = _pair_terms_where_lowered(q, k, gamma, sub, dtype)
         solved = solve_unit_lower(
             jnp.tril(beta * a, -1),
             beta * jnp.concatenate([v.astype(f32), k * grow], -1))
@@ -363,3 +663,12 @@ def _record_chunks(count: int, chunk: int, heads: int,
     metrics.LINATTN_CHUNKS_LAST.set(
         count, chunk=str(chunk), heads_here=str(heads))
     metrics.LINATTN_DECAY_WIDTH_LAST.set(decay_width)
+
+
+def _record_pair_path(chunks_a_step: int, sub: int) -> None:
+    """As the program is lowered (at trace time where the shapes alone
+    decide): the form of :func:`kimi_delta_rule`'s pair terms it holds,
+    the kernels' chunks a grid step or 0 for the plain form."""
+    from .. import metrics
+
+    metrics.LINATTN_PAIR_KERNEL_LAST.set(chunks_a_step, sub=str(sub))

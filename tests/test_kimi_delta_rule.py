@@ -8,7 +8,14 @@ With every channel's decay equal it is ``gated_delta_rule``. The scope, the
 two gauges and the refusal of a ragged sequence are there. The running sum
 ``gamma`` is a float32 product with a triangle of ones at
 ``Precision.HIGHEST``: a float64 sum's value, and no ``reduce_window`` in
-the program, forward or backward; ``gated_delta_rule`` keeps its text."""
+the program, forward or backward; ``gated_delta_rule`` keeps its text.
+
+The pair terms have two forms, ``_pair_terms`` (plain JAX: any platform but
+a TPU) and ``pair_terms_kernel`` (a Pallas kernel and its backward kernel:
+the program lowered for a TPU). The kernel in interpret mode is the plain
+form, values and the three cotangents, and every case of the rule runs
+through both (``path``): on the kernel's path the test puts the kernel
+where the lowering platform would have."""
 
 import contextlib
 import hashlib
@@ -63,9 +70,23 @@ def rule(chunk, sub):
         *a, chunk=chunk, sub=sub)
 
 
+@pytest.fixture(params=["plain", "kernel"])
+def path(request, monkeypatch):
+    """Which form of the pair terms the rule holds. The lowering platform
+    decides that in the product (and sub-blocks that fill no tile keep the
+    plain form, as all of these do); here the kernel is put in its place,
+    interpreted."""
+    if request.param == "kernel":
+        monkeypatch.setattr(
+            linear_attention, "_pair_terms_where_lowered",
+            lambda q, k, gamma, sub, dtype: linear_attention.pair_terms_kernel(
+                q, k, gamma, sub, dtype, True))
+    return request.param
+
+
 @pytest.mark.parametrize("rate", sorted(RATES))
 @pytest.mark.parametrize("chunk,sub", FORMS)
-def test_chunk_form_is_the_recurrence(chunk, sub, rate):
+def test_chunk_form_is_the_recurrence(chunk, sub, rate, path):
     args = inputs(RATES[rate])
     with jax.default_matmul_precision("highest"):
         want = jax.jit(recurrence)(*args)
@@ -77,7 +98,7 @@ def test_chunk_form_is_the_recurrence(chunk, sub, rate):
 
 
 @pytest.mark.parametrize("rate", sorted(RATES))
-def test_chunk_forms_gradients_are_the_recurrences(rate):
+def test_chunk_forms_gradients_are_the_recurrences(rate, path):
     args = inputs(RATES[rate], seed=1)
     weight = jax.random.normal(jax.random.PRNGKey(9), (B, S, H, DV))
 
@@ -94,18 +115,28 @@ def test_chunk_forms_gradients_are_the_recurrences(rate):
                                    err_msg=name)
 
 
-def test_the_cheap_factorisation_would_have_overflowed():
+def test_the_cheap_factorisation_would_have_overflowed(path, monkeypatch):
     """What the sub-blocks are for: at e^-20 a token the running sum falls
     past -88 within five tokens, so ``exp(-gamma)`` is past float32 inside
     one sub-block of eight, while every exponent the rule forms is a
-    difference ``gamma_i - gamma_j`` with ``j <= i``."""
-    _, _, _, g, _ = inputs(RATES["e-20_a_token"])
-    gamma = jnp.cumsum(g[:, :8], 1)
+    difference ``gamma_i - gamma_j`` with ``j <= i``: with an exponential
+    that answers ``nan`` to any positive argument the rule and its five
+    gradients stay finite, through the kernels too."""
+    args = inputs(RATES["e-20_a_token"])
+    gamma = jnp.cumsum(args[3][:, :8], 1)
     assert not bool(jnp.isfinite(jnp.exp(-gamma)).all())
+
+    exp = jnp.exp
+    monkeypatch.setattr(
+        jnp, "exp", lambda x: jnp.where(x > 0, jnp.nan, exp(x)))
+    assert bool(jnp.isnan(jnp.exp(jnp.float32(1e-3))))
+    out, grads = jax.value_and_grad(
+        lambda *a: rule(32, 8)(*a).sum(), argnums=range(5))(*args)
+    assert all(bool(jnp.isfinite(x).all()) for x in (out,) + grads)
 
 
 @pytest.mark.parametrize("chunk,sub", FORMS[:2])
-def test_equal_channels_are_the_gated_delta_rule(chunk, sub):
+def test_equal_channels_are_the_gated_delta_rule(chunk, sub, path):
     q, k, v, g, beta = inputs(0.3, seed=2)
     scalar = g[..., 0]
     with jax.default_matmul_precision("highest"):
@@ -117,7 +148,7 @@ def test_equal_channels_are_the_gated_delta_rule(chunk, sub):
                                atol=1e-5 * float(jnp.abs(want).max()))
 
 
-def test_bfloat16_operands_keep_float32_decays_and_state():
+def test_bfloat16_operands_keep_float32_decays_and_state(path):
     """As ``gated_delta_rule``: ``v``'s type is the products' and the
     result's; ``g`` stays float32 through ``gamma`` and the carried state."""
     q, k, v, g, beta = inputs(0.3, seed=3)
@@ -133,23 +164,137 @@ def test_bfloat16_operands_keep_float32_decays_and_state():
 
 
 @pytest.mark.parametrize("seq,chunk,sub", [(48, 32, 8), (64, 32, 12)])
-def test_a_ragged_sequence_or_sub_block_is_refused(seq, chunk, sub):
+def test_a_ragged_sequence_or_sub_block_is_refused(seq, chunk, sub, path):
     q, k, v, g, beta = (x[:, :seq] for x in inputs(0.3))
     with pytest.raises(ValueError, match="pad it upstream"):
         linear_attention.kimi_delta_rule(q, k, v, g, beta, chunk=chunk,
                                          sub=sub)
 
 
-def test_the_scope_and_the_gauges_say_which_rule_the_step_holds():
+def test_the_scope_and_the_gauges_say_which_rule_the_step_holds(path):
     args = inputs(0.3)
+    metrics.LINATTN_PAIR_KERNEL_LAST.set(-1, sub="8")
     text = jax.jit(rule(32, 8)).lower(*args).as_text(debug_info=True)
     assert "hvd.linattn.scan" in text
+    # said when the program is lowered: no kernel there, or twelve chunks
+    # in grid steps of six where the test has put the kernel, interpreted
+    assert metrics.LINATTN_PAIR_KERNEL_LAST.labels(sub="8").get() == {
+        "plain": 0, "kernel": 6}[path]
     assert metrics.LINATTN_CHUNKS_LAST.labels(
         chunk="32", heads_here=str(H)).get() == S // 32
     assert metrics.LINATTN_DECAY_WIDTH_LAST.labels().get() == DK
-    jax.jit(linear_attention.gated_delta_rule, static_argnames="chunk").lower(
-        *args[:3], args[3][..., 0], args[4], chunk=32)
+    # (a function of its own: a second trace of the same one is cached)
+    jax.jit(lambda *a: linear_attention.gated_delta_rule(*a, chunk=32)).lower(
+        *args[:3], args[3][..., 0], args[4])
     assert metrics.LINATTN_DECAY_WIDTH_LAST.labels().get() == 1
+
+
+PAIR_FORMS = [(64, 16, 128), (16, 4, 16)]  # (chunk, sub-block, d_k)
+
+
+def pair_operands(rate, chunk, width, dtype, seed=5):
+    """``q``, ``k`` in ``dtype`` and a falling float32 ``gamma``, ``[1, 2,
+    3, chunk, width]`` (six chunks: grid steps of three), and a cotangent
+    for each result."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    shape = (1, 2, 3, chunk, width)
+    q, k = (jax.random.normal(key, shape).astype(dtype) for key in keys[:2])
+    gamma = jnp.cumsum(-rate * jax.random.uniform(
+        keys[2], shape, minval=0.5, maxval=1.0), -2)
+    bars = tuple(jax.random.normal(key, shape[:-1] + (chunk,))
+                 for key in keys[3:])
+    return (q, k, gamma), bars
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("rate", sorted(RATES))
+@pytest.mark.parametrize("chunk,sub,width", PAIR_FORMS)
+def test_the_kernels_are_the_plain_pair_terms(chunk, sub, width, rate, dtype):
+    """``pair_terms_kernel`` interpreted against ``_pair_terms``: both
+    results to float32's rounding (the same reference rows, the same
+    roundings to ``dtype``), the strict upper triangle exactly zero, and
+    ``dq``, ``dk``, ``dgamma`` against ``jax.vjp`` of the plain form: to
+    float32's rounding for float32 operands, to the rounding of a
+    bfloat16 cotangent for bfloat16 ones (the plain form rounds the far
+    pairs' cotangents once more on the way)."""
+    dtype = jnp.dtype(dtype)
+    operands, bars = pair_operands(RATES[rate], chunk, width, dtype)
+    want, plain_vjp = jax.vjp(
+        lambda *a: linear_attention._pair_terms(*a, sub, dtype), *operands)
+    got, kernel_vjp = jax.vjp(
+        lambda *a: linear_attention.pair_terms_kernel(*a, sub, dtype, True),
+        *operands)
+    for name, a, b in zip(("inside", "a"), got, want):
+        assert a.dtype == jnp.float32 and a.shape == b.shape, name
+        assert bool((jnp.triu(a, 1) == 0).all()), name
+        np.testing.assert_allclose(a, b, rtol=0, err_msg=name,
+                                   atol=2e-6 * float(jnp.abs(b).max()))
+    room = 2e-6 if dtype == jnp.float32 else 2e-2
+    got_bars = kernel_vjp(bars)
+    for name, a, b in zip(("dq", "dk", "dgamma"), got_bars, plain_vjp(bars)):
+        assert a.dtype == b.dtype and bool(jnp.isfinite(a).all()), name
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        scale = float(jnp.abs(b).max())
+        if name == "dgamma":
+            # q dq + k (dk as the left side - dk as the right): the
+            # diagonal's terms cancel there, to the rounding of q dq
+            scale += float(jnp.abs(got_bars[0].astype(jnp.float32)).max())
+        np.testing.assert_allclose(a, b, rtol=0, atol=room * scale,
+                                   err_msg=name)
+
+
+def test_a_grid_step_takes_the_chunks_that_divide_their_number(monkeypatch):
+    """Eight chunks a grid step where eight divide them, else the largest
+    divisor under it (six chunks go one grid step, or two of three under a
+    limit of four, or six of one); the gauge says which as the call is
+    lowered, and no result or cotangent depends on the split."""
+    operands, bars = pair_operands(0.3, 16, 16, jnp.float32)
+    seen = []
+    for limit, step in ((8, 6), (4, 3), (1, 1)):
+        monkeypatch.setattr(linear_attention, "PAIR_CHUNKS_A_STEP", limit)
+        got, vjp = jax.vjp(
+            lambda *a: linear_attention.pair_terms_kernel(
+                *a, 4, jnp.float32, True), *operands)
+        seen.append(got + vjp(bars))
+        assert metrics.LINATTN_PAIR_KERNEL_LAST.labels(sub="4").get() == step
+    for other in seen[1:]:
+        jax.tree.map(np.testing.assert_array_equal, seen[0], other)
+    want = linear_attention._pair_terms(*operands, 4, jnp.float32)
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(
+        a, b, rtol=0, atol=1e-5), seen[0][:2], want)
+
+
+@pytest.mark.parametrize("fault", ["far_pairs_left_float32",
+                                   "cubes_rounded_to_bfloat16"])
+def test_a_moved_rounding_point_is_told(fault, monkeypatch):
+    """The rounding points are the benchmark's records' margin (the worst
+    recorded seed has half of its 2^-8 left): with bfloat16 operands the
+    kernel stands 2e-6 of the largest entry from the plain form
+    (``test_the_kernels_are_the_plain_pair_terms``), and fifty times that
+    and more once the far pairs' factors are left float32 where the
+    plain form rounds them to bfloat16, or a sub-block's cube is rounded
+    to bfloat16 where the plain form keeps it float32."""
+    sub_block = linear_attention._sub_block
+
+    def faulty(*args):
+        if fault == "far_pairs_left_float32":
+            return sub_block(*args[:-1], jnp.float32)
+        q, k, pieces, far = sub_block(*args)
+        return q, k, [(top, cube.astype(jnp.bfloat16).astype(jnp.float32))
+                      for top, cube in pieces], far
+
+    operands, _ = pair_operands(RATES["mild"], 64, 128, jnp.bfloat16)
+    want = linear_attention._pair_terms(*operands, 16, jnp.bfloat16)
+
+    def furthest():  # (a function of its own each time: nothing is cached)
+        got = jax.jit(lambda *a: linear_attention.pair_terms_kernel(
+            *a, 16, jnp.bfloat16, True))(*operands)
+        return [float(jnp.abs(a - b).max() / jnp.abs(b).max())
+                for a, b in zip(got, want)]
+
+    assert max(furthest()) < 2e-6
+    monkeypatch.setattr(linear_attention, "_sub_block", faulty)
+    assert min(furthest()) > 1e-4
 
 
 TEXT_CHUNK = 32  # of the cases that read the lowered text

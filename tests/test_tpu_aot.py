@@ -306,6 +306,114 @@ def test_olmo_hybrids_delta_rule_compiles_at_its_widths(one_chip):
     assert "custom_call_target=\"tpu_custom_call\"" not in text
 
 
+def test_kimis_pair_kernels_compile_at_its_widths(one_chip):
+    """One sequence of 8,192 in 32 heads of 128, chunks of 64 in sub-blocks
+    of 16: Kimi Delta Attention's rule, forward and backward, as the v5e's
+    compiler takes it. The program lowered for the TPU forms the pair terms
+    in two Pallas kernels (``pair_terms_kernel`` and its backward) named
+    ``kda_pair_terms`` (not ``flash_attention``, by which the benchmark finds
+    the attention kernels) under the scope the cell's readers sum, eight
+    chunks a grid step (the gauge, set when the program is lowered). What
+    the plain form wrote to HBM is gone: no array of ``k_right``'s shape
+    (``[..., 4, 64, 128]``, four times ``k``), no ``sub x sub x d`` cube.
+    Arguments + temporaries are 2,358,731,264 B and may not pass that by
+    1% (the plain form's program: 2,687,887,872). The same call lowered
+    for the CPU holds no custom call and runs."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu import metrics, profiler
+    from horovod_tpu.ops import linear_attention
+
+    def loss(q, k, v, g, beta):
+        out = linear_attention.kimi_delta_rule(q, k, v, g, beta, chunk=64,
+                                               sub=16)
+        return out.astype(jnp.float32).sum()
+
+    def shaped(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    grads = jax.jit(jax.grad(loss, argnums=range(5)))
+    wide = shaped(1, 8192, 32, 128)
+    compiled = grads.lower(
+        wide, wide, wide, shaped(1, 8192, 32, 128, dtype=jnp.float32),
+        shaped(1, 8192, 32, dtype=jnp.float32)).compile()
+    assert metrics.LINATTN_PAIR_KERNEL_LAST.labels(sub="16").get() == 8
+    text = compiled.as_text()
+    kernels = kernel_instructions(text)
+    assert len(kernels) == 2, kernels
+    assert all(name.startswith(linear_attention.PAIR_KERNEL_NAME + ".")
+               and "flash_attention" not in name for name, _ in kernels)
+    scopes = profiler.instruction_scopes(text)
+    assert {profiler.phase_of(scopes[name]) for name, _ in kernels} == {
+        "hvd.linattn.scan"}
+    assert not re.search(r"\[[\d,]*4,64,128\]", text)
+    assert not re.search(r"\[[\d,]*16,16,128\]", text)
+    planned = compiled.memory_analysis()
+    assert (planned.argument_size_in_bytes + planned.temp_size_in_bytes
+            <= 1.01 * 2_358_731_264)
+
+    # the CPU's program of the same call: the plain form, and it runs
+    keys = jax.random.split(jax.random.PRNGKey(0), 5)
+    q, k, v = (jax.random.normal(key, (1, 128, 2, 128)) for key in keys[:3])
+    q, k, v = (x.astype(jnp.bfloat16) for x in (
+        q / 128, k / jnp.linalg.norm(k, axis=-1, keepdims=True), v))
+    g = -jax.random.uniform(keys[3], (1, 128, 2, 128))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (1, 128, 2)))
+    lowered = grads.lower(q, k, v, g, beta)
+    assert metrics.LINATTN_PAIR_KERNEL_LAST.labels(sub="16").get() == 0
+    assert "tpu_custom_call" not in lowered.as_text()
+    assert re.search(r"4x64x128x", lowered.as_text())  # k_right is there
+    assert all(bool(jnp.isfinite(x.astype(jnp.float32)).all())
+               for x in lowered.compile()(q, k, v, g, beta))
+
+
+def test_a_recomputed_layer_forms_kimis_pair_terms_again(one_chip):
+    """Two layers of the rule (2,048 tokens in 8 heads of 128) each under
+    the decoders' ``jax.checkpoint`` policy, which keeps what a
+    ``pallas_call`` returned: it sees the pair terms' primitive and not the
+    call the TPU's lowering makes of it, so the two ``[C, C]`` results
+    (134 MB a layer at the cell's shape, in a step 1 GiB under the chip's
+    memory) are kept by nobody. A layer and pass holds one forward or one
+    backward ``kda_pair_terms`` call: forward, recomputed and backward,
+    three a layer, where keeping the results would leave two."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models import parts
+    from horovod_tpu.ops import linear_attention
+
+    def layer(x, w):
+        wide = jnp.dot(x, w).reshape(x.shape[:2] + (4, 8, 128))
+        q, k, v, g = (wide[:, :, n] for n in range(4))
+        beta = jax.nn.sigmoid(g[..., 0].astype(jnp.float32))
+        out = linear_attention.kimi_delta_rule(
+            q, k, v, -jax.nn.softplus(g.astype(jnp.float32)), beta,
+            chunk=64, sub=16)
+        return x + out.reshape(x.shape[:2] + (-1,))[..., :x.shape[-1]]
+
+    def loss(x, first, second):
+        recomputed = jax.checkpoint(
+            layer, policy=parts.save_kernels_and_projections)
+        out = recomputed(recomputed(x, first), second)
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    def shaped(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=one_chip)
+
+    text = jax.jit(jax.grad(loss, argnums=(1, 2))).lower(
+        shaped(1, 2048, 1024), shaped(1024, 4096),
+        shaped(1024, 4096)).compile().as_text()
+    kernels = kernel_instructions(text)
+    assert all(name.startswith(linear_attention.PAIR_KERNEL_NAME + ".")
+               for name, _ in kernels), kernels
+    passes = [("recomputed" if "rematted_computation" in op_name else
+               "backward" if "transpose(" in op_name else "forward")
+              for _, op_name in kernels]
+    assert sorted(passes) == sorted(
+        ["forward", "recomputed", "backward"] * 2), kernels
+
+
 @pytest.mark.parametrize("rows, seq, groups", [(24, 512, (2, 1)),
                                                (96, 128, (16, 8))])
 def test_a_bert_layer_hands_the_kernels_what_its_projections_wrote(

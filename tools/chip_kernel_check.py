@@ -20,8 +20,12 @@ multi-tile kernels fed tokens-major against themselves fed head-major, bit
 for bit (``check_tiles_as_they_lie``); and latent attention's call, keys of
 192 lanes and values of 128, checked and timed beside 128 / 128 and 256 /
 128 at the Kimi Linear cell's shape (``check_two_widths``; ``python
-tools/chip_kernel_check.py two_widths`` runs that alone). Compiled, never
-``interpret=True``: off a TPU this exits non-zero.
+tools/chip_kernel_check.py two_widths`` runs that alone); and Kimi Delta
+Attention's pair terms, the Pallas kernel and its backward kernel against
+the plain form on the chip, checked and timed at the cell's shape
+(``check_pair_terms``; ``python tools/chip_kernel_check.py pair_terms``
+runs that alone). Compiled, never ``interpret=True``: off a TPU this exits
+non-zero.
 
 The tolerance is the one ``tests/test_sequence_parallel.py`` uses for bf16
 inputs (rtol = atol = 2e-2) with atol multiplied by the reference's
@@ -268,6 +272,78 @@ def check_two_widths(heads=32, seq=8192, calls=20) -> None:
               f"and backward call (with the sum and its gradient)")
 
 
+def check_pair_terms(heads=32, chunks=128, size=64, sub=16, width=128,
+                     calls=20) -> None:
+    """``ops.linear_attention.pair_terms_kernel`` against ``_pair_terms``,
+    both compiled on the chip, at the Kimi Linear cell's ``[1, 32, 128, 64,
+    128]`` in bfloat16 with a float32 ``gamma`` that falls by 0.05, 1.6 and
+    20 a token: the two results (float32's rounding: the same reference
+    rows and the same roundings to bfloat16; the strict upper triangle
+    exactly zero) and ``dq``, ``dk``, ``dgamma`` under random cotangents
+    (a bfloat16 cotangent's rounding); then each form timed, forward alone
+    and forward with backward, ``calls`` dispatched back to back with only
+    the last result kept."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from horovod_tpu.ops import linear_attention
+
+    dtype, shape = jnp.bfloat16, (1, heads, chunks, size, width)
+    forms = {
+        "plain": lambda *a: linear_attention._pair_terms(*a, sub, dtype),
+        "kernel": lambda *a: linear_attention.pair_terms_kernel(
+            *a, sub, dtype)}
+
+    def both(form):
+        def run(q, k, gamma, bars):
+            out, vjp = jax.vjp(form, q, k, gamma)
+            return out + vjp(bars)
+        return jax.jit(run)
+
+    runs = {name: {"forward": jax.jit(form),
+                   "forward and backward": both(form)}
+            for name, form in forms.items()}
+    keys = jax.random.split(jax.random.PRNGKey(3), 5)
+    q, k = (jax.random.normal(key, shape).astype(dtype) for key in keys[:2])
+    bars = tuple(jax.random.normal(key, shape[:-1] + (size,))
+                 for key in keys[3:])
+    names = ("inside", "a", "dq", "dk", "dgamma")
+    for rate in (0.05, 1.6, 20.0):
+        gamma = jnp.cumsum(-rate * jax.random.uniform(
+            keys[2], shape, minval=0.5, maxval=1.0), -2)
+        want, got = (
+            [np.asarray(x, np.float32)
+             for x in runs[name]["forward and backward"](q, k, gamma, bars)]
+            for name in ("plain", "kernel"))
+        print(f" pair terms at {rate} a token:")
+        for name, a, b in zip(names, got, want):
+            assert np.isfinite(a).all(), name
+            scale = float(np.abs(b).max())
+            print(f"  {name}: max |kernel - plain| "
+                  f"{float(np.abs(a - b).max()):.3e} (plain max {scale:.3g})")
+            if name == "dgamma":  # its diagonal terms cancel, to q dq's rounding
+                scale += float(np.abs(got[2]).max())
+            room = 1e-5 if name in ("inside", "a") else BF16_TOL
+            np.testing.assert_allclose(a, b, rtol=0, atol=room * scale,
+                                       err_msg=name)
+            if name in ("inside", "a"):
+                assert (np.triu(a, 1) == 0).all(), name
+    for name, by_what in runs.items():
+        for what, fn in by_what.items():
+            args = (q, k, gamma) + ((bars,) if what != "forward" else ())
+            jax.block_until_ready(fn(*args))
+            t0 = time.perf_counter()
+            for _ in range(calls):
+                out = fn(*args)
+            jax.block_until_ready(out)
+            print(f"  {name}, {what}: "
+                  f"{(time.perf_counter() - t0) / calls * 1e3:.3f} ms a call "
+                  f"at [1, {heads}, {chunks}, {size}, {width}]")
+
+
 def check_tokens_major() -> None:
     """BERT's two shapes as its projections write them, an odd group of
     causal pairs, and a head a block."""
@@ -358,8 +434,10 @@ def main() -> None:
     d = jax.devices()[0]
     print(f"device: platform={d.platform} device_kind={d.device_kind!r} "
           f"count={len(jax.devices())}")
-    if sys.argv[1:] == ["two_widths"]:  # that check alone: ~2 minutes
-        check_two_widths()
+    alone = {"two_widths": check_two_widths,  # ~2 minutes
+             "pair_terms": check_pair_terms}
+    if len(sys.argv) == 2 and sys.argv[1] in alone:
+        alone[sys.argv[1]]()
         print("kernels ok")
         return
     check_flash(4, 16, 512, 64, causal=False)
@@ -374,6 +452,7 @@ def main() -> None:
     check_sdar_rows()
     check_tiles_as_they_lie()
     check_two_widths()
+    check_pair_terms()
     print("kernels ok")
 
 
