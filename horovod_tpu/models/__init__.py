@@ -49,3 +49,9 @@ from .kimi_linear import (  # noqa: F401
     KimiLinear,
     KimiLinearConfig,
 )
+from .nemotron_h import (  # noqa: F401
+    NEMOTRON_3_NANO_30B_A3B,
+    NEMOTRON_H_TINY,
+    NemotronH,
+    NemotronHConfig,
+)

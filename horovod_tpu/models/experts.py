@@ -1,8 +1,8 @@
 """The one experts module of the mixtures of experts here (``Olmoe``,
-``SmallThinker``, ``Sdar``), owned by none of them: a model's window of the
-experts in ``parallel/moe.py``'s capacity slots, the auxiliary losses of
-a routing group, and what reads or cuts a model by its experts
-(:func:`routing_stats`, :func:`take_expert_window`).
+``SmallThinker``, ``Sdar``, ``KimiLinear``, ``NemotronH``), owned by none of
+them: a model's window of the experts in ``parallel/moe.py``'s capacity
+slots, the auxiliary losses of a routing group, and what reads or cuts a
+model by its experts (:func:`routing_stats`, :func:`take_expert_window`).
 """
 
 from __future__ import annotations
@@ -51,7 +51,10 @@ class SparseExperts(nn.Module):
     expert)`` is a routing group's auxiliary losses, a tuple of scalars;
     their means over the groups are returned after the output. ``scores``
     and ``gate_scale`` are ``route_to_capacity``'s; ``width`` is an
-    expert's where the config's ``intermediate_size`` is a dense layer's."""
+    expert's where the config's ``intermediate_size`` is a dense layer's.
+    An expert is three matrices, ``down(activation(gate x) * up x)``, or
+    with ``gated=False`` two, ``down(activation(up x))``, and then the tree
+    has no ``experts_gate``."""
 
     config: Any
     activation: Callable = jax.nn.silu
@@ -60,6 +63,7 @@ class SparseExperts(nn.Module):
     scores: str = "softmax"
     gate_scale: float = 1.0
     width: int | None = None
+    gated: bool = True
 
     @nn.compact
     def __call__(self, x, logits=None):
@@ -72,12 +76,15 @@ class SparseExperts(nn.Module):
             router = self.param("router", nn.initializers.lecun_normal(),
                                 (hidden, cfg.num_experts), jnp.float32)
         stacked = nn.initializers.lecun_normal(batch_axis=(0,))
-        w_gate = self.param("experts_gate", stacked, (here, hidden, width),
-                            jnp.float32)
-        w_up = self.param("experts_up", stacked, (here, hidden, width),
-                          jnp.float32)
-        w_down = self.param("experts_down", stacked, (here, width, hidden),
-                            jnp.float32)
+        shapes = {"experts_gate": (here, hidden, width),
+                  "experts_up": (here, hidden, width),
+                  "experts_down": (here, width, hidden)}
+        if not self.gated:
+            del shapes["experts_gate"]
+        weights = [self.param(name, stacked, shape, jnp.float32)
+                   for name, shape in shapes.items()]
+        expert_ffn = moe.gated_expert_ffn if self.gated \
+            else moe.plain_expert_ffn
         capacity = cfg.capacity(x.shape[1])
         _record_slots(here, capacity, cfg.top_k)
 
@@ -94,9 +101,8 @@ class SparseExperts(nn.Module):
                 top_k=cfg.top_k, first_expert=cfg.first_expert,
                 experts_here=here, gates_over_picks=self.gates_over_picks,
                 scores=self.scores, gate_scale=self.gate_scale)
-            back = moe.gated_expert_ffn(
-                w_gate.astype(cfg.dtype), w_up.astype(cfg.dtype),
-                w_down.astype(cfg.dtype), send[..., :hidden],
+            back = expert_ffn(
+                *(w.astype(cfg.dtype) for w in weights), send[..., :hidden],
                 activation=self.activation)
             out = moe.combine_top_k(back, expert, pos, keep, gate,
                                     cfg.first_expert)

@@ -58,14 +58,12 @@ import jax.numpy as jnp
 
 from ..attribution import (SCOPE_BLOCK_ATTN_PROJ, SCOPE_BLOCK_EMBED,
                            SCOPE_BLOCK_FFN, SCOPE_BLOCK_HEAD,
-                           SCOPE_BLOCK_NORM, SCOPE_SSM_CONV, SCOPE_SSM_GATE)
-from ..ops.linear_attention import short_conv
-from ..ops.ssd import ssd_scan
+                           SCOPE_BLOCK_NORM)
 from ..profiler import annotate_collective
 from .loss import token_cross_entropy
-from .parts import (RMSNorm, decay_rate, dense_window_attention,
-                    grouped_flash_attention, projection, recomputed,
-                    step_bias)
+from .mamba2 import mamba2_mixer
+from .parts import (RMSNorm, dense_window_attention, grouped_flash_attention,
+                    projection, recomputed)
 
 flash_attention_fn = grouped_flash_attention  # benchmark/configs' name
 
@@ -152,46 +150,18 @@ GRANITE_TINY = GraniteConfig(  # test-sized: three Mamba layers, one attention
 
 
 class Mamba2Mixer(nn.Module):
+    """``models/mamba2.py``'s mixer at this config's sizes: one group's
+    ``B`` and ``C`` in the published model, the gated norm over all the
+    channels."""
     config: GraniteConfig
 
     @nn.compact
     def __call__(self, x):
         cfg = self.config
-        heads, groups, state = (cfg.mamba_n_heads, cfg.mamba_n_groups,
-                                cfg.mamba_d_state)
-        inner, f32 = cfg.mamba_inner, jnp.float32
-        mixed = inner + 2 * groups * state  # x, B and C pass the convolution
-        z, xbc, dt = jnp.split(
-            projection(cfg, inner + mixed + heads, "in_proj")(x),
-            [inner, inner + mixed], axis=-1)
-        # torch's Conv1d default: weights and bias uniform within
-        # 1 / sqrt(taps)
-        conv_init = nn.initializers.variance_scaling(
-            1 / 3, "fan_in", "uniform", in_axis=-1, out_axis=-2)
-        conv = self.param("conv", conv_init, (mixed, cfg.mamba_d_conv), f32)
-        bound = cfg.mamba_d_conv ** -0.5
-        conv_bias = self.param(
-            "conv_bias", lambda key, shape, dtype: jax.random.uniform(
-                key, shape, dtype, -bound, bound), (mixed,), f32)
-        a_log = self.param("A_log", decay_rate, (heads,), f32)
-        dt_bias = self.param("dt_bias", step_bias, (heads,), f32)
-        skip = self.param("D", nn.initializers.ones, (heads,), f32)
-        with annotate_collective(SCOPE_SSM_CONV):
-            xbc = jax.nn.silu(short_conv(xbc, conv, conv_bias))
-            inputs, b, c = jnp.split(
-                xbc, [inner, inner + groups * state], axis=-1)
-            steps = jax.nn.softplus(dt.astype(f32) + dt_bias)
-        out = ssd_scan(
-            inputs.reshape(x.shape[:2] + (heads, cfg.mamba_d_head)), steps,
-            -jnp.exp(a_log), b.reshape(x.shape[:2] + (groups, state)),
-            c.reshape(x.shape[:2] + (groups, state)), skip,
-            chunk=cfg.mamba_chunk_size)
-        with annotate_collective(SCOPE_SSM_GATE):
-            out = out.reshape(z.shape).astype(f32) * jax.nn.silu(
-                z.astype(f32))
-            out = RMSNorm(cfg.rms_norm_eps, name="norm")(out).astype(
-                cfg.dtype)
-        return projection(cfg, cfg.hidden_size, "out_proj")(out)
+        return mamba2_mixer(
+            self, cfg, x, heads=cfg.mamba_n_heads, head_dim=cfg.mamba_d_head,
+            state=cfg.mamba_d_state, groups=cfg.mamba_n_groups,
+            taps=cfg.mamba_d_conv, chunk=cfg.mamba_chunk_size)
 
 
 class GroupedAttention(nn.Module):

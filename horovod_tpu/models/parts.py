@@ -1,22 +1,23 @@
 """What the decoders share beside their mixers, owned by none of them:
-the norm, the l2-norm, the projection, the gated feed-forward, RoPE in its
-two forms, the dense attention fallbacks, the two adapters onto the flash
-kernels, the state-space initialisers, the recomputation policy and the
-untied head.
+the norm (over all channels or in groups), the l2-norm, the projection, the
+gated and the plain feed-forward, RoPE in its two forms, the dense attention
+fallbacks, the two adapters onto the flash kernels, the state-space
+initialisers, the recomputation policy and the untied head.
 
 ``models/olmoe.py``, ``olmo_hybrid.py``, ``smallthinker.py``, ``sdar.py``,
-``granite.py`` and ``kimi_linear.py`` import from here, from
-``models/experts.py`` and ``models/loss.py``, never from one another
-(``tests/test_decoder_imports.py``). What builds parameters here is a
-function called inside the model's own ``@nn.compact`` body, not a module
-of its own, so every leaf keeps its name and its place in the tree.
+``granite.py``, ``kimi_linear.py`` and ``nemotron_h.py`` import from here,
+from ``models/mamba2.py``, ``models/experts.py`` and ``models/loss.py``,
+never from one another (``tests/test_decoder_imports.py``). What builds
+parameters here is a function called inside the model's own ``@nn.compact``
+body, not a module of its own, so every leaf keeps its name and its place
+in the tree.
 """
 
 from __future__ import annotations
 
 import functools
 import math
-from typing import Any
+from typing import Any, Callable
 
 import flax.linen as nn
 import jax
@@ -28,15 +29,26 @@ from ..ops.heads import map_heads
 
 
 class RMSNorm(nn.Module):
+    """``x`` in float32 over its mean square, times a learned scale a
+    channel. With ``groups`` the channels are cut into that many equal
+    runs, each over its own mean square (a Mamba-2 mixer's gated norm where
+    ``B`` and ``C`` come in groups); the scale stays one leaf of all the
+    channels."""
     eps: float
+    groups: int = 1
 
     @nn.compact
     def __call__(self, x):
         x = x.astype(jnp.float32)
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
                            jnp.float32)
-        return x * jax.lax.rsqrt(
-            jnp.mean(jnp.square(x), -1, keepdims=True) + self.eps) * scale
+        if self.groups == 1:
+            return x * jax.lax.rsqrt(
+                jnp.mean(jnp.square(x), -1, keepdims=True) + self.eps) * scale
+        runs = x.reshape(x.shape[:-1] + (self.groups, -1))
+        runs = runs * jax.lax.rsqrt(
+            jnp.mean(jnp.square(runs), -1, keepdims=True) + self.eps)
+        return runs.reshape(x.shape) * scale
 
 
 def l2norm(x, eps: float = 1e-6):
@@ -65,6 +77,25 @@ class GatedMLP(nn.Module):
         hidden = jax.nn.silu(projection(cfg, self.width, "gate")(x)) \
             * projection(cfg, self.width, "up")(x)
         return projection(cfg, cfg.hidden_size, "down")(hidden)
+
+
+def relu2(x):
+    """``relu(x) ** 2``, Nemotron-H's ``relu2``; zero stays zero."""
+    return jnp.square(jax.nn.relu(x))
+
+
+class PlainMLP(nn.Module):
+    """The ungated feed-forward of ``width``: ``down(activation(up(x)))``,
+    two matrices. A shared expert's where the experts have no gate."""
+    config: Any
+    width: int
+    activation: Callable = relu2
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        return projection(cfg, cfg.hidden_size, "down")(
+            self.activation(projection(cfg, self.width, "up")(x)))
 
 
 def untied_head(model, x):

@@ -278,6 +278,21 @@ def gated_expert_ffn(w_gate, w_up, w_down, x, activation=jax.nn.silu):
         return jnp.einsum("ech,ehd->ecd", hidden, w_down)
 
 
+def plain_expert_ffn(w_up, w_down, x, activation):
+    """``experts`` ungated feed-forwards at once, two batched matmuls:
+    ``x [experts, capacity, D]``, ``w_up [experts, D, H]``, ``w_down
+    [experts, H, D]`` → ``w_down · activation(w_up · x)`` (Nemotron-H's
+    experts: ``activation`` is ``relu(·)²``). No bias, and the activation
+    must keep zero at zero, as every one of :func:`gated_expert_ffn` does,
+    so that an empty slot (zeros) stays zero and needs no mask."""
+    from ..attribution import SCOPE_MOE_EXPERTS
+    from ..profiler import annotate_collective
+
+    with annotate_collective(SCOPE_MOE_EXPERTS):
+        hidden = activation(jnp.einsum("ecd,edh->ech", x, w_up))
+        return jnp.einsum("ech,ehd->ecd", hidden, w_down)
+
+
 def expert_capacity(capacity_factor, tokens, top_k, num_experts):
     """Slots an expert gets for one routing group of ``tokens``:
     ``ceil(capacity_factor · tokens · top_k / num_experts)``."""

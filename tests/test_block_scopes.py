@@ -91,6 +91,10 @@ MODELS = {
                     COMMON | {B + "ffn"}),
     "smallthinker": (partial(decoder_loss, "smallthinker", "SmallThinker",
                              "SMALLTHINKER_TINY", 32, block=16), COMMON),
+    # a layer is a mixer alone: block.ffn is around an E layer's experts
+    "nemotron_h": (partial(decoder_loss, "nemotron_h", "NemotronH",
+                           "NEMOTRON_H_TINY", 32, block=16),
+                   COMMON | {B + "ffn"}),
 }
 TEXTS: dict = {}
 DEFINED = re.compile(r"^\s*(?:ROOT |ENTRY )?%([\w.-]+) (?:=|\()", re.M)

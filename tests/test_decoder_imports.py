@@ -1,5 +1,6 @@
-"""The five decoders share their parts through ``models/parts.py``,
-``models/experts.py`` and ``models/loss.py`` and never through one another
+"""The seven decoders share their parts through ``models/parts.py``,
+``models/mamba2.py``, ``models/experts.py`` and ``models/loss.py`` and never
+through one another
 (ROADMAP D13); the names the benchmark calls are where ``PERF.md`` §3 says;
 and the helpers of ``models/parts.py`` that build parameters left every
 leaf of the toy models where the parent of PR 42 had it."""
@@ -15,7 +16,8 @@ import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MODELS = os.path.join(ROOT, "horovod_tpu", "models")
-DECODERS = ("olmoe", "olmo_hybrid", "smallthinker", "sdar", "granite")
+DECODERS = ("olmoe", "olmo_hybrid", "smallthinker", "sdar", "granite",
+            "kimi_linear", "nemotron_h")
 
 
 def imported_modules(path):
@@ -42,7 +44,8 @@ def names_a_decoder(module: str) -> bool:
         module.startswith(".") or module.startswith("horovod_tpu.models"))
 
 
-@pytest.mark.parametrize("name", DECODERS + ("parts", "experts", "loss"))
+@pytest.mark.parametrize("name", DECODERS + ("parts", "mamba2", "experts",
+                                             "loss"))
 def test_no_shared_module_and_no_decoder_imports_a_decoder(name):
     imports = imported_modules(os.path.join(MODELS, name + ".py"))
     assert imports, name
@@ -158,6 +161,21 @@ GRANITE_MAMBA = {
         "A_log": (8,), "dt_bias": (8,), "D": (8,), "norm/scale": (64,),
         "out_proj/kernel": (64, 32)}.items()}, **GRANITE_MLP}
 GRANITE_ATTENTION = {**square_attention(32, 16), **GRANITE_MLP}
+# models/nemotron_h.py is PR 47's: its tree as that PR made it, the Mamba-2
+# leaves under the names Granite's have (one body: models/mamba2.py)
+NEMOTRON_MAMBA = {
+    **{"mamba/" + path: shape for path, shape in {
+        "in_proj/kernel": (48, 200), "conv": (128, 4), "conv_bias": (128,),
+        "A_log": (8,), "dt_bias": (8,), "D": (8,), "norm/scale": (64,),
+        "out_proj/kernel": (64, 48)}.items()}, "ln/scale": (48,)}
+NEMOTRON_EXPERTS = {
+    "moe/router": (48, 8), "moe/experts_up": (8, 48, 24),
+    "moe/experts_down": (8, 24, 48), "shared/up/kernel": (48, 40),
+    "shared/down/kernel": (40, 48), "ln/scale": (48,)}
+NEMOTRON_ATTENTION = {
+    "attention/query/kernel": (48, 64), "attention/key/kernel": (48, 16),
+    "attention/value/kernel": (48, 16), "attention/out/kernel": (64, 48),
+    "ln/scale": (48,)}
 
 TREES = {
     "olmoe": ("Olmoe", "OLMOE_TINY", 1, {
@@ -173,6 +191,10 @@ TREES = {
         "embedding": (256, 32), "ln_out/scale": (32,),
         **layers(GRANITE_MAMBA, GRANITE_MAMBA, GRANITE_ATTENTION,
                  GRANITE_MAMBA)}),
+    "nemotron_h": ("NemotronH", "NEMOTRON_H_TINY", 1, {
+        **top(48, 256),
+        **layers(NEMOTRON_MAMBA, NEMOTRON_EXPERTS, NEMOTRON_MAMBA,
+                 NEMOTRON_ATTENTION, NEMOTRON_EXPERTS)}),
 }
 
 

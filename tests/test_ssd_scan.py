@@ -168,3 +168,82 @@ def test_the_scope_is_on_forward_and_backward_and_the_gauge_is_set():
     assert any("transpose(" not in s for s in under)
     assert metrics.SSM_CHUNKS_LAST.labels(
         chunk="32", heads=str(H)).get() == S // 32
+
+
+def nemotron_inputs(seed: int = 3):
+    """Nemotron-H's grouping at a small width: 16 heads on 8 groups of
+    ``B`` and ``C`` (two heads a group), 256 tokens, so that chunk 128 is
+    two chunks and a state crosses."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    heads, groups, seq = 16, 8, 256
+    x = jax.random.normal(keys[0], (1, seq, heads, 4))
+    dt = 0.05 * jax.random.uniform(keys[1], (1, seq, heads), minval=0.1,
+                                   maxval=1.0)
+    a = -jax.random.uniform(keys[2], (heads,), minval=1.0, maxval=4.0)
+    b = jax.random.normal(keys[3], (1, seq, groups, N))
+    c = jax.random.normal(keys[4], (1, seq, groups, N))
+    d = jax.random.normal(keys[5], (heads,))
+    return x, dt, a, b, c, d
+
+
+@pytest.mark.parametrize("chunk", [128, 64])
+def test_eight_groups_at_chunk_128_are_the_recurrence(chunk):
+    args = nemotron_inputs()
+    want = recurrence(*args)
+    got = jax.jit(ssd.ssd_scan, static_argnames="chunk")(*args, chunk=chunk)
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=2e-5 * float(jnp.abs(want).max()))
+
+
+def test_eight_groups_gradients_are_the_recurrences():
+    args = nemotron_inputs(seed=4)
+    weight = jax.random.normal(jax.random.PRNGKey(9), args[0].shape)
+
+    def scalar(scan):
+        return lambda *a: jnp.sum(scan(*a) * weight)
+
+    want = jax.jit(jax.grad(scalar(recurrence), argnums=range(6)))(*args)
+    got = jax.jit(jax.grad(scalar(
+        lambda *a: ssd.ssd_scan(*a, chunk=128)), argnums=range(6)))(*args)
+    for name, g, w in zip("x dt a b c d".split(), got, want):
+        np.testing.assert_allclose(
+            g, w, rtol=0, atol=1e-4 * float(jnp.abs(w).max()), err_msg=name)
+
+
+def test_a_head_reads_its_own_group():
+    """Head ``h`` reads group ``h // (H / G)``: with every group's ``B``
+    and ``C`` set to group 0's the output is another one."""
+    x, dt, a, b, c, d = nemotron_inputs()
+    own = ssd.ssd_scan(x, dt, a, b, c, d, chunk=128)
+    first = ssd.ssd_scan(x, dt, a, jnp.repeat(b[:, :, :1], 8, 2),
+                         jnp.repeat(c[:, :, :1], 8, 2), d, chunk=128)
+    np.testing.assert_allclose(own[:, :, :2], first[:, :, :2], rtol=0,
+                               atol=1e-5)  # group 0's two heads
+    assert float(jnp.abs(own[:, :, 2:] - first[:, :, 2:]).max()) > 0.1
+
+
+@pytest.mark.parametrize("groups", [1, 2, 8])
+def test_the_grouped_norm_is_a_norm_a_group(groups):
+    """``parts.RMSNorm(eps, groups)`` against a loop over the groups, each
+    run of channels over its own mean square; one leaf of all the
+    channels; one group is the plain norm."""
+    from horovod_tpu.models.parts import RMSNorm
+
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 5, 64)) * jnp.arange(
+        1, 65)
+    scale = jax.random.normal(jax.random.PRNGKey(1), (64,))
+    params = {"params": {"scale": scale}}
+    got = RMSNorm(1e-5, groups).apply(params, x)
+    width = 64 // groups
+    want = jnp.concatenate([
+        run * jax.lax.rsqrt(jnp.mean(jnp.square(run), -1, keepdims=True)
+                            + 1e-5)
+        for run in (x[..., g * width:(g + 1) * width]
+                    for g in range(groups))], -1) * scale
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    shapes = jax.eval_shape(RMSNorm(1e-5, groups).init,
+                            jax.random.PRNGKey(0), x)["params"]
+    assert shapes["scale"].shape == (64,)
+    if groups > 1:
+        plain = RMSNorm(1e-5).apply(params, x)
+        assert float(jnp.abs(got - plain).max()) > 0.1
