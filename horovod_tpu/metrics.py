@@ -929,6 +929,14 @@ SSM_CHUNKS_LAST = gauge(
     "ssd_scan) cuts its tokens into (sequence length / chunk), for "
     "`heads` heads: set at trace time, as hvd_linattn_chunks_last is.",
     ("chunk", "heads"))
+SSM_SCAN_KERNEL_LAST = gauge(
+    "hvd_ssm_scan_kernel_last",
+    "Heads a grid step of the Pallas kernels that run ssd_scan's chunk "
+    "form in the LAST lowered program takes, 0 where that program holds "
+    "the plain form (lowered for any platform but a TPU, or shapes that "
+    "fill no tile): set as the program is lowered, since the lowering "
+    "platform chooses.",
+    ("chunk",))
 ATTN_TILES_LAST = gauge(
     "hvd_attn_tiles_last",
     "(q, k) tile pairs a (batch x head) slice of the LAST traced multi-tile "

@@ -399,18 +399,18 @@ def _backward_plain(q, k, gamma, *bars, sub, dtype, **_):
                    q, k, gamma)[1](bars)
 
 
-def _where_lowered(name, results, by_kernel, plain):
-    """The primitive ``name`` over ``[chunks, C, width]`` operands whose
-    lowering for a TPU is ``by_kernel`` and for any other platform
-    ``plain`` (``by_kernel`` interpreted where the tests say ``interpret``):
+def _where_lowered(name, results, by_kernel, plain, record):
+    """The primitive ``name`` whose lowering for a TPU is ``by_kernel`` and
+    for any other platform ``plain`` (``by_kernel`` interpreted where the
+    tests say ``interpret``), each called with the primitive's parameters:
     the lowering platform is what the code can observe, and a trace does
     not know it (``benchmark/aot.py`` lowers for a v5e from a CPU;
     ``jax.default_backend()`` would say ``cpu`` there). Only the chosen form
-    is ever traced, and it says which it is (the gauge) as it is lowered.
-    ``results(*avals)`` are the results' abstract values."""
+    is ever traced, and ``record(kernel, **how)`` says which (a gauge) as it
+    is lowered. ``results(*avals, **how)`` are the results' abstract values."""
     primitive = Primitive(name)
     primitive.multiple_results = True
-    primitive.def_abstract_eval(lambda *avals, **_: results(*avals))
+    primitive.def_abstract_eval(lambda *avals, **how: results(*avals, **how))
 
     @functools.cache
     def alone(**how):  # called outside any trace
@@ -419,11 +419,11 @@ def _where_lowered(name, results, by_kernel, plain):
     primitive.def_impl(lambda *xs, **how: alone(**how)(*xs))
 
     def lowering(on_tpu):
-        def form(*xs, step, sub, dtype, interpret):
+        def form(*xs, interpret, **how):
             kernel = on_tpu or interpret
-            _record_pair_path(step if kernel else 0, sub)
+            record(kernel, **how)
             return (by_kernel if kernel else plain)(
-                *xs, step=step, sub=sub, dtype=dtype, interpret=interpret)
+                *xs, interpret=interpret, **how)
 
         return mlir.lower_fun(form, multiple_results=True)
 
@@ -433,13 +433,13 @@ def _where_lowered(name, results, by_kernel, plain):
 
 
 _pair_forward_p = _where_lowered(
-    "hvd_kda_pair_terms",
-    lambda q, k, gamma: [gamma.update(shape=k.shape[:-1] + k.shape[-2:-1])] * 2,
-    _forward_by_kernel, _forward_plain)
+    "hvd_kda_pair_terms", lambda q, k, gamma, **_: [
+        gamma.update(shape=k.shape[:-1] + k.shape[-2:-1])] * 2,
+    _forward_by_kernel, _forward_plain, lambda *a, **k: _pair_form(*a, **k))
 _pair_backward_p = _where_lowered(
-    "hvd_kda_pair_terms_backward",
-    lambda q, k, gamma, inside_bar, a_bar: [q, k, gamma],
-    _backward_by_kernel, _backward_plain)
+    "hvd_kda_pair_terms_backward", lambda *kept, **_: list(kept[:3]),
+    _backward_by_kernel, _backward_plain,
+    lambda *a, **k: _pair_form(*a, **k))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -672,3 +672,10 @@ def _record_pair_path(chunks_a_step: int, sub: int) -> None:
     from .. import metrics
 
     metrics.LINATTN_PAIR_KERNEL_LAST.set(chunks_a_step, sub=str(sub))
+
+
+def _pair_form(kernel: bool, step: int, sub: int, **_) -> None:
+    """:func:`_where_lowered`'s ``record`` for the pair terms (down here,
+    and called late: no line above the rule's moves, so the positions in
+    the kernels' bodies stay, ``tools/lowered_sha.py``)."""
+    _record_pair_path(step if kernel else 0, sub)

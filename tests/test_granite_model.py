@@ -3,7 +3,9 @@
 attention with a mask): on seeded weights at a toy size the two are one
 function, logits, loss and every leaf's gradient, with the grouped
 multi-tile flash kernels (interpreted, two tiles) or dense attention, with
-and without recomputation. Each of the four multipliers matters, on both
+and without recomputation, and with the scan's Pallas kernels (interpreted,
+at widths that fill their tiles), where a hand-made fault still leaves the
+reference. Each of the four multipliers matters, on both
 sides alike; the head is the embedding; and the model is the published one:
 its sizes, its 772,160,448 parameters, its tree, its scopes in a factory
 step."""
@@ -18,7 +20,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu.models import granite
+from horovod_tpu.models import granite, mamba2
+from horovod_tpu.ops import ssd
 
 BENCHMARK_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
@@ -91,6 +94,50 @@ def test_float32_product_is_the_reference(bench, case):
         np.testing.assert_allclose(
             got, want, rtol=0, atol=1e-4 * scale + 5e-6,
             err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("fault", ["none", "steps_halved", "b_for_c"])
+def test_through_the_scans_kernels_the_product_is_the_reference(
+        bench, fault, monkeypatch):
+    """Widths that fill the scan kernels' tiles (``ops.ssd._heads_a_step``:
+    sixteen heads of 16 on one group, two head blocks a grid step's; a
+    state and a chunk of 128; 256 tokens, so a state crosses) with
+    ``ssd_scan_kernel`` in the plain form's place, interpreted, under the
+    layers' recomputation: unbroken the product is the reference, loss and
+    every leaf's gradient; with the scan's steps halved or ``B`` read for
+    ``C`` some leaf's gradient is a hundredth and more of itself away."""
+    kernel, real_scan, calls = ssd.ssd_scan_kernel, mamba2.ssd_scan, []
+
+    def interpreted(*args):
+        calls.append(args[0].shape)
+        return kernel(*args, True)
+
+    monkeypatch.setattr(ssd, "ssd_scan_kernel", interpreted)
+    if fault == "steps_halved":
+        monkeypatch.setattr(
+            mamba2, "ssd_scan", lambda x, dt, *rest, chunk: real_scan(
+                x, 0.5 * dt, *rest, chunk=chunk))
+    elif fault == "b_for_c":
+        monkeypatch.setattr(
+            mamba2, "ssd_scan", lambda x, dt, a, b, c, d, chunk: real_scan(
+                x, dt, a, b, b, d, chunk=chunk))
+    config = toy(bench, mamba_n_heads=16, mamba_d_head=16, mamba_d_state=128,
+                 mamba_chunk_size=128, mamba_expand=4,
+                 layer_types=["mamba", "attention"], num_hidden_layers=2,
+                 training={"attention": "dense"})
+    (loss, grads), (ref_loss, ref_grads), _ = both_sides(
+        bench, config, seq=256)
+    assert (2, 256, 16, 16) in calls
+    off = {jax.tree_util.keystr(path): float(
+        np.abs(got - want).max() / np.abs(np.asarray(want)).max())
+        for (path, got), want in zip(
+            jax.tree_util.tree_leaves_with_path(grads),
+            jax.tree.leaves(ref_grads))}
+    if fault == "none":
+        assert float(loss) == pytest.approx(float(ref_loss), rel=1e-5)
+        assert max(off.values()) < 2e-4, off
+    else:  # the loss hardly tells at a toy's multipliers: the mixer's leaves
+        assert max(off.values()) > 1e-2, off
 
 
 def test_the_logits_are_the_references(bench):

@@ -7,7 +7,9 @@ loss and every leaf's gradient, with the grouped flash kernels (interpreted,
 several tiles) or dense attention, with and without recomputation, in
 another window of the experts, with another pattern. **Nine faults made by
 hand in the product each leave the reference** (what the chip's limits see of
-them at seed weights is in the configuration's file). And the model is the
+them at seed weights is in the configuration's file), the scan's two also
+where its Pallas kernels run (interpreted, at widths that fill their tiles:
+they stay on the reference unbroken). And the model is the
 published one: its pattern, its 666,962,944 parameters at the cell's cut, its
 scopes in a lowered step."""
 
@@ -22,6 +24,7 @@ import numpy as np
 import pytest
 
 from horovod_tpu.models import experts, mamba2, nemotron_h
+from horovod_tpu.ops import ssd
 
 BENCHMARK_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
@@ -197,6 +200,50 @@ def test_each_hand_made_fault_leaves_the_reference(bench, fault,
     config = toy(bench, training={"attention": "dense"})
     (loss, _), (ref_loss, _) = both_sides(bench, config, weights=weights)
     assert abs(float(loss) - float(ref_loss)) > 3e-5 * float(ref_loss)
+
+
+# Widths that fill the scan kernels' tiles (``ops.ssd._heads_a_step``):
+# eight heads a group, a state and a chunk of 128; one M layer of two chunks
+KERNEL_WIDTHS = {
+    "hybrid_override_pattern": "ME", "mamba_num_heads": 16, "n_groups": 2,
+    "mamba_head_dim": 16, "ssm_state_size": 128, "chunk_size": 128,
+    "training": {"attention": "dense"}}
+
+
+@pytest.mark.parametrize("fault", [
+    "none", "group_0_for_every_head", "scan_at_three_mantissa_bits"])
+def test_the_scans_faults_leave_the_reference_through_its_kernels(
+        bench, fault, monkeypatch):
+    """The scan's two faults once more with ``ssd_scan_kernel`` in the
+    plain form's place (interpreted): unbroken the product is the
+    reference, loss and every leaf's gradient; with group 0's ``B`` and
+    ``C`` for every head, or ``x``, ``B``, ``C`` at three mantissa bits,
+    it is not."""
+    kernel, real_scan, calls = ssd.ssd_scan_kernel, mamba2.ssd_scan, []
+
+    def interpreted(*args):
+        calls.append(args[0].shape)
+        return kernel(*args, True)
+
+    monkeypatch.setattr(ssd, "ssd_scan_kernel", interpreted)
+    if fault == "group_0_for_every_head":
+        def scan(x, dt, a, b, c, d, chunk):
+            first = lambda t: jnp.repeat(t[:, :, :1], t.shape[2], 2)
+            return real_scan(x, dt, a, first(b), first(c), d, chunk=chunk)
+        monkeypatch.setattr(mamba2, "ssd_scan", scan)
+    elif fault == "scan_at_three_mantissa_bits":
+        def scan(x, dt, a, b, c, d, chunk):
+            return real_scan(rounded(x), dt, a, rounded(b), rounded(c), d,
+                             chunk=chunk)
+        monkeypatch.setattr(mamba2, "ssd_scan", scan)
+    (loss, grads), (ref_loss, ref_grads) = both_sides(
+        bench, toy(bench, **KERNEL_WIDTHS), seq=256)
+    assert (2, 256, 16, 16) in calls
+    if fault == "none":
+        assert float(loss) == pytest.approx(float(ref_loss), rel=1e-5)
+        assert_same_gradients(grads, ref_grads)
+    else:
+        assert abs(float(loss) - float(ref_loss)) > 3e-5 * float(ref_loss)
 
 
 def test_sharper_weights_alone_stay_on_the_reference(bench):

@@ -673,11 +673,12 @@ def test_granites_layers_compile_at_their_widths(one_chip):
     the name the readers find them by, the queries scaled by 2^-3 outside
     them. The Mamba-2 scan: 64 heads of 64 with a state of 128 in 16
     chunks of 256, forward and backward, as the v5e's compiler takes it:
-    no kernel, the states crossing the chunks in one loop each way with
-    the float32 ``[1, 1, 64, 64, 128]`` state as its carry, every
-    operation under the scope the cell's readers sum, and arguments +
-    temporaries under 1.5 GiB (the decay matrices are 268 MB in float32:
-    a form that kept several copies of them a layer would pass it)."""
+    two Pallas kernels named ``ssd_chunk_scan`` (not ``flash_attention``),
+    eight heads a grid step (the gauge, set as the program is lowered), no
+    loop over the chunks and no ``reduce-window``, every operation under
+    the scope the cell's readers sum, and arguments + temporaries under
+    0.25 GiB (the plain form's decay matrices alone are 268 MB in float32;
+    the kernels' residual, the states that enter the chunks, is 33.5 MB)."""
     import jax
     import jax.numpy as jnp
 
@@ -721,18 +722,82 @@ def test_granites_layers_compile_at_their_widths(one_chip):
         heads).compile()
     assert metrics.SSM_CHUNKS_LAST.labels(
         chunk="256", heads="64").get() == 16
+    assert metrics.SSM_SCAN_KERNEL_LAST.labels(chunk="256").get() == 8
     text = compiled.as_text()
-    assert "custom_call_target=\"tpu_custom_call\"" not in text
+    kernels = kernel_instructions(text)
+    assert len(kernels) == 2 and all(
+        name.startswith(ssd.SCAN_KERNEL_NAME + ".")
+        and "flash_attention" not in name for name, _ in kernels), kernels
     planned = compiled.memory_analysis()
     assert (planned.argument_size_in_bytes + planned.temp_size_in_bytes
-            <= 1.5 * 2 ** 30)
+            <= 0.25 * 2 ** 30)
     scopes = profiler.instruction_scopes(text)
     # (the loss's own cast and sum are the only operations outside it)
     assert {profiler.phase_of(scope) for scope in scopes.values()} == {
         "hvd.ssm.scan", None}
-    loops = [line for line in text.splitlines() if " while(" in line]
-    assert len(loops) == 2, len(loops)
-    assert all("f32[1,1,64,64,128]" in line for line in loops)
+    assert " while(" not in text and "reduce-window" not in text
+
+
+@pytest.mark.parametrize("cell", ["granite", "nemotron_h"])
+def test_a_recomputed_mixer_holds_the_scans_kernels_three_a_layer(one_chip,
+                                                                  cell):
+    """Each cell's Mamba-2 mixer at its published widths (Granite: 4,096
+    tokens, one group, chunk 256; Nemotron-H: 8,192 tokens, eight groups,
+    chunk 128) under the decoders' ``jax.checkpoint`` policy, as the v5e's
+    compiler takes it: the policy keeps what a ``pallas_call`` returned
+    and sees the scan's primitive instead, so a layer holds the kernel
+    three times, forward (no states written), recomputed (with the states
+    that enter the chunks, the backward kernel's residual) and backward,
+    all named ``ssd_chunk_scan`` under the scope the cells' readers sum,
+    eight heads a grid step; and nothing under that scope is a
+    ``reduce-window`` (the running sum is a product) or a loop."""
+    import flax.linen as nn
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu import metrics, profiler
+    from horovod_tpu.models import granite, nemotron_h, parts
+    from horovod_tpu.ops import ssd
+
+    module, cfg, seq, chunk = {
+        "granite": (granite.Mamba2Mixer, granite.GRANITE_4_0_H_MICRO, 4096,
+                    256),
+        "nemotron_h": (nemotron_h.Mamba2Mixer,
+                       nemotron_h.NEMOTRON_3_NANO_30B_A3B, 8192, 128)}[cell]
+    mixer = nn.remat(module, policy=parts.save_kernels_and_projections)(cfg)
+    x = jax.ShapeDtypeStruct((1, seq, cfg.hidden_size), jnp.bfloat16,
+                             sharding=one_chip)
+    params = jax.tree.map(
+        lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                          sharding=one_chip),
+        jax.eval_shape(mixer.init, jax.random.PRNGKey(0), x))
+
+    def loss(params, x):
+        return mixer.apply(params, x).astype(jnp.float32).sum()
+
+    metrics.SSM_SCAN_KERNEL_LAST.set(-1, chunk=str(chunk))
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    assert metrics.SSM_SCAN_KERNEL_LAST.labels(chunk=str(chunk)).get() == 8
+    kernels = kernel_instructions(text)
+    assert all(name.startswith(ssd.SCAN_KERNEL_NAME + ".")
+               for name, _ in kernels), kernels
+    passes = [("recomputed" if "rematted_computation" in op_name else
+               "backward" if "transpose(" in op_name else "forward")
+              for _, op_name in kernels]
+    assert sorted(passes) == ["backward", "forward", "recomputed"], kernels
+    scopes = profiler.instruction_scopes(text)
+    assert {profiler.phase_of(scopes[name]) for name, _ in kernels} == {
+        "hvd.ssm.scan"}
+    states = f"f32[1,{seq // chunk},128,4096]"
+    written = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and f"{states}{{" in
+               line.split(" custom-call(")[0]]
+    assert len(written) == 1 and "rematted_computation" in written[0]
+    under = [line for line in text.splitlines()
+             if "hvd.ssm.scan" in line]
+    assert under and not any(
+        " reduce-window(" in line or " while(" in line for line in under)
 
 
 @pytest.fixture(scope="module")

@@ -24,7 +24,10 @@ tools/chip_kernel_check.py two_widths`` runs that alone); and Kimi Delta
 Attention's pair terms, the Pallas kernel and its backward kernel against
 the plain form on the chip, checked and timed at the cell's shape
 (``check_pair_terms``; ``python tools/chip_kernel_check.py pair_terms``
-runs that alone). Compiled, never ``interpret=True``: off a TPU this exits
+runs that alone); and the Mamba-2 scan's two kernels against the plain
+chunk form and the float32 recurrence at Granite's and Nemotron-H's mixer
+shapes, checked and timed (``check_ssd``; ``python
+tools/chip_kernel_check.py ssd`` runs that alone). Compiled, never ``interpret=True``: off a TPU this exits
 non-zero.
 
 The tolerance is the one ``tests/test_sequence_parallel.py`` uses for bf16
@@ -344,6 +347,107 @@ def check_pair_terms(heads=32, chunks=128, size=64, sub=16, width=128,
                   f"at [1, {heads}, {chunks}, {size}, {width}]")
 
 
+def check_ssd(calls=20, cells=(("nemotron-h", 8192, 8, 128),
+                              ("granite", 4096, 1, 256))) -> None:
+    """``ops.ssd.ssd_scan_kernel`` against ``_chunk_form`` and against the
+    float32 token-by-token recurrence, all compiled on the chip, at the
+    two cells' mixer shapes (Nemotron-H: 8,192 tokens, 64 heads of 64 on 8
+    groups of 128, chunk 128; Granite: 4,096 tokens, one group, chunk 256)
+    in bfloat16 with float32 steps, at the initial draw's steps (0.001 to
+    0.1, rates 1 to 16): ``y`` and the six gradients under a random
+    cotangent, each form's largest difference from the recurrence beside
+    the other's; then each form timed, forward alone and forward with
+    backward, ``calls`` dispatched back to back with only the last result
+    kept. The VLIW bundles a grid step come from the sandbox's compile
+    (``--xla_jf_dump_to``: PERF.md), not from here."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+
+    from horovod_tpu.ops import ssd
+
+    f32, dtype = jnp.float32, jnp.bfloat16
+    heads, width, state = 64, 64, 128
+
+    def recurrence(x, dt, a, b, c, d):
+        """Token by token in float32; 128 tokens at a time under
+        ``jax.checkpoint``, so that its gradient keeps a state every 128
+        tokens and not all 8,192 of them (17 GB)."""
+        share = heads // b.shape[2]
+
+        def one_token(carry, xs):
+            x, dt, b, c = xs
+            b, c = jnp.repeat(b, share, 1), jnp.repeat(c, share, 1)
+            carry = jnp.exp(dt * a)[..., None, None] * carry + (
+                (dt[..., None] * x)[..., None] * b[..., None, :])
+            return carry, (carry * c[..., None, :]).sum(-1) + d[:, None] * x
+
+        @jax.checkpoint
+        def some_tokens(carry, xs):
+            return lax.scan(one_token, carry, xs)
+
+        start = jnp.zeros((x.shape[0], heads, width, state), f32)
+        out = lax.scan(some_tokens, start, jax.tree.map(
+            lambda t: jnp.moveaxis(t.astype(f32), 1, 0).reshape(
+                (-1, 128) + t.shape[:1] + t.shape[2:]), (x, dt, b, c)))[1]
+        return jnp.moveaxis(out.reshape((-1,) + out.shape[2:]), 0, 1)
+
+    for cell, seq, groups, chunk in cells:
+        keys = jax.random.split(jax.random.PRNGKey(7), 7)
+        x = jax.random.normal(keys[0], (1, seq, heads, width)).astype(dtype)
+        dt = jnp.exp(jax.random.uniform(
+            keys[1], (1, seq, heads), minval=np.log(1e-3), maxval=np.log(0.1)))
+        a = -jax.random.uniform(keys[2], (heads,), minval=1.0, maxval=16.0)
+        b, c = (jax.random.normal(key, (1, seq, groups, state)).astype(dtype)
+                for key in keys[3:5])
+        d = jax.random.normal(keys[5], (heads,))
+        y_bar = jax.random.normal(keys[6], x.shape).astype(dtype)
+        args = (x, dt, a, b, c, d)
+        forms = {
+            "recurrence": recurrence,
+            "plain": lambda *t: ssd._chunk_form(*t, chunk),
+            "kernel": lambda *t: ssd.ssd_scan_kernel(*t, chunk)}
+
+        def both(form):
+            def run(*t):
+                out, vjp = jax.vjp(form, *t[:-1])
+                return (out,) + vjp(t[-1].astype(out.dtype))
+            return jax.jit(run)
+
+        got = {name: [np.asarray(t, np.float32)
+                      for t in both(form)(*args, y_bar)]
+               for name, form in forms.items()}
+        print(f" ssd scan at {cell}'s [1, {seq}, {heads}, {width}], "
+              f"{groups} groups of {state}, chunk {chunk}:")
+        for n, name in enumerate(("y", "dx", "ddt", "da", "db", "dc", "dd")):
+            want = got["recurrence"][n]
+            scale = float(np.abs(want).max())
+            off = {form: float(np.abs(got[form][n] - want).max())
+                   for form in ("plain", "kernel")}
+            print(f"  {name}: max |kernel - recurrence| {off['kernel']:.3e}, "
+                  f"|plain - recurrence| {off['plain']:.3e} (recurrence max "
+                  f"{scale:.3g})")
+            assert np.isfinite(got["kernel"][n]).all(), name
+            np.testing.assert_allclose(
+                got["kernel"][n], want, rtol=0,
+                atol=max(BF16_TOL * scale, 2 * off["plain"]), err_msg=name)
+        for name in ("plain", "kernel"):
+            for what, fn, more in (("forward", jax.jit(forms[name]), ()),
+                                   ("forward and backward",
+                                    both(forms[name]), (y_bar,))):
+                jax.block_until_ready(fn(*args, *more))
+                t0 = time.perf_counter()
+                for _ in range(calls):
+                    out = fn(*args, *more)
+                jax.block_until_ready(out)
+                print(f"  {name}, {what}: "
+                      f"{(time.perf_counter() - t0) / calls * 1e3:.3f} ms a "
+                      f"call")
+
+
 def check_tokens_major() -> None:
     """BERT's two shapes as its projections write them, an odd group of
     causal pairs, and a head a block."""
@@ -435,7 +539,8 @@ def main() -> None:
     print(f"device: platform={d.platform} device_kind={d.device_kind!r} "
           f"count={len(jax.devices())}")
     alone = {"two_widths": check_two_widths,  # ~2 minutes
-             "pair_terms": check_pair_terms}
+             "pair_terms": check_pair_terms,
+             "ssd": check_ssd}
     if len(sys.argv) == 2 and sys.argv[1] in alone:
         alone[sys.argv[1]]()
         print("kernels ok")
@@ -453,6 +558,7 @@ def main() -> None:
     check_tiles_as_they_lie()
     check_two_widths()
     check_pair_terms()
+    check_ssd()
     print("kernels ok")
 
 
