@@ -13,7 +13,19 @@ Layer map (vs SURVEY.md §1): the user API here is L5; collectives compile
 to XLA HLOs over the device mesh (replacing L2b/L1's NCCL/MPI data plane).
 """
 
-from .version import __version__  # noqa: F401
+import sys as _sys
+import time as _time
+
+# The set-up account counts from this line (``hvd.cache_stats()["setup"]``):
+# the tracer's clock is ``time.time``, and tracing imports the stdlib alone.
+_import_t0 = _time.time()
+_modules_before = sum(name.startswith("horovod_tpu.") for name in _sys.modules)
+
+from . import tracing  # noqa: E402,F401
+
+tracing.get_tracer().open_setup(_import_t0)
+
+from .version import __version__  # noqa: E402,F401
 
 from .basics import (  # noqa: F401
     ccl_built,
@@ -108,7 +120,6 @@ from . import faults  # noqa: F401
 from . import metrics  # noqa: F401
 from . import peercheck  # noqa: F401
 from . import profiler  # noqa: F401
-from . import tracing  # noqa: F401
 from . import callbacks  # noqa: F401
 from . import elastic  # noqa: F401
 from . import parallel  # noqa: F401
@@ -128,3 +139,9 @@ from .parallel.param_sharding import (  # noqa: F401
 from .stall import fetch  # noqa: F401
 from .sync_batch_norm import SyncBatchNorm  # noqa: F401
 from .timeline import start_timeline, stop_timeline  # noqa: F401
+
+tracing.get_tracer().record(
+    attribution.SPAN_SETUP_IMPORT, attribution.CAT_HOST, _import_t0,
+    _time.time() - _import_t0,
+    {"modules": sum(name.startswith("horovod_tpu.") for name in _sys.modules)
+     - _modules_before})

@@ -89,6 +89,24 @@ STEP_SPAN_NAMES = (SPAN_STEP, SPAN_STEP_DISPATCH, SPAN_STEP_DRAIN)
 DRAIN_STALL_WATCH = "stall_watch"
 DRAIN_TRACE_SAMPLE = "trace_sample"
 
+#: Spans of the set-up account (``tracing.StepTracer.open_setup``): what a
+#: process does from ``import horovod_tpu`` to its first warm step, opened
+#: where the work happens and recorded only while the account is open.
+SPAN_SETUP_IMPORT = "hvd.setup.import"  # the package's import
+SPAN_SETUP_INIT = "hvd.setup.init"  # basics.init
+SPAN_SETUP_COMPILE_CACHE = "hvd.setup.compile_cache"  # enable_compile_cache
+SPAN_SETUP_OPTIMIZER_INIT = "hvd.setup.optimizer_init"
+SPAN_SETUP_PLACE = "hvd.setup.place"  # replicate, shard_*: the host's share
+SPAN_SETUP_BUILD = "hvd.setup.build"  # make_train_step
+#: From ``jax.monitoring``'s durations (``profiler.CompileAccount``), each
+#: recorded when it ends, with its start computed from its seconds.
+SPAN_SETUP_TRACE = "hvd.setup.trace"
+SPAN_SETUP_LOWER = "hvd.setup.lower"
+SPAN_SETUP_BACKEND_COMPILE = "hvd.setup.backend_compile"
+SPAN_SETUP_CACHE_READ = "hvd.setup.cache_read"
+SETUP_EVENT_SPAN_NAMES = (SPAN_SETUP_TRACE, SPAN_SETUP_LOWER,
+                          SPAN_SETUP_BACKEND_COMPILE, SPAN_SETUP_CACHE_READ)
+
 #: Phase scopes inside the compiled step (``jax.named_scope`` through
 #: ``profiler.annotate_collective``, which prepends :data:`SCOPE_PREFIX`):
 #: they reach the compiled step's ``metadata={op_name=...}`` and from

@@ -195,15 +195,19 @@ def enable_compile_cache() -> str:
     """
     import jax
 
-    from . import profiler
+    from . import profiler, tracing
+    from .attribution import SPAN_SETUP_COMPILE_CACHE
 
-    # What the cache saves is read off the compile account
-    # (``hvd.cache_stats()["compile"]``), so it listens from here on.
-    profiler.compile_account().listen()
-    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(checkout, ".jax_cache"))
+    with tracing.setup_span(SPAN_SETUP_COMPILE_CACHE) as span:
+        # What the cache saves is read off the compile account
+        # (``hvd.cache_stats()["compile"]``), so it listens from here on.
+        profiler.compile_account().listen()
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            checkout = os.path.dirname(
+                os.path.dirname(os.path.abspath(__file__)))
+            jax.config.update("jax_compilation_cache_dir",
+                              os.path.join(checkout, ".jax_cache"))
+        span.note(dir=jax.config.jax_compilation_cache_dir)
     return jax.config.jax_compilation_cache_dir
 
 
@@ -220,29 +224,38 @@ def init(devices: Sequence[Any] | None = None) -> None:
     from jax.sharding import Mesh
     import numpy as np
 
+    from . import tracing
+    from .attribution import SPAN_SETUP_INIT
+
     with _lock:
         if _state.initialized:
             return
-        # Distributed bootstrap first: in elastic mode it refreshes the env
-        # world facts from the KV, which from_env() must then see.
-        _maybe_init_distributed()
-        config = RuntimeConfig.from_env()
-        topo = Topology(devices)
-        _state.topology = topo
-        _state.config = config
-        _state.mesh = Mesh(np.array(topo.devices), (_state.axis_name,))
-        _state.initialized = True
+        # A world formed anew (a re-formation, a resume) is a set-up of
+        # its own; the account open since the import is the first one's.
+        tracing.get_tracer().reopen_setup()
+        with tracing.setup_span(SPAN_SETUP_INIT) as span:
+            # Distributed bootstrap first: in elastic mode it refreshes the
+            # env world facts from the KV, which from_env() must then see.
+            _maybe_init_distributed()
+            config = RuntimeConfig.from_env()
+            topo = Topology(devices)
+            _state.topology = topo
+            _state.config = config
+            _state.mesh = Mesh(np.array(topo.devices), (_state.axis_name,))
+            _state.initialized = True
 
-        # Register the global process set (id 0) now that the world exists.
-        from . import process_sets
+            # Register the global process set (id 0) now that the world
+            # exists.
+            from . import process_sets
 
-        process_sets._reset(topo, _state.mesh)
-        # Honor HOROVOD_PROFILER_LOGDIR (xprof capture; the reference's
-        # NVTX-activation-by-env contract).
-        from . import profiler
+            process_sets._reset(topo, _state.mesh)
+            # Honor HOROVOD_PROFILER_LOGDIR (xprof capture; the reference's
+            # NVTX-activation-by-env contract).
+            from . import profiler
 
-        profiler.maybe_start_from_env()
-        profiler.compile_account().listen()
+            profiler.maybe_start_from_env()
+            profiler.compile_account().listen()
+            span.note(ranks=topo.size, backend=jax.default_backend())
         get_logger().info(
             "horovod_tpu initialized: %d rank(s), %d host(s), backend=%s",
             topo.size,
@@ -289,6 +302,9 @@ def shutdown() -> None:
         _state.topology = None
         _state.mesh = None
         _state.config = None
+        from . import tracing
+
+        tracing.get_tracer().reopen_setup()
 
 
 def is_initialized() -> bool:

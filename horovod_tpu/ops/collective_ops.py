@@ -59,7 +59,9 @@ def cache_stats(reset: bool = False) -> dict:
          "compile": {"trace_s", "lower_s", "backend_compile_s",
                      "cache_hits", "cache_misses", "programs",
                      "listening", "steps": {step: {"first_call",
-                                                   "recompiles"}}}}
+                                                   "recompiles"}}},
+         "setup": {"open", "dropped", "spans": [span, ...],
+                   "by_name": {name: {"count", "total_s", "self_s"}}}}
 
     ``compile`` is JAX's own account (``jax.monitoring``) of every
     program this process traced, lowered and compiled since
@@ -69,6 +71,20 @@ def cache_stats(reset: bool = False) -> dict:
     of loading the executable), the persistent cache's hits and misses,
     and per factory step the share of its first call and the number of
     recompiles. ``reset`` leaves it alone.
+
+    ``setup`` is the set-up account (``tracing.SetupAccount``): every
+    span recorded from the first line of ``import horovod_tpu`` to the
+    end of the first factory-step call in which nothing compiled, whole
+    (``name``, ``t``, ``dur``, ``id``, ``parent``, ``args``) and on the
+    tracer's clock: the ``hvd.setup.*`` spans of import, ``init``,
+    placing and building, one span a tracing, lowering, backend compile
+    and cache read that JAX reported meanwhile (a nested jit's tracing a
+    child of its caller's), and the ``hvd.step`` calls up to the first
+    warm one. ``by_name`` gives each name's count, the union of its
+    intervals and its self time; ``open`` says whether the account still
+    records, ``dropped`` what its 2,048 places could not hold.
+    ``hvd.shutdown()`` and a new ``hvd.init()`` open the next account.
+    ``reset`` leaves it alone.
 
     ``bytes`` is the cache's noted memory cost — the sum of each resident
     entry's serialized-program size, recorded by the dispatch path on the
@@ -83,7 +99,7 @@ def cache_stats(reset: bool = False) -> dict:
     not leak between them. The cluster metrics registry resets
     separately via ``metrics.reset_for_testing()``.
     """
-    from .. import profiler
+    from .. import profiler, tracing
 
     cache = global_cache()
     stats = {
@@ -96,6 +112,7 @@ def cache_stats(reset: bool = False) -> dict:
         },
         "eager_dispatch": dict(_dispatch_counts),
         "compile": profiler.compile_account().summary(),
+        "setup": tracing.get_tracer().setup_summary(),
     }
     if reset:
         _dispatch_counts.clear()

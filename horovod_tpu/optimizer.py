@@ -29,6 +29,7 @@ with axis_name='hvd'.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, NamedTuple
 
 import jax
@@ -829,6 +830,24 @@ def sharded_step_update(spec, grads, local_state, params, axis_name=None,
     return new_params, new_local
 
 
+def _accounted_init(init, sync_mode: str):
+    """``init`` inside an ``hvd.setup.optimizer_init`` span of the set-up
+    account: the sync mode, and the leaves and bytes of the state it
+    returns (from shapes: nothing is waited for)."""
+    from . import tracing
+    from .attribution import SPAN_SETUP_OPTIMIZER_INIT
+
+    @functools.wraps(init)
+    def accounted(params):
+        with tracing.setup_span(SPAN_SETUP_OPTIMIZER_INIT,
+                                {"sync_mode": sync_mode}) as span:
+            state = init(params)
+            span.note_tree(state)
+        return state
+
+    return accounted
+
+
 def DistributedOptimizer(
     optimizer,
     named_parameters=None,
@@ -1048,7 +1067,8 @@ def DistributedOptimizer(
 
         init_fsdp._hvd_reduce_spec = spec
         update_fsdp._hvd_reduce_spec = spec
-        return optax.GradientTransformation(init_fsdp, update_fsdp)
+        return optax.GradientTransformation(
+            _accounted_init(init_fsdp, sync_mode), update_fsdp)
 
     if sync_mode == "sharded":
 
@@ -1095,7 +1115,8 @@ def DistributedOptimizer(
 
         init_sharded._hvd_reduce_spec = spec
         update_sharded._hvd_reduce_spec = spec
-        return optax.GradientTransformation(init_sharded, update_sharded)
+        return optax.GradientTransformation(
+            _accounted_init(init_sharded, sync_mode), update_sharded)
 
     if k == 1:
 
@@ -1131,7 +1152,8 @@ def DistributedOptimizer(
             return updates, new_inner
 
         update_fn._hvd_reduce_spec = spec
-        return optax.GradientTransformation(init_fn, update_fn)
+        return optax.GradientTransformation(
+            _accounted_init(init_fn, sync_mode), update_fn)
 
     # backward_passes_per_step > 1: accumulate locally, allreduce on the
     # k-th microstep only (the reference's local gradient aggregation).
@@ -1177,7 +1199,8 @@ def DistributedOptimizer(
 
     init_acc._hvd_reduce_spec = spec
     update_acc._hvd_reduce_spec = spec
-    return optax.GradientTransformation(init_acc, update_acc)
+    return optax.GradientTransformation(
+        _accounted_init(init_acc, sync_mode), update_acc)
 
 
 def grad(loss_fn, argnums=0, has_aux=False, **dist_kwargs):

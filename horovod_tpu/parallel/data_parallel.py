@@ -19,6 +19,8 @@ from typing import Any, Callable
 import jax
 from jax.sharding import PartitionSpec as P
 
+from .. import attribution, autotune, faults, profiler, tracing
+
 
 class _StallWatchedStep:
     """Default-on stall watch for factory-built train steps.
@@ -40,7 +42,6 @@ class _StallWatchedStep:
     """
 
     def __init__(self, fn, name_prefix: str):
-        from .. import tracing
         from ..utils.env import get_int
 
         self._fn = fn
@@ -110,18 +111,13 @@ class _StallWatchedStep:
         this process — not just one wrapping our own callable: a co-step
         (built mid-warmup, returned unwrapped) must also defer its drain
         or it biases the first tuner's samples."""
-        from ..autotune import _active_tuner
-
-        return bool(_active_tuner and _active_tuner[0]._hvd_tuning)
+        tuners = autotune._active_tuner
+        return bool(tuners and tuners[0]._hvd_tuning)
 
     def _remember_arguments(self, args, kwargs) -> None:
         """Shapes, dtypes and shardings of the first call's arguments:
         what ``profiler.step_scopes`` lowers the step with, long after
         the arrays themselves were donated."""
-        import jax
-
-        from .. import profiler
-
         def abstract(leaf):
             if hasattr(leaf, "shape") and hasattr(leaf, "dtype"):
                 return jax.ShapeDtypeStruct(
@@ -155,8 +151,6 @@ class _StallWatchedStep:
                       **share)
 
     def __call__(self, *args, **kwargs):
-        from .. import attribution, tracing
-
         # Every call is one hvd.step span, opened before anything else
         # here runs: a profiler annotation and a step record in the
         # flight-recorder ring, with the dispatch and (where there is
@@ -186,17 +180,14 @@ class _StallWatchedStep:
             raise
 
     def _watched_call(self, tracer, rec, call: int, args, kwargs):
-        from .. import attribution, faults, profiler
-        from ..autotune import _poison_error, warmup_aborted
-
-        if warmup_aborted():
+        if autotune.warmup_aborted():
             # A mid-warmup autotune abort poisons EVERY factory step in
             # the process, not just the tuner's wrapper: co-built steps
             # and steps built post-abort pass through maybe_autotune_step
             # bare, but all of them route through this wrapper — and all
             # of them would trace collective sequences that may diverge
             # from peers that pinned the broadcast winner.
-            raise _poison_error()
+            raise autotune._poison_error()
         tuning = self._tuning_live()
         watch_due = False
         cross = False
@@ -234,8 +225,6 @@ class _StallWatchedStep:
                 "RESOURCE_EXHAUSTED: injected memory pressure "
                 "(fault point memory.pressure)")
         if watch_due:
-            import jax
-
             from ..stall import watch
 
             # The announcement precedes the DISPATCH: on backends
@@ -253,8 +242,6 @@ class _StallWatchedStep:
             with tracer.host_span(attribution.SPAN_STEP_DISPATCH):
                 out = self._fn(*args, **kwargs)
             if sample_due:
-                import jax
-
                 with tracer.host_span(
                         attribution.SPAN_STEP_DRAIN,
                         {"cause": attribution.DRAIN_TRACE_SAMPLE}):
@@ -263,6 +250,9 @@ class _StallWatchedStep:
         rec.ship = sample_due
         if account.programs != self._compiled:
             self._book_compiles(account, rec, call)
+        elif call > 1 and tracer.setup_open:
+            # The first warm call of a factory step: set-up ends with it.
+            rec.closes_setup = True
         return out
 
     @property
@@ -421,7 +411,8 @@ def shard_state(tree, mesh=None, axis_name: str | None = None):
         axis_name = (MESH2D_ROW_AXES if is_mesh_2d(mesh)
                      else basics.global_axis_name())
     sharding = NamedSharding(mesh, P(axis_name))
-    return jax.tree.map(partial(jax.device_put, device=sharding), tree)
+    with tracing.place_span("shard_state", tree):
+        return jax.tree.map(partial(jax.device_put, device=sharding), tree)
 
 
 def _record_mesh_axes(sizes: dict) -> None:
@@ -508,6 +499,18 @@ def make_train_step(
       allgather of the updated parameter shards issued off the gradient
       critical path.
     """
+    with tracing.setup_span(attribution.SPAN_SETUP_BUILD) as span:
+        step = _make_train_step(
+            loss_fn, optimizer, mesh, axis_name, donate, loss_is_averaged,
+            hierarchical, deferred_param_gather)
+        span.note(kind=step._prefix)
+    return step
+
+
+def _make_train_step(loss_fn, optimizer, mesh, axis_name, donate,
+                     loss_is_averaged, hierarchical, deferred_param_gather):
+    """:func:`make_train_step`'s body: the program its optimizer's sync
+    mode and the mesh ask for."""
     spec = _sharded_spec_of(optimizer)
     fsdp_spec = _fsdp_spec_of(optimizer)
     mesh2d = _resolve_mesh_2d(mesh, hierarchical)
@@ -686,8 +689,6 @@ def _make_sharded_train_step(loss_fn, spec, mesh, axis_name, donate,
                 donate_argnums=(0,) if donate else (),
             )
         args = (shards, new_state.counter) if int8 else (shards,)
-        from .. import tracing
-
         # Host-visible half of the sharded wire: the updated-parameter
         # allgather dispatch (the program itself runs async while the
         # host does between-step work; the span times the dispatch and
@@ -1285,7 +1286,9 @@ def shard_batch(batch, mesh=None, axis_name: str | None = None):
         axis_name = (MESH2D_AXES if is_mesh_2d(mesh)
                      else basics.global_axis_name())
     sharding = NamedSharding(mesh, P(axis_name))
-    return jax.tree.map(partial(jax.device_put, device=sharding), batch)
+    with tracing.place_span("shard_batch", batch):
+        return jax.tree.map(
+            partial(jax.device_put, device=sharding), batch)
 
 
 def replicate(tree, mesh=None):
@@ -1311,7 +1314,8 @@ def replicate(tree, mesh=None):
         leaf = jnp.copy(leaf) if isinstance(leaf, jax.Array) else leaf
         return jax.device_put(leaf, sharding)
 
-    return jax.tree.map(_copy_put, tree)
+    with tracing.place_span("replicate", tree):
+        return jax.tree.map(_copy_put, tree)
 
 
 def make_elastic_train_step(
@@ -1389,10 +1393,6 @@ def make_elastic_train_step(
 
     def step(params, opt_state, batch):
         import os
-
-        from .. import tracing
-
-        from .. import attribution
 
         # The elastic step's phases ARE host-separable (compiled local
         # leg, host collective leg, compiled apply), so each gets a real

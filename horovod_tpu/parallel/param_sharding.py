@@ -204,24 +204,29 @@ def shard_params(params, world_size: int | None = None) -> ShardedParams:
         raise ValueError(
             f"shard_params needs a positive world size, got {world_size!r} "
             "(init() first, or pass world_size=)")
-    leaves, treedef = jax.tree.flatten(params)
-    # jnp.asarray only — size/shape/dtype are static facts; np.asarray
-    # here would pull every full leaf device→host on each resize hop.
-    leaves = [jnp.asarray(l) for l in leaves]
-    sizes = shard_ownership(leaves, n)
-    rows = [
-        jnp.pad(l.ravel(), (0, n * s - int(l.size))).reshape(n, s)
-        for l, s in zip(leaves, sizes)
-    ]
-    meta = _Meta(
-        treedef=treedef,
-        shapes=tuple(tuple(l.shape) for l in leaves),
-        dtypes=tuple(np.dtype(l.dtype) for l in leaves),
-        world_size=n,
-    )
-    sp = ShardedParams(rows, meta)
-    _record_resident("params", "fsdp", _resident_bytes(rows, n))
-    _note_param_leaves(params, sizes, n)
+    from ..tracing import place_span
+
+    # What the host pays here is a pad and a reshape a leaf, each a
+    # program of its own: the span does not wait for them.
+    with place_span("shard_params", params):
+        leaves, treedef = jax.tree.flatten(params)
+        # jnp.asarray only — size/shape/dtype are static facts; np.asarray
+        # here would pull every full leaf device→host on each resize hop.
+        leaves = [jnp.asarray(l) for l in leaves]
+        sizes = shard_ownership(leaves, n)
+        rows = [
+            jnp.pad(l.ravel(), (0, n * s - int(l.size))).reshape(n, s)
+            for l, s in zip(leaves, sizes)
+        ]
+        meta = _Meta(
+            treedef=treedef,
+            shapes=tuple(tuple(l.shape) for l in leaves),
+            dtypes=tuple(np.dtype(l.dtype) for l in leaves),
+            world_size=n,
+        )
+        sp = ShardedParams(rows, meta)
+        _record_resident("params", "fsdp", _resident_bytes(rows, n))
+        _note_param_leaves(params, sizes, n)
     return sp
 
 

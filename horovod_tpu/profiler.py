@@ -37,6 +37,10 @@ import re
 import threading
 import weakref
 
+from .attribution import (SPAN_SETUP_BACKEND_COMPILE, SPAN_SETUP_CACHE_READ,
+                          SPAN_SETUP_LOWER, SPAN_SETUP_TRACE)
+from .tracing import get_tracer
+
 _lock = threading.Lock()
 _active_logdir: str | None = None
 
@@ -125,7 +129,12 @@ class CompileAccount:
     backend compile (on a persistent-cache hit, of loading the
     executable); persistent-cache hits and misses; programs compiled.
     ``steps`` holds, per factory step, the share of its first call and
-    its recompiles (``data_parallel._StallWatchedStep`` books them)."""
+    its recompiles (``data_parallel._StallWatchedStep`` books them).
+
+    While the tracer's set-up account is open (``tracing.SetupAccount``)
+    each duration is also kept there as a span that ended when it was
+    reported (``SPANS``), with JAX's ``fun_name`` as ``program`` and, on a
+    backend compile, whether the persistent cache was hit or missed."""
 
     DURATIONS = {
         "/jax/core/compile/jaxpr_trace_duration": "trace_s",
@@ -137,24 +146,46 @@ class CompileAccount:
         "/jax/compilation_cache/cache_misses": "cache_misses",
     }
     FIELDS = (*DURATIONS.values(), *EVENTS.values(), "programs")
+    #: Event -> its span in the set-up account.
+    SPANS = {
+        "/jax/core/compile/jaxpr_trace_duration": SPAN_SETUP_TRACE,
+        "/jax/core/compile/jaxpr_to_mlir_module_duration": SPAN_SETUP_LOWER,
+        "/jax/core/compile/backend_compile_duration":
+            SPAN_SETUP_BACKEND_COMPILE,
+        "/jax/compilation_cache/cache_retrieval_time_sec":
+            SPAN_SETUP_CACHE_READ,
+    }
 
     def __init__(self):
         self.trace_s = self.lower_s = self.backend_compile_s = 0.0
         self.cache_hits = self.cache_misses = self.programs = 0
         self.steps: dict[str, dict] = {}
         self.listening = False
+        self._cache = None  # "hit" / "miss" heard since the last compile
 
-    def on_duration(self, event: str, seconds: float, **_) -> None:
+    def on_duration(self, event: str, seconds: float, **kwargs) -> None:
         field = self.DURATIONS.get(event)
+        cache = None
         if field is not None:
             setattr(self, field, getattr(self, field) + seconds)
             if field == "backend_compile_s":
                 self.programs += 1
+                cache, self._cache = self._cache, None
+        name = self.SPANS.get(event)
+        tracer = get_tracer()
+        if name is not None and tracer.setup_open:
+            args = {}
+            if "fun_name" in kwargs:
+                args["program"] = str(kwargs["fun_name"])
+            if cache:
+                args["cache"] = cache
+            tracer.setup_event(name, seconds, args)
 
     def on_event(self, event: str, **_) -> None:
         field = self.EVENTS.get(event)
         if field is not None:
             setattr(self, field, getattr(self, field) + 1)
+            self._cache = "hit" if field == "cache_hits" else "miss"
 
     def listen(self) -> None:
         """Register with ``jax.monitoring``, once per process (its

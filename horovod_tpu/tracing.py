@@ -49,6 +49,15 @@ profiler session open a span costs two clock reads, one ring append and
 an annotation that does nothing: no environment read, no lock beyond the
 ring's.
 
+The **set-up account** (:class:`SetupAccount`) is one bounded list beside
+the ring for the one stretch the ring cannot keep: from the first line of
+``import horovod_tpu`` to the end of the first factory-step call in which
+nothing compiled. While it is open every span recorded here is also kept
+there whole, and ``profiler.CompileAccount`` adds a span for each tracing,
+lowering, backend compile and cache read that ``jax.monitoring`` reports;
+once closed it costs one attribute test. ``hvd.cache_stats()["setup"]``
+serves it; ``hvd.shutdown()`` / a new ``hvd.init()`` open the next one.
+
 Stdlib-only and jax-free to import by design: the KV server (driver
 side, before any framework init) imports :func:`compute_skew` from here.
 ``jax.profiler`` is imported where the first span opens.
@@ -65,7 +74,8 @@ import threading
 import time
 from typing import Any, Callable, Mapping
 
-from .attribution import CAT_HOST, _length, _merge
+from .attribution import (CAT_HOST, SPAN_SETUP_LOWER, SPAN_SETUP_PLACE,
+                          SPAN_SETUP_TRACE, _length, _merge)
 from .utils.env import get_float, get_int
 
 #: KV scope trace payloads ship to (``PUT /trace/<host>``).
@@ -202,7 +212,8 @@ class StepRecord:
     just async dispatch; ``ship`` marks it for posting to the KV."""
 
     __slots__ = ("step", "kind", "t_start", "spans", "dropped",
-                 "synced", "ship", "dur", "span_id", "args")
+                 "synced", "ship", "dur", "span_id", "args",
+                 "closes_setup")
 
     def __init__(self, step: int, kind: str, t_start: float,
                  span_id: int | None = None):
@@ -216,6 +227,7 @@ class StepRecord:
         self.dur: float | None = None
         self.span_id = span_id  # the step span's id: its children's parent
         self.args: Mapping | None = None  # more args for the step span
+        self.closes_setup = False  # the first warm call: the account ends
 
     def as_dict(self) -> dict:
         out = {
@@ -232,6 +244,62 @@ class StepRecord:
         return out
 
 
+class SetupAccount:
+    """The spans of one set-up, kept past the ring: a bounded list
+    (overflow is counted as ``dropped``, as the ring counts it) on the
+    tracer's clock. ``short`` counts the tracings and lowerings too brief
+    to keep as spans: ``{name: [count, seconds]}``."""
+
+    CAP = 2048
+    #: A tracing or lowering shorter than this is counted, not kept:
+    #: ``jax.numpy``'s own functions are jitted, and one first call reports
+    #: hundreds of them, every one inside the step's own tracing. A
+    #: backend compile or a cache read is always kept: one a program.
+    SHORT_S = 0.005
+    SHORT_NAMES = (SPAN_SETUP_TRACE, SPAN_SETUP_LOWER)
+
+    __slots__ = ("t0", "spans", "dropped", "short", "closed_at")
+
+    def __init__(self, t0: float):
+        self.t0 = t0
+        self.spans: list[dict] = []
+        self.dropped = 0
+        self.short: dict[str, list] = {}
+        self.closed_at: float | None = None
+
+    def keep(self, sp: dict) -> None:
+        if len(self.spans) >= self.CAP:
+            self.dropped += 1
+        else:
+            self.spans.append(sp)
+
+    def by_name(self) -> dict:
+        """Per span name: ``count``, ``total_s`` (the union of the name's
+        intervals: a nested jit's tracing inside its caller's counts
+        once), ``self_s`` (:func:`self_times`, summed) and, where some
+        were too brief to keep, ``short``."""
+        spans = list(self.spans)
+        rows: dict[str, dict] = {}
+        for sp, own in zip(spans, self_times(spans)):
+            row = rows.setdefault(
+                sp["name"], {"count": 0, "total_s": [], "self_s": 0.0})
+            row["count"] += 1
+            row["total_s"].append((sp["t"], sp["t"] + sp["dur"]))
+            row["self_s"] += own
+        for row in rows.values():
+            row["total_s"] = round(_length(_merge(row["total_s"])), 6)
+            row["self_s"] = round(row["self_s"], 6)
+        for name, (count, seconds) in self.short.items():
+            rows.setdefault(
+                name, {"count": 0, "total_s": 0.0, "self_s": 0.0})[
+                "short"] = {"count": count, "seconds": round(seconds, 6)}
+        return rows
+
+    def summary(self) -> dict:
+        return {"open": self.closed_at is None, "dropped": self.dropped,
+                "spans": list(self.spans), "by_name": self.by_name()}
+
+
 class StepTracer:
     """Per-process span recorder: a ring of the last K steps (the flight
     recorder) plus the currently open step and spans. Recording is cheap
@@ -239,7 +307,9 @@ class StepTracer:
     sampled-step sync are gated by ``HOROVOD_TRACE_SAMPLE``.
 
     ``ring_steps`` is the flight recorder's depth; ``max_spans`` caps a
-    step's spans (overflow is counted as ``dropped_spans``)."""
+    step's spans (overflow is counted as ``dropped_spans``). ``setup`` is
+    the newest :class:`SetupAccount`, open or closed, or None where none
+    was ever opened."""
 
     def __init__(self, clock_sync: ClockSync | None = None,
                  ring_steps: int = 8, max_spans: int = 64):
@@ -258,6 +328,101 @@ class StepTracer:
         # of the spans open on it: the top one is the next span's parent.
         self._ids = itertools.count(1)
         self._tls = threading.local()
+        self.setup: SetupAccount | None = None
+        self._setup: SetupAccount | None = None  # ``setup`` while open
+
+    # -- the set-up account ---------------------------------------------------
+
+    @property
+    def setup_open(self) -> bool:
+        return self._setup is not None
+
+    def open_setup(self, t0: float | None = None) -> None:
+        """Start a new set-up account at ``t0`` (now, unless the caller
+        read the clock earlier: the package's import does, at its first
+        line). An account still open is dropped for it."""
+        with self._lock:
+            self.setup = self._setup = SetupAccount(
+                self.clock.now() if t0 is None else t0)
+
+    def reopen_setup(self) -> None:
+        """A new account unless one is open: ``hvd.shutdown()`` and a new
+        ``hvd.init()`` begin the next set-up (a re-formation, a resume)."""
+        if self._setup is None:
+            self.open_setup()
+
+    def close_setup(self) -> None:
+        """Close the open account for good and journal ``setup_finished``
+        once. ``_StallWatchedStep`` marks the first call of a factory
+        step in which nothing compiled, and the end of that step closes."""
+        with self._lock:
+            account = self._setup
+            if account is None:
+                return
+            self._setup = None
+            account.closed_at = self.clock.now()
+        try:
+            from . import metrics
+
+            if metrics.journal() is not None:
+                metrics.event(
+                    "setup_finished",
+                    seconds=round(account.closed_at - account.t0, 6),
+                    spans=len(account.spans), dropped=account.dropped,
+                    by_name=account.by_name())
+        except Exception:  # noqa: BLE001 — journaling is best-effort
+            pass
+
+    def setup_summary(self) -> dict:
+        """``hvd.cache_stats()["setup"]``."""
+        account = self.setup
+        if account is None:
+            return {"open": False, "dropped": 0, "spans": [], "by_name": {}}
+        with self._lock:
+            return account.summary()
+
+    def setup_event(self, name: str, seconds: float,
+                    args: Mapping[str, Any] | None = None) -> None:
+        """``jax.monitoring`` says ``seconds`` of ``name`` ended now: keep
+        it in the open account as a span that began ``seconds`` ago, under
+        the span open on this thread. The events come innermost first, so
+        one that began before earlier ones of the same thread and parent
+        becomes their parent: a nested jit's tracing is a child of its
+        caller's, and a union or a self time counts it once. A tracing or
+        lowering under ``SetupAccount.SHORT_S`` is counted in ``short``
+        instead."""
+        account = self._setup
+        if account is None:
+            return
+        if seconds < account.SHORT_S and name in account.SHORT_NAMES:
+            with self._lock:
+                counted = account.short.setdefault(name, [0, 0.0])
+                counted[0] += 1
+                counted[1] += seconds
+            return
+        start = round(self.clock.now() - seconds, 6)
+        stack = self._stack()
+        parent = stack[-1] if stack else None
+        sp = {"name": name, "cat": CAT_HOST, "t": start,
+              "dur": round(float(seconds), 6), "id": next(self._ids)}
+        if parent is not None:
+            sp["parent"] = parent
+        if args:
+            sp["args"] = dict(args)
+        # This thread's events that no later one has taken in yet; those
+        # under a span that has closed since can no longer be.
+        waiting = []
+        for earlier in getattr(self._tls, "events", ()):
+            above = earlier.get("parent")
+            if above == parent and earlier["t"] >= start:
+                earlier["parent"] = sp["id"]
+            elif above is None or above in stack:
+                waiting.append(earlier)
+        waiting.append(sp)
+        self._tls.events = waiting
+        with self._lock:
+            if self._setup is account:
+                account.keep(sp)
 
     # -- span recording -----------------------------------------------------
 
@@ -363,9 +528,11 @@ class StepTracer:
             if self._ambient is None:
                 self._ambient = StepRecord(-1, "eager", t_start)
             target = self._ambient
-        if len(target.spans) >= self.max_spans:
+        account = self._setup
+        full = len(target.spans) >= self.max_spans
+        if full:
             target.dropped += 1
-        else:
+        if not full or account is not None:
             sp = {"name": name, "cat": cat,
                   "t": round(float(t_start), 6),
                   "dur": round(float(dur), 6)}
@@ -376,7 +543,10 @@ class StepTracer:
                     sp["parent"] = parent
             if args:
                 sp["args"] = dict(args)
-            target.spans.append(sp)
+            if not full:
+                target.spans.append(sp)
+            if account is not None:
+                account.keep(sp)
         if (target is self._ambient
                 and len(target.spans) >= self.max_spans):
             # Full ambient window: rotate it into the ring so eager-only
@@ -411,14 +581,19 @@ class StepTracer:
         with self._lock:
             if self._current is rec:
                 self._current = None
-            rec.spans.insert(0, {
+            step_span = {
                 "name": rec.kind, "cat": "step",
                 "t": round(rec.t_start, 6),
                 "dur": round(rec.dur, 6),
                 "id": rec.span_id, "step": rec.step,
                 "args": {"synced": rec.synced, **(rec.args or {})},
-            })
+            }
+            rec.spans.insert(0, step_span)
             self._ring.append(rec.as_dict())
+            if self._setup is not None:
+                self._setup.keep(step_span)
+        if rec.closes_setup:
+            self.close_setup()
         if rec.synced:
             # Where a step was blocked on, the chip holds what the step
             # holds: the memory observatory's watermark latch. Never on
@@ -551,7 +726,9 @@ class _StepScope:
 class _HostSpan:
     """A span on both legs: a profiler annotation and a ring record with
     id and parent. The hot path of every factory step: two clock reads,
-    one append under the ring's lock, nothing else."""
+    one append under the ring's lock, nothing else. ``args`` are read
+    when the span closes, so what is only known inside it can be added
+    there (:meth:`note`, :meth:`note_tree`)."""
 
     __slots__ = ("_tracer", "_name", "_args", "_annotation", "_t0", "_id",
                  "_parent")
@@ -580,6 +757,64 @@ class _HostSpan:
                                   now - self._t0, self._args, self._id,
                                   self._parent)
         return False
+
+    def note(self, **args) -> None:
+        self._args = {**(self._args or {}), **args}
+
+    def note_tree(self, tree) -> None:
+        """``leaves`` and ``bytes`` of a pytree of arrays, from shapes
+        alone: nothing is transferred or waited for."""
+        import math
+
+        import jax
+
+        leaves = [leaf for leaf in jax.tree.leaves(tree)
+                  if hasattr(leaf, "shape") and hasattr(leaf, "dtype")]
+        self.note(leaves=len(leaves), bytes=sum(
+            math.prod(leaf.shape) * getattr(leaf.dtype, "itemsize", 0)
+            for leaf in leaves))
+
+
+class _NoSpan:
+    """What :func:`setup_span` gives once the account has closed."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def note(self, **args) -> None:
+        pass
+
+    def note_tree(self, tree) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+def setup_span(name: str, args: Mapping[str, Any] | None = None):
+    """One of the ``hvd.setup.*`` spans (``attribution.SPAN_SETUP_*``): a
+    :meth:`StepTracer.host_span` while the set-up account is open, nothing
+    once it has closed, so that a function called every step too
+    (``shard_batch``) pays one attribute test for it."""
+    tracer = get_tracer()
+    return tracer.host_span(name, args) if tracer.setup_open else _NO_SPAN
+
+
+def place_span(what: str, tree):
+    """The ``hvd.setup.place`` span around one of the placing functions
+    (``replicate``, ``shard_state``, ``shard_batch``, ``shard_params``):
+    what the HOST paid to issue the copies. The functions return before
+    the copies land and nothing here waits for them; ``leaves`` and
+    ``bytes`` are read off the tree's shapes."""
+    tracer = get_tracer()
+    if not tracer.setup_open:
+        return _NO_SPAN
+    span = tracer.host_span(SPAN_SETUP_PLACE, {"what": what})
+    span.note_tree(tree)
+    return span
 
 
 def self_times(spans) -> list[float]:
