@@ -923,6 +923,14 @@ LINATTN_PAIR_KERNEL_LAST = gauge(
     "sub-blocks that fill no tile): set as the program is lowered, since "
     "the lowering platform chooses.",
     ("sub",))
+LINATTN_SCAN_KERNEL_LAST = gauge(
+    "hvd_linattn_scan_kernel_last",
+    "Heads a grid step of the Pallas kernels that run kimi_delta_rule's "
+    "solve and chunk loop in the LAST lowered program takes, 0 where that "
+    "program holds the plain form (lowered for any platform but a TPU, or "
+    "shapes that fill no tile): set as the program is lowered, since the "
+    "lowering platform chooses.",
+    ("chunk",))
 SSM_CHUNKS_LAST = gauge(
     "hvd_ssm_chunks_last",
     "Chunks a sequence that the LAST traced Mamba-2 scan (ops/ssd.py "

@@ -50,15 +50,23 @@ Attention, arXiv:2510.26692, ``flash-linear-attention``'s ``kda``). Its
 chunk form is the one above with ``gamma [C, d_k]``, but the decay now
 sits inside the contraction, ``A_ij = beta_i sum_c k_ic k_jc exp(gamma_ic -
 gamma_jc)``, so ``(k k^T) * decay`` is no more and the pair terms are
-:func:`_pair_terms`'s. The solve, the scan over chunks, the scope and the
-gauge are shared; ``hvd_linattn_decay_width_last`` says which rule the
-step that runs holds (1, or ``d_k``). Where the shapes fill a TPU's tiles
-(``d_k`` whole 128-lane blocks, ``sub`` whole sublane tiles) the pair terms
-are a primitive whose lowering the platform chooses: for a TPU
+:func:`_pair_terms`'s. The plain solve, the plain scan over chunks, the
+scope and the gauge are shared; ``hvd_linattn_decay_width_last`` says which
+rule the step that runs holds (1, or ``d_k``). Where the shapes fill a TPU's
+tiles (``d_k`` whole 128-lane blocks, ``sub`` whole sublane tiles) the pair
+terms are a primitive whose lowering the platform chooses: for a TPU
 :func:`pair_terms_kernel`'s Pallas kernel, which forms them in VMEM, with a
 backward kernel of its own; for anything else, and at any other shape, the
 plain :func:`_pair_terms` (``hvd_linattn_pair_kernel_last`` says, as the
 program is lowered, the chunks a grid step takes, or 0 for the plain form).
+Its solve and its loop over the chunks are a primitive of the same kind
+(``d_k`` and ``d_v`` whole lane blocks, the chunk a power of two of whole
+sublane tiles, eight heads a grid step): for a TPU
+:func:`chunk_scan_kernel`'s forward and backward kernels, in which a head's
+float32 state stays in VMEM over its chunks and ``(I + A)`` is solved in
+VMEM; for anything else the plain :func:`_chunk_scan`, which is the scalar
+rule's :func:`solve_unit_lower` and ``lax.scan``
+(``hvd_linattn_scan_kernel_last``: the heads a grid step, or 0).
 Its ``gamma`` is a float32 product of the chunk's lower triangle of ones
 with ``g`` at ``Precision.HIGHEST``: summed as ``jnp.cumsum`` over the rows
 of ``[C, d_k]`` it is a ``reduce-window``, which the v5e runs at a
@@ -77,6 +85,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.extend.core import Primitive
 from jax.interpreters import mlir
+from jax.interpreters import partial_eval as pe
 
 from ..attribution import SCOPE_LINATTN_SCAN
 from ..profiler import annotate_collective
@@ -535,10 +544,6 @@ def kimi_delta_rule(q, k, v, g, beta, chunk: int = 64, sub: int = 16):
         x = x.reshape((batch, count, chunk) + x.shape[2:])
         return jnp.moveaxis(x, 3, 1)
 
-    def product(spec, a, b):
-        return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
-                          preferred_element_type=f32)
-
     with annotate_collective(SCOPE_LINATTN_SCAN):
         q, k, v = chunks(q), chunks(k), chunks(v)
         beta = chunks(beta.astype(f32))[..., None]
@@ -552,31 +557,48 @@ def kimi_delta_rule(q, k, v, g, beta, chunk: int = 64, sub: int = 16):
         gamma = jnp.einsum(                                # [B, H, N, C, d_k]
             "bhnij,bhnjd->bhnid", ones, chunks(g.astype(f32)),
             precision=lax.Precision.HIGHEST, preferred_element_type=f32)
-        grow = jnp.exp(gamma)                              # from the chunk's start
-        rest = jnp.exp(gamma[..., -1:, :] - gamma)         # to its end
-
         inside, a = _pair_terms_where_lowered(q, k, gamma, sub, dtype)
-        solved = solve_unit_lower(
-            jnp.tril(beta * a, -1),
-            beta * jnp.concatenate([v.astype(f32), k * grow], -1))
-        u, w = solved[..., :d_v], solved[..., d_v:]
+        return _chunk_scan_where_lowered(q, k, v, gamma, beta, inside, a)
 
-        def one_chunk(state, xs):
-            u, w, inside, q_in, k_out, kept = xs
-            new = u - product("bhck,bhkv->bhcv", w, state)
-            out = (product("bhck,bhkv->bhcv", q_in, state)
-                   + product("bhij,bhjv->bhiv", inside, new))
-            state = kept * state + product("bhck,bhcv->bhkv", k_out, new)
-            return state, out.astype(dtype)
 
-        # as gated_delta_rule: left operands rounded once, outside the loop
-        per_chunk = (u, w.astype(dtype), inside.astype(dtype),
-                     (q * grow).astype(dtype), (k * rest).astype(dtype),
-                     jnp.exp(gamma[..., -1, :])[..., None])
-        state = jnp.zeros((batch, heads, k.shape[-1], d_v), f32)
-        _, out = lax.scan(one_chunk, state, jax.tree.map(
-            lambda x: jnp.moveaxis(x, 2, 0), per_chunk))
-        return out.transpose(1, 0, 3, 2, 4).reshape(batch, seq, heads, d_v)
+def _chunk_scan(q, k, v, gamma, beta, inside, a):
+    """The solve and the loop over the chunks of :func:`kimi_delta_rule`,
+    plain JAX differentiated by JAX: ``q``, ``k``, ``gamma`` ``[B, H, N, C,
+    d_k]``, ``v [B, H, N, C, d_v]``, ``beta [B, H, N, C, 1]`` and the pair
+    terms ``inside``, ``a`` ``[B, H, N, C, C]`` -> ``o [B, S, H, d_v]`` in
+    ``v``'s type. What any platform but a TPU runs, what the toys' widths
+    trace and what the tests hold :func:`chunk_scan_kernel` to."""
+    batch, heads, count, chunk, d_v = v.shape
+    dtype, f32 = v.dtype, jnp.float32
+
+    def product(spec, a, b):
+        return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
+                          preferred_element_type=f32)
+
+    grow = jnp.exp(gamma)                              # from the chunk's start
+    rest = jnp.exp(gamma[..., -1:, :] - gamma)         # to its end
+    solved = solve_unit_lower(
+        jnp.tril(beta * a, -1),
+        beta * jnp.concatenate([v.astype(f32), k * grow], -1))
+    u, w = solved[..., :d_v], solved[..., d_v:]
+
+    def one_chunk(state, xs):
+        u, w, inside, q_in, k_out, kept = xs
+        new = u - product("bhck,bhkv->bhcv", w, state)
+        out = (product("bhck,bhkv->bhcv", q_in, state)
+               + product("bhij,bhjv->bhiv", inside, new))
+        state = kept * state + product("bhck,bhcv->bhkv", k_out, new)
+        return state, out.astype(dtype)
+
+    # as gated_delta_rule: left operands rounded once, outside the loop
+    per_chunk = (u, w.astype(dtype), inside.astype(dtype),
+                 (q * grow).astype(dtype), (k * rest).astype(dtype),
+                 jnp.exp(gamma[..., -1, :])[..., None])
+    state = jnp.zeros((batch, heads, k.shape[-1], d_v), f32)
+    _, out = lax.scan(one_chunk, state, jax.tree.map(
+        lambda x: jnp.moveaxis(x, 2, 0), per_chunk))
+    return out.transpose(1, 0, 3, 2, 4).reshape(
+        batch, count * chunk, heads, d_v)
 
 
 def _exact(a, b):
@@ -653,6 +675,500 @@ def _solve_backward(kept, x_bar):
 solve_unit_lower.defvjp(_solve_forward, _solve_backward)
 
 
+# The chunk loop's kernels' name (not ``flash_attention``, nor the pair
+# kernels'): XLA names the custom call's instruction after it.
+SCAN_KERNEL_NAME = "kda_chunk_scan"
+SCAN_HEADS_A_STEP = 8  # of a grid step: their chains of products overlap
+
+
+def _scan_heads_a_step(k, v) -> int:
+    """The heads a grid step of :func:`chunk_scan_kernel` takes, the
+    largest divisor of the heads up to ``SCAN_HEADS_A_STEP``, where the
+    shapes fill a TPU's tiles, or 0 where they do not and the plain form is
+    traced: ``d_k`` and ``d_v`` whole 128-lane blocks of ``k``, ``v [B, H, N,
+    C, d]``, the chunk a power of two (the solve halves it) of whole
+    sublane tiles of their type, and a step's heads whole sublane tiles of
+    ``beta``'s rows."""
+    heads, chunk = k.shape[1], k.shape[3]
+    rows = 32 // min(k.dtype.itemsize, v.dtype.itemsize)
+    step = _heads_a_step(heads)
+    fills = (k.shape[-1] % 128 == 0 and v.shape[-1] % 128 == 0
+             and chunk % rows == 0 and chunk & (chunk - 1) == 0
+             and step % 8 == 0)
+    return step if fills else 0
+
+
+def _heads_a_step(heads: int) -> int:
+    return max(n for n in range(1, SCAN_HEADS_A_STEP + 1) if not heads % n)
+
+
+def _dot(left, right, dims=_NN):
+    return lax.dot_general(left, right, dims,
+                           preferred_element_type=jnp.float32)
+
+
+def _terms(x):
+    """Float32 ``x`` as the sum of three float32 terms that are each a
+    bfloat16 number, largest first: a term is the top eight significant
+    bits of what the ones before left (a mask and a subtraction a term: 24
+    bits in all, nothing rounded); a bfloat16 ``x`` is its own one term."""
+    f32, top = jnp.float32, jnp.uint32(0xFFFF0000)
+    if x.dtype == jnp.bfloat16:
+        return [x.astype(f32)]
+    terms, x = [], x.astype(f32)
+    for _ in range(2):
+        terms.append(lax.bitcast_convert_type(
+            lax.bitcast_convert_type(x, jnp.uint32) & top, f32))
+        x = x - terms[-1]
+    return terms + [x]
+
+
+def _dot32(left, right, dims=_NN):
+    """A product of float32 operands at full precision, as :func:`_exact`:
+    the six products of their bfloat16 terms that ``Precision.HIGHEST`` is
+    on a TPU (``i + j <= 2``; three where an operand is bfloat16 as it
+    stands), the smallest first, as ONE product whose contraction runs
+    over all of them: the MXU takes each operand's terms once and sums in
+    float32 (asked for by ``precision`` the kernel pays six passes, each
+    with its own float32 operands pushed and results popped). The terms are
+    laid side by side as float32 and rounded to bfloat16 together, which
+    rounds nothing."""
+    (over_left,), (over_right,) = dims[0]
+    a, b = _terms(left), _terms(right)
+    pairs = sorted(((i, j) for i in range(len(a)) for j in range(len(b))
+                    if i + j <= 2), key=lambda p: -sum(p))
+    return _dot(
+        jnp.concatenate([a[i] for i, _ in pairs], over_left).astype(
+            jnp.bfloat16),
+        jnp.concatenate([b[j] for _, j in pairs], over_right).astype(
+            jnp.bfloat16), dims)
+
+
+def _iota(shape, axis: int):
+    return lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _turned(x):
+    """A row ``[1, n]`` as the column ``[n, 1]``, or a column as the row:
+    the diagonal of its broadcast summed the other way, one term a sum."""
+    n = max(x.shape)
+    return jnp.sum(
+        jnp.where(_iota((n, n), 0) == _iota((n, n), 1), x, 0.0),
+        axis=1 if x.shape[0] == 1 else 0, keepdims=True)
+
+
+NARROW = 16  # the diagonal blocks solved a column at a time, not doubled
+
+
+def _inverses_in_vmem(matrices):
+    """``(I + A)^-1`` of each strictly lower triangular float32 ``a [C, P *
+    C]`` of ``matrices``, ``P`` heads' matrices side by side along the
+    lanes. The diagonal blocks of ``NARROW`` rows first, all of a matrix
+    at once, block ``b`` of rows on its own lanes (``[NARROW, P * C]``):
+    forward substitution a column at a time, float32 multiply-adds (``X =
+    I``, then ``X_i -= A_ij X_j`` for the rows under ``j``: fifteen steps of
+    a lane gather, a row's broadcast and a multiply-add a register). Then
+    :func:`_unit_lower_inverse`'s block doubling: with ``D`` the inverses
+    of the diagonal blocks of one width and ``A21`` the blocks between
+    neighbours, ``D - D A21 D`` holds those of twice the width (``-T22 A21
+    T11`` below each pair), a level two float32 products at full precision
+    whose right operand holds the heads' matrices down its diagonal, so
+    that the heads share the MXU's passes. Level by level over all the
+    matrices: their chains are independent and overlap."""
+    size, lanes = matrices[0].shape
+    narrow = min(NARROW, size)
+    row, col = _iota((size, lanes), 0), _iota((size, lanes), 1) & (size - 1)
+
+    def diagonal(w):  # [C, P C] -> [P C, P C], head p's matrix at (p, p)
+        if lanes == size:
+            return w
+        tall = jnp.concatenate([w] * (lanes // size), 0)
+        same = (_iota(tall.shape, 0) // size == _iota(tall.shape, 1) // size)
+        return jnp.where(same, tall, 0.0)
+
+    own = row // narrow == col // narrow  # the diagonal blocks
+    lane = _iota((narrow, lanes), 1)
+    blocks = [jnp.where(own, a, 0.0).reshape(-1, narrow, lanes).sum(0)
+              for a in matrices]
+    eye = jnp.where(_iota((narrow, lanes), 0) == lane % narrow, 1.0, 0.0)
+    solved = [eye] * len(matrices)
+    for j in range(narrow - 1):
+        solved = [x - jnp.take_along_axis(
+            under, lane - lane % narrow + j, 1) * x[j:j + 1]
+                  for x, under in zip(solved, blocks)]
+    inverses = [jnp.where(own, jnp.concatenate([x] * (size // narrow), 0),
+                          0.0) for x in solved]
+    for level in range(narrow.bit_length() - 1, size.bit_length() - 1):
+        below = ((row >> level) & 1 == 1) & (
+            (col >> level) == (row >> level) - 1)
+        halves = [_dot32(inverse, diagonal(jnp.where(below, a, 0.0)))
+                  for inverse, a in zip(inverses, matrices)]
+        inverses = [inverse - _dot32(half, diagonal(inverse))
+                    for inverse, half in zip(inverses, halves)]
+    return inverses
+
+
+def _solved_heads(beta_ref, a_ref, inverse_ref=None):
+    """``(r, beta [1, C], (I + A)^-1 [C, C])`` of every head ``r`` of a grid
+    step, ``A = tril(beta * a, -1)``: the inverses read back
+    (``inverse_ref``) or formed, as many heads side by side as fill a lane
+    block."""
+    heads, size, _ = a_ref.shape
+    first = pl.multiple_of(pl.program_id(2) * heads, heads)
+    betas = beta_ref[pl.ds(first, heads), :]
+    beta = [betas[r:r + 1, :] for r in range(heads)]
+    if inverse_ref is not None:
+        return [(r, beta[r], inverse_ref[r]) for r in range(heads)]
+    lower = _iota((size, size), 0) > _iota((size, size), 1)
+    side = math.gcd(heads, max(1, 128 // size))
+    inverses = _inverses_in_vmem([
+        jnp.concatenate([jnp.where(lower, _turned(beta[p]) * a_ref[p], 0.0)
+                         for p in range(r, r + side)], 1)
+        for r in range(0, heads, side)])
+    return [(r, beta[r],
+             inverses[r // side][:, r % side * size:(r % side + 1) * size])
+            for r in range(heads)]
+
+
+def _chunks_of_the_heads(q_ref, k_ref, v_ref, gamma_ref, inside_ref, solved,
+                         states):
+    """Every head's chunk up to the state's step, all that both kernels
+    form alike from the operands, ``solved`` (:func:`_solved_heads`) and
+    the float32 ``states`` that enter: the plain form's values at its
+    rounding points, a dict a head. ``beta`` scales the inverse's columns
+    where the plain form scales the right side's rows (``T (beta x) = (T
+    Diag(beta)) x``), so that ``v`` goes into its product as it stands: one
+    term where it is bfloat16. **A stage over all the heads, then the next
+    stage**: the heads' chains of products are independent, and the
+    compiler overlaps what it finds side by side."""
+    f32, dtype = jnp.float32, v_ref.dtype
+    heads = []
+    for (r, beta, inverse), state in zip(solved, states):
+        gamma = gamma_ref[r]
+        size = gamma.shape[0]
+        q, k = q_ref[r].astype(f32), k_ref[r].astype(f32)
+        grow = jnp.exp(gamma)
+        rest = jnp.exp(gamma[size - 1:size] - gamma)
+        heads.append(dict(
+            q=q, k=k, grow=grow, rest=rest, grown=k * grow,
+            kept=grow[size - 1:size], scaled=inverse * beta,
+            entered=state.astype(dtype), q_in=(q * grow).astype(dtype),
+            k_out=(k * rest).astype(dtype),
+            inside=inside_ref[r].astype(dtype)))
+    for (r, _, _), c in zip(solved, heads):
+        c["u"] = _dot32(c["scaled"], v_ref[r])
+        c["w"] = _dot32(c["scaled"], c["grown"])
+        c["rounded"] = c["w"].astype(dtype)
+    for c in heads:
+        c["new"] = (c["u"] - _dot(c["rounded"], c["entered"])).astype(dtype)
+    return heads
+
+
+def _scan_forward_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref, inside_ref,
+                         a_ref, o_ref, *rest):
+    """One chunk of the heads of a grid step: ``q``, ``k``, ``v``, ``gamma``
+    ``[R, C, d]``, the pair terms ``[R, C, C]``, ``beta [H, C]`` (a head a
+    row). The solve (:func:`_inverses_in_vmem` and two float32 products),
+    then :func:`_chunk_scan`'s ``one_chunk`` on the head's float32 state
+    ``[d_k, d_v]``, which ``state_ref`` keeps across the chunks. ``o`` goes
+    where it lies in ``[B, S, H * d_v]``. The last of ``rest`` but the
+    scratch, where a backward pass follows, take the state that enters the
+    chunk and the inverse."""
+    *kept_refs, state_ref = rest
+    n, h = pl.program_id(1), pl.program_id(2)
+    d_v = v_ref.shape[-1]
+
+    @pl.when(n == 0)
+    def _():
+        state_ref[h] = jnp.zeros(state_ref.shape[1:], state_ref.dtype)
+
+    # the heads' states are read together and written together: the stages
+    # between run over all the heads
+    states, solved = state_ref[h], _solved_heads(beta_ref, a_ref)
+    heads = _chunks_of_the_heads(q_ref, k_ref, v_ref, gamma_ref, inside_ref,
+                                 solved, states)
+    for (r, _, inverse), c, state in zip(solved, heads, states):
+        out = _dot(c["q_in"], c["entered"]) + _dot(c["inside"], c["new"])
+        o_ref[:, r * d_v:(r + 1) * d_v] = out.astype(o_ref.dtype)
+        for ref, value in zip(kept_refs, (state, inverse)):
+            ref[r] = value
+    state_ref[h] = jnp.stack([
+        _turned(c["kept"]) * state + _dot(c["k_out"], c["new"], _TN)
+        for c, state in zip(heads, states)])
+
+
+def _scan_backward_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref,
+                          inside_ref, a_ref, enter_ref, inverse_ref,
+                          o_bar_ref, q_bar_ref, k_bar_ref, v_bar_ref,
+                          gamma_bar_ref, beta_bar_ref, inside_bar_ref,
+                          a_bar_ref, ahead_ref):
+    """The same chunk, the chunks last to first (the index maps turn
+    them), with ``dO``: ``ahead_ref [d_k, d_v]`` a head is the cotangent of
+    the state that leaves the chunk. The entering state and the inverse come
+    from HBM, the solution and the loop's operands are formed again. The
+    solve's rule is :func:`_solve_backward`'s (``T^T dX`` and ``-tril(. X^T,
+    -1)``, float32 at full precision). Cotangents of ``dtype`` operands go
+    into their products rounded to ``dtype`` and come out of them rounded to
+    ``dtype``, as the plain form's transposed products take and give them.
+    What ``rest`` takes away from a row of ``gamma`` and gives its last is
+    one float32 number."""
+    n, h = pl.program_id(1), pl.program_id(2)
+    heads, size, d_k = k_ref.shape
+    d_v = v_ref.shape[-1]
+    dtype, f32 = v_ref.dtype, jnp.float32
+
+    @pl.when(n == 0)
+    def _():
+        ahead_ref[h] = jnp.zeros(ahead_ref.shape[1:], ahead_ref.dtype)
+
+    lower = _iota((size, size), 0) > _iota((size, size), 1)
+    last = _iota((size, d_k), 0) == size - 1
+    first = pl.multiple_of(h * heads, heads)
+
+    def given(x):  # a dtype operand's cotangent, as JAX hands it on
+        return x.astype(dtype).astype(f32)
+
+    states, aheads = enter_ref[...], ahead_ref[h]
+    solved = _solved_heads(beta_ref, a_ref, inverse_ref)
+    chunks = _chunks_of_the_heads(q_ref, k_ref, v_ref, gamma_ref, inside_ref,
+                                  solved, states)
+    # stage by stage over the heads, as the forward's
+    for (r, beta, _), c, ahead in zip(solved, chunks, aheads):
+        c["by_row"] = _turned(beta)
+        c["o_bar"] = o_bar_ref[:, r * d_v:(r + 1) * d_v].astype(dtype)
+        c["led"] = ahead.astype(dtype)
+    for (r, _, _), c in zip(solved, chunks):
+        # o = q_in entered + inside new; leaving = kept state + k_out^T new
+        c["q_in_bar"] = given(_dot(c["o_bar"], c["entered"], _NT))
+        inside_bar_ref[r] = given(_dot(c["o_bar"], c["new"], _NT))
+        c["k_out_bar"] = given(_dot(c["new"], c["led"], _NT))
+        c["new_bar"] = (given(_dot(c["inside"], c["o_bar"], _TN))
+                        + given(_dot(c["k_out"], c["led"])))
+        c["sent"] = c["new_bar"].astype(dtype)
+    leaving = []
+    for c, ahead in zip(chunks, aheads):
+        # new = u - rounded entered
+        c["w_bar"] = (-_dot(c["sent"], c["entered"], _NT)).astype(dtype)
+        leaving.append(
+            _turned(c["kept"]) * ahead
+            + given(_dot(c["q_in"], c["o_bar"], _TN))
+            + given(-_dot(c["rounded"], c["sent"], _TN)))
+    ahead_ref[h] = jnp.stack(leaving)
+    for (_, _, inverse), c in zip(solved, chunks):
+        # (I + A) [u | w] = beta [v | k grow]
+        c["v_side"] = _dot32(inverse, c["new_bar"], _TN)
+        c["k_side"] = _dot32(inverse, c["w_bar"], _TN)
+    beta_bars = []
+    for (r, _, _), c, state, ahead in zip(solved, chunks, states, aheads):
+        v_side, k_side, by_row = c["v_side"], c["k_side"], c["by_row"]
+        a_bar = jnp.where(lower, -(_dot32(v_side, c["u"], _NT)
+                                   + _dot32(k_side, c["w"], _NT)), 0.0)
+        a_bar_ref[r] = by_row * a_bar
+        beta_bars.append(_turned(
+            jnp.sum(a_bar * a_ref[r], 1, keepdims=True)
+            + jnp.sum(v_side * v_ref[r].astype(f32), 1, keepdims=True)
+            + jnp.sum(k_side * c["grown"], 1, keepdims=True)))
+        v_bar_ref[r] = (by_row * v_side).astype(v_bar_ref.dtype)
+        grown_bar = by_row * k_side
+        q_bar_ref[r] = (c["q_in_bar"] * c["grow"]).astype(q_bar_ref.dtype)
+        k_bar_ref[r] = (grown_bar * c["grow"]
+                        + c["k_out_bar"] * c["rest"]).astype(k_bar_ref.dtype)
+        # gamma's last row is in every row's rest and in kept
+        rested = c["k_out_bar"] * c["k"] * c["rest"]
+        to_last = (jnp.sum(rested, 0, keepdims=True) + c["kept"] * _turned(
+            jnp.sum(ahead * state, 1, keepdims=True)))
+        gamma_bar_ref[r] = (
+            (grown_bar * c["k"] + c["q_in_bar"] * c["q"]) * c["grow"] - rested
+            + jnp.where(last, to_last, 0.0))
+    beta_bar_ref[pl.ds(first, heads), :] = jnp.concatenate(beta_bars, 0)
+
+
+def _scan_call(kernel, operands, results, scratch, *, shape, turned, step,
+               interpret):
+    """``kernel`` over the grid ``(B, chunks, H / step)``, the heads
+    innermost, for ``shape = (B, H, N, C, d_k, d_v)``. ``operands`` and
+    ``results`` are ``(kind, array or dtype)``, a block of each kind one
+    chunk of one step's heads: ``keys`` / ``values`` / ``pairs [B, H, N, C,
+    d_k | d_v | C]`` (as :func:`kimi_delta_rule`'s ``chunks`` lays them),
+    ``rows [B, N, H, C]`` (``beta``: every head's row of the chunk, which
+    stays while the chunk lasts), ``tokens [B, S, H * d_v]`` (``o`` and its
+    cotangent, where they lie), ``states [B, N, H, d_k, d_v]``. ``turned``:
+    the chunks last to first."""
+    batch, heads, count, chunk, d_k, d_v = shape
+
+    def at(n):
+        return count - 1 - n if turned else n
+
+    def by_head(width):
+        return ((batch, heads, count, chunk, width), pl.BlockSpec(
+            (None, step, None, chunk, width),
+            lambda i, n, h: (i, h, at(n), 0, 0)))
+
+    kinds = {
+        "keys": by_head(d_k), "values": by_head(d_v), "pairs": by_head(chunk),
+        "rows": ((batch, count, heads, chunk), pl.BlockSpec(
+            (None, None, heads, chunk), lambda i, n, h: (i, at(n), 0, 0))),
+        "tokens": ((batch, count * chunk, heads * d_v), pl.BlockSpec(
+            (None, chunk, step * d_v), lambda i, n, h: (i, at(n), h))),
+        "states": ((batch, count, heads, d_k, d_v), pl.BlockSpec(
+            (None, None, step, d_k, d_v),
+            lambda i, n, h: (i, at(n), h, 0, 0))),
+    }
+    return pl.pallas_call(
+        kernel,
+        grid=(batch, count, heads // step),
+        in_specs=[kinds[kind][1] for kind, _ in operands],
+        out_specs=[kinds[kind][1] for kind, _ in results],
+        out_shape=[jax.ShapeDtypeStruct(kinds[kind][0], dtype)
+                   for kind, dtype in results],
+        scratch_shapes=[pltpu.VMEM((heads // step, step, d_k, d_v),
+                                   jnp.float32)] + scratch,
+        interpret=interpret,
+        name=SCAN_KERNEL_NAME,
+    )(*(x.reshape(kinds[kind][0]) for kind, x in operands))
+
+
+def _scan_shape(k, v):
+    return k.shape + v.shape[-1:]
+
+
+def _scan_operands(q, k, v, gamma, beta, inside, a):
+    """As :func:`_scan_call` takes the seven that both kernels read:
+    ``beta [B, H, N, C, 1]`` a head a row."""
+    return [("keys", q), ("keys", k), ("values", v), ("keys", gamma),
+            ("rows", jnp.swapaxes(beta[..., 0], 1, 2)), ("pairs", inside),
+            ("pairs", a)]
+
+
+_KEPT = ("states", "pairs")  # the entering states and the inverses
+
+
+def _scan_forward_by_kernel(*operands, states, chunk, **how):
+    k, v = operands[1:3]
+    f32 = jnp.float32
+    o, *kept = _scan_call(
+        _scan_forward_kernel, _scan_operands(*operands),
+        [("tokens", v.dtype)] + [(kind, f32) for kind in _KEPT] * states, [],
+        shape=_scan_shape(k, v), turned=False, **how)
+    return [o.reshape(_result_shape(k, v))] + kept
+
+
+def _scan_backward_by_kernel(q, k, v, gamma, beta, inside, a, entering,
+                             inverse, o_bar, *, chunk, **how):
+    f32 = jnp.float32
+    bars = list(_scan_call(
+        _scan_backward_kernel,
+        _scan_operands(q, k, v, gamma, beta, inside, a)
+        + [("states", entering), ("pairs", inverse), ("tokens", o_bar)],
+        [("keys", q.dtype), ("keys", k.dtype), ("values", v.dtype),
+         ("keys", f32), ("rows", f32), ("pairs", f32), ("pairs", f32)], [],
+        shape=_scan_shape(k, v), turned=True, **how))
+    bars[4] = jnp.swapaxes(bars[4], 1, 2)[..., None]
+    return bars
+
+
+def _result_shape(k, v):
+    batch, heads, count, chunk, d_v = v.shape
+    return batch, count * chunk, heads, d_v
+
+
+def _kept_avals(k, v):
+    """Of the float32 states that enter each chunk and of the inverses."""
+    batch, heads, count, chunk, d_k = k.shape
+    return [k.update(shape=(batch, count, heads, d_k, v.shape[-1]),
+                     dtype=jnp.float32),
+            k.update(shape=k.shape[:-1] + (chunk,), dtype=jnp.float32)]
+
+
+def _scan_forward_plain(*operands, states, **_):
+    # the plain backward differentiates the plain form and reads neither
+    k, v = operands[1:3]
+    unread = [jnp.zeros(x.shape, x.dtype) for x in _kept_avals(k, v)] * states
+    return [_chunk_scan(*operands)] + unread
+
+
+def _scan_backward_plain(*operands, **_):
+    *operands, entering, inverse, o_bar = operands
+    return jax.vjp(_chunk_scan, *operands)[1](o_bar)
+
+
+def _scan_forward_results(q, k, v, *more, states, **_):
+    return ([v.update(shape=_result_shape(k, v))]
+            + _kept_avals(k, v) * states)
+
+
+_scan_forward_p = _where_lowered(
+    "hvd_kda_chunk_scan", _scan_forward_results, _scan_forward_by_kernel,
+    _scan_forward_plain, lambda *a, **k: _scan_form(*a, **k))
+_scan_backward_p = _where_lowered(
+    "hvd_kda_chunk_scan_backward", lambda *kept, **_: list(kept[:7]),
+    _scan_backward_by_kernel, _scan_backward_plain,
+    lambda *a, **k: _scan_form(*a, **k))
+
+
+def _unread_residuals(used, eqn):
+    """The forward pass of a recomputed layer reads ``o`` alone (the
+    policy keeps nothing of this primitive): it then writes neither the
+    states nor the inverses."""
+    if eqn.params["states"] and not any(used[1:]):
+        eqn = eqn.replace(outvars=eqn.outvars[:1],
+                          params=dict(eqn.params, states=False))
+    return [any(used)] * len(eqn.invars), eqn if any(used) else None
+
+
+pe.dce_rules[_scan_forward_p] = _unread_residuals
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
+def chunk_scan_kernel(q, k, v, gamma, beta, inside, a, interpret=False):
+    """:func:`_chunk_scan` as one Pallas kernel and its backward pass as
+    another in a program lowered for a TPU (anywhere, interpreted, where
+    the tests say ``interpret``; the plain form itself on any other
+    platform), at shapes :func:`_scan_heads_a_step` accepts. The grid walks
+    a sequence's chunks in order, the heads in steps of eight innermost; a
+    head's float32 state ``[d_k, d_v]`` stays in VMEM over its chunks, the
+    solve is float32 in VMEM, and HBM sees the operands as
+    :func:`kimi_delta_rule` lays them, ``o`` as ``[B, S, H * d_v]`` and,
+    where a backward pass follows, the float32 states that enter each chunk
+    and the inverses: the backward kernel's residuals with the operands.
+    Same rounding points as the plain form. Each pass is a primitive of its
+    own (:func:`_where_lowered`), so a recomputed layer's policy sees no
+    ``pallas_call`` whose results it would keep."""
+    return _scan_forward_p.bind(q, k, v, gamma, beta, inside, a, states=False,
+                                **_scan_how(k, v, interpret))[0]
+
+
+def _scan_how(k, v, interpret):
+    return dict(step=_heads_a_step(k.shape[1]), chunk=k.shape[3],
+                interpret=interpret)
+
+
+def _scan_forward(q, k, v, gamma, beta, inside, a, interpret):
+    operands = (q, k, v, gamma, beta, inside, a)
+    o, *kept = _scan_forward_p.bind(*operands, states=True,
+                                    **_scan_how(k, v, interpret))
+    return o, operands + tuple(kept)
+
+
+def _scan_backward(interpret, kept, o_bar):
+    return tuple(_scan_backward_p.bind(
+        *kept, o_bar, **_scan_how(kept[1], kept[2], interpret)))
+
+
+chunk_scan_kernel.defvjp(_scan_forward, _scan_backward)
+
+
+def _chunk_scan_where_lowered(q, k, v, gamma, beta, inside, a):
+    """The solve and the loop by :func:`chunk_scan_kernel` where a TPU's
+    tiles are filled, so that the program lowered for a TPU holds the
+    kernels and any other the plain form; at any other shape the plain
+    form whatever the platform."""
+    if _scan_heads_a_step(k, v):
+        return chunk_scan_kernel(q, k, v, gamma, beta, inside, a)
+    _record_scan_path(0, k.shape[3])
+    return _chunk_scan(q, k, v, gamma, beta, inside, a)
+
+
 def _record_chunks(count: int, chunk: int, heads: int,
                    decay_width: int = 1) -> None:
     """At trace time, as ``models.experts._record_slots``: the step that
@@ -679,3 +1195,17 @@ def _pair_form(kernel: bool, step: int, sub: int, **_) -> None:
     and called late: no line above the rule's moves, so the positions in
     the kernels' bodies stay, ``tools/lowered_sha.py``)."""
     _record_pair_path(step if kernel else 0, sub)
+
+
+def _record_scan_path(heads_a_step: int, chunk: int) -> None:
+    """As the program is lowered (at trace time where the shapes alone
+    decide): the form of :func:`kimi_delta_rule`'s solve and chunk loop it
+    holds, the kernels' heads a grid step or 0 for the plain form."""
+    from .. import metrics
+
+    metrics.LINATTN_SCAN_KERNEL_LAST.set(heads_a_step, chunk=str(chunk))
+
+
+def _scan_form(kernel: bool, step: int, chunk: int, **_) -> None:
+    """:func:`_where_lowered`'s ``record`` for the chunk loop."""
+    _record_scan_path(step * kernel, chunk)

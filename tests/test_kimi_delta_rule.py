@@ -395,3 +395,234 @@ def test_the_scalar_rule_lowers_to_the_text_it_had(what):
     text = lowered(fn, what, q, k, v, g[..., 0], beta)
     assert "reduce_window" in text
     assert hashlib.sha256(text.encode()).hexdigest() == SCALAR_RULES_TEXT[what]
+
+
+# --- the solve and the chunk loop: ``_chunk_scan`` (plain JAX: any platform
+# but a TPU) and ``chunk_scan_kernel`` (a Pallas kernel and its backward
+# kernel: the program lowered for a TPU), interpreted here
+
+SCAN_FORMS = {"toy": (2, 4, 3, 16, 8, 16),  # B, H, chunks, C, d_k, d_v
+              "the_cells_widths": (1, 8, 2, 64, 128, 128)}
+
+
+@pytest.fixture
+def scanned(monkeypatch):
+    """The rule with the chunk loop's kernels where the lowering platform
+    would have put them, interpreted (the pair terms stay plain)."""
+    monkeypatch.setattr(
+        linear_attention, "_chunk_scan_where_lowered",
+        lambda *a: linear_attention.chunk_scan_kernel(*a, True))
+
+
+def scan_operands(form, rate, dtype, seed=6):
+    """What ``kimi_delta_rule`` hands the chunk loop, ``[B, H, N, C, ...]``
+    (``form``: a name of ``SCAN_FORMS`` or such a tuple):
+    ``q``, ``k``, ``v`` in ``dtype``, a falling float32 ``gamma``, ``beta``,
+    the pair terms of ``q``, ``k`` and ``gamma``; and a cotangent of ``o``."""
+    batch, heads, count, size, d_k, d_v = SCAN_FORMS.get(form, form)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    lead = (batch, heads, count, size)
+    q, k = (jax.random.normal(key, lead + (d_k,)) for key in keys[:2])
+    q = (q / jnp.linalg.norm(q, axis=-1, keepdims=True)
+         * d_k ** -0.5).astype(dtype)
+    k = (k / jnp.linalg.norm(k, axis=-1, keepdims=True)).astype(dtype)
+    v = jax.random.normal(keys[2], lead + (d_v,)).astype(dtype)
+    gamma = jnp.cumsum(-rate * jax.random.uniform(
+        keys[3], lead + (d_k,), minval=0.5, maxval=1.0), -2)
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], lead + (1,)))
+    inside, a = linear_attention._pair_terms(q, k, gamma, size // 4, dtype)
+    o_bar = jax.random.normal(
+        keys[5], (batch, count * size, heads, d_v)).astype(dtype)
+    return (q, k, v, gamma, beta, inside, a), o_bar
+
+
+SCAN_NAMES = ("dq", "dk", "dv", "dgamma", "dbeta", "dinside", "da")
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("rate", sorted(RATES))
+@pytest.mark.parametrize("form", sorted(SCAN_FORMS))
+def test_the_scan_kernels_are_the_plain_chunk_form(form, rate, dtype):
+    """``chunk_scan_kernel`` interpreted against ``_chunk_scan``: ``o`` and
+    the seven cotangents (with the pair kernels' own, the rule's five
+    gradients). In float32 to float32's rounding (the solve by other sums,
+    the products of three bfloat16 terms an operand). In bfloat16 the same
+    rounding points: ``o`` is the plain form's to a last bit of a few
+    entries (a moved rounding point moves every entry: 2^-9 of each), the
+    cotangents to a bfloat16 cotangent's rounding (a CPU's plain form
+    takes them unrounded into its transposed products, a TPU's and the
+    kernel round them)."""
+    dtype = jnp.dtype(dtype)
+    operands, o_bar = scan_operands(form, RATES[rate], dtype)
+    with jax.default_matmul_precision("highest"):
+        want, plain_vjp = jax.vjp(linear_attention._chunk_scan, *operands)
+        got, kernel_vjp = jax.vjp(
+            lambda *a: linear_attention.chunk_scan_kernel(*a, True),
+            *operands)
+        want_bars, got_bars = plain_vjp(o_bar), kernel_vjp(o_bar)
+    assert got.dtype == want.dtype == dtype and got.shape == want.shape
+    off = jnp.abs(got.astype(jnp.float32) - want.astype(jnp.float32))
+    scale = float(jnp.abs(want.astype(jnp.float32)).max())
+    if dtype == jnp.float32:
+        assert float(off.max()) <= 2e-6 * scale
+    else:
+        assert float(off.max()) <= 2 ** -7 * scale
+        assert float((off > 0).mean()) < 0.02
+    room = 1e-5 if dtype == jnp.float32 else 2e-2
+    for name, a, b in zip(SCAN_NAMES, got_bars, want_bars):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        assert bool(jnp.isfinite(a.astype(jnp.float32)).all()), name
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        np.testing.assert_allclose(
+            a, b, rtol=0, atol=room * float(jnp.abs(b).max()) + 1e-9,
+            err_msg=name)
+
+
+@pytest.mark.parametrize("rate", sorted(RATES))
+@pytest.mark.parametrize("chunk,sub", FORMS)
+def test_the_scan_kernels_chunk_form_is_the_recurrence(chunk, sub, rate,
+                                                       scanned):
+    args = inputs(RATES[rate])
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(recurrence)(*args)
+        got = jax.jit(rule(chunk, sub))(*args)
+    assert got.shape == (B, S, H, DV) and got.dtype == jnp.float32
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=2e-5 * float(jnp.abs(want).max()))
+
+
+@pytest.mark.parametrize("rate", sorted(RATES))
+def test_the_scan_kernels_gradients_are_the_recurrences(rate, scanned):
+    args = inputs(RATES[rate], seed=1)
+    weight = jax.random.normal(jax.random.PRNGKey(9), (B, S, H, DV))
+
+    def scalar(fn):
+        return lambda *a: jnp.sum(fn(*a) * weight)
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(jax.grad(scalar(recurrence), argnums=range(5)))(*args)
+        got = jax.jit(jax.grad(scalar(rule(32, 8)), argnums=range(5)))(*args)
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        assert bool(jnp.isfinite(a).all()), name
+        scale = float(jnp.abs(b).max())
+        np.testing.assert_allclose(a, b, rtol=0, atol=2e-5 * scale + 2e-7,
+                                   err_msg=name)
+
+
+def test_the_scan_kernels_form_no_positive_exponent(scanned, monkeypatch):
+    """At e^-20 a token, with an exponential that answers ``nan`` to any
+    positive argument: the kernels' ``exp(gamma)`` and ``exp(gamma_C -
+    gamma)`` are of nothing positive, and the rule and its five gradients
+    stay finite through them."""
+    args = inputs(RATES["e-20_a_token"])
+    exp = jnp.exp
+    monkeypatch.setattr(
+        jnp, "exp", lambda x: jnp.where(x > 0, jnp.nan, exp(x)))
+    out, grads = jax.value_and_grad(
+        lambda *a: rule(32, 8)(*a).sum(), argnums=range(5))(*args)
+    assert all(bool(jnp.isfinite(x).all()) for x in (out,) + grads)
+
+
+def test_a_grid_step_takes_two_or_eight_heads(monkeypatch):
+    """Eight heads over three chunks (a count neither step divides) in grid
+    steps of eight, of two and of one: the gauge says which as the call is
+    lowered, and no result or cotangent depends on the split beyond
+    float32's rounding (two heads' solves share the MXU's passes where they
+    lie side by side)."""
+    size = 16
+    operands, o_bar = scan_operands((1, 8, 3, size, 8, 16), 0.3, jnp.float32)
+    seen = []
+    for step in (8, 2, 1):
+        monkeypatch.setattr(linear_attention, "SCAN_HEADS_A_STEP", step)
+        got, vjp = jax.vjp(
+            lambda *a: linear_attention.chunk_scan_kernel(*a, True),
+            *operands)
+        seen.append((got,) + vjp(o_bar))
+        assert metrics.LINATTN_SCAN_KERNEL_LAST.labels(
+            chunk=str(size)).get() == step
+    for other in seen[1:]:
+        jax.tree.map(lambda a, b: np.testing.assert_allclose(
+            a, b, rtol=0, atol=1e-6 * float(jnp.abs(a).max())),
+            seen[0], other)
+    want = linear_attention._chunk_scan(*operands)
+    np.testing.assert_allclose(seen[0][0], want, rtol=0, atol=1e-6)
+
+
+def test_a_recomputed_layer_keeps_nothing_the_scan_kernels_return(scanned):
+    """Under the decoders' policy, which keeps what a ``pallas_call``
+    returns: the chunk loop is a primitive of its own, so neither ``o`` nor
+    the states that enter the chunks nor the inverses are among a layer's
+    saved residuals, and its forward pass asks for none of them (the
+    forward primitive's DCE rule): forward without, recomputed with,
+    backward."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    from horovod_tpu.models import parts
+
+    q, k, v, g, beta = inputs(0.3)
+
+    def layer(x, w):
+        x = x * w
+        return x + rule(32, 8)(q, k, x, g, beta)
+
+    recomputed = jax.checkpoint(
+        layer, policy=parts.save_kernels_and_projections)
+    kept = saved_residuals(recomputed, v, jnp.ones(DV))
+    states, inverses = (B, S // 32, H, DK, DV), (B, H, S // 32, 32, 32)
+    assert kept and all(
+        source.startswith(("from the argument", "from a constant"))
+        and aval.shape not in (states, inverses) for aval, source in kept), (
+            kept)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda x, w: recomputed(x, w).sum(), argnums=(0, 1)))(
+            v, jnp.ones(DV))
+    scans = []
+
+    def walk(eqns):
+        for eqn in eqns:
+            if eqn.primitive.name.startswith("hvd_kda_chunk_scan"):
+                scans.append((eqn.primitive.name, eqn.params.get("states")))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub.eqns)
+
+    walk(jaxpr.jaxpr.eqns)
+    assert sorted(scans, key=str) == [
+        ("hvd_kda_chunk_scan", False), ("hvd_kda_chunk_scan", True),
+        ("hvd_kda_chunk_scan_backward", None)], scans
+
+
+def test_a_shape_that_fills_no_tile_takes_the_plain_chunk_loop():
+    """The toys' widths trace the plain form whatever the platform, and the
+    gauge reads 0 at once; the cell's widths are a primitive whose lowering
+    for this platform is the plain form (0 again, as it is lowered: the
+    ``while`` is in the text and no kernel) and, interpreted, the kernels:
+    eight heads a grid step."""
+    def primitives(*operands):
+        return str(jax.make_jaxpr(
+            linear_attention._chunk_scan_where_lowered)(*operands))
+
+    gauge = metrics.LINATTN_SCAN_KERNEL_LAST
+    toy, _ = scan_operands("toy", 0.3, jnp.float32)
+    gauge.set(-1, chunk="16")
+    assert "hvd_kda_chunk_scan" not in primitives(*toy)
+    assert gauge.labels(chunk="16").get() == 0
+    wide, _ = scan_operands("the_cells_widths", 0.3, jnp.bfloat16)
+    gauge.set(-1, chunk="64")
+    assert "hvd_kda_chunk_scan" in primitives(*wide)
+    assert gauge.labels(chunk="64").get() == -1  # not yet lowered
+    text = jax.jit(linear_attention._chunk_scan_where_lowered).lower(
+        *wide).as_text()
+    assert gauge.labels(chunk="64").get() == 0
+    assert "tpu_custom_call" not in text and "while" in text
+    jax.jit(lambda *a: linear_attention.chunk_scan_kernel(*a, True)).lower(
+        *wide)
+    assert gauge.labels(chunk="64").get() == (
+        linear_attention.SCAN_HEADS_A_STEP) == 8
+    # a width of 64, seven heads or a chunk of 48 fill no tile
+    q, k, v = wide[:3]
+    assert not linear_attention._scan_heads_a_step(k[..., :64], v)
+    assert not linear_attention._scan_heads_a_step(k, v[..., :64])
+    assert not linear_attention._scan_heads_a_step(k[:, :7], v[:, :7])
+    assert not linear_attention._scan_heads_a_step(k[:, :, :, :48],
+                                                   v[:, :, :, :48])

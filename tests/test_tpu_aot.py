@@ -339,11 +339,19 @@ def test_kimis_pair_kernels_compile_at_its_widths(one_chip):
         wide, wide, wide, shaped(1, 8192, 32, 128, dtype=jnp.float32),
         shaped(1, 8192, 32, dtype=jnp.float32)).compile()
     assert metrics.LINATTN_PAIR_KERNEL_LAST.labels(sub="16").get() == 8
+    assert metrics.LINATTN_SCAN_KERNEL_LAST.labels(chunk="64").get() == 8
     text = compiled.as_text()
     kernels = kernel_instructions(text)
-    assert len(kernels) == 2, kernels
-    assert all(name.startswith(linear_attention.PAIR_KERNEL_NAME + ".")
-               and "flash_attention" not in name for name, _ in kernels)
+    # since PR 51 the solve and the chunk loop are two kernels of their own
+    # beside them (``chunk_scan_kernel``, ``kda_chunk_scan``), under the
+    # same scope: no ``while`` is left in the rule
+    for kernel in (linear_attention.PAIR_KERNEL_NAME,
+                   linear_attention.SCAN_KERNEL_NAME):
+        assert len([name for name, _ in kernels
+                    if name.startswith(kernel + ".")]) == 2, kernels
+    assert len(kernels) == 4 and all(
+        "flash_attention" not in name for name, _ in kernels), kernels
+    assert " while(" not in text
     scopes = profiler.instruction_scopes(text)
     assert {profiler.phase_of(scopes[name]) for name, _ in kernels} == {
         "hvd.linattn.scan"}
@@ -362,6 +370,7 @@ def test_kimis_pair_kernels_compile_at_its_widths(one_chip):
     beta = jax.nn.sigmoid(jax.random.normal(keys[4], (1, 128, 2)))
     lowered = grads.lower(q, k, v, g, beta)
     assert metrics.LINATTN_PAIR_KERNEL_LAST.labels(sub="16").get() == 0
+    assert metrics.LINATTN_SCAN_KERNEL_LAST.labels(chunk="64").get() == 0
     assert "tpu_custom_call" not in lowered.as_text()
     assert re.search(r"4x64x128x", lowered.as_text())  # k_right is there
     assert all(bool(jnp.isfinite(x.astype(jnp.float32)).all())
@@ -405,13 +414,52 @@ def test_a_recomputed_layer_forms_kimis_pair_terms_again(one_chip):
         shaped(1, 2048, 1024), shaped(1024, 4096),
         shaped(1024, 4096)).compile().as_text()
     kernels = kernel_instructions(text)
-    assert all(name.startswith(linear_attention.PAIR_KERNEL_NAME + ".")
-               for name, _ in kernels), kernels
-    passes = [("recomputed" if "rematted_computation" in op_name else
-               "backward" if "transpose(" in op_name else "forward")
-              for _, op_name in kernels]
-    assert sorted(passes) == sorted(
-        ["forward", "recomputed", "backward"] * 2), kernels
+    # the chunk loop's kernels (PR 51) beside the pair terms', alike
+    for kernel in (linear_attention.PAIR_KERNEL_NAME,
+                   linear_attention.SCAN_KERNEL_NAME):
+        passes = [("recomputed" if "rematted_computation" in op_name else
+                   "backward" if "transpose(" in op_name else "forward")
+                  for name, op_name in kernels
+                  if name.startswith(kernel + ".")]
+        assert sorted(passes) == sorted(
+            ["forward", "recomputed", "backward"] * 2), kernels
+    assert len(kernels) == 12, kernels
+
+
+def test_kimi_linears_step_holds_the_chunk_loops_kernels_three_a_layer(
+        one_chip):
+    """The Kimi Linear cell's train step as ``benchmark/aot.py`` builds it,
+    lowered (not compiled) for the described ``v5e:1x1``: every layer is
+    recomputed, so each of the four Kimi Delta Attention layers holds the
+    chunk loop's kernels three times (forward without residuals,
+    recomputed with them, backward) beside the pair terms' three and the
+    latent attention layer's three flash kernels: 3 + 12 + 12 custom
+    calls, and the gauge reads eight heads a grid step."""
+    import sys
+
+    import horovod_tpu as hvd
+    from horovod_tpu import metrics
+    from horovod_tpu.ops import linear_attention
+
+    sys.path.insert(0, os.path.join(REPO_ROOT, "tools"))
+    try:
+        import lowered_sha
+    finally:
+        sys.path.remove(os.path.join(REPO_ROOT, "tools"))
+    metrics.LINATTN_SCAN_KERNEL_LAST.set(-1, chunk="64")
+    try:
+        text = lowered_sha.lowered_text("kimi-linear-48b-a3b_s8192_e8_dp1")
+    finally:
+        hvd.shutdown()
+        hvd.init()
+    names = re.findall(r'kernel_name = "([^"]*)"', text)
+    assert sorted(set(names)) == sorted([
+        "flash_attention", linear_attention.PAIR_KERNEL_NAME,
+        linear_attention.SCAN_KERNEL_NAME]), set(names)
+    assert {name: names.count(name) for name in set(names)} == {
+        "flash_attention": 3, linear_attention.PAIR_KERNEL_NAME: 12,
+        linear_attention.SCAN_KERNEL_NAME: 12}
+    assert metrics.LINATTN_SCAN_KERNEL_LAST.labels(chunk="64").get() == 8
 
 
 @pytest.mark.parametrize("rows, seq, groups", [(24, 512, (2, 1)),

@@ -27,7 +27,11 @@ the plain form on the chip, checked and timed at the cell's shape
 runs that alone); and the Mamba-2 scan's two kernels against the plain
 chunk form and the float32 recurrence at Granite's and Nemotron-H's mixer
 shapes, checked and timed (``check_ssd``; ``python
-tools/chip_kernel_check.py ssd`` runs that alone). Compiled, never ``interpret=True``: off a TPU this exits
+tools/chip_kernel_check.py ssd`` runs that alone); and Kimi Delta
+Attention's solve and chunk loop, the two kernels against the plain form
+and the float32 recurrence at the cell's shape, checked and timed
+(``check_kda_scan``; ``python tools/chip_kernel_check.py kda_scan`` runs
+that alone). Compiled, never ``interpret=True``: off a TPU this exits
 non-zero.
 
 The tolerance is the one ``tests/test_sequence_parallel.py`` uses for bf16
@@ -347,6 +351,133 @@ def check_pair_terms(heads=32, chunks=128, size=64, sub=16, width=128,
                   f"at [1, {heads}, {chunks}, {size}, {width}]")
 
 
+def check_kda_scan(heads=32, chunks=128, size=64, sub=16, width=128,
+                   calls=20) -> None:
+    """Kimi Delta Attention's solve and chunk loop at the Kimi Linear
+    cell's shape (one sequence of 8,192 = 128 chunks of 64, 32 heads of
+    128, bfloat16 with float32 decays): the rule as the TPU's program
+    holds it (``ops.linear_attention.chunk_scan_kernel``'s two kernels
+    behind the pair kernels) and the rule with the plain ``_chunk_scan`` in
+    their place, both against the float32 token-by-token recurrence, ``o``
+    and the five gradients under a random cotangent, at the initial draw's
+    decays and at 1.6 a token; then ``chunk_scan_kernel`` and
+    ``_chunk_scan`` timed alone on the same operands, forward alone and
+    forward with backward, ``calls`` dispatched back to back with only the
+    last result kept. The VLIW bundles a grid step come from the sandbox's
+    compile (``--xla_jf_dump_to``: PERF.md), not from here."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+
+    from horovod_tpu.ops import linear_attention
+
+    f32, dtype = jnp.float32, jnp.bfloat16
+    seq = chunks * size
+
+    def recurrence(q, k, v, g, beta):
+        """Token by token in float32; 128 tokens at a time under
+        ``jax.checkpoint``, so that its gradient keeps a state every 128
+        tokens and not all 8,192 of them (17 GB)."""
+        def one_token(state, xs):
+            q, k, v, g, beta = xs
+            state = jnp.exp(g)[..., None] * state
+            seen = jnp.einsum("bhkv,bhk->bhv", state, k)
+            state = state + jnp.einsum(
+                "bhk,bhv->bhkv", beta[..., None] * k, v - seen)
+            return state, jnp.einsum("bhkv,bhk->bhv", state, q)
+
+        @jax.checkpoint
+        def some_tokens(state, xs):
+            return lax.scan(one_token, state, xs)
+
+        start = jnp.zeros((1, heads, width, width), f32)
+        out = lax.scan(some_tokens, start, jax.tree.map(
+            lambda t: jnp.moveaxis(t.astype(f32), 1, 0).reshape(
+                (-1, 128) + t.shape[:1] + t.shape[2:]),
+            (q, k, v, g, beta)))[1]
+        return jnp.moveaxis(out.reshape((-1,) + out.shape[2:]), 0, 1)
+
+    def rule(*t):
+        return linear_attention.kimi_delta_rule(*t, chunk=size, sub=sub)
+
+    def plain_rule(*t):
+        lowered = linear_attention._chunk_scan_where_lowered
+        linear_attention._chunk_scan_where_lowered = (
+            linear_attention._chunk_scan)
+        try:
+            return rule(*t)
+        finally:
+            linear_attention._chunk_scan_where_lowered = lowered
+
+    def both(form):
+        def run(*t):
+            out, vjp = jax.vjp(form, *t[:-1])
+            return (out,) + vjp(t[-1].astype(out.dtype))
+        return jax.jit(run)
+
+    def timed(name, what, fn, *args):
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(calls):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        print(f"  {name}, {what}: "
+              f"{(time.perf_counter() - t0) / calls * 1e3:.3f} ms a call")
+
+    keys = jax.random.split(jax.random.PRNGKey(11), 6)
+    q, k = (jax.random.normal(key, (1, seq, heads, width)) for key in keys[:2])
+    q = (q / jnp.linalg.norm(q, axis=-1, keepdims=True)
+         * width ** -0.5).astype(dtype)
+    k = (k / jnp.linalg.norm(k, axis=-1, keepdims=True)).astype(dtype)
+    v = jax.random.normal(keys[2], (1, seq, heads, width)).astype(dtype)
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (1, seq, heads)))
+    o_bar = jax.random.normal(keys[5], v.shape).astype(dtype)
+    forms = {"recurrence": recurrence, "plain": plain_rule, "kernel": rule}
+    for rate in (0.05, 1.6):
+        g = -rate * jax.random.uniform(keys[3], q.shape, minval=0.5,
+                                       maxval=1.0)
+        args = (q, k, v, g, beta)
+        got = {name: [np.asarray(t, np.float32)
+                      for t in both(form)(*args, o_bar)]
+               for name, form in forms.items()}
+        print(f" kimi_delta_rule at [1, {seq}, {heads}, {width}], chunk "
+              f"{size}, {rate} a token:")
+        for n, name in enumerate(("o", "dq", "dk", "dv", "dg", "dbeta")):
+            want = got["recurrence"][n]
+            scale = float(np.abs(want).max())
+            off = {form: float(np.abs(got[form][n] - want).max())
+                   for form in ("plain", "kernel")}
+            apart = float(np.abs(got["kernel"][n] - got["plain"][n]).max())
+            print(f"  {name}: max |kernel - recurrence| {off['kernel']:.3e}, "
+                  f"|plain - recurrence| {off['plain']:.3e}, |kernel - plain| "
+                  f"{apart:.3e} (recurrence max {scale:.3g})")
+            assert np.isfinite(got["kernel"][n]).all(), name
+            np.testing.assert_allclose(
+                got["kernel"][n], want, rtol=0,
+                atol=max(BF16_TOL * scale, 2 * off["plain"]), err_msg=name)
+    for name in ("plain", "kernel"):
+        timed(name + " rule", "forward", jax.jit(forms[name]), *args)
+        timed(name + " rule", "forward and backward", both(forms[name]),
+              *args, o_bar)
+
+    def chunked(x):  # as kimi_delta_rule's chunks
+        return jnp.moveaxis(x.reshape((1, chunks, size) + x.shape[2:]), 3, 1)
+
+    operands = [chunked(x) for x in (q, k, v)]
+    gamma = jnp.cumsum(chunked(g), -2)
+    operands += [gamma, chunked(beta)[..., None],
+                 *linear_attention.pair_terms_kernel(
+                     *operands[:2], gamma, sub, dtype)]
+    for name, form in (("_chunk_scan", linear_attention._chunk_scan),
+                       ("chunk_scan_kernel",
+                        linear_attention.chunk_scan_kernel)):
+        timed(name, "forward", jax.jit(form), *operands)
+        timed(name, "forward and backward", both(form), *operands, o_bar)
+
+
 def check_ssd(calls=20, cells=(("nemotron-h", 8192, 8, 128),
                               ("granite", 4096, 1, 256))) -> None:
     """``ops.ssd.ssd_scan_kernel`` against ``_chunk_form`` and against the
@@ -540,6 +671,7 @@ def main() -> None:
           f"count={len(jax.devices())}")
     alone = {"two_widths": check_two_widths,  # ~2 minutes
              "pair_terms": check_pair_terms,
+             "kda_scan": check_kda_scan,
              "ssd": check_ssd}
     if len(sys.argv) == 2 and sys.argv[1] in alone:
         alone[sys.argv[1]]()
@@ -558,6 +690,7 @@ def main() -> None:
     check_tiles_as_they_lie()
     check_two_widths()
     check_pair_terms()
+    check_kda_scan()
     check_ssd()
     print("kernels ok")
 
