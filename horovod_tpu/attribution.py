@@ -140,6 +140,18 @@ SCOPE_ATTN_BLOCKDIFF = "attn.blockdiff"
 #: 128), placed as ``attn.window`` is and no phase either: it tells the
 #: latent layer's kernels from any other's in the step's text.
 SCOPE_ATTN_MLA = "attn.mla"
+#: The rotary split of latent attention (``models/latent.py``, the one
+#: module that opens it): the turn of the queries' and the shared key's
+#: rotary lanes, that key's broadcast over the heads and its join with the
+#: lanes that are not turned. A phase: its time is neither a projection's
+#: (``block.attn_proj``, which surrounds it) nor a kernel's.
+SCOPE_MLA_ROPE = "mla.rope"
+#: Around everything a multi-token-prediction module adds to a step
+#: (``models/joyai_flash.py``: its norms, its joining projection, its
+#: decoder layer, its pass through the shared head and its loss term). No
+#: phase and no block (the phases and blocks inside keep their owners): it
+#: tells the module's operations from the main stack's in the step's text.
+SCOPE_MTP = "mtp"
 #: JAX's own name-stack component for the forward operations that a
 #: ``jax.checkpoint`` (``nn.remat``) runs again inside the backward pass:
 #: ``transpose(jvp(...))/rematted_computation/...``. Not a scope this
@@ -179,7 +191,7 @@ PHASE_SCOPE_NAMES = tuple(SCOPE_PREFIX + name for name in (
     SCOPE_MOE_ROUTE, SCOPE_MOE_DISPATCH, SCOPE_MOE_EXPERTS,
     SCOPE_MOE_COMBINE, SCOPE_LINATTN_CONV, SCOPE_LINATTN_SCAN,
     SCOPE_LINATTN_GATE, SCOPE_SSM_CONV, SCOPE_SSM_SCAN, SCOPE_SSM_GATE,
-    SCOPE_MOE_SHARED))
+    SCOPE_MOE_SHARED, SCOPE_MLA_ROPE))
 #: Block scopes: the parts of a model (``models/*.py``) that no phase
 #: names, forward and backward. A phase inside a block stays the phase's
 #: (``profiler.owner_of``: the innermost phase scope, else the innermost

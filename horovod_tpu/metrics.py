@@ -994,6 +994,20 @@ ATTN_HEAD_WIDTHS_LAST = gauge(
     "every model here but latent attention (192 and 128): set at trace "
     "time, beside hvd_attn_tiles_last.",
     ("kind",))
+MLA_ROPE_LANES_LAST = gauge(
+    "hvd_mla_rope_lanes_last",
+    "Lanes of a head's queries and keys in the LAST traced latent-attention "
+    "layer with a rotary split (models/latent.py): kind=rotated the lanes "
+    "RoPE turns (the queries' last qk_rope_head_dim and the one shared "
+    "key's), kind=kept the lanes beside them that are not turned: set at "
+    "trace time, as hvd_attn_tiles_last is.",
+    ("kind",))
+MTP_DEPTH_LAST = gauge(
+    "hvd_mtp_depth_last",
+    "Multi-token-prediction modules in the LAST traced model that has them "
+    "(models/joyai_flash.py: num_nextn_predict_layers, each one more decoder "
+    "layer scored by the main model's head against a further token): set at "
+    "trace time, as hvd_attn_tiles_last is.")
 HEAD_LOGITS_BYTES_LAST = gauge(
     "hvd_head_logits_bytes_last",
     "Bytes of the logits that the LAST traced token cross entropy "

@@ -55,3 +55,10 @@ from .nemotron_h import (  # noqa: F401
     NemotronH,
     NemotronHConfig,
 )
+from .joyai_flash import (  # noqa: F401
+    JOYAI_FLASH_TINY,
+    JOYAI_LLM_FLASH,
+    JoyAIFlash,
+    JoyAIFlashConfig,
+    mtp_lm_loss,
+)

@@ -1,13 +1,15 @@
 """The one experts module of the mixtures of experts here (``Olmoe``,
-``SmallThinker``, ``Sdar``, ``KimiLinear``, ``NemotronH``), owned by none of
-them: a model's window of the experts in ``parallel/moe.py``'s capacity
-slots, the auxiliary losses of a routing group, and what reads or cuts a
-model by its experts (:func:`routing_stats`, :func:`take_expert_window`).
+``SmallThinker``, ``Sdar``, ``KimiLinear``, ``NemotronH``, ``JoyAIFlash``),
+owned by none of them: a model's window of the experts in
+``parallel/moe.py``'s capacity slots, the auxiliary losses of a routing
+group, and what reads or cuts a model by its experts
+(:func:`routing_stats`, :func:`take_expert_window`).
 """
 
 from __future__ import annotations
 
 import dataclasses
+from collections.abc import Mapping
 from typing import Any, Callable
 
 import flax.linen as nn
@@ -54,7 +56,9 @@ class SparseExperts(nn.Module):
     expert's where the config's ``intermediate_size`` is a dense layer's.
     An expert is three matrices, ``down(activation(gate x) * up x)``, or
     with ``gated=False`` two, ``down(activation(up x))``, and then the tree
-    has no ``experts_gate``."""
+    has no ``experts_gate``. ``selection_bias`` (float32
+    ``[num_experts]``, no leaf) is ``route_to_capacity``'s: added to the
+    sigmoid scores for the choice alone."""
 
     config: Any
     activation: Callable = jax.nn.silu
@@ -64,6 +68,7 @@ class SparseExperts(nn.Module):
     gate_scale: float = 1.0
     width: int | None = None
     gated: bool = True
+    selection_bias: Any = None
 
     @nn.compact
     def __call__(self, x, logits=None):
@@ -100,7 +105,8 @@ class SparseExperts(nn.Module):
                 tokens.astype(cfg.dtype), logits, cfg.num_experts, capacity,
                 top_k=cfg.top_k, first_expert=cfg.first_expert,
                 experts_here=here, gates_over_picks=self.gates_over_picks,
-                scores=self.scores, gate_scale=self.gate_scale)
+                scores=self.scores, gate_scale=self.gate_scale,
+                selection_bias=self.selection_bias)
             back = expert_ffn(
                 *(w.astype(cfg.dtype) for w in weights), send[..., :hidden],
                 activation=self.activation)
@@ -189,12 +195,11 @@ def take_expert_window(params, share):
     embedding and head are every window's alike."""
     first, last = share.first_expert, share.first_expert + share.experts_held
     out = dict(params)
-    for i in range(share.num_layers):
-        layer = dict(params[f"layer_{i}"])
-        if "moe" not in layer:  # a dense layer: every window's alike
+    for at, layer in params.items():
+        # a leaf, or a dense layer: every window's alike
+        if not isinstance(layer, Mapping) or "moe" not in layer:
             continue
-        layer["moe"] = {
+        out[at] = dict(layer, moe={
             name: leaf[first:last] if name.startswith("experts_") else leaf
-            for name, leaf in layer["moe"].items()}
-        out[f"layer_{i}"] = layer
+            for name, leaf in layer["moe"].items()})
     return out

@@ -22,10 +22,11 @@ first layer's feed-forward is dense, every later one's is the experts.
   RMSNorm over each head's output (one learned scale, shared by the heads)
   times ``sigmoid(x W_ga W_gb)``, a low-rank pair again, and the output
   projection.
-* **Latent attention** (MLA, ``q_lora_rank`` null, ``mla_use_nope``): the
-  queries come straight from the input, 192 lanes a head; the keys and
-  values come through a latent of ``kv_lora_rank`` with its RMSNorm, 128
-  lanes each a head, and the keys are completed by the 64 lanes of ``k_r``
+* **Latent attention** (``models/latent.py``; ``q_lora_rank`` null,
+  ``mla_use_nope``): the queries come straight from the input, 192 lanes a
+  head; the keys and values come through a latent of ``kv_lora_rank`` with
+  its RMSNorm, 128 lanes each a head, and the keys are completed by the 64
+  lanes of ``k_r``
   that all heads read alike. **No rotary embedding is applied** (the
   recurrent layers order the tokens), so in training it is attention whose
   scores contract over 192 lanes and whose context is 128 wide; nothing is
@@ -65,8 +66,9 @@ from ..attribution import (SCOPE_BLOCK_ATTN_PROJ, SCOPE_BLOCK_EMBED,
 from ..ops.linear_attention import kimi_delta_rule, short_conv
 from ..profiler import annotate_collective
 from .experts import ExpertWindow, SparseExperts
+from .latent import LatentAttention
 from .loss import token_cross_entropy
-from .parts import (GatedMLP, RMSNorm, decay_rate, dense_causal_attention,
+from .parts import (GatedMLP, RMSNorm, decay_rate,
                     head_major_flash_attention, l2norm, projection,
                     recomputed, step_bias, untied_head)
 
@@ -182,37 +184,6 @@ class KimiDeltaAttention(nn.Module):
                 * jax.nn.sigmoid(gate.astype(f32)).reshape(out.shape)
             out = out.astype(cfg.dtype).reshape(x.shape[:2] + (-1,))
         return projection(cfg, cfg.hidden_size, "out")(out)
-
-
-class LatentAttention(nn.Module):
-    """``attention_fn(q [B, S, H, 192], k [B, S, H, 192], v [B, S, H, 128],
-    dtype)`` returns the context ``[B, S, H, 128]``."""
-    config: KimiLinearConfig
-    attention_fn: Callable | None = None
-
-    @nn.compact
-    def __call__(self, x):
-        cfg = self.config
-        heads, nope, v_dim = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
-                              cfg.v_head_dim)
-        rows = x.shape[:2]
-        q = projection(cfg, heads * cfg.qk_head_dim, "query")(x).reshape(
-            rows + (heads, cfg.qk_head_dim))
-        latent = projection(cfg, cfg.kv_lora_rank + cfg.qk_rope_head_dim,
-                            "kv_a")(x)
-        shared = latent[..., cfg.kv_lora_rank:]  # k_r, every head's alike
-        up = projection(cfg, heads * (nope + v_dim), "kv_b")(
-            RMSNorm(cfg.rms_norm_eps, name="kv_norm")(
-                latent[..., :cfg.kv_lora_rank]).astype(cfg.dtype)).reshape(
-                    rows + (heads, nope + v_dim))
-        k = jnp.concatenate([
-            up[..., :nope], jnp.broadcast_to(
-                shared[:, :, None], rows + (heads, cfg.qk_rope_head_dim))],
-            -1)
-        attend = self.attention_fn or dense_causal_attention
-        out = attend(q, k, up[..., nope:], cfg.dtype)
-        return projection(cfg, cfg.hidden_size, "out")(
-            out.reshape(rows + (heads * v_dim,)))
 
 
 class DecoderLayer(nn.Module):

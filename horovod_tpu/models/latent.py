@@ -1,0 +1,155 @@
+"""Latent attention (MLA, DeepSeek-V2/V3's), owned by no decoder: the
+keys and values of every head come through one low-rank latent a token, and
+the keys are completed by a few lanes that all heads read alike.
+
+``h`` the normalised input, ``H`` heads, ``n`` = ``qk_nope_head_dim``, ``r``
+= ``qk_rope_head_dim``, ``v`` = ``v_head_dim``::
+
+    q = h W_q                                   [S, H, n + r]   (direct), or
+    q = RMSNorm(h W_qa) W_qb                    (``q_lora_rank``: low-rank)
+    [c | k_r] = h W_kva                         (kv_lora_rank | r)
+    [k_n | v] = RMSNorm(c) W_kvb                [S, H, n | v]
+    k = [k_n | k_r], the one k_r in every head
+    o = softmax(causal(q k^T (n + r)^-1/2)) v;  out = concat(o) W_o
+
+**The rotary split** (``rope_theta``): the last ``r`` lanes of every head's
+query and the one ``k_r`` are turned by RoPE at the token's position, the
+``n`` lanes before them are not. The pairs are the source's interleaved ones
+(``rope_interleave``): lanes ``(2i, 2i + 1)`` by the angle ``t * theta ** (-2i
+/ r)``. The source de-interleaves the lanes first and then turns half
+against half; that is the same rotation followed by one fixed permutation of
+the ``r`` lanes of ``q`` and ``k`` alike, which no score sees, so here the
+lanes stay where the projections wrote them. A head is turned whole, ``x *
+cos + (x P) * sin`` with ones and zeros in the tables of the ``n`` lanes that
+stay and ``P`` the pairs' swap **as a product on the MXU** (exact: one 1 a
+column; a swap of neighbouring lanes by slices or a reshape to pairs would
+re-tile the array), so that nothing is cut out of a head and joined again.
+Without ``rope_theta`` nothing is turned (Kimi Linear's ``mla_use_nope``:
+its recurrent layers order the tokens).
+
+In training nothing is absorbed or cached: it is ``H``-head causal attention
+whose scores contract over ``n + r`` lanes and whose context is ``v`` wide,
+through ``attention_fn=`` (``parts.head_major_flash_attention``: the
+multi-tile kernels at two widths, under ``hvd.attn.mla``).
+
+``models/kimi_linear.py`` and ``models/joyai_flash.py`` call it; it imports
+``parts`` and no decoder (``tests/test_decoder_imports.py``).
+"""
+
+from __future__ import annotations
+
+from typing import Any, Callable
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..attribution import SCOPE_MLA_ROPE
+from ..profiler import annotate_collective
+from .parts import RMSNorm, dense_causal_attention, projection
+
+
+def interleaved_pairs(lanes: int):
+    """``(partner, sign, frequency)`` of each of the ``lanes`` rotary lanes
+    in the source's interleaved layout: lane ``2i`` pairs with ``2i + 1``,
+    both at frequency ``i``; the turned lane is ``x * cos + sign *
+    x[partner] * sin``."""
+    lane = np.arange(lanes)
+    return lane ^ 1, np.where(lane % 2 == 0, -1.0, 1.0), lane // 2
+
+
+def rotary_split_tables(kept: int, lanes: int, theta: float, seq: int):
+    """``(cos, sin [S, kept + lanes], swap [kept + lanes, kept + lanes])``
+    in float32 for :func:`turn`: ones, zeros and no partner on the ``kept``
+    lanes; on the ``lanes`` after them the pairs' cosine, signed sine and
+    swap at positions ``0..S-1``."""
+    partner, sign, frequency = interleaved_pairs(lanes)
+    inv_freq = 1.0 / theta ** (jnp.asarray(2 * frequency, jnp.float32)
+                               / lanes)  # the source's arithmetic
+    angle = jnp.arange(seq, dtype=jnp.float32)[:, None] * inv_freq
+    cos = jnp.concatenate([jnp.ones((seq, kept), jnp.float32),
+                           jnp.cos(angle)], -1)
+    sin = jnp.concatenate([jnp.zeros((seq, kept), jnp.float32),
+                           jnp.sin(angle) * sign.astype(np.float32)], -1)
+    swap = np.zeros((kept + lanes, kept + lanes), np.float32)
+    swap[kept + partner, kept + np.arange(lanes)] = 1.0
+    return cos, sin, swap
+
+
+def turn(x, cos, sin, swap, dtype):
+    """``x [B, S, ..., D]`` turned at its positions, as ``dtype``: ``x * cos
+    + (x swap) * sin`` in float32, the tables ``[S, D]`` broadcast over what
+    lies between the positions and the lanes."""
+    between = (1,) * (x.ndim - 3)
+    cos, sin = (t.reshape(t.shape[:1] + between + t.shape[1:])
+                for t in (cos, sin))
+    swapped = jnp.dot(x, jnp.asarray(swap, x.dtype),
+                      precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=x.dtype)
+    return (x.astype(jnp.float32) * cos
+            + swapped.astype(jnp.float32) * sin).astype(dtype)
+
+
+def _record_lanes(rotated: int, kept: int) -> None:
+    """At trace time, as ``experts._record_slots`` does for the slots."""
+    from .. import metrics
+
+    metrics.MLA_ROPE_LANES_LAST.set(rotated, kind="rotated")
+    metrics.MLA_ROPE_LANES_LAST.set(kept, kind="kept")
+
+
+class LatentAttention(nn.Module):
+    """``attention_fn(q [B, S, H, n + r], k [B, S, H, n + r], v [B, S, H,
+    v], dtype)`` returns the context ``[B, S, H, v]``. ``config`` is the
+    model's (``hidden_size``, ``num_attention_heads``, ``kv_lora_rank``,
+    ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``,
+    ``rms_norm_eps``, ``dtype``). ``q_lora_rank`` None: the queries straight
+    from the input (leaf ``query``); a rank: through ``q_a``, ``q_norm`` and
+    ``q_b``. ``rope_theta`` None: no lane is turned."""
+    config: Any
+    attention_fn: Callable | None = None
+    q_lora_rank: int | None = None
+    rope_theta: float | None = None
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        heads, nope, rope, v_dim = (
+            cfg.num_attention_heads, cfg.qk_nope_head_dim,
+            cfg.qk_rope_head_dim, cfg.v_head_dim)
+        rows = x.shape[:2]
+        if self.q_lora_rank is None:
+            q = projection(cfg, heads * (nope + rope), "query")(x)
+        else:
+            q = projection(cfg, heads * (nope + rope), "q_b")(
+                RMSNorm(cfg.rms_norm_eps, name="q_norm")(
+                    projection(cfg, self.q_lora_rank, "q_a")(x)).astype(
+                        cfg.dtype))
+        q = q.reshape(rows + (heads, nope + rope))
+        latent = projection(cfg, cfg.kv_lora_rank + rope, "kv_a")(x)
+        shared = latent[..., cfg.kv_lora_rank:]  # k_r, every head's alike
+        up = projection(cfg, heads * (nope + v_dim), "kv_b")(
+            RMSNorm(cfg.rms_norm_eps, name="kv_norm")(
+                latent[..., :cfg.kv_lora_rank]).astype(cfg.dtype)).reshape(
+                    rows + (heads, nope + v_dim))
+
+        def keys(shared):
+            return jnp.concatenate([
+                up[..., :nope], jnp.broadcast_to(
+                    shared[:, :, None], rows + (heads, rope))], -1)
+
+        if self.rope_theta is None:
+            k = keys(shared)
+        else:
+            _record_lanes(rope, nope)
+            with annotate_collective(SCOPE_MLA_ROPE):
+                cos, sin, swap = rotary_split_tables(
+                    nope, rope, self.rope_theta, rows[1])
+                q = turn(q, cos, sin, swap, cfg.dtype)
+                k = keys(turn(shared, cos[:, nope:], sin[:, nope:],
+                              swap[nope:, nope:], cfg.dtype))
+        attend = self.attention_fn or dense_causal_attention
+        out = attend(q, k, up[..., nope:], cfg.dtype)
+        return projection(cfg, cfg.hidden_size, "out")(
+            out.reshape(rows + (heads * v_dim,)))

@@ -5,9 +5,9 @@ fallbacks, the two adapters onto the flash kernels, the state-space
 initialisers, the recomputation policy and the untied head.
 
 ``models/olmoe.py``, ``olmo_hybrid.py``, ``smallthinker.py``, ``sdar.py``,
-``granite.py``, ``kimi_linear.py`` and ``nemotron_h.py`` import from here,
-from ``models/mamba2.py``, ``models/experts.py`` and ``models/loss.py``,
-never from one another (``tests/test_decoder_imports.py``). What builds
+``granite.py``, ``kimi_linear.py``, ``nemotron_h.py`` and ``joyai_flash.py``
+import from here, from ``models/mamba2.py``, ``models/latent.py``,
+``models/experts.py`` and ``models/loss.py``, never from one another (``tests/test_decoder_imports.py``). What builds
 parameters here is a function called inside the model's own ``@nn.compact``
 body, not a module of its own, so every leaf keeps its name and its place
 in the tree.
@@ -98,17 +98,31 @@ class PlainMLP(nn.Module):
             self.activation(projection(cfg, self.width, "up")(x)))
 
 
-def untied_head(model, x):
-    """Final norm (``ln_out``), then the head's own leaf ``lm_head``: bf16
-    in, f32 out on the MXU, as ``models/bert.py``'s head. Called from
-    ``model``'s ``@nn.compact`` body, under the scope the caller opened."""
+def head_leaf(model):
+    """The head's own leaf ``lm_head`` of ``model``, ``[hidden, vocab]`` in
+    float32: made once in its ``@nn.compact`` body, by whoever projects onto
+    the vocabulary more than once (``models/joyai_flash.py``: the main pass
+    and the prediction module)."""
     cfg = model.config
-    x = RMSNorm(cfg.rms_norm_eps, name="ln_out")(x).astype(cfg.dtype)
-    head = model.param("lm_head", nn.initializers.lecun_normal(),
+    return model.param("lm_head", nn.initializers.lecun_normal(),
                        (cfg.hidden_size, cfg.vocab_size), jnp.float32)
+
+
+def head_logits(cfg, x, head):
+    """``x [..., hidden]`` (normalised, ``cfg.dtype``) onto the vocabulary:
+    bf16 in, f32 out on the MXU, as ``models/bert.py``'s head."""
     return jax.lax.dot_general(
         x, head.astype(cfg.dtype), (((x.ndim - 1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
+
+
+def untied_head(model, x):
+    """Final norm (``ln_out``), then the head's own leaf ``lm_head``.
+    Called from ``model``'s ``@nn.compact`` body, under the scope the
+    caller opened."""
+    cfg = model.config
+    x = RMSNorm(cfg.rms_norm_eps, name="ln_out")(x).astype(cfg.dtype)
+    return head_logits(cfg, x, head_leaf(model))
 
 
 def rope(x, theta: float, positions=None):

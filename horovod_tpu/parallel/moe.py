@@ -133,7 +133,7 @@ def make_moe_step(axis_name: str = "hvd", capacity: int = 4, mesh=None):
 def route_to_capacity(tokens, logits, num_experts, capacity, top_k=1,
                       first_expert=0, experts_here=None,
                       gates_over_picks=False, scores="softmax",
-                      gate_scale=1.0):
+                      gate_scale=1.0, selection_bias=None):
     """Capacity-factor top-k routing into fixed per-expert slots — the
     jit-compatible answer to ragged dispatch (the helper the uneven-split
     ``alltoall`` rejection points at).
@@ -153,8 +153,12 @@ def route_to_capacity(tokens, logits, num_experts, capacity, top_k=1,
     are the ``top_k`` of ``sigmoid(logits)`` in float32 and a gate is its
     pick's score, with ``gates_over_picks`` divided by the sum of the
     token's picked scores (plus 1e-20), and in either case times
-    ``gate_scale``: the routing of the DeepSeek-V3 family, whose
-    selection bias this does not have), each ``[T]`` for
+    ``gate_scale``: the routing of the DeepSeek-V3 family; with
+    ``selection_bias``, a float32 ``[num_experts]`` vector, the picks are
+    the ``top_k`` of ``sigmoid(logits) + selection_bias`` while a gate stays
+    its pick's score without it, and no gradient reaches the bias: the
+    family's ``noaux_tc`` choice, whose update rule is the load balancer's
+    and not here), each ``[T]`` for
     ``top_k=1`` and ``[T, top_k]`` otherwise, and ``counts
     [experts_here]`` (kept pairs per expert — the
     ``hvd_moe_expert_load`` signal).
@@ -181,9 +185,18 @@ def route_to_capacity(tokens, logits, num_experts, capacity, top_k=1,
             raise ValueError(
                 f"route_to_capacity: scores={scores!r} with gate_scale="
                 f"{gate_scale}; 'softmax' (unscaled) or 'sigmoid'")
+        if selection_bias is not None and scores != "sigmoid":
+            raise ValueError(
+                "route_to_capacity: selection_bias is the sigmoid scores'; "
+                f"got scores={scores!r}")
         if scores == "sigmoid":
-            picked, expert = lax.top_k(
-                jax.nn.sigmoid(logits.astype(jnp.float32)), top_k)
+            score = jax.nn.sigmoid(logits.astype(jnp.float32))
+            if selection_bias is None:
+                picked, expert = lax.top_k(score, top_k)
+            else:  # the choice by score + bias, the gate by the score
+                _, expert = lax.top_k(score + lax.stop_gradient(
+                    selection_bias.astype(jnp.float32)), top_k)
+                picked = jnp.take_along_axis(score, expert, axis=1)
             if gates_over_picks:
                 picked = picked / (picked.sum(-1, keepdims=True) + 1e-20)
             gate = picked * gate_scale

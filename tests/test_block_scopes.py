@@ -384,4 +384,4 @@ def test_the_block_vocabulary_stands_beside_the_phases():
     assert all(name.startswith("hvd.block.")
                for name in attribution.BLOCK_SCOPE_NAMES)
     assert len(attribution.BLOCK_SCOPE_NAMES) == 7
-    assert len(attribution.PHASE_SCOPE_NAMES) == 15
+    assert len(attribution.PHASE_SCOPE_NAMES) == 16

@@ -1,6 +1,6 @@
-"""The seven decoders share their parts through ``models/parts.py``,
-``models/mamba2.py``, ``models/experts.py`` and ``models/loss.py`` and never
-through one another
+"""The eight decoders share their parts through ``models/parts.py``,
+``models/mamba2.py``, ``models/latent.py``, ``models/experts.py`` and
+``models/loss.py`` and never through one another
 (ROADMAP D13); the names the benchmark calls are where ``PERF.md`` §3 says;
 and the helpers of ``models/parts.py`` that build parameters left every
 leaf of the toy models where the parent of PR 42 had it."""
@@ -17,7 +17,7 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MODELS = os.path.join(ROOT, "horovod_tpu", "models")
 DECODERS = ("olmoe", "olmo_hybrid", "smallthinker", "sdar", "granite",
-            "kimi_linear", "nemotron_h")
+            "kimi_linear", "nemotron_h", "joyai_flash")
 
 
 def imported_modules(path):
@@ -44,8 +44,8 @@ def names_a_decoder(module: str) -> bool:
         module.startswith(".") or module.startswith("horovod_tpu.models"))
 
 
-@pytest.mark.parametrize("name", DECODERS + ("parts", "mamba2", "experts",
-                                             "loss"))
+@pytest.mark.parametrize("name", DECODERS + ("parts", "mamba2", "latent",
+                                             "experts", "loss"))
 def test_no_shared_module_and_no_decoder_imports_a_decoder(name):
     imports = imported_modules(os.path.join(MODELS, name + ".py"))
     assert imports, name
@@ -176,6 +176,19 @@ NEMOTRON_ATTENTION = {
     "attention/query/kernel": (48, 64), "attention/key/kernel": (48, 16),
     "attention/value/kernel": (48, 16), "attention/out/kernel": (64, 48),
     "ln/scale": (48,)}
+# models/joyai_flash.py is PR 52's: its tree as that PR made it, latent
+# attention's leaves from models/latent.py (Kimi Linear's names where the
+# two share a projection), the prediction module's beside the stack's
+LATENT = {"attention/" + path: shape for path, shape in {
+    "q_a/kernel": (64, 48), "q_norm/scale": (48,), "q_b/kernel": (48, 96),
+    "kv_a/kernel": (64, 40), "kv_norm/scale": (32,),
+    "kv_b/kernel": (32, 128), "out/kernel": (64, 64)}.items()}
+JOYAI_NORMS = {"ln_attn/scale": (64,), "ln_ffn/scale": (64,)}
+JOYAI_DENSE = {**LATENT, **JOYAI_NORMS, "mlp/gate/kernel": (64, 96),
+               "mlp/up/kernel": (64, 96), "mlp/down/kernel": (96, 64)}
+JOYAI_EXPERTS = {**LATENT, **JOYAI_NORMS, **experts(64, 24),
+                 "moe/router": (64, 8), "shared/gate/kernel": (64, 24),
+                 "shared/up/kernel": (64, 24), "shared/down/kernel": (24, 64)}
 
 TREES = {
     "olmoe": ("Olmoe", "OLMOE_TINY", 1, {
@@ -195,6 +208,13 @@ TREES = {
         **top(48, 256),
         **layers(NEMOTRON_MAMBA, NEMOTRON_EXPERTS, NEMOTRON_MAMBA,
                  NEMOTRON_ATTENTION, NEMOTRON_EXPERTS)}),
+    "joyai_flash": ("JoyAIFlash", "JOYAI_FLASH_TINY", 2, {
+        **top(64, 256),
+        **layers(JOYAI_DENSE, JOYAI_EXPERTS, JOYAI_EXPERTS),
+        **{"mtp_layer/" + path: shape
+           for path, shape in JOYAI_EXPERTS.items()},
+        "mtp_embed_norm/scale": (64,), "mtp_hidden_norm/scale": (64,),
+        "mtp_proj/kernel": (128, 64), "mtp_norm/scale": (64,)}),
 }
 
 

@@ -151,14 +151,14 @@ def test_the_latent_layers_scale_and_missing_rotation_matter(
     untrained latent layer's softmax is near uniform: the configuration's
     file says so), so this holds it: at the toy size with larger queries
     and keys the product leaves the reference."""
-    from horovod_tpu.models import parts
+    from horovod_tpu.models import latent, parts
 
     config = toy(bench, num_hidden_layers=2,
                  linear_attn_config={"kda_layers": [1],
                                      "full_attn_layers": [2]},
                  training={"attention": "dense"})
     nope = config["qk_nope_head_dim"]
-    real = kimi_linear.dense_causal_attention
+    real = latent.dense_causal_attention  # the module's body lives there
 
     def other(q, k, v, dtype):
         if fault == "rope_on_the_rotary_lanes":
@@ -178,7 +178,7 @@ def test_the_latent_layers_scale_and_missing_rotation_matter(
 
     (loss, _), (ref_loss, _) = both_sides(bench, config, weights=sharper)
     assert float(loss) == pytest.approx(float(ref_loss), rel=1e-5)
-    monkeypatch.setattr(kimi_linear, "dense_causal_attention", other)
+    monkeypatch.setattr(latent, "dense_causal_attention", other)
     (loss, _), (ref_loss, _) = both_sides(bench, config, weights=sharper)
     assert abs(float(loss) - float(ref_loss)) > 3e-5 * float(ref_loss)
 
@@ -323,3 +323,31 @@ def test_routing_stats_skip_the_dense_layer(bench):
     np.testing.assert_array_equal(
         window["layer_1"]["moe"]["experts_up"],
         whole["layer_1"]["moe"]["experts_up"][2:6])
+
+
+# sha256 of jit(grad(sum(LatentAttention(KIMI_LINEAR_TINY).apply))).lower(...)
+# .as_text() on a [2, 32, 64] bfloat16 input at commit 7ec0510, when the
+# module lived in models/kimi_linear.py
+PARENTS_LATENT_TEXT = (
+    "7d55ad7f2cf88ec0857b5b19fa0088b29721149ef6e78bc5db7b2ade58e1925d")
+
+
+def test_the_latent_layer_lowers_to_the_text_it_had():
+    """``models/latent.py`` called as Kimi Linear calls it (no low-rank
+    queries, no rotary split): every leaf under its name, and the program
+    the parent's."""
+    import hashlib
+
+    from horovod_tpu.models import latent
+
+    layer = latent.LatentAttention(kimi_linear.KIMI_LINEAR_TINY)
+    x = jnp.zeros((2, 32, 64), jnp.bfloat16)
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+    assert sorted(params["params"]) == ["kv_a", "kv_b", "kv_norm", "out",
+                                        "query"]
+
+    def loss(p, x):  # the name is in the text
+        return layer.apply(p, x).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss)).lower(params, x).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == PARENTS_LATENT_TEXT

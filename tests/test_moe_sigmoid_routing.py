@@ -1,9 +1,11 @@
 """``parallel/moe.py::route_to_capacity`` with ``scores="sigmoid"`` (the
-DeepSeek-V3 family's routing without its selection bias): the picks are the
-top-k of the sigmoid scores, a gate is its pick's score, renormalised over
-the picks and scaled where asked, against a hand count; the windows'
-gates add up to the scale over all the windows; the gradient reaches the
-logits through the gates; softmax routing lowers to the text it had; and
+DeepSeek-V3 family's routing): the picks are the top-k of the sigmoid
+scores, a gate is its pick's score, renormalised over the picks and scaled
+where asked, against a hand count; with ``selection_bias`` the picks are by
+score plus bias and the gates by the score alone, and nothing is
+differentiated through the bias; the windows' gates add up to the scale over
+all the windows; the gradient reaches the logits through the gates; softmax
+routing, and sigmoid routing without a bias, lower to the text they had; and
 what is no score function is refused."""
 
 import hashlib
@@ -19,11 +21,11 @@ T, E, K = 6, 8, 3
 SCALE = 2.446
 
 
-def hand_count(logits, renormalised, scale):
-    """numpy, a token at a time: scores, the K largest (ties to the lower
-    index), their share of their sum."""
+def hand_count(logits, renormalised, scale, bias=0.0):
+    """numpy, a token at a time: scores, the K largest of score + bias
+    (ties to the lower index), the scores' share of their sum."""
     scores = 1.0 / (1.0 + np.exp(-np.asarray(logits, np.float64)))
-    picks = np.argsort(-scores, axis=1, kind="stable")[:, :K]
+    picks = np.argsort(-(scores + bias), axis=1, kind="stable")[:, :K]
     picked = np.take_along_axis(scores, picks, 1)
     if renormalised:
         picked = picked / (picked.sum(1, keepdims=True) + 1e-20)
@@ -51,6 +53,49 @@ def test_sigmoid_gates_are_the_hand_count(routed, renormalised, scale):
     assert int(counts.sum()) == T * K
     if renormalised:
         np.testing.assert_allclose(gate.sum(1), scale, rtol=1e-6)
+
+
+@pytest.mark.parametrize("renormalised", [True, False])
+def test_a_selection_bias_moves_the_picks_and_no_gate(routed, renormalised):
+    """``noaux_tc``: the choice is by ``s + b``, the gate by ``s``."""
+    tokens, logits = routed
+    bias = jnp.asarray([0.9, -0.9, 0.0, 0.6, -0.3, 0.0, 0.45, -0.6])
+    _, expert, _, _, gate, _ = moe.route_to_capacity(
+        tokens, logits, E, T * K, top_k=K, scores="sigmoid",
+        gates_over_picks=renormalised, gate_scale=SCALE,
+        selection_bias=bias)
+    picks, gates = hand_count(logits, renormalised, SCALE, np.asarray(bias))
+    np.testing.assert_array_equal(expert, picks)
+    np.testing.assert_allclose(gate, gates, rtol=2e-6)
+    plain, _ = hand_count(logits, renormalised, SCALE)
+    assert (picks != plain).any()  # the bias did choose otherwise
+    # one number for every expert chooses as none does
+    _, same, _, _, same_gate, _ = moe.route_to_capacity(
+        tokens, logits, E, T * K, top_k=K, scores="sigmoid",
+        gates_over_picks=renormalised, gate_scale=SCALE,
+        selection_bias=jnp.full((E,), 0.25))
+    np.testing.assert_array_equal(same, plain)
+
+    def weighted(logits, bias):
+        _, _, _, _, gate, _ = moe.route_to_capacity(
+            tokens, logits, E, T * K, top_k=K, scores="sigmoid",
+            gates_over_picks=renormalised, gate_scale=SCALE,
+            selection_bias=bias)
+        return jnp.sum(gate * jnp.arange(1.0, K + 1))
+
+    to_logits, to_bias = jax.grad(weighted, (0, 1))(logits, bias)
+    assert not np.asarray(to_bias).any()
+    chosen = np.zeros((T, E), bool)
+    np.put_along_axis(chosen, picks, True, 1)
+    assert np.all(np.asarray(to_logits)[~chosen] == 0)
+    assert np.all(np.abs(np.asarray(to_logits)[chosen]) > 0)
+
+
+def test_a_selection_bias_is_the_sigmoid_scores_alone(routed):
+    tokens, logits = routed
+    with pytest.raises(ValueError, match="selection_bias is the sigmoid"):
+        moe.route_to_capacity(tokens, logits, E, 4, top_k=K,
+                              selection_bias=jnp.zeros((E,)))
 
 
 def test_the_windows_gates_add_up_to_the_scale(routed):
@@ -117,13 +162,23 @@ PARENTS_TEXT = {
 }
 
 
+# the same with scores="sigmoid", gate_scale=2.5 at commit 7ec0510, before
+# the selection bias was an argument
+PARENTS_SIGMOID_TEXT = {
+    False: "15e6839f137106508717ab06f1b4cc4a81d74b0b153a11915e0cda68e6e2a832",
+    True: "2ac2f72141e0044d249f263db2b0234ec36fdc46b42b2253bc86eeb4807a3d2c",
+}
+
+
 @pytest.mark.parametrize("over_picks", [False, True])
-def test_softmax_routing_lowers_to_the_text_it_had(over_picks):
+@pytest.mark.parametrize("scores", ["softmax", "sigmoid"])
+def test_routing_without_a_bias_lowers_to_the_text_it_had(scores, over_picks):
     t = jnp.zeros((32, 8), jnp.bfloat16)
     l = jnp.zeros((32, 8), jnp.float32)  # noqa: E741
+    how = {"softmax": {}, "sigmoid": dict(scores="sigmoid", gate_scale=2.5)}
     f = lambda t, l: moe.route_to_capacity(  # noqa: E731,E741
         t, l, 8, 10, top_k=2, first_expert=2, experts_here=4,
-        gates_over_picks=over_picks)
+        gates_over_picks=over_picks, **how[scores])
     text = jax.jit(f).lower(t, l).as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == PARENTS_TEXT[
-        over_picks]
+    want = {"softmax": PARENTS_TEXT, "sigmoid": PARENTS_SIGMOID_TEXT}[scores]
+    assert hashlib.sha256(text.encode()).hexdigest() == want[over_picks]
