@@ -921,16 +921,20 @@ LINATTN_PAIR_KERNEL_LAST = gauge(
     "pair terms in the LAST lowered program takes, 0 where that program "
     "holds the plain form (lowered for any platform but a TPU, or "
     "sub-blocks that fill no tile): set as the program is lowered, since "
-    "the lowering platform chooses.",
-    ("sub",))
+    "the lowering platform chooses. operands: the layout q, k and gamma "
+    "cross HBM in, tokens_major ([B, S, H * d] as the projections wrote "
+    "them, read through the kernels' index maps) or plain (the plain "
+    "form's head-major view).",
+    ("sub", "operands"))
 LINATTN_SCAN_KERNEL_LAST = gauge(
     "hvd_linattn_scan_kernel_last",
     "Heads a grid step of the Pallas kernels that run kimi_delta_rule's "
     "solve and chunk loop in the LAST lowered program takes, 0 where that "
     "program holds the plain form (lowered for any platform but a TPU, or "
     "shapes that fill no tile): set as the program is lowered, since the "
-    "lowering platform chooses.",
-    ("chunk",))
+    "lowering platform chooses. operands: as hvd_linattn_pair_kernel_last's "
+    "(q, k, v, gamma, o and their cotangents).",
+    ("chunk", "operands"))
 SSM_CHUNKS_LAST = gauge(
     "hvd_ssm_chunks_last",
     "Chunks a sequence that the LAST traced Mamba-2 scan (ops/ssd.py "

@@ -47,7 +47,13 @@ counted once, add up to the whole layer's.
 
 TPU-first choices, as the other decoders: bfloat16 activations; float32
 parameters, norms, router, convolution weights and gates (``A_log``,
-``dt_bias``, ``g``, ``beta``).
+``dt_bias``, ``g``, ``beta``). The mixer's activations stay ``[B, S, H *
+d]`` from the projections to the output's gate (the rule's kernels read
+and write them so): the two l2-norms and the output's RMSNorm, each a
+head at a time, sum a head's lanes and spread the factor back over them as
+products with the heads' matrix of ones and zeros
+(:func:`scaled_by_head`), because a reduction over part of the lanes makes
+the v5e's compiler lay the array out a head a row and back.
 """
 
 from __future__ import annotations
@@ -69,8 +75,8 @@ from .experts import ExpertWindow, SparseExperts
 from .latent import LatentAttention
 from .loss import token_cross_entropy
 from .parts import (GatedMLP, RMSNorm, decay_rate,
-                    head_major_flash_attention, l2norm, projection,
-                    recomputed, step_bias, untied_head)
+                    head_major_flash_attention, projection, recomputed,
+                    step_bias, untied_head)
 
 flash_attention_fn = head_major_flash_attention  # benchmark/configs' name
 
@@ -138,6 +144,53 @@ KIMI_LINEAR_TINY = KimiLinearConfig(  # test-sized: one period
 )
 
 
+def scaled_by_head(x, heads: int, factor):
+    """``x [B, S, H * d]`` in float32 times ``factor(the sum of a head's
+    squares)``, every head's ``d`` lanes by their own factor, **where they
+    lie**: the sums a head and the factors' way back to the lanes are two
+    float32 products at ``Precision.HIGHEST`` with the heads' ``[H * d, H]``
+    matrix of ones and zeros (float32's sum in another order; a factor
+    times one, exactly). A reduction over part of the lanes of ``[B, S, H *
+    d]`` makes the v5e's compiler lay the array out a head a row first, and
+    the factor's broadcast back over the lanes is written out and laid out
+    again (``PERF.md`` §6, PR 53: 16 ms of Kimi Linear's step); the MXU
+    takes both as they are. The batch is the products' batch dimension,
+    the matrix broadcast: a recomputed layer's policy keeps every product
+    without one."""
+    f32, highest = jnp.float32, jax.lax.Precision.HIGHEST
+    x = x.astype(f32)
+    member = jnp.broadcast_to(
+        jnp.repeat(jnp.eye(heads, dtype=f32), x.shape[-1] // heads, 0),
+        x.shape[:1] + (x.shape[-1], heads))
+    squares = jnp.einsum("bsx,bxh->bsh", jnp.square(x), member,
+                         precision=highest, preferred_element_type=f32)
+    return x * jnp.einsum("bsh,bxh->bsx", factor(squares), member,
+                          precision=highest, preferred_element_type=f32)
+
+
+def l2norm_of_heads(x, heads: int, eps: float = 1e-6):
+    """``parts.l2norm`` of every head's ``d`` lanes of ``x [B, S, H * d]``,
+    float32, where they lie (:func:`scaled_by_head`)."""
+    return scaled_by_head(x, heads, lambda sums: jax.lax.rsqrt(sums + eps))
+
+
+class HeadsRMSNorm(nn.Module):
+    """``parts.RMSNorm`` of every head's ``d`` lanes of ``x [B, S, H * d]``
+    with one learned scale ``[d]`` that the heads share, float32, where they
+    lie (:func:`scaled_by_head`): the tree's leaf is ``parts.RMSNorm``'s on
+    ``[B, S, H, d]``."""
+    eps: float
+    heads: int
+
+    @nn.compact
+    def __call__(self, x):
+        d = x.shape[-1] // self.heads
+        scale = self.param("scale", nn.initializers.ones, (d,), jnp.float32)
+        return scaled_by_head(
+            x, self.heads, lambda sums: jax.lax.rsqrt(sums / d + self.eps)
+        ) * jnp.tile(scale, self.heads)
+
+
 class KimiDeltaAttention(nn.Module):
     config: KimiLinearConfig
 
@@ -167,12 +220,12 @@ class KimiDeltaAttention(nn.Module):
         a_log = self.param("A_log", decay_rate, (heads,), f32)
         dt_bias = self.param("dt_bias", step_bias, (heads * d,), f32)
         with annotate_collective(SCOPE_LINATTN_CONV):
-            q, k, v = (
-                jax.nn.silu(short_conv(y, w)).reshape(
-                    x.shape[:2] + (heads, d))
-                for y, w in before)
-            q = (l2norm(q) * d ** -0.5).astype(cfg.dtype)
-            k = l2norm(k).astype(cfg.dtype)
+            by_head = x.shape[:2] + (heads, d)
+            q, k, v = (jax.nn.silu(short_conv(y, w)) for y, w in before)
+            q = (l2norm_of_heads(q, heads) * d ** -0.5).astype(
+                cfg.dtype).reshape(by_head)
+            k = l2norm_of_heads(k, heads).astype(cfg.dtype).reshape(by_head)
+            v = v.reshape(by_head)
             beta = jax.nn.sigmoid(nn.Dense(
                 heads, use_bias=False, dtype=f32, name="beta")(x))
             g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
@@ -180,9 +233,10 @@ class KimiDeltaAttention(nn.Module):
         out = kimi_delta_rule(q, k, v, g, beta, chunk=cfg.chunk,
                               sub=cfg.sub_chunk)
         with annotate_collective(SCOPE_LINATTN_GATE):
-            out = RMSNorm(cfg.rms_norm_eps, name="o_norm")(out) \
-                * jax.nn.sigmoid(gate.astype(f32)).reshape(out.shape)
-            out = out.astype(cfg.dtype).reshape(x.shape[:2] + (-1,))
+            out = HeadsRMSNorm(cfg.rms_norm_eps, heads, name="o_norm")(
+                out.reshape(x.shape[:2] + (-1,))
+            ) * jax.nn.sigmoid(gate.astype(f32))
+            out = out.astype(cfg.dtype)
         return projection(cfg, cfg.hidden_size, "out")(out)
 
 

@@ -58,7 +58,8 @@ terms are a primitive whose lowering the platform chooses: for a TPU
 :func:`pair_terms_kernel`'s Pallas kernel, which forms them in VMEM, with a
 backward kernel of its own; for anything else, and at any other shape, the
 plain :func:`_pair_terms` (``hvd_linattn_pair_kernel_last`` says, as the
-program is lowered, the chunks a grid step takes, or 0 for the plain form).
+program is lowered, the chunks a grid step takes, or 0 for the plain form,
+and its label ``operands`` the layout they cross HBM in).
 Its solve and its loop over the chunks are a primitive of the same kind
 (``d_k`` and ``d_v`` whole lane blocks, the chunk a power of two of whole
 sublane tiles, eight heads a grid step): for a TPU
@@ -71,6 +72,11 @@ Its ``gamma`` is a float32 product of the chunk's lower triangle of ones
 with ``g`` at ``Precision.HIGHEST``: summed as ``jnp.cumsum`` over the rows
 of ``[C, d_k]`` it is a ``reduce-window``, which the v5e runs at a
 fourteenth of its memory's pace.
+The four kernels read ``q``, ``k``, ``v``, ``gamma`` and write ``o`` and
+every ``d``-wide cotangent as ``[B, S, H * d]``, where the projections
+wrote them: a head is a lane block of a chunk's rows through the index
+maps, and nothing ``d`` wide is transposed on the way in or out; only the
+plain forms take the head-major view (:func:`_by_head`).
 """
 
 from __future__ import annotations
@@ -228,15 +234,32 @@ def _pair_terms(q, k, gamma, sub: int, dtype):
 # The kernels' name: not ``flash_attention``, by which the benchmark finds
 # the attention kernels. XLA names the custom call's instruction after it.
 PAIR_KERNEL_NAME = "kda_pair_terms"
-PAIR_CHUNKS_A_STEP = 8  # of a grid step, where that many divide the chunks
+PAIR_CHUNKS_A_STEP = 4  # of a grid step, where that many divide the chunks
 _MASKED = -1e30  # an exponent above the diagonal: exp gives 0, never a nan
 _NT = (((1,), (1,)), ((), ()))  # a @ b^T
 _TN = (((0,), (0,)), ((), ()))  # a^T @ b
 _NN = (((1,), (0,)), ((), ()))  # a @ b
 
 
-def _sub_block(q_ref, k_ref, gamma_ref, c, lo, hi, dtype):
-    """Sub-block ``[lo, hi)`` of chunk ``c`` of a grid step: its rows of
+def _by_head(x, chunk: int):
+    """``[B, S, H, ...]`` as the plain forms take it: ``[B, H, chunks,
+    chunk, ...]``. No kernel's operand goes through here."""
+    x = x.reshape((x.shape[0], -1, chunk) + x.shape[2:])
+    return jnp.moveaxis(x, 3, 1)
+
+
+def _one_chunk_of_a_head(ref, t, heads: int):
+    """Pair ``t`` of a grid step's chunks and heads in a block ``[chunks,
+    C, heads * d]`` of ``[B, S, H * d]``: ``(c, r, lanes)``, the heads
+    innermost."""
+    width = ref.shape[-1] // heads
+    c, r = t // heads, t % heads
+    return c, r, pl.ds(pl.multiple_of(r * width, width), width)
+
+
+def _sub_block(q_ref, k_ref, gamma_ref, c, lanes, lo, hi, dtype):
+    """Sub-block ``[lo, hi)`` of chunk ``c``, of the head on ``lanes``, of
+    a grid step's blocks: its rows of
     ``q`` and ``k`` in float32; the cube ``e_ijc`` of its own pairs in
     pieces ``(first row, [rows, columns, d])`` of eight rows (a float32
     tile's) against the columns up to their last, zero above the
@@ -246,8 +269,12 @@ def _sub_block(q_ref, k_ref, gamma_ref, c, lo, hi, dtype):
     gamma_j)``, rounded to ``dtype``, with the two float32 factors
     (``None`` for the first sub-block). No exponent is positive."""
     f32 = jnp.float32
-    gamma = gamma_ref[c, lo:hi, :]
-    q, k = q_ref[c, lo:hi, :].astype(f32), k_ref[c, lo:hi, :].astype(f32)
+
+    def read(ref, start, stop):
+        return ref[c, start:stop, lanes]
+
+    gamma = read(gamma_ref, lo, hi)
+    q, k = read(q_ref, lo, hi).astype(f32), read(k_ref, lo, hi).astype(f32)
     pieces, rows = [], min(8, hi - lo)
     for top in range(0, hi - lo, rows):
         shape = (rows, top + rows, gamma.shape[-1])
@@ -259,76 +286,82 @@ def _sub_block(q_ref, k_ref, gamma_ref, c, lo, hi, dtype):
     if not lo:
         return q, k, pieces, None
     left = jnp.exp(gamma - gamma[:1])
-    right = jnp.exp(gamma[:1] - gamma_ref[c, 0:lo, :])
+    right = jnp.exp(gamma[:1] - read(gamma_ref, 0, lo))
     far = (jnp.concatenate([(q * left).astype(dtype),
                             (k * left).astype(dtype)], 0),
-           (k_ref[c, 0:lo, :].astype(f32) * right).astype(dtype), left, right)
+           (read(k_ref, 0, lo).astype(f32) * right).astype(dtype), left,
+           right)
     return q, k, pieces, far
 
 
 def _pair_forward_kernel(q_ref, k_ref, gamma_ref, inside_ref, a_ref, *,
                          sub, dtype):
-    """``inside`` and ``a`` of the grid step's chunks, ``[chunks, C, C]``
-    float32, sub-block by sub-block of rows: the columns before it one
-    product of ``dtype`` operands for both, its own the cube's lane sums,
-    those after a row's piece zero."""
-    chunks, size, _ = q_ref.shape
+    """``inside`` and ``a`` of the grid step's chunks and heads, ``[heads,
+    chunks, C, C]`` float32, from ``q``, ``k``, ``gamma`` as they lie in
+    ``[B, S, H * d]`` (``[chunks, C, heads * d]``), sub-block by sub-block
+    of rows: the columns before it one product of ``dtype`` operands for
+    both, its own the cube's lane sums, those after a row's piece zero."""
+    heads, chunks, size, _ = inside_ref.shape
     f32 = jnp.float32
 
-    def one_chunk(c, carry):
+    def one_chunk(t, carry):
+        c, r, lanes = _one_chunk_of_a_head(q_ref, t, heads)
         for lo in range(0, size, sub):
             hi = lo + sub
-            q, k, pieces, far = _sub_block(q_ref, k_ref, gamma_ref, c, lo,
-                                           hi, dtype)
+            q, k, pieces, far = _sub_block(q_ref, k_ref, gamma_ref, c, lanes,
+                                           lo, hi, dtype)
             if far is not None:
                 both = lax.dot_general(far[0], far[1], _NT,
                                        preferred_element_type=f32)
-                inside_ref[c, lo:hi, 0:lo] = both[:sub]
-                a_ref[c, lo:hi, 0:lo] = both[sub:]
+                inside_ref[r, c, lo:hi, 0:lo] = both[:sub]
+                a_ref[r, c, lo:hi, 0:lo] = both[sub:]
             for top, cube in pieces:
                 rows, columns = cube.shape[:2]
                 here = slice(lo + top, lo + top + rows)
                 near = cube * k[:columns][None, :, :]
-                inside_ref[c, here, lo:lo + columns] = (
+                inside_ref[r, c, here, lo:lo + columns] = (
                     near * q[top:top + rows][:, None, :]).sum(-1)
-                a_ref[c, here, lo:lo + columns] = (
+                a_ref[r, c, here, lo:lo + columns] = (
                     near * k[top:top + rows][:, None, :]).sum(-1)
                 if lo + columns < size:
                     above = jnp.zeros((rows, size - lo - columns), f32)
-                    inside_ref[c, here, lo + columns:size] = above
-                    a_ref[c, here, lo + columns:size] = above
+                    inside_ref[r, c, here, lo + columns:size] = above
+                    a_ref[r, c, here, lo + columns:size] = above
         return carry
 
-    lax.fori_loop(0, chunks, one_chunk, 0)
+    lax.fori_loop(0, chunks * heads, one_chunk, 0)
 
 
 def _pair_backward_kernel(q_ref, k_ref, gamma_ref, inside_bar_ref, a_bar_ref,
                           q_bar_ref, k_bar_ref, gamma_bar_ref, right_ref, *,
                           sub, dtype):
-    """``dq``, ``dk``, ``dgamma`` of the grid step's chunks from the two
-    cotangents: the forward's factors formed again, sub-blocks last to
+    """``dq``, ``dk``, ``dgamma`` of the grid step's chunks and heads, where
+    they lie in ``[B, S, H * d]``, from the two cotangents: the forward's
+    factors formed again, sub-blocks last to
     first, so that a row's cotangent as a pair's right side (``right_ref``,
     float32 scratch) is whole when its own sub-block is done. The sums run
     over ``i`` or ``j``, never over lanes. By term ``dgamma_i = q_i dq_i +
     k_i (dk_i as the left side - dk_i as the right side)``: no cube of its
     own. Cotangents of ``dtype`` operands go into their products rounded
     to ``dtype``, as the plain form's transposed products take them."""
-    chunks, size, _ = q_ref.shape
+    heads, chunks, size, _ = inside_bar_ref.shape
     f32 = jnp.float32
 
-    def one_chunk(c, carry):
+    def one_chunk(t, carry):
+        c, r, lanes = _one_chunk_of_a_head(q_ref, t, heads)
         right_ref[...] = jnp.zeros_like(right_ref)
         for lo in reversed(range(0, size, sub)):
             hi = lo + sub
-            q, k, pieces, far = _sub_block(q_ref, k_ref, gamma_ref, c, lo,
-                                           hi, dtype)
+            q, k, pieces, far = _sub_block(q_ref, k_ref, gamma_ref, c, lanes,
+                                           lo, hi, dtype)
             q_bar, as_left = [], []
             for top, cube in pieces:
                 rows, columns = cube.shape[:2]
                 here = slice(lo + top, lo + top + rows)
-                by_inside = inside_bar_ref[c, here, lo:lo + columns][
+                by_inside = inside_bar_ref[r, c, here, lo:lo + columns][
                     :, :, None] * cube
-                by_a = a_bar_ref[c, here, lo:lo + columns][:, :, None] * cube
+                by_a = a_bar_ref[r, c, here, lo:lo + columns][
+                    :, :, None] * cube
                 q_bar.append((by_inside * k[:columns][None, :, :]).sum(1))
                 as_left.append((by_a * k[:columns][None, :, :]).sum(1))
                 right_ref[lo:lo + columns, :] += (
@@ -337,8 +370,8 @@ def _pair_backward_kernel(q_ref, k_ref, gamma_ref, inside_bar_ref, a_bar_ref,
             q_bar, as_left = jnp.concatenate(q_bar), jnp.concatenate(as_left)
             if far is not None:
                 stacked, k_right, left, right = far
-                bars = jnp.concatenate([inside_bar_ref[c, lo:hi, 0:lo],
-                                        a_bar_ref[c, lo:hi, 0:lo]],
+                bars = jnp.concatenate([inside_bar_ref[r, c, lo:hi, 0:lo],
+                                        a_bar_ref[r, c, lo:hi, 0:lo]],
                                        0).astype(dtype)
                 to_left = lax.dot_general(bars, k_right, _NN,
                                           preferred_element_type=f32)
@@ -347,13 +380,14 @@ def _pair_backward_kernel(q_ref, k_ref, gamma_ref, inside_bar_ref, a_bar_ref,
                 right_ref[0:lo, :] += right * lax.dot_general(
                     bars, stacked, _TN, preferred_element_type=f32)
             as_right = right_ref[lo:hi, :]
-            q_bar_ref[c, lo:hi, :] = q_bar.astype(q_bar_ref.dtype)
-            k_bar_ref[c, lo:hi, :] = (as_left + as_right).astype(
+            q_bar_ref[c, lo:hi, lanes] = q_bar.astype(q_bar_ref.dtype)
+            k_bar_ref[c, lo:hi, lanes] = (as_left + as_right).astype(
                 k_bar_ref.dtype)
-            gamma_bar_ref[c, lo:hi, :] = q * q_bar + k * (as_left - as_right)
+            gamma_bar_ref[c, lo:hi, lanes] = (
+                q * q_bar + k * (as_left - as_right))
         return carry
 
-    lax.fori_loop(0, chunks, one_chunk, 0)
+    lax.fori_loop(0, chunks * heads, one_chunk, 0)
 
 
 def _chunks_a_step(count: int) -> int:
@@ -362,49 +396,66 @@ def _chunks_a_step(count: int) -> int:
     return max(n for n in range(1, PAIR_CHUNKS_A_STEP + 1) if not count % n)
 
 
-def _pair_call(kernel, operands, widths, dtypes, scratch=(), *, step, sub,
-               dtype, interpret):
-    """``kernel`` over ``operands [chunks, C, width]`` in grid steps of
-    ``step`` chunks, giving ``[chunks, C, widths[n]]`` in ``dtypes[n]``."""
-    count, size = operands[0].shape[:2]
-
-    def block(width):
-        return pl.BlockSpec((step, size, width), lambda n: (n, 0, 0))
-
-    return pl.pallas_call(
+def _pair_call(kernel, operands, results, scratch=(), *, chunk, step, heads,
+               sub, dtype, interpret):
+    """``kernel`` over the grid ``(B, chunks / step, H / heads)``, the heads
+    innermost. ``operands`` and ``results`` are ``(kind, array or dtype)``,
+    a block of each kind ``step`` chunks of ``heads`` heads: ``tokens [B, S,
+    H * d]`` (``q``, ``k``, ``gamma`` and their cotangents **where the
+    projections wrote them and the convolution's backward reads them**:
+    ``step`` chunks' rows of ``heads * d`` lanes, taken as ``[B, N, C, H *
+    d]``, which splits whole sublane tiles off ``S`` and moves nothing;
+    nothing is transposed on either side) and ``pairs [B, H, N, C, C]``
+    (kernel to kernel)."""
+    batch, seq, all_heads, width = operands[0][1].shape
+    count = seq // chunk
+    kinds = {
+        "tokens": ((batch, count, chunk, all_heads * width), pl.BlockSpec(
+            (None, step, chunk, heads * width),
+            lambda i, n, h: (i, n, 0, h))),
+        "pairs": ((batch, all_heads, count, chunk, chunk), pl.BlockSpec(
+            (None, heads, step, chunk, chunk),
+            lambda i, n, h: (i, h, n, 0, 0))),
+    }
+    out = pl.pallas_call(
         functools.partial(kernel, sub=sub, dtype=dtype),
-        grid=(count // step,),
-        in_specs=[block(x.shape[-1]) for x in operands],
-        out_specs=[block(width) for width in widths],
-        out_shape=[jax.ShapeDtypeStruct((count, size, width), kind)
-                   for width, kind in zip(widths, dtypes)],
+        grid=(batch, count // step, all_heads // heads),
+        in_specs=[kinds[kind][1] for kind, _ in operands],
+        out_specs=[kinds[kind][1] for kind, _ in results],
+        out_shape=[jax.ShapeDtypeStruct(kinds[kind][0], kept)
+                   for kind, kept in results],
         scratch_shapes=scratch,
         interpret=interpret,
         name=PAIR_KERNEL_NAME,
-    )(*operands)
+    )(*(x.reshape(kinds[kind][0]) for kind, x in operands))
+    return [x.reshape(operands[0][1].shape) if kind == "tokens" else x
+            for (kind, _), x in zip(results, out)]
 
 
 def _forward_by_kernel(q, k, gamma, **how):
-    size = k.shape[-2]
-    return _pair_call(_pair_forward_kernel, [q, k, gamma], [size, size],
-                      [jnp.float32] * 2, **how)
-
-
-def _backward_by_kernel(q, k, gamma, inside_bar, a_bar, **how):
-    size, width = k.shape[-2:]
     return _pair_call(
-        _pair_backward_kernel, [q, k, gamma, inside_bar, a_bar], [width] * 3,
-        [q.dtype, k.dtype, jnp.float32],
-        [pltpu.VMEM((size, width), jnp.float32)], **how)
+        _pair_forward_kernel,
+        [("tokens", q), ("tokens", k), ("tokens", gamma)],
+        [("pairs", jnp.float32)] * 2, **how)
 
 
-def _forward_plain(q, k, gamma, *, sub, dtype, **_):
-    return _pair_terms(q, k, gamma, sub, dtype)
+def _backward_by_kernel(q, k, gamma, inside_bar, a_bar, *, chunk, **how):
+    return _pair_call(
+        _pair_backward_kernel,
+        [("tokens", q), ("tokens", k), ("tokens", gamma),
+         ("pairs", inside_bar), ("pairs", a_bar)],
+        [("tokens", q.dtype), ("tokens", k.dtype), ("tokens", jnp.float32)],
+        [pltpu.VMEM((chunk, k.shape[-1]), jnp.float32)], chunk=chunk, **how)
 
 
-def _backward_plain(q, k, gamma, *bars, sub, dtype, **_):
+def _forward_plain(q, k, gamma, *, chunk, sub, dtype, **_):
+    return _pair_terms(*(_by_head(x, chunk) for x in (q, k, gamma)), sub,
+                       dtype)
+
+
+def _backward_plain(q, k, gamma, *bars, **how):
     # the factors and the cubes again from the operands, as jax.checkpoint's
-    return jax.vjp(lambda *xs: _pair_terms(*xs, sub, dtype),
+    return jax.vjp(functools.partial(_forward_plain, **how),
                    q, k, gamma)[1](bars)
 
 
@@ -441,9 +492,13 @@ def _where_lowered(name, results, by_kernel, plain, record):
     return primitive
 
 
+def _pair_avals(q, k, gamma, *, chunk, **_):
+    batch, seq, heads, _ = k.shape
+    return [gamma.update(shape=(batch, heads, seq // chunk, chunk, chunk))] * 2
+
+
 _pair_forward_p = _where_lowered(
-    "hvd_kda_pair_terms", lambda q, k, gamma, **_: [
-        gamma.update(shape=k.shape[:-1] + k.shape[-2:-1])] * 2,
+    "hvd_kda_pair_terms", _pair_avals,
     _forward_by_kernel, _forward_plain, lambda *a, **k: _pair_form(*a, **k))
 _pair_backward_p = _where_lowered(
     "hvd_kda_pair_terms_backward", lambda *kept, **_: list(kept[:3]),
@@ -451,65 +506,63 @@ _pair_backward_p = _where_lowered(
     lambda *a, **k: _pair_form(*a, **k))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def pair_terms_kernel(q, k, gamma, sub, dtype, interpret=False):
-    """:func:`_pair_terms` as one Pallas kernel, and its backward pass as
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def pair_terms_kernel(q, k, gamma, chunk, sub, dtype, interpret=False):
+    """:func:`_pair_terms` of every chunk and head of ``q``, ``k``,
+    ``gamma [B, S, H, d]`` (which the calls read as ``[B, S, H * d]``, as
+    the projections wrote them) as one Pallas kernel giving ``[B, H, N, C,
+    C]`` twice, and its backward pass as
     another, in a program lowered for a TPU (anywhere, interpreted, where
     the tests say ``interpret``; the plain form itself on any other
-    platform): a grid step takes some chunks' ``q``, ``k`` and ``gamma``
-    into VMEM and nothing between them and the two ``[C, C]`` results is
-    written to HBM (the plain form writes ``k_right``, four times ``k``,
-    and the sub-blocks' cubes). The same reference rows, the same
+    platform): a grid step takes some chunks of eight heads' ``q``, ``k``
+    and ``gamma`` into VMEM and nothing between them and the two ``[C, C]``
+    results is written to HBM (the plain form writes ``k_right``, four
+    times ``k``, and the sub-blocks' cubes). The same reference rows, the same
     rounding points: a pair in different sub-blocks is a product of
     ``dtype`` operands with float32 accumulation, a pair inside one is
     float32 throughout. The residuals are the operands; the backward
-    kernel forms the factors again in VMEM. Each pass is a primitive of
+    kernel forms the factors again in VMEM and writes ``dq``, ``dk``,
+    ``dgamma`` as ``[B, S, H * d]`` too. Each pass is a primitive of
     its own (:func:`_where_lowered`), so a recomputed layer's policy sees
     no ``pallas_call`` whose results it would keep (134 MB a layer at
     8,192 tokens that no backward kernel wants): they are formed again in
     the backward pass, as the plain form's under ``jax.checkpoint``."""
-    return _pair_forward(q, k, gamma, sub, dtype, interpret)[0]
+    return _pair_forward(q, k, gamma, chunk, sub, dtype, interpret)[0]
 
 
-def _flat(x):  # [..., C, width] -> [chunks, C, width]
-    return x.reshape((-1,) + x.shape[-2:])
-
-
-def _how(k, sub, dtype, interpret):
-    return dict(step=_chunks_a_step(math.prod(k.shape[:-2])), sub=sub,
+def _how(k, chunk, sub, dtype, interpret):
+    return dict(chunk=chunk, step=_chunks_a_step(k.shape[1] // chunk),
+                heads=_heads_a_step(k.shape[2]), sub=sub,
                 dtype=jnp.dtype(dtype), interpret=interpret)
 
 
-def _pair_forward(q, k, gamma, sub, dtype, interpret):
-    inside, a = _pair_forward_p.bind(
-        _flat(q), _flat(k), _flat(gamma), **_how(k, sub, dtype, interpret))
-    lead = k.shape[:-1] + k.shape[-2:-1]
-    return (inside.reshape(lead), a.reshape(lead)), (q, k, gamma)
+def _pair_forward(q, k, gamma, chunk, sub, dtype, interpret):
+    out = _pair_forward_p.bind(q, k, gamma,
+                               **_how(k, chunk, sub, dtype, interpret))
+    return tuple(out), (q, k, gamma)
 
 
-def _pair_backward(sub, dtype, interpret, kept, bars):
-    k = kept[1]
-    out = _pair_backward_p.bind(
-        *(_flat(x) for x in kept + tuple(bars)),
-        **_how(k, sub, dtype, interpret))
-    return tuple(x.reshape(k.shape) for x in out)
+def _pair_backward(chunk, sub, dtype, interpret, kept, bars):
+    return tuple(_pair_backward_p.bind(
+        *kept, *bars, **_how(kept[1], chunk, sub, dtype, interpret)))
 
 
 pair_terms_kernel.defvjp(_pair_forward, _pair_backward)
 
 
-def _pair_terms_where_lowered(q, k, gamma, sub, dtype):
-    """The pair terms by :func:`pair_terms_kernel` where a TPU's tiles are
+def _pair_terms_where_lowered(q, k, gamma, chunk, sub, dtype):
+    """The pair terms ``[B, H, N, C, C]`` of ``q``, ``k``, ``gamma [B, S,
+    H, d_k]`` by :func:`pair_terms_kernel` where a TPU's tiles are
     filled (``d_k`` whole lanes, ``sub`` whole sublanes of ``q``'s and
     ``k``'s type), so that the program lowered for a TPU holds the kernels
     and any other the plain form; at any other shape the plain form under
     ``jax.checkpoint`` whatever the platform."""
     rows = 32 // min(q.dtype.itemsize, k.dtype.itemsize)  # a tile's sublanes
     if k.shape[-1] % 128 == 0 and sub % rows == 0:
-        return pair_terms_kernel(q, k, gamma, sub, dtype)
+        return pair_terms_kernel(q, k, gamma, chunk, sub, dtype)
     _record_pair_path(0, sub)
-    return jax.checkpoint(
-        lambda q, k, gamma: _pair_terms(q, k, gamma, sub, dtype))(q, k, gamma)
+    return jax.checkpoint(functools.partial(
+        _forward_plain, chunk=chunk, sub=sub, dtype=dtype))(q, k, gamma)
 
 
 def kimi_delta_rule(q, k, v, g, beta, chunk: int = 64, sub: int = 16):
@@ -530,44 +583,79 @@ def kimi_delta_rule(q, k, v, g, beta, chunk: int = 64, sub: int = 16):
     float32 product at ``Precision.HIGHEST`` (float32's sum in another
     order; the backward pass's reverse sum is the product with ``L^T``):
     as a ``reduce-window`` it was 50 ms of the v5e's step, the products
-    are 11."""
+    are 11.
+
+    **Nothing ``d`` wide is transposed.** ``q``, ``k``, ``v``, ``gamma``
+    and ``o`` stay tokens-major, ``[B, S, H * d]`` as the projections wrote
+    them and the output's gate reads them, and so do ``dq``, ``dk``, ``dv``,
+    ``dg``: the four kernels take a head as a lane block of a chunk's rows
+    through their index maps (:func:`_pair_call`, :func:`_scan_call`).
+    Head-major are only ``beta`` (one number a token) and the pair terms
+    ``[B, H, N, C, C]``, which go from kernel to kernel; the plain forms
+    (any platform but a TPU, and shapes that fill no tile) take
+    :func:`_by_head`'s view of everything."""
     batch, seq, heads, d_v = v.shape
     if seq % chunk or chunk % sub:
         raise ValueError(
             f"kimi_delta_rule: a sequence of {seq} is no multiple of the "
             f"chunk of {chunk}, or the chunk none of the sub-block of "
             f"{sub}; pad it upstream")
-    count, dtype, f32 = seq // chunk, v.dtype, jnp.float32
-    _record_chunks(count, chunk, heads, decay_width=k.shape[-1])
-
-    def chunks(x):  # [B, S, H, ...] -> [B, H, chunks, chunk, ...]
-        x = x.reshape((batch, count, chunk) + x.shape[2:])
-        return jnp.moveaxis(x, 3, 1)
-
+    _record_chunks(seq // chunk, chunk, heads, decay_width=k.shape[-1])
     with annotate_collective(SCOPE_LINATTN_SCAN):
-        q, k, v = chunks(q), chunks(k), chunks(v)
-        beta = chunks(beta.astype(f32))[..., None]
-        # Batch, head and chunk are batch dimensions of the running sum's
-        # product, the triangle broadcast (XLA never writes it out): a
-        # recomputed layer's policy keeps every product without one, and
-        # gamma would stay, 134 MB a layer; with the chunk alone as one
-        # gamma comes out chunk-major and the v5e's step is 32 ms longer.
-        ones = jnp.broadcast_to(jnp.tril(jnp.ones((chunk, chunk), f32)),
-                                (batch, heads, count, chunk, chunk))
-        gamma = jnp.einsum(                                # [B, H, N, C, d_k]
-            "bhnij,bhnjd->bhnid", ones, chunks(g.astype(f32)),
-            precision=lax.Precision.HIGHEST, preferred_element_type=f32)
-        inside, a = _pair_terms_where_lowered(q, k, gamma, sub, dtype)
+        beta = _by_head(beta.astype(jnp.float32), chunk)[..., None]
+        gamma = _running_sums(g.astype(jnp.float32), chunk)
+        inside, a = _pair_terms_where_lowered(q, k, gamma, chunk, sub,
+                                              v.dtype)
         return _chunk_scan_where_lowered(q, k, v, gamma, beta, inside, a)
+
+
+def _triangles_product(spec, x, chunk):
+    """``x [B, S, H, d]`` float32 summed along each chunk's rows by one
+    product with the ``[C, C]`` lower triangle of ones, as ``spec`` says
+    (``ij`` the triangle, ``b`` and ``n`` batch and chunk, ``x`` the heads'
+    lanes side by side), at ``Precision.HIGHEST``. Batch and chunk are the
+    product's batch dimensions, the triangle broadcast (XLA never writes
+    it out): a recomputed layer's policy keeps every product without one,
+    and ``gamma`` would stay, 134 MB a layer; and a product's batch
+    dimensions lead its result, so the sums come out ``[B, N, C, H * d]``,
+    which is ``[B, S, H * d]`` where the kernels read it (with the head as
+    a dimension of its own, batch or free, the v5e's compiler lays the
+    result out head-major and copies it back; with the chunk alone as a
+    batch dimension chunk-major: 32 ms of its step in copies)."""
+    f32 = jnp.float32
+    batch, seq = x.shape[:2]
+    ones = jnp.broadcast_to(jnp.tril(jnp.ones((chunk, chunk), f32)),
+                            (batch, seq // chunk, chunk, chunk))
+    return jnp.einsum(
+        spec, ones, x.reshape((batch, seq // chunk, chunk, -1)),
+        precision=lax.Precision.HIGHEST,
+        preferred_element_type=f32).reshape(x.shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _running_sums(g, chunk):
+    """``gamma = L g`` a chunk, head and channel, ``g [B, S, H, d_k]``
+    float32 where it lies; its cotangent is the product with ``L^T`` where
+    ``dgamma`` lies (JAX's own transposition of the product gives the same
+    sums as ``[B, N, H * d, C]`` and transposes them)."""
+    return _triangles_product("bnij,bnjx->bnix", g, chunk)
+
+
+_running_sums.defvjp(
+    lambda g, chunk: (_triangles_product("bnij,bnjx->bnix", g, chunk), None),
+    lambda chunk, _, bar: (
+        _triangles_product("bnij,bnix->bnjx", bar, chunk),))
 
 
 def _chunk_scan(q, k, v, gamma, beta, inside, a):
     """The solve and the loop over the chunks of :func:`kimi_delta_rule`,
-    plain JAX differentiated by JAX: ``q``, ``k``, ``gamma`` ``[B, H, N, C,
+    plain JAX differentiated by JAX, on the head-major view
+    (:func:`_by_head`): ``q``, ``k``, ``gamma`` ``[B, H, N, C,
     d_k]``, ``v [B, H, N, C, d_v]``, ``beta [B, H, N, C, 1]`` and the pair
     terms ``inside``, ``a`` ``[B, H, N, C, C]`` -> ``o [B, S, H, d_v]`` in
     ``v``'s type. What any platform but a TPU runs, what the toys' widths
-    trace and what the tests hold :func:`chunk_scan_kernel` to."""
+    trace and what the tests hold :func:`chunk_scan_kernel` to
+    (:func:`_chunk_scan_of_tokens`)."""
     batch, heads, count, chunk, d_v = v.shape
     dtype, f32 = v.dtype, jnp.float32
 
@@ -681,15 +769,15 @@ SCAN_KERNEL_NAME = "kda_chunk_scan"
 SCAN_HEADS_A_STEP = 8  # of a grid step: their chains of products overlap
 
 
-def _scan_heads_a_step(k, v) -> int:
+def _scan_heads_a_step(k, v, chunk: int) -> int:
     """The heads a grid step of :func:`chunk_scan_kernel` takes, the
     largest divisor of the heads up to ``SCAN_HEADS_A_STEP``, where the
     shapes fill a TPU's tiles, or 0 where they do not and the plain form is
-    traced: ``d_k`` and ``d_v`` whole 128-lane blocks of ``k``, ``v [B, H, N,
-    C, d]``, the chunk a power of two (the solve halves it) of whole
+    traced: ``d_k`` and ``d_v`` whole 128-lane blocks of ``k``, ``v [B, S,
+    H, d]``, the chunk a power of two (the solve halves it) of whole
     sublane tiles of their type, and a step's heads whole sublane tiles of
     ``beta``'s rows."""
-    heads, chunk = k.shape[1], k.shape[3]
+    heads = k.shape[2]
     rows = 32 // min(k.dtype.itemsize, v.dtype.itemsize)
     step = _heads_a_step(heads)
     fills = (k.shape[-1] % 128 == 0 and v.shape[-1] % 128 == 0
@@ -830,10 +918,18 @@ def _solved_heads(beta_ref, a_ref, inverse_ref=None):
             for r in range(heads)]
 
 
+def _lanes(ref, r: int, heads: int):
+    """Head ``r``'s lanes of a block ``[C, heads * d]`` of ``[B, S, H *
+    d]``."""
+    width = ref.shape[-1] // heads
+    return slice(r * width, (r + 1) * width)
+
+
 def _chunks_of_the_heads(q_ref, k_ref, v_ref, gamma_ref, inside_ref, solved,
                          states):
     """Every head's chunk up to the state's step, all that both kernels
-    form alike from the operands, ``solved`` (:func:`_solved_heads`) and
+    form alike from the operands (``q``, ``k``, ``v``, ``gamma`` a head a
+    lane block of the chunk's rows), ``solved`` (:func:`_solved_heads`) and
     the float32 ``states`` that enter: the plain form's values at its
     rounding points, a dict a head. ``beta`` scales the inverse's columns
     where the plain form scales the right side's rows (``T (beta x) = (T
@@ -842,11 +938,12 @@ def _chunks_of_the_heads(q_ref, k_ref, v_ref, gamma_ref, inside_ref, solved,
     stage**: the heads' chains of products are independent, and the
     compiler overlaps what it finds side by side."""
     f32, dtype = jnp.float32, v_ref.dtype
-    heads = []
+    heads, count = [], len(solved)
     for (r, beta, inverse), state in zip(solved, states):
-        gamma = gamma_ref[r]
+        gamma = gamma_ref[:, _lanes(gamma_ref, r, count)]
         size = gamma.shape[0]
-        q, k = q_ref[r].astype(f32), k_ref[r].astype(f32)
+        q = q_ref[:, _lanes(q_ref, r, count)].astype(f32)
+        k = k_ref[:, _lanes(k_ref, r, count)].astype(f32)
         grow = jnp.exp(gamma)
         rest = jnp.exp(gamma[size - 1:size] - gamma)
         heads.append(dict(
@@ -856,7 +953,7 @@ def _chunks_of_the_heads(q_ref, k_ref, v_ref, gamma_ref, inside_ref, solved,
             k_out=(k * rest).astype(dtype),
             inside=inside_ref[r].astype(dtype)))
     for (r, _, _), c in zip(solved, heads):
-        c["u"] = _dot32(c["scaled"], v_ref[r])
+        c["u"] = _dot32(c["scaled"], v_ref[:, _lanes(v_ref, r, count)])
         c["w"] = _dot32(c["scaled"], c["grown"])
         c["rounded"] = c["w"].astype(dtype)
     for c in heads:
@@ -867,8 +964,9 @@ def _chunks_of_the_heads(q_ref, k_ref, v_ref, gamma_ref, inside_ref, solved,
 def _scan_forward_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref, inside_ref,
                          a_ref, o_ref, *rest):
     """One chunk of the heads of a grid step: ``q``, ``k``, ``v``, ``gamma``
-    ``[R, C, d]``, the pair terms ``[R, C, C]``, ``beta [H, C]`` (a head a
-    row). The solve (:func:`_inverses_in_vmem` and two float32 products),
+    ``[C, R * d]`` where they lie in ``[B, S, H * d]``, the pair terms ``[R,
+    C, C]``, ``beta [H, C]`` (a head a row). The solve
+    (:func:`_inverses_in_vmem` and two float32 products),
     then :func:`_chunk_scan`'s ``one_chunk`` on the head's float32 state
     ``[d_k, d_v]``, which ``state_ref`` keeps across the chunks. ``o`` goes
     where it lies in ``[B, S, H * d_v]``. The last of ``rest`` but the
@@ -876,7 +974,6 @@ def _scan_forward_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref, inside_ref,
     chunk and the inverse."""
     *kept_refs, state_ref = rest
     n, h = pl.program_id(1), pl.program_id(2)
-    d_v = v_ref.shape[-1]
 
     @pl.when(n == 0)
     def _():
@@ -889,7 +986,7 @@ def _scan_forward_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref, inside_ref,
                                  solved, states)
     for (r, _, inverse), c, state in zip(solved, heads, states):
         out = _dot(c["q_in"], c["entered"]) + _dot(c["inside"], c["new"])
-        o_ref[:, r * d_v:(r + 1) * d_v] = out.astype(o_ref.dtype)
+        o_ref[:, _lanes(o_ref, r, len(solved))] = out.astype(o_ref.dtype)
         for ref, value in zip(kept_refs, (state, inverse)):
             ref[r] = value
     state_ref[h] = jnp.stack([
@@ -913,8 +1010,8 @@ def _scan_backward_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref,
     What ``rest`` takes away from a row of ``gamma`` and gives its last is
     one float32 number."""
     n, h = pl.program_id(1), pl.program_id(2)
-    heads, size, d_k = k_ref.shape
-    d_v = v_ref.shape[-1]
+    heads, size, _ = a_ref.shape
+    d_k = k_ref.shape[-1] // heads
     dtype, f32 = v_ref.dtype, jnp.float32
 
     @pl.when(n == 0)
@@ -935,7 +1032,7 @@ def _scan_backward_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref,
     # stage by stage over the heads, as the forward's
     for (r, beta, _), c, ahead in zip(solved, chunks, aheads):
         c["by_row"] = _turned(beta)
-        c["o_bar"] = o_bar_ref[:, r * d_v:(r + 1) * d_v].astype(dtype)
+        c["o_bar"] = o_bar_ref[:, _lanes(o_bar_ref, r, heads)].astype(dtype)
         c["led"] = ahead.astype(dtype)
     for (r, _, _), c in zip(solved, chunks):
         # o = q_in entered + inside new; leaving = kept state + k_out^T new
@@ -966,18 +1063,21 @@ def _scan_backward_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref,
         a_bar_ref[r] = by_row * a_bar
         beta_bars.append(_turned(
             jnp.sum(a_bar * a_ref[r], 1, keepdims=True)
-            + jnp.sum(v_side * v_ref[r].astype(f32), 1, keepdims=True)
+            + jnp.sum(v_side * v_ref[:, _lanes(v_ref, r, heads)].astype(f32),
+                      1, keepdims=True)
             + jnp.sum(k_side * c["grown"], 1, keepdims=True)))
-        v_bar_ref[r] = (by_row * v_side).astype(v_bar_ref.dtype)
-        grown_bar = by_row * k_side
-        q_bar_ref[r] = (c["q_in_bar"] * c["grow"]).astype(q_bar_ref.dtype)
-        k_bar_ref[r] = (grown_bar * c["grow"]
-                        + c["k_out_bar"] * c["rest"]).astype(k_bar_ref.dtype)
+        v_bar_ref[:, _lanes(v_bar_ref, r, heads)] = (by_row * v_side).astype(
+            v_bar_ref.dtype)
+        grown_bar, keys = by_row * k_side, _lanes(k_bar_ref, r, heads)
+        q_bar_ref[:, keys] = (c["q_in_bar"] * c["grow"]).astype(
+            q_bar_ref.dtype)
+        k_bar_ref[:, keys] = (grown_bar * c["grow"] + c["k_out_bar"]
+                              * c["rest"]).astype(k_bar_ref.dtype)
         # gamma's last row is in every row's rest and in kept
         rested = c["k_out_bar"] * c["k"] * c["rest"]
         to_last = (jnp.sum(rested, 0, keepdims=True) + c["kept"] * _turned(
             jnp.sum(ahead * state, 1, keepdims=True)))
-        gamma_bar_ref[r] = (
+        gamma_bar_ref[:, keys] = (
             (grown_bar * c["k"] + c["q_in_bar"] * c["q"]) * c["grow"] - rested
             + jnp.where(last, to_last, 0.0))
     beta_bar_ref[pl.ds(first, heads), :] = jnp.concatenate(beta_bars, 0)
@@ -988,28 +1088,31 @@ def _scan_call(kernel, operands, results, scratch, *, shape, turned, step,
     """``kernel`` over the grid ``(B, chunks, H / step)``, the heads
     innermost, for ``shape = (B, H, N, C, d_k, d_v)``. ``operands`` and
     ``results`` are ``(kind, array or dtype)``, a block of each kind one
-    chunk of one step's heads: ``keys`` / ``values`` / ``pairs [B, H, N, C,
-    d_k | d_v | C]`` (as :func:`kimi_delta_rule`'s ``chunks`` lays them),
-    ``rows [B, N, H, C]`` (``beta``: every head's row of the chunk, which
-    stays while the chunk lasts), ``tokens [B, S, H * d_v]`` (``o`` and its
-    cotangent, where they lie), ``states [B, N, H, d_k, d_v]``. ``turned``:
-    the chunks last to first."""
+    chunk of one step's heads: ``keys`` / ``values [B, S, H * d_k | d_v]``
+    (``q``, ``k``, ``gamma`` / ``v``, ``o`` and all their cotangents **where
+    the projections wrote them and the gate and the convolution's backward
+    read them**: the chunk's rows of the step's lane blocks, a head
+    ``ref[:, r * d:(r + 1) * d]``, nothing transposed on either side),
+    ``pairs [B, H, N, C, C]`` (kernel to kernel), ``rows [B, N, H, C]``
+    (``beta``: every head's row of the chunk, which stays while the chunk
+    lasts), ``states [B, N, H, d_k, d_v]``. ``turned``: the chunks last to
+    first."""
     batch, heads, count, chunk, d_k, d_v = shape
 
     def at(n):
         return count - 1 - n if turned else n
 
-    def by_head(width):
-        return ((batch, heads, count, chunk, width), pl.BlockSpec(
-            (None, step, None, chunk, width),
-            lambda i, n, h: (i, h, at(n), 0, 0)))
+    def tokens(width):
+        return ((batch, count * chunk, heads * width), pl.BlockSpec(
+            (None, chunk, step * width), lambda i, n, h: (i, at(n), h)))
 
     kinds = {
-        "keys": by_head(d_k), "values": by_head(d_v), "pairs": by_head(chunk),
+        "keys": tokens(d_k), "values": tokens(d_v),
+        "pairs": ((batch, heads, count, chunk, chunk), pl.BlockSpec(
+            (None, step, None, chunk, chunk),
+            lambda i, n, h: (i, h, at(n), 0, 0))),
         "rows": ((batch, count, heads, chunk), pl.BlockSpec(
             (None, None, heads, chunk), lambda i, n, h: (i, at(n), 0, 0))),
-        "tokens": ((batch, count * chunk, heads * d_v), pl.BlockSpec(
-            (None, chunk, step * d_v), lambda i, n, h: (i, at(n), h))),
         "states": ((batch, count, heads, d_k, d_v), pl.BlockSpec(
             (None, None, step, d_k, d_v),
             lambda i, n, h: (i, at(n), h, 0, 0))),
@@ -1028,8 +1131,9 @@ def _scan_call(kernel, operands, results, scratch, *, shape, turned, step,
     )(*(x.reshape(kinds[kind][0]) for kind, x in operands))
 
 
-def _scan_shape(k, v):
-    return k.shape + v.shape[-1:]
+def _scan_shape(k, v, chunk):
+    batch, seq, heads, d_k = k.shape
+    return batch, heads, seq // chunk, chunk, d_k, v.shape[-1]
 
 
 def _scan_operands(q, k, v, gamma, beta, inside, a):
@@ -1048,9 +1152,9 @@ def _scan_forward_by_kernel(*operands, states, chunk, **how):
     f32 = jnp.float32
     o, *kept = _scan_call(
         _scan_forward_kernel, _scan_operands(*operands),
-        [("tokens", v.dtype)] + [(kind, f32) for kind in _KEPT] * states, [],
-        shape=_scan_shape(k, v), turned=False, **how)
-    return [o.reshape(_result_shape(k, v))] + kept
+        [("values", v.dtype)] + [(kind, f32) for kind in _KEPT] * states, [],
+        shape=_scan_shape(k, v, chunk), turned=False, **how)
+    return [o.reshape(v.shape)] + kept
 
 
 def _scan_backward_by_kernel(q, k, v, gamma, beta, inside, a, entering,
@@ -1059,42 +1163,45 @@ def _scan_backward_by_kernel(q, k, v, gamma, beta, inside, a, entering,
     bars = list(_scan_call(
         _scan_backward_kernel,
         _scan_operands(q, k, v, gamma, beta, inside, a)
-        + [("states", entering), ("pairs", inverse), ("tokens", o_bar)],
+        + [("states", entering), ("pairs", inverse), ("values", o_bar)],
         [("keys", q.dtype), ("keys", k.dtype), ("values", v.dtype),
          ("keys", f32), ("rows", f32), ("pairs", f32), ("pairs", f32)], [],
-        shape=_scan_shape(k, v), turned=True, **how))
+        shape=_scan_shape(k, v, chunk), turned=True, **how))
+    bars[:4] = [bar.reshape(x.shape) for bar, x in zip(bars, (q, k, v, gamma))]
     bars[4] = jnp.swapaxes(bars[4], 1, 2)[..., None]
     return bars
 
 
-def _result_shape(k, v):
-    batch, heads, count, chunk, d_v = v.shape
-    return batch, count * chunk, heads, d_v
-
-
-def _kept_avals(k, v):
+def _kept_avals(k, v, chunk):
     """Of the float32 states that enter each chunk and of the inverses."""
-    batch, heads, count, chunk, d_k = k.shape
-    return [k.update(shape=(batch, count, heads, d_k, v.shape[-1]),
+    batch, heads, count, chunk, d_k, d_v = _scan_shape(k, v, chunk)
+    return [k.update(shape=(batch, count, heads, d_k, d_v),
                      dtype=jnp.float32),
-            k.update(shape=k.shape[:-1] + (chunk,), dtype=jnp.float32)]
+            k.update(shape=(batch, heads, count, chunk, chunk),
+                     dtype=jnp.float32)]
 
 
-def _scan_forward_plain(*operands, states, **_):
+def _chunk_scan_of_tokens(q, k, v, gamma, *more):
+    """:func:`_chunk_scan` of ``q``, ``k``, ``v``, ``gamma [B, S, H, d]``."""
+    chunk = more[-1].shape[-1]
+    return _chunk_scan(*(_by_head(x, chunk) for x in (q, k, v, gamma)), *more)
+
+
+def _scan_forward_plain(*operands, states, chunk, **_):
     # the plain backward differentiates the plain form and reads neither
     k, v = operands[1:3]
-    unread = [jnp.zeros(x.shape, x.dtype) for x in _kept_avals(k, v)] * states
-    return [_chunk_scan(*operands)] + unread
+    unread = [jnp.zeros(x.shape, x.dtype)
+              for x in _kept_avals(k, v, chunk)] * states
+    return [_chunk_scan_of_tokens(*operands)] + unread
 
 
 def _scan_backward_plain(*operands, **_):
     *operands, entering, inverse, o_bar = operands
-    return jax.vjp(_chunk_scan, *operands)[1](o_bar)
+    return jax.vjp(_chunk_scan_of_tokens, *operands)[1](o_bar)
 
 
-def _scan_forward_results(q, k, v, *more, states, **_):
-    return ([v.update(shape=_result_shape(k, v))]
-            + _kept_avals(k, v) * states)
+def _scan_forward_results(q, k, v, *more, states, chunk, **_):
+    return [v] + _kept_avals(k, v, chunk) * states
 
 
 _scan_forward_p = _where_lowered(
@@ -1127,32 +1234,34 @@ def chunk_scan_kernel(q, k, v, gamma, beta, inside, a, interpret=False):
     platform), at shapes :func:`_scan_heads_a_step` accepts. The grid walks
     a sequence's chunks in order, the heads in steps of eight innermost; a
     head's float32 state ``[d_k, d_v]`` stays in VMEM over its chunks, the
-    solve is float32 in VMEM, and HBM sees the operands as
-    :func:`kimi_delta_rule` lays them, ``o`` as ``[B, S, H * d_v]`` and,
+    solve is float32 in VMEM, and HBM sees ``q``, ``k``, ``v``, ``gamma [B,
+    S, H, d]`` and ``o`` as ``[B, S, H * d]`` (their cotangents too: nothing
+    is transposed around either kernel), ``beta [B, H, N, C, 1]`` and the
+    pair terms ``[B, H, N, C, C]`` as :func:`kimi_delta_rule` hands them and,
     where a backward pass follows, the float32 states that enter each chunk
     and the inverses: the backward kernel's residuals with the operands.
     Same rounding points as the plain form. Each pass is a primitive of its
     own (:func:`_where_lowered`), so a recomputed layer's policy sees no
     ``pallas_call`` whose results it would keep."""
     return _scan_forward_p.bind(q, k, v, gamma, beta, inside, a, states=False,
-                                **_scan_how(k, v, interpret))[0]
+                                **_scan_how(k, a, interpret))[0]
 
 
-def _scan_how(k, v, interpret):
-    return dict(step=_heads_a_step(k.shape[1]), chunk=k.shape[3],
+def _scan_how(k, a, interpret):
+    return dict(step=_heads_a_step(k.shape[2]), chunk=a.shape[-1],
                 interpret=interpret)
 
 
 def _scan_forward(q, k, v, gamma, beta, inside, a, interpret):
     operands = (q, k, v, gamma, beta, inside, a)
     o, *kept = _scan_forward_p.bind(*operands, states=True,
-                                    **_scan_how(k, v, interpret))
+                                    **_scan_how(k, a, interpret))
     return o, operands + tuple(kept)
 
 
 def _scan_backward(interpret, kept, o_bar):
     return tuple(_scan_backward_p.bind(
-        *kept, o_bar, **_scan_how(kept[1], kept[2], interpret)))
+        *kept, o_bar, **_scan_how(kept[1], kept[6], interpret)))
 
 
 chunk_scan_kernel.defvjp(_scan_forward, _scan_backward)
@@ -1163,10 +1272,11 @@ def _chunk_scan_where_lowered(q, k, v, gamma, beta, inside, a):
     tiles are filled, so that the program lowered for a TPU holds the
     kernels and any other the plain form; at any other shape the plain
     form whatever the platform."""
-    if _scan_heads_a_step(k, v):
+    chunk = a.shape[-1]
+    if _scan_heads_a_step(k, v, chunk):
         return chunk_scan_kernel(q, k, v, gamma, beta, inside, a)
-    _record_scan_path(0, k.shape[3])
-    return _chunk_scan(q, k, v, gamma, beta, inside, a)
+    _record_scan_path(0, chunk)
+    return _chunk_scan_of_tokens(q, k, v, gamma, beta, inside, a)
 
 
 def _record_chunks(count: int, chunk: int, heads: int,
@@ -1181,13 +1291,19 @@ def _record_chunks(count: int, chunk: int, heads: int,
     metrics.LINATTN_DECAY_WIDTH_LAST.set(decay_width)
 
 
+def _operands(kernels_step: int) -> str:
+    """The gauges' label: how ``q``, ``k``, ``v`` and ``gamma`` cross HBM."""
+    return "tokens_major" if kernels_step else "plain"
+
+
 def _record_pair_path(chunks_a_step: int, sub: int) -> None:
     """As the program is lowered (at trace time where the shapes alone
     decide): the form of :func:`kimi_delta_rule`'s pair terms it holds,
     the kernels' chunks a grid step or 0 for the plain form."""
     from .. import metrics
 
-    metrics.LINATTN_PAIR_KERNEL_LAST.set(chunks_a_step, sub=str(sub))
+    metrics.LINATTN_PAIR_KERNEL_LAST.set(
+        chunks_a_step, sub=str(sub), operands=_operands(chunks_a_step))
 
 
 def _pair_form(kernel: bool, step: int, sub: int, **_) -> None:
@@ -1203,7 +1319,8 @@ def _record_scan_path(heads_a_step: int, chunk: int) -> None:
     holds, the kernels' heads a grid step or 0 for the plain form."""
     from .. import metrics
 
-    metrics.LINATTN_SCAN_KERNEL_LAST.set(heads_a_step, chunk=str(chunk))
+    metrics.LINATTN_SCAN_KERNEL_LAST.set(
+        heads_a_step, chunk=str(chunk), operands=_operands(heads_a_step))
 
 
 def _scan_form(kernel: bool, step: int, chunk: int, **_) -> None:

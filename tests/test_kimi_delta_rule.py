@@ -15,9 +15,16 @@ a TPU) and ``pair_terms_kernel`` (a Pallas kernel and its backward kernel:
 the program lowered for a TPU). The kernel in interpret mode is the plain
 form, values and the three cotangents, and every case of the rule runs
 through both (``path``): on the kernel's path the test puts the kernel
-where the lowering platform would have."""
+where the lowering platform would have.
+
+The kernels take ``q``, ``k``, ``v``, ``gamma`` and give their cotangents
+tokens-major, ``[B, S, H, d]`` read as ``[B, S, H * d]``: the tests feed
+them so and hold them to the plain forms on ``_by_head``'s view, and the
+rule's jaxpr with the kernels in it holds no ``transpose`` of anything ``d``
+wide."""
 
 import contextlib
+import functools
 import hashlib
 import re
 
@@ -79,8 +86,7 @@ def path(request, monkeypatch):
     if request.param == "kernel":
         monkeypatch.setattr(
             linear_attention, "_pair_terms_where_lowered",
-            lambda q, k, gamma, sub, dtype: linear_attention.pair_terms_kernel(
-                q, k, gamma, sub, dtype, True))
+            lambda *a: linear_attention.pair_terms_kernel(*a, True))
     return request.param
 
 
@@ -173,13 +179,17 @@ def test_a_ragged_sequence_or_sub_block_is_refused(seq, chunk, sub, path):
 
 def test_the_scope_and_the_gauges_say_which_rule_the_step_holds(path):
     args = inputs(0.3)
-    metrics.LINATTN_PAIR_KERNEL_LAST.set(-1, sub="8")
+    layout = {"plain": "plain", "kernel": "tokens_major"}[path]
+    metrics.LINATTN_PAIR_KERNEL_LAST.set(-1, sub="8", operands=layout)
     text = jax.jit(rule(32, 8)).lower(*args).as_text(debug_info=True)
     assert "hvd.linattn.scan" in text
-    # said when the program is lowered: no kernel there, or twelve chunks
-    # in grid steps of six where the test has put the kernel, interpreted
-    assert metrics.LINATTN_PAIR_KERNEL_LAST.labels(sub="8").get() == {
-        "plain": 0, "kernel": 6}[path]
+    # said when the program is lowered: no kernel there, or a sequence's two
+    # chunks a grid step (of its three heads) where the test has put the
+    # kernel, interpreted; the label says how q, k and gamma cross HBM
+    assert metrics.LINATTN_PAIR_KERNEL_LAST.labels(
+        sub="8", operands=layout).get() == {"plain": 0, "kernel": 2}[path]
+    assert metrics.LINATTN_SCAN_KERNEL_LAST.labels(
+        chunk="32", operands="plain").get() == 0
     assert metrics.LINATTN_CHUNKS_LAST.labels(
         chunk="32", heads_here=str(H)).get() == S // 32
     assert metrics.LINATTN_DECAY_WIDTH_LAST.labels().get() == DK
@@ -192,25 +202,40 @@ def test_the_scope_and_the_gauges_say_which_rule_the_step_holds(path):
 PAIR_FORMS = [(64, 16, 128), (16, 4, 16)]  # (chunk, sub-block, d_k)
 
 
-def pair_operands(rate, chunk, width, dtype, seed=5):
-    """``q``, ``k`` in ``dtype`` and a falling float32 ``gamma``, ``[1, 2,
-    3, chunk, width]`` (six chunks: grid steps of three), and a cotangent
-    for each result."""
+def tokens_major(x):
+    """``[B, H, N, C, d]`` as the kernels take it, ``[B, S, H, d]``: the
+    inverse of ``linear_attention._by_head``."""
+    batch, heads, count, chunk, width = x.shape
+    return jnp.moveaxis(x, 1, 3).reshape(batch, count * chunk, heads, width)
+
+
+def pair_operands(rate, chunk, width, dtype, seed=5, count=3):
+    """``q``, ``k`` in ``dtype`` and a float32 ``gamma`` that falls inside
+    each chunk, ``[1, count * chunk, 2, width]`` as the kernels take them
+    (``count`` chunks of two heads), and a cotangent for each result, ``[1,
+    2, count, chunk, chunk]``."""
     keys = jax.random.split(jax.random.PRNGKey(seed), 5)
-    shape = (1, 2, 3, chunk, width)
+    shape = (1, 2, count, chunk, width)
     q, k = (jax.random.normal(key, shape).astype(dtype) for key in keys[:2])
     gamma = jnp.cumsum(-rate * jax.random.uniform(
         keys[2], shape, minval=0.5, maxval=1.0), -2)
     bars = tuple(jax.random.normal(key, shape[:-1] + (chunk,))
                  for key in keys[3:])
-    return (q, k, gamma), bars
+    return tuple(tokens_major(x) for x in (q, k, gamma)), bars
+
+
+def plain_pair_terms(chunk, sub, dtype):
+    """``_pair_terms`` of tokens-major operands, through ``_by_head``."""
+    return lambda *a: linear_attention._pair_terms(
+        *(linear_attention._by_head(x, chunk) for x in a), sub, dtype)
 
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 @pytest.mark.parametrize("rate", sorted(RATES))
 @pytest.mark.parametrize("chunk,sub,width", PAIR_FORMS)
 def test_the_kernels_are_the_plain_pair_terms(chunk, sub, width, rate, dtype):
-    """``pair_terms_kernel`` interpreted against ``_pair_terms``: both
+    """``pair_terms_kernel`` interpreted, fed ``[B, S, H, d]``, against
+    ``_pair_terms`` on the head-major view of the same arrays: both
     results to float32's rounding (the same reference rows, the same
     roundings to ``dtype``), the strict upper triangle exactly zero, and
     ``dq``, ``dk``, ``dgamma`` against ``jax.vjp`` of the plain form: to
@@ -219,11 +244,10 @@ def test_the_kernels_are_the_plain_pair_terms(chunk, sub, width, rate, dtype):
     pairs' cotangents once more on the way)."""
     dtype = jnp.dtype(dtype)
     operands, bars = pair_operands(RATES[rate], chunk, width, dtype)
-    want, plain_vjp = jax.vjp(
-        lambda *a: linear_attention._pair_terms(*a, sub, dtype), *operands)
+    want, plain_vjp = jax.vjp(plain_pair_terms(chunk, sub, dtype), *operands)
     got, kernel_vjp = jax.vjp(
-        lambda *a: linear_attention.pair_terms_kernel(*a, sub, dtype, True),
-        *operands)
+        lambda *a: linear_attention.pair_terms_kernel(
+            *a, chunk, sub, dtype, True), *operands)
     for name, a, b in zip(("inside", "a"), got, want):
         assert a.dtype == jnp.float32 and a.shape == b.shape, name
         assert bool((jnp.triu(a, 1) == 0).all()), name
@@ -244,22 +268,31 @@ def test_the_kernels_are_the_plain_pair_terms(chunk, sub, width, rate, dtype):
 
 
 def test_a_grid_step_takes_the_chunks_that_divide_their_number(monkeypatch):
-    """Eight chunks a grid step where eight divide them, else the largest
-    divisor under it (six chunks go one grid step, or two of three under a
-    limit of four, or six of one); the gauge says which as the call is
-    lowered, and no result or cotangent depends on the split."""
-    operands, bars = pair_operands(0.3, 16, 16, jnp.float32)
+    """A grid step of the pair kernels is chunks x heads: four chunks of a
+    sequence where four divide them, else the largest divisor under the
+    limit (a sequence's six chunks go three a grid step, or all six under a
+    limit of eight, or one), of as many heads as the chunk loop's kernels
+    take (two here: both, or one under a limit of one); the gauge says the
+    chunks as the call is lowered, and no result or cotangent depends on
+    the split."""
+    operands, bars = pair_operands(0.3, 16, 16, jnp.float32, count=6)
+    assert linear_attention.PAIR_CHUNKS_A_STEP == 4
     seen = []
-    for limit, step in ((8, 6), (4, 3), (1, 1)):
-        monkeypatch.setattr(linear_attention, "PAIR_CHUNKS_A_STEP", limit)
+    for chunks, heads, step in ((4, 8, (3, 2)), (8, 8, (6, 2)),
+                                (1, 1, (1, 1))):
+        monkeypatch.setattr(linear_attention, "PAIR_CHUNKS_A_STEP", chunks)
+        monkeypatch.setattr(linear_attention, "SCAN_HEADS_A_STEP", heads)
+        how = linear_attention._how(operands[1], 16, 4, jnp.float32, True)
+        assert (how["step"], how["heads"]) == step
         got, vjp = jax.vjp(
             lambda *a: linear_attention.pair_terms_kernel(
-                *a, 4, jnp.float32, True), *operands)
-        seen.append(got + vjp(bars))
-        assert metrics.LINATTN_PAIR_KERNEL_LAST.labels(sub="4").get() == step
+                *a, 16, 4, jnp.float32, True), *operands)
+        seen.append(tuple(got) + vjp(bars))
+        assert metrics.LINATTN_PAIR_KERNEL_LAST.labels(
+            sub="4", operands="tokens_major").get() == step[0]
     for other in seen[1:]:
         jax.tree.map(np.testing.assert_array_equal, seen[0], other)
-    want = linear_attention._pair_terms(*operands, 4, jnp.float32)
+    want = plain_pair_terms(16, 4, jnp.float32)(*operands)
     jax.tree.map(lambda a, b: np.testing.assert_allclose(
         a, b, rtol=0, atol=1e-5), seen[0][:2], want)
 
@@ -284,11 +317,11 @@ def test_a_moved_rounding_point_is_told(fault, monkeypatch):
                       for top, cube in pieces], far
 
     operands, _ = pair_operands(RATES["mild"], 64, 128, jnp.bfloat16)
-    want = linear_attention._pair_terms(*operands, 16, jnp.bfloat16)
+    want = plain_pair_terms(64, 16, jnp.bfloat16)(*operands)
 
     def furthest():  # (a function of its own each time: nothing is cached)
         got = jax.jit(lambda *a: linear_attention.pair_terms_kernel(
-            *a, 16, jnp.bfloat16, True))(*operands)
+            *a, 64, 16, jnp.bfloat16, True))(*operands)
         return [float(jnp.abs(a - b).max() / jnp.abs(b).max())
                 for a, b in zip(got, want)]
 
@@ -309,12 +342,12 @@ def lowered(fn, what: str, *args) -> str:
 
 def running_sums_products(text: str):
     """The precisions of the ``dot_general``s that take ``g`` in chunks,
-    or ``gamma``'s cotangent (a ``[B, H, N, C, d_k]`` float32 operand in
-    whatever order), against a ``[..., C, C]`` one: the running sum and
-    its transpose. No other product of the rule has a five-dimensional
-    operand ``d_k`` wide."""
+    or ``gamma``'s cotangent (a ``[B, N, C, H * d_k]`` float32 operand, as
+    both lie: a chunk's rows of every head's lanes), against a ``[..., C,
+    C]`` one: the running sum and its transpose. No other product of the
+    rule has an operand of that shape."""
     chunk = TEXT_CHUNK
-    g_dims, found = sorted([B, H, S // chunk, chunk, DK]), []
+    g_dims, found = sorted([B, S // chunk, chunk, H * DK]), []
     for line in text.splitlines():
         types = re.search(
             r"stablehlo\.dot_general.*precision = \[(\w+), (\w+)\].*"
@@ -415,10 +448,11 @@ def scanned(monkeypatch):
 
 
 def scan_operands(form, rate, dtype, seed=6):
-    """What ``kimi_delta_rule`` hands the chunk loop, ``[B, H, N, C, ...]``
-    (``form``: a name of ``SCAN_FORMS`` or such a tuple):
-    ``q``, ``k``, ``v`` in ``dtype``, a falling float32 ``gamma``, ``beta``,
-    the pair terms of ``q``, ``k`` and ``gamma``; and a cotangent of ``o``."""
+    """What ``kimi_delta_rule`` hands the chunk loop (``form``: a name of
+    ``SCAN_FORMS`` or such a tuple): ``q``, ``k``, ``v`` in ``dtype`` and a
+    float32 ``gamma`` that falls inside each chunk, ``[B, S, H, d]``;
+    ``beta [B, H, N, C, 1]`` and the pair terms of ``q``, ``k`` and
+    ``gamma``, ``[B, H, N, C, C]``; and a cotangent of ``o``."""
     batch, heads, count, size, d_k, d_v = SCAN_FORMS.get(form, form)
     keys = jax.random.split(jax.random.PRNGKey(seed), 6)
     lead = (batch, heads, count, size)
@@ -433,6 +467,7 @@ def scan_operands(form, rate, dtype, seed=6):
     inside, a = linear_attention._pair_terms(q, k, gamma, size // 4, dtype)
     o_bar = jax.random.normal(
         keys[5], (batch, count * size, heads, d_v)).astype(dtype)
+    q, k, v, gamma = (tokens_major(x) for x in (q, k, v, gamma))
     return (q, k, v, gamma, beta, inside, a), o_bar
 
 
@@ -443,7 +478,8 @@ SCAN_NAMES = ("dq", "dk", "dv", "dgamma", "dbeta", "dinside", "da")
 @pytest.mark.parametrize("rate", sorted(RATES))
 @pytest.mark.parametrize("form", sorted(SCAN_FORMS))
 def test_the_scan_kernels_are_the_plain_chunk_form(form, rate, dtype):
-    """``chunk_scan_kernel`` interpreted against ``_chunk_scan``: ``o`` and
+    """``chunk_scan_kernel`` interpreted, fed ``[B, S, H, d]``, against
+    ``_chunk_scan`` on the head-major view of the same arrays: ``o`` and
     the seven cotangents (with the pair kernels' own, the rule's five
     gradients). In float32 to float32's rounding (the solve by other sums,
     the products of three bfloat16 terms an operand). In bfloat16 the same
@@ -455,7 +491,8 @@ def test_the_scan_kernels_are_the_plain_chunk_form(form, rate, dtype):
     dtype = jnp.dtype(dtype)
     operands, o_bar = scan_operands(form, RATES[rate], dtype)
     with jax.default_matmul_precision("highest"):
-        want, plain_vjp = jax.vjp(linear_attention._chunk_scan, *operands)
+        want, plain_vjp = jax.vjp(linear_attention._chunk_scan_of_tokens,
+                                  *operands)
         got, kernel_vjp = jax.vjp(
             lambda *a: linear_attention.chunk_scan_kernel(*a, True),
             *operands)
@@ -540,12 +577,12 @@ def test_a_grid_step_takes_two_or_eight_heads(monkeypatch):
             *operands)
         seen.append((got,) + vjp(o_bar))
         assert metrics.LINATTN_SCAN_KERNEL_LAST.labels(
-            chunk=str(size)).get() == step
+            chunk=str(size), operands="tokens_major").get() == step
     for other in seen[1:]:
         jax.tree.map(lambda a, b: np.testing.assert_allclose(
             a, b, rtol=0, atol=1e-6 * float(jnp.abs(a).max())),
             seen[0], other)
-    want = linear_attention._chunk_scan(*operands)
+    want = linear_attention._chunk_scan_of_tokens(*operands)
     np.testing.assert_allclose(seen[0][0], want, rtol=0, atol=1e-6)
 
 
@@ -604,25 +641,96 @@ def test_a_shape_that_fills_no_tile_takes_the_plain_chunk_loop():
 
     gauge = metrics.LINATTN_SCAN_KERNEL_LAST
     toy, _ = scan_operands("toy", 0.3, jnp.float32)
-    gauge.set(-1, chunk="16")
+    gauge.set(-1, chunk="16", operands="plain")
     assert "hvd_kda_chunk_scan" not in primitives(*toy)
-    assert gauge.labels(chunk="16").get() == 0
+    assert gauge.labels(chunk="16", operands="plain").get() == 0
     wide, _ = scan_operands("the_cells_widths", 0.3, jnp.bfloat16)
-    gauge.set(-1, chunk="64")
+    gauge.set(-1, chunk="64", operands="plain")
+    gauge.set(-1, chunk="64", operands="tokens_major")
     assert "hvd_kda_chunk_scan" in primitives(*wide)
-    assert gauge.labels(chunk="64").get() == -1  # not yet lowered
+    assert gauge.labels(chunk="64", operands="plain").get() == -1  # not yet
     text = jax.jit(linear_attention._chunk_scan_where_lowered).lower(
         *wide).as_text()
-    assert gauge.labels(chunk="64").get() == 0
+    assert gauge.labels(chunk="64", operands="plain").get() == 0
+    assert gauge.labels(chunk="64", operands="tokens_major").get() == -1
     assert "tpu_custom_call" not in text and "while" in text
     jax.jit(lambda *a: linear_attention.chunk_scan_kernel(*a, True)).lower(
         *wide)
-    assert gauge.labels(chunk="64").get() == (
+    assert gauge.labels(chunk="64", operands="tokens_major").get() == (
         linear_attention.SCAN_HEADS_A_STEP) == 8
     # a width of 64, seven heads or a chunk of 48 fill no tile
     q, k, v = wide[:3]
-    assert not linear_attention._scan_heads_a_step(k[..., :64], v)
-    assert not linear_attention._scan_heads_a_step(k, v[..., :64])
-    assert not linear_attention._scan_heads_a_step(k[:, :7], v[:, :7])
-    assert not linear_attention._scan_heads_a_step(k[:, :, :, :48],
-                                                   v[:, :, :, :48])
+    fills = linear_attention._scan_heads_a_step
+    assert fills(k, v, 64) == 8
+    assert not fills(k[..., :64], v, 64)
+    assert not fills(k, v[..., :64], 64)
+    assert not fills(k[:, :, :7], v[:, :, :7], 64)
+    assert not fills(k, v, 48)
+
+
+# --- the layout the kernels take: nothing ``d`` wide is transposed
+
+KERNEL_FORMS = {  # what each primitive's lowering for a TPU traces
+    "hvd_kda_pair_terms": linear_attention._forward_by_kernel,
+    "hvd_kda_pair_terms_backward": linear_attention._backward_by_kernel,
+    "hvd_kda_chunk_scan": linear_attention._scan_forward_by_kernel,
+    "hvd_kda_chunk_scan_backward": linear_attention._scan_backward_by_kernel,
+}
+
+
+def under_the_scope(jaxpr, met: list, moved: list, least: int,
+                    stack: str = "") -> None:
+    """Walks ``jaxpr`` and everything it calls (``stack``: the name stacks
+    of the calls around it), a ``hvd_kda_*`` primitive's kernel form (the
+    ``pallas_call`` and what stands around it) in the primitive's place:
+    ``met`` gains every such primitive's name and ``moved`` every
+    ``transpose`` of ``least`` elements or more, both under
+    ``hvd.linattn.scan``."""
+    for eqn in jaxpr.eqns:
+        here = f"{stack}/{eqn.source_info.name_stack}"
+        name = eqn.primitive.name
+        if "hvd.linattn.scan" not in here:
+            assert name not in KERNEL_FORMS, eqn
+        elif name in KERNEL_FORMS:
+            met.append(name)
+            form = jax.make_jaxpr(functools.partial(
+                KERNEL_FORMS[name], **eqn.params))(*(
+                    jax.ShapeDtypeStruct(v.aval.shape, v.aval.dtype)
+                    for v in eqn.invars))
+            under_the_scope(form.jaxpr, met, moved, least, here)
+        elif name == "transpose" and eqn.invars[0].aval.size >= least:
+            moved.append((eqn.invars[0].aval.shape, eqn.outvars[0].aval.shape,
+                          eqn.params["permutation"]))
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            under_the_scope(inner, met, moved, least, here)
+
+
+@pytest.mark.parametrize("what", ["value", "gradient"])
+def test_nothing_d_wide_is_transposed_under_the_scope(what):
+    """The jaxpr of ``kimi_delta_rule`` at the cell's shapes (one sequence
+    of 8,192 in 32 heads of 128, chunks of 64 in sub-blocks of 16; traced,
+    nothing allocated or run), with each of the four primitives replaced by
+    what its lowering for a TPU traces: the ``pallas_call`` takes ``q``,
+    ``k``, ``v``, ``gamma`` and gives ``o``, ``dq``, ``dk``, ``dv``,
+    ``dgamma`` where they lie, so no ``transpose`` of an array as large as
+    ``q`` is left, forward or backward (``beta``'s, one number a token, and
+    nothing else). ``gamma``'s running sum and its transposed sum are
+    products whose results lead with batch and chunk."""
+    wide = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16)
+    args = (wide, wide, wide,
+            jax.ShapeDtypeStruct(wide.shape, jnp.float32),
+            jax.ShapeDtypeStruct(wide.shape[:3], jnp.float32))
+
+    def fn(*a):
+        return linear_attention.kimi_delta_rule(*a, chunk=64, sub=16)
+
+    if what == "gradient":
+        fn = jax.grad(lambda *a, fn=fn: fn(*a).astype(jnp.float32).sum(),
+                      argnums=range(5))
+    met, moved = [], []
+    under_the_scope(jax.make_jaxpr(fn)(*args).jaxpr, met, moved,
+                    least=8192 * 32 * 128)
+    assert sorted(met) == sorted(
+        name for name in KERNEL_FORMS
+        if what == "gradient" or not name.endswith("_backward")), met
+    assert not moved, moved

@@ -351,3 +351,79 @@ def test_the_latent_layer_lowers_to_the_text_it_had():
 
     text = jax.jit(jax.grad(loss)).lower(params, x).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == PARENTS_LATENT_TEXT
+
+
+# --- the norms a head, where the heads' lanes lie (PR 53)
+
+HEADS, WIDTH = 4, 16
+
+
+def by_head_norms(dtype, seed=0):
+    """``x [2, 24, heads * width]`` in ``dtype``, a scale ``[width]`` and a
+    cotangent."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    x = (3 * jax.random.normal(keys[0], (2, 24, HEADS * WIDTH))).astype(dtype)
+    scale = 1 + 0.1 * jax.random.normal(keys[1], (WIDTH,))
+    return x, scale, jax.random.normal(keys[2], x.shape)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("norm", ["l2norm", "rms_norm"])
+def test_a_heads_norm_where_the_lanes_lie_is_the_norm_a_head(norm, dtype):
+    """``l2norm_of_heads`` and ``HeadsRMSNorm`` on ``[B, S, H * d]`` are
+    ``parts.l2norm`` and ``parts.RMSNorm`` on ``[B, S, H, d]``, values and
+    gradients (``x``'s and the scale's), to float32's rounding: the sums a
+    head are float32 products at full precision (another order of the same
+    sum), the factors come back to the lanes exactly; the scale is the same
+    leaf ``[d]``."""
+    from horovod_tpu.models import parts
+
+    x, scale, bar = by_head_norms(jnp.dtype(dtype))
+    shape = x.shape[:2] + (HEADS, WIDTH)
+
+    def flat(x, scale):
+        if norm == "l2norm":
+            return kimi_linear.l2norm_of_heads(x, HEADS)
+        return kimi_linear.HeadsRMSNorm(1e-5, HEADS).apply(
+            {"params": {"scale": scale}}, x)
+
+    def a_head(x, scale):
+        if norm == "l2norm":
+            return parts.l2norm(x.reshape(shape)).reshape(x.shape)
+        return parts.RMSNorm(1e-5).apply(
+            {"params": {"scale": scale}}, x.reshape(shape)).reshape(x.shape)
+
+    got, got_vjp = jax.vjp(flat, x, scale)
+    want, want_vjp = jax.vjp(a_head, x, scale)
+    assert got.dtype == want.dtype == jnp.float32
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=2e-6 * float(jnp.abs(want).max()))
+    for name, a, b in zip(("x", "scale"), got_vjp(bar), want_vjp(bar)):
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        room = 2e-6 if dtype == "float32" or name == "scale" else 2e-2
+        np.testing.assert_allclose(
+            a, b, rtol=0, atol=room * float(jnp.abs(b).max()) + 1e-9,
+            err_msg=name)
+
+
+def test_the_heads_norm_keeps_the_trees_leaf_and_a_policy_keeps_nothing():
+    """The scale is ``parts.RMSNorm``'s leaf (``scale [d]``, ones), so the
+    cell's parameters and their count stand; and both products have the
+    batch as a batch dimension, so the decoders' recomputation policy,
+    which keeps every product without one, keeps neither the sums nor the
+    factors spread over the lanes (134 MB a norm at the cell's shape)."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    from horovod_tpu.models import parts
+
+    x, _, _ = by_head_norms(jnp.float32)
+    norm = kimi_linear.HeadsRMSNorm(1e-5, HEADS)
+    tree = jax.eval_shape(norm.init, jax.random.PRNGKey(0), x)["params"]
+    assert jax.tree.map(lambda leaf: (leaf.shape, leaf.dtype), tree) == {
+        "scale": ((WIDTH,), jnp.float32)}
+    kept = saved_residuals(jax.checkpoint(
+        lambda x: kimi_linear.l2norm_of_heads(x, HEADS),
+        policy=parts.save_kernels_and_projections), x)
+    assert all(source.startswith(("from the argument", "from a constant"))
+               for _, source in kept), kept

@@ -17,6 +17,7 @@ and this is the only test file that does so.
 from __future__ import annotations
 
 import json
+import math
 import os
 import re
 
@@ -312,13 +313,19 @@ def test_kimis_pair_kernels_compile_at_its_widths(one_chip):
     compiler takes it. The program lowered for the TPU forms the pair terms
     in two Pallas kernels (``pair_terms_kernel`` and its backward) named
     ``kda_pair_terms`` (not ``flash_attention``, by which the benchmark finds
-    the attention kernels) under the scope the cell's readers sum, eight
-    chunks a grid step (the gauge, set when the program is lowered). What
+    the attention kernels) under the scope the cell's readers sum, four
+    chunks of eight heads a grid step (the gauge, set when the program is
+    lowered, whose label says the operands are tokens-major). What
     the plain form wrote to HBM is gone: no array of ``k_right``'s shape
     (``[..., 4, 64, 128]``, four times ``k``), no ``sub x sub x d`` cube.
-    Arguments + temporaries are 2,358,731,264 B and may not pass that by
-    1% (the plain form's program: 2,687,887,872). The same call lowered
-    for the CPU holds no custom call and runs."""
+    **And no array is head-major** (PR 53): the four kernels take ``q``,
+    ``k``, ``v``, ``gamma`` and give their cotangents as ``[1, 8192,
+    4096]``, so nothing of the shape ``[1, 32, 128, 64, 128]`` that
+    ``chunks()`` made (and ``dgamma`` came back through) is in the text.
+    Arguments + temporaries are 1,748,331,008 B and may not pass that by
+    1% (2,358,731,264 with head-major operands; the plain form's program:
+    2,687,887,872). The same call lowered for the CPU holds no custom call
+    and runs."""
     import jax
     import jax.numpy as jnp
 
@@ -338,8 +345,10 @@ def test_kimis_pair_kernels_compile_at_its_widths(one_chip):
     compiled = grads.lower(
         wide, wide, wide, shaped(1, 8192, 32, 128, dtype=jnp.float32),
         shaped(1, 8192, 32, dtype=jnp.float32)).compile()
-    assert metrics.LINATTN_PAIR_KERNEL_LAST.labels(sub="16").get() == 8
-    assert metrics.LINATTN_SCAN_KERNEL_LAST.labels(chunk="64").get() == 8
+    assert metrics.LINATTN_PAIR_KERNEL_LAST.labels(
+        sub="16", operands="tokens_major").get() == 4
+    assert metrics.LINATTN_SCAN_KERNEL_LAST.labels(
+        chunk="64", operands="tokens_major").get() == 8
     text = compiled.as_text()
     kernels = kernel_instructions(text)
     # since PR 51 the solve and the chunk loop are two kernels of their own
@@ -357,9 +366,10 @@ def test_kimis_pair_kernels_compile_at_its_widths(one_chip):
         "hvd.linattn.scan"}
     assert not re.search(r"\[[\d,]*4,64,128\]", text)
     assert not re.search(r"\[[\d,]*16,16,128\]", text)
+    assert not re.search(r"\[(1,)?32,128,64,128\]", text)
     planned = compiled.memory_analysis()
     assert (planned.argument_size_in_bytes + planned.temp_size_in_bytes
-            <= 1.01 * 2_358_731_264)
+            <= 1.01 * 1_748_331_008)
 
     # the CPU's program of the same call: the plain form, and it runs
     keys = jax.random.split(jax.random.PRNGKey(0), 5)
@@ -369,8 +379,10 @@ def test_kimis_pair_kernels_compile_at_its_widths(one_chip):
     g = -jax.random.uniform(keys[3], (1, 128, 2, 128))
     beta = jax.nn.sigmoid(jax.random.normal(keys[4], (1, 128, 2)))
     lowered = grads.lower(q, k, v, g, beta)
-    assert metrics.LINATTN_PAIR_KERNEL_LAST.labels(sub="16").get() == 0
-    assert metrics.LINATTN_SCAN_KERNEL_LAST.labels(chunk="64").get() == 0
+    assert metrics.LINATTN_PAIR_KERNEL_LAST.labels(
+        sub="16", operands="plain").get() == 0
+    assert metrics.LINATTN_SCAN_KERNEL_LAST.labels(
+        chunk="64", operands="plain").get() == 0
     assert "tpu_custom_call" not in lowered.as_text()
     assert re.search(r"4x64x128x", lowered.as_text())  # k_right is there
     assert all(bool(jnp.isfinite(x.astype(jnp.float32)).all())
@@ -426,6 +438,47 @@ def test_a_recomputed_layer_forms_kimis_pair_terms_again(one_chip):
     assert len(kernels) == 12, kernels
 
 
+def test_kimis_delta_mixer_moves_nothing_as_large_as_its_queries(one_chip):
+    """One Kimi Delta Attention mixer at the cell's widths (8,192 tokens of
+    2,304 into 32 heads of 128), value and every gradient, as the v5e's
+    compiler takes it: from the projections to the output's gate no
+    ``copy``, ``transpose`` or ``reshape`` instruction moves an array as
+    large as the queries (33.5 M elements). The four kernels read and write
+    ``[1, 8192, 4096]`` (PR 53), and the norms a head in front of them and
+    behind them (``l2norm_of_heads``, ``HeadsRMSNorm``) sum a head's lanes
+    and spread the factor back over them as products: a reduction over
+    part of the lanes made the compiler lay ``[S, H * d]`` out a head a row
+    and back, a dozen such passes a layer (16 ms of the cell's step)."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.models import kimi_linear
+
+    mixer = kimi_linear.KimiDeltaAttention(kimi_linear.KIMI_LINEAR_48B_A3B)
+    x = jax.ShapeDtypeStruct((1, 8192, 2304), jnp.bfloat16,
+                             sharding=one_chip)
+    params = jax.tree.map(
+        lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                          sharding=one_chip),
+        jax.eval_shape(mixer.init, jax.random.PRNGKey(0), x)["params"])
+
+    def loss(params, x):
+        out = mixer.apply({"params": params}, x)
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    assert len(kernel_instructions(text)) == 4
+    moved = []
+    for line in text.splitlines():
+        at = re.match(r"\s*(?:ROOT )?%?(\S+) = \w+\[([\d,]*)\]\S* "
+                      r"(copy|transpose|reshape)\(", line)
+        if at and math.prod(int(n) for n in at.group(2).split(",")
+                            if n) >= 8192 * 32 * 128:
+            moved.append((at.group(1), at.group(2)))
+    assert not moved, moved
+
+
 def test_kimi_linears_step_holds_the_chunk_loops_kernels_three_a_layer(
         one_chip):
     """The Kimi Linear cell's train step as ``benchmark/aot.py`` builds it,
@@ -446,7 +499,8 @@ def test_kimi_linears_step_holds_the_chunk_loops_kernels_three_a_layer(
         import lowered_sha
     finally:
         sys.path.remove(os.path.join(REPO_ROOT, "tools"))
-    metrics.LINATTN_SCAN_KERNEL_LAST.set(-1, chunk="64")
+    metrics.LINATTN_SCAN_KERNEL_LAST.set(-1, chunk="64",
+                                         operands="tokens_major")
     try:
         text = lowered_sha.lowered_text("kimi-linear-48b-a3b_s8192_e8_dp1")
     finally:
@@ -459,7 +513,8 @@ def test_kimi_linears_step_holds_the_chunk_loops_kernels_three_a_layer(
     assert {name: names.count(name) for name in set(names)} == {
         "flash_attention": 3, linear_attention.PAIR_KERNEL_NAME: 12,
         linear_attention.SCAN_KERNEL_NAME: 12}
-    assert metrics.LINATTN_SCAN_KERNEL_LAST.labels(chunk="64").get() == 8
+    assert metrics.LINATTN_SCAN_KERNEL_LAST.labels(
+        chunk="64", operands="tokens_major").get() == 8
 
 
 @pytest.mark.parametrize("rows, seq, groups", [(24, 512, (2, 1)),

@@ -281,9 +281,12 @@ def check_two_widths(heads=32, seq=8192, calls=20) -> None:
 
 def check_pair_terms(heads=32, chunks=128, size=64, sub=16, width=128,
                      calls=20) -> None:
-    """``ops.linear_attention.pair_terms_kernel`` against ``_pair_terms``,
-    both compiled on the chip, at the Kimi Linear cell's ``[1, 32, 128, 64,
-    128]`` in bfloat16 with a float32 ``gamma`` that falls by 0.05, 1.6 and
+    """``ops.linear_attention.pair_terms_kernel`` (operands tokens-major,
+    ``[1, 8192, 32, 128]``, as the cell's projections write them) against
+    ``_pair_terms`` on the head-major view of the same arrays (``_by_head``:
+    the transposes are part of what the plain form costs),
+    both compiled on the chip, at the Kimi Linear cell's shape
+    in bfloat16 with a float32 ``gamma`` that falls by 0.05, 1.6 and
     20 a token: the two results (float32's rounding: the same reference
     rows and the same roundings to bfloat16; the strict upper triangle
     exactly zero) and ``dq``, ``dk``, ``dgamma`` under random cotangents
@@ -299,10 +302,15 @@ def check_pair_terms(heads=32, chunks=128, size=64, sub=16, width=128,
     from horovod_tpu.ops import linear_attention
 
     dtype, shape = jnp.bfloat16, (1, heads, chunks, size, width)
+
+    def tokens_major(x):  # [B, H, N, C, d] -> [B, S, H, d]
+        return jnp.moveaxis(x, 1, 3).reshape(1, chunks * size, heads, width)
+
     forms = {
-        "plain": lambda *a: linear_attention._pair_terms(*a, sub, dtype),
+        "plain": lambda *a: linear_attention._pair_terms(
+            *(linear_attention._by_head(x, size) for x in a), sub, dtype),
         "kernel": lambda *a: linear_attention.pair_terms_kernel(
-            *a, sub, dtype)}
+            *a, size, sub, dtype)}
 
     def both(form):
         def run(q, k, gamma, bars):
@@ -314,13 +322,14 @@ def check_pair_terms(heads=32, chunks=128, size=64, sub=16, width=128,
                    "forward and backward": both(form)}
             for name, form in forms.items()}
     keys = jax.random.split(jax.random.PRNGKey(3), 5)
-    q, k = (jax.random.normal(key, shape).astype(dtype) for key in keys[:2])
+    q, k = (tokens_major(jax.random.normal(key, shape).astype(dtype))
+            for key in keys[:2])
     bars = tuple(jax.random.normal(key, shape[:-1] + (size,))
                  for key in keys[3:])
     names = ("inside", "a", "dq", "dk", "dgamma")
     for rate in (0.05, 1.6, 20.0):
-        gamma = jnp.cumsum(-rate * jax.random.uniform(
-            keys[2], shape, minval=0.5, maxval=1.0), -2)
+        gamma = tokens_major(jnp.cumsum(-rate * jax.random.uniform(
+            keys[2], shape, minval=0.5, maxval=1.0), -2))
         want, got = (
             [np.asarray(x, np.float32)
              for x in runs[name]["forward and backward"](q, k, gamma, bars)]
@@ -348,7 +357,7 @@ def check_pair_terms(heads=32, chunks=128, size=64, sub=16, width=128,
             jax.block_until_ready(out)
             print(f"  {name}, {what}: "
                   f"{(time.perf_counter() - t0) / calls * 1e3:.3f} ms a call "
-                  f"at [1, {heads}, {chunks}, {size}, {width}]")
+                  f"at [1, {chunks * size}, {heads}, {width}]")
 
 
 def check_kda_scan(heads=32, chunks=128, size=64, sub=16, width=128,
@@ -406,7 +415,7 @@ def check_kda_scan(heads=32, chunks=128, size=64, sub=16, width=128,
     def plain_rule(*t):
         lowered = linear_attention._chunk_scan_where_lowered
         linear_attention._chunk_scan_where_lowered = (
-            linear_attention._chunk_scan)
+            linear_attention._chunk_scan_of_tokens)
         try:
             return rule(*t)
         finally:
@@ -463,15 +472,12 @@ def check_kda_scan(heads=32, chunks=128, size=64, sub=16, width=128,
         timed(name + " rule", "forward and backward", both(forms[name]),
               *args, o_bar)
 
-    def chunked(x):  # as kimi_delta_rule's chunks
-        return jnp.moveaxis(x.reshape((1, chunks, size) + x.shape[2:]), 3, 1)
-
-    operands = [chunked(x) for x in (q, k, v)]
-    gamma = jnp.cumsum(chunked(g), -2)
-    operands += [gamma, chunked(beta)[..., None],
-                 *linear_attention.pair_terms_kernel(
-                     *operands[:2], gamma, sub, dtype)]
-    for name, form in (("_chunk_scan", linear_attention._chunk_scan),
+    gamma = linear_attention._running_sums(g, size)
+    operands = [q, k, v, gamma,
+                linear_attention._by_head(beta, size)[..., None],
+                *linear_attention.pair_terms_kernel(q, k, gamma, size, sub,
+                                                    dtype)]
+    for name, form in (("_chunk_scan", linear_attention._chunk_scan_of_tokens),
                        ("chunk_scan_kernel",
                         linear_attention.chunk_scan_kernel)):
         timed(name, "forward", jax.jit(form), *operands)
