@@ -186,12 +186,18 @@ SCOPE_LINATTN_GATE = "linattn.gate"
 SCOPE_SSM_CONV = "ssm.conv"
 SCOPE_SSM_SCAN = "ssm.scan"
 SCOPE_SSM_GATE = "ssm.gate"
+#: A mixer that is a doubly gated short convolution (``models/lfm2.py``,
+#: the one module that opens it): ``C * conv(B * x)`` between the mixer's
+#: two projections, the first gate, the depth-wise causal taps and the
+#: second gate. A phase: its time is no projection's (``block.attn_proj``
+#: surrounds it). Backward too.
+SCOPE_SHORTCONV_MIX = "shortconv.mix"
 PHASE_SCOPE_NAMES = tuple(SCOPE_PREFIX + name for name in (
     SCOPE_WIRE, SCOPE_OPTIMIZER, SCOPE_ATTN_FWD, SCOPE_ATTN_BWD,
     SCOPE_MOE_ROUTE, SCOPE_MOE_DISPATCH, SCOPE_MOE_EXPERTS,
     SCOPE_MOE_COMBINE, SCOPE_LINATTN_CONV, SCOPE_LINATTN_SCAN,
     SCOPE_LINATTN_GATE, SCOPE_SSM_CONV, SCOPE_SSM_SCAN, SCOPE_SSM_GATE,
-    SCOPE_MOE_SHARED, SCOPE_MLA_ROPE))
+    SCOPE_MOE_SHARED, SCOPE_MLA_ROPE, SCOPE_SHORTCONV_MIX))
 #: Block scopes: the parts of a model (``models/*.py``) that no phase
 #: names, forward and backward. A phase inside a block stays the phase's
 #: (``profiler.owner_of``: the innermost phase scope, else the innermost

@@ -1012,6 +1012,13 @@ MTP_DEPTH_LAST = gauge(
     "(models/joyai_flash.py: num_nextn_predict_layers, each one more decoder "
     "layer scored by the main model's head against a further token): set at "
     "trace time, as hvd_attn_tiles_last is.")
+SHORTCONV_TAPS_LAST = gauge(
+    "hvd_shortconv_taps_last",
+    "Taps of the depth-wise causal convolution in the LAST traced gated "
+    "short-convolution mixer (models/lfm2.py: conv_L_cache, 3, over "
+    "channels=hidden_size lanes between the mixer's two gates): set at "
+    "trace time, as hvd_attn_tiles_last is.",
+    ("channels",))
 HEAD_LOGITS_BYTES_LAST = gauge(
     "hvd_head_logits_bytes_last",
     "Bytes of the logits that the LAST traced token cross entropy "

@@ -62,3 +62,9 @@ from .joyai_flash import (  # noqa: F401
     JoyAIFlashConfig,
     mtp_lm_loss,
 )
+from .lfm2 import (  # noqa: F401
+    LFM2_24B_A2B,
+    LFM2_TINY,
+    Lfm2,
+    Lfm2Config,
+)

@@ -1,5 +1,6 @@
 """The one experts module of the mixtures of experts here (``Olmoe``,
-``SmallThinker``, ``Sdar``, ``KimiLinear``, ``NemotronH``, ``JoyAIFlash``),
+``SmallThinker``, ``Sdar``, ``KimiLinear``, ``NemotronH``, ``JoyAIFlash``,
+``Lfm2``),
 owned by none of them: a model's window of the experts in
 ``parallel/moe.py``'s capacity slots, the auxiliary losses of a routing
 group, and what reads or cuts a model by its experts
@@ -53,7 +54,9 @@ class SparseExperts(nn.Module):
     expert)`` is a routing group's auxiliary losses, a tuple of scalars;
     their means over the groups are returned after the output. ``scores``
     and ``gate_scale`` are ``route_to_capacity``'s; ``width`` is an
-    expert's where the config's ``intermediate_size`` is a dense layer's.
+    expert's where the config's ``intermediate_size`` is a dense layer's;
+    ``gate_eps`` is ``route_to_capacity``'s too, the constant beside the sum
+    of a token's picked sigmoid scores.
     An expert is three matrices, ``down(activation(gate x) * up x)``, or
     with ``gated=False`` two, ``down(activation(up x))``, and then the tree
     has no ``experts_gate``. ``selection_bias`` (float32
@@ -69,6 +72,7 @@ class SparseExperts(nn.Module):
     width: int | None = None
     gated: bool = True
     selection_bias: Any = None
+    gate_eps: float = 1e-20
 
     @nn.compact
     def __call__(self, x, logits=None):
@@ -106,7 +110,7 @@ class SparseExperts(nn.Module):
                 top_k=cfg.top_k, first_expert=cfg.first_expert,
                 experts_here=here, gates_over_picks=self.gates_over_picks,
                 scores=self.scores, gate_scale=self.gate_scale,
-                selection_bias=self.selection_bias)
+                selection_bias=self.selection_bias, gate_eps=self.gate_eps)
             back = expert_ffn(
                 *(w.astype(cfg.dtype) for w in weights), send[..., :hidden],
                 activation=self.activation)

@@ -5,8 +5,8 @@ fallbacks, the two adapters onto the flash kernels, the state-space
 initialisers, the recomputation policy and the untied head.
 
 ``models/olmoe.py``, ``olmo_hybrid.py``, ``smallthinker.py``, ``sdar.py``,
-``granite.py``, ``kimi_linear.py``, ``nemotron_h.py`` and ``joyai_flash.py``
-import from here, from ``models/mamba2.py``, ``models/latent.py``,
+``granite.py``, ``kimi_linear.py``, ``nemotron_h.py``, ``joyai_flash.py`` and
+``lfm2.py`` import from here, from ``models/mamba2.py``, ``models/latent.py``,
 ``models/experts.py`` and ``models/loss.py``, never from one another (``tests/test_decoder_imports.py``). What builds
 parameters here is a function called inside the model's own ``@nn.compact``
 body, not a module of its own, so every leaf keeps its name and its place

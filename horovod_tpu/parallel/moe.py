@@ -133,7 +133,7 @@ def make_moe_step(axis_name: str = "hvd", capacity: int = 4, mesh=None):
 def route_to_capacity(tokens, logits, num_experts, capacity, top_k=1,
                       first_expert=0, experts_here=None,
                       gates_over_picks=False, scores="softmax",
-                      gate_scale=1.0, selection_bias=None):
+                      gate_scale=1.0, selection_bias=None, gate_eps=1e-20):
     """Capacity-factor top-k routing into fixed per-expert slots — the
     jit-compatible answer to ragged dispatch (the helper the uneven-split
     ``alltoall`` rejection points at).
@@ -152,7 +152,9 @@ def route_to_capacity(tokens, logits, num_experts, capacity, top_k=1,
     up to one over all the windows; with ``scores="sigmoid"`` the picks
     are the ``top_k`` of ``sigmoid(logits)`` in float32 and a gate is its
     pick's score, with ``gates_over_picks`` divided by the sum of the
-    token's picked scores (plus 1e-20), and in either case times
+    token's picked scores plus ``gate_eps`` (the source's own constant:
+    1e-20 in DeepSeek-V3's, Kimi Linear's, Nemotron-H's and JoyAI Flash's
+    routers, the default; 1e-6 in LFM2's), and in either case times
     ``gate_scale``: the routing of the DeepSeek-V3 family; with
     ``selection_bias``, a float32 ``[num_experts]`` vector, the picks are
     the ``top_k`` of ``sigmoid(logits) + selection_bias`` while a gate stays
@@ -198,7 +200,7 @@ def route_to_capacity(tokens, logits, num_experts, capacity, top_k=1,
                     selection_bias.astype(jnp.float32)), top_k)
                 picked = jnp.take_along_axis(score, expert, axis=1)
             if gates_over_picks:
-                picked = picked / (picked.sum(-1, keepdims=True) + 1e-20)
+                picked = picked / (picked.sum(-1, keepdims=True) + gate_eps)
             gate = picked * gate_scale
         else:
             picked, expert = lax.top_k(logits, top_k)          # [T, K]

@@ -384,4 +384,5 @@ def test_the_block_vocabulary_stands_beside_the_phases():
     assert all(name.startswith("hvd.block.")
                for name in attribution.BLOCK_SCOPE_NAMES)
     assert len(attribution.BLOCK_SCOPE_NAMES) == 7
-    assert len(attribution.PHASE_SCOPE_NAMES) == 16
+    assert len(attribution.PHASE_SCOPE_NAMES) == 17
+    assert "hvd.shortconv.mix" in attribution.PHASE_SCOPE_NAMES

@@ -1,4 +1,4 @@
-"""The eight decoders share their parts through ``models/parts.py``,
+"""The nine decoders share their parts through ``models/parts.py``,
 ``models/mamba2.py``, ``models/latent.py``, ``models/experts.py`` and
 ``models/loss.py`` and never through one another
 (ROADMAP D13); the names the benchmark calls are where ``PERF.md`` §3 says;
@@ -17,7 +17,7 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 MODELS = os.path.join(ROOT, "horovod_tpu", "models")
 DECODERS = ("olmoe", "olmo_hybrid", "smallthinker", "sdar", "granite",
-            "kimi_linear", "nemotron_h", "joyai_flash")
+            "kimi_linear", "nemotron_h", "joyai_flash", "lfm2")
 
 
 def imported_modules(path):
@@ -189,6 +189,15 @@ JOYAI_DENSE = {**LATENT, **JOYAI_NORMS, "mlp/gate/kernel": (64, 96),
 JOYAI_EXPERTS = {**LATENT, **JOYAI_NORMS, **experts(64, 24),
                  "moe/router": (64, 8), "shared/gate/kernel": (64, 24),
                  "shared/up/kernel": (64, 24), "shared/down/kernel": (24, 64)}
+# models/lfm2.py is PR 54's: its tree as that PR made it, a conv mixer's
+# three leaves and the attention's with its two scales a head's lanes wide
+LFM2_NORMS = {"ln_mixer/scale": (64,), "ln_ffn/scale": (64,)}
+LFM2_CONV = {"conv/in_proj/kernel": (64, 192), "conv/conv": (64, 3),
+             "conv/out_proj/kernel": (64, 64), **LFM2_NORMS}
+LFM2_ROUTED = {**experts(64, 24), "moe/router": (64, 8)}
+LFM2_ATTENTION = {**square_attention(64, 16), **LFM2_NORMS, **LFM2_ROUTED,
+                  "attention/q_norm/scale": (8,),
+                  "attention/k_norm/scale": (8,)}
 
 TREES = {
     "olmoe": ("Olmoe", "OLMOE_TINY", 1, {
@@ -215,6 +224,11 @@ TREES = {
            for path, shape in JOYAI_EXPERTS.items()},
         "mtp_embed_norm/scale": (64,), "mtp_hidden_norm/scale": (64,),
         "mtp_proj/kernel": (128, 64), "mtp_norm/scale": (64,)}),
+    "lfm2": ("Lfm2", "LFM2_TINY", 1, {
+        "embedding": (256, 64), "ln_out/scale": (64,),
+        **layers({**LFM2_CONV, "mlp/gate/kernel": (64, 96),
+                  "mlp/up/kernel": (64, 96), "mlp/down/kernel": (96, 64)},
+                 LFM2_ATTENTION, *[{**LFM2_CONV, **LFM2_ROUTED}] * 3)}),
 }
 
 
