@@ -1006,6 +1006,17 @@ MLA_ROPE_LANES_LAST = gauge(
     "key's), kind=kept the lanes beside them that are not turned: set at "
     "trace time, as hvd_attn_tiles_last is.",
     ("kind",))
+MLA_ROPE_PATH_LAST = gauge(
+    "hvd_mla_rope_path_last",
+    "Which way the LAST traced latent-attention layer with a rotary split "
+    "(models/latent.py) took its queries and keys to the kernels, 1 beside "
+    "the one taken and 0 beside the other: path=one_pass the kernels of "
+    "ops/rotary_split.py, which turn the rotary lanes while they lay q, k "
+    "and v out head-major (heads that pair up into whole lane tiles, an "
+    "attention_fn that takes head-major operands), path=plain turn() and "
+    "XLA's reshapes and transposes (any other shape): set at trace time, "
+    "beside hvd_mla_rope_lanes_last.",
+    ("path",))
 MTP_DEPTH_LAST = gauge(
     "hvd_mtp_depth_last",
     "Multi-token-prediction modules in the LAST traced model that has them "

@@ -19,13 +19,37 @@ query and the one ``k_r`` are turned by RoPE at the token's position, the
 / r)``. The source de-interleaves the lanes first and then turns half
 against half; that is the same rotation followed by one fixed permutation of
 the ``r`` lanes of ``q`` and ``k`` alike, which no score sees, so here the
-lanes stay where the projections wrote them. A head is turned whole, ``x *
-cos + (x P) * sin`` with ones and zeros in the tables of the ``n`` lanes that
-stay and ``P`` the pairs' swap **as a product on the MXU** (exact: one 1 a
-column; a swap of neighbouring lanes by slices or a reshape to pairs would
-re-tile the array), so that nothing is cut out of a head and joined again.
-Without ``rope_theta`` nothing is turned (Kimi Linear's ``mla_use_nope``:
-its recurrent layers order the tokens).
+lanes stay where the projections wrote them. A turned lane is ``x * cos +
+partner(x) * sin`` in float32 from the stored type, rounded once. Without
+``rope_theta`` nothing is turned (Kimi Linear's ``mla_use_nope``: its
+recurrent layers order the tokens).
+
+**Two ways to the kernels**, by what the layer can observe
+(``hvd_mla_rope_path_last{path}`` says which a trace took; no knob):
+
+* ``one_pass``: where the heads pair up into whole lane tiles (``n`` and
+  ``v`` whole tiles, ``2 r`` one tile: 128 + 64 and 128, an even number of
+  heads, tokens in whole sublane tiles: ``ops.rotary_split.tokens_a_step``)
+  and ``attention_fn`` takes head-major operands
+  (``parts.takes_head_major``), ``ops/rotary_split.py``'s kernels read
+  ``q``, ``kv_b``'s output and ``k_r`` as the projections wrote them and
+  write ``q``, ``[k_n | turn(k_r)]`` and ``v`` as ``[B, H, S, d]``, turning
+  the rotary lanes on the way, and their backward kernels take ``dq``,
+  ``dk``, ``dv`` back the same way (``d k_r`` summed over the heads in
+  float32). What the chip showed (PR 55; the parent's numbers are PR 52's
+  cell): a head of 192 lanes is one and a half lane tiles, so ``[S, H *
+  192] -> [S, H, 192]`` is no view there; XLA copied ``q`` twice each way
+  to get it head-major even with nothing turned, and with the turn beside
+  it (a whole head ``x cos + (x P) sin``, ``P`` the pairs' swap as a
+  product at ``Precision.HIGHEST``, which splits the fusion) it wrote ``q``
+  in float32 and re-tiled that too: 2.8 GB a forward pass of one layer
+  where the kernels move 0.75.
+* ``plain``: any other shape (the toys of ``tests/``, an odd head count,
+  dense attention): :func:`turn`, a head turned whole with ones and zeros in
+  the tables of the ``n`` lanes that stay and the swap **as a product on the
+  MXU** (exact: one 1 a column), then the reshapes and the adapter's
+  transposes. The one pass keeps its arithmetic and its transpose's
+  roundings bit for bit (``tests/test_joyai_flash_model.py``).
 
 In training nothing is absorbed or cached: it is ``H``-head causal attention
 whose scores contract over ``n + r`` lanes and whose context is ``v`` wide,
@@ -46,8 +70,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..attribution import SCOPE_MLA_ROPE
+from ..ops.rotary_split import head_major_operands, tokens_a_step
 from ..profiler import annotate_collective
-from .parts import RMSNorm, dense_causal_attention, projection
+from .parts import (RMSNorm, dense_causal_attention, projection,
+                    takes_head_major)
 
 
 def interleaved_pairs(lanes: int):
@@ -99,6 +125,14 @@ def _record_lanes(rotated: int, kept: int) -> None:
     metrics.MLA_ROPE_LANES_LAST.set(kept, kind="kept")
 
 
+def _record_path(taken: str) -> None:
+    """At trace time too: which way a layer with a rotary split went."""
+    from .. import metrics
+
+    for path in ("one_pass", "plain"):
+        metrics.MLA_ROPE_PATH_LAST.set(int(path == taken), path=path)
+
+
 class LatentAttention(nn.Module):
     """``attention_fn(q [B, S, H, n + r], k [B, S, H, n + r], v [B, S, H,
     v], dtype)`` returns the context ``[B, S, H, v]``. ``config`` is the
@@ -119,6 +153,7 @@ class LatentAttention(nn.Module):
             cfg.num_attention_heads, cfg.qk_nope_head_dim,
             cfg.qk_rope_head_dim, cfg.v_head_dim)
         rows = x.shape[:2]
+        attend = self.attention_fn or dense_causal_attention
         if self.q_lora_rank is None:
             q = projection(cfg, heads * (nope + rope), "query")(x)
         else:
@@ -126,13 +161,21 @@ class LatentAttention(nn.Module):
                 RMSNorm(cfg.rms_norm_eps, name="q_norm")(
                     projection(cfg, self.q_lora_rank, "q_a")(x)).astype(
                         cfg.dtype))
-        q = q.reshape(rows + (heads, nope + rope))
+        tile = None  # of the one pass, where a layer takes it
+        if self.rope_theta is not None and takes_head_major(attend):
+            tile = tokens_a_step(q, heads, nope, rope, v_dim)
+
+        def apart(flat):
+            """The heads of what a projection wrote, where XLA splits
+            them: the one pass reads the lanes as they lie."""
+            return flat if tile else flat.reshape(rows + (heads, -1))
+
+        q = apart(q)
         latent = projection(cfg, cfg.kv_lora_rank + rope, "kv_a")(x)
         shared = latent[..., cfg.kv_lora_rank:]  # k_r, every head's alike
-        up = projection(cfg, heads * (nope + v_dim), "kv_b")(
+        up = apart(projection(cfg, heads * (nope + v_dim), "kv_b")(
             RMSNorm(cfg.rms_norm_eps, name="kv_norm")(
-                latent[..., :cfg.kv_lora_rank]).astype(cfg.dtype)).reshape(
-                    rows + (heads, nope + v_dim))
+                latent[..., :cfg.kv_lora_rank]).astype(cfg.dtype)))
 
         def keys(shared):
             return jnp.concatenate([
@@ -143,13 +186,20 @@ class LatentAttention(nn.Module):
             k = keys(shared)
         else:
             _record_lanes(rope, nope)
+            _record_path("one_pass" if tile else "plain")
             with annotate_collective(SCOPE_MLA_ROPE):
                 cos, sin, swap = rotary_split_tables(
                     nope, rope, self.rope_theta, rows[1])
-                q = turn(q, cos, sin, swap, cfg.dtype)
-                k = keys(turn(shared, cos[:, nope:], sin[:, nope:],
-                              swap[nope:, nope:], cfg.dtype))
-        attend = self.attention_fn or dense_causal_attention
-        out = attend(q, k, up[..., nope:], cfg.dtype)
+                rotary = cos[:, nope:], sin[:, nope:]  # the lanes' that turn
+                if not tile:
+                    q = turn(q, cos, sin, swap, cfg.dtype)
+                    k = keys(turn(shared, *rotary, swap[nope:, nope:],
+                                  cfg.dtype))
+        if tile:  # under the same scope, which its passes open themselves
+            q, k, v = head_major_operands(q, up, shared, *rotary, heads,
+                                          nope, tile)
+            out = attend(q, k, v, cfg.dtype, head_major=True)
+        else:
+            out = attend(q, k, up[..., nope:], cfg.dtype)
         return projection(cfg, cfg.hidden_size, "out")(
             out.reshape(rows + (heads * v_dim,)))

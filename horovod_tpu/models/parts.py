@@ -210,16 +210,32 @@ def dense_window_attention(q, k, v, dtype, window=None):
 
 
 def head_major_flash_attention(q, k, v, dtype, interpret: bool = False,
-                               block: int | None = None):
+                               block: int | None = None,
+                               head_major: bool = False):
     """Adapter plugging the causal Pallas flash kernels into ``Olmoe`` and
     ``OlmoHybrid`` (their ``flash_attention_fn``): ``[B, S, H, D]`` ->
     transpose -> kernel. ``block`` is for tests that want several tiles of
-    a short sequence."""
-    out = flash_attention(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3), causal=True, block_q=block, block_k=block,
-        interpret=interpret)
+    a short sequence. ``head_major``: ``q``, ``k``, ``v`` come ``[B, H, S,
+    D]`` as the kernels read them (``models/latent.py``'s one pass writes
+    them so, which it may because the adapter says ``head_major`` of
+    itself); the context comes back ``[B, S, H, D]`` either way."""
+    if not head_major:
+        q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    out = flash_attention(q, k, v, causal=True, block_q=block, block_k=block,
+                          interpret=interpret)
     return out.transpose(0, 2, 1, 3).astype(dtype)
+
+
+head_major_flash_attention.head_major = True
+
+
+def takes_head_major(attention_fn) -> bool:
+    """Whether an ``attention_fn`` says of itself (``head_major``, seen
+    through ``functools.partial``) that it takes ``head_major=True`` with
+    q, k, v as ``[B, H, S, D]``; any other is handed ``[B, S, H, D]``."""
+    while isinstance(attention_fn, functools.partial):
+        attention_fn = attention_fn.func
+    return getattr(attention_fn, "head_major", False)
 
 
 def grouped_flash_attention(q, k, v, dtype, window=None,

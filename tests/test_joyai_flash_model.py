@@ -483,3 +483,281 @@ def test_routing_stats_read_the_stacks_expert_layers(bench):
         params, tokens[:, :-2], tokens[:, 1:-1])
     assert stats["load"].shape == (2, 4)   # two expert layers of three
     assert 0 < int(stats["load"].sum()) <= 2 * 2 * 32 * 2
+
+
+# --- the one pass to the kernels (PR 55, ops/rotary_split.py)
+
+NOPE, ROPE, V_DIM, TILE = 128, 64, 128, 32
+
+
+def projected(heads, seq=2 * TILE, batch=2, seed=0):
+    """``(q [B, S, H * 192], up [B, S, H * 256], shared [B, S, 64])`` in
+    bfloat16, as ``q_b``, ``kv_b`` and ``kv_a`` write them, and one
+    cotangent for each of ``q``, ``k``, ``v`` head-major, as the flash
+    backward kernels write theirs."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    shapes = [(batch, seq, heads * (NOPE + ROPE)),
+              (batch, seq, heads * (NOPE + V_DIM)), (batch, seq, ROPE)] + [
+        (batch, heads, seq, lanes) for lanes in (NOPE + ROPE, NOPE + ROPE,
+                                                 V_DIM)]
+    drawn = [jax.random.normal(key, shape, jnp.float32).astype(jnp.bfloat16)
+             for key, shape in zip(keys, shapes)]
+    return drawn[:3], drawn[3:]
+
+
+def the_parents_way(heads, seq, sum_in_float32=False):
+    """``(q, up, shared) -> (q, k, v)`` head-major as the parent of PR 55
+    made them: ``latent.turn`` on whole heads, the shared key broadcast and
+    joined, the adapter's three transposes. ``sum_in_float32``: the shared
+    key's cotangent summed over the heads in float32 and rounded once (the
+    kernel's accumulator), where the broadcast's own transpose is a
+    bfloat16 reduction (which XLA's CPU rounds after every add)."""
+    cos, sin, swap = latent.rotary_split_tables(NOPE, ROPE, 10000.0, seq)
+    dtype = jnp.bfloat16
+
+    @jax.custom_vjp
+    def every_head(turned):
+        return jnp.broadcast_to(
+            turned[:, :, None], turned.shape[:2] + (heads, ROPE))
+
+    every_head.defvjp(
+        lambda turned: (every_head(turned), None),
+        lambda _, bar: (bar.astype(jnp.float32).sum(2).astype(bar.dtype),))
+
+    def way(q, up, shared):
+        rows = q.shape[:2]
+        q = latent.turn(q.reshape(rows + (heads, -1)), cos, sin, swap, dtype)
+        up = up.reshape(rows + (heads, -1))
+        turned = latent.turn(shared, cos[:, NOPE:], sin[:, NOPE:],
+                             swap[NOPE:, NOPE:], dtype)
+        spread = every_head(turned) if sum_in_float32 else jnp.broadcast_to(
+            turned[:, :, None], rows + (heads, ROPE))
+        k = jnp.concatenate([up[..., :NOPE], spread], -1)
+        return tuple(x.transpose(0, 2, 1, 3)
+                     for x in (q, k, up[..., NOPE:]))
+
+    return way, (cos[:, NOPE:], sin[:, NOPE:])
+
+
+def bits(x):
+    return np.asarray(x.astype(jnp.float32))
+
+
+@pytest.mark.parametrize("heads", [2, 4])
+@pytest.mark.parametrize("run", ["forward", "backward"])
+@pytest.mark.parametrize("operand", ["q", "k"])
+def test_the_one_pass_is_the_parents_turn_reshape_and_transposes(
+        operand, run, heads):
+    """``ops/rotary_split.py``'s kernels (interpreted, two tiles of tokens)
+    against ``latent.turn`` + reshape + the adapter's transposes at JoyAI
+    Flash's widths, **bit for bit**: ``q`` forward and ``dq`` backward; ``k``
+    and ``v`` forward and ``d kv_b``, ``d k_r`` backward. The turn's
+    cotangent keeps the roundings of ``turn``'s own transpose. ``d k_r``
+    sums the heads in a float32 accumulator: with two heads that is the
+    parent's sum bit for bit, with four it is the parent's with the sum in
+    float32 bit for bit and the parent's bfloat16 sum within its
+    rounding."""
+    from horovod_tpu.ops import rotary_split
+
+    (q, up, shared), bars = projected(heads)
+    seq = q.shape[1]
+    parent, tables = the_parents_way(heads, seq, sum_in_float32=heads > 2)
+
+    def one_pass(q, up, shared):
+        return rotary_split.head_major_operands(
+            q, up, shared, *tables, heads, NOPE, TILE, interpret=True)
+
+    want, want_pull = jax.vjp(parent, q, up, shared)
+    got, got_pull = jax.vjp(one_pass, q, up, shared)
+    names = {"q": ("q",), "k": ("k", "v")}[operand]
+    if run == "forward":
+        for name in names:
+            at = "qkv".index(name)
+            assert got[at].shape == want[at].shape, name
+            np.testing.assert_array_equal(bits(got[at]), bits(want[at]), name)
+            turned = got[at][..., NOPE:] if name != "v" else None
+            if turned is not None:  # and something was turned
+                flat = (q if name == "q" else jnp.broadcast_to(
+                    shared[:, :, None], shared.shape[:2] + (heads, ROPE)))
+                flat = flat.reshape(q.shape[:2] + (heads, -1))[
+                    ..., -ROPE:].transpose(0, 2, 1, 3)
+                assert float(jnp.abs(turned[:, :, 1:].astype(jnp.float32)
+                                     - flat[:, :, 1:].astype(jnp.float32)
+                                     ).max()) > 0.1
+        return
+    want_bars, got_bars = want_pull(tuple(bars)), got_pull(tuple(bars))
+    for at in {"q": (0,), "k": (1, 2)}[operand]:
+        name = ("dq", "d kv_b", "d k_r")[at]
+        assert got_bars[at].dtype == jnp.bfloat16
+        np.testing.assert_array_equal(bits(got_bars[at]), bits(want_bars[at]),
+                                      name)
+    if operand == "k" and heads > 2:
+        rounded = jax.vjp(the_parents_way(heads, seq)[0], q, up, shared)[1](
+            tuple(bars))[2]
+        np.testing.assert_allclose(bits(got_bars[2]), bits(rounded),
+                                   rtol=0, atol=heads * 2.0 ** -8 * 4)
+        assert not np.array_equal(bits(got_bars[2]), bits(rounded))
+
+
+def test_any_other_platform_lowers_the_plain_form_of_the_same_function():
+    """A program lowered for the CPU holds the plain form of each of the
+    four passes (``linear_attention._where_lowered``): the kernels'
+    function to a bfloat16 rounding (XLA's CPU contracts ``x cos + p sin``
+    its own way), and no Pallas call."""
+    from horovod_tpu.ops import rotary_split
+
+    heads = 4
+    (q, up, shared), bars = projected(heads)
+    _, tables = the_parents_way(heads, q.shape[1])
+
+    def one_pass(interpret, q, up, shared):
+        return rotary_split.head_major_operands(
+            q, up, shared, *tables, heads, NOPE, TILE, interpret=interpret)
+
+    kernels, kernels_pull = jax.vjp(partial(one_pass, True), q, up, shared)
+    plain, plain_pull = jax.vjp(partial(one_pass, False), q, up, shared)
+    for got, want in zip(plain + plain_pull(tuple(bars)),
+                         kernels + kernels_pull(tuple(bars))):
+        assert got.shape == want.shape and got.dtype == want.dtype
+        np.testing.assert_allclose(bits(got), bits(want), rtol=2.0 ** -7,
+                                   atol=1e-6)
+    lowered = jax.jit(jax.grad(lambda *xs: sum(
+        x.astype(jnp.float32).sum() for x in one_pass(False, *xs)),
+        (0, 1, 2))).lower(q, up, shared).as_text()
+    assert "hvd.mla.rope" in jax.jit(partial(one_pass, False)).lower(
+        q, up, shared).as_text(debug_info=True)
+    assert "pallas" not in lowered and "custom_call" not in lowered
+
+
+def latent_layer(heads, rope_theta=10000.0, attention=True, seq=2 * TILE):
+    """A latent layer at JoyAI Flash's head widths, its kernels interpreted
+    in tiles of ``TILE``, and an input for it."""
+    cfg = dataclasses.replace(
+        joyai_flash.JOYAI_FLASH_TINY, num_attention_heads=heads,
+        qk_nope_head_dim=NOPE, qk_rope_head_dim=ROPE, v_head_dim=V_DIM)
+    attention_fn = partial(joyai_flash.flash_attention_fn, interpret=True,
+                           block=TILE) if attention else None
+    layer = latent.LatentAttention(
+        cfg, attention_fn, q_lora_rank=cfg.q_lora_rank,
+        rope_theta=rope_theta)
+    x = jax.random.normal(jax.random.PRNGKey(3), (1, seq, cfg.hidden_size),
+                          jnp.float32).astype(cfg.dtype)
+    return layer, x
+
+
+def path_taken():
+    from horovod_tpu import metrics
+
+    return {sample["labels"]["path"]: sample["value"]
+            for sample in metrics.MLA_ROPE_PATH_LAST.dump()["samples"]}
+
+
+@pytest.mark.parametrize("shapes,path", [
+    ("joyai_llm_flash", "one_pass"), ("two_heads", "one_pass"),
+    ("three_heads", "plain"), ("dense_attention", "plain"),
+    ("an_odd_tile", "plain"), ("the_toy", "plain")])
+def test_the_path_gauge_says_which_way_a_layer_went(shapes, path):
+    """``hvd_mla_rope_path_last{path}``: the published shapes (and any
+    whose heads pair up into whole lane tiles, with an adapter that takes
+    head-major operands) take the one pass; three heads, dense attention
+    (it reads ``[B, S, H, D]``), tokens that fill no sublane tile and the
+    toy's 16 + 8 lanes take ``latent.turn``."""
+    if shapes == "joyai_llm_flash":
+        cfg = joyai_flash.JOYAI_LLM_FLASH
+        layer = latent.LatentAttention(
+            cfg, partial(joyai_flash.flash_attention_fn, interpret=True),
+            q_lora_rank=cfg.q_lora_rank, rope_theta=cfg.rope_theta)
+        x = jax.ShapeDtypeStruct((1, 8192, cfg.hidden_size), cfg.dtype)
+    elif shapes == "the_toy":
+        cfg = joyai_flash.JOYAI_FLASH_TINY
+        layer = latent.LatentAttention(
+            cfg, partial(joyai_flash.flash_attention_fn, interpret=True,
+                         block=16), q_lora_rank=cfg.q_lora_rank,
+            rope_theta=cfg.rope_theta)
+        x = jax.ShapeDtypeStruct((1, 32, cfg.hidden_size), cfg.dtype)
+    else:
+        layer, x = latent_layer(
+            3 if shapes == "three_heads" else 2,
+            attention=shapes != "dense_attention",
+            seq=40 if shapes == "an_odd_tile" else 2 * TILE)
+        if shapes == "an_odd_tile":  # 40 tokens: tiles of 8, half a bf16 tile
+            layer = layer.clone(attention_fn=partial(
+                joyai_flash.flash_attention_fn, interpret=True, block=8))
+    jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+    assert path_taken() == {"one_pass": float(path == "one_pass"),
+                            "plain": float(path == "plain")}
+
+
+def under_the_scope(jaxpr, scope="hvd.mla.rope", inside=False):
+    """Every equation of ``jaxpr`` and of the jaxprs in its parameters
+    whose name stack holds ``scope``."""
+    from jax.extend import core
+
+    for eqn in jaxpr.eqns:
+        here = inside or scope in str(eqn.source_info.name_stack)
+        if here:
+            yield eqn
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else [value]):
+                if isinstance(sub, core.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, core.Jaxpr):
+                    yield from under_the_scope(sub, scope, here)
+
+
+@pytest.mark.parametrize("heads", [2, 3])
+def test_the_one_pass_holds_no_float32_copy_of_the_queries(heads):
+    """The jaxpr of a layer's gradient: with two heads (the one pass)
+    what lies under ``hvd.mla.rope`` is the four passes' primitives and the
+    tables, and no float32 array as large as ``q``; with three (the plain
+    turn) the scope holds ``q`` in float32, forward and backward: the
+    test's own control."""
+    layer, x = latent_layer(heads)
+    params = jax.jit(layer.init)(jax.random.PRNGKey(0), x)
+
+    def loss(p, x):
+        return layer.apply(p, x).astype(jnp.float32).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss))(params, x).jaxpr
+    eqns = list(under_the_scope(jaxpr))
+    size = x.shape[0] * x.shape[1] * heads * (NOPE + ROPE)
+    wide = [eqn.primitive.name for eqn in eqns for out in eqn.outvars
+            if out.aval.dtype == jnp.float32 and out.aval.size >= size]
+    passes = sorted({eqn.primitive.name for eqn in eqns
+                     if eqn.primitive.name.startswith("hvd_mla_rope")})
+    if heads == 2:
+        assert not wide
+        assert passes == ["hvd_mla_rope_keys", "hvd_mla_rope_keys_backward",
+                          "hvd_mla_rope_queries",
+                          "hvd_mla_rope_queries_backward"]
+    else:
+        assert not passes
+        assert "convert_element_type" in wide and "mul" in wide
+
+
+def test_a_layer_through_the_one_pass_is_the_layer_through_the_turn():
+    """The whole layer, loss and every leaf's gradient: the one pass (the
+    plain form of its primitives, on this platform) against the same layer
+    sent down ``latent.turn`` (by an adapter that does not say
+    ``head_major``), on the same weights."""
+    layer, x = latent_layer(4)
+    params = jax.jit(layer.init)(jax.random.PRNGKey(0), x)
+    turned = layer.clone(attention_fn=lambda *a, **k: layer.attention_fn(
+        *a, **k))
+
+    def loss(layer, p, x):
+        return (layer.apply(p, x).astype(jnp.float32) ** 2).sum()
+
+    got = jax.jit(jax.value_and_grad(partial(loss, layer)))(params, x)
+    assert path_taken()["one_pass"] == 1
+    want = jax.jit(jax.value_and_grad(partial(loss, turned)))(params, x)
+    assert path_taken()["plain"] == 1
+    assert float(got[0]) == pytest.approx(float(want[0]), rel=2e-3)
+    for (path, leaf), ref in zip(
+            jax.tree_util.tree_leaves_with_path(got[1]),
+            jax.tree.leaves(want[1])):
+        scale = float(jnp.abs(ref).max())
+        np.testing.assert_allclose(
+            leaf, ref, rtol=0, atol=2e-2 * scale,
+            err_msg=jax.tree_util.keystr(path))

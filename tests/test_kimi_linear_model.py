@@ -293,6 +293,10 @@ def test_the_scopes_are_in_a_lowered_step_and_the_gauges_set(bench):
     params = jax.eval_shape(partial(code.init_params, config, {}),
                             jax.random.PRNGKey(0))
     tokens = jax.ShapeDtypeStruct((2, 33), jnp.int32)
+    # the gauges below are set as a call is traced: a trace of the toy's
+    # flash call kept from an earlier test (JoyAI Flash's toy has the same
+    # widths) would leave them at whatever was traced since
+    jax.clear_caches()
     text = jax.jit(jax.grad(code.loss_fn(config, {}))).lower(
         params, tokens).as_text(debug_info=True)
     for scope in ("hvd.linattn.conv", "hvd.linattn.scan", "hvd.linattn.gate",
@@ -351,6 +355,43 @@ def test_the_latent_layer_lowers_to_the_text_it_had():
 
     text = jax.jit(jax.grad(loss)).lower(params, x).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == PARENTS_LATENT_TEXT
+
+
+# sha256 of str(make_jaxpr(grad(loss))) of the layer below at commit 831a554,
+# before models/latent.py had a second way to the kernels (PR 55)
+PARENTS_LATENT_JAXPR = (
+    "387dcd5d3ca496d5319b6bde8f6e36189e27497b73c8c41d8c73108aa165e644")
+
+
+def test_a_layer_with_nothing_turned_traces_to_the_jaxpr_it_had():
+    """Kimi Linear's latent layer has no positions (``rope_theta`` None),
+    so it takes neither the turn nor the one pass (``ops/rotary_split.py``)
+    even at widths whose heads pair up into whole lane tiles and with the
+    adapter that takes head-major operands: its branch is untouched, the
+    reshapes where they were and the adapter's three transposes after
+    them, and no path gauge is set."""
+    import hashlib
+
+    from horovod_tpu import metrics
+    from horovod_tpu.models import latent, parts
+
+    before = metrics.MLA_ROPE_PATH_LAST.dump()["samples"]
+    cfg = dataclasses.replace(
+        kimi_linear.KIMI_LINEAR_TINY, hidden_size=64, num_attention_heads=2,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        kv_lora_rank=32)
+    layer = latent.LatentAttention(cfg, partial(
+        parts.head_major_flash_attention, interpret=True, block=16))
+    x = jnp.zeros((1, 32, 64), jnp.bfloat16)
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+
+    def loss(p, x):
+        return layer.apply(p, x).astype(jnp.float32).sum()
+
+    text = str(jax.make_jaxpr(jax.grad(loss))(params, x))
+    assert "hvd_mla_rope" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == PARENTS_LATENT_JAXPR
+    assert metrics.MLA_ROPE_PATH_LAST.dump()["samples"] == before
 
 
 # --- the norms a head, where the heads' lanes lie (PR 53)

@@ -479,6 +479,73 @@ def test_kimis_delta_mixer_moves_nothing_as_large_as_its_queries(one_chip):
     assert not moved, moved
 
 
+@pytest.mark.parametrize("path", ["one_pass", "plain"])
+def test_joyais_latent_layer_reaches_the_kernels_in_one_pass(one_chip, path):
+    """One latent layer at JoyAI Flash's published widths (8,192 tokens, 32
+    heads of 128 + 64 lanes, values of 128), value and every gradient, as
+    the v5e's compiler takes it. **The one pass** (PR 55): beside the three
+    ``flash_attention`` calls under ``hvd.attn.mla`` four calls named
+    ``mla_rope_heads`` under ``hvd.mla.rope`` (queries and keys with values,
+    forward and backward), and between the projections and the kernels no
+    ``copy``, ``transpose``, ``reshape`` or ``convert`` of an array as large
+    as the queries (50.3 M elements). **The plain turn** (the same layer
+    with an adapter that does not say ``head_major``: the test's control,
+    and what the parent compiled to): the float32 ``convert`` of ``q`` and
+    the copies that re-tile it."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu import metrics
+    from horovod_tpu.models import joyai_flash, latent
+
+    cfg = joyai_flash.JOYAI_LLM_FLASH
+    attend = joyai_flash.flash_attention_fn
+    if path == "plain":
+        def attend(q, k, v, dtype):
+            return joyai_flash.flash_attention_fn(q, k, v, dtype)
+    layer = latent.LatentAttention(cfg, attend, q_lora_rank=cfg.q_lora_rank,
+                                   rope_theta=cfg.rope_theta)
+    x = jax.ShapeDtypeStruct((1, 8192, cfg.hidden_size), cfg.dtype,
+                             sharding=one_chip)
+    params = jax.tree.map(
+        lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                          sharding=one_chip),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)["params"])
+
+    def loss(params, x):
+        out = layer.apply({"params": params}, x)
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    assert metrics.MLA_ROPE_PATH_LAST.labels(path=path).get() == 1
+    kernels = kernel_instructions(text)
+    flash = [scope for name, scope in kernels
+             if re.fullmatch(r"flash_attention(\.\d+)?", name)]
+    turned = [scope for name, scope in kernels
+              if re.fullmatch(r"mla_rope_heads(\.\d+)?", name)]
+    assert len(flash) == 3 and all("hvd.attn.mla" in s for s in flash)
+    assert len(flash) + len(turned) == len(kernels)
+    moved = []
+    for line in text.splitlines():
+        at = re.match(r"\s*(?:ROOT )?%?(\S+) = (\w+)\[([\d,]*)\]\S* "
+                      r"(copy|transpose|reshape|convert)\(", line)
+        if at and math.prod(int(n) for n in at.group(3).split(",")
+                            if n) >= 8192 * 32 * 192:
+            moved.append((at.group(4), at.group(2), at.group(3)))
+    if path == "one_pass":
+        assert len(turned) == 4
+        assert all("hvd.mla.rope" in s and "hvd.attn" not in s
+                   for s in turned)
+        assert sum("transpose(" in s for s in turned) == 2  # the backward
+        assert not moved, moved
+    else:
+        assert not turned
+        assert [dims for kind, dtype, dims in moved
+                if kind == "convert" and dtype == "f32"]
+        assert sum(kind == "copy" for kind, _, _ in moved) >= 2
+
+
 def test_kimi_linears_step_holds_the_chunk_loops_kernels_three_a_layer(
         one_chip):
     """The Kimi Linear cell's train step as ``benchmark/aot.py`` builds it,

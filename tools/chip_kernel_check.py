@@ -31,7 +31,10 @@ tools/chip_kernel_check.py ssd`` runs that alone); and Kimi Delta
 Attention's solve and chunk loop, the two kernels against the plain form
 and the float32 recurrence at the cell's shape, checked and timed
 (``check_kda_scan``; ``python tools/chip_kernel_check.py kda_scan`` runs
-that alone). Compiled, never ``interpret=True``: off a TPU this exits
+that alone); and latent attention's one pass to the kernels at the JoyAI
+Flash cell's shapes, the four kernels against ``latent.turn`` with XLA's
+reshapes and transposes, checked and timed (``check_mla_rope``; ``python
+tools/chip_kernel_check.py mla_rope`` runs that alone). Compiled, never ``interpret=True``: off a TPU this exits
 non-zero.
 
 The tolerance is the one ``tests/test_sequence_parallel.py`` uses for bf16
@@ -664,6 +667,74 @@ def check_tiles_as_they_lie() -> None:
     assert worst <= 1e-6 * float(jnp.abs(want).max()), worst
 
 
+def check_mla_rope(calls=20) -> None:
+    """Latent attention's operands on their way to the kernels at the JoyAI
+    Flash cell's shapes (``tools/mla_rope_forms.py``'s, and its two forms):
+    ``ops/rotary_split.py``'s four kernels (compiled) against
+    ``latent.turn`` + reshape + the adapter's transposes as XLA compiles
+    them for the chip, ``q``, ``k``, ``v`` and the three cotangents; how
+    many elements differ at all is printed (the arithmetic is the same
+    float32 with the same roundings, the heads' sum of ``d k_r`` apart:
+    XLA's order is its own), and both forms are timed forward and forward
+    + backward, ``calls`` dispatched back to back."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import mla_rope_forms as forms  # beside this file
+    import numpy as np
+
+    heads, seq, dtype = forms.HEADS, forms.SEQ, forms.DTYPE
+    wide, values = forms.NOPE + forms.ROPE, forms.V_DIM
+    keys = jax.random.split(jax.random.PRNGKey(5), 6)
+    shapes = [(1, seq, heads * wide), (1, seq, forms.ROPE),
+              (1, seq, heads * (forms.NOPE + values)), (1, heads, seq, wide),
+              (1, heads, seq, wide), (1, heads, seq, values)]
+    *projected, q_bar, k_bar, v_bar = (
+        jax.random.normal(key, shape, jnp.float32).astype(dtype)
+        for key, shape in zip(keys, shapes))
+
+    def head_major(form):
+        def operands(q, shared, up):
+            q, k, v, as_the_kernels_read = forms.operands(q, shared, up, form)
+            if as_the_kernels_read:
+                return q, k, v
+            return tuple(x.transpose(0, 2, 1, 3) for x in (q, k, v))
+
+        return operands
+
+    def both(form):
+        def run(q, shared, up, *bars):
+            out, pull = jax.vjp(head_major(form), q, shared, up)
+            return out + pull(bars)
+
+        return jax.jit(run)
+
+    names = ("q", "k", "v", "dq", "d k_r", "d kv_b")
+    got = both("one_pass")(*projected, q_bar, k_bar, v_bar)
+    want = both("plain")(*projected, q_bar, k_bar, v_bar)
+    for name, a, b in zip(names, got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        a, b = (np.asarray(x.astype(jnp.float32)) for x in (a, b))
+        differ = int((a != b).sum())
+        print(f"  {name}: {differ} of {a.size} elements differ from "
+              f"latent.turn's, at most by {float(np.abs(a - b).max()):.3e}")
+        _close(f"one pass, {name}", a, b)
+    for form, what in (("one_pass", "ops/rotary_split.py's kernels"),
+                       ("plain", "latent.turn, reshape and transposes")):
+        for run, step, args in (
+                ("forward", jax.jit(head_major(form)), projected),
+                ("forward + backward", both(form),
+                 projected + [q_bar, k_bar, v_bar])):
+            jax.block_until_ready(step(*args))
+            t0 = time.perf_counter()
+            for _ in range(calls):
+                out = step(*args)
+            jax.block_until_ready(out)
+            print(f"  {what}, {run}: "
+                  f"{(time.perf_counter() - t0) / calls * 1e3:.3f} ms a call")
+
+
 def main() -> None:
     import jax
 
@@ -678,7 +749,8 @@ def main() -> None:
     alone = {"two_widths": check_two_widths,  # ~2 minutes
              "pair_terms": check_pair_terms,
              "kda_scan": check_kda_scan,
-             "ssd": check_ssd}
+             "ssd": check_ssd,
+             "mla_rope": check_mla_rope}
     if len(sys.argv) == 2 and sys.argv[1] in alone:
         alone[sys.argv[1]]()
         print("kernels ok")
@@ -698,6 +770,7 @@ def main() -> None:
     check_pair_terms()
     check_kda_scan()
     check_ssd()
+    check_mla_rope()
     print("kernels ok")
 
 
