@@ -897,153 +897,6 @@ MOE_EXPERT_LOAD = gauge(
     "Tokens routed to each expert in the last observed MoE step (this "
     "rank's routing view) — the imbalance the skew attribution chases.",
     ("expert",))
-MOE_SLOTS_LAST = gauge(
-    "hvd_moe_slots_last",
-    "Expert slots (experts_here x capacity) one routing group of the LAST "
-    "traced top-k MoE layer computes, occupied or not: set at trace time, "
-    "as hvd_grad_sync_last_bytes is.",
-    ("experts_here", "capacity", "top_k"))
-LINATTN_CHUNKS_LAST = gauge(
-    "hvd_linattn_chunks_last",
-    "Chunks a sequence that the LAST traced gated-delta-rule call scans "
-    "(sequence length / chunk), one scan step each: set at trace time, as "
-    "hvd_grad_sync_last_bytes is.",
-    ("chunk", "heads_here"))
-LINATTN_DECAY_WIDTH_LAST = gauge(
-    "hvd_linattn_decay_width_last",
-    "Decays a head and token of the LAST traced delta-rule call: 1 for "
-    "gated_delta_rule (one scalar a head), d_k for kimi_delta_rule (one a "
-    "key channel, the pair terms formed sub-block by sub-block): set at "
-    "trace time, beside hvd_linattn_chunks_last.")
-LINATTN_PAIR_KERNEL_LAST = gauge(
-    "hvd_linattn_pair_kernel_last",
-    "Chunks a grid step of the Pallas kernel that forms kimi_delta_rule's "
-    "pair terms in the LAST lowered program takes, 0 where that program "
-    "holds the plain form (lowered for any platform but a TPU, or "
-    "sub-blocks that fill no tile): set as the program is lowered, since "
-    "the lowering platform chooses. operands: the layout q, k and gamma "
-    "cross HBM in, tokens_major ([B, S, H * d] as the projections wrote "
-    "them, read through the kernels' index maps) or plain (the plain "
-    "form's head-major view).",
-    ("sub", "operands"))
-LINATTN_SCAN_KERNEL_LAST = gauge(
-    "hvd_linattn_scan_kernel_last",
-    "Heads a grid step of the Pallas kernels that run kimi_delta_rule's "
-    "solve and chunk loop in the LAST lowered program takes, 0 where that "
-    "program holds the plain form (lowered for any platform but a TPU, or "
-    "shapes that fill no tile): set as the program is lowered, since the "
-    "lowering platform chooses. operands: as hvd_linattn_pair_kernel_last's "
-    "(q, k, v, gamma, o and their cotangents).",
-    ("chunk", "operands"))
-SSM_CHUNKS_LAST = gauge(
-    "hvd_ssm_chunks_last",
-    "Chunks a sequence that the LAST traced Mamba-2 scan (ops/ssd.py "
-    "ssd_scan) cuts its tokens into (sequence length / chunk), for "
-    "`heads` heads: set at trace time, as hvd_linattn_chunks_last is.",
-    ("chunk", "heads"))
-SSM_SCAN_KERNEL_LAST = gauge(
-    "hvd_ssm_scan_kernel_last",
-    "Heads a grid step of the Pallas kernels that run ssd_scan's chunk "
-    "form in the LAST lowered program takes, 0 where that program holds "
-    "the plain form (lowered for any platform but a TPU, or shapes that "
-    "fill no tile): set as the program is lowered, since the lowering "
-    "platform chooses.",
-    ("chunk",))
-ATTN_TILES_LAST = gauge(
-    "hvd_attn_tiles_last",
-    "(q, k) tile pairs a (batch x head) slice of the LAST traced multi-tile "
-    "flash-attention call computes and skips (a causal call skips the "
-    "tiles its mask leaves nothing of), and the grid steps a slice takes "
-    "(kind=grid: the whole tile grid, under a window the band alone): set "
-    "at trace time, as hvd_grad_sync_last_bytes is. "
-    "kind=blockdiff_computed|blockdiff_skipped|blockdiff_grid: the same "
-    "three for the LAST traced block_diffusion_attention as a whole, its "
-    "two kernel calls together (computed and grid steps summed; skipped "
-    "out of the doubled stream's 2S x 2S square of tiles).",
-    ("kind",))
-ATTN_GROUP_LAST = gauge(
-    "hvd_attn_group_last",
-    "Slices a grid step of the LAST traced single-tile flash-attention "
-    "call takes, by kernel (fwd: the direct-softmax forward; bwd: the "
-    "fused backward). A slice is a (batch x head) pair of a head-major "
-    "call and a batch row's block of hvd_attn_heads_per_block_last heads "
-    "of a tokens-major one: set at trace time, as hvd_attn_tiles_last is.",
-    ("kernel",))
-ATTN_HEADS_PER_BLOCK_LAST = gauge(
-    "hvd_attn_heads_per_block_last",
-    "Heads whose lanes one block of the LAST traced single-tile "
-    "flash-attention call holds, by kernel: 1 for a head-major call "
-    "([BH, S, D] blocks of D lanes), for a tokens-major one "
-    "([B, S, H * D]) the fewest that fill whole 128-lane tiles (2 at "
-    "D = 64): set at trace time, beside hvd_attn_group_last.",
-    ("kernel",))
-ATTN_OPERAND_LAYOUT_LAST = gauge(
-    "hvd_attn_operand_layout_last",
-    "How the LAST traced multi-tile flash-attention call of each kind "
-    "(kernel = fwd, dq, dkv) took its operands: 1 tokens-major ([B, S, "
-    "H * D] as a projection wrote them, a head a 128-lane block the index "
-    "map finds: nothing transposed in HBM), 0 head-major ([BH, S, D]): set "
-    "at trace time, beside hvd_attn_tiles_last.",
-    ("kernel",))
-ATTN_KV_GROUP_LAST = gauge(
-    "hvd_attn_kv_group_last",
-    "Query heads that read one key/value head in the LAST traced multi-tile "
-    "flash-attention call (1: a head of keys and values a query head): set "
-    "at trace time, beside hvd_attn_tiles_last.")
-ATTN_HEAD_WIDTHS_LAST = gauge(
-    "hvd_attn_head_widths_last",
-    "Lanes of a head in the LAST traced multi-tile flash-attention call: "
-    "kind=qk the queries' and keys' (the scores contract over them), "
-    "kind=v the values', the context's and their gradients'. Equal in "
-    "every model here but latent attention (192 and 128): set at trace "
-    "time, beside hvd_attn_tiles_last.",
-    ("kind",))
-MLA_ROPE_LANES_LAST = gauge(
-    "hvd_mla_rope_lanes_last",
-    "Lanes of a head's queries and keys in the LAST traced latent-attention "
-    "layer with a rotary split (models/latent.py): kind=rotated the lanes "
-    "RoPE turns (the queries' last qk_rope_head_dim and the one shared "
-    "key's), kind=kept the lanes beside them that are not turned: set at "
-    "trace time, as hvd_attn_tiles_last is.",
-    ("kind",))
-MLA_ROPE_PATH_LAST = gauge(
-    "hvd_mla_rope_path_last",
-    "Which way the LAST traced latent-attention layer with a rotary split "
-    "(models/latent.py) took its queries and keys to the kernels, 1 beside "
-    "the one taken and 0 beside the other: path=one_pass the kernels of "
-    "ops/rotary_split.py, which turn the rotary lanes while they lay q, k "
-    "and v out head-major (heads that pair up into whole lane tiles, an "
-    "attention_fn that takes head-major operands), path=plain turn() and "
-    "XLA's reshapes and transposes (any other shape): set at trace time, "
-    "beside hvd_mla_rope_lanes_last.",
-    ("path",))
-MTP_DEPTH_LAST = gauge(
-    "hvd_mtp_depth_last",
-    "Multi-token-prediction modules in the LAST traced model that has them "
-    "(models/joyai_flash.py: num_nextn_predict_layers, each one more decoder "
-    "layer scored by the main model's head against a further token): set at "
-    "trace time, as hvd_attn_tiles_last is.")
-SHORTCONV_TAPS_LAST = gauge(
-    "hvd_shortconv_taps_last",
-    "Taps of the depth-wise causal convolution in the LAST traced gated "
-    "short-convolution mixer (models/lfm2.py: conv_L_cache, 3, over "
-    "channels=hidden_size lanes between the mixer's two gates): set at "
-    "trace time, as hvd_attn_tiles_last is.",
-    ("channels",))
-HEAD_LOGITS_BYTES_LAST = gauge(
-    "hvd_head_logits_bytes_last",
-    "Bytes of the logits that the LAST traced token cross entropy "
-    "(models/loss.py token_cross_entropy) was traced over, by the rule "
-    "that differentiates it (custom_vjp: softmax minus the one-hot in one "
-    "pass, nothing else of the logits' size kept): set at trace time, as "
-    "hvd_attn_tiles_last is.",
-    ("rule",))
-DIFFUSION_MASKED_SHARE_LAST = gauge(
-    "hvd_diffusion_masked_share_last",
-    "Share of the positions that the LAST block-diffusion batch made "
-    "(models.sdar.noisy_batch) replaced by the mask token: a run-time "
-    "value, set by a host callback where the batch is made (expected "
-    "(t_min + 1) / 2 under the clipped linear schedule).")
 ALLTOALL_LATENCY = histogram(
     "hvd_alltoall_latency_seconds",
     "Wall time of alltoall exchanges (eager dispatches and MoE "

@@ -95,7 +95,6 @@ class SparseExperts(nn.Module):
         expert_ffn = moe.gated_expert_ffn if self.gated \
             else moe.plain_expert_ffn
         capacity = cfg.capacity(x.shape[1])
-        _record_slots(here, capacity, cfg.top_k)
 
         def one_group(tokens, logits):
             if router is not None:
@@ -131,16 +130,6 @@ class SparseExperts(nn.Module):
         if self.auxiliary is None:
             return out
         return (out,) + tuple(loss.mean() for loss in losses)
-
-
-def _record_slots(experts_here: int, capacity: int, top_k: int) -> None:
-    """At trace time, as ``optimizer._record_flush`` does for the wire:
-    the step that runs computes this many slots a routing group."""
-    from .. import metrics
-
-    metrics.MOE_SLOTS_LAST.set(
-        experts_here * capacity, experts_here=str(experts_here),
-        capacity=str(capacity), top_k=str(top_k))
 
 
 def load_balance_loss(logits, expert):

@@ -199,7 +199,6 @@ class JoyAIFlash(nn.Module):
         logits = scored(x, "ln_out")
         if not modules:
             return logits
-        _record_depth(modules)
         with annotate_collective(SCOPE_MTP):
             with annotate_collective(SCOPE_BLOCK_EMBED):
                 joined = jnp.concatenate([
@@ -211,13 +210,6 @@ class JoyAIFlash(nn.Module):
             z = layer(cfg, False, self.attention_fn, routed(cfg.num_layers),
                       name="mtp_layer")(z)
             return logits, scored(z, "mtp_norm")
-
-
-def _record_depth(modules: int) -> None:
-    """At trace time, as ``experts._record_slots`` does for the slots."""
-    from .. import metrics
-
-    metrics.MTP_DEPTH_LAST.set(modules)
 
 
 def mtp_lm_loss(model: JoyAIFlash, params, tokens):

@@ -24,8 +24,9 @@ partner(x) * sin`` in float32 from the stored type, rounded once. Without
 ``rope_theta`` nothing is turned (Kimi Linear's ``mla_use_nope``: its
 recurrent layers order the tokens).
 
-**Two ways to the kernels**, by what the layer can observe
-(``hvd_mla_rope_path_last{path}`` says which a trace took; no knob):
+**Two ways to the kernels**, by what the layer can observe (no knob; a
+trace that took the first holds the primitives ``hvd_mla_rope_queries`` and
+``hvd_mla_rope_keys``):
 
 * ``one_pass``: where the heads pair up into whole lane tiles (``n`` and
   ``v`` whole tiles, ``2 r`` one tile: 128 + 64 and 128, an even number of
@@ -117,22 +118,6 @@ def turn(x, cos, sin, swap, dtype):
             + swapped.astype(jnp.float32) * sin).astype(dtype)
 
 
-def _record_lanes(rotated: int, kept: int) -> None:
-    """At trace time, as ``experts._record_slots`` does for the slots."""
-    from .. import metrics
-
-    metrics.MLA_ROPE_LANES_LAST.set(rotated, kind="rotated")
-    metrics.MLA_ROPE_LANES_LAST.set(kept, kind="kept")
-
-
-def _record_path(taken: str) -> None:
-    """At trace time too: which way a layer with a rotary split went."""
-    from .. import metrics
-
-    for path in ("one_pass", "plain"):
-        metrics.MLA_ROPE_PATH_LAST.set(int(path == taken), path=path)
-
-
 class LatentAttention(nn.Module):
     """``attention_fn(q [B, S, H, n + r], k [B, S, H, n + r], v [B, S, H,
     v], dtype)`` returns the context ``[B, S, H, v]``. ``config`` is the
@@ -185,8 +170,6 @@ class LatentAttention(nn.Module):
         if self.rope_theta is None:
             k = keys(shared)
         else:
-            _record_lanes(rope, nope)
-            _record_path("one_pass" if tile else "plain")
             with annotate_collective(SCOPE_MLA_ROPE):
                 cos, sin, swap = rotary_split_tables(
                     nope, rope, self.rope_theta, rows[1])

@@ -158,7 +158,6 @@ class ShortConv(nn.Module):
             1 / 3, "fan_in", "uniform", in_axis=-1, out_axis=-2),
             (width, cfg.conv_L_cache), f32)
         bcx = projection(cfg, 3 * width, "in_proj")(x)
-        _record_taps(cfg.conv_L_cache, width)
         with annotate_collective(SCOPE_SHORTCONV_MIX):
             # lane slices of the one array, not a [B, S, 3, width] view
             gate_in, gate_out, inner = (
@@ -167,13 +166,6 @@ class ShortConv(nn.Module):
             mixed = (gate_out * short_conv(gate_in * inner, taps)).astype(
                 cfg.dtype)
         return projection(cfg, width, "out_proj")(mixed)
-
-
-def _record_taps(taps: int, channels: int) -> None:
-    """At trace time, as ``experts._record_slots`` does for the slots."""
-    from .. import metrics
-
-    metrics.SHORTCONV_TAPS_LAST.set(taps, channels=str(channels))
 
 
 class GroupedAttention(nn.Module):

@@ -13,9 +13,7 @@ the weights, its backward holds no ``scatter`` and no zeros, and the
 compiler fuses ``dlogits`` into the two products that read it.
 
 Both halves open ``hvd.block.head`` themselves, so the backward's device
-time is the head's in ``benchmark/owners.py``'s table. The gauge
-``hvd_head_logits_bytes_last{rule="custom_vjp"}`` says at trace time how
-many bytes of logits the rule was traced over.
+time is the head's in ``benchmark/owners.py``'s table.
 
 ``models/smallthinker.py``, ``models/sdar.py`` and ``models/granite.py``
 call it. ``models/olmoe.py`` and ``models/olmo_hybrid.py`` keep their
@@ -44,10 +42,6 @@ def _one_hot(logits, labels):
 def _forward(logits, labels, weights):
     """``(loss, lse)``: one pass over the logits for the row maximum, one
     for the log-sum-exp and the label's logit."""
-    from .. import metrics
-
-    metrics.HEAD_LOGITS_BYTES_LAST.set(
-        logits.size * logits.dtype.itemsize, rule="custom_vjp")
     with annotate_collective(SCOPE_BLOCK_HEAD):
         x = logits.astype(jnp.float32)
         top = x.max(axis=-1, keepdims=True)

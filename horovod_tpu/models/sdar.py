@@ -242,10 +242,7 @@ def noisy_batch(key, clean_ids, block_length: int, mask_id: int):
     clipped from below, under which a block masks one token in expectation
     at the least), every position is replaced by ``mask_id`` with its
     block's probability, and a masked position's loss weight is ``1 / t``
-    (others 0). Sets ``hvd_diffusion_masked_share_last`` when it runs, by
-    a host callback: the share is a run-time value."""
-    from .. import metrics
-
+    (others 0)."""
     rows, seq_len = clean_ids.shape
     if seq_len % block_length:
         raise ValueError(
@@ -256,9 +253,6 @@ def noisy_batch(key, clean_ids, block_length: int, mask_id: int):
         1.0 / block_length, 1.0)
     level = jnp.repeat(level, block_length, axis=1)
     masked = jax.random.uniform(mask_key, (rows, seq_len)) < level
-    jax.debug.callback(
-        lambda share: metrics.DIFFUSION_MASKED_SHARE_LAST.set(float(share)),
-        masked.mean())
     return {"clean": clean_ids,
             "noisy": jnp.where(masked, mask_id, clean_ids),
             "weight": jnp.where(masked, 1.0 / level, 0.0)}

@@ -17,13 +17,12 @@ Two implementations, one semantics:
   128 x 64 slice alone is a chain of small dependent steps that leaves
   the units idle. ``_group_size`` picks G from the call's shapes (the
   largest divisor of batch*heads whose blocks and scores fit
-  ``GROUP_BUDGET_BYTES`` of VMEM); the gauge
-  ``hvd_attn_group_last{kernel}`` says which. The same two kernels
+  ``GROUP_BUDGET_BYTES`` of VMEM). The same two kernels
   take operands where a projection wrote them, tokens major
   (``flash_attention_tokens_major``: ``[B, S, H * D]``): a block is then
   the lanes of whole heads, two at D = 64, of a group of batch rows, the
   grid (B // G, lane blocks), and each head is computed out of its lanes
-  of the block (``_head_lanes``); ``hvd_attn_heads_per_block_last`` says
+  of the block (``_head_lanes``); ``_heads_per_block`` says
   how many. Nothing is transposed in HBM on the way in or out. Longer
   sequences run
   (batch*heads, Q blocks, K blocks) with K innermost in the forward and
@@ -32,8 +31,8 @@ Two implementations, one semantics:
   take tokens-major operands where a head is whole 128-lane blocks (``D %
   128 == 0``): grids, bodies and the order of tiles stay, a head is the
   lane block the index maps find (``_block_at``), so the results are the
-  head-major call's bits, and ``hvd_attn_operand_layout_last{kernel}``
-  says which way a call was fed. Differentiable:
+  head-major call's bits (a call's lowered text shows in its operands'
+  shapes which way it was fed). Differentiable:
   a ``jax.custom_vjp`` supplies the backward kernels from saved
   (out, logsumexp) residuals, so ring attention trains end-to-end.
 - ``blockwise_attention_reference``: pure-jnp same math; the numerics
@@ -64,17 +63,16 @@ index map, which stops at ``_last_k_block(i)``; the dk/dv kernel is the
 mirror. Every visible tile is visited once and in the whole grid's order,
 so the results are the same bits. What stays empty is the corner where
 the band has not yet left the sequence's start: 36 of 288 steps a slice
-at SmallThinker's shapes, where the whole grid had 772 of 1,024. The
-gauge ``hvd_attn_tiles_last{kind}`` says how many tile pairs a call
-computes and skips and how many grid steps it takes.
+at SmallThinker's shapes, where the whole grid had 772 of 1,024.
+``_tile_plan`` says how many tile pairs a call computes and how far its
+grids' innermost dimensions run.
 
 And ``k``, ``v`` may have fewer heads than ``q`` (*grouped* keys and
 values: query head ``n`` reads key/value head ``n // group``): the
 key/value block is found through the index map, nothing is repeated in
 HBM, and the dk/dv kernel sums over a group's query heads in its float32
-accumulators (a grid axis of its own inside the K block's reduction). The
-gauge ``hvd_attn_kv_group_last`` says the group. Both go through the
-multi-tile kernels, whatever the length.
+accumulators (a grid axis of its own inside the K block's reduction).
+Both go through the multi-tile kernels, whatever the length.
 
 A causal call may round its diagonal to *blocks* instead
 (``block_length=B``): query ``i`` sees the keys of the blocks up to its own
@@ -88,8 +86,7 @@ queries' own block of ``B`` keys in plain XLA, merged through the
 log-sum-exp. No array of ``2S x 2S`` exists. It takes the two streams apart
 and returns them apart (a model cuts them where a row is narrowest);
 ``block_diffusion_attention`` is the same over one array. Under the scope
-``hvd.attn.blockdiff``; ``hvd_attn_tiles_last{kind=blockdiff_*}`` counts
-both calls.
+``hvd.attn.blockdiff``.
 """
 
 from __future__ import annotations
@@ -332,7 +329,7 @@ def _put_head_lanes(into, x, head, heads):
     return jnp.where((lane >= head * d) & (lane < (head + 1) * d), x, into)
 
 
-def _group_specs(kernel, qr, block_q, block_k, slice_counts, heads=None):
+def _group_specs(qr, block_q, block_k, slice_counts, heads=None):
     """The grid of a single-tile call, the heads a block of it holds, the
     block specs of its operands and the shape of its float32 rows (the
     log-sum-exp and its like): ``(grid, heads a block, q spec, k/v spec,
@@ -346,19 +343,13 @@ def _group_specs(kernel, qr, block_q, block_k, slice_counts, heads=None):
     the same lanes of ``G`` rows, blocks ``[G, S, heads a block * D]``,
     rows ``[B, H // heads a block, heads a block, S]`` in blocks ``[G,
     heads a block, S]``, grid ``(B // G, H // heads a block)``: the block
-    map does the head split. Sets ``hvd_attn_group_last{kernel}`` and
-    ``hvd_attn_heads_per_block_last{kernel}`` at trace time, as
-    ``_record_tiles`` does its gauges."""
-    from .. import metrics
-
+    map does the head split."""
     slices, _, width = qr.shape
     per_block = 1 if heads is None else _heads_per_block(
         width // heads, heads)
     lanes = width if heads is None else per_block * (width // heads)
     group = _group_size(slices, block_q, block_k, lanes, qr.dtype.itemsize,
                         heads=per_block, **slice_counts)
-    metrics.ATTN_GROUP_LAST.set(group, kernel=kernel)
-    metrics.ATTN_HEADS_PER_BLOCK_LAST.set(per_block, kernel=kernel)
     if heads is None:
         grid, block_at = (slices // group,), lambda i: (i, 0, 0)
         row_spec = pl.BlockSpec((group, 1, block_q), block_at)
@@ -479,26 +470,6 @@ def _tile_plan(causal, num_qb, num_kb, block_q, block_k, q_offset, k_offset,
         return pairs, num_kb, num_qb
     return (pairs, max(1, int(visible.sum(1).max())),
             max(1, int(visible.sum(0).max())))
-
-
-def _record_tiles(causal, num_qb, num_kb, block_q, block_k, q_offset,
-                  k_offset, window=None, group=1, behind=0):
-    """At trace time, as ``optimizer._record_flush`` does for the wire:
-    the (q, k) tile pairs a slice of this multi-tile call computes and
-    skips, the steps its K-innermost grids take a slice (the dk/dv
-    kernel's is the mirror, K blocks x ``band_qb``), and the query heads
-    that share one key/value head. Returns ``(band_kb, band_qb)``, the
-    innermost extents that count rests on (``_tile_plan``)."""
-    from .. import metrics
-
-    computed, band_kb, band_qb = _tile_plan(
-        causal, num_qb, num_kb, block_q, block_k, q_offset, k_offset, window,
-        behind)
-    metrics.ATTN_TILES_LAST.set(computed, kind="computed")
-    metrics.ATTN_TILES_LAST.set(num_qb * num_kb - computed, kind="skipped")
-    metrics.ATTN_TILES_LAST.set(num_qb * band_kb, kind="grid")
-    metrics.ATTN_KV_GROUP_LAST.set(group)
-    return band_kb, band_qb
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
@@ -961,7 +932,7 @@ def _single_tile_fwd(qr, kr, vr, causal, block_q, block_k, q_offset,
     the operands' layout. Single-tile sequences skip the online-softmax
     machinery, and a grid step takes a group of them."""
     grid, per_block, q_spec, kv_spec, row_spec, rows = _group_specs(
-        "fwd", qr, block_q, block_k, _FWD_SLICE, heads)
+        qr, block_q, block_k, _FWD_SLICE, heads)
     return pl.pallas_call(
         functools.partial(
             _flash_fwd_single_kernel, causal=causal,
@@ -992,7 +963,7 @@ def _single_tile_bwd(qr, kr, vr, do, lse, delta, g_lse, causal, block_q,
     the forward's output ``out`` and neither ``delta`` nor ``g_lse``."""
     from_out = out is not None
     grid, per_block, q_spec, kv_spec, row_spec, _ = _group_specs(
-        "bwd", qr, block_q, block_k,
+        qr, block_q, block_k,
         _BWD_FROM_OUT_SLICE if from_out else _BWD_SLICE, heads)
     return tuple(pl.pallas_call(
         functools.partial(
@@ -1037,25 +1008,8 @@ def _value_width(kr, vr, d: int) -> int:
     bodies read every width off their blocks. Head-major operands only: a
     tokens-major head is a lane block of one width, and ``_flash_tokens``
     reads a ``v`` row of another width as another number of heads, which
-    ``_prepare_flash`` refuses. Sets ``hvd_attn_head_widths_last{kind}`` at
-    trace time."""
-    from .. import metrics
-
-    d_v = d * vr.shape[-1] // kr.shape[-1]
-    metrics.ATTN_HEAD_WIDTHS_LAST.set(d, kind="qk")
-    metrics.ATTN_HEAD_WIDTHS_LAST.set(d_v, kind="v")
-    return d_v
-
-
-def _record_layout(heads, *kernels):
-    """At trace time, beside ``_record_tiles``:
-    ``hvd_attn_operand_layout_last{kernel}``, 1 where the multi-tile
-    kernel took its operands tokens-major and 0 where head-major."""
-    from .. import metrics
-
-    for kernel in kernels:
-        metrics.ATTN_OPERAND_LAYOUT_LAST.set(int(heads is not None),
-                                             kernel=kernel)
+    ``_prepare_flash`` refuses."""
+    return d * vr.shape[-1] // kr.shape[-1]
 
 
 def _fwd_kernels(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
@@ -1079,9 +1033,8 @@ def _fwd_kernels(qr, kr, vr, causal, block_q, block_k, q_offset, k_offset,
         blocks=blocks,
     )
     behind = _behind(blocks)
-    band_kb, _ = _record_tiles(causal, num_qb, num_kb, block_q, block_k,
-                               q_offset, k_offset, window, group, behind)
-    _record_layout(heads, "fwd")
+    _, band_kb, _ = _tile_plan(causal, num_qb, num_kb, block_q, block_k,
+                               q_offset, k_offset, window, behind)
     q_at = _block_at(heads)
     kv_at = _kv_index_map(causal, num_kb, block_q, block_k, q_offset,
                           k_offset, window, group, behind,
@@ -1160,10 +1113,9 @@ def _bwd_kernels(causal, block_q, block_k, q_offset, k_offset, interpret,
 
     num_qb, num_kb = Sq // block_q, Sk // block_k
     behind = _behind(blocks)
-    band_kb, band_qb = _record_tiles(causal, num_qb, num_kb, block_q,
+    _, band_kb, band_qb = _tile_plan(causal, num_qb, num_kb, block_q,
                                      block_k, q_offset, k_offset, window,
-                                     group, behind)
-    _record_layout(heads, "dq", "dkv")
+                                     behind)
     q_at, kv_at = _block_at(heads), _block_at(heads and heads // group)
     kv_seen = _kv_index_map(causal, num_kb, block_q, block_k, q_offset,
                             k_offset, window, group, behind, kv_at)
@@ -1571,8 +1523,7 @@ def flash_attention_tokens_major(q, k, v, num_heads: int,
     (``_block_at``), so the context and every gradient are
     :func:`flash_attention`'s bit for bit, under a window, grouped keys
     and values and a block mask too. Nothing is transposed in HBM either
-    way; ``hvd_attn_operand_layout_last`` says which way the multi-tile
-    kernels were fed. What is left (several tiles of narrower heads: two
+    way. What is left (several tiles of narrower heads: two
     heads a lane block would want lane masks in three more kernels)
     transposes to ``[B, H, S, D]`` and is :func:`flash_attention`'s, so no
     caller needs to know which it is."""
@@ -1686,27 +1637,6 @@ def _merge_own_block(q, k, v, past, lse_past, length):
     return jnp.stack(merged, axis=2).reshape(B, H, S, D).astype(q.dtype)
 
 
-def _record_blockdiff_tiles(S, block_length, block_q, block_k):
-    """At trace time, from the two calls' tile plans alone:
-    ``hvd_attn_tiles_last{kind=blockdiff_*}``, the (q, k) tile pairs of
-    the doubled stream's ``2S x 2S`` square that a slice computes and
-    skips, and the steps the two K-innermost grids take."""
-    from .. import metrics
-
-    block_q = block_q if block_q is not None else _auto_block(S)
-    block_k = block_k if block_k is not None else _auto_block(S)
-    num_qb, num_kb = S // block_q, S // block_k
-    plans = [_tile_plan(True, num_qb, num_kb, block_q, block_k, 0, 0,
-                        behind=behind) for behind in (0, block_length)]
-    computed = sum(pairs for pairs, _, _ in plans)
-    metrics.ATTN_TILES_LAST.set(computed, kind="blockdiff_computed")
-    metrics.ATTN_TILES_LAST.set(4 * num_qb * num_kb - computed,
-                                kind="blockdiff_skipped")
-    metrics.ATTN_TILES_LAST.set(sum(num_qb * band_kb
-                                    for _, band_kb, _ in plans),
-                                kind="blockdiff_grid")
-
-
 def block_diffusion_streams(noisy, clean, block_length: int,
                             block_q: int | None = None,
                             block_k: int | None = None,
@@ -1730,9 +1660,8 @@ def block_diffusion_streams(noisy, clean, block_length: int,
     ``block_length`` keys a query: plain XLA (``_merge_own_block``),
     merged with the kernels' result through the log-sum-exp as ring
     attention merges its shards, differentiably on both sides. All of it
-    under ``hvd.attn.blockdiff``, which is opened here and nowhere else;
-    the gauge ``hvd_attn_tiles_last{kind=blockdiff_*}`` counts both calls
-    (``_record_blockdiff_tiles``). A caller that holds the two streams in
+    under ``hvd.attn.blockdiff``, which is opened here and nowhere else.
+    A caller that holds the two streams in
     one array cuts it before the projections, where a row is narrowest
     (``models/sdar.py``), or calls :func:`block_diffusion_attention`."""
     from ..profiler import annotate_collective
@@ -1746,7 +1675,6 @@ def block_diffusion_streams(noisy, clean, block_length: int,
             f"whole blocks of {block_length}; got q={q_noisy.shape} and "
             f"{q_clean.shape}, k={k_noisy.shape} and {k_clean.shape}")
     with annotate_collective(SCOPE_ATTN_BLOCKDIFF):
-        _record_blockdiff_tiles(S, block_length, block_q, block_k)
         tiles = dict(block_q=block_q, block_k=block_k, interpret=interpret)
         clean = flash_attention(q_clean, k_clean, v_clean, causal=True,
                                 block_length=block_length, **tiles)

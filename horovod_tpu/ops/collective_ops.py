@@ -179,7 +179,7 @@ def _axis_size(axis_name: str) -> int:
 # ---------------------------------------------------------------------------
 
 
-def _allreduce_traced(x, op, axis_name, prescale_factor, postscale_factor):
+def allreduce_traced(x, op, axis_name, prescale_factor, postscale_factor):
     if isinstance(axis_name, (tuple, list)) and len(axis_name) == 2:
         # Hierarchical (cross, local) axes: Sum/Average/Adasum take the
         # two-level ICI+DCN composition (reduce-scatter local → allreduce
@@ -567,7 +567,7 @@ def allreduce(
     ps = _resolve_process_set(process_set)
     traced_axis = _effective_traced_axis(ps)
     if traced_axis is not None:
-        return _allreduce_traced(
+        return allreduce_traced(
             tensor, op, traced_axis, prescale_factor, postscale_factor
         )
     world = _native_world_if_per_process(ps, tensor)
@@ -586,7 +586,7 @@ def allreduce(
         )
     del name  # names exist for runtime negotiation; nothing to key here
     traced = functools.partial(
-        _allreduce_traced,
+        allreduce_traced,
         op=op,
         axis_name=ps.axis_name,
         prescale_factor=prescale_factor,

@@ -873,7 +873,7 @@ def rhd_allreduce_sum(flat, axis_name, world_size: int):
     return jnp.where(r >= p, folded, full)
 
 
-def _two_level_groups(islands) -> tuple[list[list[int]], list[list[int]]]:
+def two_level_groups(islands) -> tuple[list[list[int]], list[list[int]]]:
     """(local groups, cross groups) for ``axis_index_groups``: locals
     are the islands; cross group j = position-j ranks across islands."""
     groups = [list(isl) for isl in islands]
@@ -893,7 +893,7 @@ def two_level_allreduce_sum(flat, axis_name, islands):
 
     from ..profiler import annotate_collective
 
-    groups, cross = _two_level_groups(islands)
+    groups, cross = two_level_groups(islands)
     L = len(groups[0])
     m = int(flat.size)
     pad = (-m) % L
@@ -915,7 +915,7 @@ def _two_level_row_perm(islands, world: int):
     intra-island scatter (over L) then cross-island scatter (over G)
     land rank ``groups[g][j]`` exactly on its own row — the
     ``shard_ownership`` contract preserved through the hierarchy."""
-    groups, _ = _two_level_groups(islands)
+    groups, _ = two_level_groups(islands)
     G, L = len(groups), len(groups[0])
     perm = [0] * world
     for g in range(G):
@@ -935,7 +935,7 @@ def two_level_reducescatter_sum(flat, axis_name, world_size: int, islands):
     from ..profiler import annotate_collective
 
     n = int(world_size)
-    groups, cross = _two_level_groups(islands)
+    groups, cross = two_level_groups(islands)
     perm = jnp.asarray(_two_level_row_perm(islands, n))
     rows = flat.reshape(n, -1)[perm].reshape(-1)
     with annotate_collective("planner.two_level.rs_local"):
@@ -958,7 +958,7 @@ def two_level_allgather_row(row, axis_name, world_size: int, islands):
     from ..profiler import annotate_collective
 
     n = int(world_size)
-    groups, cross = _two_level_groups(islands)
+    groups, cross = two_level_groups(islands)
     perm = _two_level_row_perm(islands, n)
     inv = [0] * n
     for pos, src in enumerate(perm):
@@ -994,7 +994,7 @@ def two_level_alltoall(chunks, axis_name, islands):
 
     from ..profiler import annotate_collective
 
-    groups, cross = _two_level_groups(islands)
+    groups, cross = two_level_groups(islands)
     G, L = len(groups), len(groups[0])
     n = G * L
     # Destination-rank rows → [l2, i2] island-major view (rank

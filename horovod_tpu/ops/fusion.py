@@ -310,12 +310,12 @@ def _note_leaf_sizes(tensors) -> None:
 
 
 def _reduce_bucket(flat, op, axis_name, prescale_factor, postscale_factor):
-    from .collective_ops import _allreduce_traced
+    from .collective_ops import allreduce_traced
 
-    return _allreduce_traced(flat, op, axis_name, prescale_factor, postscale_factor)
+    return allreduce_traced(flat, op, axis_name, prescale_factor, postscale_factor)
 
 
-def _plan_bucket(op_name: str, nbytes: int, axis_name, world_size,
+def bucket_plan(op_name: str, nbytes: int, axis_name, world_size,
                  candidates=None):
     """The comms planner's schedule for one bucket, or None (planner
     off, world unknown, hierarchical axis tuple — the two-level mesh
@@ -341,7 +341,7 @@ def _plan_bucket(op_name: str, nbytes: int, axis_name, world_size,
     return plan
 
 
-def _bucket_suffix(plan) -> str:
+def bucket_suffix(plan) -> str:
     """The annotation-name leg naming a non-flat schedule — parsed back
     out by ``comms_model._BUCKET_NAME_RE`` so re-ingested spans feed
     the right per-algorithm fit."""
@@ -424,11 +424,11 @@ def _fused_allreduce(tensors, op, axis_name, threshold_bytes,
         # (the tracing plane's per-collective vocabulary, trace-time leg).
         sizes = {i: _wire_bytes(tensors[i]) for i in bucket}
         nbytes = sum(sizes.values())
-        plan = (_plan_bucket("allreduce", nbytes, axis_name, world_size)
+        plan = (bucket_plan("allreduce", nbytes, axis_name, world_size)
                 if plannable else None)
         alone, small = _alone_and_small(bucket, sizes, plan, hierarchical)
         with annotate_collective(
-                f"allreduce.bucket{bi}.{nbytes}B{_bucket_suffix(plan)}"):
+                f"allreduce.bucket{bi}.{nbytes}B{bucket_suffix(plan)}"):
             # Next to each other, in their own shapes: the compiler's
             # combiner merges neighbouring all-reduces into one that takes
             # its operands where they lie.
@@ -569,7 +569,7 @@ def _flat_padded(leaf, length: int):
     return jnp.pad(flat, (0, pad)) if pad else flat
 
 
-def _pack_shard_rows(leaves, shard_sizes, world_size):
+def pack_shard_rows(leaves, shard_sizes, world_size):
     """Pack same-dtype leaves into one ``(world_size, R)`` block whose row
     ``r`` is the concatenation of rank r's per-leaf owned slices — the
     layout under which a tiled reduce-scatter of the flattened block hands
@@ -580,8 +580,8 @@ def _pack_shard_rows(leaves, shard_sizes, world_size):
     return rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=1)
 
 
-def _split_shard_row(row, shard_sizes):
-    """Inverse of one row of :func:`_pack_shard_rows`: split a rank's
+def split_shard_row(row, shard_sizes):
+    """Inverse of one row of :func:`pack_shard_rows`: split a rank's
     contiguous owned run back into per-leaf 1-D shards."""
     out = []
     offset = 0
@@ -631,11 +631,11 @@ def _fused_reducescatter(tensors, op, axis_name, world_size,
             bucket_leaves(tensors, threshold_bytes), issue_reversed):
         wire = {i: _wire_bytes(tensors[i]) for i in bucket}
         nbytes = sum(wire.values())
-        plan = _plan_bucket("reducescatter", nbytes, axis_name, n)
+        plan = bucket_plan("reducescatter", nbytes, axis_name, n)
         alone, small = _alone_and_small(bucket, wire, plan)
         small_sizes = [sizes[i] for i in small]
         with annotate_collective(
-                f"reducescatter.bucket{bi}.{nbytes}B{_bucket_suffix(plan)}"):
+                f"reducescatter.bucket{bi}.{nbytes}B{bucket_suffix(plan)}"):
             # A leaf alone: the tiled scatter of its flat view is its
             # owned shard, nothing packed and nothing cut.
             for i in (reversed(alone) if issue_reversed else alone):
@@ -643,13 +643,13 @@ def _fused_reducescatter(tensors, op, axis_name, world_size,
                     _flat_padded(tensors[i], n * sizes[i]), plan)
             if small:
                 packed_bytes += sum(wire[i] for i in small)
-                row = scatter(_pack_shard_rows(
+                row = scatter(pack_shard_rows(
                     [tensors[i] for i in small], small_sizes, n).ravel(),
                     plan)
         if small:
             with annotate_collective(SCOPE_WIRE_UNPACK):
                 for i, shard in zip(small,
-                                    _split_shard_row(row, small_sizes)):
+                                    split_shard_row(row, small_sizes)):
                     out[i] = shard
     return out, packed_bytes
 
@@ -676,7 +676,7 @@ def fused_reducescatter(
     :data:`PACK_CUTOFF_BYTES` is scattered as itself (a tiled
     ``psum_scatter`` of its flat view *is* its owned shard: the ownership
     map, :func:`shard_ownership`, is per leaf and contiguous), and the
-    smaller ones are packed in the :func:`_pack_shard_rows` interleaved
+    smaller ones are packed in the :func:`pack_shard_rows` interleaved
     layout so ONE tiled ``psum_scatter`` hands every rank their owned
     slices. A planned bucket packs every leaf. Returns one 1-D shard per
     input tensor, length ``shard_ownership(tensors, world_size)[i]``.
@@ -705,12 +705,12 @@ def _fused_allgather_shards(shards, templates, axis_name, world_size,
         itemsize = {i: jnp.dtype(shards[i].dtype).itemsize for i in bucket}
         wire = {i: int(templates[i].size) * itemsize[i] for i in bucket}
         nbytes = sum(n * sizes[i] * itemsize[i] for i in bucket)
-        plan = _plan_bucket("allgather", nbytes, axis_name, n)
+        plan = bucket_plan("allgather", nbytes, axis_name, n)
         alone, small = _alone_and_small(bucket, wire, plan)
         small_sizes = [sizes[i] for i in small]
         full = {}
         with annotate_collective(
-                f"allgather.bucket{bi}.{nbytes}B{_bucket_suffix(plan)}"):
+                f"allgather.bucket{bi}.{nbytes}B{bucket_suffix(plan)}"):
             # A leaf alone: the tiled gather of its shards is its flat
             # view, nothing concatenated and nothing cut out of a grid.
             for i in (reversed(alone) if issue_reversed else alone):

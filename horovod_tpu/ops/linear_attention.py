@@ -40,9 +40,7 @@ product at ``Precision.HIGHEST``, and is differentiated by a rule of its
 own that keeps the inverse and the solution and makes two such products.
 The loop's left operands are rounded to the compute type once, outside
 it. The rest of the scalar rule is plain JAX, differentiated by JAX. The
-scope ``hvd.linattn.scan`` is around all of it, forward and backward, and
-the gauge ``hvd_linattn_chunks_last{chunk,heads_here}`` says at trace time
-how many chunks a sequence the step that runs scans.
+scope ``hvd.linattn.scan`` is around all of it, forward and backward.
 
 :func:`kimi_delta_rule` is the same recurrence with **a decay a key
 channel** (``g [B, S, H, d_k]``: ``S' = Diag(exp(g_t)) S``; Kimi Delta
@@ -50,24 +48,23 @@ Attention, arXiv:2510.26692, ``flash-linear-attention``'s ``kda``). Its
 chunk form is the one above with ``gamma [C, d_k]``, but the decay now
 sits inside the contraction, ``A_ij = beta_i sum_c k_ic k_jc exp(gamma_ic -
 gamma_jc)``, so ``(k k^T) * decay`` is no more and the pair terms are
-:func:`_pair_terms`'s. The plain solve, the plain scan over chunks, the
-scope and the gauge are shared; ``hvd_linattn_decay_width_last`` says which
-rule the step that runs holds (1, or ``d_k``). Where the shapes fill a TPU's
+:func:`_pair_terms`'s. The plain solve, the plain scan over chunks and
+the scope are shared. Where the shapes fill a TPU's
 tiles (``d_k`` whole 128-lane blocks, ``sub`` whole sublane tiles) the pair
-terms are a primitive whose lowering the platform chooses: for a TPU
+terms are a primitive whose lowering the platform chooses
+(``kernel_parts.where_lowered``): for a TPU
 :func:`pair_terms_kernel`'s Pallas kernel, which forms them in VMEM, with a
-backward kernel of its own; for anything else, and at any other shape, the
-plain :func:`_pair_terms` (``hvd_linattn_pair_kernel_last`` says, as the
-program is lowered, the chunks a grid step takes, or 0 for the plain form,
-and its label ``operands`` the layout they cross HBM in).
+backward kernel of its own, :func:`_chunks_a_step` chunks a grid step; for
+anything else, and at any other shape, the plain :func:`_pair_terms`.
 Its solve and its loop over the chunks are a primitive of the same kind
 (``d_k`` and ``d_v`` whole lane blocks, the chunk a power of two of whole
-sublane tiles, eight heads a grid step): for a TPU
-:func:`chunk_scan_kernel`'s forward and backward kernels, in which a head's
-float32 state stays in VMEM over its chunks and ``(I + A)`` is solved in
-VMEM; for anything else the plain :func:`_chunk_scan`, which is the scalar
-rule's :func:`solve_unit_lower` and ``lax.scan``
-(``hvd_linattn_scan_kernel_last``: the heads a grid step, or 0).
+sublane tiles, eight heads a grid step: :func:`_scan_heads_a_step`): for a
+TPU :func:`chunk_scan_kernel`'s forward and backward kernels, in which a
+head's float32 state stays in VMEM over its chunks and ``(I + A)`` is
+solved in VMEM; for anything else the plain :func:`_chunk_scan`, which is
+the scalar rule's :func:`solve_unit_lower` and ``lax.scan``. The program
+says which form it holds: the primitives ``hvd_kda_pair_terms`` and
+``hvd_kda_chunk_scan`` in its jaxpr, the kernels' names in its text.
 Its ``gamma`` is a float32 product of the chunk's lower triangle of ones
 with ``g`` at ``Precision.HIGHEST``: summed as ``jnp.cumsum`` over the rows
 of ``[C, d_k]`` it is a ``reduce-window``, which the v5e runs at a
@@ -89,12 +86,12 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.extend.core import Primitive
-from jax.interpreters import mlir
 from jax.interpreters import partial_eval as pe
 
 from ..attribution import SCOPE_LINATTN_SCAN
 from ..profiler import annotate_collective
+from .kernel_parts import (MASKED, NN, NT, TN, chunk_grid_call, dot,
+                           running_sum, where_lowered)
 
 
 def short_conv(x, w, bias=None):
@@ -124,7 +121,6 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = 64):
             f"gated_delta_rule: a sequence of {seq} is no multiple of the "
             f"chunk of {chunk}; pad it upstream")
     count, dtype, f32 = seq // chunk, v.dtype, jnp.float32
-    _record_chunks(count, chunk, heads)
 
     def chunks(x):  # [B, S, H, ...] -> [B, H, chunks, chunk, ...]
         x = x.reshape((batch, count, chunk) + x.shape[2:])
@@ -235,10 +231,6 @@ def _pair_terms(q, k, gamma, sub: int, dtype):
 # the attention kernels. XLA names the custom call's instruction after it.
 PAIR_KERNEL_NAME = "kda_pair_terms"
 PAIR_CHUNKS_A_STEP = 4  # of a grid step, where that many divide the chunks
-_MASKED = -1e30  # an exponent above the diagonal: exp gives 0, never a nan
-_NT = (((1,), (1,)), ((), ()))  # a @ b^T
-_TN = (((0,), (0,)), ((), ()))  # a^T @ b
-_NN = (((1,), (0,)), ((), ()))  # a @ b
 
 
 def _by_head(x, chunk: int):
@@ -282,7 +274,7 @@ def _sub_block(q_ref, k_ref, gamma_ref, c, lanes, lo, hi, dtype):
                  <= lax.broadcasted_iota(jnp.int32, shape, 0) + top)
         pieces.append((top, jnp.exp(jnp.where(
             lower, gamma[top:top + rows][:, None, :]
-            - gamma[:top + rows][None, :, :], _MASKED))))
+            - gamma[:top + rows][None, :, :], MASKED))))
     if not lo:
         return q, k, pieces, None
     left = jnp.exp(gamma - gamma[:1])
@@ -311,8 +303,7 @@ def _pair_forward_kernel(q_ref, k_ref, gamma_ref, inside_ref, a_ref, *,
             q, k, pieces, far = _sub_block(q_ref, k_ref, gamma_ref, c, lanes,
                                            lo, hi, dtype)
             if far is not None:
-                both = lax.dot_general(far[0], far[1], _NT,
-                                       preferred_element_type=f32)
+                both = dot(far[0], far[1], NT)
                 inside_ref[r, c, lo:hi, 0:lo] = both[:sub]
                 a_ref[r, c, lo:hi, 0:lo] = both[sub:]
             for top, cube in pieces:
@@ -345,7 +336,6 @@ def _pair_backward_kernel(q_ref, k_ref, gamma_ref, inside_bar_ref, a_bar_ref,
     own. Cotangents of ``dtype`` operands go into their products rounded
     to ``dtype``, as the plain form's transposed products take them."""
     heads, chunks, size, _ = inside_bar_ref.shape
-    f32 = jnp.float32
 
     def one_chunk(t, carry):
         c, r, lanes = _one_chunk_of_a_head(q_ref, t, heads)
@@ -373,12 +363,10 @@ def _pair_backward_kernel(q_ref, k_ref, gamma_ref, inside_bar_ref, a_bar_ref,
                 bars = jnp.concatenate([inside_bar_ref[r, c, lo:hi, 0:lo],
                                         a_bar_ref[r, c, lo:hi, 0:lo]],
                                        0).astype(dtype)
-                to_left = lax.dot_general(bars, k_right, _NN,
-                                          preferred_element_type=f32)
+                to_left = dot(bars, k_right)
                 q_bar = q_bar + to_left[:sub] * left
                 as_left = as_left + to_left[sub:] * left
-                right_ref[0:lo, :] += right * lax.dot_general(
-                    bars, stacked, _TN, preferred_element_type=f32)
+                right_ref[0:lo, :] += right * dot(bars, stacked, TN)
             as_right = right_ref[lo:hi, :]
             q_bar_ref[c, lo:hi, lanes] = q_bar.astype(q_bar_ref.dtype)
             k_bar_ref[c, lo:hi, lanes] = (as_left + as_right).astype(
@@ -410,24 +398,17 @@ def _pair_call(kernel, operands, results, scratch=(), *, chunk, step, heads,
     batch, seq, all_heads, width = operands[0][1].shape
     count = seq // chunk
     kinds = {
-        "tokens": ((batch, count, chunk, all_heads * width), pl.BlockSpec(
-            (None, step, chunk, heads * width),
-            lambda i, n, h: (i, n, 0, h))),
-        "pairs": ((batch, all_heads, count, chunk, chunk), pl.BlockSpec(
-            (None, heads, step, chunk, chunk),
-            lambda i, n, h: (i, h, n, 0, 0))),
+        "tokens": ((batch, count, chunk, all_heads * width),
+                   (None, step, chunk, heads * width),
+                   lambda i, n, h: (i, n, 0, h)),
+        "pairs": ((batch, all_heads, count, chunk, chunk),
+                  (None, heads, step, chunk, chunk),
+                  lambda i, n, h: (i, h, n, 0, 0)),
     }
-    out = pl.pallas_call(
-        functools.partial(kernel, sub=sub, dtype=dtype),
-        grid=(batch, count // step, all_heads // heads),
-        in_specs=[kinds[kind][1] for kind, _ in operands],
-        out_specs=[kinds[kind][1] for kind, _ in results],
-        out_shape=[jax.ShapeDtypeStruct(kinds[kind][0], kept)
-                   for kind, kept in results],
-        scratch_shapes=scratch,
-        interpret=interpret,
-        name=PAIR_KERNEL_NAME,
-    )(*(x.reshape(kinds[kind][0]) for kind, x in operands))
+    out = chunk_grid_call(
+        functools.partial(kernel, sub=sub, dtype=dtype), kinds, operands,
+        results, scratch, grid=(batch, count // step, all_heads // heads),
+        turned=False, interpret=interpret, name=PAIR_KERNEL_NAME)
     return [x.reshape(operands[0][1].shape) if kind == "tokens" else x
             for (kind, _), x in zip(results, out)]
 
@@ -459,51 +440,16 @@ def _backward_plain(q, k, gamma, *bars, **how):
                    q, k, gamma)[1](bars)
 
 
-def _where_lowered(name, results, by_kernel, plain, record):
-    """The primitive ``name`` whose lowering for a TPU is ``by_kernel`` and
-    for any other platform ``plain`` (``by_kernel`` interpreted where the
-    tests say ``interpret``), each called with the primitive's parameters:
-    the lowering platform is what the code can observe, and a trace does
-    not know it (``benchmark/aot.py`` lowers for a v5e from a CPU;
-    ``jax.default_backend()`` would say ``cpu`` there). Only the chosen form
-    is ever traced, and ``record(kernel, **how)`` says which (a gauge) as it
-    is lowered. ``results(*avals, **how)`` are the results' abstract values."""
-    primitive = Primitive(name)
-    primitive.multiple_results = True
-    primitive.def_abstract_eval(lambda *avals, **how: results(*avals, **how))
-
-    @functools.cache
-    def alone(**how):  # called outside any trace
-        return jax.jit(functools.partial(primitive.bind, **how))
-
-    primitive.def_impl(lambda *xs, **how: alone(**how)(*xs))
-
-    def lowering(on_tpu):
-        def form(*xs, interpret, **how):
-            kernel = on_tpu or interpret
-            record(kernel, **how)
-            return (by_kernel if kernel else plain)(
-                *xs, interpret=interpret, **how)
-
-        return mlir.lower_fun(form, multiple_results=True)
-
-    mlir.register_lowering(primitive, lowering(True), platform="tpu")
-    mlir.register_lowering(primitive, lowering(False))
-    return primitive
-
-
 def _pair_avals(q, k, gamma, *, chunk, **_):
     batch, seq, heads, _ = k.shape
     return [gamma.update(shape=(batch, heads, seq // chunk, chunk, chunk))] * 2
 
 
-_pair_forward_p = _where_lowered(
-    "hvd_kda_pair_terms", _pair_avals,
-    _forward_by_kernel, _forward_plain, lambda *a, **k: _pair_form(*a, **k))
-_pair_backward_p = _where_lowered(
+_pair_forward_p = where_lowered(
+    "hvd_kda_pair_terms", _pair_avals, _forward_by_kernel, _forward_plain)
+_pair_backward_p = where_lowered(
     "hvd_kda_pair_terms_backward", lambda *kept, **_: list(kept[:3]),
-    _backward_by_kernel, _backward_plain,
-    lambda *a, **k: _pair_form(*a, **k))
+    _backward_by_kernel, _backward_plain)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -523,10 +469,11 @@ def pair_terms_kernel(q, k, gamma, chunk, sub, dtype, interpret=False):
     float32 throughout. The residuals are the operands; the backward
     kernel forms the factors again in VMEM and writes ``dq``, ``dk``,
     ``dgamma`` as ``[B, S, H * d]`` too. Each pass is a primitive of
-    its own (:func:`_where_lowered`), so a recomputed layer's policy sees
-    no ``pallas_call`` whose results it would keep (134 MB a layer at
-    8,192 tokens that no backward kernel wants): they are formed again in
-    the backward pass, as the plain form's under ``jax.checkpoint``."""
+    its own (``kernel_parts.where_lowered``), so a recomputed layer's
+    policy sees no ``pallas_call`` whose results it would keep (134 MB a
+    layer at 8,192 tokens that no backward kernel wants): they are formed
+    again in the backward pass, as the plain form's under
+    ``jax.checkpoint``."""
     return _pair_forward(q, k, gamma, chunk, sub, dtype, interpret)[0]
 
 
@@ -560,7 +507,6 @@ def _pair_terms_where_lowered(q, k, gamma, chunk, sub, dtype):
     rows = 32 // min(q.dtype.itemsize, k.dtype.itemsize)  # a tile's sublanes
     if k.shape[-1] % 128 == 0 and sub % rows == 0:
         return pair_terms_kernel(q, k, gamma, chunk, sub, dtype)
-    _record_pair_path(0, sub)
     return jax.checkpoint(functools.partial(
         _forward_plain, chunk=chunk, sub=sub, dtype=dtype))(q, k, gamma)
 
@@ -594,13 +540,12 @@ def kimi_delta_rule(q, k, v, g, beta, chunk: int = 64, sub: int = 16):
     ``[B, H, N, C, C]``, which go from kernel to kernel; the plain forms
     (any platform but a TPU, and shapes that fill no tile) take
     :func:`_by_head`'s view of everything."""
-    batch, seq, heads, d_v = v.shape
+    seq = v.shape[1]
     if seq % chunk or chunk % sub:
         raise ValueError(
             f"kimi_delta_rule: a sequence of {seq} is no multiple of the "
             f"chunk of {chunk}, or the chunk none of the sub-block of "
             f"{sub}; pad it upstream")
-    _record_chunks(seq // chunk, chunk, heads, decay_width=k.shape[-1])
     with annotate_collective(SCOPE_LINATTN_SCAN):
         beta = _by_head(beta.astype(jnp.float32), chunk)[..., None]
         gamma = _running_sums(g.astype(jnp.float32), chunk)
@@ -610,26 +555,18 @@ def kimi_delta_rule(q, k, v, g, beta, chunk: int = 64, sub: int = 16):
 
 
 def _triangles_product(spec, x, chunk):
-    """``x [B, S, H, d]`` float32 summed along each chunk's rows by one
-    product with the ``[C, C]`` lower triangle of ones, as ``spec`` says
-    (``ij`` the triangle, ``b`` and ``n`` batch and chunk, ``x`` the heads'
-    lanes side by side), at ``Precision.HIGHEST``. Batch and chunk are the
-    product's batch dimensions, the triangle broadcast (XLA never writes
-    it out): a recomputed layer's policy keeps every product without one,
-    and ``gamma`` would stay, 134 MB a layer; and a product's batch
-    dimensions lead its result, so the sums come out ``[B, N, C, H * d]``,
-    which is ``[B, S, H * d]`` where the kernels read it (with the head as
-    a dimension of its own, batch or free, the v5e's compiler lays the
-    result out head-major and copies it back; with the chunk alone as a
-    batch dimension chunk-major: 32 ms of its step in copies)."""
-    f32 = jnp.float32
+    """``x [B, S, H, d]`` float32 summed along each chunk's rows
+    (``kernel_parts.running_sum``) as ``spec`` says: ``ij`` the triangle,
+    ``b`` and ``n`` batch and chunk, ``x`` the heads' lanes side by side.
+    Batch and chunk are the product's batch dimensions, and a product's
+    batch dimensions lead its result, so the sums come out ``[B, N, C, H *
+    d]``, which is ``[B, S, H * d]`` where the kernels read it (with the
+    head as a dimension of its own, batch or free, the v5e's compiler lays
+    the result out head-major and copies it back; with the chunk alone as
+    a batch dimension chunk-major: 32 ms of its step in copies)."""
     batch, seq = x.shape[:2]
-    ones = jnp.broadcast_to(jnp.tril(jnp.ones((chunk, chunk), f32)),
-                            (batch, seq // chunk, chunk, chunk))
-    return jnp.einsum(
-        spec, ones, x.reshape((batch, seq // chunk, chunk, -1)),
-        precision=lax.Precision.HIGHEST,
-        preferred_element_type=f32).reshape(x.shape)
+    return running_sum(spec, (batch, seq // chunk), chunk)(
+        x.reshape((batch, seq // chunk, chunk, -1))).reshape(x.shape)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
@@ -790,11 +727,6 @@ def _heads_a_step(heads: int) -> int:
     return max(n for n in range(1, SCAN_HEADS_A_STEP + 1) if not heads % n)
 
 
-def _dot(left, right, dims=_NN):
-    return lax.dot_general(left, right, dims,
-                           preferred_element_type=jnp.float32)
-
-
 def _terms(x):
     """Float32 ``x`` as the sum of three float32 terms that are each a
     bfloat16 number, largest first: a term is the top eight significant
@@ -811,7 +743,7 @@ def _terms(x):
     return terms + [x]
 
 
-def _dot32(left, right, dims=_NN):
+def _dot32(left, right, dims=NN):
     """A product of float32 operands at full precision, as :func:`_exact`:
     the six products of their bfloat16 terms that ``Precision.HIGHEST`` is
     on a TPU (``i + j <= 2``; three where an operand is bfloat16 as it
@@ -825,7 +757,7 @@ def _dot32(left, right, dims=_NN):
     a, b = _terms(left), _terms(right)
     pairs = sorted(((i, j) for i in range(len(a)) for j in range(len(b))
                     if i + j <= 2), key=lambda p: -sum(p))
-    return _dot(
+    return dot(
         jnp.concatenate([a[i] for i, _ in pairs], over_left).astype(
             jnp.bfloat16),
         jnp.concatenate([b[j] for _, j in pairs], over_right).astype(
@@ -957,7 +889,7 @@ def _chunks_of_the_heads(q_ref, k_ref, v_ref, gamma_ref, inside_ref, solved,
         c["w"] = _dot32(c["scaled"], c["grown"])
         c["rounded"] = c["w"].astype(dtype)
     for c in heads:
-        c["new"] = (c["u"] - _dot(c["rounded"], c["entered"])).astype(dtype)
+        c["new"] = (c["u"] - dot(c["rounded"], c["entered"])).astype(dtype)
     return heads
 
 
@@ -985,12 +917,12 @@ def _scan_forward_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref, inside_ref,
     heads = _chunks_of_the_heads(q_ref, k_ref, v_ref, gamma_ref, inside_ref,
                                  solved, states)
     for (r, _, inverse), c, state in zip(solved, heads, states):
-        out = _dot(c["q_in"], c["entered"]) + _dot(c["inside"], c["new"])
+        out = dot(c["q_in"], c["entered"]) + dot(c["inside"], c["new"])
         o_ref[:, _lanes(o_ref, r, len(solved))] = out.astype(o_ref.dtype)
         for ref, value in zip(kept_refs, (state, inverse)):
             ref[r] = value
     state_ref[h] = jnp.stack([
-        _turned(c["kept"]) * state + _dot(c["k_out"], c["new"], _TN)
+        _turned(c["kept"]) * state + dot(c["k_out"], c["new"], TN)
         for c, state in zip(heads, states)])
 
 
@@ -1036,30 +968,30 @@ def _scan_backward_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref,
         c["led"] = ahead.astype(dtype)
     for (r, _, _), c in zip(solved, chunks):
         # o = q_in entered + inside new; leaving = kept state + k_out^T new
-        c["q_in_bar"] = given(_dot(c["o_bar"], c["entered"], _NT))
-        inside_bar_ref[r] = given(_dot(c["o_bar"], c["new"], _NT))
-        c["k_out_bar"] = given(_dot(c["new"], c["led"], _NT))
-        c["new_bar"] = (given(_dot(c["inside"], c["o_bar"], _TN))
-                        + given(_dot(c["k_out"], c["led"])))
+        c["q_in_bar"] = given(dot(c["o_bar"], c["entered"], NT))
+        inside_bar_ref[r] = given(dot(c["o_bar"], c["new"], NT))
+        c["k_out_bar"] = given(dot(c["new"], c["led"], NT))
+        c["new_bar"] = (given(dot(c["inside"], c["o_bar"], TN))
+                        + given(dot(c["k_out"], c["led"])))
         c["sent"] = c["new_bar"].astype(dtype)
     leaving = []
     for c, ahead in zip(chunks, aheads):
         # new = u - rounded entered
-        c["w_bar"] = (-_dot(c["sent"], c["entered"], _NT)).astype(dtype)
+        c["w_bar"] = (-dot(c["sent"], c["entered"], NT)).astype(dtype)
         leaving.append(
             _turned(c["kept"]) * ahead
-            + given(_dot(c["q_in"], c["o_bar"], _TN))
-            + given(-_dot(c["rounded"], c["sent"], _TN)))
+            + given(dot(c["q_in"], c["o_bar"], TN))
+            + given(-dot(c["rounded"], c["sent"], TN)))
     ahead_ref[h] = jnp.stack(leaving)
     for (_, _, inverse), c in zip(solved, chunks):
         # (I + A) [u | w] = beta [v | k grow]
-        c["v_side"] = _dot32(inverse, c["new_bar"], _TN)
-        c["k_side"] = _dot32(inverse, c["w_bar"], _TN)
+        c["v_side"] = _dot32(inverse, c["new_bar"], TN)
+        c["k_side"] = _dot32(inverse, c["w_bar"], TN)
     beta_bars = []
     for (r, _, _), c, state, ahead in zip(solved, chunks, states, aheads):
         v_side, k_side, by_row = c["v_side"], c["k_side"], c["by_row"]
-        a_bar = jnp.where(lower, -(_dot32(v_side, c["u"], _NT)
-                                   + _dot32(k_side, c["w"], _NT)), 0.0)
+        a_bar = jnp.where(lower, -(_dot32(v_side, c["u"], NT)
+                                   + _dot32(k_side, c["w"], NT)), 0.0)
         a_bar_ref[r] = by_row * a_bar
         beta_bars.append(_turned(
             jnp.sum(a_bar * a_ref[r], 1, keepdims=True)
@@ -1086,9 +1018,10 @@ def _scan_backward_kernel(q_ref, k_ref, v_ref, gamma_ref, beta_ref,
 def _scan_call(kernel, operands, results, scratch, *, shape, turned, step,
                interpret):
     """``kernel`` over the grid ``(B, chunks, H / step)``, the heads
-    innermost, for ``shape = (B, H, N, C, d_k, d_v)``. ``operands`` and
-    ``results`` are ``(kind, array or dtype)``, a block of each kind one
-    chunk of one step's heads: ``keys`` / ``values [B, S, H * d_k | d_v]``
+    innermost (``kernel_parts.chunk_grid_call``), for ``shape = (B, H, N,
+    C, d_k, d_v)``. ``operands`` and ``results`` are ``(kind, array or
+    dtype)``, a block of each kind one chunk of one step's heads: ``keys``
+    / ``values [B, S, H * d_k | d_v]``
     (``q``, ``k``, ``gamma`` / ``v``, ``o`` and all their cotangents **where
     the projections wrote them and the gate and the convolution's backward
     read them**: the chunk's rows of the step's lane blocks, a head
@@ -1099,36 +1032,26 @@ def _scan_call(kernel, operands, results, scratch, *, shape, turned, step,
     first."""
     batch, heads, count, chunk, d_k, d_v = shape
 
-    def at(n):
-        return count - 1 - n if turned else n
-
     def tokens(width):
-        return ((batch, count * chunk, heads * width), pl.BlockSpec(
-            (None, chunk, step * width), lambda i, n, h: (i, at(n), h)))
+        return ((batch, count * chunk, heads * width),
+                (None, chunk, step * width), lambda i, n, h: (i, n, h))
 
     kinds = {
         "keys": tokens(d_k), "values": tokens(d_v),
-        "pairs": ((batch, heads, count, chunk, chunk), pl.BlockSpec(
-            (None, step, None, chunk, chunk),
-            lambda i, n, h: (i, h, at(n), 0, 0))),
-        "rows": ((batch, count, heads, chunk), pl.BlockSpec(
-            (None, None, heads, chunk), lambda i, n, h: (i, at(n), 0, 0))),
-        "states": ((batch, count, heads, d_k, d_v), pl.BlockSpec(
-            (None, None, step, d_k, d_v),
-            lambda i, n, h: (i, at(n), h, 0, 0))),
+        "pairs": ((batch, heads, count, chunk, chunk),
+                  (None, step, None, chunk, chunk),
+                  lambda i, n, h: (i, h, n, 0, 0)),
+        "rows": ((batch, count, heads, chunk), (None, None, heads, chunk),
+                 lambda i, n, h: (i, n, 0, 0)),
+        "states": ((batch, count, heads, d_k, d_v),
+                   (None, None, step, d_k, d_v),
+                   lambda i, n, h: (i, n, h, 0, 0)),
     }
-    return pl.pallas_call(
-        kernel,
-        grid=(batch, count, heads // step),
-        in_specs=[kinds[kind][1] for kind, _ in operands],
-        out_specs=[kinds[kind][1] for kind, _ in results],
-        out_shape=[jax.ShapeDtypeStruct(kinds[kind][0], dtype)
-                   for kind, dtype in results],
-        scratch_shapes=[pltpu.VMEM((heads // step, step, d_k, d_v),
-                                   jnp.float32)] + scratch,
-        interpret=interpret,
-        name=SCAN_KERNEL_NAME,
-    )(*(x.reshape(kinds[kind][0]) for kind, x in operands))
+    return chunk_grid_call(
+        kernel, kinds, operands, results,
+        [pltpu.VMEM((heads // step, step, d_k, d_v), jnp.float32)] + scratch,
+        grid=(batch, count, heads // step), turned=turned,
+        interpret=interpret, name=SCAN_KERNEL_NAME)
 
 
 def _scan_shape(k, v, chunk):
@@ -1204,13 +1127,12 @@ def _scan_forward_results(q, k, v, *more, states, chunk, **_):
     return [v] + _kept_avals(k, v, chunk) * states
 
 
-_scan_forward_p = _where_lowered(
+_scan_forward_p = where_lowered(
     "hvd_kda_chunk_scan", _scan_forward_results, _scan_forward_by_kernel,
-    _scan_forward_plain, lambda *a, **k: _scan_form(*a, **k))
-_scan_backward_p = _where_lowered(
+    _scan_forward_plain)
+_scan_backward_p = where_lowered(
     "hvd_kda_chunk_scan_backward", lambda *kept, **_: list(kept[:7]),
-    _scan_backward_by_kernel, _scan_backward_plain,
-    lambda *a, **k: _scan_form(*a, **k))
+    _scan_backward_by_kernel, _scan_backward_plain)
 
 
 def _unread_residuals(used, eqn):
@@ -1241,8 +1163,8 @@ def chunk_scan_kernel(q, k, v, gamma, beta, inside, a, interpret=False):
     where a backward pass follows, the float32 states that enter each chunk
     and the inverses: the backward kernel's residuals with the operands.
     Same rounding points as the plain form. Each pass is a primitive of its
-    own (:func:`_where_lowered`), so a recomputed layer's policy sees no
-    ``pallas_call`` whose results it would keep."""
+    own (``kernel_parts.where_lowered``), so a recomputed layer's policy
+    sees no ``pallas_call`` whose results it would keep."""
     return _scan_forward_p.bind(q, k, v, gamma, beta, inside, a, states=False,
                                 **_scan_how(k, a, interpret))[0]
 
@@ -1275,54 +1197,4 @@ def _chunk_scan_where_lowered(q, k, v, gamma, beta, inside, a):
     chunk = a.shape[-1]
     if _scan_heads_a_step(k, v, chunk):
         return chunk_scan_kernel(q, k, v, gamma, beta, inside, a)
-    _record_scan_path(0, chunk)
     return _chunk_scan_of_tokens(q, k, v, gamma, beta, inside, a)
-
-
-def _record_chunks(count: int, chunk: int, heads: int,
-                   decay_width: int = 1) -> None:
-    """At trace time, as ``models.experts._record_slots``: the step that
-    runs scans this many chunks a sequence, under a decay that many wide
-    a head (1: :func:`gated_delta_rule`'s scalar)."""
-    from .. import metrics
-
-    metrics.LINATTN_CHUNKS_LAST.set(
-        count, chunk=str(chunk), heads_here=str(heads))
-    metrics.LINATTN_DECAY_WIDTH_LAST.set(decay_width)
-
-
-def _operands(kernels_step: int) -> str:
-    """The gauges' label: how ``q``, ``k``, ``v`` and ``gamma`` cross HBM."""
-    return "tokens_major" if kernels_step else "plain"
-
-
-def _record_pair_path(chunks_a_step: int, sub: int) -> None:
-    """As the program is lowered (at trace time where the shapes alone
-    decide): the form of :func:`kimi_delta_rule`'s pair terms it holds,
-    the kernels' chunks a grid step or 0 for the plain form."""
-    from .. import metrics
-
-    metrics.LINATTN_PAIR_KERNEL_LAST.set(
-        chunks_a_step, sub=str(sub), operands=_operands(chunks_a_step))
-
-
-def _pair_form(kernel: bool, step: int, sub: int, **_) -> None:
-    """:func:`_where_lowered`'s ``record`` for the pair terms (down here,
-    and called late: no line above the rule's moves, so the positions in
-    the kernels' bodies stay, ``tools/lowered_sha.py``)."""
-    _record_pair_path(step if kernel else 0, sub)
-
-
-def _record_scan_path(heads_a_step: int, chunk: int) -> None:
-    """As the program is lowered (at trace time where the shapes alone
-    decide): the form of :func:`kimi_delta_rule`'s solve and chunk loop it
-    holds, the kernels' heads a grid step or 0 for the plain form."""
-    from .. import metrics
-
-    metrics.LINATTN_SCAN_KERNEL_LAST.set(
-        heads_a_step, chunk=str(chunk), operands=_operands(heads_a_step))
-
-
-def _scan_form(kernel: bool, step: int, chunk: int, **_) -> None:
-    """:func:`_where_lowered`'s ``record`` for the chunk loop."""
-    _record_scan_path(step * kernel, chunk)

@@ -224,12 +224,12 @@ def int8_two_level_allreduce_flat(flat, axis_name: str, islands,
     island layout the plan carries (equal sizes, ≥2 islands). Returns
     f32 with ``flat``'s shape; callers cast."""
     from ..profiler import annotate_collective
-    from .comms_planner import _two_level_groups
+    from .comms_planner import two_level_groups
 
     # One grouping convention for the int8 and f32 wires: the planner's
     # helper owns the (local, cross) construction, so the two schedules
     # can never silently diverge on the position mapping.
-    groups, cross = _two_level_groups(islands)
+    groups, cross = two_level_groups(islands)
     L = len(groups[0])
     G = len(groups)
     m = int(flat.size)
@@ -332,10 +332,10 @@ def int8_fused_reducescatter(
     allgather in EQuARX form). Each rank keeps only its owned per-leaf
     slices as f32 1-D shards (callers cast). Non-float leaves ride an
     uncompressed allreduce and are sliced locally."""
-    from .collective_ops import _allreduce_traced
+    from .collective_ops import allreduce_traced
     from .fusion import (
-        _pack_shard_rows,
-        _split_shard_row,
+        pack_shard_rows,
+        split_shard_row,
         bucket_leaves,
         shard_ownership,
     )
@@ -349,7 +349,7 @@ def int8_fused_reducescatter(
                  if jnp.issubdtype(t.dtype, jnp.floating)]
     for i, t in enumerate(tensors):
         if i not in float_idx:
-            full = _allreduce_traced(
+            full = allreduce_traced(
                 t, op, axis_name, prescale_factor, postscale_factor)
             s = sizes[i]
             padded = jnp.pad(full.ravel(), (0, n * s - int(full.size)))
@@ -362,7 +362,7 @@ def int8_fused_reducescatter(
             reversed(list(enumerate(buckets))) if issue_reversed
             else enumerate(buckets)):
         bucket_sizes = [float_sizes[j] for j in bucket]
-        rows = _pack_shard_rows(
+        rows = pack_shard_rows(
             [floats[j] for j in bucket], bucket_sizes, n)
         if prescale_factor != 1.0:
             rows = rows * prescale_factor
@@ -374,7 +374,7 @@ def int8_fused_reducescatter(
             row = _reduce_scattered_rows(rows, axis_name, n, op, salt)[:R]
         if postscale_factor != 1.0:
             row = row * postscale_factor
-        for j, shard in zip(bucket, _split_shard_row(row, bucket_sizes)):
+        for j, shard in zip(bucket, split_shard_row(row, bucket_sizes)):
             out[float_idx[j]] = shard
     return out
 
@@ -458,7 +458,7 @@ def int8_fused_allreduce(
     step counter into the stochastic rounding; ``issue_reversed`` emits
     buckets last-first (the overlap scheduler's issue order — gradients
     materialize in reverse layer order during backward)."""
-    from .collective_ops import _allreduce_traced
+    from .collective_ops import allreduce_traced
     from .fusion import bucket_leaves
     from ..profiler import annotate_collective
 
@@ -468,7 +468,7 @@ def int8_fused_allreduce(
                  if jnp.issubdtype(t.dtype, jnp.floating)]
     for i, t in enumerate(tensors):
         if i not in float_idx:
-            out[i] = _allreduce_traced(
+            out[i] = allreduce_traced(
                 t, op, axis_name, prescale_factor, postscale_factor)
     # Bucket the POST-CAST f32 view: the exchange is f32-sized whatever
     # the leaf dtype was, and bucketing pre-cast would split buckets at
@@ -488,13 +488,13 @@ def int8_fused_allreduce(
         # bucket bytes offered to the planner are the WIRE bytes
         # (~2/element: int8 out + int8 back), matching what the fitted
         # per-algorithm model observes for this exchange.
-        from .fusion import _bucket_suffix, _plan_bucket
+        from .fusion import bucket_suffix, bucket_plan
 
-        plan = _plan_bucket(
+        plan = bucket_plan(
             "allreduce", 2 * int(packed.size), axis_name, world_size,
             candidates=("flat", "two_level"))
         with annotate_collective(
-                f"int8_allreduce.bucket{bi}{_bucket_suffix(plan)}"):
+                f"int8_allreduce.bucket{bi}{bucket_suffix(plan)}"):
             if plan is not None and plan.algorithm == "two_level":
                 reduced = int8_two_level_allreduce_flat(
                     packed, axis_name, plan.islands, op=op,

@@ -28,7 +28,7 @@ interleaved pairs, ``sin`` carrying the sign): a lane rotate each way and a
 select on the lane's parity, exact. The ``n`` lanes that are kept and the
 values pass through as they are.
 
-Each pass is a primitive of its own (``linear_attention._where_lowered``):
+Each pass is a primitive of its own (``kernel_parts.where_lowered``):
 the kernels in a program lowered for a TPU (anywhere, interpreted, where
 the tests say ``interpret``), the plain form on any other platform; a
 recomputed layer's policy sees no ``pallas_call`` whose results it would
@@ -50,7 +50,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ..attribution import SCOPE_MLA_ROPE
 from ..profiler import annotate_collective
 from .attention import LANES
-from .linear_attention import _where_lowered
+from .kernel_parts import where_lowered
 
 KERNEL_NAME = "mla_rope_heads"
 TOKENS_A_STEP = 512
@@ -322,31 +322,27 @@ def _tokens_major_like(x, lanes):
     return x.update(shape=(x.shape[0], x.shape[2], x.shape[1] * lanes))
 
 
-def _nothing_recorded(kernel, **how):
-    """``models/latent.py`` says at trace time which path a layer took."""
-
-
-_queries_p = _where_lowered(
+_queries_p = where_lowered(
     "hvd_mla_rope_queries",
     lambda x, cos, sin, *, heads, **_: [
         _head_major_like(x, x.shape[-1] // heads, heads)],
-    _queries_by_kernel, _queries_plain, _nothing_recorded)
-_queries_back_p = _where_lowered(
+    _queries_by_kernel, _queries_plain)
+_queries_back_p = where_lowered(
     "hvd_mla_rope_queries_backward",
     lambda dy, cos, sin, **_: [_tokens_major_like(dy, dy.shape[-1])],
-    _queries_back_by_kernel, _queries_back_plain, _nothing_recorded)
-_keys_p = _where_lowered(
+    _queries_back_by_kernel, _queries_back_plain)
+_keys_p = where_lowered(
     "hvd_mla_rope_keys",
     lambda up, shared, cos, sin, *, heads, nope, **_: [
         _head_major_like(up, nope + shared.shape[-1], heads),
         _head_major_like(up, up.shape[-1] // heads - nope, heads)],
-    _keys_by_kernel, _keys_plain, _nothing_recorded)
-_keys_back_p = _where_lowered(
+    _keys_by_kernel, _keys_plain)
+_keys_back_p = where_lowered(
     "hvd_mla_rope_keys_backward",
     lambda dk, dv, cos, sin, *, nope, **_: [
         _tokens_major_like(dk, nope + dv.shape[-1]),
         dk.update(shape=(dk.shape[0], dk.shape[2], dk.shape[-1] - nope))],
-    _keys_back_by_kernel, _keys_back_plain, _nothing_recorded)
+    _keys_back_by_kernel, _keys_back_plain)
 
 
 def _bound(primitive, *operands, **how):

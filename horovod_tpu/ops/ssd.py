@@ -45,7 +45,7 @@ can observe, the shapes and the platform the program is lowered for:
 * :func:`ssd_scan_kernel`, two Pallas kernels named ``ssd_chunk_scan``
   joined by a ``jax.custom_vjp``, in a program lowered for a TPU (each
   pass a primitive whose lowering the platform chooses:
-  ``linear_attention._where_lowered``). The grid is ``(B, chunks, H / 8)``:
+  ``kernel_parts.where_lowered``). The grid is ``(B, chunks, H / 8)``:
   a sequence's chunks in order (the backward kernel's index maps turn
   them), the heads in steps of eight that share a group innermost. **In
   VMEM**: a step's ``x [Q, 8 P]`` read where it lies in ``[B, S, H P]``,
@@ -72,11 +72,9 @@ can observe, the shapes and the platform the program is lowered for:
   plain form; the sums' order differs.
 
 The scope ``hvd.ssm.scan`` is around all of either form, forward and
-backward. The gauge ``hvd_ssm_chunks_last{chunk,heads}`` says at trace
-time how many chunks a sequence the step that runs scans, and
-``hvd_ssm_scan_kernel_last{chunk}``, as the program is lowered, the heads
-a grid step of the kernels takes, or 0 where the program holds the plain
-form.
+backward. The program says which form it holds: the primitive
+``hvd_ssd_chunk_scan`` in its jaxpr where the shapes fill the tiles, the
+kernels' name in the text lowered for a TPU.
 """
 
 from __future__ import annotations
@@ -92,7 +90,8 @@ from jax.interpreters import partial_eval as pe
 
 from ..attribution import SCOPE_SSM_SCAN
 from ..profiler import annotate_collective
-from .linear_attention import _MASKED, _NN, _NT, _TN, _where_lowered
+from .kernel_parts import (MASKED, NT, TN, chunk_grid_call, dot, running_sum,
+                           where_lowered)
 
 
 def ssd_scan(x, dt, a, b, c, d=None, chunk: int = 256):
@@ -113,11 +112,9 @@ def ssd_scan(x, dt, a, b, c, d=None, chunk: int = 256):
         raise ValueError(
             f"ssd_scan: {heads} heads do not share {groups} groups of B and "
             f"C evenly")
-    _record_chunks(seq // chunk, chunk, heads)
     if _heads_a_step(x, b, chunk):
         skip = jnp.zeros((heads,), jnp.float32) if d is None else d
         return ssd_scan_kernel(x, dt, a, b, c, skip, chunk)
-    _record_kernel(0, chunk)
     return _chunk_form(x, dt, a, b, c, d, chunk)
 
 
@@ -247,17 +244,12 @@ def _decay(alpha, cols, r: int, lower):
     """Head ``r``'s ``L [Q, Q]``, masked before the exponential."""
     heads = alpha.shape[0]
     return jnp.exp(jnp.where(
-        lower, cols[:, heads + r:heads + r + 1] - alpha[r:r + 1, :], _MASKED))
+        lower, cols[:, heads + r:heads + r + 1] - alpha[r:r + 1, :], MASKED))
 
 
 def _lower_triangle(size: int):
     return (lax.broadcasted_iota(jnp.int32, (size, size), 0)
             >= lax.broadcasted_iota(jnp.int32, (size, size), 1))
-
-
-def _dot(left, right, dims=_NN):
-    return lax.dot_general(left, right, dims,
-                           preferred_element_type=jnp.float32)
 
 
 def _head_lanes(tile, k: int, per: int, width: int, other):
@@ -293,7 +285,7 @@ def _scan_forward_kernel(x_ref, steps_ref, alpha_ref, b_ref, c_ref, skip_ref,
 
     @pl.when(h % per_group == 0)
     def _():
-        cb_ref[...] = _dot(c_ref[...], b_ref[...], _NT)
+        cb_ref[...] = dot(c_ref[...], b_ref[...], NT)
 
     alpha, cols = _factors(steps_ref, alpha_ref)
     lower = _lower_triangle(size)
@@ -308,14 +300,14 @@ def _scan_forward_kernel(x_ref, steps_ref, alpha_ref, b_ref, c_ref, skip_ref,
         for k in range(per):
             inside = (cb_ref[...] * _decay(alpha, cols, first + k, lower)
                       ).astype(dtype)
-            within = _head_lanes(_dot(inside, operand), k, per, width, within)
+            within = _head_lanes(dot(inside, operand), k, per, width, within)
         y_ref[:, lanes] = (
-            within + grow * _dot(c_ref[...], entering.astype(dtype))
+            within + grow * dot(c_ref[...], entering.astype(dtype))
             + skip_ref[:, lanes] * x).astype(y_ref.dtype)
         for ref in enter_ref:
             ref[:, lanes] = entering.astype(ref.dtype)
-        state_ref[h, :, lanes] = (grow[size - 1:size] * entering + _dot(
-            b_ref[...], (stepped * rest).astype(dtype), _TN)).astype(
+        state_ref[h, :, lanes] = (grow[size - 1:size] * entering + dot(
+            b_ref[...], (stepped * rest).astype(dtype), TN)).astype(
                 state_ref.dtype)
 
 
@@ -348,7 +340,7 @@ def _scan_backward_kernel(x_ref, steps_ref, alpha_ref, b_ref, c_ref, skip_ref,
 
     @pl.when(h % per_group == 0)
     def _():
-        cb_ref[...] = _dot(c_ref[...], b_ref[...], _NT)
+        cb_ref[...] = dot(c_ref[...], b_ref[...], NT)
         cb_bar_ref[...] = jnp.zeros_like(cb_bar_ref)
         b_sum_ref[...] = jnp.zeros_like(b_sum_ref)
         c_sum_ref[...] = jnp.zeros_like(c_sum_ref)
@@ -369,15 +361,15 @@ def _scan_backward_kernel(x_ref, steps_ref, alpha_ref, b_ref, c_ref, skip_ref,
                               for t in (entering, leaving, ahead))
         kept = grow[size - 1:size]
         # y = within + grow * (C h0^T) + D x: the entering state's term
-        read = grow * _dot(c_ref[...], entered)
+        read = grow * dot(c_ref[...], entered)
         read_bar = (grow * y_bar).astype(dtype)
-        c_sum = c_sum + _dot(read_bar, entered, _NT)
+        c_sum = c_sum + dot(read_bar, entered, NT)
         ahead_ref[h, :, lanes] = (
-            kept * ahead + _dot(c_ref[...], read_bar, _TN)).astype(
+            kept * ahead + dot(c_ref[...], read_bar, TN)).astype(
                 ahead_ref.dtype)
         # h1 = kept * h0 + B^T leaving
-        leaving_bar = _dot(b_ref[...], led)
-        b_sum = b_sum + _dot(left, led, _NT)
+        leaving_bar = dot(b_ref[...], led)
+        b_sum = b_sum + dot(left, led, NT)
         # within = (C B^T * L) stepped, a head at a time. alpha_i adds and
         # alpha_j takes away the SAME pair term G_ij: both sums are read
         # off one float32 matrix (G^T - G down the rows), because alpha's
@@ -388,13 +380,13 @@ def _scan_backward_kernel(x_ref, steps_ref, alpha_ref, b_ref, c_ref, skip_ref,
         for k in range(per):
             decay = _decay(alpha, cols, first + k, lower)
             inside = (cb_ref[...] * decay).astype(dtype)
-            inside_bar = _dot(
+            inside_bar = dot(
                 _head_lanes(bar, k, per, width, jnp.zeros_like(bar)),
-                operand, _NT) * decay
+                operand, NT) * decay
             cb_bar = cb_bar + inside_bar
             pair = inside_bar * cb_ref[...]
             pairs.append(jnp.sum(pair.T - pair, 0, keepdims=True))
-            within_bar = _head_lanes(_dot(inside, bar, _TN), k, per, width,
+            within_bar = _head_lanes(dot(inside, bar, TN), k, per, width,
                                      within_bar)
         stepped_bar = rest * leaving_bar + within_bar
         x_bar_ref[:, lanes] = (
@@ -419,74 +411,58 @@ def _scan_backward_kernel(x_ref, steps_ref, alpha_ref, b_ref, c_ref, skip_ref,
     @pl.when(h % per_group == per_group - 1)
     def _():
         summed = cb_bar.astype(dtype)
-        b_bar_ref[...] = (b_sum + _dot(summed, c_ref[...], _TN)).astype(
+        b_bar_ref[...] = (b_sum + dot(summed, c_ref[...], TN)).astype(
             b_bar_ref.dtype)
-        c_bar_ref[...] = (c_sum + _dot(summed, b_ref[...])).astype(
+        c_bar_ref[...] = (c_sum + dot(summed, b_ref[...])).astype(
             c_bar_ref.dtype)
 
 
 def _running_sum(dt, a, chunk: int):
     """The steps heads-major and ``alpha``, their running sum times the
     rate inside each chunk, both ``[B, H, S]`` float32: tokens along the
-    lanes, as the kernels' blocks take them. The sum is a float32 product
-    with the chunk's lower triangle of ones at ``Precision.HIGHEST``
-    (float32's sum in another order), batch and head its batch dimensions
-    (the triangle broadcast, never written out: a recomputed layer's
-    policy keeps a product without one); as ``jnp.cumsum`` it is a
-    ``reduce-window``, 0.8 ms a call at 8,192 tokens on a v5e."""
+    lanes, as the kernels' blocks take them. The sum is
+    ``kernel_parts.running_sum``'s product, batch and head its batch
+    dimensions: the sums come out heads-major as the steps lie."""
     f32 = jnp.float32
     batch, seq, heads = dt.shape
     steps = jnp.moveaxis(dt.astype(f32), 1, 2)
-    ones = jnp.broadcast_to(jnp.tril(jnp.ones((chunk, chunk), f32)),
-                            (batch, heads, chunk, chunk))
-    alpha = jnp.einsum(
-        "bhij,bhnj->bhni", ones,
-        (steps * a.astype(f32)[:, None]).reshape(batch, heads, -1, chunk),
-        precision=lax.Precision.HIGHEST, preferred_element_type=f32)
+    alpha = running_sum("bhij,bhnj->bhni", (batch, heads), chunk)(
+        (steps * a.astype(f32)[:, None]).reshape(batch, heads, -1, chunk))
     return steps, alpha.reshape(batch, heads, seq)
 
 
 def _scan_call(kernel, operands, results, scratch, *, shape, turned, step,
                chunk, interpret):
     """``kernel`` over the grid ``(B, chunks, H / step)``, the heads
-    innermost, for ``shape = (B, S, H, P, G, N)``. ``operands`` and
-    ``results`` are ``(kind, array or dtype)``, a block of each kind one
-    chunk of one step's heads: ``tokens [B, S, H * P]`` (``x``, ``y`` and
-    their cotangents, read where they lie: PR 40's ``_block_at``), ``rows
+    innermost (``kernel_parts.chunk_grid_call``), for ``shape = (B, S, H,
+    P, G, N)``. ``operands`` and ``results`` are ``(kind, array or
+    dtype)``, a block of each kind one chunk of one step's heads: ``tokens
+    [B, S, H * P]`` (``x``, ``y`` and their cotangents, read where they
+    lie: PR 40's ``_block_at``), ``rows
     [B, H, S]`` (float32, tokens along the lanes), ``group [B, S, G * N]``
     (the block stays while the steps stay in the group), ``skip [1, H *
     P]`` and ``states [B, chunks, N, H * P]``. ``turned``: the chunks last
     to first."""
     batch, seq, heads, width, groups, state = shape
     count, lanes, per_group = seq // chunk, step * width, heads // groups // step
-
-    def at(n):
-        return count - 1 - n if turned else n
-
     kinds = {
-        "tokens": ((batch, seq, heads * width), pl.BlockSpec(
-            (None, chunk, lanes), lambda i, n, h: (i, at(n), h))),
-        "rows": ((batch, heads, seq), pl.BlockSpec(
-            (None, step, chunk), lambda i, n, h: (i, h, at(n)))),
-        "group": ((batch, seq, groups * state), pl.BlockSpec(
-            (None, chunk, state), lambda i, n, h: (i, at(n), h // per_group))),
-        "skip": ((1, heads * width), pl.BlockSpec(
-            (1, lanes), lambda i, n, h: (0, h))),
-        "states": ((batch, count, state, heads * width), pl.BlockSpec(
-            (None, None, state, lanes), lambda i, n, h: (i, at(n), 0, h))),
+        "tokens": ((batch, seq, heads * width), (None, chunk, lanes),
+                   lambda i, n, h: (i, n, h)),
+        "rows": ((batch, heads, seq), (None, step, chunk),
+                 lambda i, n, h: (i, h, n)),
+        "group": ((batch, seq, groups * state), (None, chunk, state),
+                  lambda i, n, h: (i, n, h // per_group)),
+        "skip": ((1, heads * width), (1, lanes), lambda i, n, h: (0, h)),
+        "states": ((batch, count, state, heads * width),
+                   (None, None, state, lanes), lambda i, n, h: (i, n, 0, h)),
     }
-    return pl.pallas_call(
-        functools.partial(kernel, width=width, per_group=per_group),
-        grid=(batch, count, heads // step),
-        in_specs=[kinds[kind][1] for kind, _ in operands],
-        out_specs=[kinds[kind][1] for kind, _ in results],
-        out_shape=[jax.ShapeDtypeStruct(kinds[kind][0], dtype)
-                   for kind, dtype in results],
-        scratch_shapes=[pltpu.VMEM((heads // step, state, lanes), jnp.float32),
-                        pltpu.VMEM((chunk, chunk), jnp.float32)] + scratch,
-        interpret=interpret,
-        name=SCAN_KERNEL_NAME,
-    )(*(x.reshape(kinds[kind][0]) for kind, x in operands))
+    return chunk_grid_call(
+        functools.partial(kernel, width=width, per_group=per_group), kinds,
+        operands, results,
+        [pltpu.VMEM((heads // step, state, lanes), jnp.float32),
+         pltpu.VMEM((chunk, chunk), jnp.float32)] + scratch,
+        grid=(batch, count, heads // step), turned=turned,
+        interpret=interpret, name=SCAN_KERNEL_NAME)
 
 
 def _kernel_operands(x, steps, alpha, b, c, d):
@@ -550,17 +526,13 @@ def _forward_results(x, dt, a, b, c, d, *, states, chunk, **_):
                            dtype=jnp.float32)] * states
 
 
-def _lowered_as(kernel: bool, step: int, chunk: int, **_) -> None:
-    _record_kernel(step * kernel, chunk)
-
-
-_scan_forward_p = _where_lowered(
+_scan_forward_p = where_lowered(
     "hvd_ssd_chunk_scan", _forward_results, _forward_by_kernel,
-    _forward_plain, _lowered_as)
-_scan_backward_p = _where_lowered(
+    _forward_plain)
+_scan_backward_p = where_lowered(
     "hvd_ssd_chunk_scan_backward",
     lambda x, dt, a, b, c, d, entering, y_bar, **_: [x, dt, a, b, c, d],
-    _backward_by_kernel, _backward_plain, _lowered_as)
+    _backward_by_kernel, _backward_plain)
 
 
 def _unread_states(used, eqn):
@@ -588,7 +560,7 @@ def ssd_scan_kernel(x, dt, a, b, c, d, chunk, interpret=False):
     float32 states that enter each chunk (``[B, chunks, N, H * P]``): the
     backward kernel's residuals with the operands. Same rounding points
     as the plain form. Each pass is a primitive of its own
-    (``linear_attention._where_lowered``), so a recomputed layer's policy
+    (``kernel_parts.where_lowered``), so a recomputed layer's policy
     sees no ``pallas_call`` whose results it would keep: ``y`` and the
     states are formed again in the backward pass."""
     return _scan_forward_p.bind(
@@ -612,20 +584,3 @@ def _scan_backward(chunk, interpret, kept, y_bar):
 
 
 ssd_scan_kernel.defvjp(_scan_forward, _scan_backward)
-
-
-def _record_chunks(count: int, chunk: int, heads: int) -> None:
-    """At trace time, as ``ops.linear_attention._record_chunks``: the step
-    that runs scans this many chunks a sequence."""
-    from .. import metrics
-
-    metrics.SSM_CHUNKS_LAST.set(count, chunk=str(chunk), heads=str(heads))
-
-
-def _record_kernel(heads_a_step: int, chunk: int) -> None:
-    """As the program is lowered (at trace time where the shapes alone
-    decide): the form of the scan it holds, the heads a grid step of the
-    kernels takes or 0 for the plain form."""
-    from .. import metrics
-
-    metrics.SSM_SCAN_KERNEL_LAST.set(heads_a_step, chunk=str(chunk))

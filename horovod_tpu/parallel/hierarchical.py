@@ -109,9 +109,9 @@ def hierarchical_allreduce(
     )
 
     if op in (Min, Max, Product):
-        from ..ops.collective_ops import _allreduce_traced
+        from ..ops.collective_ops import allreduce_traced
 
-        return _allreduce_traced(
+        return allreduce_traced(
             x, op, (cross_axis, local_axis), prescale_factor, postscale_factor
         )
     if op == Adasum:

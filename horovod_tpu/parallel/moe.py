@@ -363,7 +363,7 @@ def _moe_exchange(axis, groups, plan):
     SAME schedule. Planner plan with a non-flat algorithm → the staged
     two_level form; otherwise the flat tiled alltoall scoped to the
     dispatch groups — which is also the planner-off emission, the
-    bit-for-bit inertness contract (``_plan_bucket`` returns None for
+    bit-for-bit inertness contract (``bucket_plan`` returns None for
     flat plans, so a flat *choice* never reaches here either)."""
     idx_groups = [list(g) for g in groups]
 
@@ -427,7 +427,7 @@ def expert_parallel_moe_layer(tokens, gates_w, w1, w2, axis, capacity,
     ``i+1``'s dispatch alltoall is emitted before segment ``i``'s expert
     FFN, so XLA overlaps wire and compute. ``compression="int8"`` rides
     the EQuARX exchange; a planner ``plan`` (from
-    ``fusion._plan_bucket("alltoall", ...)``) stages the wire two_level.
+    ``fusion.bucket_plan("alltoall", ...)``) stages the wire two_level.
     Returns ``(out [T, D], dropped [1] int32, load [1, E] int32)``.
     """
     from ..ops import fusion
@@ -556,7 +556,7 @@ def make_expert_parallel_moe_step(axis_name: str = "hvd",
 
     def _plan_for(d):
         wire = _wire_bytes(e, capacity, d, comp)
-        plan = fusion._plan_bucket("alltoall", wire, axis_name, e,
+        plan = fusion.bucket_plan("alltoall", wire, axis_name, e,
                                    candidates=("flat", "two_level"))
         meta.update(
             plan=plan, nbytes=int(wire),

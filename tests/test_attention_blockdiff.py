@@ -14,11 +14,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu import attribution, metrics
+from horovod_tpu import attribution
 from horovod_tpu.ops import attention
 from horovod_tpu.ops.attention import (LSE_MASKED, block_diffusion_attention,
                                        block_diffusion_streams,
                                        flash_attention, flash_attention_lse)
+from traced import pallas_grids
 
 
 def operands(batch, heads, kv_heads, seq, dim, seed=0, dtype=jnp.float32):
@@ -191,21 +192,26 @@ class TestTilesAndClamps:
         assert attention._tile_plan(True, 32, 32, 512, 512, 0, 0) == (
             528, 32, 32)
 
-    def test_the_gauges_count_both_calls(self):
+    def test_both_calls_walk_the_causal_plans_grid(self):
         q, k, v, _ = operands(1, 4, 1, 2 * 8192, 128, dtype=jnp.bfloat16)
-        jax.eval_shape(partial(block_diffusion_attention, block_length=4),
-                       q, k, v)
-        tiles = {kind: int(metrics.ATTN_TILES_LAST.labels(kind=kind).get())
-                 for kind in ("computed", "skipped", "grid",
-                              "blockdiff_computed", "blockdiff_skipped",
-                              "blockdiff_grid")}
-        assert tiles == dict(
-            computed=136, skipped=120, grid=256, blockdiff_computed=272,
-            blockdiff_skipped=1024 - 272, blockdiff_grid=512)
+        plans = [attention._tile_plan(
+            True, 16, 16, 512, 512, 0, 0, None,
+            attention._behind((4, before))) for before in (False, True)]
+        computed = sum(pairs for pairs, _, _ in plans)
+        steps = sum(16 * band_kb for _, band_kb, _ in plans)
+        assert (plans[0][0], 16 * 16 - plans[0][0]) == (136, 120)
         # no tile outside the two triangles: twice the causal plan of S x S
-        assert tiles["blockdiff_computed"] == 2 * (16 * 17 // 2)
-        assert tiles["blockdiff_grid"] < 32 * 32  # not the 2S x 2S grid
-        assert int(metrics.ATTN_KV_GROUP_LAST.labels().get()) == 4
+        assert computed == 272 == 2 * (16 * 17 // 2)
+        assert steps == 512 < 32 * 32  # not the 2S x 2S grid
+        # traced: two calls, each four query heads (on the one key/value
+        # head) over the S x S plan's grid
+        assert pallas_grids(
+            partial(block_diffusion_attention, block_length=4), q, k, v) == [
+            (4, 16, plans[0][1]), (4, 16, plans[1][1])]
+        shaped = jax.ShapeDtypeStruct
+        assert attention._tiled_shapes(
+            shaped((4, 8192, 128), q.dtype), shaped((1, 8192, 128), k.dtype),
+            None)[-1] == 4
 
 
 def stream_mask(seq, length):
@@ -341,19 +347,22 @@ class TestBlockDiffusionAttention:
             block_diffusion_streams((q[:, :, :32], k, v), (q, k, v), 4,
                                     interpret=True)
 
-    def test_the_gauge_counts_both_plans_where_they_differ(self):
+    def test_the_two_calls_plans_differ_where_a_block_is_a_tile(self):
         """Blocks of a tile's length: the noisy stream's call leaves the
         diagonal tiles out (its queries see only the blocks before their
-        own), the clean stream's keeps them, and the gauge is the sum of
-        the two plans, not twice the last one."""
+        own), the clean stream's keeps them: two plans, each its own, on
+        one grid."""
         q, k, v, _ = operands(1, 4, 2, 128, 16)
         block_diffusion_attention(q, k, v, 16, block_q=16, block_k=16,
                                   interpret=True)
-        tiles = {kind: int(metrics.ATTN_TILES_LAST.labels(kind=kind).get())
-                 for kind in ("blockdiff_computed", "blockdiff_skipped",
-                              "blockdiff_grid")}
-        assert tiles == dict(blockdiff_computed=10 + 6,
-                             blockdiff_skipped=64 - 16, blockdiff_grid=32)
+        plans = [attention._tile_plan(
+            True, 4, 4, 16, 16, 0, 0, None, attention._behind((16, before)))
+            for before in (False, True)]
+        assert [pairs for pairs, _, _ in plans] == [10, 6]
+        assert 4 * 4 * 4 - (10 + 6) == 64 - 16
+        assert pallas_grids(
+            partial(block_diffusion_attention, block_length=16, block_q=16,
+                    block_k=16, interpret=True), q, k, v) == [(4, 4, 4)] * 2
 
 
 class TestGuards:

@@ -17,11 +17,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu import metrics
 from horovod_tpu.models import bert
 from horovod_tpu.ops import attention as att
 from horovod_tpu.ops.attention import (flash_attention,
                                        flash_attention_tokens_major)
+from traced import pallas_calls, pallas_grids
 
 
 def operands(batch, seq, heads, kv_heads, dim, dtype, seed=0):
@@ -43,23 +43,31 @@ def tokens_major(x):
     return x.transpose(0, 2, 1, 3).reshape(x.shape[0], x.shape[2], -1)
 
 
+def single_tile_plan(fn, args, batch, heads):
+    """``(group fwd, group bwd, heads a block fwd, bwd)`` of the single-tile
+    kernels that ``fn(*args)`` traces, read off their grids (``(B // G, H //
+    heads a block)`` of a tokens-major call, ``(B H // G,)`` of a head-major
+    one, whose block holds one head); ``()`` where it traces none."""
+    grids = [grid for grid in pallas_grids(fn, *args) if len(grid) < 3]
+    return (tuple((batch if len(grid) == 2 else batch * heads) // grid[0]
+                  for grid in grids)
+            + tuple(heads // grid[1] if len(grid) == 2 else 1
+                    for grid in grids))
+
+
 def both(batch, seq, heads, kv_heads, dim, dtype, **call):
     """``(out, dq, dk, dv)`` of the tokens-major entry and of
-    ``flash_attention`` on the transposed operands, and the gauges the
-    first left: ``(group fwd, group bwd, heads a block fwd, bwd)``."""
+    ``flash_attention`` on the transposed operands, and the first's
+    :func:`single_tile_plan`."""
     q, k, v, weight = operands(batch, seq, heads, kv_heads, dim, dtype)
-    for gauge in (metrics.ATTN_GROUP_LAST, metrics.ATTN_HEADS_PER_BLOCK_LAST):
-        for kernel in ("fwd", "bwd"):
-            gauge.set(0, kernel=kernel)
-    out, vjp = jax.vjp(functools.partial(
+    entry = functools.partial(
         flash_attention_tokens_major, num_heads=heads, interpret=True,
-        **call), q, k, v)
+        **call)
+    out, vjp = jax.vjp(entry, q, k, v)
     here = (out,) + vjp(weight)
-    gauges = tuple(
-        int(gauge.labels(kernel=kernel).get())
-        for gauge in (metrics.ATTN_GROUP_LAST,
-                      metrics.ATTN_HEADS_PER_BLOCK_LAST)
-        for kernel in ("fwd", "bwd"))
+    gauges = single_tile_plan(
+        lambda q, k, v: jax.vjp(entry, q, k, v)[1](weight), (q, k, v),
+        batch, heads)
     out, vjp = jax.vjp(
         lambda q, k, v: tokens_major(flash_attention(
             head_major(q, dim), head_major(k, dim), head_major(v, dim),
@@ -104,12 +112,11 @@ class TestTheSingleTileKernelsOnTokensMajorOperands:
         *_, gauges = both(3, 128, 4, 4, 64, jnp.bfloat16)
         assert gauges == (3, 3, 2, 2)
 
-    def test_a_head_major_call_sets_the_gauge_to_one(self):
+    def test_a_head_major_calls_block_holds_one_head(self):
         q = jnp.zeros((1, 2, 128, 64), jnp.bfloat16)
-        jax.grad(lambda q: flash_attention(
-            q, q, q, interpret=True).astype(jnp.float32).sum())(q)
-        assert [int(metrics.ATTN_HEADS_PER_BLOCK_LAST.labels(
-            kernel=kernel).get()) for kernel in ("fwd", "bwd")] == [1, 1]
+        plan = single_tile_plan(jax.grad(lambda q: flash_attention(
+            q, q, q, interpret=True).astype(jnp.float32).sum()), (q,), 1, 2)
+        assert plan[2:] == (1, 1)
 
     def test_offsets_and_fully_masked_rows(self):
         """Keys from position 64 on, queries from 0: the first 64 queries
@@ -146,7 +153,7 @@ class TestAnyOtherCallTransposes:
                     "heads-that-leave-a-block-short"):
             assert gauges[2:] == (1, 1)  # the head-major single tile
         else:
-            assert gauges == (0, 0, 0, 0)  # no single-tile kernel at all
+            assert gauges == ()  # no single-tile kernel at all
 
     def test_a_width_the_heads_do_not_divide_is_refused(self):
         q = jnp.zeros((1, 128, 100), jnp.float32)
@@ -177,24 +184,22 @@ TILED = {
     "offsets": ((1, 64, 2, 1, 16),
                 dict(causal=True, q_offset=32, k_offset=16)),
 }
-LAYOUT_KERNELS = ("fwd", "dq", "dkv")
 
 
-def layout_gauge():
-    return [int(metrics.ATTN_OPERAND_LAYOUT_LAST.labels(kernel=kernel).get())
-            for kernel in LAYOUT_KERNELS]
-
-
-def mark_layout_gauge(value):
-    for kernel in LAYOUT_KERNELS:
-        metrics.ATTN_OPERAND_LAYOUT_LAST.set(value, kernel=kernel)
+def layouts(fn, *args, dim):
+    """How each multi-tile kernel (forward, dq, dk/dv: grids of three axes
+    and four) that ``fn(*args)`` traces is fed its queries: 1 tokens-major
+    (``[B, S, H * D]``, the lanes of several heads), 0 head-major (``[BH,
+    S, D]``). ``[]`` where it traces none."""
+    return [int(shapes[0][-1] != dim)
+            for grid, shapes in pallas_calls(fn, *args) if len(grid) >= 3]
 
 
 def tiled_both(name, dtype, with_lse):
     """``(out, lse, dq, dk, dv)`` of the tokens-major entry and of the
     head-major one on the transposed operands (``lse`` left out without
     ``with_lse``), a cotangent on the log-sum-exp too where there is one,
-    and what each left in ``hvd_attn_operand_layout_last``."""
+    and each one's :func:`layouts`."""
     (batch, seq, heads, kv_heads, tile), call = TILED[name]
     block_q, block_k = tile if isinstance(tile, tuple) else (tile, tile)
     call = dict(call, block_q=block_q, block_k=block_k, interpret=True)
@@ -219,11 +224,12 @@ def tiled_both(name, dtype, with_lse):
 
     found = []
     for fn in (here, there):
-        mark_layout_gauge(-1)
         outs, vjp = jax.vjp(fn, q, k, v)
         cotangents = (weight.astype(outs[0].dtype),) + (
             (lse_weight,) if with_lse else ())
-        found.append((tuple(outs) + vjp(cotangents), layout_gauge()))
+        found.append((tuple(outs) + vjp(cotangents), layouts(
+            lambda q, k, v: jax.vjp(fn, q, k, v)[1](cotangents), q, k, v,
+            dim=dim)))
     return found
 
 
@@ -255,24 +261,23 @@ class TestSeveralTilesOfWholeLaneBlocksGoAsTheyLie:
 
     def test_the_kernels_are_the_head_major_calls_but_for_the_index_maps(
             self):
-        """Same grid, same three kernels, same number of tiles computed:
-        what differs between the two lowered programs' kernels is where a
-        block is looked for."""
+        """Same three kernels on the same grids, the one tile plan's: what
+        differs between the two traced programs' kernels is where a block is
+        looked for."""
         q, k, v, _ = operands(1, 64, 4, 2, 128, jnp.float32)
         call = dict(causal=True, window=24, block_q=16, block_k=16,
                     interpret=True)
-
-        def tiles():
-            return {kind: int(metrics.ATTN_TILES_LAST.labels(kind=kind).get())
-                    for kind in ("computed", "skipped", "grid")}
-
-        jax.grad(lambda q, k, v: flash_attention_tokens_major(
-            q, k, v, num_heads=4, **call).sum(), (0, 1, 2))(q, k, v)
-        lying = tiles()
-        jax.grad(lambda q, k, v: flash_attention(
+        lying = pallas_grids(jax.grad(
+            lambda q, k, v: flash_attention_tokens_major(
+                q, k, v, num_heads=4, **call).sum(), (0, 1, 2)), q, k, v)
+        transposed = pallas_grids(jax.grad(lambda q, k, v: flash_attention(
             head_major(q, 128), head_major(k, 128), head_major(v, 128),
-            **call).sum(), (0, 1, 2))(q, k, v)
-        assert lying == tiles() and lying["computed"] > 0
+            **call).sum(), (0, 1, 2)), q, k, v)
+        computed, band_kb, band_qb = att._tile_plan(
+            True, 4, 4, 16, 16, 0, 0, 24)
+        assert computed > 0
+        assert lying == transposed == [
+            (4, 4, band_kb), (4, 4, band_kb), (2, 4, 2, band_qb)]
 
     @pytest.mark.parametrize("name, shape, call, wanted", [
         # two heads a lane block: lane masks are the one-tile kernels' alone
@@ -281,26 +286,25 @@ class TestSeveralTilesOfWholeLaneBlocksGoAsTheyLie:
         ("narrow-grouped-heads-as-granite", (1, 128, 4, 1, 64),
          dict(causal=True, block_q=64, block_k=64), [0, 0, 0]),
         # one tile of whole lane blocks: the single-tile kernels, as since
-        # PR 35, which the gauge does not count
-        ("one-tile", (3, 128, 2, 2, 128), dict(), [-1, -1, -1]),
+        # PR 35: no multi-tile kernel at all
+        ("one-tile", (3, 128, 2, 2, 128), dict(), []),
         ("one-tile-of-several-blocks-a-head", (1, 128, 2, 2, 256), dict(),
-         [-1, -1, -1]),
+         []),
         ("several-blocks-a-head", (1, 64, 2, 1, 256),
          dict(causal=True, block_q=16, block_k=16), [1, 1, 1]),
     ])
     def test_which_way_a_call_goes_is_read_off_its_shapes(self, name, shape,
                                                           call, wanted):
-        """The gauge is set where a call is traced, so each case has shapes
-        no other test of this file traces."""
         batch, seq, heads, kv_heads, dim = shape
         q, k, v, weight = operands(batch, seq, heads, kv_heads, dim,
                                    jnp.float32)
-        mark_layout_gauge(-1)
-        out, vjp = jax.vjp(functools.partial(
+        entry = functools.partial(
             flash_attention_tokens_major, num_heads=heads, interpret=True,
-            **call), q, k, v)
+            **call)
+        out, vjp = jax.vjp(entry, q, k, v)
         here = (out,) + vjp(weight)
-        assert layout_gauge() == wanted
+        assert layouts(lambda q, k, v: jax.vjp(entry, q, k, v)[1](weight),
+                       q, k, v, dim=dim) == wanted
         out, vjp = jax.vjp(
             lambda q, k, v: tokens_major(flash_attention(
                 head_major(q, dim), head_major(k, dim), head_major(v, dim),
@@ -316,7 +320,6 @@ class TestSeveralTilesOfWholeLaneBlocksGoAsTheyLie:
         caller that wants one of a one-tile call gets the head-major
         kernels', as before."""
         q, k, v, _ = operands(1, 128, 2, 2, 128, jnp.float32)
-        mark_layout_gauge(-1)
         out, lse = att.flash_attention_tokens_major_lse(
             q, k, v, num_heads=2, interpret=True)
         want, want_lse = att.flash_attention_lse(
@@ -324,7 +327,12 @@ class TestSeveralTilesOfWholeLaneBlocksGoAsTheyLie:
             interpret=True)
         np.testing.assert_array_equal(out, tokens_major(want))
         np.testing.assert_array_equal(lse, want_lse)
-        assert layout_gauge() == [-1, -1, -1]
+        # the head-major single-tile forward (a grid over B H // G), fed
+        # the transposed ``[2, 128, 128]``
+        assert [shapes[0] for _, shapes in pallas_calls(
+            functools.partial(att.flash_attention_tokens_major_lse,
+                              num_heads=2, interpret=True), q, k, v)] == [
+            (2, 128, 128)]
 
 
 class TestHeadsABlockAndTheGroup:
@@ -467,8 +475,8 @@ class TestBertHandsTheKernelsWhatTheProjectionsWrote:
         loss, gradients = loss_and_gradients(watched, ids)
         # what the projections wrote, and the heads beside it
         assert seen == [((3, 128, 128),) * 3 + ({"num_heads": 2},)] * 2
-        assert [int(metrics.ATTN_HEADS_PER_BLOCK_LAST.labels(
-            kernel=kernel).get()) for kernel in ("fwd", "bwd")] == [2, 2]
+        assert att._heads_per_block(TOY.hidden_size // TOY.num_heads,
+                                    TOY.num_heads) == 2
         wanted_loss, wanted = loss_and_gradients(None, ids)
         np.testing.assert_allclose(loss, wanted_loss, rtol=1e-5)
         for (path, a), b in zip(

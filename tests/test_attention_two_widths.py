@@ -15,9 +15,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu import metrics
 from horovod_tpu.ops import attention as att
 from horovod_tpu.ops.attention import flash_attention, flash_attention_lse
+from traced import pallas_calls
 
 
 def dense(q, k, v, causal=True, window=None):
@@ -91,7 +91,7 @@ def test_the_log_sum_exp_entry_takes_two_widths_too():
     np.testing.assert_allclose(lse, want, rtol=0, atol=1e-5)
 
 
-def test_the_scope_and_the_gauge_tell_the_latent_layers_kernels():
+def test_the_scope_and_the_blocks_tell_the_latent_layers_kernels():
     q, k, v, _ = operands(2, 2, 64, 24, 16)
 
     def loss(q, k, v):
@@ -102,13 +102,17 @@ def test_the_scope_and_the_gauge_tell_the_latent_layers_kernels():
         debug_info=True)
     assert "hvd.attn.mla/hvd.attn.fwd" in text
     assert "hvd.attn.mla/hvd.attn.bwd" in text
-    assert metrics.ATTN_HEAD_WIDTHS_LAST.labels(kind="qk").get() == 24
-    assert metrics.ATTN_HEAD_WIDTHS_LAST.labels(kind="v").get() == 16
-    # equal widths: no such scope, and the gauge says so
+    head_major = jax.ShapeDtypeStruct((4, 64, 24), q.dtype)
+    assert att._value_width(head_major, v.reshape(4, 64, 16), 24) == 16
+    # every kernel reads queries and keys of 24 lanes and values of 16
+    assert {(shapes[0][-1], shapes[1][-1], shapes[2][-1]) for _, shapes in
+            pallas_calls(jax.grad(loss, (0, 1, 2)), q, k, v)} == {
+        (24, 24, 16)}
+    # equal widths: no such scope, and the values' blocks are the keys'
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, k, k).as_text(
         debug_info=True)
     assert "hvd.attn.mla" not in text and "hvd.attn.fwd" in text
-    assert metrics.ATTN_HEAD_WIDTHS_LAST.labels(kind="v").get() == 24
+    assert att._value_width(head_major, head_major, 24) == 24
 
 
 def test_grouped_tokens_major_rows_are_not_taken_for_two_widths():

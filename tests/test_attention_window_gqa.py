@@ -4,7 +4,8 @@ masked softmax forward and in all three gradients, the tile predicate, the
 band grid and the index maps against a brute-force table, what a window
 that hides nothing lowers to, what the calls without a window lowered to
 and the windowed ones returned before their grid ran over the band alone,
-and the gauges. Interpret mode, small shapes."""
+and the tile plan with the grids a traced call walks. Interpret mode, small
+shapes."""
 
 import hashlib
 
@@ -13,9 +14,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu import metrics
 from horovod_tpu.ops import attention as att
 from horovod_tpu.ops.attention import flash_attention, flash_attention_lse
+from traced import pallas_grids
 
 
 def dense(q, k, v, window=None, q_offset=0, k_offset=0):
@@ -110,7 +111,9 @@ class TestAgainstDenseSoftmax:
             assert a.shape == b.shape, name
             np.testing.assert_allclose(a, b, atol=5e-5, rtol=5e-5,
                                        err_msg=f"d{name}")
-        assert metrics.ATTN_KV_GROUP_LAST.labels().get() == 4
+        # four query heads a key/value head: the dk/dv grid's axis of its own
+        assert (8, seq // tile, 4, seq // tile) in pallas_grids(
+            jax.grad(lambda *a: flash(*a).sum(), (0, 1, 2)), q, k, v)
 
     @pytest.mark.parametrize("sq, sk, q_off, k_off, window", [
         pytest.param(64, 128, 64, 0, 40, id="bottom-right"),
@@ -434,14 +437,16 @@ class TestTheBandGridChangesNoBit:
         grid: the steps past the band compute nothing, on any machine."""
         case = RECORDED[name][0]
         band = windowed(case, att._flash)
-        plan = att._tile_plan
-        monkeypatch.setattr(
-            att, "_tile_plan", lambda causal, num_qb, num_kb, *rest: (
-                plan(causal, num_qb, num_kb, *rest)[0], num_kb, num_qb))
+        plan, widened = att._tile_plan, []
+
+        def whole(causal, num_qb, num_kb, *rest):
+            widened.append((num_qb, num_kb))
+            return plan(causal, num_qb, num_kb, *rest)[0], num_kb, num_qb
+
+        monkeypatch.setattr(att, "_tile_plan", whole)
         for a, b in zip(band, windowed(case, att._flash)):
             np.testing.assert_array_equal(a, b)
-        assert int(metrics.ATTN_TILES_LAST.labels(kind="grid").get()) == (
-            case[3] // case[5]) * (case[4] // case[6])
+        assert set(widened) == {(case[3] // case[5], case[4] // case[6])}
 
     @pytest.mark.parametrize("name", sorted(LOWERED))
     def test_calls_without_a_window_lower_to_the_parents_text(self, name):
@@ -453,22 +458,15 @@ class TestTheBandGridChangesNoBit:
         assert hashlib.sha256(text.encode()).hexdigest() == recorded
 
 
-class TestGaugesAndGuards:
-    def gauge(self, name):
-        return {tuple(sorted(cell["labels"].items())): cell["value"]
-                for family in metrics.snapshot() if family["name"] == name
-                for cell in family["samples"]}
-
+class TestPlansAndGuards:
     def test_smallthinkers_band_is_252_of_the_triangles_528_tiles(self):
-        att._record_tiles(True, 32, 32, 512, 512, 0, 0, 4096, 7)
-        tiles = self.gauge("hvd_attn_tiles_last")
-        assert tiles[(("kind", "computed"),)] == 252
-        assert tiles[(("kind", "skipped"),)] == 1024 - 252
-        assert self.gauge("hvd_attn_kv_group_last")[()] == 7
-        att._record_tiles(True, 32, 32, 512, 512, 0, 0, None, 1)
-        assert self.gauge("hvd_attn_tiles_last")[
-            (("kind", "computed"),)] == 528
-        assert self.gauge("hvd_attn_kv_group_last")[()] == 1
+        assert att._tile_plan(True, 32, 32, 512, 512, 0, 0, 4096)[0] == 252
+        assert att._tile_plan(True, 32, 32, 512, 512, 0, 0, None)[0] == 528
+        # its 28 query heads read 4 key/value heads, seven each
+        shaped = jax.ShapeDtypeStruct
+        assert att._tiled_shapes(shaped((28, 16384, 128), jnp.bfloat16),
+                                 shaped((4, 16384, 128), jnp.bfloat16),
+                                 None)[-1] == 7
 
     @pytest.mark.parametrize("blocks, window, grid, extents", [
         pytest.param(32, 4096, 288, (9, 9), id="smallthinker-window-layer"),
@@ -477,27 +475,29 @@ class TestGaugesAndGuards:
     ])
     def test_the_grid_a_slice_runs_over(self, blocks, window, grid, extents):
         """A windowed call's grid is the band's 9 steps a row; what is
-        still empty of it is ``grid - computed`` (36 of 288), while
-        ``skipped`` stays the tile pairs the mask throws away."""
-        assert att._record_tiles(True, blocks, blocks, 512, 512, 0, 0,
-                                 window, 7) == extents
-        tiles = self.gauge("hvd_attn_tiles_last")
-        assert tiles[(("kind", "grid"),)] == grid
-        assert (tiles[(("kind", "computed"),)]
-                + tiles[(("kind", "skipped"),)]) == blocks * blocks
+        still empty of it is ``grid - computed`` (36 of 288)."""
+        computed, band_kb, band_qb = att._tile_plan(
+            True, blocks, blocks, 512, 512, 0, 0, window)
+        assert (band_kb, band_qb) == extents
+        assert blocks * band_kb == grid
+        assert computed <= grid
         if window:
-            assert grid - tiles[(("kind", "computed"),)] == 36
+            assert grid - computed == 36
 
-    def test_a_traced_call_sets_both(self):
+    def test_a_traced_call_walks_the_plans_grid(self):
         q, k, v, _ = operands(1, 14, 2, 64, 64)
-        jax.jit(lambda q, k, v: flash_attention(
-            q, k, v, causal=True, window=20, block_q=16, block_k=16,
-            interpret=True)).lower(q, k, v)
-        tiles = self.gauge("hvd_attn_tiles_last")
+
+        def loss(q, k, v):
+            return flash_attention(
+                q, k, v, causal=True, window=20, block_q=16, block_k=16,
+                interpret=True).sum()
+
         # rows of 16 see 1, 2, 3, 3 tiles of 16 under a window of 20
-        assert tiles[(("kind", "computed"),)] == 9
-        assert tiles[(("kind", "grid"),)] == 4 * 3
-        assert self.gauge("hvd_attn_kv_group_last")[()] == 7
+        assert att._tile_plan(True, 4, 4, 16, 16, 0, 0, 20) == (9, 3, 3)
+        # forward and dq: 14 slices x 4 q blocks x the band's 3; dk/dv: 2
+        # key/value heads x 4 k blocks x 7 query heads each x the band's 3
+        assert sorted(pallas_grids(jax.grad(loss, (0, 1, 2)), q, k, v)) == [
+            (2, 4, 7, 3), (14, 4, 3), (14, 4, 3)]
 
     def test_a_window_needs_the_causal_mask(self):
         q, k, v, _ = operands(1, 2, 2, 32, 32)

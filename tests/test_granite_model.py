@@ -335,7 +335,8 @@ def test_a_factory_step_names_the_state_space_phases_and_the_blocks():
     import optax
 
     import horovod_tpu as hvd
-    from horovod_tpu import attribution, metrics, profiler
+    from horovod_tpu import attribution, profiler
+    from traced import loop_trips
 
     config = dataclasses.replace(granite.GRANITE_TINY, dtype=jnp.float32)
     model = granite.Granite(config)
@@ -366,4 +367,6 @@ def test_a_factory_step_names_the_state_space_phases_and_the_blocks():
     # a mixer's projections are the attention block's, as Olmo Hybrid's
     assert any(profiler.owner_of(scope) == "hvd.block.attn_proj"
                and "in_proj" in scope for scope in scopes)
-    assert metrics.SSM_CHUNKS_LAST.labels(chunk="8", heads="8").get() == 4
+    # the scan's loops, forward and backward, take a trip a chunk of 8
+    trips = loop_trips(text, "hvd.ssm.scan")
+    assert trips and set(trips) == {32 // 8}

@@ -23,6 +23,7 @@ import numpy as np
 import pytest
 
 from horovod_tpu.models import experts, joyai_flash, latent
+from traced import bound
 
 BENCHMARK_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
@@ -445,9 +446,7 @@ def test_parameters_at_the_published_sizes():
     assert len(jax.tree.leaves(shapes)) == 99
 
 
-def test_the_scopes_are_in_a_lowered_step_and_the_gauges_set(bench):
-    from horovod_tpu import metrics
-
+def test_the_scopes_are_in_a_lowered_step_and_the_widths_its_configs(bench):
     config = toy(bench)
     code, _ = files(bench)
     params = jax.eval_shape(partial(code.init_params, config, {}),
@@ -470,9 +469,17 @@ def test_the_scopes_are_in_a_lowered_step_and_the_gauges_set(bench):
                    "hvd.mtp/hvd.block.head"):
         assert inside in text, inside
     assert "hvd.mtp/layer_" not in text
-    assert metrics.MLA_ROPE_LANES_LAST.labels(kind="rotated").get() == 8
-    assert metrics.MLA_ROPE_LANES_LAST.labels(kind="kept").get() == 16
-    assert metrics.MTP_DEPTH_LAST.labels().get() == 1
+    # 8 lanes turned beside 16 kept: kv_a writes the one shared key's 8
+    # beside the latent, q_b every head's 16 + 8; and one prediction module
+    built = code.model_config(config)
+    attention = params["layer_0"]["attention"]
+    assert (built.qk_rope_head_dim, built.qk_nope_head_dim) == (8, 16)
+    assert attention["kv_a"]["kernel"].shape[-1] == built.kv_lora_rank + 8
+    assert attention["q_b"]["kernel"].shape[-1] == (
+        built.num_attention_heads * (16 + 8))
+    assert built.num_nextn_predict_layers == 1
+    assert [name for name in params if name.startswith("mtp_layer")] == [
+        "mtp_layer"]
 
 
 def test_routing_stats_read_the_stacks_expert_layers(bench):
@@ -601,7 +608,7 @@ def test_the_one_pass_is_the_parents_turn_reshape_and_transposes(
 
 def test_any_other_platform_lowers_the_plain_form_of_the_same_function():
     """A program lowered for the CPU holds the plain form of each of the
-    four passes (``linear_attention._where_lowered``): the kernels'
+    four passes (``kernel_parts.where_lowered``): the kernels'
     function to a bfloat16 rounding (XLA's CPU contracts ``x cos + p sin``
     its own way), and no Pallas call."""
     from horovod_tpu.ops import rotary_split
@@ -645,21 +652,15 @@ def latent_layer(heads, rope_theta=10000.0, attention=True, seq=2 * TILE):
     return layer, x
 
 
-def path_taken():
-    from horovod_tpu import metrics
-
-    return {sample["labels"]["path"]: sample["value"]
-            for sample in metrics.MLA_ROPE_PATH_LAST.dump()["samples"]}
-
-
 @pytest.mark.parametrize("shapes,path", [
     ("joyai_llm_flash", "one_pass"), ("two_heads", "one_pass"),
     ("three_heads", "plain"), ("dense_attention", "plain"),
     ("an_odd_tile", "plain"), ("the_toy", "plain")])
-def test_the_path_gauge_says_which_way_a_layer_went(shapes, path):
-    """``hvd_mla_rope_path_last{path}``: the published shapes (and any
-    whose heads pair up into whole lane tiles, with an adapter that takes
-    head-major operands) take the one pass; three heads, dense attention
+def test_the_trace_says_which_way_a_layer_went(shapes, path):
+    """The one pass's primitives are in the trace or they are not: the
+    published shapes (and any whose heads pair up into whole lane tiles,
+    with an adapter that takes head-major operands) take the one pass;
+    three heads, dense attention
     (it reads ``[B, S, H, D]``), tokens that fill no sublane tile and the
     toy's 16 + 8 lanes take ``latent.turn``."""
     if shapes == "joyai_llm_flash":
@@ -683,9 +684,10 @@ def test_the_path_gauge_says_which_way_a_layer_went(shapes, path):
         if shapes == "an_odd_tile":  # 40 tokens: tiles of 8, half a bf16 tile
             layer = layer.clone(attention_fn=partial(
                 joyai_flash.flash_attention_fn, interpret=True, block=8))
-    jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
-    assert path_taken() == {"one_pass": float(path == "one_pass"),
-                            "plain": float(path == "plain")}
+    passes = [name for name, _ in bound(
+        layer.init, jax.random.PRNGKey(0), x, prefix="hvd_mla_rope")]
+    assert passes == {"one_pass": ["hvd_mla_rope_queries",
+                                   "hvd_mla_rope_keys"], "plain": []}[path]
 
 
 def under_the_scope(jaxpr, scope="hvd.mla.rope", inside=False):
@@ -749,10 +751,14 @@ def test_a_layer_through_the_one_pass_is_the_layer_through_the_turn():
     def loss(layer, p, x):
         return (layer.apply(p, x).astype(jnp.float32) ** 2).sum()
 
+    def passes(layer):
+        return [name for name, _ in bound(partial(loss, layer), params, x,
+                                          prefix="hvd_mla_rope")]
+
     got = jax.jit(jax.value_and_grad(partial(loss, layer)))(params, x)
-    assert path_taken()["one_pass"] == 1
+    assert passes(layer) == ["hvd_mla_rope_queries", "hvd_mla_rope_keys"]
     want = jax.jit(jax.value_and_grad(partial(loss, turned)))(params, x)
-    assert path_taken()["plain"] == 1
+    assert not passes(turned)
     assert float(got[0]) == pytest.approx(float(want[0]), rel=2e-3)
     for (path, leaf), ref in zip(
             jax.tree_util.tree_leaves_with_path(got[1]),

@@ -4,8 +4,9 @@ recurrence ``S' = Diag(exp(g_t)) S``, values and all five gradients, at
 mild decays, at the initial draw's strongest (1.6 a token) and at e^-20 a
 token, where the cheap factorisation ``(k e^gamma)(k e^-gamma)^T`` would
 overflow inside one sub-block: nothing here is an ``inf`` or a ``nan``.
-With every channel's decay equal it is ``gated_delta_rule``. The scope, the
-two gauges and the refusal of a ragged sequence are there. The running sum
+With every channel's decay equal it is ``gated_delta_rule``. The scope, what
+the trace says of the rule it holds and the refusal of a ragged sequence are
+there. The running sum
 ``gamma`` is a float32 product with a triangle of ones at
 ``Precision.HIGHEST``: a float64 sum's value, and no ``reduce_window`` in
 the program, forward or backward; ``gated_delta_rule`` keeps its text.
@@ -33,8 +34,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu import metrics
 from horovod_tpu.ops import linear_attention
+from traced import bound, equations
 
 B, S, H, DK, DV = 2, 64, 3, 8, 16
 RATES = {"mild": 0.05, "initial_draws_strongest": 1.6, "e-20_a_token": 20.0}
@@ -177,26 +178,40 @@ def test_a_ragged_sequence_or_sub_block_is_refused(seq, chunk, sub, path):
                                          sub=sub)
 
 
-def test_the_scope_and_the_gauges_say_which_rule_the_step_holds(path):
+def test_the_scope_and_the_trace_say_which_rule_the_step_holds(path):
     args = inputs(0.3)
-    layout = {"plain": "plain", "kernel": "tokens_major"}[path]
-    metrics.LINATTN_PAIR_KERNEL_LAST.set(-1, sub="8", operands=layout)
     text = jax.jit(rule(32, 8)).lower(*args).as_text(debug_info=True)
     assert "hvd.linattn.scan" in text
-    # said when the program is lowered: no kernel there, or a sequence's two
-    # chunks a grid step (of its three heads) where the test has put the
-    # kernel, interpreted; the label says how q, k and gamma cross HBM
-    assert metrics.LINATTN_PAIR_KERNEL_LAST.labels(
-        sub="8", operands=layout).get() == {"plain": 0, "kernel": 2}[path]
-    assert metrics.LINATTN_SCAN_KERNEL_LAST.labels(
-        chunk="32", operands="plain").get() == 0
-    assert metrics.LINATTN_CHUNKS_LAST.labels(
-        chunk="32", heads_here=str(H)).get() == S // 32
-    assert metrics.LINATTN_DECAY_WIDTH_LAST.labels().get() == DK
-    # (a function of its own: a second trace of the same one is cached)
-    jax.jit(lambda *a: linear_attention.gated_delta_rule(*a, chunk=32)).lower(
-        *args[:3], args[3][..., 0], args[4])
-    assert metrics.LINATTN_DECAY_WIDTH_LAST.labels().get() == 1
+    traced = jax.make_jaxpr(rule(32, 8))(*args)
+    # the pair terms: no primitive where the shapes fill no tile, or, where
+    # the test has put the kernel, a sequence's two chunks of its three
+    # heads a grid step
+    assert [(name, how["step"], how["heads"], how["sub"]) for name, how in
+            bound(traced, prefix="hvd_kda_pair_terms")] == {
+        "plain": [], "kernel": [("hvd_kda_pair_terms", 2, 3, 8)]}[path]
+    # the chunk loop: these widths fill no tile, so one plain ``scan`` over
+    # the sequence's chunks
+    assert not linear_attention._scan_heads_a_step(args[1], args[2], 32)
+    assert not bound(traced, prefix="hvd_kda_chunk_scan")
+    assert [how["length"] for _, how in
+            bound(traced, prefix="scan")] == [S // 32]
+
+    def summed(closed):  # what each running sum is taken over
+        eqns = list(equations(closed.jaxpr))
+        return ([eqn.invars[0].aval.shape for eqn in eqns
+                 if eqn.primitive.name == "cumsum"],
+                [eqn.invars[1].aval.shape for eqn in eqns
+                 if eqn.primitive.name == "dot_general"
+                 and eqn.params["precision"] is not None
+                 and eqn.invars[0].aval.shape == (B, S // 32, 32, 32)])
+
+    # a decay a key channel: gamma is a product with the triangle over the
+    # heads' DK lanes side by side; the scalar rule's is one number a head
+    assert summed(traced) == ([], [(B, S // 32, 32, H * DK)])
+    assert summed(jax.make_jaxpr(
+        lambda *a: linear_attention.gated_delta_rule(*a, chunk=32))(
+            *args[:3], args[3][..., 0], args[4]))[0] == [
+                (B, H, S // 32, 32)]
 
 
 PAIR_FORMS = [(64, 16, 128), (16, 4, 16)]  # (chunk, sub-block, d_k)
@@ -272,9 +287,9 @@ def test_a_grid_step_takes_the_chunks_that_divide_their_number(monkeypatch):
     sequence where four divide them, else the largest divisor under the
     limit (a sequence's six chunks go three a grid step, or all six under a
     limit of eight, or one), of as many heads as the chunk loop's kernels
-    take (two here: both, or one under a limit of one); the gauge says the
-    chunks as the call is lowered, and no result or cotangent depends on
-    the split."""
+    take (two here: both, or one under a limit of one); the primitive the
+    call binds carries the split, and no result or cotangent depends on
+    it."""
     operands, bars = pair_operands(0.3, 16, 16, jnp.float32, count=6)
     assert linear_attention.PAIR_CHUNKS_A_STEP == 4
     seen = []
@@ -288,8 +303,11 @@ def test_a_grid_step_takes_the_chunks_that_divide_their_number(monkeypatch):
             lambda *a: linear_attention.pair_terms_kernel(
                 *a, 16, 4, jnp.float32, True), *operands)
         seen.append(tuple(got) + vjp(bars))
-        assert metrics.LINATTN_PAIR_KERNEL_LAST.labels(
-            sub="4", operands="tokens_major").get() == step[0]
+        (name, traced), = bound(
+            lambda *a: linear_attention.pair_terms_kernel(
+                *a, 16, 4, jnp.float32, True), *operands)
+        assert (name, traced["step"], traced["heads"], traced["sub"]) == (
+            "hvd_kda_pair_terms",) + step + (4,)
     for other in seen[1:]:
         jax.tree.map(np.testing.assert_array_equal, seen[0], other)
     want = plain_pair_terms(16, 4, jnp.float32)(*operands)
@@ -563,8 +581,8 @@ def test_the_scan_kernels_form_no_positive_exponent(scanned, monkeypatch):
 
 def test_a_grid_step_takes_two_or_eight_heads(monkeypatch):
     """Eight heads over three chunks (a count neither step divides) in grid
-    steps of eight, of two and of one: the gauge says which as the call is
-    lowered, and no result or cotangent depends on the split beyond
+    steps of eight, of two and of one: the primitive the call binds says
+    which, and no result or cotangent depends on the split beyond
     float32's rounding (two heads' solves share the MXU's passes where they
     lie side by side)."""
     size = 16
@@ -576,8 +594,11 @@ def test_a_grid_step_takes_two_or_eight_heads(monkeypatch):
             lambda *a: linear_attention.chunk_scan_kernel(*a, True),
             *operands)
         seen.append((got,) + vjp(o_bar))
-        assert metrics.LINATTN_SCAN_KERNEL_LAST.labels(
-            chunk=str(size), operands="tokens_major").get() == step
+        (name, traced), = bound(
+            lambda *a: linear_attention.chunk_scan_kernel(*a, True),
+            *operands)
+        assert (name, traced["step"], traced["chunk"]) == (
+            "hvd_kda_chunk_scan", step, size)
     for other in seen[1:]:
         jax.tree.map(lambda a, b: np.testing.assert_allclose(
             a, b, rtol=0, atol=1e-6 * float(jnp.abs(a).max())),
@@ -630,37 +651,33 @@ def test_a_recomputed_layer_keeps_nothing_the_scan_kernels_return(scanned):
 
 
 def test_a_shape_that_fills_no_tile_takes_the_plain_chunk_loop():
-    """The toys' widths trace the plain form whatever the platform, and the
-    gauge reads 0 at once; the cell's widths are a primitive whose lowering
-    for this platform is the plain form (0 again, as it is lowered: the
-    ``while`` is in the text and no kernel) and, interpreted, the kernels:
-    eight heads a grid step."""
+    """The toys' widths trace the plain form whatever the platform: no
+    primitive, a ``scan`` at once; the cell's widths are a primitive whose
+    lowering for this platform is the plain form (the ``while`` is in the
+    text and no kernel) and, interpreted, the kernels: eight heads a grid
+    step."""
     def primitives(*operands):
-        return str(jax.make_jaxpr(
-            linear_attention._chunk_scan_where_lowered)(*operands))
+        return [name for name, _ in bound(
+            linear_attention._chunk_scan_where_lowered, *operands,
+            prefix="")]
 
-    gauge = metrics.LINATTN_SCAN_KERNEL_LAST
+    fills = linear_attention._scan_heads_a_step
     toy, _ = scan_operands("toy", 0.3, jnp.float32)
-    gauge.set(-1, chunk="16", operands="plain")
-    assert "hvd_kda_chunk_scan" not in primitives(*toy)
-    assert gauge.labels(chunk="16", operands="plain").get() == 0
+    assert not fills(toy[1], toy[2], toy[-1].shape[-1])
+    traced = primitives(*toy)
+    assert "hvd_kda_chunk_scan" not in traced and "scan" in traced
     wide, _ = scan_operands("the_cells_widths", 0.3, jnp.bfloat16)
-    gauge.set(-1, chunk="64", operands="plain")
-    gauge.set(-1, chunk="64", operands="tokens_major")
-    assert "hvd_kda_chunk_scan" in primitives(*wide)
-    assert gauge.labels(chunk="64", operands="plain").get() == -1  # not yet
+    traced = primitives(*wide)  # no ``scan`` yet: the lowering's choice
+    assert "hvd_kda_chunk_scan" in traced and "scan" not in traced
     text = jax.jit(linear_attention._chunk_scan_where_lowered).lower(
         *wide).as_text()
-    assert gauge.labels(chunk="64", operands="plain").get() == 0
-    assert gauge.labels(chunk="64", operands="tokens_major").get() == -1
     assert "tpu_custom_call" not in text and "while" in text
-    jax.jit(lambda *a: linear_attention.chunk_scan_kernel(*a, True)).lower(
-        *wide)
-    assert gauge.labels(chunk="64", operands="tokens_major").get() == (
+    (_, interpreted), = bound(
+        lambda *a: linear_attention.chunk_scan_kernel(*a, True), *wide)
+    assert interpreted["interpret"] and interpreted["step"] == (
         linear_attention.SCAN_HEADS_A_STEP) == 8
     # a width of 64, seven heads or a chunk of 48 fill no tile
     q, k, v = wide[:3]
-    fills = linear_attention._scan_heads_a_step
     assert fills(k, v, 64) == 8
     assert not fills(k[..., :64], v, 64)
     assert not fills(k, v[..., :64], 64)

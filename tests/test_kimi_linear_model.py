@@ -285,29 +285,33 @@ def test_parameters_at_the_published_sizes():
     assert whole == pytest.approx(49.12e9, rel=1e-3)
 
 
-def test_the_scopes_are_in_a_lowered_step_and_the_gauges_set(bench):
-    from horovod_tpu import metrics
+def test_the_scopes_are_in_a_lowered_step_and_the_widths_in_its_trace(
+        bench):
+    from traced import equations, pallas_calls
 
     config = toy(bench)
     code = bench.load_code(bench.HERE, "configs", "kimi_linear.py")
     params = jax.eval_shape(partial(code.init_params, config, {}),
                             jax.random.PRNGKey(0))
     tokens = jax.ShapeDtypeStruct((2, 33), jnp.int32)
-    # the gauges below are set as a call is traced: a trace of the toy's
-    # flash call kept from an earlier test (JoyAI Flash's toy has the same
-    # widths) would leave them at whatever was traced since
-    jax.clear_caches()
-    text = jax.jit(jax.grad(code.loss_fn(config, {}))).lower(
-        params, tokens).as_text(debug_info=True)
+    traced = jax.jit(jax.grad(code.loss_fn(config, {}))).trace(
+        params, tokens)
+    text = traced.lower().as_text(debug_info=True)
     for scope in ("hvd.linattn.conv", "hvd.linattn.scan", "hvd.linattn.gate",
                   "hvd.attn.mla/hvd.attn.fwd", "hvd.attn.mla/hvd.attn.bwd",
                   "hvd.moe.shared", "hvd.moe.route", "hvd.moe.experts",
                   "hvd.block.ffn", "hvd.block.attn_proj", "hvd.block.norm",
                   "hvd.block.embed", "hvd.block.head"):
         assert scope in text, scope
-    assert metrics.LINATTN_DECAY_WIDTH_LAST.labels().get() == 16
-    assert metrics.ATTN_HEAD_WIDTHS_LAST.labels(kind="qk").get() == 24
-    assert metrics.ATTN_HEAD_WIDTHS_LAST.labels(kind="v").get() == 16
+    # a decay a key channel: gamma is the triangle's product over the four
+    # heads' 16 lanes side by side (the scalar rule's is a sum a head)
+    assert (2, 2, 16, 4 * 16) in [
+        eqn.invars[1].aval.shape for eqn in equations(traced.jaxpr.jaxpr)
+        if eqn.primitive.name == "dot_general"
+        and eqn.invars[0].aval.shape == (2, 2, 16, 16)]
+    # the latent layer's kernels read keys of 16 + 8 lanes, values of 16
+    assert {(shapes[1][-1], shapes[2][-1])
+            for _, shapes in pallas_calls(traced.jaxpr)} == {(24, 16)}
 
 
 def test_routing_stats_skip_the_dense_layer(bench):
@@ -369,13 +373,12 @@ def test_a_layer_with_nothing_turned_traces_to_the_jaxpr_it_had():
     even at widths whose heads pair up into whole lane tiles and with the
     adapter that takes head-major operands: its branch is untouched, the
     reshapes where they were and the adapter's three transposes after
-    them, and no path gauge is set."""
+    them."""
     import hashlib
 
-    from horovod_tpu import metrics
     from horovod_tpu.models import latent, parts
+    from horovod_tpu.ops import rotary_split
 
-    before = metrics.MLA_ROPE_PATH_LAST.dump()["samples"]
     cfg = dataclasses.replace(
         kimi_linear.KIMI_LINEAR_TINY, hidden_size=64, num_attention_heads=2,
         qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
@@ -391,7 +394,9 @@ def test_a_layer_with_nothing_turned_traces_to_the_jaxpr_it_had():
     text = str(jax.make_jaxpr(jax.grad(loss))(params, x))
     assert "hvd_mla_rope" not in text
     assert hashlib.sha256(text.encode()).hexdigest() == PARENTS_LATENT_JAXPR
-    assert metrics.MLA_ROPE_PATH_LAST.dump()["samples"] == before
+    # (shapes the one pass would have taken, had anything been turned)
+    assert rotary_split.tokens_a_step(
+        jax.ShapeDtypeStruct((1, 32, 2 * 192), jnp.bfloat16), 2, 128, 64, 128)
 
 
 # --- the norms a head, where the heads' lanes lie (PR 53)

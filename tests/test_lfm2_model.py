@@ -516,16 +516,17 @@ def test_parameters_at_the_published_sizes():
     assert round(whole / 1e9, 2) == 23.84
 
 
-def test_the_scope_is_in_a_lowered_step_and_the_gauges_set(bench):
-    from horovod_tpu import metrics
+def test_the_scope_is_in_a_lowered_step_and_the_widths_in_its_trace(bench):
+    from traced import pallas_grids, shapes
 
     config = toy(bench)
     code, _ = files(bench)
     params = jax.eval_shape(partial(code.init_params, config, {}),
                             jax.random.PRNGKey(0))
     tokens = jax.ShapeDtypeStruct((2, 33), jnp.int32)
-    text = jax.jit(jax.grad(code.loss_fn(config, {}))).lower(
-        params, tokens).as_text(debug_info=True)
+    traced = jax.jit(jax.grad(code.loss_fn(config, {}))).trace(
+        params, tokens)
+    text = traced.lower().as_text(debug_info=True)
     for scope in ("hvd.shortconv.mix", "hvd.attn.fwd", "hvd.attn.bwd",
                   "hvd.moe.route", "hvd.moe.dispatch", "hvd.moe.experts",
                   "hvd.moe.combine", "hvd.block.ffn", "hvd.block.attn_proj",
@@ -537,10 +538,17 @@ def test_the_scope_is_in_a_lowered_step_and_the_gauges_set(bench):
     assert "hvd.shortconv.mix/in_proj" not in text
     assert "hvd.shortconv.mix/out_proj" not in text
     assert "layer_1/hvd.block.attn_proj/attention/hvd.shortconv" not in text
-    assert metrics.SHORTCONV_TAPS_LAST.labels(channels="64").get() == 3
-    assert metrics.ATTN_KV_GROUP_LAST.labels().get() == 4
-    assert metrics.MOE_SLOTS_LAST.labels(
-        experts_here="4", capacity="3", top_k="2").get() == 12
+    # three taps over the hidden size's 64 channels
+    assert params["layer_0"]["conv"]["conv"].shape == (64, 3)
+    # four query heads a key/value head: the dk/dv grid's axis of its own
+    assert [grid[2] for grid in pallas_grids(traced.jaxpr)
+            if len(grid) == 4] == [4]
+    # 4 experts here x the 10 slots ``capacity`` plans a sequence of 32 (3
+    # the 8 tokens ``init_params`` traces: the 12 slots the gauge once held)
+    built = code.model_config(config)
+    assert (built.experts_held, built.top_k) == (4, 2)
+    assert (built.capacity(32), built.capacity(8)) == (10, 3)
+    assert (2, 4, 10, 64) in shapes(traced.jaxpr)
 
 
 def test_routing_stats_read_the_expert_layers(bench):

@@ -14,8 +14,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu import metrics, profiler
+from horovod_tpu import profiler
 from horovod_tpu.ops import linear_attention
+from traced import loop_trips
 
 B, S, H, DK, DV = 2, 128, 3, 8, 16
 
@@ -218,7 +219,7 @@ def test_short_conv_is_the_published_one_and_sees_no_future_token():
     np.testing.assert_array_equal(changed[:, 11:], y[:, 11:])  # width 4
 
 
-def test_the_scope_is_on_forward_and_backward_and_the_gauge_is_set():
+def test_the_scope_is_on_forward_and_backward_and_a_loop_trip_is_a_chunk():
     args = inputs("weak")
     text = jax.jit(jax.grad(lambda *a: jnp.sum(
         linear_attention.gated_delta_rule(*a, chunk=32)))).lower(
@@ -228,5 +229,4 @@ def test_the_scope_is_on_forward_and_backward_and_the_gauge_is_set():
              if profiler.phase_of(s) == "hvd.linattn.scan"]
     assert any("transpose(" in s for s in under)
     assert any("transpose(" not in s for s in under)
-    assert metrics.LINATTN_CHUNKS_LAST.labels(
-        chunk="32", heads_here=str(H)).get() == S // 32
+    assert loop_trips(text, "hvd.linattn.scan") == [S // 32] * 2

@@ -191,7 +191,8 @@ def test_routing_stats_count_load_and_drops():
     np.testing.assert_allclose(
         stats["dropped_share"][0],
         stats["dropped"][0] / mine.sum(), rtol=1e-6)
-    # the gauge set at trace time names the slots the step computes
-    from horovod_tpu import metrics
-    assert metrics.MOE_SLOTS_LAST.labels(
-        experts_here="4", capacity="8", top_k="2").get() == 32
+    # the slots the traced step computes a sequence: 4 experts x capacity
+    from traced import shapes
+    assert (config.experts_held, config.top_k) == (4, 2)
+    assert (3, 4, capacity, config.hidden_size) in shapes(
+        partial(experts.routing_stats, model), params, ids)

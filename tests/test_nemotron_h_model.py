@@ -313,16 +313,17 @@ def test_parameters_at_the_published_sizes():
     assert len(jax.tree.leaves(shapes)) == 68
 
 
-def test_the_scopes_are_in_a_lowered_step_and_the_group_gauge_set(bench):
-    from horovod_tpu import metrics
+def test_the_scopes_are_in_a_lowered_step_and_the_group_in_its_grid(bench):
+    from traced import pallas_grids
 
     config = toy(bench)
     code = bench.load_code(bench.HERE, "configs", "nemotron_h.py")
     params = jax.eval_shape(partial(code.init_params, config, {}),
                             jax.random.PRNGKey(0))
     tokens = jax.ShapeDtypeStruct((2, 33), jnp.int32)
-    text = jax.jit(jax.grad(code.loss_fn(config, {}))).lower(
-        params, tokens).as_text(debug_info=True)
+    traced = jax.jit(jax.grad(code.loss_fn(config, {}))).trace(
+        params, tokens)
+    text = traced.lower().as_text(debug_info=True)
     for scope in ("hvd.ssm.conv", "hvd.ssm.scan", "hvd.ssm.gate",
                   "hvd.attn.fwd", "hvd.attn.bwd", "hvd.moe.shared",
                   "hvd.moe.route", "hvd.moe.dispatch", "hvd.moe.experts",
@@ -332,7 +333,9 @@ def test_the_scopes_are_in_a_lowered_step_and_the_group_gauge_set(bench):
     # the experts and the shared one inside the E layer's block scope
     assert "hvd.block.ffn/moe/" in text
     assert "hvd.block.ffn/hvd.moe.shared/shared" in text
-    assert metrics.ATTN_KV_GROUP_LAST.labels().get() == 4  # 8 heads on 2
+    # 8 heads on 2: the dk/dv grid's axis over a key/value head's four
+    assert [grid[2] for grid in pallas_grids(traced.jaxpr)
+            if len(grid) == 4] == [4]
 
 
 def test_granites_mixer_is_one_group_of_each(bench):

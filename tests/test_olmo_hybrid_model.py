@@ -288,7 +288,8 @@ def test_a_factory_step_names_the_linear_attention_phases():
     import optax
 
     import horovod_tpu as hvd
-    from horovod_tpu import metrics, profiler
+    from horovod_tpu import profiler
+    from traced import loop_trips
 
     config = dataclasses.replace(olmo_hybrid.OLMO_HYBRID_TINY,
                                  dtype=jnp.float32, heads_here=2)
@@ -311,5 +312,6 @@ def test_a_factory_step_names_the_linear_attention_phases():
     for name in ("hvd.linattn.conv", "hvd.linattn.scan", "hvd.linattn.gate"):
         assert any("transpose(" in scope and name in scope
                    for scope in scopes), name
-    assert metrics.LINATTN_CHUNKS_LAST.labels(
-        chunk="16", heads_here="2").get() == 2
+    # the rule's loops, forward and backward, take a trip a chunk of 16
+    trips = loop_trips(text, "hvd.linattn.scan")
+    assert trips and set(trips) == {32 // 16}

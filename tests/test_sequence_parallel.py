@@ -13,6 +13,7 @@ from horovod_tpu.ops.attention import (
     flash_attention,
 )
 from horovod_tpu.parallel import sequence as sp
+from traced import pallas_grids
 
 
 def dense_attention(q, k, v, causal=False):
@@ -301,9 +302,9 @@ class TestCausalTileSkipping:
 
     @pytest.mark.parametrize("causal, computed, skipped",
                              [(True, 36, 28), (False, 64, 0)])
-    def test_the_gauge_counts_the_tiles_of_the_last_traced_call(
+    def test_the_plan_counts_the_tiles_a_traced_call_walks(
             self, causal, computed, skipped):
-        from horovod_tpu import metrics
+        from horovod_tpu.ops import attention as att
 
         q, k, v = make_qkv(B=1, H=1, S=256, D=32)
 
@@ -311,16 +312,13 @@ class TestCausalTileSkipping:
             return flash_attention(q, k, v, causal=causal, block_q=32,
                                    block_k=32, interpret=True).sum()
 
-        # Lowered, not run: the gauge is set while the calls are traced,
-        # by the forward's and again by the backward's.
-        for fn in (loss, jax.grad(loss, argnums=(0, 1, 2))):
-            metrics.ATTN_TILES_LAST.set(-1, kind="computed")
-            metrics.ATTN_TILES_LAST.set(-1, kind="skipped")
-            jax.jit(fn).lower(q, k, v)
-            assert metrics.ATTN_TILES_LAST.labels(
-                kind="computed").get() == computed
-            assert metrics.ATTN_TILES_LAST.labels(
-                kind="skipped").get() == skipped
+        pairs, band_kb, band_qb = att._tile_plan(causal, 8, 8, 32, 32, 0, 0)
+        assert (pairs, 8 * 8 - pairs) == (computed, skipped)
+        # Traced, not run: the forward walks the plan's grid, and the
+        # backward's three kernels (the forward again, dq, dk/dv) too.
+        assert pallas_grids(loss, q, k, v) == [(1, 8, band_kb)]
+        assert pallas_grids(jax.grad(loss, argnums=(0, 1, 2)), q, k, v) == [
+            (1, 8, band_kb), (1, 8, band_kb), (1, 8, band_qb)]
 
 
 # (BH, causal): odd, prime and composite counts of (batch x head) slices.
@@ -343,7 +341,6 @@ class TestSliceGroups:
 
     @staticmethod
     def run(monkeypatch, budget, bh, causal, q_off=0, k_off=0, S=32, D=16):
-        from horovod_tpu import metrics
         from horovod_tpu.ops import attention as att
 
         monkeypatch.setattr(att, "GROUP_BUDGET_BYTES", budget)
@@ -359,8 +356,11 @@ class TestSliceGroups:
 
         (out, lse), vjp = jax.vjp(flash, q, k, v)
         dq, dk, dv = vjp((g_out, g_lse))
-        groups = tuple(int(metrics.ATTN_GROUP_LAST.labels(kernel=kernel).get())
-                       for kernel in ("fwd", "bwd"))
+        # the slices a grid step of the forward and of the fused backward
+        # takes: their grids are (BH // G,)
+        groups = tuple(bh // grid[0] for grid in pallas_grids(
+            lambda q, k, v: jax.vjp(flash, q, k, v)[1]((g_out, g_lse)),
+            q, k, v))
         return [np.asarray(x) for x in (out, lse, dq, dk, dv)], groups
 
     @pytest.mark.parametrize("budget", [None, SMALL_BUDGET],
@@ -438,9 +438,8 @@ class TestSliceGroups:
         assert groups == sorted(groups, reverse=True)
         assert groups[2] > 1  # BERT's S=128 is grouped
 
-    def test_the_gauge_holds_the_group_of_the_last_traced_call(
+    def test_a_traced_call_takes_the_group_the_budget_plans(
             self, monkeypatch):
-        from horovod_tpu import metrics
         from horovod_tpu.ops import attention as att
 
         q, k, v = make_qkv(B=3, H=4, S=32, D=16)
@@ -453,26 +452,18 @@ class TestSliceGroups:
             jax.clear_caches()
             want = (att._group_size(12, 32, 32, 16, 4, **att._FWD_SLICE),
                     att._group_size(12, 32, 32, 16, 4, **att._BWD_SLICE))
-            # Lowered, not run: set while the calls are traced.
-            for kernel in ("fwd", "bwd"):
-                metrics.ATTN_GROUP_LAST.set(-1, kernel=kernel)
-            jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, k, v)
-            got = tuple(metrics.ATTN_GROUP_LAST.labels(kernel=kernel).get()
-                        for kernel in ("fwd", "bwd"))
+            # Traced, not run: the grids are (BH // G,).
+            got = tuple(12 // grid[0] for grid in pallas_grids(
+                jax.grad(loss, argnums=(0, 1, 2)), q, k, v))
             assert got == want
         jax.clear_caches()
 
     def test_a_multi_tile_call_takes_no_group(self):
-        from horovod_tpu import metrics
-
         q, k, v = make_qkv(B=1, H=4, S=64, D=16)
-        for kernel in ("fwd", "bwd"):
-            metrics.ATTN_GROUP_LAST.set(-1, kernel=kernel)
-        jax.jit(jax.grad(lambda q, k, v: flash_attention(
-            q, k, v, block_q=32, block_k=32, interpret=True).sum())).lower(
-                q, k, v)
-        assert metrics.ATTN_GROUP_LAST.labels(kernel="fwd").get() == -1
-        assert metrics.ATTN_GROUP_LAST.labels(kernel="bwd").get() == -1
+        grids = pallas_grids(jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, block_q=32, block_k=32, interpret=True).sum()), q, k, v)
+        # a slice a step of (BH, Q blocks, K blocks), never (BH // G,)
+        assert grids == [(4, 2, 2)] * 3
 
 
 class TestRingAttention:

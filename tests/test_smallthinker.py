@@ -16,7 +16,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu import metrics
 from horovod_tpu.models import experts, parts
 from horovod_tpu.models import smallthinker as st
 from horovod_tpu.parallel import moe
@@ -316,14 +315,17 @@ class TestConfig:
         with pytest.raises(ValueError, match="cannot share"):
             dataclasses.replace(TINY, num_kv_heads=4)
 
-    def test_the_slots_gauge_is_set_at_trace_time(self, params, tokens):
-        jax.eval_shape(partial(st.causal_lm_loss, st.SmallThinker(TINY)),
-                       params, tokens)
-        family, = [f for f in metrics.snapshot()
-                   if f["name"] == "hvd_moe_slots_last"]
-        labels = {"experts_here": "8", "capacity": "64", "top_k": "3"}
-        assert [c["value"] for c in family["samples"]
-                if c["labels"] == labels] == [8 * 64]
+    def test_a_traced_step_computes_the_plans_slots(self, params, tokens):
+        """8 experts x the 64 slots ``capacity`` plans a sequence: the
+        dispatch buffer of the trace."""
+        from traced import shapes
+
+        rows, seq = tokens.shape[0], tokens.shape[1] - 1
+        assert (TINY.experts_held, TINY.capacity(seq), TINY.top_k) == (
+            8, 64, 3)
+        assert (rows, 8, 64, TINY.hidden_size) in shapes(
+            partial(st.causal_lm_loss, st.SmallThinker(TINY)), params,
+            tokens)
 
 
 class TestWhatTheModelAsksOfMoe:

@@ -16,9 +16,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from horovod_tpu import metrics, profiler
+from horovod_tpu import profiler
 from horovod_tpu.models import parts
 from horovod_tpu.ops import linear_attention, ssd
+from traced import bound, loop_trips
 
 B, S, H, P, N = 2, 96, 6, 8, 16
 
@@ -162,7 +163,7 @@ def test_the_short_convolution_takes_a_bias():
     assert rounded.dtype == jnp.bfloat16
 
 
-def test_the_scope_is_on_forward_and_backward_and_the_gauge_is_set():
+def test_the_scope_is_on_forward_and_backward_and_a_loop_trip_is_a_chunk():
     args = inputs("weak")
     text = jax.jit(jax.grad(lambda *a: jnp.sum(
         ssd.ssd_scan(*a, chunk=32)))).lower(*args).compile().as_text()
@@ -171,8 +172,7 @@ def test_the_scope_is_on_forward_and_backward_and_the_gauge_is_set():
              if profiler.phase_of(s) == "hvd.ssm.scan"]
     assert any("transpose(" in s for s in under)
     assert any("transpose(" not in s for s in under)
-    assert metrics.SSM_CHUNKS_LAST.labels(
-        chunk="32", heads=str(H)).get() == S // 32
+    assert loop_trips(text, "hvd.ssm.scan") == [S // 32] * 2
 
 
 def nemotron_inputs(seed: int = 3):
@@ -493,28 +493,30 @@ def test_a_bfloat16_carried_state_fails_the_rounding_points(monkeypatch):
 
 
 def test_a_shape_that_fills_no_tile_takes_the_plain_form():
-    """The toys' widths trace the plain form whatever the platform, and
-    the gauge reads 0 at once; a shape that fills the tiles is a primitive
-    whose lowering for this platform is the plain form (0 again, as it is
-    lowered) and, interpreted, the kernels: eight heads a grid step."""
+    """The toys' widths trace the plain form whatever the platform (its
+    ``cumsum`` at once); a shape that fills the tiles is a primitive whose
+    lowering for this platform is the plain form (the ``cumsum`` only in
+    the lowered text) and, interpreted, the kernels: eight heads a grid
+    step."""
     def primitives(*args, chunk):
-        return str(jax.make_jaxpr(lambda *t: ssd.ssd_scan(*t, chunk=chunk))(
-            *args))
+        return [name for name, _ in bound(
+            lambda *t: ssd.ssd_scan(*t, chunk=chunk), *args, prefix="")]
 
-    gauge = metrics.SSM_SCAN_KERNEL_LAST
-    gauge.set(-1, chunk="32")
-    assert "hvd_ssd_chunk_scan" not in primitives(*inputs("weak"), chunk=32)
-    assert gauge.labels(chunk="32").get() == 0
+    weak = inputs("weak")
+    assert not ssd._heads_a_step(weak[0], weak[3], 32)
+    traced = primitives(*weak, chunk=32)
+    assert "hvd_ssd_chunk_scan" not in traced and "cumsum" in traced
     args, chunk = tile_filling_inputs("eight_groups_chunk_128_one_chunk")
-    gauge.set(-1, chunk="128")
-    assert "hvd_ssd_chunk_scan" in primitives(*args, chunk=chunk)
-    assert gauge.labels(chunk="128").get() == -1  # not yet lowered
+    assert ssd._heads_a_step(args[0], args[3], chunk) == 8
+    traced = primitives(*args, chunk=chunk)  # no ``cumsum``: not yet lowered
+    assert "hvd_ssd_chunk_scan" in traced and "cumsum" not in traced
     text = jax.jit(lambda *t: ssd.ssd_scan(*t, chunk=chunk)).lower(
         *args).as_text()
-    assert gauge.labels(chunk="128").get() == 0
     assert "hvd_ssd_chunk_scan" not in text and "cumsum" in text
-    jax.jit(lambda *t: ssd.ssd_scan_kernel(*t, chunk, True)).lower(*args)
-    assert gauge.labels(chunk="128").get() == ssd.SCAN_HEADS_A_STEP == 8
+    (_, interpreted), = bound(
+        lambda *t: ssd.ssd_scan_kernel(*t, chunk, True), *args)
+    assert interpreted["interpret"] and (
+        interpreted["step"] == ssd.SCAN_HEADS_A_STEP == 8)
     # a state of 64, heads of 24 or seven heads a group fill no tile
     x, dt, a, b, c, d = args
     assert not ssd._heads_a_step(x, b[..., :64], chunk)

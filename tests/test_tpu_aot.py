@@ -75,6 +75,22 @@ def kernel_bodies(lowered_text: str) -> list:
     return bodies
 
 
+def kernel_grids(bodies: list) -> list:
+    """Each Mosaic body's grid, as its text has it: ``"32, 16, 16"``."""
+    return [re.search(r"iteration_bounds = array<i64: ([^>]*)>", body)
+            .group(1) for body in bodies]
+
+
+def kernel_operands(lowered_text: str) -> list:
+    """The type of the first operand of every ``tpu_custom_call`` of a
+    lowered (StableHLO) text, in the text's order (layers of one shape
+    share their text): the layout a kernel was fed in (``1x2048x512xbf16``
+    tokens-major, ``4x2048x128xbf16`` head-major)."""
+    return re.findall(
+        r"stablehlo\.custom_call @tpu_custom_call\(.*\} : "
+        r"\(tensor<([^>]*)>", lowered_text)
+
+
 CLAMP = re.compile(r"arith\.(minsi|maxsi)")
 
 
@@ -109,15 +125,16 @@ def test_the_flash_kernels_keep_the_name_the_benchmark_finds_them_by(
 def test_berts_s128_kernels_take_a_group_of_slices_a_grid_step(one_chip):
     """96 rows of 128 in 16 heads of 64: 1,536 single-tile slices, which
     the forward and the fused backward kernel take ``G`` a grid step. The
-    grid is ``BH // G`` with ``G > 1`` as the gauge says, the blocks hold
-    ``G`` slices, the body is the ungrouped one's two and five products
+    grid is ``BH // G`` with ``G > 1`` as ``_group_size`` plans, the blocks
+    hold ``G`` slices, the body is the ungrouped one's two and five products
     (batched, no loop: its size does not grow with ``G``), and the
     compiled step still holds one kernel of each under the name the
     benchmark finds them by."""
     import jax
     import jax.numpy as jnp
 
-    from horovod_tpu import metrics, profiler
+    from horovod_tpu import profiler
+    from horovod_tpu.ops import attention
     from horovod_tpu.ops.attention import flash_attention
 
     def loss(q, k, v):
@@ -128,8 +145,8 @@ def test_berts_s128_kernels_take_a_group_of_slices_a_grid_step(one_chip):
                                  sharding=one_chip)
     lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
         shape, shape, shape)
-    groups = [int(metrics.ATTN_GROUP_LAST.labels(kernel=kernel).get())
-              for kernel in ("fwd", "bwd")]
+    groups = [attention._group_size(bh, 128, 128, 64, 2, **counts)
+              for counts in (attention._FWD_SLICE, attention._BWD_SLICE)]
     bodies = kernel_bodies(lowered.as_text())
     assert len(bodies) == 2
     for group, body, products in zip(groups, bodies, (2, 5)):
@@ -201,8 +218,9 @@ def test_smallthinkers_kernels_compile_on_the_grid_they_should(
     import jax
     import jax.numpy as jnp
 
-    from horovod_tpu import attribution, metrics, profiler
+    from horovod_tpu import attribution, profiler
     from horovod_tpu.models import smallthinker
+    from horovod_tpu.ops import attention
 
     def loss(q, k, v):
         out = smallthinker.flash_attention_fn(q, k, v, jnp.bfloat16,
@@ -215,13 +233,16 @@ def test_smallthinkers_kernels_compile_on_the_grid_they_should(
 
     lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
         shaped(28), shaped(4), shaped(4))
-    tiles = {kind: int(metrics.ATTN_TILES_LAST.labels(kind=kind).get())
-             for kind in ("computed", "skipped", "grid")}
-    assert tiles == (dict(computed=252, skipped=772, grid=288) if window
-                     else dict(computed=528, skipped=496, grid=1024))
+    # the plan of 32 x 32 tiles of 512: the pairs a slice computes, and the
+    # K blocks a q block its grids walk (x 32 q blocks: 288 or 1,024 steps)
+    computed, band_kb, band_qb = attention._tile_plan(
+        True, 32, 32, 512, 512, 0, 0, window)
+    assert (computed, 32 * 32 - computed, 32 * band_kb) == (
+        (252, 772, 288) if window else (528, 496, 1024))
     bodies = kernel_bodies(lowered.as_text())
-    assert [re.search(r"iteration_bounds = array<i64: ([^>]*)>", body)
-            .group(1) for body in bodies] == [fwd_grid, fwd_grid, dkv_grid]
+    assert kernel_grids(bodies) == [fwd_grid, fwd_grid, dkv_grid]
+    assert fwd_grid.endswith(f", {band_kb}")
+    assert dkv_grid.endswith(f", {band_qb}")
     assert all(CLAMP.search(body) for body in bodies)
     assert all(body.count("scf.if") == 3 for body in bodies)
     found = kernel_instructions(lowered.compile().as_text())
@@ -314,8 +335,9 @@ def test_kimis_pair_kernels_compile_at_its_widths(one_chip):
     in two Pallas kernels (``pair_terms_kernel`` and its backward) named
     ``kda_pair_terms`` (not ``flash_attention``, by which the benchmark finds
     the attention kernels) under the scope the cell's readers sum, four
-    chunks of eight heads a grid step (the gauge, set when the program is
-    lowered, whose label says the operands are tokens-major). What
+    chunks of eight heads a grid step (the planning functions, and the
+    grids of the lowered kernels, whose first operands are tokens-major).
+    What
     the plain form wrote to HBM is gone: no array of ``k_right``'s shape
     (``[..., 4, 64, 128]``, four times ``k``), no ``sub x sub x d`` cube.
     **And no array is head-major** (PR 53): the four kernels take ``q``,
@@ -329,7 +351,7 @@ def test_kimis_pair_kernels_compile_at_its_widths(one_chip):
     import jax
     import jax.numpy as jnp
 
-    from horovod_tpu import metrics, profiler
+    from horovod_tpu import profiler
     from horovod_tpu.ops import linear_attention
 
     def loss(q, k, v, g, beta):
@@ -342,13 +364,18 @@ def test_kimis_pair_kernels_compile_at_its_widths(one_chip):
 
     grads = jax.jit(jax.grad(loss, argnums=range(5)))
     wide = shaped(1, 8192, 32, 128)
-    compiled = grads.lower(
+    on_tpu = grads.lower(
         wide, wide, wide, shaped(1, 8192, 32, 128, dtype=jnp.float32),
-        shaped(1, 8192, 32, dtype=jnp.float32)).compile()
-    assert metrics.LINATTN_PAIR_KERNEL_LAST.labels(
-        sub="16", operands="tokens_major").get() == 4
-    assert metrics.LINATTN_SCAN_KERNEL_LAST.labels(
-        chunk="64", operands="tokens_major").get() == 8
+        shaped(1, 8192, 32, dtype=jnp.float32))
+    assert linear_attention._chunks_a_step(8192 // 64) == 4
+    assert linear_attention._scan_heads_a_step(wide, wide, 64) == 8
+    # 128 chunks in steps of four, 32 heads in steps of eight; the chunk
+    # loop a chunk a step
+    assert sorted(kernel_grids(kernel_bodies(on_tpu.as_text()))) == [
+        "1, 128, 4"] * 2 + ["1, 32, 4"] * 2
+    assert set(kernel_operands(on_tpu.as_text())) <= {
+        "1x128x64x4096xbf16", "1x8192x4096xbf16"}
+    compiled = on_tpu.compile()
     text = compiled.as_text()
     kernels = kernel_instructions(text)
     # since PR 51 the solve and the chunk loop are two kernels of their own
@@ -379,10 +406,6 @@ def test_kimis_pair_kernels_compile_at_its_widths(one_chip):
     g = -jax.random.uniform(keys[3], (1, 128, 2, 128))
     beta = jax.nn.sigmoid(jax.random.normal(keys[4], (1, 128, 2)))
     lowered = grads.lower(q, k, v, g, beta)
-    assert metrics.LINATTN_PAIR_KERNEL_LAST.labels(
-        sub="16", operands="plain").get() == 0
-    assert metrics.LINATTN_SCAN_KERNEL_LAST.labels(
-        chunk="64", operands="plain").get() == 0
     assert "tpu_custom_call" not in lowered.as_text()
     assert re.search(r"4x64x128x", lowered.as_text())  # k_right is there
     assert all(bool(jnp.isfinite(x.astype(jnp.float32)).all())
@@ -495,8 +518,8 @@ def test_joyais_latent_layer_reaches_the_kernels_in_one_pass(one_chip, path):
     import jax
     import jax.numpy as jnp
 
-    from horovod_tpu import metrics
-    from horovod_tpu.models import joyai_flash, latent
+    from horovod_tpu.models import joyai_flash, latent, parts
+    from horovod_tpu.ops import rotary_split
 
     cfg = joyai_flash.JOYAI_LLM_FLASH
     attend = joyai_flash.flash_attention_fn
@@ -516,9 +539,13 @@ def test_joyais_latent_layer_reaches_the_kernels_in_one_pass(one_chip, path):
         out = layer.apply({"params": params}, x)
         return (out.astype(jnp.float32) ** 2).sum()
 
+    # the two things the layer observes: the shapes fill the tiles either
+    # way, the adapter says ``head_major`` or does not
+    queries = jax.ShapeDtypeStruct((1, 8192, 32 * 192), cfg.dtype)
+    assert rotary_split.tokens_a_step(queries, 32, 128, 64, 128) == 512
+    assert parts.takes_head_major(attend) == (path == "one_pass")
     text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         params, x).compile().as_text()
-    assert metrics.MLA_ROPE_PATH_LAST.labels(path=path).get() == 1
     kernels = kernel_instructions(text)
     flash = [scope for name, scope in kernels
              if re.fullmatch(r"flash_attention(\.\d+)?", name)]
@@ -554,11 +581,11 @@ def test_kimi_linears_step_holds_the_chunk_loops_kernels_three_a_layer(
     chunk loop's kernels three times (forward without residuals,
     recomputed with them, backward) beside the pair terms' three and the
     latent attention layer's three flash kernels: 3 + 12 + 12 custom
-    calls, and the gauge reads eight heads a grid step."""
+    calls, and the chunk loop's twelve walk the 128 chunks of a sequence
+    eight of the 32 heads a grid step."""
     import sys
 
     import horovod_tpu as hvd
-    from horovod_tpu import metrics
     from horovod_tpu.ops import linear_attention
 
     sys.path.insert(0, os.path.join(REPO_ROOT, "tools"))
@@ -566,8 +593,6 @@ def test_kimi_linears_step_holds_the_chunk_loops_kernels_three_a_layer(
         import lowered_sha
     finally:
         sys.path.remove(os.path.join(REPO_ROOT, "tools"))
-    metrics.LINATTN_SCAN_KERNEL_LAST.set(-1, chunk="64",
-                                         operands="tokens_major")
     try:
         text = lowered_sha.lowered_text("kimi-linear-48b-a3b_s8192_e8_dp1")
     finally:
@@ -580,8 +605,7 @@ def test_kimi_linears_step_holds_the_chunk_loops_kernels_three_a_layer(
     assert {name: names.count(name) for name in set(names)} == {
         "flash_attention": 3, linear_attention.PAIR_KERNEL_NAME: 12,
         linear_attention.SCAN_KERNEL_NAME: 12}
-    assert metrics.LINATTN_SCAN_KERNEL_LAST.labels(
-        chunk="64", operands="tokens_major").get() == 8
+    assert kernel_grids(kernel_bodies(text)).count("1, 128, 4") == 12
 
 
 @pytest.mark.parametrize("rows, seq, groups", [(24, 512, (2, 1)),
@@ -591,7 +615,7 @@ def test_a_bert_layer_hands_the_kernels_what_its_projections_wrote(
     """A BERT-Large layer at the benchmark's two shapes, forward and
     backward: the projections write ``bf16[rows, seq, 1024]`` and the two
     flash kernels take it in blocks of 128 lanes, two heads, of ``G`` rows
-    (``hvd_attn_heads_per_block_last`` 2), their bodies two and five
+    (``_heads_per_block`` 2, ``_group_size`` G), their bodies two and five
     products a head. Nothing of an activation's size is copied or
     transposed anywhere in the compiled layer: not q, k, v, the context or
     their gradients under ``hvd.block.attn_proj`` (8 copies a layer of
@@ -601,8 +625,9 @@ def test_a_bert_layer_hands_the_kernels_what_its_projections_wrote(
     import jax
     import jax.numpy as jnp
 
-    from horovod_tpu import metrics, profiler
+    from horovod_tpu import profiler
     from horovod_tpu.models import bert
+    from horovod_tpu.ops import attention
 
     cfg = bert.BERT_LARGE
     layer = bert.TransformerLayer(cfg, bert.flash_attention_fn)
@@ -622,10 +647,11 @@ def test_a_bert_layer_hands_the_kernels_what_its_projections_wrote(
 
     lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
         params, x, bias)
-    assert [int(metrics.ATTN_HEADS_PER_BLOCK_LAST.labels(kernel=k).get())
-            for k in ("fwd", "bwd")] == [2, 2]
-    assert tuple(int(metrics.ATTN_GROUP_LAST.labels(kernel=k).get())
-                 for k in ("fwd", "bwd")) == groups
+    assert attention._heads_per_block(64, 16) == 2
+    assert tuple(
+        attention._group_size(rows, seq, seq, 128, 2, heads=2, **counts)
+        for counts in (attention._FWD_SLICE,
+                       attention._BWD_FROM_OUT_SLICE)) == groups
     bodies = kernel_bodies(lowered.as_text())
     assert len(bodies) == 2
     for group, body, products in zip(groups, bodies, (2, 5)):
@@ -663,8 +689,9 @@ def test_sdars_two_streams_compile_on_two_causal_grids(one_chip):
     import jax
     import jax.numpy as jnp
 
-    from horovod_tpu import attribution, metrics, profiler
+    from horovod_tpu import attribution, profiler
     from horovod_tpu.models import sdar
+    from horovod_tpu.ops import attention
 
     def loss(q, k, v):
         streams = ([x[:, rows] for x in (q, k, v)]
@@ -679,15 +706,16 @@ def test_sdars_two_streams_compile_on_two_causal_grids(one_chip):
 
     lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
         shaped(32), shaped(4), shaped(4))
-    tiles = {kind: int(metrics.ATTN_TILES_LAST.labels(kind=kind).get())
-             for kind in ("blockdiff_computed", "blockdiff_skipped",
-                          "blockdiff_grid")}
-    assert tiles == dict(blockdiff_computed=272, blockdiff_skipped=752,
-                         blockdiff_grid=512)
+    # the two calls' plans together, out of the doubled stream's 32 x 32
+    # tiles: 136 + 136 computed, 752 skipped, 2 x 16 x 16 grid steps
+    plans = [attention._tile_plan(True, 16, 16, 512, 512, 0, 0,
+                                  behind=behind) for behind in (0, 4)]
+    computed = sum(pairs for pairs, _, _ in plans)
+    assert (computed, 4 * 16 * 16 - computed,
+            sum(16 * band_kb for _, band_kb, _ in plans)) == (272, 752, 512)
     bodies = kernel_bodies(lowered.as_text())
-    grids = sorted(re.search(r"iteration_bounds = array<i64: ([^>]*)>", body)
-                   .group(1) for body in bodies)
-    assert grids == ["32, 16, 16"] * 4 + ["4, 16, 8, 16"] * 2
+    assert sorted(kernel_grids(bodies)) == (
+        ["32, 16, 16"] * 4 + ["4, 16, 8, 16"] * 2)
     assert all(CLAMP.search(body) for body in bodies)
     assert all("arith.remsi" in body for body in bodies)
     compiled = lowered.compile().as_text()
@@ -725,13 +753,6 @@ def moved_activations(text: str, elements: int) -> list:
     return found
 
 
-def layout_gauge() -> list:
-    from horovod_tpu import metrics
-
-    return [int(metrics.ATTN_OPERAND_LAYOUT_LAST.labels(kernel=kernel).get())
-            for kernel in ("fwd", "dq", "dkv")]
-
-
 def test_a_toy_smallthinker_step_moves_no_query_sized_array(one_chip):
     """Four layers (full + NoPE, then three windowed with RoPE) of 4 query
     heads of 128 on 2, one sequence of 2,048 in tiles of 512. **The
@@ -766,9 +787,10 @@ def test_a_toy_smallthinker_step_moves_no_query_sized_array(one_chip):
     lowered = jax.jit(jax.value_and_grad(
         lambda params, tokens: smallthinker.causal_lm_loss(
             model, params, tokens))).lower(placed(params), tokens)
-    # the last forward traced is a windowed layer's, the last backward
-    # the first layer's, which is full
-    assert layout_gauge() == [1, 0, 0]
+    # the layer of full attention's kernels are fed head-major, the
+    # windowed layers' as the projections wrote them
+    assert set(kernel_operands(lowered.as_text())) == {
+        "1x2048x512xbf16", "4x2048x128xbf16"}
     text = lowered.compile().as_text()
     assert len(kernel_instructions(text)) == 3 * cfg.num_layers
     moved = moved_activations(text, 2048 * 4 * 128)
@@ -782,9 +804,9 @@ def test_a_toy_smallthinker_step_moves_no_query_sized_array(one_chip):
         return jax.ShapeDtypeStruct((1, 2048, heads, 64), jnp.bfloat16,
                                     sharding=one_chip)
 
-    jax.jit(jax.grad(attend, argnums=(0, 1, 2))).lower(
+    narrow = jax.jit(jax.grad(attend, argnums=(0, 1, 2))).lower(
         shaped(8), shaped(2), shaped(2))
-    assert layout_gauge() == [0, 0, 0]
+    assert kernel_operands(narrow.as_text()) == ["8x2048x64xbf16"] * 3
 
 
 def test_a_toy_sdar_step_never_holds_both_streams_heads_in_one_array(
@@ -824,7 +846,7 @@ def test_a_toy_sdar_step_never_holds_both_streams_heads_in_one_array(
     lowered = jax.jit(jax.value_and_grad(
         lambda params, batch: sdar.block_diffusion_loss(
             model, params, batch))).lower(placed(params), batch)
-    assert layout_gauge() == [0, 0, 0]
+    assert set(kernel_operands(lowered.as_text())) == {"4x1024x128xbf16"}
     text = lowered.compile().as_text()
     assert len(kernel_instructions(text)) == 6 * cfg.num_layers
     doubled = sorted({
@@ -844,17 +866,17 @@ def test_granites_layers_compile_at_their_widths(one_chip):
     them. The Mamba-2 scan: 64 heads of 64 with a state of 128 in 16
     chunks of 256, forward and backward, as the v5e's compiler takes it:
     two Pallas kernels named ``ssd_chunk_scan`` (not ``flash_attention``),
-    eight heads a grid step (the gauge, set as the program is lowered), no
-    loop over the chunks and no ``reduce-window``, every operation under
+    eight heads a grid step (``ssd._heads_a_step``, and the lowered
+    kernels' grid of 16 chunks x 8 steps), no loop over the chunks and no ``reduce-window``, every operation under
     the scope the cell's readers sum, and arguments + temporaries under
     0.25 GiB (the plain form's decay matrices alone are 268 MB in float32;
     the kernels' residual, the states that enter the chunks, is 33.5 MB)."""
     import jax
     import jax.numpy as jnp
 
-    from horovod_tpu import metrics, profiler
+    from horovod_tpu import profiler
     from horovod_tpu.models import granite
-    from horovod_tpu.ops import ssd
+    from horovod_tpu.ops import attention, ssd
 
     def shaped(*dims, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
@@ -867,11 +889,12 @@ def test_granites_layers_compile_at_their_widths(one_chip):
     lowered = jax.jit(jax.value_and_grad(attend, argnums=(0, 1, 2))).lower(
         shaped(1, 4096, 32, 64), shaped(1, 4096, 8, 64),
         shaped(1, 4096, 8, 64))
-    assert metrics.ATTN_KV_GROUP_LAST.labels().get() == 4
+    # four query heads read a key/value head: the dk/dv grid's third axis
+    assert attention._tiled_shapes(
+        shaped(32, 4096, 64), shaped(8, 4096, 64), None)[-1] == 4
     bodies = kernel_bodies(lowered.as_text())
-    grids = sorted(re.search(r"iteration_bounds = array<i64: ([^>]*)>", body)
-                   .group(1) for body in bodies)
-    assert grids == ["32, 8, 8", "32, 8, 8", "8, 8, 4, 8"]
+    assert sorted(kernel_grids(bodies)) == [
+        "32, 8, 8", "32, 8, 8", "8, 8, 4, 8"]
     found = kernel_instructions(lowered.compile().as_text())
     assert len(found) == 3
     with open(os.path.join(REPO_ROOT, "benchmark", "layer_metrics",
@@ -886,13 +909,13 @@ def test_granites_layers_compile_at_their_widths(one_chip):
             jnp.float32).sum()
 
     heads = shaped(64, dtype=jnp.float32)
-    compiled = jax.jit(jax.grad(scan, argnums=range(6))).lower(
-        shaped(1, 4096, 64, 64), shaped(1, 4096, 64, dtype=jnp.float32),
-        heads, shaped(1, 4096, 1, 128), shaped(1, 4096, 1, 128),
-        heads).compile()
-    assert metrics.SSM_CHUNKS_LAST.labels(
-        chunk="256", heads="64").get() == 16
-    assert metrics.SSM_SCAN_KERNEL_LAST.labels(chunk="256").get() == 8
+    x, group = shaped(1, 4096, 64, 64), shaped(1, 4096, 1, 128)
+    scanned = jax.jit(jax.grad(scan, argnums=range(6))).lower(
+        x, shaped(1, 4096, 64, dtype=jnp.float32), heads, group, group,
+        heads)
+    assert ssd._heads_a_step(x, group, 256) == 8
+    assert kernel_grids(kernel_bodies(scanned.as_text())) == ["1, 16, 8"] * 2
+    compiled = scanned.compile()
     text = compiled.as_text()
     kernels = kernel_instructions(text)
     assert len(kernels) == 2 and all(
@@ -925,7 +948,7 @@ def test_a_recomputed_mixer_holds_the_scans_kernels_three_a_layer(one_chip,
     import jax
     import jax.numpy as jnp
 
-    from horovod_tpu import metrics, profiler
+    from horovod_tpu import profiler
     from horovod_tpu.models import granite, nemotron_h, parts
     from horovod_tpu.ops import ssd
 
@@ -945,10 +968,12 @@ def test_a_recomputed_mixer_holds_the_scans_kernels_three_a_layer(one_chip,
     def loss(params, x):
         return mixer.apply(params, x).astype(jnp.float32).sum()
 
-    metrics.SSM_SCAN_KERNEL_LAST.set(-1, chunk=str(chunk))
-    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
-        params, x).compile().as_text()
-    assert metrics.SSM_SCAN_KERNEL_LAST.labels(chunk=str(chunk)).get() == 8
+    lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x)
+    # both cells' 64 heads in steps of eight
+    assert kernel_grids(kernel_bodies(lowered.as_text())) == [
+        f"1, {seq // chunk}, 8"] * 3
+    text = lowered.compile().as_text()
     kernels = kernel_instructions(text)
     assert all(name.startswith(ssd.SCAN_KERNEL_NAME + ".")
                for name, _ in kernels), kernels

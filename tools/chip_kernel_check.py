@@ -58,6 +58,26 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 BF16_TOL = 2e-2
 
 
+def plain_form(fn):
+    """``fn`` as any platform but a TPU lowers it, compiled for the chip
+    all the same: every primitive of ``ops/kernel_parts.where_lowered``
+    takes its plain form (its lowering for a CPU), and XLA compiles the
+    program for the device that runs this. What the kernels are held to
+    and timed beside, through the public entries alone. Traced and
+    compiled at the first call's shapes."""
+    import jax
+
+    compiled = []
+
+    def run(*args):
+        if not compiled:
+            compiled.append(jax.jit(fn).trace(*args).lower(
+                lowering_platforms=("cpu",)).compile())
+        return compiled[0](*args)
+
+    return run
+
+
 def _close(name, got, want) -> None:
     import numpy as np
 
@@ -286,8 +306,8 @@ def check_pair_terms(heads=32, chunks=128, size=64, sub=16, width=128,
                      calls=20) -> None:
     """``ops.linear_attention.pair_terms_kernel`` (operands tokens-major,
     ``[1, 8192, 32, 128]``, as the cell's projections write them) against
-    ``_pair_terms`` on the head-major view of the same arrays (``_by_head``:
-    the transposes are part of what the plain form costs),
+    the same call's plain form (:func:`plain_form`: on the head-major view
+    of the same arrays, the transposes part of what it costs),
     both compiled on the chip, at the Kimi Linear cell's shape
     in bfloat16 with a float32 ``gamma`` that falls by 0.05, 1.6 and
     20 a token: the two results (float32's rounding: the same reference
@@ -309,21 +329,17 @@ def check_pair_terms(heads=32, chunks=128, size=64, sub=16, width=128,
     def tokens_major(x):  # [B, H, N, C, d] -> [B, S, H, d]
         return jnp.moveaxis(x, 1, 3).reshape(1, chunks * size, heads, width)
 
-    forms = {
-        "plain": lambda *a: linear_attention._pair_terms(
-            *(linear_attention._by_head(x, size) for x in a), sub, dtype),
-        "kernel": lambda *a: linear_attention.pair_terms_kernel(
-            *a, size, sub, dtype)}
+    def forward(*a):
+        return linear_attention.pair_terms_kernel(*a, size, sub, dtype)
 
-    def both(form):
-        def run(q, k, gamma, bars):
-            out, vjp = jax.vjp(form, q, k, gamma)
-            return out + vjp(bars)
-        return jax.jit(run)
+    def both(q, k, gamma, bars):
+        out, vjp = jax.vjp(forward, q, k, gamma)
+        return out + vjp(bars)
 
-    runs = {name: {"forward": jax.jit(form),
-                   "forward and backward": both(form)}
-            for name, form in forms.items()}
+    runs = {name: {"forward": compiled(forward),
+                   "forward and backward": compiled(both)}
+            for name, compiled in (("plain", plain_form),
+                                   ("kernel", jax.jit))}
     keys = jax.random.split(jax.random.PRNGKey(3), 5)
     q, k = (tokens_major(jax.random.normal(key, shape).astype(dtype))
             for key in keys[:2])
@@ -369,12 +385,12 @@ def check_kda_scan(heads=32, chunks=128, size=64, sub=16, width=128,
     cell's shape (one sequence of 8,192 = 128 chunks of 64, 32 heads of
     128, bfloat16 with float32 decays): the rule as the TPU's program
     holds it (``ops.linear_attention.chunk_scan_kernel``'s two kernels
-    behind the pair kernels) and the rule with the plain ``_chunk_scan`` in
-    their place, both against the float32 token-by-token recurrence, ``o``
-    and the five gradients under a random cotangent, at the initial draw's
-    decays and at 1.6 a token; then ``chunk_scan_kernel`` and
-    ``_chunk_scan`` timed alone on the same operands, forward alone and
-    forward with backward, ``calls`` dispatched back to back with only the
+    behind the pair kernels) and the rule's plain form
+    (:func:`plain_form`), both against the float32 token-by-token
+    recurrence, ``o`` and the five gradients under a random cotangent, at
+    the initial draw's decays and at 1.6 a token; then
+    ``chunk_scan_kernel`` and its plain form timed alone on the same
+    operands, forward alone and forward with backward, ``calls`` dispatched back to back with only the
     last result kept. The VLIW bundles a grid step come from the sandbox's
     compile (``--xla_jf_dump_to``: PERF.md), not from here."""
     import time
@@ -384,7 +400,7 @@ def check_kda_scan(heads=32, chunks=128, size=64, sub=16, width=128,
     import numpy as np
     from jax import lax
 
-    from horovod_tpu.ops import linear_attention
+    from horovod_tpu.ops import kernel_parts, linear_attention
 
     f32, dtype = jnp.float32, jnp.bfloat16
     seq = chunks * size
@@ -415,20 +431,11 @@ def check_kda_scan(heads=32, chunks=128, size=64, sub=16, width=128,
     def rule(*t):
         return linear_attention.kimi_delta_rule(*t, chunk=size, sub=sub)
 
-    def plain_rule(*t):
-        lowered = linear_attention._chunk_scan_where_lowered
-        linear_attention._chunk_scan_where_lowered = (
-            linear_attention._chunk_scan_of_tokens)
-        try:
-            return rule(*t)
-        finally:
-            linear_attention._chunk_scan_where_lowered = lowered
-
-    def both(form):
+    def both(form, compiled=jax.jit):
         def run(*t):
             out, vjp = jax.vjp(form, *t[:-1])
             return (out,) + vjp(t[-1].astype(out.dtype))
-        return jax.jit(run)
+        return compiled(run)
 
     def timed(name, what, fn, *args):
         jax.block_until_ready(fn(*args))
@@ -447,13 +454,14 @@ def check_kda_scan(heads=32, chunks=128, size=64, sub=16, width=128,
     v = jax.random.normal(keys[2], (1, seq, heads, width)).astype(dtype)
     beta = jax.nn.sigmoid(jax.random.normal(keys[4], (1, seq, heads)))
     o_bar = jax.random.normal(keys[5], v.shape).astype(dtype)
-    forms = {"recurrence": recurrence, "plain": plain_rule, "kernel": rule}
+    forms = {"recurrence": (recurrence, jax.jit),
+             "plain": (rule, plain_form), "kernel": (rule, jax.jit)}
     for rate in (0.05, 1.6):
         g = -rate * jax.random.uniform(keys[3], q.shape, minval=0.5,
                                        maxval=1.0)
         args = (q, k, v, g, beta)
         got = {name: [np.asarray(t, np.float32)
-                      for t in both(form)(*args, o_bar)]
+                      for t in both(*form)(*args, o_bar)]
                for name, form in forms.items()}
         print(f" kimi_delta_rule at [1, {seq}, {heads}, {width}], chunk "
               f"{size}, {rate} a token:")
@@ -471,26 +479,33 @@ def check_kda_scan(heads=32, chunks=128, size=64, sub=16, width=128,
                 got["kernel"][n], want, rtol=0,
                 atol=max(BF16_TOL * scale, 2 * off["plain"]), err_msg=name)
     for name in ("plain", "kernel"):
-        timed(name + " rule", "forward", jax.jit(forms[name]), *args)
-        timed(name + " rule", "forward and backward", both(forms[name]),
+        form, compiled = forms[name]
+        timed(name + " rule", "forward", compiled(form), *args)
+        timed(name + " rule", "forward and backward", both(form, compiled),
               *args, o_bar)
 
-    gamma = linear_attention._running_sums(g, size)
+    # the chunk loop alone: gamma a chunk's running sums where g lies, beta
+    # a head a row, the pair terms the kernels'
+    gamma = kernel_parts.running_sum("bnij,bnjx->bnix", (1, chunks), size)(
+        g.reshape(1, chunks, size, -1)).reshape(g.shape)
     operands = [q, k, v, gamma,
-                linear_attention._by_head(beta, size)[..., None],
+                jnp.moveaxis(beta.reshape(1, chunks, size, heads), 3, 1)[
+                    ..., None],
                 *linear_attention.pair_terms_kernel(q, k, gamma, size, sub,
                                                     dtype)]
-    for name, form in (("_chunk_scan", linear_attention._chunk_scan_of_tokens),
-                       ("chunk_scan_kernel",
-                        linear_attention.chunk_scan_kernel)):
-        timed(name, "forward", jax.jit(form), *operands)
-        timed(name, "forward and backward", both(form), *operands, o_bar)
+    for name, compiled in (("chunk_scan_kernel's plain form", plain_form),
+                           ("chunk_scan_kernel", jax.jit)):
+        form = linear_attention.chunk_scan_kernel
+        timed(name, "forward", compiled(form), *operands)
+        timed(name, "forward and backward", both(form, compiled), *operands,
+              o_bar)
 
 
 def check_ssd(calls=20, cells=(("nemotron-h", 8192, 8, 128),
                               ("granite", 4096, 1, 256))) -> None:
-    """``ops.ssd.ssd_scan_kernel`` against ``_chunk_form`` and against the
-    float32 token-by-token recurrence, all compiled on the chip, at the
+    """``ops.ssd.ssd_scan_kernel`` against its plain form
+    (:func:`plain_form`) and against the float32 token-by-token
+    recurrence, all compiled on the chip, at the
     two cells' mixer shapes (Nemotron-H: 8,192 tokens, 64 heads of 64 on 8
     groups of 128, chunk 128; Granite: 4,096 tokens, one group, chunk 256)
     in bfloat16 with float32 steps, at the initial draw's steps (0.001 to
@@ -546,19 +561,20 @@ def check_ssd(calls=20, cells=(("nemotron-h", 8192, 8, 128),
         d = jax.random.normal(keys[5], (heads,))
         y_bar = jax.random.normal(keys[6], x.shape).astype(dtype)
         args = (x, dt, a, b, c, d)
-        forms = {
-            "recurrence": recurrence,
-            "plain": lambda *t: ssd._chunk_form(*t, chunk),
-            "kernel": lambda *t: ssd.ssd_scan_kernel(*t, chunk)}
+        def scan(*t):
+            return ssd.ssd_scan_kernel(*t, chunk)
 
-        def both(form):
+        forms = {"recurrence": (recurrence, jax.jit),
+                 "plain": (scan, plain_form), "kernel": (scan, jax.jit)}
+
+        def both(form, compiled):
             def run(*t):
                 out, vjp = jax.vjp(form, *t[:-1])
                 return (out,) + vjp(t[-1].astype(out.dtype))
-            return jax.jit(run)
+            return compiled(run)
 
         got = {name: [np.asarray(t, np.float32)
-                      for t in both(form)(*args, y_bar)]
+                      for t in both(*form)(*args, y_bar)]
                for name, form in forms.items()}
         print(f" ssd scan at {cell}'s [1, {seq}, {heads}, {width}], "
               f"{groups} groups of {state}, chunk {chunk}:")
@@ -575,9 +591,10 @@ def check_ssd(calls=20, cells=(("nemotron-h", 8192, 8, 128),
                 got["kernel"][n], want, rtol=0,
                 atol=max(BF16_TOL * scale, 2 * off["plain"]), err_msg=name)
         for name in ("plain", "kernel"):
-            for what, fn, more in (("forward", jax.jit(forms[name]), ()),
+            form, compiled = forms[name]
+            for what, fn, more in (("forward", compiled(form), ()),
                                    ("forward and backward",
-                                    both(forms[name]), (y_bar,))):
+                                    both(form, compiled), (y_bar,))):
                 jax.block_until_ready(fn(*args, *more))
                 t0 = time.perf_counter()
                 for _ in range(calls):
